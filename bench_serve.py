@@ -3,25 +3,24 @@
 
 Drives `inference/serve.ServeEngine` with N concurrent seeded streams
 against a tiny decoder and reports aggregate decode throughput plus the
-latency distribution — the serving analog of bench.py, under the SAME
-freshness-guard contract:
+latency distribution — the serving analog of bench.py:
 
 - exactly ONE JSON line on stdout
-  (``{"metric", "value", "unit", "vs_baseline", "extra"}``);
+  (``{"metric", "value", "unit", "vs_baseline", "extra", "device"}``);
   everything else goes to stderr;
-- a successful canonical run refreshes ``SERVE_LAST_GOOD.json``
-  (atomic replace, measured_utc + TADNN_BENCH_ROUND);
-- a failed run NEVER replays a previous number — it emits an explicit
-  zero-value ``*_unmeasurable`` record pointing at the last good round
-  (``stale_of``), which ``tadnn report --check`` fails loudly;
-- ``tadnn report --check`` covers ``SERVE_BENCH_r*.json`` the moment
-  the first round is committed (obs/report.check_bench).
+- it runs on the device JAX gives it and says which (``device``,
+  ``extra.backend``); a run that fails exits non-zero and prints no
+  record;
+- ``vs_baseline`` is the ratio to the committed ``SERVE_LAST_GOOD.json``
+  record when that holds the same metric (the file is read, never
+  written), and ``tadnn report --check`` covers the committed
+  ``SERVE_BENCH_r*.json`` rounds (obs/report.check_bench).
 
-The engine itself is backend-agnostic; the canonical capture runs on
-the 8-device CPU sim (metric suffix ``_cpu_sim``) because the serving
-numbers this round exists to track are SCHEDULING numbers — occupancy,
-queue time, iteration-level batching wins — which the sim measures
-honestly.  A TPU-attached run drops the suffix automatically.
+The committed rounds were captured on the 8-device CPU sim
+(``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8``,
+metric suffix ``_cpu_sim``): they are SCHEDULING numbers — occupancy,
+queue time, iteration-level batching wins — not device speeds.  A run
+on the chip drops the suffix.
 
 Usage (all key=value, bench.py-style):
 
@@ -34,10 +33,8 @@ Usage (all key=value, bench.py-style):
 
 ``gateway=1`` drives the SAME mix through the real HTTP/SSE ingress
 (inference/gateway): ``replicas=N`` engines behind the prefix-affinity
-router, one blocking SSE client per stream.  Non-canonical (argv
-present), so it never touches SERVE_LAST_GOOD — the number it reports
-is the HTTP/ingress overhead vs the direct-engine run on the same
-knobs (see BENCH_NOTES.md).
+router, one blocking SSE client per stream.  The number it reports is
+the HTTP/ingress overhead vs the direct-engine run on the same knobs.
 
 r05 makes the canonical run a SHARED-PREFIX mix: every stream's prompt
 opens with the same ``shared_prefix`` seeded tokens (a common system
@@ -77,7 +74,6 @@ block table in-kernel and the dense view is never materialized.
 
 from __future__ import annotations
 
-import datetime
 import json
 import os
 import sys
@@ -107,36 +103,12 @@ def parse_args():
     return args
 
 
-def _canonical_argv() -> bool:
-    """Only the bare invocation is the headline (bench.py's rule: debug
-    overrides must neither be saved nor replayed as the headline)."""
-    return not sys.argv[1:]
-
-
 def _load_last_good() -> dict:
     try:
         with open(LAST_GOOD_PATH) as f:
             return json.load(f)
     except (OSError, ValueError):
         return {}
-
-
-def _save_last_good(result: dict, device_kind: str) -> None:
-    data = _load_last_good()
-    data["serve"] = {
-        "result": result,
-        "measured_utc": datetime.datetime.now(
-            datetime.timezone.utc).strftime("%Y-%m-%dT%H:%M:%SZ"),
-        "device_kind": device_kind,
-    }
-    rnd = os.environ.get("TADNN_BENCH_ROUND")
-    if rnd:
-        data["serve"]["round"] = rnd
-    tmp = LAST_GOOD_PATH + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(data, f, indent=1, sort_keys=True)
-        f.write("\n")
-    os.replace(tmp, LAST_GOOD_PATH)
 
 
 def _pct(sorted_vals, q):
@@ -447,11 +419,10 @@ def run_gateway_load(args, journal) -> dict:
     """gateway=1: the same shared-prefix mix, but through the REAL
     HTTP/SSE path — ``replicas=N`` engines behind the prefix-affinity
     router, an asyncio ingress in a background thread, and one
-    blocking SSE client per stream.  Non-canonical by construction
-    (key=value argv disables the freshness guard): the number this
-    mode exists for is the GATEWAY OVERHEAD — tokens/s and latency
-    through HTTP vs the direct-engine r05 run on the same argv minus
-    ``gateway=1`` — not a new headline.
+    blocking SSE client per stream.  The number this mode exists for
+    is the GATEWAY OVERHEAD — tokens/s and latency through HTTP vs the
+    direct-engine r05 run on the same argv minus ``gateway=1`` — not a
+    new headline.
     """
     import asyncio
     import threading
@@ -583,47 +554,23 @@ def run_gateway_load(args, journal) -> dict:
 
 
 def main():
-    # serving scheduling numbers are backend-independent; default to the
-    # 8-device CPU sim unless a real accelerator is already visible
-    if not os.environ.get("JAX_PLATFORMS"):
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8").strip()
     args = parse_args()
     from torch_automatic_distributed_neural_network_tpu.obs.journal import (
         Journal,
     )
+    from torch_automatic_distributed_neural_network_tpu.topology import (
+        device_record,
+        enable_compilation_cache,
+    )
 
+    enable_compilation_cache()
     jpath = os.environ.get("TADNN_SERVE_JOURNAL")  # None -> in-memory
-    try:
-        with Journal(jpath, host0_only=False,
-                     meta={"tool": "bench_serve"}) as jnl:
-            result = (run_gateway_load(args, jnl)
-                      if int(args.get("gateway", 0))
-                      else run_load(args, jnl))
-    except Exception as e:  # noqa: BLE001 — the record IS the report
-        log(f"serve bench failed: {type(e).__name__}: {e}")
-        last = _load_last_good().get("serve")
-        stale_of = (last or {}).get("round") or (
-            last or {}).get("measured_utc")
-        print(json.dumps({
-            "metric": "serve_unmeasurable",
-            "value": 0.0,
-            "unit": "none",
-            "vs_baseline": 0.0,
-            "status": "backend_unreachable",
-            "stale": True,
-            **({"stale_of": stale_of} if stale_of else {}),
-            "extra": {"error": f"{type(e).__name__}: {e}"},
-        }), flush=True)
-        return
-    import jax
-
-    if (result.get("value", 0) > 0
-            and "error" not in (result.get("extra") or {})
-            and _canonical_argv()):
-        _save_last_good(result, jax.devices()[0].device_kind)
+    with Journal(jpath, host0_only=False,
+                 meta={"tool": "bench_serve"}) as jnl:
+        result = (run_gateway_load(args, jnl)
+                  if int(args.get("gateway", 0))
+                  else run_load(args, jnl))
+    result["device"] = device_record()
     print(json.dumps(result), flush=True)
 
 

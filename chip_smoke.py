@@ -1,0 +1,462 @@
+"""chip_smoke.py — the quickest proof that tadnn still starts on the chip.
+
+One process, one import of JAX, which holds the chip itself.  With no
+arguments it needs ONE TPU chip and drives the main path once through
+the entry points a user calls, at the full published width of GPT-2
+1.3B (24 layers, d_model 2048, 16 heads, vocab 50257; random weights
+from ``--seed``):
+
+1. **train** — ``AutoDistribute`` + ``Trainer`` as ``examples/train_gpt2.py``
+   builds them (``SyntheticLM`` data, bf16 train state, batch 16 x seq
+   1024).  Passes when every loss is finite, the last is below the first,
+   and the compiled step holds the Pallas flash kernel.
+2. **serve** — the same model in ``ServeEngine``: 8 streams (prompt 128,
+   32 new tokens) to completion with bf16 and with int8 KV, on the paged
+   kernel and next to the dense path.  Passes when every request
+   finishes with its token count, the paged kernel is in the decode
+   executable compiled (not interpreted), and its output is within a
+   stated tolerance of ``paged_attention_reference`` on the real pool.
+3. **cache** — where the persistent compile cache is, who placed it, and
+   how many entries it held before and after.
+
+``--chips 4`` runs, and runs only, what exists only across chips: the
+same train phase over four devices under ``strategy="fsdp"`` and under
+the planner's own ``auto`` choice, against the same seed and batch on
+one device (the repo's parity oracle).
+
+Every phase prints one JSON line; a phase that raises prints what failed
+and the exit code is non-zero.  The LAST line is exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``
+with the values JAX reports.  Without a TPU the script refuses to run;
+``--rehearsal`` is the one way to run it off the chip — the same phases
+at the ``test`` model size, for finding wrong paths and arguments on the
+CPU — and it says so on its first line.  A rehearsal is not a chip run.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import gc
+import json
+import math
+import os
+import statistics
+import sys
+import time
+import traceback
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    model: str
+    vocab: int
+    seq: int
+    batch: int
+    steps: int  # Trainer steps; the first one compiles
+    lr: float
+    streams: int
+    prompt: int
+    max_new: int
+    slots: int
+    max_len: int
+    block_size: int = 16
+
+
+CHIP = Sizes(model="1p3b", vocab=50257, seq=1024, batch=16, steps=8, lr=1e-4,
+             streams=8, prompt=128, max_new=32, slots=4, max_len=256)
+# (a model this small needs a larger step to move in a few updates)
+REHEARSAL = Sizes(model="test", vocab=512, seq=64, batch=8, steps=6, lr=1e-2,
+                  streams=4, prompt=24, max_new=6, slots=2, max_len=64)
+
+# bf16 train state, different reduction orders: per-step loss of a
+# sharded run against the one-device run of the same seed and batch
+# (5e-4 was measured on a v5e 2x2 for fsdp and tp_fsdp)
+PARITY_RTOL = 0.01
+# fp32 query, fp32 kernel arithmetic, against the fp32 reference at the
+# highest matmul precision: max |kernel - ref| over max |ref|
+# (2e-6 was measured on a v5e, bf16 and int8 pools)
+KERNEL_RTOL = 1e-4
+
+
+def emit(record: dict) -> None:
+    print(json.dumps(record), flush=True)
+
+
+def memory_record(devices) -> dict:
+    """Per-device allocator stats (the CPU backend reports none)."""
+    stats = [d.memory_stats() or {} for d in devices]
+    return {"peak_bytes_in_use": [s.get("peak_bytes_in_use") for s in stats],
+            "bytes_in_use": [s.get("bytes_in_use") for s in stats]}
+
+
+def mesh_record(mesh) -> dict:
+    """The mesh AutoDistribute built and which branch of
+    ``topology.build_mesh`` gives that device order: the ICI-aware
+    ``create_device_mesh`` or its row-major reshape fallback."""
+    from jax.experimental import mesh_utils
+
+    from torch_automatic_distributed_neural_network_tpu import mesh_degrees
+
+    devices = list(mesh.devices.flat)
+    try:
+        mesh_utils.create_device_mesh(mesh.devices.shape, devices=devices)
+        branch = "create_device_mesh"
+    except (ValueError, NotImplementedError, AssertionError) as e:
+        branch = f"row-major reshape ({type(e).__name__}: {e})"
+    return {"degrees": {a: n for a, n in mesh_degrees(mesh).items() if n > 1},
+            "device_ids": [d.id for d in devices], "build_mesh_branch": branch}
+
+
+class OneBatch:
+    """Step-indexed source (the Trainer's protocol) that serves one batch
+    at every step.  A few steps on fresh batches move the loss by less
+    than the batches differ; on one batch every update has to lower it,
+    which is the sharper check that the optimizer path works."""
+
+    step_indexed = True
+
+    def __init__(self, batch):
+        self._batch = batch
+
+    def batch(self, step: int):
+        return self._batch
+
+
+def train_phase(sz: Sizes, *, label: str, devices, strategy: str, seed: int,
+                on_chip: bool) -> dict:
+    """GPT-2 through AutoDistribute + Trainer; returns the phase record."""
+    import jax
+    import optax
+
+    import torch_automatic_distributed_neural_network_tpu as tad
+    from torch_automatic_distributed_neural_network_tpu.data.synthetic import (
+        SyntheticLM,
+    )
+    from torch_automatic_distributed_neural_network_tpu.models import GPT2
+    from torch_automatic_distributed_neural_network_tpu.obs.journal import (
+        Journal,
+    )
+    from torch_automatic_distributed_neural_network_tpu.topology import (
+        device_record,
+    )
+    from torch_automatic_distributed_neural_network_tpu.training import (
+        Trainer,
+        TrainerConfig,
+        next_token_loss,
+    )
+
+    data = OneBatch(SyntheticLM(vocab_size=sz.vocab, seq_len=sz.seq + 1,
+                                batch_size=sz.batch, seed=seed).batch(0))
+    ad = tad.AutoDistribute(
+        # the 1.3B recipe of bench.py: per-layer full recompute bounds the
+        # activations, so the planner's outer checkpoint stays off
+        GPT2(sz.model, vocab_size=sz.vocab, max_seq_len=sz.seq,
+             remat_policy="nothing"),
+        optimizer=optax.adamw(sz.lr),
+        loss_fn=next_token_loss,
+        strategy=strategy,
+        precision="bf16",
+        remat=False,
+        devices=devices,
+        export_cache=False,  # the AOT export cache stays out of the smoke
+    )
+    rng = jax.random.key(seed)
+    t0 = time.perf_counter()
+    text = ad.compiled_step_text(rng, data.batch(0))
+    compile_s = time.perf_counter() - t0
+    if text is None:
+        raise RuntimeError("the train step did not lower and compile")
+    kernels = text.count("tpu_custom_call")
+    if on_chip and not kernels:
+        raise RuntimeError(
+            "no tpu_custom_call in the compiled train step: attention "
+            "dispatched to the einsum path, not the Pallas flash kernel")
+
+    losses: list[float] = []
+    stamps: list[float] = []
+
+    def on_step(step, state, metrics):
+        losses.append(float(jax.block_until_ready(metrics["loss"])))
+        stamps.append(time.perf_counter())
+
+    journal = Journal(None, host0_only=False)
+    trainer = Trainer(
+        ad, TrainerConfig(steps=sz.steps, log_every=1), callbacks=[on_step],
+        items_per_step=sz.batch * sz.seq, journal=journal)
+    t0 = time.perf_counter()
+    state = trainer.fit(data, rng=rng)
+    fit_s = time.perf_counter() - t0
+    step_s = [b - a for a, b in zip(stamps, stamps[1:])]
+
+    if len(losses) != sz.steps or not all(math.isfinite(x) for x in losses):
+        raise RuntimeError(f"losses not finite or missing: {losses}")
+    if not losses[-1] < losses[0]:
+        raise RuntimeError(f"loss did not fall: {losses}")
+
+    # where one large parameter lives: code that has only ever seen one
+    # real chip may put everything on the first
+    big = max(jax.tree.leaves(state.params), key=lambda x: x.size)
+    shards = [{"device": s.device.id, "shape": list(s.data.shape)}
+              for s in big.addressable_shards]
+    skipped = journal.named("lint.skipped")
+    record = {
+        "phase": label, "device": device_record(),
+        "n_devices": len(devices), "strategy": ad.plan.strategy,
+        "remat": bool(ad.plan.remat), "mesh": mesh_record(ad.plan.mesh),
+        "model": f"gpt2-{sz.model}", "vocab": sz.vocab, "seq": sz.seq,
+        "batch": sz.batch, "precision": ad.precision.name,
+        "data": "SyntheticLM(seed) batch 0, at every step",
+        "steps": sz.steps, "losses": [round(x, 4) for x in losses],
+        "compile_s": round(compile_s, 2),
+        "first_step_s": round(stamps[0] - t0, 2), "fit_s": round(fit_s, 2),
+        "step_s_median": round(statistics.median(step_s), 4),
+        "custom_calls_in_step": kernels,
+        "preflight_skipped": ([e.get("error") for e in skipped] or False),
+        "largest_param": {"shape": list(big.shape), "shards": shards},
+        "memory": memory_record(devices),
+    }
+    del state, trainer, ad, big
+    gc.collect()
+    return record
+
+
+def kernel_vs_reference(eng, sz: Sizes, seed: int, on_chip: bool) -> dict:
+    """The paged kernel against ``paged_attention_reference`` on layer 0
+    of the pool the engine just served from: a numeric comparison (token
+    equality is the wrong test for a bf16 path)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torch_automatic_distributed_neural_network_tpu.ops import (
+        paged_attention as pa,
+    )
+
+    if on_chip and pa._default_interpret():
+        raise RuntimeError("the paged kernel would run interpreted on a TPU")
+    cfg = eng.cfg
+    S, MB, nb = eng.n_slots, eng.max_blocks, eng.pool.num_blocks
+    k0 = jax.tree.map(lambda x: x[0], eng.pool.kv["k"])
+    v0 = jax.tree.map(lambda x: x[0], eng.pool.kv["v"])
+    tables = jnp.asarray(
+        1 + np.arange(S * MB).reshape(S, MB) % (nb - 1), jnp.int32)
+    ctx = jnp.full((S,), sz.prompt + sz.max_new - 1, jnp.int32)
+    q = jax.random.normal(jax.random.key(seed + 1),
+                          (S, cfg.n_heads, cfg.head_dim), jnp.float32)
+    # on the chip the kernel is asked for compiled, not left to the default
+    out = jax.jit(lambda *a: pa.paged_attention(
+        *a, window=cfg.sliding_window,
+        interpret=False if on_chip else None))(q, k0, v0, tables, ctx)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda *a: pa.paged_attention_reference(
+            *a, window=cfg.sliding_window))(q, k0, v0, tables, ctx)
+    err = float(jnp.max(jnp.abs(out - ref)))
+    scale = float(jnp.max(jnp.abs(ref)))
+    if not (scale > 0 and math.isfinite(err) and err <= KERNEL_RTOL * scale):
+        raise RuntimeError(
+            f"paged kernel vs reference: max abs err {err:.3e} against "
+            f"max |ref| {scale:.3e} exceeds rtol {KERNEL_RTOL}")
+    return {"kernel_vs_reference_max_abs": err, "reference_max_abs": scale,
+            "kernel_rtol": KERNEL_RTOL}
+
+
+def serve_phase(sz: Sizes, *, seed: int, on_chip: bool) -> None:
+    """The model in ServeEngine: paged (the default) next to dense, with
+    bf16 and int8 KV.  Emits one record per engine."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torch_automatic_distributed_neural_network_tpu.inference.serve import (
+        ServeEngine,
+    )
+    from torch_automatic_distributed_neural_network_tpu.models import GPT2
+    from torch_automatic_distributed_neural_network_tpu.obs.journal import (
+        Journal,
+    )
+    from torch_automatic_distributed_neural_network_tpu.topology import (
+        device_record,
+    )
+
+    device = jax.devices()[0]
+    model = GPT2(sz.model, vocab_size=sz.vocab, max_seq_len=sz.max_len,
+                 remat=False)
+    rs = np.random.RandomState(seed)
+    prompts = rs.randint(1, sz.vocab, size=(sz.streams + 1, sz.prompt))
+    variables = jax.jit(model.init)(
+        jax.random.key(seed), jnp.asarray(prompts[:1], jnp.int32))
+    params_dtype = str(jax.tree.leaves(variables)[0].dtype)
+
+    dense_tokens: dict[str, list] = {}
+    for kv in ("bf16", "int8"):
+        for impl in ("dense", "paged"):
+            journal = Journal(None, host0_only=False)
+            eng = ServeEngine(
+                model, variables, n_slots=sz.slots, max_len=sz.max_len,
+                block_size=sz.block_size, quant_kv=(kv == "int8"),
+                attention_impl=impl, journal=journal, export_cache=False)
+            # a throwaway request compiles the prefill and decode traces
+            t0 = time.perf_counter()
+            eng.submit([int(t) for t in prompts[-1]], max_new_tokens=2)
+            eng.run()
+            warm_s = time.perf_counter() - t0
+            eng.finished.clear()
+            warm_steps = len(journal.named("serve.step"))
+            for p in prompts[:sz.streams]:
+                eng.submit([int(t) for t in p], max_new_tokens=sz.max_new)
+            t0 = time.perf_counter()
+            done = eng.run()
+            run_s = time.perf_counter() - t0
+
+            counts = sorted(r.n_generated for r in done)
+            if len(done) != sz.streams or counts != [sz.max_new] * sz.streams:
+                raise RuntimeError(
+                    f"serve {impl}/{kv}: {len(done)} of {sz.streams} "
+                    f"requests finished, token counts {counts}")
+            tokens = [r.out_tokens for r in sorted(done, key=lambda r: r.rid)]
+            decode_s = [e["decode_s"]
+                        for e in journal.named("serve.step")[warm_steps:]
+                        if e.get("decode_s")]
+            record = {
+                "phase": f"serve.{impl}.{kv}", "device": device_record(),
+                "model": f"gpt2-{sz.model}", "params_dtype": params_dtype,
+                "attention_impl": eng.attention_impl, "kv": kv,
+                "streams": sz.streams, "slots": sz.slots,
+                "prompt_len": sz.prompt, "max_new": sz.max_new,
+                "n_finished": len(done),
+                "tokens_generated": sum(counts),
+                "warm_s": round(warm_s, 2), "run_s": round(run_s, 2),
+                "decode_step_s_median": round(
+                    statistics.median(decode_s), 5),
+                "memory": memory_record([device]),
+            }
+            if impl == "dense":
+                dense_tokens[kv] = tokens
+            else:
+                kernels = eng.compiled_decode_text().count("tpu_custom_call")
+                if on_chip and not kernels:
+                    raise RuntimeError(
+                        "no tpu_custom_call in the compiled decode step: "
+                        "the paged kernel did not run compiled")
+                record["custom_calls_in_decode_step"] = kernels
+                record.update(kernel_vs_reference(eng, sz, seed, on_chip))
+                # informational: bf16 paths may part ways token by token
+                record["streams_agreeing_with_dense"] = sum(
+                    a == b for a, b in zip(tokens, dense_tokens[kv]))
+            emit(record)
+            del eng
+            gc.collect()
+
+
+def count_entries(path: str | None) -> int | None:
+    if path is None:
+        return None
+    return len(os.listdir(path)) if os.path.isdir(path) else 0
+
+
+def run_one_chip(sz: Sizes, seed: int, on_chip: bool) -> None:
+    import jax
+
+    emit(train_phase(sz, label="train", devices=jax.devices()[:1],
+                     strategy="auto", seed=seed, on_chip=on_chip))
+    serve_phase(sz, seed=seed, on_chip=on_chip)
+
+
+def run_four_chips(sz: Sizes, seed: int, on_chip: bool) -> None:
+    """The sharded train phase and what it is compared with, no other."""
+    import jax
+
+    devices = jax.devices()[:4]
+    if len(devices) < 4:
+        raise RuntimeError(f"--chips 4 needs four devices, JAX found "
+                           f"{len(jax.devices())}")
+    runs = {
+        "train.one_device": train_phase(
+            sz, label="train.one_device", devices=devices[:1],
+            strategy="auto", seed=seed, on_chip=on_chip),
+    }
+    emit(runs["train.one_device"])
+    for strategy in ("fsdp", "auto"):
+        label = f"train.4chips.{strategy}"
+        rec = runs[label] = train_phase(
+            sz, label=label, devices=devices, strategy=strategy, seed=seed,
+            on_chip=on_chip)
+        ref = runs["train.one_device"]["losses"]
+        rec["loss_rel_diff_vs_one_device"] = [
+            round(abs(a - b) / abs(b), 5) for a, b in zip(rec["losses"], ref)]
+        rec["parity_rtol"] = PARITY_RTOL
+        emit(rec)
+        if max(rec["loss_rel_diff_vs_one_device"]) > PARITY_RTOL:
+            raise RuntimeError(
+                f"{label}: losses {rec['losses']} differ from one device "
+                f"{ref} by more than rtol {PARITY_RTOL}")
+        in_use = rec["memory"]["bytes_in_use"]
+        if on_chip and not all(in_use):
+            raise RuntimeError(f"{label}: a chip holds no memory: {in_use}")
+    # fsdp is the one whose layout is known beforehand: the large
+    # parameter is cut four ways, one piece on each chip
+    big = runs["train.4chips.fsdp"]["largest_param"]
+    holders = {s["device"] for s in big["shards"]}
+    if len(holders) != 4 or any(s["shape"] == big["shape"]
+                                for s in big["shards"]):
+        raise RuntimeError(f"fsdp did not shard the largest parameter "
+                           f"over four devices: {big}")
+
+
+def main(argv: list[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: only the sharded train phase over four chips "
+                         "and its one-device comparison")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="weights, data and prompts are made from it")
+    ap.add_argument("--rehearsal", action="store_true",
+                    help="run off the chip at the test model size")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    from torch_automatic_distributed_neural_network_tpu.topology import (
+        compilation_cache_dir,
+        device_record,
+        enable_compilation_cache,
+    )
+
+    on_chip = jax.devices()[0].platform == "tpu"
+    if not on_chip and not args.rehearsal:
+        print(f"chip_smoke: needs a TPU and JAX found "
+              f"{jax.devices()[0].platform!r}; --rehearsal runs the phases "
+              f"at the test size off the chip", file=sys.stderr)
+        return 2
+    sz = REHEARSAL if args.rehearsal else CHIP
+    if args.rehearsal:
+        emit({"phase": "rehearsal",
+              "note": "REHEARSAL at the test model size: not a chip run, "
+                      "no number below is a device metric"})
+    cache_dir = enable_compilation_cache()  # None: opted out
+    placed_by = cache_dir and (
+        "JAX_COMPILATION_CACHE_DIR" if compilation_cache_dir()[1] == "env"
+        else "fixed in-checkout default")
+    entries_before = count_entries(cache_dir)
+    emit({"phase": "config", "device": device_record(), "chips": args.chips,
+          "rehearsal": args.rehearsal, "seed": args.seed,
+          "export_cache": "off", **dataclasses.asdict(sz)})
+    try:
+        if args.chips == 4:
+            run_four_chips(sz, args.seed, on_chip)
+        else:
+            run_one_chip(sz, args.seed, on_chip)
+        emit({"phase": "cache", "dir": cache_dir, "placed_by": placed_by,
+              "entries_before": entries_before,
+              "entries_after": count_entries(cache_dir)})
+    except Exception as e:  # noqa: BLE001 — report the phase, then fail
+        traceback.print_exc()
+        emit({"ok": False, "error": f"{type(e).__name__}: {e}"})
+        return 1
+    emit({"ok": True, "device": device_record()})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

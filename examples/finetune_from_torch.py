@@ -19,7 +19,7 @@ Two sources:
 
 Run (CPU sim)::
 
-    env -u PYTHONPATH JAX_PLATFORMS=cpu \
+    JAX_PLATFORMS=cpu \
       XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       python examples/finetune_from_torch.py run.steps=30
     # or the hand-written torch model:

@@ -20,7 +20,7 @@ def test_broadcast_delivers_root_shard(devices8):
     import jax.numpy as jnp
     import numpy as np
     from functools import partial
-    from torch_automatic_distributed_neural_network_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     import torch_automatic_distributed_neural_network_tpu as tad

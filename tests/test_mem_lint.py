@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import torch_automatic_distributed_neural_network_tpu as tad
@@ -249,7 +249,7 @@ class TestDtypeLint:
     def test_weak_type_into_collective_is_dt003(self, devices8):
         mesh = jax.make_mesh((8,), ("d",))
         f = shard_map(lambda x: jax.lax.psum(x, "d"), mesh=mesh,
-                      in_specs=P(), out_specs=P())
+                      in_specs=P(), out_specs=P(), check_vma=False)
         # tracing with a Python float keeps the operand weak-typed
         fs = dtype_lint.lint_dtypes(jax.make_jaxpr(f)(2.0))
         assert "DT003" in codes(fs)

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from torch_automatic_distributed_neural_network_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 import torch_automatic_distributed_neural_network_tpu as tad

@@ -206,3 +206,111 @@ def test_parse_topology_dcn_override_changes_chip_and_fingerprint():
     assert slow.chip.flops_per_s == base.chip.flops_per_s
     assert (tune_cache.topology_fingerprint(base)
             != tune_cache.topology_fingerprint(slow))
+
+
+# -- the one peak table -------------------------------------------------------
+
+
+def test_chip_table_resolves_the_v5e_device_kind():
+    from torch_automatic_distributed_neural_network_tpu import planner
+    from torch_automatic_distributed_neural_network_tpu.training import (
+        peak_flops_per_chip,
+    )
+
+    # "TPU v5 lite" is what jax.devices()[0].device_kind says on a v5e
+    spec = topology.chip_spec("TPU v5 lite")
+    assert spec is topology._CHIP_SPECS["v5 lite"]
+    assert spec.flops_per_s == 197e12 and spec.hbm_bytes == 16 * 2**30
+    # MFU reporting and the planner's HBM budget read the same table
+    assert peak_flops_per_chip("TPU v5 lite") == spec.flops_per_s
+    assert planner._hbm_bytes("TPU v5 lite") == spec.hbm_bytes
+    assert peak_flops_per_chip() == topology.chip_spec("cpu").flops_per_s
+
+
+@pytest.mark.parametrize("kind", ["unknown", "Quantum 9000", "TPU v3"])
+def test_unknown_device_kind_is_an_error_not_a_default(kind):
+    from torch_automatic_distributed_neural_network_tpu import planner
+    from torch_automatic_distributed_neural_network_tpu.training import (
+        peak_flops_per_chip,
+    )
+
+    with pytest.raises(ValueError, match="no peak numbers"):
+        topology.chip_spec(kind)
+    with pytest.raises(ValueError, match="no peak numbers"):
+        peak_flops_per_chip(kind)
+    with pytest.raises(ValueError, match="no peak numbers"):
+        planner._hbm_bytes(kind)
+    with pytest.raises(ValueError, match="no peak numbers"):
+        topology.Topology(num_devices=1, num_hosts=1, platform="tpu",
+                          device_kind=kind).chip
+
+
+# -- compile cache placement --------------------------------------------------
+
+
+@pytest.fixture
+def cache_config():
+    """Put jax's compile-cache config back as the test found it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs)
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev[0])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", prev[1])
+    cc.reset_cache()
+
+
+def test_compile_cache_placed_by_the_environment(
+        tmp_path, monkeypatch, cache_config):
+    placed = tmp_path / "placed" / "cache"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(placed))
+    monkeypatch.delenv("TADNN_NO_COMPILE_CACHE", raising=False)
+    monkeypatch.setattr(topology, "_DEFAULT_COMPILE_CACHE",
+                        str(tmp_path / "default"))
+    before = jax.config.jax_compilation_cache_dir
+    assert topology.compilation_cache_dir() == (str(placed), "env")
+    assert topology.enable_compilation_cache() == str(placed)
+    # the directory is left to the variable (jax reads it itself) ...
+    assert jax.config.jax_compilation_cache_dir == before
+    # ... only the threshold is set, and nothing is created
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 1.0
+    assert list(tmp_path.iterdir()) == []
+
+
+def test_compile_cache_default_is_one_fixed_path_in_the_checkout(
+        tmp_path, monkeypatch):
+    import pathlib
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = pathlib.Path(topology.__file__).resolve().parents[1]
+    seen = []
+    for cwd in (tmp_path, repo / "tests"):
+        monkeypatch.chdir(cwd)
+        seen.append(topology.compilation_cache_dir())
+    assert seen[0] == seen[1] == (str(repo / ".jax_cache"), "default")
+    # listed in .gitignore, so a checkout never commits it
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+
+
+def test_compile_cache_default_is_created_only_when_called(
+        tmp_path, monkeypatch, cache_config):
+    default = tmp_path / "jc"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("TADNN_NO_COMPILE_CACHE", raising=False)
+    monkeypatch.setattr(topology, "_DEFAULT_COMPILE_CACHE", str(default))
+    assert not default.exists()
+    assert topology.enable_compilation_cache() == str(default)
+    assert default.is_dir()
+    assert jax.config.jax_compilation_cache_dir == str(default)
+    assert topology.enable_compilation_cache() == str(default)  # idempotent
+
+
+def test_compile_cache_opt_out(tmp_path, monkeypatch):
+    monkeypatch.setenv("TADNN_NO_COMPILE_CACHE", "1")
+    monkeypatch.setattr(topology, "_DEFAULT_COMPILE_CACHE",
+                        str(tmp_path / "jc"))
+    before = jax.config.jax_compilation_cache_dir
+    assert topology.enable_compilation_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+    assert list(tmp_path.iterdir()) == []

@@ -441,14 +441,23 @@ def test_check_unwrapped_record_too(tmp_path):
     assert code == 0
 
 
-def test_repo_current_round_is_flagged_stale():
-    # the committed r05 artifact IS the backend-unreachable case the
-    # guard exists for — it must fail the check until a live round lands
+def test_repo_check_covers_the_committed_serving_rounds_alone():
+    # the repo commits serving rounds and no training rounds: --check
+    # must judge the family that exists and not fail the one that does not
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if not any(f.startswith("BENCH_r") for f in os.listdir(repo)):
-        pytest.skip("no committed bench rounds")
     code, msgs = obs_report.check_bench(repo)
-    assert code == 1
+    assert code == 0, msgs
+    assert msgs and all(m.startswith("SERVE_BENCH_r") for m in msgs)
+    assert cli.main(["report", repo, "--check"]) == 0
+
+
+def test_check_serving_family_alone_in_a_directory(tmp_path):
+    _write_round(str(tmp_path), 1, {"metric": "serve_tokens", "value": 5.0})
+    os.rename(os.path.join(str(tmp_path), "BENCH_r01.json"),
+              os.path.join(str(tmp_path), "SERVE_BENCH_r01.json"))
+    code, msgs = obs_report.check_bench(str(tmp_path))
+    assert code == 0 and len(msgs) == 1
+    assert msgs[0].startswith("SERVE_BENCH_r01.json: fresh")
 
 
 # ----------------------------------------------- cost-model feedback

@@ -2,7 +2,7 @@
 
 Trace only — ``jax.make_jaxpr`` runs the Python of the step function
 under abstract values and never invokes XLA, so this layer is cheap
-enough to run as a preflight on every Trainer start (BENCH_NOTES).
+enough to run as a preflight on every Trainer start.
 
 The collective inventory covers the *explicit* collectives visible in
 the jaxpr — the manual ``shard_map``/``pmap`` regions (ring attention,

@@ -660,7 +660,7 @@ def default_paths(repo_root: pathlib.Path | str | None = None
     for rel in ("torch_automatic_distributed_neural_network_tpu", "tadnn"):
         if (repo_root / rel).is_dir():
             paths.append(repo_root / rel)
-    for rel in ("bench.py", "__graft_entry__.py", "tpu_probe.py"):
+    for rel in ("bench.py", "bench_serve.py", "chip_smoke.py"):
         if (repo_root / rel).exists():
             paths.append(repo_root / rel)
     return paths

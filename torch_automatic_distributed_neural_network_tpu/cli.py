@@ -73,10 +73,9 @@ def _run_script(script: str, script_args: list[str]) -> int:
 
 
 def cmd_run(args: argparse.Namespace) -> int:
-    if not os.environ.get("TADNN_NO_COMPILE_CACHE"):
-        from .topology import enable_compilation_cache
+    from .topology import enable_compilation_cache
 
-        enable_compilation_cache()
+    enable_compilation_cache()
     _maybe_init_distributed()
     return _run_script(args.script, args.script_args)
 
@@ -172,7 +171,9 @@ def cmd_fit(args: argparse.Namespace) -> int:
     import optax
 
     from . import AutoDistribute
+    from .topology import enable_compilation_cache
 
+    enable_compilation_cache()
     if args.loss == "blockwise" and args.family in ("bert", "vit"):
         # blockwise CE is a CAUSAL next-token loss; silently running it
         # on an encoder would fit-report a graph no real config trains
@@ -1105,7 +1106,9 @@ def cmd_serve(args: argparse.Namespace) -> int:
     from .inference.serve import ServeEngine, random_adapter
     from .models import GPT2, Llama, MoE
     from .obs.journal import Journal
+    from .topology import enable_compilation_cache
 
+    enable_compilation_cache()
     family = {"gpt2": GPT2, "llama": Llama, "moe": MoE}[args.family]
     size = args.size or "test"
     max_len = args.max_len or 256

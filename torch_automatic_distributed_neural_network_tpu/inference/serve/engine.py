@@ -576,7 +576,6 @@ class ServeEngine:
         from ...topology import detect
         from ...tune import cache as tune_cache
 
-        S, MB, T = self.n_slots, self.max_blocks, 1 + self.speculative
         devices = (list(self.mesh.devices.flat)
                    if self.mesh is not None else None)
         topo_fp = tune_cache.topology_fingerprint(detect(devices))
@@ -584,7 +583,7 @@ class ServeEngine:
         # everything the serve traces close over: two engines that
         # differ in any of these must compile separately
         program = {
-            "n_slots": S, "max_len": self.max_len,
+            "n_slots": self.n_slots, "max_len": self.max_len,
             "block_size": block_size, "num_blocks": num_blocks,
             "attention_impl": self.attention_impl,
             "speculative": self.speculative,
@@ -601,16 +600,9 @@ class ServeEngine:
                       n_adapters, quant_adapters]
                      if self.lora_spec is not None else None),
         }
-        factors = (self.adapter_pool.factors
-                   if self.adapter_pool is not None else {})
-        decode_abs = jax.eval_shape(lambda: (
-            self.params, self.pool.kv,
-            jnp.zeros((S, MB), jnp.int32), jnp.zeros((S,), jnp.int32),
-            jnp.zeros((S, T), jnp.int32), jnp.zeros((S,), jnp.bool_),
-            factors, jnp.zeros((S,), jnp.int32),
-            jax.random.fold_in(self._rng, 2**20)))
         res = aot_mod.cached_compile(
-            self._step_fn, decode_abs, cache=cache, kind="serve_decode",
+            self._step_fn, self._abstract_decode_args(), cache=cache,
+            kind="serve_decode",
             key=export_cache_mod.executable_key(
                 "serve_decode", sig, topo_fp, program, tags))
         if res is not None:
@@ -633,6 +625,27 @@ class ServeEngine:
                 self._prefill_fn = aot_mod.ExportedCallable(
                     res.compiled, self._prefill_fn, "serve_prefill")
                 self.export_info.append(res.to_json())
+
+    def _abstract_decode_args(self) -> tuple:
+        """Abstract operands of the decode step, from ``jax.eval_shape``
+        over the exact runtime operands — nothing is materialized."""
+        S, MB, T = self.n_slots, self.max_blocks, 1 + self.speculative
+        factors = (self.adapter_pool.factors
+                   if self.adapter_pool is not None else {})
+        return jax.eval_shape(lambda: (
+            self.params, self.pool.kv,
+            jnp.zeros((S, MB), jnp.int32), jnp.zeros((S,), jnp.int32),
+            jnp.zeros((S, T), jnp.int32), jnp.zeros((S,), jnp.bool_),
+            factors, jnp.zeros((S,), jnp.int32),
+            jax.random.fold_in(self._rng, 2**20)))
+
+    def compiled_decode_text(self) -> str:
+        """Optimized HLO text of the compiled decode step (the serving
+        analog of ``AutoDistribute.compiled_step_text``): what shows
+        whether the paged kernel is in the executable as a Mosaic
+        ``tpu_custom_call`` rather than interpreted."""
+        return self._step_fn.lower(
+            *self._abstract_decode_args()).compile().as_text()
 
     # -- request intake ------------------------------------------------------
 

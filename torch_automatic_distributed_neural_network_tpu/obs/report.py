@@ -4,8 +4,7 @@ answer "where did the wall-clock go?" from artifacts the run produced.
 Inputs: a run directory (containing ``journal.jsonl`` and optionally
 ``metrics.jsonl``) or explicit file paths.  Output: one dict (``--json``)
 or a human summary — throughput, MFU, compile/recompile accounting,
-expected comm bytes vs. XLA bytes-accessed, goodput breakdown, and any
-bench probe/tunnel incidents recorded in the journal.
+expected comm bytes vs. XLA bytes-accessed, and the goodput breakdown.
 """
 
 from __future__ import annotations
@@ -247,17 +246,6 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
     skew = host_skew(events)
     if skew:
         report["hosts"] = skew
-    probes = [e for e in events
-              if str(e.get("name", "")).startswith("bench.")]
-    if probes:
-        report["bench_incidents"] = [
-            {k: v for k, v in e.items() if k not in ("kind", "depth")}
-            for e in probes
-            if e.get("name") in ("bench.probe", "bench.stale",
-                                 "bench.unmeasurable")
-            and (e.get("probe_error") or e.get("stale")
-                 or e.get("ok") is False)
-        ]
     stalls = [e for e in events if e.get("name") == "watchdog.stall"]
     restarts = [e for e in events if e.get("name") == "elastic.restart"]
     corrupt = [e for e in events if e.get("name") == "ckpt.corrupt"]
@@ -1189,13 +1177,6 @@ def format_report(report: dict) -> str:
                 f"  xla compiled peak "
                 f"{_fmt_bytes(me['compiled_peak_bytes'])} "
                 f"(static/compiled {me.get('static_over_compiled')}x)")
-    bi = report.get("bench_incidents")
-    if bi:
-        lines.append(f"bench incidents: {len(bi)}")
-        for e in bi[-3:]:
-            lines.append(f"  {e.get('name')}: mode={e.get('mode')} "
-                         f"error={e.get('probe_error')} "
-                         f"stale={e.get('stale')}")
     return "\n".join(lines)
 
 
@@ -1237,32 +1218,36 @@ def check_bench(target: str, *, bench_path: str | None = None,
     ``BENCH_LAST_GOOD.json`` (the repo root in CI); explicit paths
     override discovery.  Returns ``(exit_code, messages)``.
 
-    The serving trajectory (``SERVE_BENCH_r*.json`` +
-    ``SERVE_LAST_GOOD.json`` from bench_serve.py) is checked under the
-    SAME rules whenever either artifact exists in ``target`` — once a
-    serving round has been committed it can never silently go stale —
-    and skipped entirely before that (a training-only checkout is not
-    failed for a trajectory it never started).  Explicit ``bench_path``
-    / ``last_good_path`` bypass the serve check (single-family mode).
+    A family — training (``BENCH_r*.json`` + ``BENCH_LAST_GOOD.json``)
+    or serving (``SERVE_BENCH_r*.json`` + ``SERVE_LAST_GOOD.json``) — is
+    checked whenever either of its artifacts exists in ``target``: once
+    a round has been committed it can never silently go stale, and a
+    checkout is not failed for a trajectory it never started.  A
+    directory holding neither fails as a missing training trajectory.
+    Explicit ``bench_path`` / ``last_good_path`` check the training
+    family alone.
     """
     import glob as _glob
 
     d = target if os.path.isdir(target) else os.path.dirname(
         os.path.abspath(target)) or "."
-    if bench_path is None and last_good_path is None:
-        code, msgs = _check_bench_family(
-            d, "BENCH", bench_path=None, last_good_path=None)
-        armed = (_glob.glob(os.path.join(d, "SERVE_BENCH_r*.json"))
-                 or os.path.isfile(
-                     os.path.join(d, "SERVE_LAST_GOOD.json")))
-        if armed:
-            scode, smsgs = _check_bench_family(
-                d, "SERVE_BENCH", bench_path=None, last_good_path=None)
-            code = max(code, scode)
-            msgs = msgs + smsgs
-        return code, msgs
-    return _check_bench_family(d, "BENCH", bench_path=bench_path,
-                               last_good_path=last_good_path)
+    if bench_path is not None or last_good_path is not None:
+        return _check_bench_family(d, "BENCH", bench_path=bench_path,
+                                   last_good_path=last_good_path)
+    armed = [
+        prefix for prefix, last_good in (
+            ("BENCH", "BENCH_LAST_GOOD.json"),
+            ("SERVE_BENCH", "SERVE_LAST_GOOD.json"))
+        if _glob.glob(os.path.join(d, f"{prefix}_r*.json"))
+        or os.path.isfile(os.path.join(d, last_good))
+    ]
+    code, msgs = 0, []
+    for prefix in armed or ["BENCH"]:
+        fcode, fmsgs = _check_bench_family(
+            d, prefix, bench_path=None, last_good_path=None)
+        code = max(code, fcode)
+        msgs += fmsgs
+    return code, msgs
 
 
 def _check_bench_family(d: str, prefix: str, *,

@@ -425,17 +425,6 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
        req={"window_start_s": "float?", "window_end_s": "float?",
             "ok_windows": "int"}),
 
-    # -- bench probes -------------------------------------------------------
-    _s("bench.probe", "bench backend probe result",
-       req={"mode": "str", "ok": "bool", "probe_error": "str?"},
-       opt={"argv": "list"}),
-    _s("bench.stale", "backend unreachable; last committed result is "
-       "stale, NOT re-emitted",
-       req={"mode": "str", "stale": "bool", "probe_error": "str?"},
-       opt={"measured_utc": "str", "stale_of": "any", "metric": "str?"}),
-    _s("bench.unmeasurable", "backend unreachable and no committed "
-       "result exists",
-       req={"mode": "str", "ok": "bool", "probe_error": "str?"}),
 )}
 
 

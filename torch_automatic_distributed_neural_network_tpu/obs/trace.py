@@ -52,7 +52,7 @@ COLLECTIVE_OPS = (
 
 # Which planner.expected_collective_bytes per-device category each HLO
 # collective family lands in (tune/cost.py._CATEGORY_AXES is the same
-# taxonomy from the modeled side).
+# classification from the modeled side).
 CATEGORY_BY_OP = {
     "all-reduce": "grad_allreduce",
     "all-gather": "param_allgather",

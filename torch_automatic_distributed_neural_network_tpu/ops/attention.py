@@ -169,18 +169,14 @@ def chunked_attention(
 def _flash_ok(q: jax.Array, k: jax.Array, mask) -> bool:
     """Auto-dispatch gate for the Pallas flash kernel: TPU backend, no
     explicit mask, a sequence long enough that block streaming wins.
-    Measured on the v5e (bench.py mode=attention, BENCH_NOTES.md): flash
-    beats the einsum path 20x at seq 512, 87x at 2048, 43x at 8192
-    (fwd+bwd, causal, 16 heads x d128) — 512 is a conservative floor set
-    by the kernel's block size, not the perf crossover."""
+    The flash-vs-einsum speedup is not measured on the current code
+    (``bench.py mode=attention`` measures it); 512 is a conservative
+    floor set by the kernel's block size, not the perf crossover."""
     if mask is not None:
         return False
     if q.shape[1] < 512 or q.shape[1] != k.shape[1]:
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def attention(
@@ -261,7 +257,7 @@ def attention(
             # custom call is not partitionable — run it under shard_map
             # over the batch (and head, under TP) axes, which is exact:
             # attention is independent per batch element and per head.
-            from ..utils.jax_compat import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             tp = ctx.degrees.get(ctx.head_axis, 1)

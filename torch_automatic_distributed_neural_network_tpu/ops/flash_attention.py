@@ -398,12 +398,11 @@ def _pad_to(x, target, dim):
     return jnp.pad(x, widths)
 
 
-# Per-seq (block_q, block_k) fwd+bwd winners, measured on a live v5e
-# (BENCH_NOTES.md round-5 `mode=attention sweep=1`: 36.1% HW util @ 8k
-# with 512x2048 vs 29.2% for the old 1024x1024 default; 40.3% @ 16k with
-# 1024x1024; 25.2% @ 2k with 512x2048).  2048-wide q blocks, and
-# bq>=1024 x bk>=1024 combinations beyond these, exceed the compile
-# helper's VMEM budget and fail to compile.
+# Per-seq (block_q, block_k) fwd+bwd winners of an earlier v5e sweep
+# (`bench.py mode=attention sweep=1`); their utilization is not measured
+# on the current code.  2048-wide q blocks, and bq>=1024 x bk>=1024
+# combinations beyond these, exceed the compile helper's VMEM budget
+# and fail to compile.
 _MEASURED_BLOCKS = {
     2048: (512, 2048),
     8192: (512, 2048),
@@ -478,10 +477,10 @@ def flash_attention(
     entirely outside the band are skipped at the grid level (fwd AND both
     bwd passes), so compute scales O(S * window) instead of O(S^2 / 2).
 
-    Block defaults resolve per-sequence from a live-v5e sweep
-    (:func:`default_blocks`; BENCH_NOTES.md round-5 block sweep):
-    512x2048 up to seq 8k, 1024x1024 at 16k+.  2048-wide q blocks
-    exceed the VMEM budget and fail to compile.
+    Block defaults resolve per-sequence (:func:`default_blocks`):
+    512x2048 up to seq 8k, 1024x1024 at 16k+ (not measured on the
+    current code).  2048-wide q blocks exceed the VMEM budget and fail
+    to compile.
     """
     qf, kf, vf, cfg, (b, hq, sq, d) = _prep_bshd(
         q, k, v, causal, block_q, block_k, interpret, window

@@ -17,14 +17,17 @@ Shape of the problem (one decode token per slot):
     tables: [S, MB] int32        block ids, null-padded (kv_pool)
     ctx:    [S] int32            keys 0..ctx inclusive are valid
 
-Grid is ``(S, kvH, MB)`` with the block axis innermost ("arbitrary"
-semantics): VMEM scratch carries flash-style online-softmax statistics
-(running max / sum / accumulator, fp32) across a slot's blocks, exactly
-the ``ops/flash_attention.py`` discipline.  GQA is native — each kv
-head serves its ``Hq // kvH`` query group without materializing the
-head broadcast.  Blocks past a slot's context (null-table padding) are
-skipped at the grid level via the prefetched ``ctx``; a sliding window
-additionally skips blocks entirely older than ``ctx - window``.
+Grid is ``(S, MB)`` with the block axis innermost ("arbitrary"
+semantics); a grid step takes one whole page, every kv head of it (the
+block shape the TPU lowering accepts — it refuses one head sliced out
+of the second-minor dimension).  VMEM scratch carries flash-style
+online-softmax statistics (running max / sum / accumulator, fp32)
+across a slot's blocks, exactly the ``ops/flash_attention.py``
+discipline.  GQA is native — each kv head serves its ``Hq // kvH``
+query group without materializing the head broadcast.  Blocks past a
+slot's context (null-table padding) are skipped at the grid level via
+the prefetched ``ctx``; a sliding window additionally skips blocks
+entirely older than ``ctx - window``.
 
 int8 KV (``inference/quant.quantize_kv``'s ``{"q", "scale"}`` leaves)
 is dequantized ON LOAD, fused into the kernel: the int8 payload and its
@@ -70,7 +73,16 @@ class _Cfg:
 
 
 def _decode_kernel(*refs, cfg: _Cfg, scale: float):
-    """One (slot, kv_head, block) grid step of paged decode attention."""
+    """One (slot, block) grid step of paged decode attention, all kv
+    heads of the block at once.
+
+    A pool page arrives as ``[bs, kvH, hd]`` — its natural layout, heads
+    on sublanes and ``hd`` on lanes — so the one-query-row products are
+    VPU broadcasts and reductions in that layout (no per-head slice of
+    the second-minor dimension, which the TPU lowering refuses, and no
+    transpose): scores reduce over lanes, the softmax statistics and the
+    value sum reduce over the leading ``bs`` dimension.
+    """
     tables_ref, ctx_ref = refs[0], refs[1]
     if cfg.quantized:
         (q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
@@ -79,8 +91,8 @@ def _decode_kernel(*refs, cfg: _Cfg, scale: float):
         q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs[2:]
 
     s = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
@@ -100,44 +112,41 @@ def _decode_kernel(*refs, cfg: _Cfg, scale: float):
 
     @pl.when(relevant)
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32)  # [G, hd]
         if cfg.quantized:
-            # dequantize-on-load: int8 payload x per-(token, head) scale,
-            # fused right before the dot — the dense form never hits HBM
-            k = kq_ref[0, :, 0].astype(jnp.float32) * ks_ref[0, :, 0]
-            v = vq_ref[0, :, 0].astype(jnp.float32) * vs_ref[0, :, 0]
+            # dequantize-on-load: int8 payload x per-(token, head) scale
+            # (lane-broadcast) — the dense form never hits HBM
+            k = kq_ref[0].astype(jnp.float32) * ks_ref[0]  # [bs, kvH, hd]
+            v = vq_ref[0].astype(jnp.float32) * vs_ref[0]
         else:
-            k = k_ref[0, :, 0].astype(jnp.float32)  # [bs, hd]
-            v = v_ref[0, :, 0].astype(jnp.float32)
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [G, bs]
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            k = k_ref[0].astype(jnp.float32)
+            v = v_ref[0].astype(jnp.float32)
+        pos = start + jax.lax.broadcasted_iota(
+            jnp.int32, (k.shape[0], k.shape[1], 1), 0)
         valid = pos <= ctx
         if cfg.window is not None:
             valid = jnp.logical_and(valid, pos > ctx - cfg.window)
-        sc = jnp.where(valid, sc, _NEG_BIG)
 
-        m_prev = m_ref[:, :1]  # [G, 1] (lane-broadcast storage)
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        m_new = jnp.maximum(m_new, _NEG_BIG / 2)
-        p = jnp.exp(sc - m_new)  # [G, bs] fp32
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [G, hd]
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        for g in range(cfg.group):  # static: query heads per kv head
+            q = q_ref[0, g].astype(jnp.float32)  # [kvH, hd]
+            sc = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+            sc = jnp.where(valid, sc, _NEG_BIG)  # [bs, kvH, 1]
+
+            m_prev = m_ref[g][:, :1]  # [kvH, 1] (lane-broadcast storage)
+            l_prev = l_ref[g][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0))
+            m_new = jnp.maximum(m_new, _NEG_BIG / 2)
+            p = jnp.exp(sc - m_new[None])  # [bs, kvH, 1] fp32
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(p, axis=0)
+            pv = jnp.sum(p * v, axis=0)  # [kvH, hd]
+            acc_ref[g] = acc_ref[g] * alpha + pv
+            m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
     @pl.when(j == nj - 1)
     def _finish():
-        l_safe = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+        l_safe = jnp.maximum(l_ref[:, :, :1], 1e-30)
+        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
 
 
 def tensor_degree(mesh, axis: str = "tensor") -> int:
@@ -187,7 +196,7 @@ def paged_attention(
     t = tensor_degree(mesh, axis)
     kvH_full = kv_leaf_parts(k_pool)[0].shape[2]
     if t > 1 and kvH_full % t == 0:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         heads = P(None, axis, None)        # [S, Hq, hd] on the head axis
@@ -197,7 +206,7 @@ def paged_attention(
         return shard_map(
             local, mesh=mesh,
             in_specs=(heads, pool, pool, P(None, None), P(None)),
-            out_specs=heads, check_rep=False,
+            out_specs=heads, check_vma=False,
         )(q, k_pool, v_pool, tables, ctx_lens)
     return _paged_attention_local(
         q, k_pool, v_pool, tables, ctx_lens,
@@ -231,14 +240,18 @@ def _paged_attention_local(
     G = Hq // kvH
     cfg = _Cfg(block_size=bs, group=G, window=window,
                quantized=quantized, interpret=interpret)
-    qg = q.reshape(S, kvH, G, hd)
+    # heads are kv-major in q ([S, kvH*G, hd]); the kernel wants one
+    # [kvH, hd] tile per group member, so G leads (a tiny XLA transpose)
+    qg = q.reshape(S, kvH, G, hd).swapaxes(1, 2)
 
-    q_spec = pl.BlockSpec((1, 1, G, hd), lambda s, h, j, t, c: (s, h, 0, 0))
-    # the table read: grid step (s, h, j) DMAs pool block table[s, j]
+    q_spec = pl.BlockSpec((1, G, kvH, hd), lambda s, j, t, c: (s, 0, 0, 0))
+    # the table read: grid step (s, j) DMAs pool block table[s, j] — a
+    # whole page, every kv head (the last two block dims equal the
+    # array's, which is what the TPU lowering takes for any kvH and hd)
     kv_spec = pl.BlockSpec(
-        (1, bs, 1, hd), lambda s, h, j, t, c: (t[s, j], 0, h, 0))
+        (1, bs, kvH, hd), lambda s, j, t, c: (t[s, j], 0, 0, 0))
     scale_spec = pl.BlockSpec(
-        (1, bs, 1, 1), lambda s, h, j, t, c: (t[s, j], 0, h, 0))
+        (1, bs, kvH, 1), lambda s, j, t, c: (t[s, j], 0, 0, 0))
     if quantized:
         in_specs = [q_spec, kv_spec, scale_spec, kv_spec, scale_spec]
         operands = (qg, k_arr, k_scale, v_arr, v_scale)
@@ -248,24 +261,24 @@ def _paged_attention_local(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, kvH, MB),
+        grid=(S, MB),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, G, hd), lambda s, h, j, t, c: (s, h, 0, 0)),
+            (1, G, kvH, hd), lambda s, j, t, c: (s, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, hd), jnp.float32),
-            pltpu.VMEM((G, _LANES), jnp.float32),
-            pltpu.VMEM((G, _LANES), jnp.float32),
+            pltpu.VMEM((G, kvH, hd), jnp.float32),
+            pltpu.VMEM((G, kvH, _LANES), jnp.float32),
+            pltpu.VMEM((G, kvH, _LANES), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, cfg=cfg,
                           scale=1.0 / float(np.sqrt(hd))),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, kvH, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, G, kvH, hd), q.dtype),
         interpret=interpret,
     )(tables.astype(jnp.int32), ctx_lens.astype(jnp.int32), *operands)
-    return out.reshape(S, Hq, hd)
+    return out.swapaxes(1, 2).reshape(S, Hq, hd)
 
 
 def paged_attention_reference(

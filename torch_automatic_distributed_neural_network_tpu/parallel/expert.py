@@ -25,7 +25,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

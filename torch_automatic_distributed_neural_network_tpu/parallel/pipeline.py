@@ -74,7 +74,7 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from ..utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import topology as topo_mod

@@ -18,7 +18,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.jax_compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 
 from ..ops.attention import xla_attention
 

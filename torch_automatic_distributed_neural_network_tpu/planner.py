@@ -268,23 +268,8 @@ def _fsdp_spec(
 # Planner entry points
 # ---------------------------------------------------------------------------
 
-# Rough per-chip HBM capacities (bytes) by device kind substring.
-_HBM_BYTES = {
-    "v5 lite": 16 * 2**30,
-    "v5e": 16 * 2**30,
-    "v4": 32 * 2**30,
-    "v5p": 95 * 2**30,
-    "v6": 32 * 2**30,
-    "cpu": 8 * 2**30,
-}
-
-
 def _hbm_bytes(device_kind: str) -> int:
-    dk = device_kind.lower()
-    for k, v in _HBM_BYTES.items():
-        if k in dk:
-            return v
-    return 16 * 2**30
+    return topo_mod.chip_spec(device_kind).hbm_bytes
 
 
 # Parameter paths holding a scanned layer stack (leading [n_layers, ...]

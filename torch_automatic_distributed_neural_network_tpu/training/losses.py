@@ -146,8 +146,8 @@ def mse_loss(params, batch, rng, apply_fn):
 # ---------------------------------------------------------------------------
 #
 # The fp32 [B,S,V] logits tensor (plus its grad twin) dominates peak HBM
-# for large-vocab models: the Llama-8B/128k-vocab memfit showed 16.3 of
-# 17.2 GiB in logits-shaped temps (BENCH_NOTES.md r3).  This loss asks
+# for large-vocab models (the Llama-8B/128k-vocab `bench.py mode=memfit`
+# compile shows it; not measured on the current code).  This loss asks
 # the model for post-final-norm FEATURES (return_features=True), then
 # folds the LM head into the loss blockwise along the sequence under
 # jax.checkpoint: peak temp is [B, block, V] instead of [B, S, V], and

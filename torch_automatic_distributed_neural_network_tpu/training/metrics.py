@@ -17,24 +17,12 @@ from typing import Any, IO
 
 import jax
 
-# Peak dense matmul TFLOP/s per chip by device-kind substring (bf16).
-# Public spec-sheet numbers for each generation.
-PEAK_TFLOPS = {
-    "v5 lite": 197.0,
-    "v5e": 197.0,
-    "v5p": 459.0,
-    "v4": 275.0,
-    "v6": 918.0,
-    "cpu": 0.5,  # nominal, so CPU-sim MFU numbers are obviously synthetic
-}
-
-
 def peak_flops_per_chip(device_kind: str | None = None) -> float:
-    dk = (device_kind or jax.devices()[0].device_kind).lower()
-    for k, v in PEAK_TFLOPS.items():
-        if k in dk:
-            return v * 1e12
-    return 100e12
+    """Peak dense bf16 FLOP/s of one chip, from the one table
+    (``topology.chip_spec``); raises for a kind the table does not hold."""
+    from ..topology import chip_spec
+
+    return chip_spec(device_kind or jax.devices()[0].device_kind).flops_per_s
 
 
 def transformer_step_flops(n_params: int, tokens_per_batch: int) -> float:
@@ -76,7 +64,9 @@ class MetricsLogger:
         self.console = console and jax.process_index() == 0
         self.console_every = console_every
         self._t_last: float | None = None
-        self._peak = peak_flops_per_chip()
+        # looked up only where MFU is reported: an unknown chip is an
+        # error there, and no business of a logger that reports none
+        self._peak = peak_flops_per_chip() if flops_per_step else None
         self._n_chips = jax.device_count()
         self._dropped_warned: set[str] = set()
 

@@ -86,7 +86,7 @@ class TrainerConfig:
     # local flag every step regardless.
     preempt_check_every: int = 8
     # static plan/graph/mem/dtype lint (analysis.preflight) before
-    # step 0 — trace-only, no extra compile (BENCH_NOTES)
+    # step 0 — trace-only, no extra compile
     preflight: bool = True
     preflight_action: str = "warn"  # 'warn' | 'raise'
     # HBM budget for the memory lint ('16GiB' or bytes); None -> the
