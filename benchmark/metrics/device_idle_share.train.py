@@ -1,0 +1,2 @@
+"""``device_idle_share`` of the train cells."""
+from lib.readers import device_idle_share as read  # noqa: F401
