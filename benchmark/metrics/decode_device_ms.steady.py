@@ -1,0 +1,3 @@
+"""``decode_device_ms`` (device time of one decode step, the program
+``jit_serve_decode_step``) where it moves ``itl_p95_ms``."""
+from lib.serve_phases import decode_device_ms as read  # noqa: F401

@@ -132,3 +132,44 @@ def test_paged_decode_tp_compiles_for_v5e(v5e, quantized):
                                               interpret=False, mesh=mesh),
         *_paged_args(lambda spec: NamedSharding(mesh, spec), 16, 16,
                      quantized))
+
+
+# -- kernel names: what a device trace tells the kernels apart by ------------
+
+
+@pytest.fixture(scope="module")
+def kernel_texts(v5e):
+    """Compiled text of a flash forward + backward and of a paged decode,
+    for one described chip (the cache is off around module fixtures too)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(
+                q, k, v, causal=True, interpret=False).astype(jnp.float32))
+
+        one = SingleDeviceSharding(v5e[0])
+        return (
+            _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(v5e[0])),
+            _compile(
+                lambda q, k, v, t, c: paged_attention(q, k, v, t, c,
+                                                      interpret=False),
+                *_paged_args(lambda spec: one, 16, 16, False)))
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+@pytest.mark.parametrize("name,where", [
+    ("tadnn_flash_fwd", 0), ("tadnn_flash_bwd_dkv", 0),
+    ("tadnn_flash_bwd_dq", 0), ("tadnn_paged_decode", 1)])
+def test_kernel_is_named_in_the_compiled_text(kernel_texts, name, where):
+    """Each ``pallas_call`` carries a ``name``: it becomes part of the
+    Mosaic custom call's instruction name, which is what a profile of the
+    chip shows for the kernel (``%jvp_tadnn_flash_fwd_.1 = ...``)."""
+    calls = [l.split(" = ")[0] for l in kernel_texts[where].splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l]
+    assert any(name in c for c in calls), (name, calls)

@@ -68,7 +68,11 @@ event carrying its full span timeline — submit -> admit (queue wait)
 (disaggregated) -> first token (TTFT) -> per-token inter-token
 latencies -> preempt/recompute tax -> finish — and every step a
 ``serve.step`` event (slot occupancy, free blocks, tokens emitted,
-adapter residency) through ``obs.journal``.  ``tadnn report`` renders
+adapter residency, and ``phases``: the host seconds of each phase of
+the iteration, see ``PHASES``) through ``obs.journal``.  Each phase is
+also a ``serve.<phase>`` profiler annotation inside one ``serve.step``
+annotation, so a profiler capture shows the host loop on the device
+trace's clock; nothing is fenced for it.  ``tadnn report`` renders
 p50/p99 latency, TTFT/ITL percentiles, goodput, occupancy, and
 speculative accept rates from exactly these records, and ``tadnn
 monitor`` (obs/slo_monitor) folds the same stream into rolling SLO
@@ -83,7 +87,6 @@ import dataclasses
 import math
 import os
 import time
-from functools import partial
 from typing import Any
 
 import jax
@@ -118,6 +121,17 @@ from .kv_pool import (
 )
 from .prefix_cache import PrefixCache
 from .scheduler import Request, Scheduler
+
+# the phases of one ``ServeEngine.step`` in program order: keys of the
+# ``serve.step`` event's ``phases``, annotations ``serve.<phase>``.  Only
+# ``prefill_first_token`` and ``decode_wait`` wait for the device; the
+# others are host work and dispatches (``admit`` on a prefix-cache hit
+# includes dispatching ``pool.read_blocks``).  A single-shot prefill
+# (``prefill_chunk=None``) is one ``prefill_dispatch``, its first token's
+# ``prefill_first_token`` and a ``prefill_commit``, not part of ``admit``
+PHASES = ("evict", "admit", "prefill_dispatch", "prefill_first_token",
+          "prefill_commit", "grow", "decode_prepare", "decode_upload",
+          "decode_dispatch", "decode_wait", "emit")
 
 
 def _paged_decode_step(params, kv, tables, ctx_lens, tok, active,
@@ -458,7 +472,9 @@ class ServeEngine:
         # additionally snapped to prefill-chunk boundaries, so the
         # cache-off run's chunk partition of the recomputed suffix is
         # reproduced exactly (bit-identical tokens either way).
-        self.journal = journal or _journal.get_default()
+        self.journal = journal or _journal.get_default()  # never None
+        self._compiles = _journal.compile_counter()
+        self._phases: dict[str, float] = {}  # this step's, by PHASES name
         self._prefix_cache = None
         # publish lease: prompts enter the radix index with this TTL
         # (clock units), so stale preambles age out instead of pinning
@@ -516,21 +532,34 @@ class ServeEngine:
         self.tokens_emitted = 0
         self.finished: list[Request] = []
         self._prefill: dict[int, _PrefillState] = {}
-        self._step_fn = jax.jit(
-            partial(_paged_decode_step, cfg=self.cfg, sample=self.sample,
-                    moe_decode=moe_decode, attention_impl=attention_impl,
-                    lora_scaling=(lora_spec.scaling if lora_spec else 1.0),
-                    mesh=mesh, spec=self.pool.spec),
-            donate_argnums=(1,))
-        self._prefill_fn = jax.jit(
-            partial(_prefill_chunk_step, cfg=self.cfg,
-                    moe_decode=moe_decode, quantize=bool(quant_kv)))
-        self._prefill_lora_fn = None
-        if lora_spec is not None:
-            self._prefill_lora_fn = jax.jit(
-                partial(_prefill_chunk_lora_step, cfg=self.cfg,
-                        moe_decode=moe_decode, lora_spec=lora_spec,
-                        quantize=bool(quant_kv)))
+        # the engine's programs are jitted from named functions: a
+        # trace shows jit_serve_decode_step and jit_serve_prefill_chunk
+        # (a functools.partial has no name: jit__unknown).  They close
+        # over locals, not self: no cycle keeps a dropped engine alive
+        cfg, sample, pool_spec = self.cfg, self.sample, self.pool.spec
+        quantize = bool(quant_kv)
+
+        def serve_decode_step(*operands):
+            return _paged_decode_step(
+                *operands, cfg=cfg, sample=sample, moe_decode=moe_decode,
+                attention_impl=attention_impl,
+                lora_scaling=(lora_spec.scaling if lora_spec else 1.0),
+                mesh=mesh, spec=pool_spec)
+
+        def serve_prefill_chunk(*operands):
+            return _prefill_chunk_step(
+                *operands, cfg=cfg, moe_decode=moe_decode,
+                quantize=quantize)
+
+        def serve_prefill_chunk_lora(*operands):
+            return _prefill_chunk_lora_step(
+                *operands, cfg=cfg, moe_decode=moe_decode,
+                lora_spec=lora_spec, quantize=quantize)
+
+        self._step_fn = jax.jit(serve_decode_step, donate_argnums=(1,))
+        self._prefill_fn = jax.jit(serve_prefill_chunk)
+        self._prefill_lora_fn = (jax.jit(serve_prefill_chunk_lora)
+                                 if lora_spec is not None else None)
         # AOT executable cache (export/): replica spin-up goes
         # cache-first on the two fixed-shape serve traces, so a warm
         # replica deserializes the decode step and the prefill chunk
@@ -546,21 +575,20 @@ class ServeEngine:
                 quant_kv=bool(quant_kv), cache_dtype=cache_dtype,
                 n_adapters=n_adapters,
                 quant_adapters=bool(quant_adapters))
-        if self.journal is not None:
-            from ...ops.paged_attention import tensor_degree
+        from ...ops.paged_attention import tensor_degree
 
-            self.journal.event(
-                "serve.engine", attention_impl=attention_impl,
-                prefill_chunk=self.prefill_chunk,
-                n_slots=n_slots, max_len=max_len, block_size=block_size,
-                quant_kv=bool(quant_kv),
-                n_adapters=(n_adapters if lora_spec else 0),
-                adapter_rank=(lora_spec.rank if lora_spec else None),
-                quant_adapters=bool(quant_adapters and lora_spec),
-                speculative=self.speculative,
-                prefix_cache=self._prefix_cache is not None,
-                disaggregate=self.disaggregate,
-                tp=tensor_degree(mesh))
+        self.journal.event(
+            "serve.engine", attention_impl=attention_impl,
+            prefill_chunk=self.prefill_chunk,
+            n_slots=n_slots, max_len=max_len, block_size=block_size,
+            quant_kv=bool(quant_kv),
+            n_adapters=(n_adapters if lora_spec else 0),
+            adapter_rank=(lora_spec.rank if lora_spec else None),
+            quant_adapters=bool(quant_adapters and lora_spec),
+            speculative=self.speculative,
+            prefix_cache=self._prefix_cache is not None,
+            disaggregate=self.disaggregate,
+            tp=tensor_degree(mesh))
 
     def _export_compiled(self, cache, tags: dict, *, num_blocks: int,
                          block_size: int, quant_kv: bool, cache_dtype,
@@ -699,6 +727,12 @@ class ServeEngine:
 
     # -- one serving iteration ----------------------------------------------
 
+    def _phase(self, key: str, **ids) -> _journal.phase:
+        """One of ``PHASES``: its seconds land in this step's ``phases``
+        and its interval on the profiler's timeline as ``serve.<key>``."""
+        return _journal.phase(self._phases, key, "serve." + key,
+                              step=self._step_count + 1, **ids)
+
     def _bind_adapter(self, slot: int, req: Request) -> bool:
         """Pin the request's adapter at the transition into decode
         (pins back live decode reads ONLY — prefilling slots reference
@@ -711,11 +745,10 @@ class ServeEngine:
         if info is None:
             self._prefill.pop(req.rid, None)
             self.scheduler.requeue(slot)
-            if self.journal is not None:
-                self.journal.event("serve.adapter", kind="stall",
-                                   rid=req.rid, adapter=req.adapter)
+            self.journal.event("serve.adapter", kind="stall",
+                               rid=req.rid, adapter=req.adapter)
             return False
-        if info and self.journal is not None:
+        if info:
             self.journal.event(
                 "serve.adapter", kind="hit" if info["hit"] else "fault",
                 rid=req.rid, adapter=req.adapter, idx=info["idx"],
@@ -748,10 +781,9 @@ class ServeEngine:
         else:
             moved = self.pool.ship_prefill(blocks, k, v)
             self.scheduler.record_ship(slot, len(blocks))
-            if self.journal is not None:
-                self.journal.event(
-                    "serve.kv_ship", rid=req.rid, slot=slot,
-                    n_blocks=len(blocks), bytes=moved)
+            self.journal.event(
+                "serve.kv_ship", rid=req.rid, slot=slot,
+                n_blocks=len(blocks), bytes=moved)
         if self._prefix_cache is not None:
             # publish every FULL prompt block: decode writes start at
             # position n_prompt, so these rows are immutable (CoW
@@ -760,28 +792,31 @@ class ServeEngine:
             new = self._prefix_cache.insert(
                 req.prompt[:n_pub * self.pool.block_size],
                 req.blocks[:n_pub], ttl_s=self.prefix_ttl_s)
-            if new and self.journal is not None:
+            if new:
                 self.journal.event(
                     "serve.prefix", kind="publish", rid=req.rid,
                     n_blocks=new)
 
     def _prefill_into_slot(self, slot: int, req: Request) -> None:
-        tokens = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        cache = KVCache.init(self.cfg, 1, tokens.shape[1],
-                             dtype=jnp.bfloat16)
-        lora = self._req_lora(req)
-        params = (self.params if lora is None
-                  else merge_lora(self.params, lora, self.lora_spec))
-        # forward_cached retraces per distinct prompt length — the only
-        # shape-varying compile in the serving loop
-        logits, cache = forward_cached(
-            params, self.cfg, tokens, cache,
-            moe_decode=self.moe_decode, mesh=None)
-        req_rng = jax.random.fold_in(self._rng, req.rid)
-        _, first_rng = jax.random.split(req_rng)
-        first = int(jax.device_get(
-            _sample(logits, first_rng, self.sample))[0])
-        self._commit_prefill(slot, req, cache.k[:, 0], cache.v[:, 0])
+        with self._phase("prefill_dispatch", rid=req.rid, pos=0):
+            tokens = jnp.asarray(req.prompt, jnp.int32)[None, :]
+            cache = KVCache.init(self.cfg, 1, tokens.shape[1],
+                                 dtype=jnp.bfloat16)
+            lora = self._req_lora(req)
+            params = (self.params if lora is None
+                      else merge_lora(self.params, lora, self.lora_spec))
+            # forward_cached retraces per distinct prompt length — the
+            # only shape-varying compile in the serving loop
+            logits, cache = forward_cached(
+                params, self.cfg, tokens, cache,
+                moe_decode=self.moe_decode, mesh=None)
+        with self._phase("prefill_first_token", rid=req.rid):
+            req_rng = jax.random.fold_in(self._rng, req.rid)
+            _, first_rng = jax.random.split(req_rng)
+            first = int(jax.device_get(
+                _sample(logits, first_rng, self.sample))[0])
+        with self._phase("prefill_commit", rid=req.rid):
+            self._commit_prefill(slot, req, cache.k[:, 0], cache.v[:, 0])
         req.out_tokens = [first]
         req.t_first_token = self.scheduler.clock()
         req.token_walls = [req.t_first_token]
@@ -790,20 +825,28 @@ class ServeEngine:
     def _start_prefill(self, slot: int, req: Request) -> None:
         """Admission entry point: legacy single-shot prefill, or flip
         the slot to "prefilling" so step() streams the prompt through
-        the shared chunk trace, interleaved with decode.
-
-        A prefix-cache hit seeds the temp cache by reading the matched
-        blocks' KV back from the pool (``pool.read_blocks``) and starts
-        the cursor after them — the chunk trace then computes only the
-        uncached suffix, attending to the reused rows exactly as the
-        original prefill's later chunks attended to them."""
+        the shared chunk trace, interleaved with decode.  The host's
+        part is phase ``admit``; a single-shot prefill's forward, first
+        token and commit are the ``prefill_*`` phases, outside it."""
         if self.prefill_chunk is None:
             # single-shot requests go straight to running, so the pin
             # happens here (before the prefill work, cheaply bounced)
-            if not self._bind_adapter(slot, req):
-                return
-            self._prefill_into_slot(slot, req)
+            with self._phase("admit", rid=req.rid):
+                bound = self._bind_adapter(slot, req)
+            if bound:
+                self._prefill_into_slot(slot, req)  # the prefill_* phases
             return
+        with self._phase("admit", rid=req.rid):
+            self._seed_prefill(req)
+
+    def _seed_prefill(self, req: Request) -> None:
+        """The chunked path's temp cache and cursor.  A prefix-cache
+        hit seeds the temp cache by reading the matched blocks' KV back
+        from the pool (``pool.read_blocks``, dispatched and not waited
+        for) and starts the cursor after them — the chunk trace then
+        computes only the uncached suffix, attending to the reused rows
+        exactly as the original prefill's later chunks attended to
+        them."""
         req.state = "prefilling"
         cache = KVCache.init(self.cfg, 1, self.max_len,
                              dtype=jnp.bfloat16)
@@ -823,12 +866,11 @@ class ServeEngine:
                     k=kd[:, None, :self.max_len],
                     v=vd[:, None, :self.max_len],
                     length=jnp.asarray(req.cached_tokens, jnp.int32))
-            if self.journal is not None:
-                self.journal.event(
-                    "serve.prefix", kind="match", rid=req.rid,
-                    hit=bool(req.cached_tokens),
-                    cached_tokens=req.cached_tokens,
-                    cached_blocks=req.cached_blocks)
+            self.journal.event(
+                "serve.prefix", kind="match", rid=req.rid,
+                hit=bool(req.cached_tokens),
+                cached_tokens=req.cached_tokens,
+                cached_blocks=req.cached_blocks)
         self._prefill[req.rid] = _PrefillState(
             cache=cache, pos=req.cached_tokens,
             lora=self._req_lora(req))
@@ -841,58 +883,64 @@ class ServeEngine:
         blocks, and hand the slot to decode."""
         st = self._prefill[req.rid]
         C = self.prefill_chunk
-        chunk = req.prompt[st.pos:st.pos + C]
-        n_real = len(chunk)
-        tokens = jnp.asarray(chunk + [0] * (C - n_real), jnp.int32)[None]
-        t0 = time.monotonic()
-        # np.int32, not a weak-typed python int: the AOT-exported trace
-        # pins the cursor's dtype, and jit would silently retrace
-        last_idx = np.int32(n_real - 1)
-        fn, args = self._prefill_fn, (self.params, tokens, st.cache,
-                                      last_idx)
-        if st.lora is not None:
-            fn, args = self._prefill_lora_fn, (
-                self.params, st.lora, tokens, st.cache, last_idx)
-        if self.pool.quantize:
-            logits, st.cache, qchunk = fn(*args)
-            st.qchunks.append(qchunk)
-        else:
-            logits, st.cache = fn(*args)
+        with self._phase("prefill_dispatch", rid=req.rid, pos=st.pos):
+            chunk = req.prompt[st.pos:st.pos + C]
+            n_real = len(chunk)
+            tokens = jnp.asarray(
+                chunk + [0] * (C - n_real), jnp.int32)[None]
+            t0 = time.monotonic()
+            # np.int32, not a weak-typed python int: the AOT-exported
+            # trace pins the cursor's dtype, and jit would silently retrace
+            last_idx = np.int32(n_real - 1)
+            fn, args = self._prefill_fn, (self.params, tokens, st.cache,
+                                          last_idx)
+            if st.lora is not None:
+                fn, args = self._prefill_lora_fn, (
+                    self.params, st.lora, tokens, st.cache, last_idx)
+            if self.pool.quantize:
+                logits, st.cache, qchunk = fn(*args)
+                st.qchunks.append(qchunk)
+            else:
+                logits, st.cache = fn(*args)
         st.pos += n_real
         done = st.pos >= req.n_prompt
         bounced = done and not self._bind_adapter(slot, req)
         if done and not bounced:
-            req_rng = jax.random.fold_in(self._rng, req.rid)
-            _, first_rng = jax.random.split(req_rng)
-            first = int(jax.device_get(
-                _sample(logits, first_rng, self.sample))[0])
-            n_suffix = req.n_prompt - req.cached_tokens
-            if self.pool.quantize:
-                # commit the trace's own (q, scale) chunks verbatim —
-                # re-quantizing the round-tripped rows would not be
-                # idempotent through a bf16 temp cache
-                k_rows, v_rows = _cat_qchunks(st.qchunks, n_suffix)
-            else:
-                k_rows = st.cache.k[:, 0,
-                                    req.cached_tokens:req.n_prompt]
-                v_rows = st.cache.v[:, 0,
-                                    req.cached_tokens:req.n_prompt]
-            self._commit_prefill(slot, req, k_rows, v_rows)
+            # the only wait of a prefill: chunks before the last are
+            # dispatched and never fenced
+            with self._phase("prefill_first_token", rid=req.rid):
+                req_rng = jax.random.fold_in(self._rng, req.rid)
+                _, first_rng = jax.random.split(req_rng)
+                first = int(jax.device_get(
+                    _sample(logits, first_rng, self.sample))[0])
+            with self._phase("prefill_commit", rid=req.rid):
+                n_suffix = req.n_prompt - req.cached_tokens
+                if self.pool.quantize:
+                    # commit the trace's own (q, scale) chunks verbatim —
+                    # re-quantizing the round-tripped rows would not be
+                    # idempotent through a bf16 temp cache
+                    k_rows, v_rows = _cat_qchunks(st.qchunks, n_suffix)
+                else:
+                    k_rows = st.cache.k[:, 0,
+                                        req.cached_tokens:req.n_prompt]
+                    v_rows = st.cache.v[:, 0,
+                                        req.cached_tokens:req.n_prompt]
+                self._commit_prefill(slot, req, k_rows, v_rows)
             req.out_tokens = [first]
             req.t_first_token = self.scheduler.clock()
             req.token_walls = [req.t_first_token]
             self.tokens_emitted += 1
             req.state = "running"
             del self._prefill[req.rid]
+        # host seconds: a dispatch, plus the wait on the last chunk only
         chunk_s = time.monotonic() - t0
         req.prefill_chunks += 1
         req.prefill_compute_s += chunk_s
-        if self.journal is not None:
-            self.journal.event(
-                "serve.prefill_chunk", rid=req.rid, slot=slot,
-                pos=min(st.pos, req.n_prompt), n_tokens=n_real,
-                seconds=chunk_s,
-                done=bool(done and not bounced))
+        self.journal.event(
+            "serve.prefill_chunk", rid=req.rid, slot=slot,
+            pos=min(st.pos, req.n_prompt), n_tokens=n_real,
+            seconds=chunk_s,
+            done=bool(done and not bounced))
 
     def _cow_fork_writes(self) -> None:
         """Copy-on-write guard, run right before the decode step: any
@@ -928,45 +976,57 @@ class ServeEngine:
                 req.blocks[bi] = nb
                 alloc.release([b])
                 self.cow_forks += 1
-                if self.journal is not None:
-                    self.journal.event(
-                        "serve.prefix", kind="cow", rid=req.rid,
-                        block=b, fork=nb)
+                self.journal.event(
+                    "serve.prefix", kind="cow", rid=req.rid,
+                    block=b, fork=nb)
 
     def _decode_all(self) -> None:
         S, MB = self.n_slots, self.max_blocks
         k_spec = self.speculative
         T = 1 + k_spec
-        tables = np.zeros((S, MB), np.int32)
-        ctx = np.zeros((S,), np.int32)
-        tok = np.zeros((S, T), np.int32)
-        ids = np.zeros((S,), np.int32)
-        act = np.zeros((S,), bool)
-        for s, req in enumerate(self.scheduler.slots):
-            if req is None or req.state != "running":
-                # prefilling slots keep an all-null table here: the
-                # step's unconditional KV write lands in the scratch
-                # block instead of their half-filled prompt blocks
-                continue
-            tables[s, :len(req.blocks)] = req.blocks
-            # this step writes token n_generated at absolute position
-            # n_prompt + n_generated - 1 (the first generated token
-            # came from prefill and was never written)
-            ctx[s] = req.n_prompt + req.n_generated - 1
-            tok[s, 0] = req.out_tokens[-1]
-            if k_spec:
-                tok[s, 1:] = ngram_propose(
-                    req.prompt + req.out_tokens, k_spec)
-            ids[s] = req.adapter_idx
-            act[s] = True
-        step_rng = jax.random.fold_in(self._rng, 2**20 + self._step_count)
-        factors = (self.adapter_pool.factors
-                   if self.adapter_pool is not None else {})
-        self.pool.kv, out = self._step_fn(
-            self.params, self.pool.kv, jnp.asarray(tables),
-            jnp.asarray(ctx), jnp.asarray(tok), jnp.asarray(act),
-            factors, jnp.asarray(ids), step_rng)
-        out = np.asarray(jax.device_get(out))
+        with self._phase("decode_prepare"):
+            tables = np.zeros((S, MB), np.int32)
+            ctx = np.zeros((S,), np.int32)
+            tok = np.zeros((S, T), np.int32)
+            ids = np.zeros((S,), np.int32)
+            act = np.zeros((S,), bool)
+            for s, req in enumerate(self.scheduler.slots):
+                if req is None or req.state != "running":
+                    # prefilling slots keep an all-null table here: the
+                    # step's unconditional KV write lands in the scratch
+                    # block instead of their half-filled prompt blocks
+                    continue
+                tables[s, :len(req.blocks)] = req.blocks
+                # this step writes token n_generated at absolute position
+                # n_prompt + n_generated - 1 (the first generated token
+                # came from prefill and was never written)
+                ctx[s] = req.n_prompt + req.n_generated - 1
+                tok[s, 0] = req.out_tokens[-1]
+                if k_spec:
+                    tok[s, 1:] = ngram_propose(
+                        req.prompt + req.out_tokens, k_spec)
+                ids[s] = req.adapter_idx
+                act[s] = True
+        with self._phase("decode_upload"):
+            step_rng = jax.random.fold_in(
+                self._rng, 2**20 + self._step_count)
+            factors = (self.adapter_pool.factors
+                       if self.adapter_pool is not None else {})
+            operands = (jnp.asarray(tables), jnp.asarray(ctx),
+                        jnp.asarray(tok), jnp.asarray(act), factors,
+                        jnp.asarray(ids), step_rng)
+        with self._phase("decode_dispatch"):
+            self.pool.kv, out = self._step_fn(
+                self.params, self.pool.kv, *operands)
+        with self._phase("decode_wait"):
+            out = np.asarray(jax.device_get(out))
+        with self._phase("emit"):
+            self._emit(out, tok)
+
+    def _emit(self, out: np.ndarray, tok: np.ndarray) -> None:
+        """Append the step's tokens ``out`` to their requests; with
+        speculation, ``tok[:, 1:]`` are the drafts it verified."""
+        k_spec = self.speculative
         # one stamp per step: every token this step emits shares it (a
         # speculative burst lands together, so its interior ITLs are 0)
         now = self.scheduler.clock()
@@ -1000,11 +1060,10 @@ class ServeEngine:
             self.tokens_emitted += len(emit)
         self.spec_drafted += drafted
         self.spec_accepted += accepted
-        if self.journal is not None:
-            self.journal.event(
-                "serve.speculate", step=self._step_count + 1, k=k_spec,
-                n_active=n_active, drafted=drafted, accepted=accepted,
-                accept_rate=(accepted / drafted if drafted else None))
+        self.journal.event(
+            "serve.speculate", step=self._step_count + 1, k=k_spec,
+            n_active=n_active, drafted=drafted, accepted=accepted,
+            accept_rate=(accepted / drafted if drafted else None))
 
     def _finish(self, slot: int) -> None:
         # evict() zeroes the prefix-cache accounting with the block
@@ -1012,8 +1071,6 @@ class ServeEngine:
         cached_tokens = self.scheduler.slots[slot].cached_tokens
         req = self.scheduler.evict(slot)
         self.finished.append(req)
-        if self.journal is None:
-            return
         # phase attribution: queue_s runs submit -> LAST admission (so
         # it absorbs time spent queued again after a preemption; lost_s
         # separates out the thrown-away attempts), prefill_s runs
@@ -1057,35 +1114,47 @@ class ServeEngine:
         concurrently, only the KV-block shipment couples them."""
         sched = self.scheduler
         tokens_before = self.tokens_emitted
-        for s in range(self.n_slots):
-            req = sched.slots[s]
-            if (req is not None and req.state == "running"
-                    and req.finished()):
-                self._finish(s)
-        for slot, req in sched.admit():
-            self._start_prefill(slot, req)
-            if req.state == "running" and req.finished():
-                self._finish(slot)  # single-shot, max_new_tokens == 1
-        prefill_s = 0.0
-        budget = None if self.disaggregate else self.prefill_chunks_per_step
-        for slot, req in sched.prefill_plan(budget):
-            t0 = time.monotonic()
-            self._advance_prefill(slot, req)
-            prefill_s += time.monotonic() - t0
-            if req.state == "running" and req.finished():
-                self._finish(slot)  # chunked, max_new_tokens == 1
-        for victim in sched.grow_for_step():
-            self._prefill.pop(victim.rid, None)
-            if self.journal is not None:
-                self.journal.event("serve.preempt", rid=victim.rid,
-                                   n_regenerate=victim.n_prompt)
-        decode_s = 0.0
-        if sched.n_decoding:
-            if self._prefix_cache is not None:
-                self._cow_fork_writes()
-            t0 = time.monotonic()
-            self._decode_all()
-            decode_s = time.monotonic() - t0
+        compiles, compile_s = self._compiles.n, self._compiles.seconds
+        self._phases = phases = {}
+        whole: dict[str, float] = {}
+        n_chunks = 0
+        with _journal.phase(whole, "step_s", "serve.step",
+                            step=self._step_count + 1):
+            with self._phase("evict"):
+                for s in range(self.n_slots):
+                    req = sched.slots[s]
+                    if (req is not None and req.state == "running"
+                            and req.finished()):
+                        self._finish(s)
+            with self._phase("admit"):
+                admitted = sched.admit()
+            for slot, req in admitted:
+                self._start_prefill(slot, req)  # more admit, by request
+                if req.state == "running" and req.finished():
+                    self._finish(slot)  # single-shot, one new token
+            prefill_s = 0.0
+            budget = (None if self.disaggregate
+                      else self.prefill_chunks_per_step)
+            for slot, req in sched.prefill_plan(budget):
+                n_chunks += 1
+                t0 = time.monotonic()
+                self._advance_prefill(slot, req)
+                prefill_s += time.monotonic() - t0
+                if req.state == "running" and req.finished():
+                    self._finish(slot)  # chunked, max_new_tokens == 1
+            with self._phase("grow"):
+                for victim in sched.grow_for_step():
+                    self._prefill.pop(victim.rid, None)
+                    self.journal.event("serve.preempt", rid=victim.rid,
+                                       n_regenerate=victim.n_prompt)
+                if sched.n_decoding and self._prefix_cache is not None:
+                    self._cow_fork_writes()
+            decode_s = 0.0
+            if sched.n_decoding:
+                t0 = time.monotonic()
+                self._decode_all()
+                decode_s = time.monotonic() - t0
+        t_end = sched.clock()
         self._step_count += 1
         self._occupancy_sum += sched.n_active / self.n_slots
         self.prefill_busy_s += prefill_s
@@ -1095,29 +1164,39 @@ class ServeEngine:
         overlap_s = (max(prefill_s, decode_s) if self.disaggregate
                      else prefill_s + decode_s)
         self.overlapped_wall_s += overlap_s
-        if self.journal is not None:
-            adapter_stats = {}
-            if self.adapter_pool is not None:
-                alloc = self.adapter_pool.allocator
-                adapter_stats = dict(
-                    adapters_resident=alloc.n_resident,
-                    adapters_pinned=alloc.n_pinned)
-            if self._prefix_cache is not None:
-                adapter_stats.update(
-                    prefix_blocks=self._prefix_cache.n_blocks,
-                    prefix_hit_tokens=self._prefix_cache.hit_tokens)
+        compiles = self._compiles.n - compiles
+        if compiles:
+            # an unseen shape (a new prompt length at a prefill commit)
+            # stalled every stream for this long
             self.journal.event(
-                "serve.step", step=self._step_count,
-                n_active=sched.n_active, n_queued=sched.n_queued,
-                n_prefilling=sched.n_prefilling,
-                new_tokens=self.tokens_emitted - tokens_before,
-                occupancy=sched.n_active / self.n_slots,
-                free_blocks=self.pool.allocator.n_free,
-                prefill_s=prefill_s, decode_s=decode_s,
-                mode=("disaggregated" if self.disaggregate
-                      else "colocated"),
-                overlap_s=overlap_s,
-                **adapter_stats)
+                "compile", fn="serve",
+                dur_s=self._compiles.seconds - compile_s)
+        adapter_stats = {}
+        if self.adapter_pool is not None:
+            alloc = self.adapter_pool.allocator
+            adapter_stats = dict(
+                adapters_resident=alloc.n_resident,
+                adapters_pinned=alloc.n_pinned)
+        if self._prefix_cache is not None:
+            adapter_stats.update(
+                prefix_blocks=self._prefix_cache.n_blocks,
+                prefix_hit_tokens=self._prefix_cache.hit_tokens)
+        # prefill_s is dispatch time (only a prompt's last chunk waits
+        # for the device); decode_s ends in the step's device_get
+        self.journal.event(
+            "serve.step", step=self._step_count,
+            n_active=sched.n_active, n_queued=sched.n_queued,
+            n_prefilling=sched.n_prefilling,
+            new_tokens=self.tokens_emitted - tokens_before,
+            occupancy=sched.n_active / self.n_slots,
+            free_blocks=self.pool.allocator.n_free,
+            prefill_s=prefill_s, decode_s=decode_s,
+            mode=("disaggregated" if self.disaggregate
+                  else "colocated"),
+            overlap_s=overlap_s,
+            phases=phases, step_s=whole["step_s"], t_end=t_end,
+            n_prefill_chunks=n_chunks, compiles=compiles,
+            **adapter_stats)
         if self._debug_invariants:
             sched.check_invariants()
 

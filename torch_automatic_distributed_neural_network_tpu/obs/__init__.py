@@ -12,8 +12,10 @@ from .goodput import BUCKETS, GoodputMeter
 from .journal import (
     Journal,
     as_default,
+    compile_counter,
     event,
     get_default,
+    phase,
     set_default,
     span,
 )
@@ -30,9 +32,11 @@ __all__ = [
     "SLOMonitor",
     "aggregate",
     "as_default",
+    "compile_counter",
     "schema",
     "event",
     "get_default",
+    "phase",
     "set_default",
     "span",
     "live",

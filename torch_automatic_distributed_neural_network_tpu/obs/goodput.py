@@ -16,16 +16,21 @@ Buckets (the TorchTitan-style breakdown, PAPERS.md):
 
 ``summary()`` fractions are of total wall-clock and sum to ~1.0 by
 construction; ``goodput`` is step / total.
+
+A measured bucket is a ``journal.phase``: under a profiler capture it
+shows on the timeline as ``train.<bucket>``, but for the two ``TIMELINE``
+renames (``measure("step")`` times the fence on a dispatched step).
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
-from typing import Iterator
+
+from .journal import phase
 
 BUCKETS = ("compile", "step", "checkpoint", "eval", "trace",
            "input_stall", "idle")
+TIMELINE = {"step": "train.fence", "input_stall": "train.input"}
 
 
 class GoodputMeter:
@@ -35,20 +40,19 @@ class GoodputMeter:
         self._t_start = time.monotonic()
         self.seconds: dict[str, float] = {b: 0.0 for b in BUCKETS}
 
-    def add(self, bucket: str, seconds: float) -> None:
+    def _known(self, bucket: str) -> str:
         if bucket not in self.seconds:
             raise ValueError(
                 f"unknown goodput bucket {bucket!r}; expected one of {BUCKETS}"
             )
-        self.seconds[bucket] += max(0.0, seconds)
+        return bucket
 
-    @contextlib.contextmanager
-    def measure(self, bucket: str) -> Iterator[None]:
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            self.add(bucket, time.monotonic() - t0)
+    def add(self, bucket: str, seconds: float) -> None:
+        self.seconds[self._known(bucket)] += max(0.0, seconds)
+
+    def measure(self, bucket: str) -> phase:
+        return phase(self.seconds, self._known(bucket),
+                     TIMELINE.get(bucket, "train." + bucket))
 
     def total_wall_s(self) -> float:
         return time.monotonic() - self._t_start

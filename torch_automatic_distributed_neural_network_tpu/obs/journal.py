@@ -17,9 +17,20 @@ host-0 gating).  Usable three ways::
     obs.set_default(j)                           # process-global sink:
     obs.event("watchdog.stall", age_s=12.0)      # library code logs here
 
+Work too fine for a record of its own is timed as a ``phase``: its
+seconds go into a dict the caller owns and ride on the parent record
+(``serve.step``'s ``phases``, the ``goodput`` buckets), and the same
+interval is a ``jax.profiler.TraceAnnotation``, so under any profiler
+capture it sits on the device trace's clock::
+
+    with phase(seconds, "decode_wait", "serve.decode_wait", step=n):
+        out = jax.device_get(out)
+
 With no default installed, module-level ``span``/``event`` are cheap
-no-ops (a null journal), so instrumented library code costs nothing in
-un-observed runs.  ``TADNN_JOURNAL=<path>`` in the environment installs
+no-ops (a null journal).  A ``phase`` runs observed or not: its two
+clock reads and its annotation object cost ``ServeEngine.step`` 50-70
+us (0.2% of a 30 ms step on a v5e, PERF.md) with no journal and no
+capture.  ``TADNN_JOURNAL=<path>`` in the environment installs
 a default sink automatically on first use.
 """
 
@@ -31,6 +42,70 @@ import os
 import time
 import warnings
 from typing import Any, IO, Iterator
+
+
+_TraceAnnotation = None  # jax.profiler.TraceAnnotation, on first phase
+
+
+class phase:
+    """Timed phase: adds its ``time.monotonic`` duration to
+    ``seconds[key]`` and is a profiler annotation called ``name``
+    carrying ``ids``, over the same interval.
+    Writes no journal record.  Outside a profiler capture the
+    annotation is one flag test; no fence, nothing on the device."""
+
+    __slots__ = ("_seconds", "_key", "_annotation", "_t0")
+
+    def __init__(self, seconds: dict, key: str, name: str, **ids: Any):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        self._seconds, self._key = seconds, key
+        self._annotation = _TraceAnnotation(name, **ids)
+
+    def __enter__(self) -> None:
+        self._annotation.__enter__()
+        self._t0 = time.monotonic()
+
+    def __exit__(self, *exc) -> None:
+        dur = time.monotonic() - self._t0
+        self._seconds[self._key] = self._seconds.get(self._key, 0.0) + dur
+        self._annotation.__exit__(*exc)
+
+
+class CompileCounter:
+    """XLA backend compiles of this process and their seconds, counted by
+    one ``jax.monitoring`` listener (it fires only when XLA compiles or
+    loads a program: nothing on a hot path).  ``compile_counter()``.
+    The count is the process's: a reader that diffs it over an interval
+    (``serve.step``'s ``compiles``) also sees what another engine or
+    thread compiled in that interval."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.n = 0
+        self.seconds = 0.0
+
+    def _on(self, event: str, dur_s: float, **_kw) -> None:
+        if event == self.EVENT:
+            self.n += 1
+            self.seconds += dur_s
+
+
+_compile_counter: CompileCounter | None = None
+
+
+def compile_counter() -> CompileCounter:
+    """The process's one compile counter, registered on first call."""
+    global _compile_counter
+    if _compile_counter is None:
+        import jax
+
+        _compile_counter = CompileCounter()
+        jax.monitoring.register_event_duration_secs_listener(
+            _compile_counter._on)
+    return _compile_counter
 
 
 def _process_index() -> int:

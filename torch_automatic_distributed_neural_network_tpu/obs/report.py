@@ -369,6 +369,36 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
             "overlapped_wall_s": (sum(_finite(
                 e.get("overlap_s") for e in ssteps)) or None),
         }
+        # where the host's time in a step goes (engine.PHASES): mean
+        # seconds of each phase over the steps in which it ran, and the
+        # step's self time (step_s less its phases)
+        phased = [e for e in ssteps if e.get("phases")]
+        if phased:
+            keys = list(dict.fromkeys(
+                k for e in phased for k in e.get("phases")))
+            serving["step_phase_mean_s"] = {
+                k: _mean(e.get("phases").get(k) for e in phased)
+                for k in keys}
+            serving["mean_step_s"] = _mean(e.get("step_s") for e in phased)
+            serving["mean_step_self_s"] = _mean(
+                e.get("step_s") - sum(e.get("phases").values())
+                for e in phased if e.get("step_s") is not None)
+            serving["steps_that_compiled"] = sum(
+                1 for e in ssteps if e.get("compiles"))
+            # the two kinds of decoding step behind the ITL's two
+            # modes: a step that also ran prefill chunks, and one that
+            # only decoded
+            decoding = [e for e in phased if e.get("decode_s")
+                        and e.get("step_s") is not None]
+            chunked = [e for e in decoding if e.get("n_prefill_chunks")]
+            if chunked:
+                serving["decode_steps_with_chunk"] = len(chunked)
+                serving["decode_steps"] = len(decoding)
+                serving["mean_step_with_chunk_s"] = _mean(
+                    e.get("step_s") for e in chunked)
+                serving["mean_step_decode_only_s"] = _mean(
+                    e.get("step_s") for e in decoding
+                    if not e.get("n_prefill_chunks"))
         # request span timelines (r06 serve.request_done fields): TTFT
         # and inter-token latency percentiles plus the mean phase mix —
         # where a request's wall time went, attributed per phase
@@ -930,6 +960,28 @@ def format_report(report: dict) -> str:
                    if sv.get("prefill_chunk") else ""))
         if bparts:
             lines.append("  " + "  ".join(bparts))
+        if sv.get("step_phase_mean_s"):
+            lines.append(
+                "  step phases (mean ms, host): " + " ".join(
+                    f"{k} {v * 1e3:.2f}"
+                    for k, v in sv["step_phase_mean_s"].items()
+                    if v is not None)
+                + (f" self {sv['mean_step_self_s'] * 1e3:.2f}"
+                   if sv.get("mean_step_self_s") is not None else "")
+                + (f" of step {sv['mean_step_s'] * 1e3:.2f}"
+                   if sv.get("mean_step_s") is not None else "")
+                + (f"; XLA built programs in this process during "
+                   f"{sv['steps_that_compiled']} step(s)"
+                   if sv.get("steps_that_compiled") else ""))
+        if sv.get("decode_steps_with_chunk"):
+            only = sv.get("mean_step_decode_only_s")
+            lines.append(
+                f"  {sv['decode_steps_with_chunk']} of "
+                f"{sv['decode_steps']} decoding step(s) also ran a "
+                f"prefill chunk: {sv['mean_step_with_chunk_s'] * 1e3:.2f}"
+                " ms a step"
+                + (f" against {only * 1e3:.2f} ms decode-only"
+                   if only is not None else ""))
         if sv.get("mode") == "disaggregated" or (sv.get("tp") or 1) > 1:
             dparts = [f"mode {sv.get('mode') or 'colocated'}"]
             if (sv.get("tp") or 1) > 1:

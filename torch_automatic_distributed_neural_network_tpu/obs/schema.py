@@ -130,7 +130,8 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             "predicted_reduce_scatter_bytes": "number",
             "compiled_bytes": "number?"}),
     _s("compile", "first XLA compile of a jitted fn (event from the "
-       "jit cache; span from AOT paths)",
+       "jit cache; span from AOT paths); fn=serve: every program XLA "
+       "built or loaded in the process during one engine step",
        req={"fn": "str"},
        opt={"dur_s": "float", "signature": "str"}, kind="both"),
     _s("recompile", "signature change re-traced an already-compiled fn",
@@ -341,7 +342,17 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             "decode_s": "float", "mode": "str", "overlap_s": "float",
             "adapters_resident": "int", "adapters_pinned": "int",
             "prefix_blocks": "int", "prefix_hit_tokens": "int",
-            "replica": "str", "prefill_chunks": "int"}),
+            "replica": "str", "prefill_chunks": "int",
+            # host seconds of the iteration's phases (engine.PHASES;
+            # absent ones did not run), the whole iteration (self time
+            # is step_s less their sum), the scheduler clock at its end
+            # (the clock of Request.token_walls), and counts: the
+            # step's prefill chunks, and the programs XLA built or
+            # loaded in the PROCESS during the step (one counter a
+            # process, not an engine: another engine's or a thread's
+            # compile in that interval is counted here too)
+            "phases": "dict", "step_s": "float", "t_end": "float",
+            "n_prefill_chunks": "int", "compiles": "int"}),
     _s("serve.request_done", "per-request completion span with the "
        "full phase-attributed timeline", version=2,
        req={"rid": "int", "n_prompt": "int", "n_new": "int",
@@ -355,7 +366,8 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             "replica": "str"}),
     _s("serve.preempt", "optimistic-growth preemption recycled a slot",
        req={"rid": "int", "n_regenerate": "int"}),
-    _s("serve.prefill_chunk", "one chunked-prefill advance",
+    _s("serve.prefill_chunk", "one chunked-prefill advance (seconds: host "
+       "time, a dispatch; only a prompt's last chunk waits for the device)",
        req={"rid": "int", "slot": "int", "pos": "int", "n_tokens": "int",
             "seconds": "float", "done": "bool"}),
     _s("serve.kv_ship", "disaggregated prefill shipped KV blocks into "

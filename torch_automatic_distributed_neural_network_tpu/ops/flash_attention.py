@@ -184,6 +184,7 @@ def _fwd(q, k, v, cfg: _Cfg):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=cfg.interpret,
+        name="tadnn_flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -333,6 +334,7 @@ def _bwd_impl(cfg: _Cfg, res, do, dlse):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=cfg.interpret,
+        name="tadnn_flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     dq = pl.pallas_call(
@@ -346,6 +348,7 @@ def _bwd_impl(cfg: _Cfg, res, do, dlse):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=cfg.interpret,
+        name="tadnn_flash_bwd_dq",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -277,6 +277,7 @@ def _paged_attention_local(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, G, kvH, hd), q.dtype),
         interpret=interpret,
+        name="tadnn_paged_decode",
     )(tables.astype(jnp.int32), ctx_lens.astype(jnp.int32), *operands)
     return out.swapaxes(1, 2).reshape(S, Hq, hd)
 

@@ -25,7 +25,6 @@ import dataclasses
 import math
 import os
 import sys
-import time
 from typing import Any, Callable, Iterable
 
 import jax
@@ -347,13 +346,16 @@ class Trainer:
                 # compile-dominated and would profile XLA, not the step
                 traced = bool(cfg.trace_every_n and i != start
                               and (i - start) % cfg.trace_every_n == 0)
-                t0 = time.perf_counter()
                 n_before = self.ad.n_compiles + self.ad.recompile_count
-                if traced:
-                    state, step_metrics = self._traced_step(state, batch, i)
-                else:
-                    state, step_metrics = self.ad.step(state, batch)
-                dur = time.perf_counter() - t0
+                took: dict[str, float] = {}
+                with obs_journal.phase(took, "dispatch",
+                                       "train.step_dispatch", step=i):
+                    if traced:
+                        state, step_metrics = self._traced_step(
+                            state, batch, i)
+                    else:
+                        state, step_metrics = self.ad.step(state, batch)
+                dur = took["dispatch"]
                 # a dispatch that tripped a (re)trace blocked on XLA, so
                 # its wall time is compile, not useful step time; a
                 # traced step is fenced+profiled, so overhead, not goodput

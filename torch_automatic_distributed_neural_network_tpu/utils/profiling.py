@@ -1,36 +1,14 @@
-"""Tracing / profiling hooks (SURVEY.md §5).
-
-TPU-native: ``jax.profiler`` TensorBoard traces (XLA ops + ICI comm lanes)
-and compiled-program cost analysis for MFU accounting — replaces the
-reference world's torch profiler/nvprof path.
+"""Compiled-program cost analysis (SURVEY.md §5): FLOPs, memory and
+bytes accessed of a jitted step from XLA, for MFU accounting and the
+planner's cross-checks.  Timeline annotations live in ``obs.journal``
+(``phase``); captures in ``obs.trace`` and ``tadnn profile``.
 """
 
 from __future__ import annotations
 
-import contextlib
 from typing import Any
 
 import jax
-
-
-@contextlib.contextmanager
-def trace(logdir: str):
-    """Capture a TensorBoard trace of everything inside the block::
-
-        with profiling.trace("/tmp/trace"):
-            for _ in range(10):
-                state, _ = ad.step(state, batch)
-    """
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region that shows up on the trace timeline."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 def _flops_of(compiled) -> float | None:
