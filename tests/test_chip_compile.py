@@ -173,3 +173,91 @@ def test_kernel_is_named_in_the_compiled_text(kernel_texts, name, where):
     calls = [l.split(" = ")[0] for l in kernel_texts[where].splitlines()
              if 'custom_call_target="tpu_custom_call"' in l]
     assert any(name in c for c in calls), (name, calls)
+
+
+# -- the two base serving programs, for the tree the engine holds -------------
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_serving_program_holds_no_convert_of_a_weight_stack(
+        v5e, monkeypatch, program):
+    """``jit_serve_decode_step`` and ``jit_serve_prefill_chunk`` at the
+    GPT-2 1.3B geometry of the benchmark's serving cells (8 slots, 1024
+    positions, blocks of 16, chunks of 128), compiled for one described
+    v5e with the operands ``ServeEngine`` hands them: the layers' weights
+    already in bf16 (``decode.compute_dtype_params``).  Handed float32
+    weights, this compiler moves their rounding out of the layer scan and
+    converts each whole ``[24, ...]`` stack in every call, beside a bf16
+    copy of all of them among the temporaries; here no layer's weight
+    is converted at all and the temporaries are the KV pool's copy
+    (decode) or nothing (chunk)."""
+    import re
+
+    from torch_automatic_distributed_neural_network_tpu.inference import (
+        decode,
+    )
+    from torch_automatic_distributed_neural_network_tpu.inference.serve import (
+        engine,
+    )
+    from torch_automatic_distributed_neural_network_tpu.models.transformer_core import (
+        DecoderLM,
+        TransformerConfig,
+    )
+    from torch_automatic_distributed_neural_network_tpu.ops import (
+        paged_attention as paged,
+    )
+
+    # the default backend is the CPU here: ask for the kernel, not its
+    # interpreter, as the chip would
+    monkeypatch.setattr(paged, "_default_interpret", lambda: False)
+    cfg = TransformerConfig(
+        vocab_size=50257, d_model=2048, n_layers=24, n_heads=16, d_ff=8192,
+        max_seq_len=2048, dtype=jnp.bfloat16, remat=False)
+    given = jax.eval_shape(DecoderLM(cfg).init, jax.random.key(0),
+                           np.zeros((1, 8), np.int32))["params"]
+    params = jax.eval_shape(
+        lambda p: decode.compute_dtype_params(p, cfg), given)
+    slots, max_len, block, chunk = 8, 1024, 16, 128
+    if program == "decode_step":
+        pool = jax.ShapeDtypeStruct(
+            (cfg.n_layers, slots * max_len // block + 1, block,
+             cfg.kv_heads, cfg.head_dim), jnp.bfloat16)
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+        operands = (
+            params, {"k": pool, "v": pool}, i32(slots, max_len // block),
+            i32(slots), i32(slots, 1),
+            jax.ShapeDtypeStruct((slots,), jnp.bool_), {}, i32(slots),
+            jax.eval_shape(lambda: jax.random.key(0)))
+
+        def step(params, *rest):
+            return engine._paged_decode_step(
+                params, *rest, cfg=cfg, moe_decode="dense",
+                sample=decode.SampleConfig(temperature=0.0))
+
+        fn = jax.jit(step, donate_argnums=(1,))
+    else:
+        operands = (
+            params, jax.ShapeDtypeStruct((1, chunk), jnp.int32),
+            jax.eval_shape(lambda: decode.KVCache.init(
+                cfg, 1, max_len, dtype=jnp.bfloat16)),
+            jax.ShapeDtypeStruct((), jnp.int32))
+
+        def step(params, *rest):
+            return engine._prefill_chunk_step(
+                params, *rest, cfg=cfg, moe_decode="dense")
+
+        fn = jax.jit(step)
+    one = SingleDeviceSharding(v5e[0])
+    compiled = fn.lower(*jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        operands)).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (program == "decode_step")
+    # a weight is an entry parameter named for its path in ``params``
+    assert re.search(r"%params__layers____mlp____up_proj____kernel__\S* = "
+                     r"bf16\[24,2048,8192\]\S* parameter\(", text)
+    to_bf16 = [l.strip()[:120] for l in text.splitlines()
+               if re.search(r"= bf16\[[^\]]*\]\S* convert\(%params__layers", l)]
+    assert to_bf16 == []
+    temp_gib = compiled.memory_analysis().temp_size_in_bytes / 2**30
+    assert temp_gib < (1.6 if program == "decode_step" else 0.1), temp_gib

@@ -4,6 +4,7 @@ sequential generate() on the CPU sim mesh (slow), serving telemetry
 rendering through tadnn report, the serve_estimate capacity lint, and
 the SERVE_BENCH freshness family of check_bench."""
 
+import dataclasses
 import json
 
 import jax
@@ -15,16 +16,28 @@ from torch_automatic_distributed_neural_network_tpu.analysis.serve_lint import (
     serve_estimate,
 )
 from torch_automatic_distributed_neural_network_tpu.inference import generate
+from torch_automatic_distributed_neural_network_tpu.inference.decode import (
+    KVCache,
+    compute_dtype_params,
+)
+from torch_automatic_distributed_neural_network_tpu.inference.quant import (
+    quantize_for_decode,
+)
 from torch_automatic_distributed_neural_network_tpu.inference.serve import (
     BlockAllocator,
     Request,
     Scheduler,
     ServeEngine,
     blocks_for_tokens,
+    random_adapter,
 )
-from torch_automatic_distributed_neural_network_tpu.models import GPT2
+from torch_automatic_distributed_neural_network_tpu.models import GPT2, MoE
 from torch_automatic_distributed_neural_network_tpu.obs import (
     report as obs_report,
+)
+from torch_automatic_distributed_neural_network_tpu.planner import path_str
+from torch_automatic_distributed_neural_network_tpu.training.lora import (
+    LoraSpec,
 )
 
 VOCAB = 128
@@ -297,7 +310,9 @@ def test_report_renders_serving_breakdown(tmp_path):
     recs = [{"kind": "event", "name": "serve.engine", "t": 0.0,
              "attention_impl": "paged", "prefill_chunk": 32,
              "n_slots": 4, "max_len": 64, "block_size": 8,
-             "quant_kv": False}]
+             "quant_kv": False, "weights_cast": 12,
+             "weight_bytes_compute": 3 * 2**29,
+             "weight_bytes_fp32": 2**28}]
     recs += [{"kind": "event", "name": "serve.step", "t": 0.1 * i,
               "step": i, "n_active": 2, "n_queued": 0,
               "n_prefilling": 1, "occupancy": 0.5, "free_blocks": 3,
@@ -322,6 +337,8 @@ def test_report_renders_serving_breakdown(tmp_path):
     text = obs_report.format_report(obs_report.generate(str(jp)))
     assert "decode impl paged" in text
     assert "prefill chunk" in text
+    assert ("weights 1.50 GiB in the compute dtype + 0.25 GiB float32 "
+            "(12 leaves rounded once)") in text
 
 
 @pytest.mark.slow
@@ -726,3 +743,267 @@ def test_debug_invariants_env_gate(monkeypatch):
             eng.submit([1, 2, 3], max_new_tokens=4, eos_id=0)
             done = eng.run()
             assert len(done) == 1
+
+
+# -- the tree the base programs take: weights rounded once --------------------
+#
+# Every weight below is float32 and NOT representable in bfloat16, so a
+# rounding made twice, not at all, or after a float32 sum would show.  The
+# comparisons are bit for bit on the CPU backend as it is: it was checked
+# (PR 26) that this backend keeps the float32 -> bf16 -> float32 pair around
+# a weight, so nothing is compiled with xla_allow_excess_precision=false.
+
+
+def _bf16_model_and_vars(moe=False):
+    kw = dict(vocab_size=VOCAB, max_seq_len=64, dtype=jnp.bfloat16,
+              remat=False)
+    model = (MoE if moe else GPT2)("test", **kw)
+    variables = model.init(jax.random.key(1), jnp.ones((1, 12), jnp.int32))
+    leaves, treedef = jax.tree.flatten(variables["params"])
+    keys = jax.random.split(jax.random.key(7), len(leaves))
+    rough = [x + 0.013 * jax.random.normal(k, x.shape, x.dtype)
+             for x, k in zip(leaves, keys)]
+    assert all(x.dtype == jnp.float32 for x in rough)
+    assert all(bool((x.astype(jnp.bfloat16).astype(jnp.float32) != x).any())
+               for x in rough)
+    return model, {"params": jax.tree.unflatten(treedef, rough)}
+
+
+def _paths(tree):
+    return {path_str(p): x
+            for p, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def _is_layer_weight(path: str) -> bool:
+    """What the layer code rounds to ``cfg.dtype`` at use and the TPU's
+    compiler rounds too (``pos_embed`` it adds in float32: it stays)."""
+    return (path.startswith(("layers/attn/", "layers/mlp/"))
+            and "/router/" not in path
+            and not path.endswith(("/kernel/q", "/kernel/scale")))  # int8
+
+
+@pytest.mark.parametrize("kind", ["plain", "moe", "int8", "rounded_already",
+                                  "float32_compute"])
+def test_compute_dtype_params_rounds_the_layer_weights_and_nothing_else(kind):
+    model, variables = _bf16_model_and_vars(moe=kind == "moe")
+    cfg, given = model.cfg, variables["params"]
+    if kind == "int8":
+        given = quantize_for_decode(given)
+    elif kind == "rounded_already":
+        given = compute_dtype_params(given, cfg)
+    elif kind == "float32_compute":
+        cfg = dataclasses.replace(cfg, dtype=jnp.float32)
+    before, after = _paths(given), _paths(compute_dtype_params(given, cfg))
+    assert list(before) == list(after)
+    cast = [p for p in before if after[p] is not before[p]]
+    want = [p for p in before
+            if _is_layer_weight(p) and before[p].dtype == jnp.float32
+            and cfg.dtype != jnp.float32]
+    assert cast == want
+    for p in cast:
+        assert after[p].dtype == cfg.dtype
+        assert bool((after[p] == before[p].astype(cfg.dtype)).all()), p
+    if kind == "plain":
+        assert {"layers/attn/q_proj/bias", "layers/attn/o_proj/kernel",
+                "layers/mlp/down_proj/kernel"} <= set(cast)
+        assert {"embed/embedding", "pos_embed", "final_norm/scale",
+                "layers/attn_norm/bias", "layers/mlp_norm/scale"
+                } <= set(before) - set(cast)
+    if kind == "moe":  # rope, rmsnorm, no biases, untied head
+        assert {"layers/attn/q_proj/kernel", "layers/mlp/experts_up",
+                "layers/mlp/experts_down"} <= set(cast)
+        assert {"layers/mlp/router/kernel", "embed/embedding",
+                "layers/attn_norm/scale"} <= set(before) - set(cast)
+    if kind == "int8":  # only the biases are left to round
+        assert cast and all(p.endswith("/bias") for p in cast)
+    if kind in ("rounded_already", "float32_compute"):
+        assert not cast
+
+
+def test_compute_dtype_params_keeps_a_leafs_sharding(devices8):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    model, variables = _bf16_model_and_vars()
+    mesh = Mesh(np.asarray(devices8[:2]), ("tensor",))
+    params = variables["params"]
+    sh = NamedSharding(mesh, P(None, None, "tensor"))
+    up = jax.device_put(params["layers"]["mlp"]["up_proj"]["kernel"], sh)
+    params["layers"]["mlp"]["up_proj"]["kernel"] = up
+    out = compute_dtype_params(params, model.cfg)
+    got = out["layers"]["mlp"]["up_proj"]["kernel"]
+    assert got.dtype == jnp.bfloat16
+    assert got.sharding.is_equivalent_to(sh, got.ndim)
+
+
+def _base_program_operands(model, variables, program):
+    """(function, operands after the params) of one base program as the
+    engine builds it, on a pool that holds a few written tokens."""
+    eng = ServeEngine(model, variables, n_slots=2, max_len=32, block_size=8,
+                      prefill_chunk=8, export_cache=False)
+    rs = np.random.RandomState(3)
+    if program == "prefill_chunk":
+        cache = KVCache.init(model.cfg, 1, 32, dtype=jnp.bfloat16)
+        return eng._prefill_fn.__wrapped__, (
+            jnp.asarray(rs.randint(1, VOCAB, size=(1, 8)), jnp.int32),
+            cache, np.int32(5))
+    kv = jax.tree.map(
+        lambda x: jnp.asarray(rs.normal(size=x.shape), x.dtype), eng.pool.kv)
+    tables = jnp.asarray([[1, 2, 0, 0], [3, 0, 0, 0]], jnp.int32)
+    return eng._step_fn.__wrapped__, (
+        kv, tables, jnp.asarray([9, 3], jnp.int32),
+        jnp.asarray(rs.randint(1, VOCAB, size=(2, 1)), jnp.int32),
+        jnp.asarray([True, True]), {}, jnp.zeros((2,), jnp.int32),
+        jax.random.key(0))
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_base_program_returns_the_same_for_the_rounded_tree(program):
+    """Tokens and KV of ``_paged_decode_step``, last-position logits and
+    cache of ``_prefill_chunk_step``: bit for bit what the float32 tree,
+    rounded inside the call, gives."""
+    model, variables = _bf16_model_and_vars()
+    fn, operands = _base_program_operands(model, variables, program)
+    given = variables["params"]
+    rounded = compute_dtype_params(given, model.cfg)
+    a = jax.tree.leaves(jax.jit(fn)(given, *operands))
+    b = jax.tree.leaves(jax.jit(fn)(rounded, *operands))
+    assert len(a) == len(b) >= 2
+    for x, y in zip(a, b):
+        assert x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x.astype(jnp.float32)),
+                                      np.asarray(y.astype(jnp.float32)))
+    if program == "prefill_chunk":
+        assert a[0].shape == (1, VOCAB) and bool(jnp.any(a[0] != 0))
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_base_program_lowers_without_a_convert_of_a_weight(program):
+    """What keeps the loop-invariant converts from coming back: lowered
+    for the tree the engine holds, neither base program has a float32 ->
+    bf16 ``convert`` of a tensor shaped like a layer weight, stacked
+    ``[n_layers, ...]`` or one layer's slice of it (where the layer code
+    writes it; XLA moves it out of the scan, over the whole stack).  The
+    same search finds them in the program lowered for the float32 tree."""
+    import re
+
+    model, variables = _bf16_model_and_vars()
+    fn, operands = _base_program_operands(model, variables, program)
+    given = variables["params"]
+    shapes = set()
+    for p, x in _paths(given).items():
+        if _is_layer_weight(p):
+            shapes |= {x.shape, x.shape[1:]}
+
+    def weight_converts(params):
+        text = jax.jit(fn).lower(params, *operands).as_text()
+        found = re.findall(
+            r"stablehlo\.convert [^\n]*\(tensor<([0-9x]+)xf32>\) -> "
+            r"tensor<[0-9x]+xbf16>", text)
+        return [s for s in found
+                if tuple(int(d) for d in s.split("x")) in shapes]
+
+    assert weight_converts(compute_dtype_params(given, model.cfg)) == []
+    assert len(weight_converts(given)) >= 6
+
+
+def _serve_tokens(eng, tenants=()):
+    rs = np.random.RandomState(5)
+    prompts = [[int(t) for t in rs.randint(1, VOCAB, size=(n,))]
+               for n in (5, 19, 11)]
+    names = [None, None, None]
+    for name, lora in tenants:
+        eng.register_adapter(name, lora)
+        names = [None, name, name]
+    reqs = [eng.submit(p, max_new_tokens=6, adapter=a)
+            for p, a in zip(prompts, names)]
+    eng.run()
+    return [list(r.out_tokens) for r in reqs]
+
+
+@pytest.mark.parametrize("kind", ["plain", "int8_weights", "speculative",
+                                  "lora"])
+def test_engine_serves_the_tokens_of_the_float32_tree(kind):
+    """End to end, greedy: an engine emits what the same engine emits when
+    its base programs are handed the tree it was given (which is what
+    they were handed before the rounding moved to construction)."""
+    model, variables = _bf16_model_and_vars()
+    if kind == "int8_weights":
+        variables = quantize_for_decode(variables)
+    kw = dict(n_slots=2, max_len=32, block_size=8, prefill_chunk=8,
+              export_cache=False)
+    tenants = ()
+    if kind == "speculative":
+        kw["speculative"] = 2
+    if kind == "lora":
+        kw.update(lora_spec=LoraSpec(rank=4, alpha=8.0), n_adapters=2)
+        tenants = (("t0", random_adapter(
+            variables["params"], kw["lora_spec"], seed=11)),)
+    eng = ServeEngine(model, variables, **kw)
+    assert eng.params is not variables["params"]
+    before = ServeEngine(model, variables, **kw)
+    before.params = variables["params"]
+    got, want = _serve_tokens(eng, tenants), _serve_tokens(before, tenants)
+    assert got == want
+    assert all(len(t) == 6 for t in got) and len({tuple(t) for t in got}) > 1
+
+
+def test_tenant_prefill_merges_into_the_float32_weights():
+    """A ``lora_spec`` engine hands its tenant prefill the tree it was
+    given: ``merge_lora`` adds the delta to the float32 weight and the
+    program rounds the sum.  An engine without tenants keeps no float32
+    layer weight alive."""
+    import gc
+    import weakref
+
+    model, variables = _bf16_model_and_vars()
+    spec = LoraSpec(rank=4, alpha=8.0)
+    eng = ServeEngine(model, variables, n_slots=2, max_len=32, block_size=8,
+                      prefill_chunk=8, lora_spec=spec, n_adapters=2,
+                      export_cache=False)
+    seen = []
+    lora_fn = eng._prefill_lora_fn
+    eng._prefill_lora_fn = lambda params, *rest: (
+        seen.append(params) or lora_fn(params, *rest))
+    eng.register_adapter(
+        "t0", random_adapter(variables["params"], spec, seed=11))
+    eng.submit([3, 1, 4, 1, 5, 9, 2, 6, 5, 3], max_new_tokens=2,
+               adapter="t0")
+    eng.run()
+    assert len(seen) == 2  # ten tokens in chunks of eight
+    given = _paths(variables["params"])
+    for params in seen:
+        assert all(x is given[p] for p, x in _paths(params).items())
+    assert given["layers/attn/q_proj/kernel"].dtype == jnp.float32
+    assert (eng.params["layers"]["attn"]["q_proj"]["kernel"].dtype
+            == jnp.bfloat16)
+
+    plain = ServeEngine(model, variables, n_slots=2, max_len=32,
+                        block_size=8, prefill_chunk=8, export_cache=False)
+    assert plain._merge_base is None
+    kernel = weakref.ref(variables["params"]["layers"]["mlp"]["up_proj"]
+                         ["kernel"])
+    embedding = variables["params"]["embed"]["embedding"]
+    del variables, given, seen, eng, lora_fn, params
+    gc.collect()
+    assert kernel() is None
+    assert plain.params["embed"]["embedding"] is embedding
+
+
+def test_engine_event_counts_the_rounded_weights():
+    from torch_automatic_distributed_neural_network_tpu.obs.journal import (
+        Journal,
+    )
+
+    model, variables = _bf16_model_and_vars()
+    j = Journal(None, validate=True, host0_only=False)
+    eng = ServeEngine(model, variables, n_slots=2, max_len=32, block_size=8,
+                      journal=j, export_cache=False)
+    (ev,) = j.named("serve.engine")
+    held = jax.tree.leaves(eng.params)
+    rounded = [x for x in held if x.dtype == jnp.bfloat16]
+    assert ev["weights_cast"] == len(rounded) == 12  # 6 kernels, 6 biases
+    assert ev["weight_bytes_compute"] == sum(x.nbytes for x in rounded)
+    assert ev["weight_bytes_fp32"] == sum(
+        x.nbytes for x in held if x.dtype == jnp.float32)
+    assert ev["weight_bytes_compute"] + ev["weight_bytes_fp32"] == sum(
+        x.nbytes for x in held)

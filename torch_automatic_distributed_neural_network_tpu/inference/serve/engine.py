@@ -107,6 +107,7 @@ from ..decode import (
     SampleConfig,
     _moe_mlp_cached,
     _sample,
+    compute_dtype_params,
     forward_cached,
 )
 from ..quant import dequantize_kv, dequantize_leaf, dequantize_tree, \
@@ -387,6 +388,12 @@ class ServeEngine:
     decode); ``run()`` steps until idle.  A long-lived server calls
     ``submit`` from its frontend and ``step`` in a loop — nothing here
     blocks on a full batch.
+
+    ``eng.params`` is the tree the base programs take, not the one the
+    engine was given: ``decode.compute_dtype_params`` has rounded the
+    layers' weights to ``cfg.dtype`` once, so no call rounds them again.
+    Only an engine with ``lora_spec`` also keeps the tree it was given,
+    for the tenant prefill's float32 merge.
     """
 
     def __init__(self, model, variables: Any, *,
@@ -419,7 +426,13 @@ class ServeEngine:
                 f"unknown attention_impl {attention_impl!r} "
                 f"(expected 'paged' or 'dense')")
         self.cfg: TransformerConfig = model.cfg
-        self.params = variables["params"]
+        # the tree the base programs take (see the class docstring)
+        self.params = compute_dtype_params(variables["params"], self.cfg)
+        # the tenant prefill adds a low-rank delta to the weight as it
+        # was given and rounds the SUM: it keeps that tree, and an
+        # engine without tenants keeps no reference to it
+        self._merge_base = (variables["params"] if lora_spec is not None
+                            else None)
         self.sample = sample or SampleConfig(temperature=0.0)
         self.n_slots = n_slots
         self.max_len = max_len
@@ -577,6 +590,12 @@ class ServeEngine:
                 quant_adapters=bool(quant_adapters))
         from ...ops.paged_attention import tensor_degree
 
+        given = jax.tree.leaves(variables["params"])
+        held = jax.tree.leaves(self.params)
+
+        def held_bytes(dtype) -> int:
+            return sum(x.nbytes for x in held if x.dtype == dtype)
+
         self.journal.event(
             "serve.engine", attention_impl=attention_impl,
             prefill_chunk=self.prefill_chunk,
@@ -588,7 +607,12 @@ class ServeEngine:
             speculative=self.speculative,
             prefix_cache=self._prefix_cache is not None,
             disaggregate=self.disaggregate,
-            tp=tensor_degree(mesh))
+            tp=tensor_degree(mesh),
+            # of the tree the base programs take: leaves rounded to the
+            # compute dtype at construction, and its bytes by dtype
+            weights_cast=sum(a is not b for a, b in zip(given, held)),
+            weight_bytes_compute=held_bytes(jnp.dtype(self.cfg.dtype)),
+            weight_bytes_fp32=held_bytes(jnp.float32))
 
     def _export_compiled(self, cache, tags: dict, *, num_blocks: int,
                          block_size: int, quant_kv: bool, cache_dtype,
@@ -803,8 +827,8 @@ class ServeEngine:
             cache = KVCache.init(self.cfg, 1, tokens.shape[1],
                                  dtype=jnp.bfloat16)
             lora = self._req_lora(req)
-            params = (self.params if lora is None
-                      else merge_lora(self.params, lora, self.lora_spec))
+            params = (self.params if lora is None else merge_lora(
+                self._merge_base, lora, self.lora_spec))
             # forward_cached retraces per distinct prompt length — the
             # only shape-varying compile in the serving loop
             logits, cache = forward_cached(
@@ -896,7 +920,7 @@ class ServeEngine:
                                           last_idx)
             if st.lora is not None:
                 fn, args = self._prefill_lora_fn, (
-                    self.params, st.lora, tokens, st.cache, last_idx)
+                    self._merge_base, st.lora, tokens, st.cache, last_idx)
             if self.pool.quantize:
                 logits, st.cache, qchunk = fn(*args)
                 st.qchunks.append(qchunk)

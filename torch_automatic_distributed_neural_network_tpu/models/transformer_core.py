@@ -61,7 +61,11 @@ class TransformerConfig:
     type_vocab_size: int = 0  # >0 -> segment embeddings (BERT NSP-style)
     tie_embeddings: bool = True
     dropout_rate: float = 0.0
-    dtype: Any = jnp.bfloat16  # compute dtype; params stay fp32
+    # compute dtype.  model.init gives fp32 params and training keeps
+    # them so (or as the plan's precision says); ServeEngine is handed
+    # them as they are and holds the layers' weights rounded to this
+    # dtype once (inference/decode.compute_dtype_params)
+    dtype: Any = jnp.bfloat16
     attention_impl: str = "auto"
     scan_layers: bool = True
     remat: bool = True

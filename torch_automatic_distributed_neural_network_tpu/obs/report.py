@@ -363,6 +363,11 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                                           for e in schunks),
             "attention_impl": (sengine or {}).get("attention_impl"),
             "prefill_chunk": (sengine or {}).get("prefill_chunk"),
+            # the tree the base programs take (engine construction)
+            "weights_cast": (sengine or {}).get("weights_cast"),
+            "weight_bytes_compute": (sengine or {}).get(
+                "weight_bytes_compute"),
+            "weight_bytes_fp32": (sengine or {}).get("weight_bytes_fp32"),
             # disaggregated / sharded serving (r04 fields)
             "mode": (ssteps[-1].get("mode") if ssteps else None),
             "tp": (sengine or {}).get("tp"),
@@ -958,6 +963,11 @@ def format_report(report: dict) -> str:
                 f" x{sv.get('n_prefill_chunks', 0)}"
                 + (f" (C={sv['prefill_chunk']})"
                    if sv.get("prefill_chunk") else ""))
+        if sv.get("weights_cast") is not None:
+            bparts.append(
+                f"weights {sv['weight_bytes_compute'] / 2**30:.2f} GiB in "
+                f"the compute dtype + {sv['weight_bytes_fp32'] / 2**30:.2f}"
+                f" GiB float32 ({sv['weights_cast']} leaves rounded once)")
         if bparts:
             lines.append("  " + "  ".join(bparts))
         if sv.get("step_phase_mean_s"):

@@ -333,7 +333,12 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             "prefill_chunk": "int?", "speculative": "int",
             "disaggregate": "bool", "tp": "int", "prefix_cache": "bool",
             "n_adapters": "int", "adapter_rank": "int?",
-            "quant_adapters": "bool"}),
+            "quant_adapters": "bool"},
+       # the tree the base programs take: leaves the engine rounded to
+       # the compute dtype at construction, and its bytes in that dtype
+       # and in float32 (int8 leaves are in neither)
+       opt={"weights_cast": "int", "weight_bytes_compute": "int",
+            "weight_bytes_fp32": "int"}),
     _s("serve.step", "one serving iteration (engine or gateway "
        "SimReplica)",
        req={"n_active": "int", "n_queued": "int", "new_tokens": "int",
