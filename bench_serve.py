@@ -166,10 +166,10 @@ def _component_breakdown(eng, impl: str) -> dict:
     q = jnp.asarray(rs.randn(S, cfg.n_heads, cfg.head_dim), jnp.float32)
     new = jnp.asarray(rs.randn(S, cfg.kv_heads, cfg.head_dim),
                       jnp.float32)
-    k0 = jax.tree.map(lambda x: x[0], eng.pool.kv["k"])
-    v0 = jax.tree.map(lambda x: x[0], eng.pool.kv["v"])
+    k0, v0 = eng.pool.kv["k"][0], eng.pool.kv["v"][0]  # layer 0's pages
 
-    gather = jax.jit(lambda kl, t: gather_blocks(kl, t, cfg.dtype))
+    gather = jax.jit(lambda kl, t: gather_blocks(
+        kl, t, cfg.dtype, cfg.kv_heads))
     t_gather = _time_ms(gather, k0, tables)
     scatter = jax.jit(lambda kl, t, p, x: write_token(kl, t, p, x))
     t_scatter = _time_ms(scatter, k0, tables, ctx, new)

@@ -237,8 +237,7 @@ def kernel_vs_reference(eng, sz: Sizes, seed: int, on_chip: bool) -> dict:
         raise RuntimeError("the paged kernel would run interpreted on a TPU")
     cfg = eng.cfg
     S, MB, nb = eng.n_slots, eng.max_blocks, eng.pool.num_blocks
-    k0 = jax.tree.map(lambda x: x[0], eng.pool.kv["k"])
-    v0 = jax.tree.map(lambda x: x[0], eng.pool.kv["v"])
+    k0, v0 = eng.pool.kv["k"][0], eng.pool.kv["v"][0]  # layer 0's pages
     tables = jnp.asarray(
         1 + np.arange(S * MB).reshape(S, MB) % (nb - 1), jnp.int32)
     ctx = jnp.full((S,), sz.prompt + sz.max_new - 1, jnp.int32)

@@ -175,89 +175,172 @@ def test_kernel_is_named_in_the_compiled_text(kernel_texts, name, where):
     assert any(name in c for c in calls), (name, calls)
 
 
-# -- the two base serving programs, for the tree the engine holds -------------
+# -- the two serving programs, for the tree and the pool the engine holds ------
+
+_GPT2_1P3B = dict(
+    vocab_size=50257, d_model=2048, n_layers=24, n_heads=16, d_ff=8192,
+    max_seq_len=2048, remat=False)
+# (slots, max_len, block, chunk) of the benchmark's serving cells
+_SERVING = {"gpt2-1p3b": (8, 1024, 16, 128),
+            "trinity-large-ep8": (16, 13312, 16, 512)}
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
-def test_serving_program_holds_no_convert_of_a_weight_stack(
-        v5e, monkeypatch, program):
-    """``jit_serve_decode_step`` and ``jit_serve_prefill_chunk`` at the
-    GPT-2 1.3B geometry of the benchmark's serving cells (8 slots, 1024
-    positions, blocks of 16, chunks of 128), compiled for one described
+@pytest.mark.parametrize("config", sorted(_SERVING))
+def test_serving_programs_update_the_pool_in_place(
+        v5e, monkeypatch, config, program):
+    """``jit_serve_decode_step`` and ``jit_serve_prefill_chunk``
+    (``inference/serve/programs.py``: ONE pair for both models) at the
+    geometry of the benchmark's serving cells, compiled for one described
     v5e with the operands ``ServeEngine`` hands them: the layers' weights
-    already in bf16 (``decode.compute_dtype_params``).  Handed float32
-    weights, this compiler moves their rounding out of the layer scan and
-    converts each whole ``[24, ...]`` stack in every call, beside a bf16
-    copy of all of them among the temporaries; here no layer's weight
-    is converted at all and the temporaries are the KV pool's copy
-    (decode) or nothing (chunk)."""
+    already in bf16 and a subtree a layer (``decode.compute_dtype_params``,
+    ``per_layer_params``), one pair of pool arrays a layer.  GPT-2 1.3B (24
+    like layers, 8 slots of 1,024) and ``trinity-large-ep8`` (5 layers of
+    two kinds, 32 of 256 experts, 16 slots of 13,312 beside 8.3 GiB of
+    weights) alike: no layer's weight is converted, the pool is updated in
+    place (the output aliases it), and no copy of a layer's pages is among
+    the temporaries (threaded through a layer scan, the pool was copied
+    whole every step)."""
+    import json
+    import os
     import re
 
-    from torch_automatic_distributed_neural_network_tpu.inference import (
-        decode,
-    )
+    from torch_automatic_distributed_neural_network_tpu.inference import decode
     from torch_automatic_distributed_neural_network_tpu.inference.serve import (
-        engine,
+        programs,
+    )
+    from torch_automatic_distributed_neural_network_tpu.inference.serve.kv_pool import (
+        PagedKVPool,
+        blocks_for_tokens,
     )
     from torch_automatic_distributed_neural_network_tpu.models.transformer_core import (
         DecoderLM,
         TransformerConfig,
     )
     from torch_automatic_distributed_neural_network_tpu.ops import (
+        grouped_matmul as gmm,
         paged_attention as paged,
     )
 
-    # the default backend is the CPU here: ask for the kernel, not its
+    # the default backend is the CPU here: ask for the kernels, not their
     # interpreter, as the chip would
     monkeypatch.setattr(paged, "_default_interpret", lambda: False)
-    cfg = TransformerConfig(
-        vocab_size=50257, d_model=2048, n_layers=24, n_heads=16, d_ff=8192,
-        max_seq_len=2048, dtype=jnp.bfloat16, remat=False)
+    monkeypatch.setattr(gmm, "_default_interpret", lambda: False)
+    keys = _GPT2_1P3B
+    if config == "trinity-large-ep8":
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "trinity-large-ep8.json")
+        with open(path) as f:
+            keys = json.load(f)["model"]
+    cfg = TransformerConfig(**keys, dtype=jnp.bfloat16)
     given = jax.eval_shape(DecoderLM(cfg).init, jax.random.key(0),
                            np.zeros((1, 8), np.int32))["params"]
-    params = jax.eval_shape(
-        lambda p: decode.compute_dtype_params(p, cfg), given)
-    slots, max_len, block, chunk = 8, 1024, 16, 128
+    params = jax.eval_shape(lambda p: decode.per_layer_params(
+        decode.compute_dtype_params(p, cfg), cfg), given)
+    slots, max_len, block, chunk = _SERVING[config]
+    MB = blocks_for_tokens(max_len, block)
+    made = {}
+
+    def arrays():
+        made["pool"] = PagedKVPool(
+            cfg, num_blocks=slots * MB + 1, block_size=block, n_slots=slots,
+            max_blocks=MB, prefill_chunk=chunk)
+        return made["pool"].kv, made["pool"].win_tables
+
+    kv, win = jax.eval_shape(arrays)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     if program == "decode_step":
-        pool = jax.ShapeDtypeStruct(
-            (cfg.n_layers, slots * max_len // block + 1, block,
-             cfg.kv_heads, cfg.head_dim), jnp.bfloat16)
-        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
-        operands = (
-            params, {"k": pool, "v": pool}, i32(slots, max_len // block),
-            i32(slots), i32(slots, 1),
-            jax.ShapeDtypeStruct((slots,), jnp.bool_), {}, i32(slots),
-            jax.eval_shape(lambda: jax.random.key(0)))
+        operands = (params, kv, i32(slots, MB + 4), win, {},
+                    jax.eval_shape(lambda: jax.random.key(0)))
 
-        def step(params, *rest):
-            return engine._paged_decode_step(
-                params, *rest, cfg=cfg, moe_decode="dense",
+        def step(params, *a):
+            return programs.decode_step(
+                params, *a, cfg=cfg,
                 sample=decode.SampleConfig(temperature=0.0))
-
-        fn = jax.jit(step, donate_argnums=(1,))
     else:
-        operands = (
-            params, jax.ShapeDtypeStruct((1, chunk), jnp.int32),
-            jax.eval_shape(lambda: decode.KVCache.init(
-                cfg, 1, max_len, dtype=jnp.bfloat16)),
-            jax.ShapeDtypeStruct((), jnp.int32))
+        operands = (params, kv, i32(MB + chunk + 2), i32(win.shape[1]))
 
-        def step(params, *rest):
-            return engine._prefill_chunk_step(
-                params, *rest, cfg=cfg, moe_decode="dense")
+        def step(params, *a):
+            return programs.prefill_chunk(params, *a, cfg=cfg, max_blocks=MB)
 
-        fn = jax.jit(step)
     one = SingleDeviceSharding(v5e[0])
-    compiled = fn.lower(*jax.tree.map(
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(*jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
         operands)).compile()
-    text = compiled.as_text()
-    assert ("tpu_custom_call" in text) == (program == "decode_step")
-    # a weight is an entry parameter named for its path in ``params``
-    assert re.search(r"%params__layers____mlp____up_proj____kernel__\S* = "
-                     r"bf16\[24,2048,8192\]\S* parameter\(", text)
-    to_bf16 = [l.strip()[:120] for l in text.splitlines()
-               if re.search(r"= bf16\[[^\]]*\]\S* convert\(%params__layers", l)]
-    assert to_bf16 == []
-    temp_gib = compiled.memory_analysis().temp_size_in_bytes / 2**30
-    assert temp_gib < (1.6 if program == "decode_step" else 0.1), temp_gib
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert ("tadnn_paged_decode_folded" in text) == (program == "decode_step")
+    assert "tadnn_paged_decode." not in text  # one kernel for both models
+    # a weight is an entry parameter named for its path in ``params``, read
+    # as it is: not converted, not copied
+    assert re.search(r"%params__layers_1____attn____q_proj____kernel__\S* = "
+                     r"bf16\[\S* parameter\(", text)
+    assert not [l.strip()[:120] for l in text.splitlines() if re.search(
+        r"= bf16\[[^\]]*\]\S* convert\(%params__layers", l)]
+    pool_bytes = made["pool"].total_bytes
+    assert mem.alias_size_in_bytes >= pool_bytes  # updated in place
+    assert mem.temp_size_in_bytes < 0.2 * 2**30, mem.temp_size_in_bytes
+    page_arrays = {"bf16[%d,%d,%d]" % x.shape for x in jax.tree.leaves(kv)}
+    assert not [l[:100] for l in text.splitlines()
+                if " copy(" in l and any(a in l for a in page_arrays)]
+    if config == "trinity-large-ep8":
+        assert text.count("tadnn_moe_grouped_mm") >= 8  # 2 kernels, 4 layers
+        assert round(pool_bytes / 2**30, 2) == 1.94
+        assert mem.argument_size_in_bytes < 10.5 * 2**30
+    else:
+        assert "tadnn_moe_grouped_mm" not in text
+
+
+# -- a model whose layers differ: the folded decode kernel, the grouped
+# matmuls, and the two serving programs at the published widths -------------
+
+
+def test_folded_paged_decode_compiles_for_v5e(v5e):
+    """48 query heads on 8 KV heads of 128, 16 slots over pages for 13,312
+    positions: the MXU form of the decode kernel, with and without a
+    window."""
+    from torch_automatic_distributed_neural_network_tpu.ops.paged_attention import (
+        paged_attention_folded,
+    )
+
+    one = SingleDeviceSharding(v5e[0])
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+    pool = sds((16 * 832 + 1, 16, 8 * 128), jnp.bfloat16)
+    for window in (None, 4096):
+        _compile(lambda q, k, v, t, c, window=window: paged_attention_folded(
+            q, k, v, t, c, window=window, interpret=False),
+            sds((16, 48, 128), jnp.bfloat16), pool, pool,
+            sds((16, 832), jnp.int32), sds((16,), jnp.int32))
+    # float32 queries over a bf16 pool (``chip_smoke.py``'s comparison with
+    # the reference, at GPT-2 1.3B's 16 heads of 128): float32 products
+    pool = sds((8 * 64 + 1, 16, 16 * 128), jnp.bfloat16)
+    _compile(lambda q, k, v, t, c: paged_attention_folded(
+        q, k, v, t, c, interpret=False),
+        sds((8, 16, 128), jnp.float32), pool, pool,
+        sds((8, 64), jnp.int32), sds((8,), jnp.int32))
+
+
+@pytest.mark.parametrize("pairs", [64, 2048], ids=["decode", "chunk"])
+def test_grouped_matmul_compiles_for_v5e(v5e, pairs):
+    """The expert FFN's two kernels over 32 held experts of 3072 x 3072:
+    16-row tiles for a decode step's 64 pairs, 128-row tiles for a
+    chunk's 2,048."""
+    from torch_automatic_distributed_neural_network_tpu.ops.grouped_matmul import (
+        grouped_matmul,
+    )
+
+    one = SingleDeviceSharding(v5e[0])
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+    tm = 16 if pairs <= 256 else 128
+    n_tiles = pairs // tm + 32
+    w = sds((32, 3072, 3072), jnp.bfloat16)
+
+    def ffn(rows, wg, wu, wd, tg, na):
+        h = grouped_matmul(rows, wu, tg, na, tm=tm, w_gate=wg,
+                           interpret=False)
+        return grouped_matmul(h, wd, tg, na, tm=tm, interpret=False)
+
+    text = _compile(ffn, sds((n_tiles * tm, 3072), jnp.bfloat16), w, w, w,
+                    sds((n_tiles,), jnp.int32), sds((), jnp.int32))
+    assert "tadnn_moe_grouped_mm_gate_up" in text
+    assert "tadnn_moe_grouped_mm_down" in text

@@ -17,11 +17,14 @@ from torch_automatic_distributed_neural_network_tpu.analysis.serve_lint import (
 )
 from torch_automatic_distributed_neural_network_tpu.inference import generate
 from torch_automatic_distributed_neural_network_tpu.inference.decode import (
-    KVCache,
     compute_dtype_params,
+    per_layer_params,
 )
 from torch_automatic_distributed_neural_network_tpu.inference.quant import (
     quantize_for_decode,
+)
+from torch_automatic_distributed_neural_network_tpu.inference.serve import (
+    programs,
 )
 from torch_automatic_distributed_neural_network_tpu.inference.serve import (
     BlockAllocator,
@@ -838,42 +841,45 @@ def test_compute_dtype_params_keeps_a_leafs_sharding(devices8):
 def _base_program_operands(model, variables, program):
     """(function, operands after the params) of one base program as the
     engine builds it, on a pool that holds a few written tokens."""
-    eng = ServeEngine(model, variables, n_slots=2, max_len=32, block_size=8,
+    # 8 pages of 8 keys a slot: the paged kernel's [heads, keys] tiles are
+    # then shaped like no weight of the model
+    eng = ServeEngine(model, variables, n_slots=2, max_len=64, block_size=8,
                       prefill_chunk=8, export_cache=False)
     rs = np.random.RandomState(3)
-    if program == "prefill_chunk":
-        cache = KVCache.init(model.cfg, 1, 32, dtype=jnp.bfloat16)
-        return eng._prefill_fn.__wrapped__, (
-            jnp.asarray(rs.randint(1, VOCAB, size=(1, 8)), jnp.int32),
-            cache, np.int32(5))
     kv = jax.tree.map(
         lambda x: jnp.asarray(rs.normal(size=x.shape), x.dtype), eng.pool.kv)
-    tables = jnp.asarray([[1, 2, 0, 0], [3, 0, 0, 0]], jnp.int32)
+    if program == "prefill_chunk":  # the second chunk of a prompt, 6 real
+        return eng._prefill_fn.__wrapped__, (
+            kv, programs.pack_chunk(
+                [1, 2] + [0] * 6, rs.randint(1, VOCAB, size=(8,)), 8, 5),
+            eng.pool.win_tables[0])
+    tables = np.asarray([[1, 2] + [0] * 6, [3] + [0] * 7], np.int32)
     return eng._step_fn.__wrapped__, (
-        kv, tables, jnp.asarray([9, 3], jnp.int32),
-        jnp.asarray(rs.randint(1, VOCAB, size=(2, 1)), jnp.int32),
-        jnp.asarray([True, True]), {}, jnp.zeros((2,), jnp.int32),
-        jax.random.key(0))
+        kv, programs.pack_step(
+            tables, np.asarray([9, 3]), rs.randint(1, VOCAB, size=(2, 1)),
+            np.asarray([True, True]), np.zeros((2,), np.int32)),
+        eng.pool.win_tables, {}, jax.random.key(0))
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
 def test_base_program_returns_the_same_for_the_rounded_tree(program):
-    """Tokens and KV of ``_paged_decode_step``, last-position logits and
-    cache of ``_prefill_chunk_step``: bit for bit what the float32 tree,
-    rounded inside the call, gives."""
+    """Tokens and KV of ``programs.decode_step``, last-position logits and
+    KV of ``programs.prefill_chunk``: bit for bit what the float32 tree
+    (the scanned stack, sliced and rounded inside the call) gives."""
     model, variables = _bf16_model_and_vars()
     fn, operands = _base_program_operands(model, variables, program)
     given = variables["params"]
     rounded = compute_dtype_params(given, model.cfg)
     a = jax.tree.leaves(jax.jit(fn)(given, *operands))
-    b = jax.tree.leaves(jax.jit(fn)(rounded, *operands))
-    assert len(a) == len(b) >= 2
-    for x, y in zip(a, b):
-        assert x.dtype == y.dtype
-        np.testing.assert_array_equal(np.asarray(x.astype(jnp.float32)),
-                                      np.asarray(y.astype(jnp.float32)))
+    for tree in (rounded, per_layer_params(rounded, model.cfg)):
+        b = jax.tree.leaves(jax.jit(fn)(tree, *operands))
+        assert len(a) == len(b) >= 2
+        for x, y in zip(a, b):
+            assert x.dtype == y.dtype
+            np.testing.assert_array_equal(np.asarray(x.astype(jnp.float32)),
+                                          np.asarray(y.astype(jnp.float32)))
     if program == "prefill_chunk":
-        assert a[0].shape == (1, VOCAB) and bool(jnp.any(a[0] != 0))
+        assert a[-1].shape == (1, VOCAB) and bool(jnp.any(a[-1] != 0))
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
@@ -902,7 +908,9 @@ def test_base_program_lowers_without_a_convert_of_a_weight(program):
         return [s for s in found
                 if tuple(int(d) for d in s.split("x")) in shapes]
 
-    assert weight_converts(compute_dtype_params(given, model.cfg)) == []
+    rounded = compute_dtype_params(given, model.cfg)
+    assert weight_converts(rounded) == []
+    assert weight_converts(per_layer_params(rounded, model.cfg)) == []
     assert len(weight_converts(given)) >= 6
 
 
@@ -974,7 +982,8 @@ def test_tenant_prefill_merges_into_the_float32_weights():
     for params in seen:
         assert all(x is given[p] for p, x in _paths(params).items())
     assert given["layers/attn/q_proj/kernel"].dtype == jnp.float32
-    assert (eng.params["layers"]["attn"]["q_proj"]["kernel"].dtype
+    assert "layers" not in eng.params  # taken apart: layers_0 ..
+    assert (eng.params["layers_0"]["attn"]["q_proj"]["kernel"].dtype
             == jnp.bfloat16)
 
     plain = ServeEngine(model, variables, n_slots=2, max_len=32,
@@ -1001,7 +1010,9 @@ def test_engine_event_counts_the_rounded_weights():
     (ev,) = j.named("serve.engine")
     held = jax.tree.leaves(eng.params)
     rounded = [x for x in held if x.dtype == jnp.bfloat16]
-    assert ev["weights_cast"] == len(rounded) == 12  # 6 kernels, 6 biases
+    # 6 kernels and 6 biases of the scanned stack, a layer each here
+    assert ev["weights_cast"] == 12
+    assert len(rounded) == 12 * model.cfg.n_layers
     assert ev["weight_bytes_compute"] == sum(x.nbytes for x in rounded)
     assert ev["weight_bytes_fp32"] == sum(
         x.nbytes for x in held if x.dtype == jnp.float32)

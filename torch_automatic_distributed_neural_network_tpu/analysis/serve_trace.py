@@ -42,11 +42,8 @@ def serve_trace_check(
     no XLA compile runs.
     """
     import jax
-    import jax.numpy as jnp
-    import numpy as np
 
     from ..inference.serve import ServeEngine
-    from ..inference.serve.engine import KVCache
     from . import dtype_lint, graph_lint
 
     eng = ServeEngine(
@@ -82,23 +79,8 @@ def serve_trace_check(
             "collectives": len(graph_lint.collective_inventory(closed)),
         }
 
-    # decode: the exact operand tuple _export_compiled feeds eval_shape
-    S, MB, T = eng.n_slots, eng.max_blocks, 1 + eng.speculative
-    factors = (eng.adapter_pool.factors
-               if eng.adapter_pool is not None else {})
-    decode_abs = jax.eval_shape(lambda: (
-        eng.params, eng.pool.kv,
-        jnp.zeros((S, MB), jnp.int32), jnp.zeros((S,), jnp.int32),
-        jnp.zeros((S, T), jnp.int32), jnp.zeros((S,), jnp.bool_),
-        factors, jnp.zeros((S,), jnp.int32),
-        jax.random.fold_in(eng._rng, 2**20)))
-    lint_one("decode", eng._step_fn, decode_abs)
-
+    # the exact operand tuples _export_compiled feeds eval_shape
+    lint_one("decode", eng._step_fn, eng._abstract_decode_args())
     if eng.prefill_chunk:
-        C = eng.prefill_chunk
-        prefill_abs = jax.eval_shape(lambda: (
-            eng.params, jnp.zeros((1, C), jnp.int32),
-            KVCache.init(eng.cfg, 1, eng.max_len, dtype=jnp.bfloat16),
-            np.int32(0)))
-        lint_one("prefill", eng._prefill_fn, prefill_abs)
+        lint_one("prefill", eng._prefill_fn, eng._abstract_prefill_args())
     return findings, stats

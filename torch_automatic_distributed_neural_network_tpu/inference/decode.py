@@ -200,6 +200,11 @@ def forward_cached(
     routed instead of dense FLOPs."""
     if moe_decode not in ("dense", "routed"):
         raise ValueError(f"unknown moe_decode {moe_decode!r}")
+    if cfg.layer_types is not None:
+        raise ValueError(
+            "forward_cached runs one scanned layer; a model with "
+            "layer_types is served by ServeEngine, whose programs walk the "
+            "layers one by one (inference/serve/programs.py)")
     if "layers" not in params:
         raise ValueError(
             "forward_cached needs the scanned parameter layout (a stacked "
@@ -281,12 +286,13 @@ def forward_cached(
 
 def _cast_floats(tree: Any, dtype) -> Any:
     """Floating leaves of a (sub)tree in ``dtype``; int8 ``{"q", "scale"}``
-    leaves, a ``router`` and leaves already in ``dtype`` as they are."""
+    leaves, a ``router``, the QK norms' gains and leaves already in
+    ``dtype`` as they are."""
     if is_quantized_leaf(tree):
         return tree
     if isinstance(tree, dict):
-        return {k: v if k == "router" else _cast_floats(v, dtype)
-                for k, v in tree.items()}
+        return {k: v if k in ("router", "q_norm", "k_norm")
+                else _cast_floats(v, dtype) for k, v in tree.items()}
     if jnp.issubdtype(tree.dtype, jnp.floating) and tree.dtype != dtype:
         return tree.astype(dtype)
     return tree
@@ -319,12 +325,47 @@ def compute_dtype_params(params: Any, cfg: TransformerConfig) -> Any:
 
     Not for a tree that ``merge_lora`` will add a tenant's delta to: that
     sum is taken in float32 and rounded after."""
-    if "layers" not in params:  # forward_cached refuses this layout
-        return params
     dtype = jnp.dtype(cfg.dtype)
-    layers = {k: _cast_floats(v, dtype) if k in ("attn", "mlp") else v
-              for k, v in params["layers"].items()}
-    return {**params, "layers": layers}
+
+    def rounded(layer):
+        return {k: _cast_floats(v, dtype) if k in ("attn", "mlp") else v
+                for k, v in layer.items()}
+
+    if "layers" in params:
+        return {**params, "layers": rounded(params["layers"])}
+    # layers_0 ..: one subtree a layer (layer kinds, or scan_layers=False)
+    return {k: rounded(v) if k.startswith("layers_") else v
+            for k, v in params.items()}
+
+
+def layer_params(params: Any, name: str) -> Any:
+    """Layer ``name`` (``layers_<i>``, as ``transformer_core.layer_plan``
+    names it) of either parameter layout: its own subtree, or slice ``i``
+    of every leaf of the scanned ``layers`` stack."""
+    if "layers" not in params:
+        return params[name]
+    i = int(name.rsplit("_", 1)[1])
+    return jax.tree.map(lambda x: x[i], params["layers"])
+
+
+def per_layer_params(params: Any, cfg: TransformerConfig) -> Any:
+    """The tree the serving programs are handed: ``compute_dtype_params``
+    of ``params``, with a scanned ``layers`` stack taken apart into
+    ``layers_0 ..`` (the layout of a model built layer by layer).  The
+    programs walk the layers one by one; handed this tree, they slice no
+    stack inside the program (the TPU's compiler copies each slice of a
+    stacked parameter in every call: 2.6 GB a step at GPT-2 1.3B).  A
+    layer is sliced and rounded at a time, so no rounded copy of a whole
+    stack is ever held beside its layers; every entry outside ``layers``
+    is the same object."""
+    if "layers" not in params:
+        return compute_dtype_params(params, cfg)
+    out = {k: v for k, v in params.items() if k != "layers"}
+    for i in range(cfg.n_layers):
+        name = f"layers_{i}"
+        out.update(compute_dtype_params(
+            {name: layer_params(params, name)}, cfg))
+    return out
 
 
 @dataclasses.dataclass(frozen=True)

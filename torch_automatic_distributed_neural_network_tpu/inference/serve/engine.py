@@ -9,30 +9,27 @@ executable compiles once and runs for the life of the server — no
 recompiles as the request mix churns (prefill is the only shape-varying
 entry point, one trace per distinct prompt length).
 
-Per-layer math is the TRAINING modules applied piecewise — the same
-single-source-of-truth discipline as ``decode.forward_cached``, from
-which this step differs in exactly three ways:
+The two programs are ``programs.decode_step`` and
+``programs.prefill_chunk`` (``serve/programs.py``): ONE pair for every
+model.  They walk the layers one by one, a model with one kind of layer
+and a model whose layers differ (``cfg.layer_types``: window and full
+attention mixed, expert FFNs) alike, the per-layer math the TRAINING
+modules applied piecewise, and every layer's pages updated in place.
 
-- positions/lengths are PER-SLOT vectors (requests at different depths
-  share a step), so rope angles and the attention mask row vary by slot;
-- KV reads/writes go through the paged pool (``kv_pool.gather_blocks``
-  / ``write_token``) instead of a contiguous cache strip;
-- sampled tokens are masked to 0 on inactive slots.
-
-Prefill reuses ``forward_cached`` itself on a dense temp cache, then
-copies the rows into the request's blocks — numerically the exact
-prefill ``generate()`` runs, which is what makes token-parity with
-sequential generation testable (greedy decoding is deterministic; for
-stochastic sampling the engine is reproducible under its own rng but
-not per-request-identical to ``generate()``, since one categorical
-call samples all slots).  By default prefill is CHUNKED: the prompt
-streams through one jitted [1, C]-chunk trace against a fixed
-[1, max_len] temp cache (C snapped to a divisor of max_len), one chunk
-per engine step per prefilling slot, INTERLEAVED with decode — a long
-prompt no longer stalls every running request for its whole prefill,
-and no per-prompt-length retrace exists.  ``prefill_chunk=None``
-restores the legacy single-shot prefill (one [1, P] pass at
-admission, one trace per distinct P).
+Prefill writes a prompt's keys and values straight into the request's
+blocks and attends through its table: the same math as ``generate()``'s
+prefill over the same stored values, which is what makes token-parity
+with sequential generation testable (greedy decoding is deterministic;
+for stochastic sampling the engine is reproducible under its own rng but
+not per-request-identical to ``generate()``, since one categorical call
+samples all slots).  By default prefill is CHUNKED: the prompt streams
+through one jitted [1, C]-chunk trace (C snapped to a divisor of
+max_len), one chunk per engine step per prefilling slot, INTERLEAVED
+with decode — a long prompt no longer stalls every running request for
+its whole prefill, and no per-prompt-length retrace exists.
+``prefill_chunk=None`` is the single-shot prefill: the same program over
+the whole prompt as one chunk at admission (padded to whole pages, one
+trace per distinct padded length).
 
 The decode-step attention is config-gated (``attention_impl``):
 ``"paged"`` (default) runs the fused Pallas kernel that reads the
@@ -43,7 +40,7 @@ path the kernel is parity-pinned against.
 Multi-tenant LoRA (``lora_spec=...``): each request may name a
 registered adapter; the decode step gathers its (A, B) factors from the
 fixed-shape adapter pool by per-slot id and applies the segmented
-low-rank delta inside the scanned layer body, so heterogeneous tenants
+low-rank delta inside the layer body, so heterogeneous tenants
 (and the base model, via identity adapter 0) share the ONE decode
 trace.  Prefill merges the tenant's factors into the weights INSIDE a
 jitted chunk step (rank-r matmul fused into the weight load, factors
@@ -84,6 +81,7 @@ produces the same fields on virtual time.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import math
 import os
 import time
@@ -93,287 +91,43 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.transformer_core import (
-    MLPBlock,
-    SelfAttention,
-    TransformerConfig,
-    make_norm,
-    rope,
-)
+from ...models.transformer_core import TransformerConfig
 from ...obs import journal as _journal
-from ...training.lora import LoraSpec, merge_lora
+from ...training.lora import LoraSpec
 from ..decode import (
-    KVCache,
     SampleConfig,
-    _moe_mlp_cached,
     _sample,
     compute_dtype_params,
-    forward_cached,
+    per_layer_params,
 )
-from ..quant import dequantize_kv, dequantize_leaf, dequantize_tree, \
-    embedding_lookup, is_quantized_leaf, quantize_kv
 from ..speculative import accept_length, ngram_propose
-from .adapters import IDENTITY_ADAPTER, AdapterPool, factor_rows
-from .kv_pool import (
-    PagedKVPool,
-    blocks_for_tokens,
-    gather_blocks,
-    write_token,
-)
+from . import programs
+from .adapters import AdapterPool
+from .kv_pool import PagedKVPool, blocks_for_tokens
 from .prefix_cache import PrefixCache
 from .scheduler import Request, Scheduler
 
 # the phases of one ``ServeEngine.step`` in program order: keys of the
 # ``serve.step`` event's ``phases``, annotations ``serve.<phase>``.  Only
 # ``prefill_first_token`` and ``decode_wait`` wait for the device; the
-# others are host work and dispatches (``admit`` on a prefix-cache hit
-# includes dispatching ``pool.read_blocks``).  A single-shot prefill
-# (``prefill_chunk=None``) is one ``prefill_dispatch``, its first token's
-# ``prefill_first_token`` and a ``prefill_commit``, not part of ``admit``
+# others are host work and dispatches.  A single-shot prefill
+# (``prefill_chunk=None``) is one ``prefill_dispatch`` and its first
+# token's ``prefill_first_token``, not part of ``admit``.  A prefill lands
+# in the request's pages as it runs: there is no commit to time
 PHASES = ("evict", "admit", "prefill_dispatch", "prefill_first_token",
-          "prefill_commit", "grow", "decode_prepare", "decode_upload",
+          "grow", "decode_prepare", "decode_upload",
           "decode_dispatch", "decode_wait", "emit")
-
-
-def _paged_decode_step(params, kv, tables, ctx_lens, tok, active,
-                       adapters, adapter_ids, rng, *,
-                       cfg: TransformerConfig,
-                       sample: SampleConfig, moe_decode: str,
-                       attention_impl: str = "paged",
-                       lora_scaling: float = 1.0,
-                       mesh=None, spec=None):
-    """A [S, T] token chunk for every slot — T == 1 is plain one-token
-    decode, T == 1+k is a speculative verify step (position t attends
-    keys 0..ctx+t, exactly the sequential semantics).  Static shapes
-    throughout (S slots, T chunk, tables [S, max_blocks]) so each
-    engine configuration traces exactly once.
-
-    ``attention_impl`` picks the per-layer KV read:
-
-    - ``"paged"`` (default): the fused Pallas kernel
-      (ops/paged_attention.py) reads the block table in-kernel — the
-      dense gathered view never materializes, int8 dequantize happens
-      on load inside the kernel; single-query only, so T > 1 verify
-      steps fall back to the dense path below;
-    - ``"dense"``: the reference path — ``gather_blocks`` to a dense
-      [S, max_len] view, then stock ``xla_attention`` under an explicit
-      mask.  Kept as the parity oracle and the fallback.
-
-    ``adapters`` is the AdapterPool's factor pytree ({} when serving
-    the base model only): per layer and per q/k/v/o site, stacked
-    ``a [A, d_in, r]`` / ``b [A, r, d_out]`` factors.  Each slot
-    gathers its ``adapter_ids`` row and adds the segmented low-rank
-    delta ``scaling * (x @ A) @ B`` to that projection's output —
-    slot 0 holds zero factors (IDENTITY_ADAPTER), so base-model slots
-    pay one gather of zeros instead of a second trace.  q/k deltas are
-    rope-rotated like the projections they perturb (rope is linear, so
-    rotating the delta IS the merged-weight semantics).
-
-    Returns the updated kv plus sampled tokens [S] (T == 1) or the
-    target's greedy choices [S, T] (verify steps are temperature-0 by
-    contract — sampled speculative needs rejection resampling).
-    """
-    from ...ops.attention import xla_attention
-    from ...ops.paged_attention import paged_attention
-
-    dtype = cfg.dtype
-    T = tok.shape[1]
-    norm = make_norm(cfg)
-    attn = SelfAttention(cfg)
-    mlp = MLPBlock(cfg)
-    if mesh is not None and spec is not None:
-        from jax.sharding import NamedSharding
-
-        sh = NamedSharding(mesh, spec)
-        kv = jax.tree.map(
-            lambda x: jax.lax.with_sharding_constraint(x, sh), kv)
-
-    x = embedding_lookup(
-        params["embed"]["embedding"], tok, dtype)  # [S, T, d]
-    # per-slot, per-chunk-offset absolute positions
-    positions = ctx_lens[:, None] + jnp.arange(T)[None, :]  # [S, T]
-    if cfg.pos == "learned":
-        pe = params["pos_embed"].astype(dtype)
-        x = x + pe[positions]
-
-    mask = None
-    if attention_impl == "dense" or T > 1:
-        n_keys = tables.shape[1] * (
-            kv["k"]["q"] if is_quantized_leaf(kv["k"]) else kv["k"]
-        ).shape[2]
-        key_idx = jnp.arange(n_keys)[None, None, :]
-        # chunk position t writes at positions[s, t] then attends keys
-        # 0..positions[s, t] inclusive — the causal triangle across the
-        # chunk plus the full context below it; table padding beyond a
-        # slot's blocks gathers null-block garbage this never admits
-        mask = key_idx <= positions[:, :, None]
-        if cfg.sliding_window is not None:
-            mask &= key_idx > positions[:, :, None] - cfg.sliding_window
-        mask = mask[:, None]  # [S, 1, T, K]
-
-    def layer(x, xs):
-        lp, k_layer, v_layer, ad = xs
-        lp = dequantize_tree(lp, dtype)
-        h = norm.apply({"params": lp["attn_norm"]}, x)
-        q, k, v = attn.apply(
-            {"params": lp["attn"]}, h, positions, method="qkv")
-        if ad:
-            hf = h.astype(jnp.float32)
-
-            def delta(site, inp):
-                a = factor_rows(ad[site]["a"], adapter_ids)  # [S, d_in, r]
-                b = factor_rows(ad[site]["b"], adapter_ids)  # [S, r, d_out]
-                t2 = jnp.einsum("std,sdr->str", inp, a)
-                return lora_scaling * jnp.einsum("str,sro->sto", t2, b)
-
-            def adapted(tensor, site, inp, rotate=False):
-                d = delta(site, inp).reshape(tensor.shape)
-                if rotate and cfg.pos == "rope":
-                    d = rope(d, positions, cfg.rope_theta)
-                return (tensor.astype(jnp.float32) + d).astype(tensor.dtype)
-
-            if "q" in ad:
-                q = adapted(q, "q", hf, rotate=True)
-            if "k" in ad:
-                k = adapted(k, "k", hf, rotate=True)
-            if "v" in ad:
-                v = adapted(v, "v", hf)
-        for t in range(T):  # T is static and small (1 + draft length)
-            k_layer = write_token(k_layer, tables, ctx_lens + t, k[:, t])
-            v_layer = write_token(v_layer, tables, ctx_lens + t, v[:, t])
-        if attention_impl == "paged" and T == 1:
-            # fused path: block table consumed in-kernel, same ctx/window
-            # mask semantics, no [S, max_len] gather; with a mesh the
-            # kernel shard_maps over the tensor axis (kv-head parallel)
-            o = paged_attention(
-                q[:, 0], k_layer, v_layer, tables, ctx_lens,
-                window=cfg.sliding_window, mesh=mesh)[:, None]
-        else:
-            kd = gather_blocks(k_layer, tables, dtype)
-            vd = gather_blocks(v_layer, tables, dtype)
-            o = xla_attention(q, kd, vd, causal=False, mask=mask)
-        ao = attn.apply(
-            {"params": lp["attn"]}, o.astype(dtype), method="out_proj")
-        if ad and "o" in ad:
-            of = o.reshape(o.shape[0], o.shape[1], -1).astype(jnp.float32)
-            ao = adapted(ao, "o", of)
-        x = x + ao
-        h = norm.apply({"params": lp["mlp_norm"]}, x)
-        if "experts_up" in lp["mlp"]:
-            x = x + _moe_mlp_cached(lp["mlp"], h, cfg)
-        else:
-            x = x + mlp.apply({"params": lp["mlp"]}, h)
-        return x, (k_layer, v_layer)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer, x, (params["layers"], kv["k"], kv["v"], adapters))
-
-    x = norm.apply({"params": params["final_norm"]}, x)
-    feats = x.astype(jnp.float32)  # [S, T, d]
-    if cfg.tie_embeddings:
-        emb = params["embed"]["embedding"]
-        if is_quantized_leaf(emb):
-            emb = dequantize_leaf(emb, jnp.float32)
-        logits = feats @ emb.astype(jnp.float32).T
-    else:
-        head = params["lm_head"]["kernel"]
-        if is_quantized_leaf(head):
-            head = dequantize_leaf(head, jnp.float32)
-        logits = feats @ head.astype(jnp.float32)
-    if T == 1:
-        nxt = _sample(logits[:, 0], rng, sample)
-        return {"k": new_k, "v": new_v}, jnp.where(active, nxt, 0)
-    # verify step: the target's own greedy choice at every chunk
-    # position (the all-logits discipline of decode.generate)
-    tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, T]
-    return {"k": new_k, "v": new_v}, jnp.where(active[:, None], tgt, 0)
-
-
-def _prefill_chunk_step(params, tokens, cache, last_idx, *,
-                        cfg: TransformerConfig, moe_decode: str,
-                        quantize: bool = False):
-    """One fixed-shape prefill chunk: [1, C] tokens through
-    ``forward_cached`` against the fixed [1, max_len] temp cache.
-
-    Every chunk of every prompt reuses this ONE jitted trace: the chunk
-    length is constant and both the cache cursor (``cache.length``) and
-    ``last_idx`` are traced scalars.  The final chunk of a prompt may
-    be right-padded; ``last_idx`` selects the last REAL token's logits,
-    and causal masking keeps the pad positions (which sit after it) out
-    of that row entirely.
-
-    ``quantize=True`` (int8 KV pools) round-trips the chunk's fresh
-    cache rows through the pool's (q, scale) representation before the
-    next chunk attends to them, and ALSO returns that quantized chunk
-    so the commit scatters the exact same (q, scale) pairs — no second
-    quantization.  The point is a single KV representation everywhere:
-    later prefill chunks, decode, and any future request that reuses
-    these rows through the prefix cache all see bit-identical values,
-    which is what makes cache-on vs cache-off token parity exact in
-    int8 mode instead of merely close.
-    """
-    pos0 = cache.length
-    logits, cache = forward_cached(
-        params, cfg, tokens, cache, moe_decode=moe_decode, mesh=None,
-        all_logits=True)
-    last = jax.lax.dynamic_index_in_dim(
-        logits, last_idx, axis=1, keepdims=False)
-    if not quantize:
-        return last, cache
-    T = tokens.shape[1]
-    k_rows = jax.lax.dynamic_slice_in_dim(
-        cache.k, pos0, T, axis=2)[:, 0]  # [L, T, kvH, hd]
-    v_rows = jax.lax.dynamic_slice_in_dim(cache.v, pos0, T, axis=2)[:, 0]
-    qk, qv = quantize_kv(k_rows), quantize_kv(v_rows)
-    cache = cache._replace(
-        k=jax.lax.dynamic_update_slice_in_dim(
-            cache.k, dequantize_kv(qk, cache.k.dtype)[:, None],
-            pos0, axis=2),
-        v=jax.lax.dynamic_update_slice_in_dim(
-            cache.v, dequantize_kv(qv, cache.v.dtype)[:, None],
-            pos0, axis=2))
-    return last, cache, {"k": qk, "v": qv}
-
-
-def _prefill_chunk_lora_step(params, lora, tokens, cache, last_idx, *,
-                             cfg: TransformerConfig, moe_decode: str,
-                             lora_spec: LoraSpec, quantize: bool = False):
-    """Chunked prefill through per-tenant merged weights: ``merge_lora``
-    runs INSIDE the jit (the rank-r matmul fuses into the weight load),
-    so ONE trace serves every tenant — the factor tree is a traced
-    operand and the merged weights never materialize on the host."""
-    merged = merge_lora(params, lora, lora_spec)
-    return _prefill_chunk_step(merged, tokens, cache, last_idx,
-                               cfg=cfg, moe_decode=moe_decode,
-                               quantize=quantize)
-
-
-def _cat_qchunks(qchunks: list, n_tokens: int):
-    """Concatenate the prefill trace's per-chunk quantized KV along the
-    token axis and trim the final chunk's pad rows: two ``{"q",
-    "scale"}`` leaves of [L, n_tokens, kvH, *], ready for
-    ``write_prefill`` to scatter without re-quantizing."""
-    out = []
-    for side in ("k", "v"):
-        q = jnp.concatenate([c[side]["q"] for c in qchunks], axis=1)
-        s = jnp.concatenate([c[side]["scale"] for c in qchunks], axis=1)
-        out.append({"q": q[:, :n_tokens], "scale": s[:, :n_tokens]})
-    return out[0], out[1]
 
 
 @dataclasses.dataclass
 class _PrefillState:
-    """Host-side cursor of one in-flight chunked prefill: the [1,
-    max_len] temp cache being filled, how many prompt tokens have
-    streamed through it so far (a prefix-cache hit starts the cursor
-    past the reused rows), the tenant's factor tree (None for
-    base-model requests), and — int8 pools only — the per-chunk
-    (q, scale) pairs the commit will scatter verbatim."""
+    """Host-side cursor of one in-flight prefill: how many prompt
+    tokens have streamed into the request's pages so far (a prefix-cache
+    hit starts the cursor past the reused blocks), and the tenant's
+    factor tree (None for base-model requests)."""
 
-    cache: KVCache
     pos: int = 0
     lora: Any = None
-    qchunks: list = dataclasses.field(default_factory=list)
 
 
 class ServeEngine:
@@ -390,8 +144,10 @@ class ServeEngine:
     blocks on a full batch.
 
     ``eng.params`` is the tree the base programs take, not the one the
-    engine was given: ``decode.compute_dtype_params`` has rounded the
-    layers' weights to ``cfg.dtype`` once, so no call rounds them again.
+    engine was given: ``decode.per_layer_params`` has rounded the layers'
+    weights to ``cfg.dtype`` once (``compute_dtype_params``), so no call
+    rounds them again, and taken a scanned ``layers`` stack apart into
+    ``layers_0 ..``, so no call slices it.
     Only an engine with ``lora_spec`` also keeps the tree it was given,
     for the tenant prefill's float32 merge.
     """
@@ -427,7 +183,7 @@ class ServeEngine:
                 f"(expected 'paged' or 'dense')")
         self.cfg: TransformerConfig = model.cfg
         # the tree the base programs take (see the class docstring)
-        self.params = compute_dtype_params(variables["params"], self.cfg)
+        self.params = per_layer_params(variables["params"], self.cfg)
         # the tenant prefill adds a low-rank delta to the weight as it
         # was given and rounds the SUM: it keeps that tree, and an
         # engine without tenants keeps no reference to it
@@ -447,10 +203,9 @@ class ServeEngine:
                 "compares against the target's argmax; sampled variants "
                 "need rejection resampling) — use temperature=0.0")
         if prefill_chunk is not None:
-            # snap the chunk to a divisor of max_len: the temp cache is
-            # exactly [1, max_len], so the cursor can never run past it
-            # (a learned-pos dynamic_slice would clamp its start and
-            # silently corrupt the chunk's position embeddings)
+            # snap the chunk to a divisor of max_len, so the cursor can
+            # never run past it (learned positions past the table's end
+            # would clamp and silently corrupt a chunk's embeddings)
             prefill_chunk = math.gcd(
                 min(int(prefill_chunk), max_len), max_len)
         self.prefill_chunk = prefill_chunk
@@ -459,29 +214,54 @@ class ServeEngine:
         # disaggregated mode: prefill runs on its own mesh slice, so a
         # step's prefill chunks don't serialize with decode — every
         # prefilling slot advances each step (no chunks-per-step cap),
-        # finished KV ships through pool.ship_prefill, and the step's
+        # finished KV is accounted by pool.record_ship, and the step's
         # modeled wall time is max(prefill, decode) instead of the sum.
         # Token-identical to colocated: the phases touch disjoint state
-        # (temp caches vs the pool), so only the time model changes.
+        # (a prefilling slot's pages vs the decoding slots'), so only the
+        # time model changes.
         self.disaggregate = bool(disaggregate)
+        kinds = self.cfg.layer_types or ()
+        refused = {
+            # a sliding layer's ring is written over as its window passes,
+            # so a finished prompt's keys are no longer there for another
+            # request to reuse
+            "prefix_cache with sliding_attention layers": (
+                prefix_cache and "sliding_attention" in kinds),
+            # a ring has room for ONE chunk beside the window
+            "prefill_chunk=None with sliding_attention layers": (
+                prefill_chunk is None and "sliding_attention" in kinds),
+            # the adapter pool factorizes the projections of a scanned
+            # ``layers`` stack
+            "lora_spec for a model with layer_types": (
+                lora_spec is not None and bool(kinds)),
+            # the expert layer has no form under a mesh: on one chip it
+            # runs without its exchange
+            "mesh for a model with expert layers": (
+                mesh is not None and bool(self.cfg.n_expert_layers))}
+        if any(refused.values()):
+            raise ValueError("not served: " + "; ".join(
+                k for k, v in refused.items() if v))
         self.max_blocks = blocks_for_tokens(max_len, block_size)
         if num_blocks is None:
             # worst case every slot full-length, plus the null block
             num_blocks = n_slots * self.max_blocks + 1
         self.pool = PagedKVPool(
             self.cfg, num_blocks=num_blocks, block_size=block_size,
-            dtype=cache_dtype, quantize=quant_kv, mesh=mesh)
+            dtype=cache_dtype, quantize=quant_kv, mesh=mesh,
+            n_slots=n_slots, max_blocks=self.max_blocks,
+            prefill_chunk=self.prefill_chunk)
+        self._win_rows = list(self.pool.win_tables)  # a slot's ring
         self.lora_spec = lora_spec
         self.adapter_pool: AdapterPool | None = None
         if lora_spec is not None:
-            self.adapter_pool = AdapterPool(
-                self.params, lora_spec, n_adapters=n_adapters,
+            self.adapter_pool = AdapterPool(  # reads the stack's shapes
+                variables["params"], lora_spec, n_adapters=n_adapters,
                 quantize=quant_adapters, mesh=mesh)
         # cross-request prefix caching: radix index over resident
         # prompt-prefix blocks; matched prefixes are ref'd into the new
-        # request's table and their chunks skipped.  Chunked-prefill
-        # only: the reuse path seeds the chunk trace's temp cache.
-        # Match alignment: block granularity in fp mode; in int8 mode
+        # request's table and their chunks skipped (the later chunks
+        # attend to the reused blocks through the table, where they lie).
+        # Chunked-prefill only.  Match alignment: block granularity in fp mode; in int8 mode
         # additionally snapped to prefill-chunk boundaries, so the
         # cache-off run's chunk partition of the recomputed suffix is
         # reproduced exactly (bit-identical tokens either way).
@@ -505,13 +285,6 @@ class ServeEngine:
                 journal=self.journal)
             match_align = (math.lcm(block_size, self.prefill_chunk)
                            if quant_kv else block_size)
-            # pre-compile the hit-seeding reads (fixed shapes compile
-            # exactly once) so the first matched request doesn't pay
-            # them inside its prefill window
-            kd, vd = self.pool.read_blocks(
-                [], self.max_blocks, dtype=jnp.bfloat16)
-            jax.block_until_ready(
-                (kd[:, None, :max_len], vd[:, None, :max_len]))
         self.prefix_queries = 0
         self.prefix_hits = 0
         self.prefix_cached_tokens = 0
@@ -550,29 +323,30 @@ class ServeEngine:
         # (a functools.partial has no name: jit__unknown).  They close
         # over locals, not self: no cycle keeps a dropped engine alive
         cfg, sample, pool_spec = self.cfg, self.sample, self.pool.spec
-        quantize = bool(quant_kv)
+        n_tok, max_blocks = 1 + self.speculative, self.max_blocks
 
         def serve_decode_step(*operands):
-            return _paged_decode_step(
-                *operands, cfg=cfg, sample=sample, moe_decode=moe_decode,
+            return programs.decode_step(
+                *operands, cfg=cfg, sample=sample, n_tok=n_tok,
                 attention_impl=attention_impl,
                 lora_scaling=(lora_spec.scaling if lora_spec else 1.0),
                 mesh=mesh, spec=pool_spec)
 
         def serve_prefill_chunk(*operands):
-            return _prefill_chunk_step(
-                *operands, cfg=cfg, moe_decode=moe_decode,
-                quantize=quantize)
+            return programs.prefill_chunk(
+                *operands, cfg=cfg, max_blocks=max_blocks,
+                moe_decode=moe_decode)
 
         def serve_prefill_chunk_lora(*operands):
-            return _prefill_chunk_lora_step(
-                *operands, cfg=cfg, moe_decode=moe_decode,
-                lora_spec=lora_spec, quantize=quantize)
+            return programs.prefill_chunk_lora(
+                *operands, cfg=cfg, max_blocks=max_blocks,
+                moe_decode=moe_decode, lora_spec=lora_spec)
 
         self._step_fn = jax.jit(serve_decode_step, donate_argnums=(1,))
-        self._prefill_fn = jax.jit(serve_prefill_chunk)
-        self._prefill_lora_fn = (jax.jit(serve_prefill_chunk_lora)
-                                 if lora_spec is not None else None)
+        self._prefill_fn = jax.jit(serve_prefill_chunk, donate_argnums=(1,))
+        self._prefill_lora_fn = (
+            jax.jit(serve_prefill_chunk_lora, donate_argnums=(2,))
+            if lora_spec is not None else None)
         # AOT executable cache (export/): replica spin-up goes
         # cache-first on the two fixed-shape serve traces, so a warm
         # replica deserializes the decode step and the prefill chunk
@@ -610,9 +384,23 @@ class ServeEngine:
             tp=tensor_degree(mesh),
             # of the tree the base programs take: leaves rounded to the
             # compute dtype at construction, and its bytes by dtype
-            weights_cast=sum(a is not b for a, b in zip(given, held)),
+            weights_cast=sum(a.dtype != b.dtype for a, b in zip(
+                given, jax.tree.leaves(jax.eval_shape(
+                    lambda: compute_dtype_params(
+                        variables["params"], self.cfg))))),
             weight_bytes_compute=held_bytes(jnp.dtype(self.cfg.dtype)),
-            weight_bytes_fp32=held_bytes(jnp.float32))
+            weight_bytes_fp32=held_bytes(jnp.float32),
+            # the layers by kind and the bytes their pages hold: pages for
+            # max_len a slot, and the sliding layers' rings
+            layer_kinds=(list(kinds) or None),
+            experts_held=(self.cfg.n_experts_held
+                          if self.cfg.n_expert_layers else 0),
+            experts_published=(self.cfg.experts_published
+                               if self.cfg.n_expert_layers else 0),
+            kv_bytes_full=self.pool.bytes_full,
+            kv_bytes_window=self.pool.bytes_window)
+        # the last decode step's expert counters (serve.step carries them)
+        self._moe: dict[str, int] = {}
 
     def _export_compiled(self, cache, tags: dict, *, num_blocks: int,
                          block_size: int, quant_kv: bool, cache_dtype,
@@ -644,10 +432,9 @@ class ServeEngine:
             "cache_dtype": str(np.dtype(cache_dtype)),
             "sample": dataclasses.asdict(self.sample),
             "prefill_chunk": self.prefill_chunk,
-            # int8 chunked prefill round-trips + returns (q, scale)
-            # chunks — a different program than the pre-prefix-cache
-            # trace, so quantized engines must not load stale payloads
-            **({"prefill_q_commit": True} if quant_kv else {}),
+            # prefill writes the request's pages in place: another
+            # program than the temp-cache trace of the same options
+            "prefill_in_place": True,
             "lora": ([self.lora_spec.rank, self.lora_spec.scaling,
                       n_adapters, quant_adapters]
                      if self.lora_spec is not None else None),
@@ -662,14 +449,8 @@ class ServeEngine:
                 res.compiled, self._step_fn, "serve_decode")
             self.export_info.append(res.to_json())
         if self.prefill_chunk:
-            C = self.prefill_chunk
-            prefill_abs = jax.eval_shape(lambda: (
-                self.params, jnp.zeros((1, C), jnp.int32),
-                KVCache.init(self.cfg, 1, self.max_len,
-                             dtype=jnp.bfloat16),
-                np.int32(0)))
             res = aot_mod.cached_compile(
-                self._prefill_fn, prefill_abs, cache=cache,
+                self._prefill_fn, self._abstract_prefill_args(), cache=cache,
                 kind="serve_prefill",
                 key=export_cache_mod.executable_key(
                     "serve_prefill", sig, topo_fp, program, tags))
@@ -686,10 +467,17 @@ class ServeEngine:
                    if self.adapter_pool is not None else {})
         return jax.eval_shape(lambda: (
             self.params, self.pool.kv,
-            jnp.zeros((S, MB), jnp.int32), jnp.zeros((S,), jnp.int32),
-            jnp.zeros((S, T), jnp.int32), jnp.zeros((S,), jnp.bool_),
-            factors, jnp.zeros((S,), jnp.int32),
-            jax.random.fold_in(self._rng, 2**20)))
+            jnp.zeros((S, MB + T + 3), jnp.int32), self.pool.win_tables,
+            factors, self._rng))
+
+    def _abstract_prefill_args(self, chunk: int | None = None) -> tuple:
+        """Abstract operands of the base prefill chunk (of
+        ``prefill_chunk`` tokens unless ``chunk`` says otherwise)."""
+        C = chunk or self.prefill_chunk
+        return jax.eval_shape(lambda: (
+            self.params, self.pool.kv,
+            jnp.zeros((self.max_blocks + C + 2,), jnp.int32),
+            self._win_rows[0]))
 
     def compiled_decode_text(self) -> str:
         """Optimized HLO text of the compiled decode step (the serving
@@ -784,30 +572,24 @@ class ServeEngine:
             return None
         return self.adapter_pool.effective_lora(req.adapter)
 
-    def _commit_prefill(self, slot: int, req: Request,
-                        k: Any, v: Any) -> None:
-        """Land a finished prefill's computed cache rows in the
-        request's blocks — only the UNCACHED suffix: rows ``k``/``v``
-        start at token ``req.cached_tokens`` (a prefix-cache hit's
-        reused blocks already hold their KV and are never rewritten).
-        Colocated mode writes in place; disaggregated mode routes
-        through ``pool.ship_prefill`` — same payload, plus the
-        block/byte transfer accounting that becomes DCN traffic when
-        the prefill slice is a distinct pod slice — and journals the
-        shipment.  Afterwards the request's full prompt blocks are
-        published into the radix index (for disaggregated serving that
-        IS ship time: a block is only advertised for reuse once it is
-        resident in the decode slice's pool)."""
-        full = blocks_for_tokens(req.n_prompt, self.pool.block_size)
-        blocks = req.blocks[req.cached_blocks:full]
-        if not self.disaggregate:
-            self.pool.write_prefill(blocks, k, v)
-        else:
-            moved = self.pool.ship_prefill(blocks, k, v)
-            self.scheduler.record_ship(slot, len(blocks))
+    def _publish_prefill(self, slot: int, req: Request) -> None:
+        """A finished prefill: its keys and values already lie in the
+        request's blocks (the chunks wrote them there).  Disaggregated mode
+        accounts the blocks it computed (not a prefix-cache hit's reused
+        ones) as shipped to the decode slice — the block/byte transfer
+        that becomes DCN traffic when the prefill slice is a distinct pod
+        slice — and journals the shipment.  Then the request's full prompt
+        blocks are published into the radix index (for disaggregated
+        serving that IS ship time: a block is only advertised for reuse
+        once it is resident in the decode slice's pool)."""
+        if self.disaggregate:
+            full = blocks_for_tokens(req.n_prompt, self.pool.block_size)
+            n_blocks = full - req.cached_blocks
+            moved = self.pool.record_ship(n_blocks)
+            self.scheduler.record_ship(slot, n_blocks)
             self.journal.event(
                 "serve.kv_ship", rid=req.rid, slot=slot,
-                n_blocks=len(blocks), bytes=moved)
+                n_blocks=n_blocks, bytes=moved)
         if self._prefix_cache is not None:
             # publish every FULL prompt block: decode writes start at
             # position n_prompt, so these rows are immutable (CoW
@@ -821,59 +603,32 @@ class ServeEngine:
                     "serve.prefix", kind="publish", rid=req.rid,
                     n_blocks=new)
 
-    def _prefill_into_slot(self, slot: int, req: Request) -> None:
-        with self._phase("prefill_dispatch", rid=req.rid, pos=0):
-            tokens = jnp.asarray(req.prompt, jnp.int32)[None, :]
-            cache = KVCache.init(self.cfg, 1, tokens.shape[1],
-                                 dtype=jnp.bfloat16)
-            lora = self._req_lora(req)
-            params = (self.params if lora is None else merge_lora(
-                self._merge_base, lora, self.lora_spec))
-            # forward_cached retraces per distinct prompt length — the
-            # only shape-varying compile in the serving loop
-            logits, cache = forward_cached(
-                params, self.cfg, tokens, cache,
-                moe_decode=self.moe_decode, mesh=None)
-        with self._phase("prefill_first_token", rid=req.rid):
-            req_rng = jax.random.fold_in(self._rng, req.rid)
-            _, first_rng = jax.random.split(req_rng)
-            first = int(jax.device_get(
-                _sample(logits, first_rng, self.sample))[0])
-        with self._phase("prefill_commit", rid=req.rid):
-            self._commit_prefill(slot, req, cache.k[:, 0], cache.v[:, 0])
-        req.out_tokens = [first]
-        req.t_first_token = self.scheduler.clock()
-        req.token_walls = [req.t_first_token]
-        self.tokens_emitted += 1
-
     def _start_prefill(self, slot: int, req: Request) -> None:
-        """Admission entry point: legacy single-shot prefill, or flip
-        the slot to "prefilling" so step() streams the prompt through
-        the shared chunk trace, interleaved with decode.  The host's
-        part is phase ``admit``; a single-shot prefill's forward, first
-        token and commit are the ``prefill_*`` phases, outside it."""
+        """Admission entry point: flip the slot to "prefilling" so step()
+        streams the prompt through the shared chunk trace, interleaved
+        with decode — or, single-shot (``prefill_chunk=None``), run the
+        whole prompt now as one chunk.  The host's part is phase ``admit``;
+        a single-shot prefill's forward and first token are the
+        ``prefill_*`` phases, outside it."""
         if self.prefill_chunk is None:
             # single-shot requests go straight to running, so the pin
             # happens here (before the prefill work, cheaply bounced)
             with self._phase("admit", rid=req.rid):
                 bound = self._bind_adapter(slot, req)
+                if bound:
+                    self._seed_prefill(req)
             if bound:
-                self._prefill_into_slot(slot, req)  # the prefill_* phases
+                self._advance_prefill(slot, req, single_shot=True)
             return
         with self._phase("admit", rid=req.rid):
             self._seed_prefill(req)
 
     def _seed_prefill(self, req: Request) -> None:
-        """The chunked path's temp cache and cursor.  A prefix-cache
-        hit seeds the temp cache by reading the matched blocks' KV back
-        from the pool (``pool.read_blocks``, dispatched and not waited
-        for) and starts the cursor after them — the chunk trace then
-        computes only the uncached suffix, attending to the reused rows
-        exactly as the original prefill's later chunks attended to
-        them."""
+        """A prefill's cursor.  A prefix-cache hit starts it after the
+        matched blocks — the chunk trace then computes only the uncached
+        suffix, attending to the reused blocks through the request's table
+        exactly as the original prefill's later chunks attended to them."""
         req.state = "prefilling"
-        cache = KVCache.init(self.cfg, 1, self.max_len,
-                             dtype=jnp.bfloat16)
         if self._prefix_cache is not None:
             self.prefix_queries += 1
             if req.cached_tokens:
@@ -883,52 +638,46 @@ class ServeEngine:
                 self.prefix_saved_chunks += (
                     -(-req.n_prompt // C)
                     - -(-(req.n_prompt - req.cached_tokens) // C))
-                kd, vd = self.pool.read_blocks(
-                    req.blocks[:req.cached_blocks], self.max_blocks,
-                    dtype=cache.k.dtype)
-                cache = cache._replace(
-                    k=kd[:, None, :self.max_len],
-                    v=vd[:, None, :self.max_len],
-                    length=jnp.asarray(req.cached_tokens, jnp.int32))
             self.journal.event(
                 "serve.prefix", kind="match", rid=req.rid,
                 hit=bool(req.cached_tokens),
                 cached_tokens=req.cached_tokens,
                 cached_blocks=req.cached_blocks)
         self._prefill[req.rid] = _PrefillState(
-            cache=cache, pos=req.cached_tokens,
-            lora=self._req_lora(req))
+            pos=req.cached_tokens, lora=self._req_lora(req))
 
-    def _advance_prefill(self, slot: int, req: Request) -> None:
-        """One [1, C] chunk of ``req``'s prompt.  On the final chunk:
-        pin the adapter (bouncing the request if the pool is full),
-        sample the first token (identical rng derivation to single-shot
-        prefill), copy the filled temp-cache rows into the request's
-        blocks, and hand the slot to decode."""
+    def _advance_prefill(self, slot: int, req: Request,
+                         single_shot: bool = False) -> None:
+        """One [1, C] chunk of ``req``'s prompt, written into its blocks.
+        On the final chunk: pin the adapter (bouncing the request if the
+        pool is full), sample the first token, and hand the slot to
+        decode.  Single-shot, the chunk is the whole prompt padded to
+        whole pages (one trace per distinct padded length — the only
+        shape-varying compile in the serving loop) and the adapter is
+        already pinned."""
         st = self._prefill[req.rid]
-        C = self.prefill_chunk
+        bs = self.pool.block_size
+        C = (blocks_for_tokens(req.n_prompt, bs) * bs if single_shot
+             else self.prefill_chunk)
         with self._phase("prefill_dispatch", rid=req.rid, pos=st.pos):
             chunk = req.prompt[st.pos:st.pos + C]
             n_real = len(chunk)
-            tokens = jnp.asarray(
-                chunk + [0] * (C - n_real), jnp.int32)[None]
             t0 = time.monotonic()
-            # np.int32, not a weak-typed python int: the AOT-exported
-            # trace pins the cursor's dtype, and jit would silently retrace
-            last_idx = np.int32(n_real - 1)
-            fn, args = self._prefill_fn, (self.params, tokens, st.cache,
-                                          last_idx)
-            if st.lora is not None:
-                fn, args = self._prefill_lora_fn, (
-                    self._merge_base, st.lora, tokens, st.cache, last_idx)
-            if self.pool.quantize:
-                logits, st.cache, qchunk = fn(*args)
-                st.qchunks.append(qchunk)
+            # one upload: table row, tokens, cursor, last real row
+            packed = programs.pack_chunk(
+                self.pool.table_row(req.blocks, self.max_blocks),
+                chunk + [0] * (C - n_real), st.pos, n_real - 1)
+            if st.lora is None:
+                self.pool.kv, logits = self._prefill_fn(
+                    self.params, self.pool.kv, packed, self._win_rows[slot])
             else:
-                logits, st.cache = fn(*args)
+                self.pool.kv, logits = self._prefill_lora_fn(
+                    self._merge_base, st.lora, self.pool.kv, packed,
+                    self._win_rows[slot])
         st.pos += n_real
         done = st.pos >= req.n_prompt
-        bounced = done and not self._bind_adapter(slot, req)
+        bounced = (done and not single_shot
+                   and not self._bind_adapter(slot, req))
         if done and not bounced:
             # the only wait of a prefill: chunks before the last are
             # dispatched and never fenced
@@ -937,25 +686,15 @@ class ServeEngine:
                 _, first_rng = jax.random.split(req_rng)
                 first = int(jax.device_get(
                     _sample(logits, first_rng, self.sample))[0])
-            with self._phase("prefill_commit", rid=req.rid):
-                n_suffix = req.n_prompt - req.cached_tokens
-                if self.pool.quantize:
-                    # commit the trace's own (q, scale) chunks verbatim —
-                    # re-quantizing the round-tripped rows would not be
-                    # idempotent through a bf16 temp cache
-                    k_rows, v_rows = _cat_qchunks(st.qchunks, n_suffix)
-                else:
-                    k_rows = st.cache.k[:, 0,
-                                        req.cached_tokens:req.n_prompt]
-                    v_rows = st.cache.v[:, 0,
-                                        req.cached_tokens:req.n_prompt]
-                self._commit_prefill(slot, req, k_rows, v_rows)
+            self._publish_prefill(slot, req)
             req.out_tokens = [first]
             req.t_first_token = self.scheduler.clock()
             req.token_walls = [req.t_first_token]
             self.tokens_emitted += 1
             req.state = "running"
             del self._prefill[req.rid]
+        if single_shot:
+            return
         # host seconds: a dispatch, plus the wait on the last chunk only
         chunk_s = time.monotonic() - t0
         req.prefill_chunks += 1
@@ -1032,18 +771,28 @@ class ServeEngine:
                 ids[s] = req.adapter_idx
                 act[s] = True
         with self._phase("decode_upload"):
-            step_rng = jax.random.fold_in(
-                self._rng, 2**20 + self._step_count)
+            # greedy sampling reads no key: no fold a step for it
+            step_rng = (self._rng if self.sample.temperature == 0.0
+                        else jax.random.fold_in(
+                            self._rng, 2**20 + self._step_count))
             factors = (self.adapter_pool.factors
                        if self.adapter_pool is not None else {})
-            operands = (jnp.asarray(tables), jnp.asarray(ctx),
-                        jnp.asarray(tok), jnp.asarray(act), factors,
-                        jnp.asarray(ids), step_rng)
+            # one upload a step: tables, tokens, contexts, flags, ids
+            packed = programs.pack_step(tables, ctx, tok, act, ids)
         with self._phase("decode_dispatch"):
             self.pool.kv, out = self._step_fn(
-                self.params, self.pool.kv, *operands)
+                self.params, self.pool.kv, packed, self.pool.win_tables,
+                factors, step_rng)
         with self._phase("decode_wait"):
+            # the expert counters ride with the tokens: one fetch
             out = np.asarray(jax.device_get(out))
+            out, moe = out[:-3], out[-3:]
+            if T > 1:
+                out = out.reshape(S, T)
+            if self.cfg.n_expert_layers:
+                self._moe = dict(zip(
+                    ("moe_pairs", "moe_experts_touched",
+                     "moe_max_expert_tokens"), map(int, moe)))
         with self._phase("emit"):
             self._emit(out, tok)
 
@@ -1140,6 +889,7 @@ class ServeEngine:
         tokens_before = self.tokens_emitted
         compiles, compile_s = self._compiles.n, self._compiles.seconds
         self._phases = phases = {}
+        self._moe = {}
         whole: dict[str, float] = {}
         n_chunks = 0
         with _journal.phase(whole, "step_s", "serve.step",
@@ -1190,11 +940,20 @@ class ServeEngine:
         self.overlapped_wall_s += overlap_s
         compiles = self._compiles.n - compiles
         if compiles:
-            # an unseen shape (a new prompt length at a prefill commit)
-            # stalled every stream for this long
+            # a program built in this step (a first step, a single-shot
+            # prompt of a new length) stalled every stream for this long
             self.journal.event(
                 "compile", fn="serve",
                 dur_s=self._compiles.seconds - compile_s)
+            # what building it left on the heap (jaxprs, executables, their
+            # caches: a quarter of a million objects) lives as long as the
+            # engine.  A full pass of Python's collector walks all of it,
+            # 100-130 ms inside whichever later step's allocations set the
+            # pass off (3-5 steps of a 51 s window; my chip runs, PR 27):
+            # collect now, in a step that has stalled anyway, and freeze
+            # what is left, so that later passes walk the young objects only
+            gc.collect()
+            gc.freeze()
         adapter_stats = {}
         if self.adapter_pool is not None:
             alloc = self.adapter_pool.allocator
@@ -1220,7 +979,7 @@ class ServeEngine:
             overlap_s=overlap_s,
             phases=phases, step_s=whole["step_s"], t_end=t_end,
             n_prefill_chunks=n_chunks, compiles=compiles,
-            **adapter_stats)
+            **adapter_stats, **self._moe)
         if self._debug_invariants:
             sched.check_invariants()
 

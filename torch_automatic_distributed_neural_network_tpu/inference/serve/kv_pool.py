@@ -2,15 +2,28 @@
 
 ``decode.KVCache`` reserves a contiguous [B, S_max] strip per row, so a
 batch of mixed-length requests pays worst-case memory for every slot.
-Serving flips that: the pool owns ONE block-granular store per layer,
+Serving flips that: the pool owns one block-granular store A LAYER,
 
-    k, v: [L, num_blocks, block_size, kvH, hd]
+    k, v: a list of n_layers arrays, each [num_blocks, block_size, kvH * hd]
 
 and each live request holds an ordered list of block ids (its *block
 table*).  Token ``p`` of a request lives at ``(table[p // bs], p % bs)``
 — the classic paged layout.  Memory is O(tokens actually cached), blocks
 return to the free list the step a request finishes, and a new prefill
 can reuse them immediately (iteration-level batching never drains).
+One array a layer, not one stacked over the layers: the serving programs
+walk the layers one by one and update each layer's pages in place (the
+arrays are donated), where a stack threaded through a layer scan was
+copied whole every step (60% of the GPT-2 1.3B decode program; my chip
+run, PR 26).
+
+A page is stored FOLDED, its KV heads side by side in one row a token
+(``[bs, kvH * hd]``): the layout the MXU decode kernel
+(``ops/paged_attention.py``) multiplies.  The two pools that kernel does
+not serve keep a page as ``[bs, kvH, hd]``, which is what the VPU kernel
+takes: int8 pools (``{"q", "scale"}`` leaves, below) and pools sharded
+over a mesh (kv heads on the tensor axis).  Everything here takes either
+(a written row is reshaped to the page's trailing shape).
 
 Block 0 is the **null block**: never allocated, never read through an
 active mask.  Inactive decode slots keep a table of zeros, so the fully
@@ -24,16 +37,28 @@ tokens quantize independently and freeing/reusing a block needs no
 scale bookkeeping.  ~2x KV capacity per byte of HBM; the numerics bound
 is pinned in tests/test_quant.py.
 
-Reads inside the jitted decode step go through :func:`gather_blocks`
-(table-indexed gather to a dense [S, max_len, kvH, hd] view feeding the
-stock ``xla_attention``).  That is the correctness-first choice — a
-fused paged-attention kernel that never materializes the gathered view
-is the known follow-up (ROADMAP), not a prerequisite: on the CPU sim
-mesh and at smoke scale the gather is XLA-fused and exact.
+Reads inside the jitted decode step go through the paged kernel, or
+through :func:`gather_blocks` (table-indexed gather to a dense
+[S, max_len, kvH, hd] view feeding the stock ``xla_attention``): the
+engine's ``attention_impl="dense"`` path and the oracle of every kernel
+parity test.
 
-Sharding: the pool leaf spec is ``cache_partition_spec`` with NO batch
-axes (blocks are a global resource, any slot may use any block) — kv
-heads split over the tensor axis exactly like the dense decode cache.
+Two kinds of layer (``cfg.layer_types``).  A full-attention layer, and
+every layer of a model with one kind of layer, has the pages above: the
+allocator's ids, a request's table, room for ``max_len`` a slot.  A
+``sliding_attention`` layer needs only the last ``window`` keys, so it
+keeps a RING of ``window_pages`` pages a slot, owned by the slot for the
+engine's life: position ``p`` lives in ring page ``(p // bs) %
+window_pages``, and a page is written over once the window has passed it.
+The ring is ``ceil((window + prefill_chunk) / bs) + 1`` pages, enough for
+a prefill chunk to land while every key its first row may see is still
+there.  The kernels see a ring through an ordinary table, ``ring_table``:
+logical block ``j`` at the ring page that holds it, null outside the live
+band.  The scheduler's block accounting is the full layers' alone.
+
+Sharding: a leaf's spec is ``cache_partition_spec`` less its layer and
+batch axes (blocks are a global resource, any slot may use any block) —
+kv heads split over the tensor axis exactly like the dense decode cache.
 """
 
 from __future__ import annotations
@@ -139,12 +164,32 @@ class BlockAllocator:
     free = release
 
 
+def window_pages(window: int, prefill_chunk: int, block_size: int) -> int:
+    """Pages of a sliding-attention layer's ring, a slot (see the module
+    docstring)."""
+    return math.ceil((window + prefill_chunk) / block_size) + 1
+
+
+def ring_table(win_rows: jax.Array, max_blocks: int, lo: jax.Array,
+               hi: jax.Array) -> jax.Array:
+    """A window layer's table as the kernels take one: ``[S, max_blocks]``,
+    logical block ``j`` of slot ``s`` at ring page ``win_rows[s, j % W]``
+    for ``lo[s] <= j <= hi[s]`` and the null block elsewhere (an unchanged
+    index is not copied again, so the dead blocks cost a grid step and no
+    read)."""
+    j = jnp.arange(max_blocks)[None, :]
+    live = (j >= lo[:, None]) & (j <= hi[:, None])
+    return jnp.where(live, win_rows[:, j[0] % win_rows.shape[1]], NULL_BLOCK)
+
+
 def pool_kv_bytes(cfg: TransformerConfig, num_blocks: int, block_size: int,
-                  dtype=jnp.bfloat16, quantize: bool = False) -> int:
+                  dtype=jnp.bfloat16, quantize: bool = False, *,
+                  n_layers: int | None = None) -> int:
     """Global bytes of the k+v pool arrays (scales included in int8
     mode) — the static number admission control and `check --serving`
-    budget against."""
-    n_cells = cfg.n_layers * num_blocks * block_size * cfg.kv_heads
+    budget against.  ``n_layers``: of that many layers alone."""
+    n_layers = cfg.n_layers if n_layers is None else n_layers
+    n_cells = n_layers * num_blocks * block_size * cfg.kv_heads
     if quantize:
         per_cell = cfg.head_dim * 1 + 4  # int8 payload + fp32 scale
     else:
@@ -161,33 +206,43 @@ def _zeros_side(shape, dtype, quantize: bool):
     }
 
 
-def gather_blocks(kv_layer: Any, table: jax.Array,
-                  dtype=jnp.bfloat16) -> jax.Array:
+def read_pages(kv_layer: Any, pages: jax.Array, kv_heads: int,
+               dtype=jnp.bfloat16) -> jax.Array:
+    """The pages ``pages`` (any shape of block ids) of one layer, dense and
+    dequantized: ``[*pages.shape, bs, kvH, hd]`` in ``dtype``.  Dequantize
+    on gather: only what is read converts, and an fp pool that already
+    stores ``dtype`` converts nothing."""
+    payload, scale = kv_leaf_parts(kv_layer)
+    g = payload[pages]
+    if scale is not None:
+        g = (g.astype(jnp.float32) * scale[pages]).astype(dtype)
+    elif g.dtype != dtype:
+        g = g.astype(dtype)
+    return g.reshape(*pages.shape, payload.shape[1], kv_heads, -1)
+
+
+def gather_blocks(kv_layer: Any, table: jax.Array, dtype=jnp.bfloat16,
+                  kv_heads: int | None = None) -> jax.Array:
     """Dense per-slot view of one layer's paged KV — the REFERENCE path.
 
-    ``kv_layer``: [NB, bs, kvH, hd] (or its ``{"q","scale"}`` int8
-    form); ``table``: [S, max_blocks] int32 —> [S, max_blocks*bs, kvH,
-    hd].  Table rows are padded with :data:`NULL_BLOCK`; the garbage
-    gathered from those pages sits beyond each slot's context length
-    and the attention mask never admits it.  Dequantize-on-gather keeps
-    the int8 arrays as what lives in HBM (same contract as the weight
-    path) — only the gathered working set converts; an fp pool skips
-    the dequantize pass entirely (no per-element convert when the pool
-    already stores ``dtype``).
+    ``kv_layer``: [NB, bs, kvH, hd], folded [NB, bs, kvH * hd] (give
+    ``kv_heads`` then), or the ``{"q","scale"}`` int8 form; ``table``:
+    [S, max_blocks] int32 —> [S, max_blocks*bs, kvH, hd].  Table rows are
+    padded with :data:`NULL_BLOCK`; the garbage gathered from those pages
+    sits beyond each slot's context length and the attention mask never
+    admits it.
 
     This materialized view is what the fused kernel
     (ops/paged_attention.py) exists to eliminate; it stays as the
     engine's ``attention_impl="dense"`` path and as the oracle every
     kernel parity test compares against.
     """
-    payload, scale = kv_leaf_parts(kv_layer)
-    if scale is not None:
-        g = (payload[table].astype(jnp.float32)
-             * scale[table]).astype(dtype)
-    else:
-        g = payload[table]
-        if g.dtype != dtype:
-            g = g.astype(dtype)
+    payload = kv_leaf_parts(kv_layer)[0]
+    if kv_heads is None:
+        if payload.ndim != 4:
+            raise ValueError("a folded page needs kv_heads")
+        kv_heads = payload.shape[2]
+    g = read_pages(kv_layer, table, kv_heads, dtype)
     S, MB, bs, H, hd = g.shape
     return g.reshape(S, MB * bs, H, hd)
 
@@ -202,8 +257,8 @@ def write_token(kv_layer: Any, table: jax.Array, pos: jax.Array,
     in the scratch block.  int8 mode quantizes the token in place with
     its own per-head scale.
     """
-    bs = kv_leaf_parts(kv_layer)[0].shape[1]
-    S = table.shape[0]
+    payload = kv_leaf_parts(kv_layer)[0]
+    bs = payload.shape[1]
     blk = jnp.take_along_axis(
         table, (pos // bs)[:, None].astype(jnp.int32), axis=1)[:, 0]
     off = pos % bs
@@ -213,70 +268,133 @@ def write_token(kv_layer: Any, table: jax.Array, pos: jax.Array,
             "q": kv_layer["q"].at[blk, off].set(q["q"]),
             "scale": kv_layer["scale"].at[blk, off].set(q["scale"]),
         }
-    return kv_layer.at[blk, off].set(new.astype(kv_layer.dtype))
+    return kv_layer.at[blk, off].set(  # in the page's own trailing shape
+        new.reshape(-1, *payload.shape[2:]).astype(payload.dtype))
+
+
+def write_chunk(kv_layer: Any, table_row: jax.Array, pos0: jax.Array,
+                rows: jax.Array) -> Any:
+    """``rows`` [C, kvH, hd] at positions ``pos0 .. pos0 + C`` of ONE slot,
+    through its table row (the engine's prefill chunk, written where decode
+    will read it).  A chunk of whole pages
+    that starts on a page boundary (``C % bs == 0``; the engine's cursor
+    then only ever stands on one) is written a page at a time; any other a
+    token at a time.  Positions past the row's last block land in the null
+    block, like a padded chunk's tail past the request's own blocks."""
+    bs = kv_leaf_parts(kv_layer)[0].shape[1]
+    C = rows.shape[0]
+    # a padded last chunk may reach past the table: the null block there,
+    # not the clamped start of a dynamic_slice
+    table_row = jnp.pad(table_row, (0, -(-C // bs) + 1))
+    if C % bs == 0:
+        idx = (jax.lax.dynamic_slice_in_dim(table_row, pos0 // bs, C // bs),)
+        lead = (C // bs, bs)
+    else:
+        pos = pos0 + jnp.arange(C)
+        idx, lead = (table_row[pos // bs], pos % bs), (C,)
+
+    def put(leaf, new):  # in the page's own trailing shape
+        return leaf.at[idx].set(
+            new.reshape(*lead, *leaf.shape[2:]).astype(leaf.dtype))
+
+    if is_quantized_leaf(kv_layer):
+        new = quantize_kv(rows)
+        return {k: put(kv_layer[k], new[k]) for k in ("q", "scale")}
+    return put(kv_layer, rows)
 
 
 class PagedKVPool:
     """Device storage + allocator + host-side table building.
 
-    The arrays live as a pytree ``{"k": .., "v": ..}`` with leading
-    layer axis on every leaf so the engine's ``lax.scan`` over layers
-    threads them exactly like ``forward_cached`` threads the dense
-    cache.  The pool object itself is host state (free list, shapes);
-    the arrays are swapped wholesale through the jitted step (donated),
-    so there is no device<->host copy per token.
+    The arrays live as a pytree ``{"k": [..], "v": [..]}``, one leaf a
+    layer (see the module docstring).  The pool object itself is host
+    state (free list, shapes); the arrays are swapped wholesale through
+    the jitted programs (donated), so there is no device<->host copy per
+    token.
+
+    ``n_slots``, ``max_blocks`` and ``prefill_chunk`` size the rings of a
+    model's ``sliding_attention`` layers; a model without them needs none.
     """
 
     def __init__(self, cfg: TransformerConfig, *, num_blocks: int,
                  block_size: int, dtype=jnp.bfloat16,
-                 quantize: bool = False, mesh=None):
+                 quantize: bool = False, mesh=None,
+                 n_slots: int | None = None, max_blocks: int | None = None,
+                 prefill_chunk: int | None = None):
         self.cfg = cfg
-        self.block_size = int(block_size)
+        self.block_size = bs = int(block_size)
         self.dtype = dtype
         self.quantize = bool(quantize)
         self.allocator = BlockAllocator(num_blocks)
+        self.spec = None
         # prefill->decode block-transfer accounting (disaggregated
-        # serving ships finished prefill KV through ship_prefill)
+        # serving: see record_ship)
         self.n_transfers = 0
         self.transferred_blocks = 0
         self.transferred_bytes = 0
-        shape = (cfg.n_layers, num_blocks, block_size,
-                 cfg.kv_heads, cfg.head_dim)
-        self.kv = {"k": _zeros_side(shape, dtype, quantize),
-                   "v": _zeros_side(shape, dtype, quantize)}
-        self.spec = None
+        # the kind of every layer's pages: True where the layer keeps a ring
+        self.ring = [kind == "sliding_attention"
+                     for kind in cfg.layer_types or (None,) * cfg.n_layers]
+        # a slot's ring, [n_slots, W] page ids (W 0: no layer has one)
+        W = 0
+        if any(self.ring):
+            W = min(max_blocks, window_pages(
+                cfg.sliding_window, prefill_chunk or max_blocks * bs, bs))
+        n_slots = n_slots or 0
+        self.win_tables = (
+            1 + W * jnp.arange(n_slots, dtype=jnp.int32)[:, None]
+            + jnp.arange(W, dtype=jnp.int32)[None, :])
+        self.n_window_blocks = n_slots * W + 1 if W else 0
+        # folded pages wherever the MXU kernel reads them (module docstring)
+        folded = not self.quantize and mesh is None
+        page = ((bs, cfg.kv_heads * cfg.head_dim) if folded
+                else (bs, cfg.kv_heads, cfg.head_dim))
+
+        def side():
+            return [_zeros_side(
+                (self.n_window_blocks if ring else num_blocks, *page),
+                dtype, self.quantize) for ring in self.ring]
+
+        self.kv = {"k": side(), "v": side()}
         if mesh is not None:
-            self.spec = cache_partition_spec(cfg, mesh, batch_axes=())
-            from jax.sharding import NamedSharding
+            from jax.sharding import NamedSharding, PartitionSpec
 
+            spec = cache_partition_spec(cfg, mesh, batch_axes=())
+            self.spec = PartitionSpec(*spec[1:])  # a leaf has no layer axis
             sh = NamedSharding(mesh, self.spec)
-
-            def place(x):
-                return jax.device_put(x, sh)
-
-            self.kv = {
-                side: ({"q": place(leaf["q"]),
-                        "scale": place(leaf["scale"])}
-                       if is_quantized_leaf(leaf) else place(leaf))
-                for side, leaf in self.kv.items()
-            }
+            self.kv = jax.tree.map(lambda x: jax.device_put(x, sh), self.kv)
 
     @property
     def num_blocks(self) -> int:
         return self.allocator.num_blocks
 
     @property
-    def total_bytes(self) -> int:
+    def bytes_full(self) -> int:
+        """Bytes of the layers that keep pages for ``max_len`` (every
+        layer of a model with one kind of layer)."""
         return pool_kv_bytes(self.cfg, self.num_blocks, self.block_size,
-                             self.dtype, self.quantize)
+                             self.dtype, self.quantize,
+                             n_layers=self.ring.count(False))
+
+    @property
+    def bytes_window(self) -> int:
+        """Bytes of the sliding layers' rings (0 without such layers)."""
+        return pool_kv_bytes(
+            self.cfg, self.n_window_blocks, self.block_size, self.dtype,
+            self.quantize, n_layers=self.ring.count(True))
+
+    @property
+    def total_bytes(self) -> int:
+        return self.bytes_full + self.bytes_window
 
     @property
     def bytes_per_block(self) -> int:
-        """Global bytes one block id holds across all layers, k and v
-        (scales included in int8 mode) — the unit the block-transfer
-        accounting charges per shipped block."""
-        return pool_kv_bytes(self.cfg, 1, self.block_size,
-                             self.dtype, self.quantize)
+        """Global bytes one block id holds across the layers that keep
+        pages for ``max_len``, k and v (scales included in int8 mode) —
+        the unit the block-transfer accounting charges per shipped
+        block."""
+        return pool_kv_bytes(self.cfg, 1, self.block_size, self.dtype,
+                             self.quantize, n_layers=self.ring.count(False))
 
     def alloc(self, n: int) -> list[int] | None:
         return self.allocator.alloc(n)
@@ -289,46 +407,18 @@ class PagedKVPool:
         device content into it, return the new id (None when the pool
         is exhausted — the caller must evict or preempt first).  The
         caller owns the table update and the release of its reference
-        on ``src``; the copy itself is one fused per-leaf scatter, no
-        host round-trip."""
+        on ``src``; the copy itself is one scatter a leaf, no host
+        round-trip.  A ring has no block ids to share, and is left alone."""
         got = self.allocator.acquire(1)
         if got is None:
             return None
         dst = got[0]
-        for side, leaf in self.kv.items():
-            if is_quantized_leaf(leaf):
-                self.kv[side] = {
-                    "q": leaf["q"].at[:, dst].set(leaf["q"][:, src]),
-                    "scale": leaf["scale"].at[:, dst].set(
-                        leaf["scale"][:, src]),
-                }
-            else:
-                self.kv[side] = leaf.at[:, dst].set(leaf[:, src])
+        for side, layers in self.kv.items():
+            self.kv[side] = [
+                leaf if ring else jax.tree.map(
+                    lambda x: x.at[dst].set(x[src]), leaf)
+                for leaf, ring in zip(layers, self.ring)]
         return dst
-
-    def read_blocks(self, blocks: list[int], max_blocks: int,
-                    dtype=jnp.bfloat16) -> tuple[jax.Array, jax.Array]:
-        """Dense dequantized view of a block list, padded to a fixed
-        width: (k, v) each ``[L, max_blocks * bs, kvH, hd]``.  This is
-        the prefix-cache seeding path — a matched prompt prefix reads
-        its resident KV back into the [1, max_len] prefill temp cache
-        instead of recomputing it.  The fixed ``max_blocks`` width
-        (rows past the real blocks gather null-block garbage the
-        cursor/mask never admits before they are overwritten) keeps the
-        op's shape constant, so it compiles once per engine config."""
-        table = jnp.asarray(self.table_row(blocks, max_blocks), jnp.int32)
-        out = []
-        for side in ("k", "v"):
-            payload, scale = kv_leaf_parts(self.kv[side])
-            g = jnp.take(payload, table, axis=1)  # [L, MB, bs, H, hd]
-            if scale is not None:
-                g = (g.astype(jnp.float32)
-                     * jnp.take(scale, table, axis=1)).astype(dtype)
-            elif g.dtype != dtype:
-                g = g.astype(dtype)
-            L, MB, bs, H, hd = g.shape
-            out.append(g.reshape(L, MB * bs, H, hd))
-        return out[0], out[1]
 
     def table_row(self, blocks: list[int], max_blocks: int) -> list[int]:
         """Fixed-width table row: allocated ids then null padding."""
@@ -337,76 +427,16 @@ class PagedKVPool:
                 f"{len(blocks)} blocks exceed table width {max_blocks}")
         return list(blocks) + [NULL_BLOCK] * (max_blocks - len(blocks))
 
-    def write_prefill(self, blocks: list[int], k: jax.Array,
-                      v: jax.Array) -> None:
-        """Copy a dense prefill cache slice into allocated blocks.
-
-        ``k``/``v``: [L, P, kvH, hd] (the batch-1 prefill cache row,
-        squeezed) — or, in int8 mode, the already-quantized
-        ``{"q", "scale"}`` form of those rows: the chunked prefill
-        trace quantizes each chunk as it lands in the temp cache, and
-        committing those exact (q, scale) pairs (instead of
-        re-quantizing the dequantized rows) is what makes a
-        prefix-cache read-back bit-identical to the rows the original
-        prefill attended to.  P is right-padded with zeros to a whole
-        number of blocks here; the pad cells are dead until the decode
-        steps that overwrite them, and the mask excludes them
-        meanwhile.
-        """
-        if is_quantized_leaf(k) != is_quantized_leaf(v):
-            raise ValueError("k/v must both be dense or both quantized")
-        if is_quantized_leaf(k):
-            if not self.quantize:
-                raise ValueError(
-                    "quantized prefill rows into a dense pool")
-            L, P, H, hd = k["q"].shape
-        else:
-            L, P, H, hd = k.shape
-        n = len(blocks)
-        pad = n * self.block_size - P
-        if pad < 0:
-            raise ValueError(
-                f"{P} prefill tokens need "
-                f"{blocks_for_tokens(P, self.block_size)} blocks, "
-                f"got {n}")
-        idx = jnp.asarray(blocks, jnp.int32)
-
-        def blocked(x, fill=0):
-            x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)),
-                        constant_values=fill)
-            return x.reshape(L, n, self.block_size, H, x.shape[-1])
-
-        for side, rows in (("k", k), ("v", v)):
-            leaf = self.kv[side]
-            if is_quantized_leaf(rows):
-                self.kv[side] = {
-                    "q": leaf["q"].at[:, idx].set(blocked(rows["q"])),
-                    "scale": leaf["scale"].at[:, idx].set(
-                        blocked(rows["scale"], fill=1)),
-                }
-            elif self.quantize:
-                q = quantize_kv(blocked(rows))
-                self.kv[side] = {
-                    "q": leaf["q"].at[:, idx].set(q["q"]),
-                    "scale": leaf["scale"].at[:, idx].set(q["scale"]),
-                }
-            else:
-                self.kv[side] = leaf.at[:, idx].set(
-                    blocked(rows).astype(leaf.dtype))
-
-    def ship_prefill(self, blocks: list[int], k: jax.Array,
-                     v: jax.Array) -> int:
-        """``write_prefill`` plus block-transfer accounting — the
-        disaggregated engine's path for handing a finished prefill's KV
-        to the decode slice.  The payload is the same either way (the
-        pool write IS the transfer when both slices share one process);
-        what this adds is the metric: blocks and bytes shipped at pool
-        storage precision, i.e. what crosses the wire when prefill and
-        decode live on distinct mesh slices.  Returns the bytes moved.
-        """
-        self.write_prefill(blocks, k, v)
-        moved = len(blocks) * self.bytes_per_block
+    def record_ship(self, n_blocks: int) -> int:
+        """Block-transfer accounting of the disaggregated engine: a
+        finished prefill's ``n_blocks`` handed to the decode slice.  The
+        prefill program writes the pages where decode reads them (the pool
+        write IS the transfer when both slices share one process); what
+        this adds is the metric: blocks and bytes shipped at pool storage
+        precision, i.e. what crosses the wire when prefill and decode live
+        on distinct mesh slices.  Returns the bytes moved."""
+        moved = n_blocks * self.bytes_per_block
         self.n_transfers += 1
-        self.transferred_blocks += len(blocks)
+        self.transferred_blocks += n_blocks
         self.transferred_bytes += moved
         return moved

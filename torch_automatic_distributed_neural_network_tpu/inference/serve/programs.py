@@ -1,0 +1,450 @@
+"""The two serving programs: the decode step and the prefill chunk.
+
+Both walk the model's layers one by one (``transformer_core.layer_plan``:
+a model with one kind of layer is a plan of that one kind), each layer
+with its own window, rotation and FFN, and each with its own pair of pool
+arrays (``kv_pool``: pages for ``max_len``, or a ring on a
+``sliding_attention`` layer), so a call updates every layer's pages in
+place and copies none.  The per-layer math is the TRAINING modules applied
+piecewise, the single-source-of-truth discipline of
+``decode.forward_cached``: ``SelfAttention.qkv`` / ``out_proj``,
+``MLPBlock``, ``SparseMLP``, ``make_norm``.
+
+- The decode step takes a [S, T] token chunk for every slot: T == 1 is
+  plain one-token decode, T == 1 + k a speculative verify step.  Positions
+  and context lengths are PER-SLOT vectors (requests at different depths
+  share a step), KV goes through the paged pool, sampled tokens are masked
+  to 0 on inactive slots.
+- The prefill chunk takes [1, C] tokens of ONE slot's prompt at positions
+  ``pos0 ..``, writes their keys and values straight into the slot's pages
+  and attends through the table of its layer's kind, a block of keys at a
+  time from the first block its window reaches to the one it wrote.  The
+  pages are the only copy (a [1, max_len] cache a request would not fit
+  beside the weights at 16 slots of 13k positions), and a prefix the
+  radix index matched is read where it lies.
+
+A layer is traced once a kind, not once a layer: the walk calls one jitted
+function a (kind, FFN) pair, so a model of 24 like layers traces one.
+
+Host operands go up PACKED, one int32 array a call (``pack_step``,
+``pack_chunk``), and the step's tokens come back with its expert counters
+in one array: an upload costs about 0.4 ms on a v5e whatever its size.
+"""
+
+from __future__ import annotations
+
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ...models.transformer_core import (
+    MLPBlock,
+    SelfAttention,
+    SparseMLP,
+    TransformerConfig,
+    layer_plan,
+    make_norm,
+    rope,
+)
+from ...training.lora import LoraSpec, merge_lora
+from ..decode import (
+    SampleConfig,
+    _moe_mlp_cached,
+    _moe_mlp_routed,
+    _sample,
+    layer_params,
+)
+from ..quant import (
+    dequantize_leaf,
+    dequantize_tree,
+    embedding_lookup,
+    is_quantized_leaf,
+    kv_leaf_parts,
+)
+from .adapters import factor_rows
+from .kv_pool import gather_blocks, read_pages, ring_table, write_chunk, \
+    write_token
+
+_NEG_BIG = -0.7 * float(np.finfo(np.float32).max)
+KEY_BLOCK = 512  # keys a step of the chunk's attention takes
+
+
+def _embed(params, cfg: TransformerConfig, tok, positions):
+    x = embedding_lookup(params["embed"]["embedding"], tok, cfg.dtype)
+    if cfg.embed_scale:
+        x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
+    if cfg.pos == "learned":
+        x = x + params["pos_embed"].astype(cfg.dtype)[positions]
+    return x
+
+
+def _logits(params, cfg: TransformerConfig, x):
+    x = make_norm(cfg).apply({"params": params["final_norm"]}, x)
+    feats = x.astype(jnp.float32)
+    if cfg.tie_embeddings:
+        emb = params["embed"]["embedding"]
+        if is_quantized_leaf(emb):
+            emb = dequantize_leaf(emb, jnp.float32)
+        return feats @ emb.astype(jnp.float32).T
+    head = params["lm_head"]["kernel"]
+    if is_quantized_leaf(head):
+        head = dequantize_leaf(head, jnp.float32)
+    return feats @ head.astype(jnp.float32)
+
+
+def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
+           moe: str = "dense", adapted=None):
+    """One layer: ``attend(q, k, v)`` writes the new keys and values and
+    returns the attention output; everything else is shared by the chunk
+    and the step.  ``adapted(tensor, site, inp, rotate)`` adds a tenant's
+    low-rank delta at a projection (decode steps with tenants).  Returns
+    ``(x, the expert FFN's counters or None)``."""
+    dtype = cfg.dtype
+    norm = make_norm(cfg)
+    attn = SelfAttention(cfg, kind)
+    # int8 weight-only serving: only this layer's weights convert
+    lp = dequantize_tree(lp, dtype)
+    h = norm.apply({"params": lp["attn_norm"]}, x)
+    q, k, v = attn.apply({"params": lp["attn"]}, h, positions, method="qkv")
+    if adapted is not None:
+        hf = h.astype(jnp.float32)
+        q = adapted(q, "q", hf, cfg.layer_rotates(kind))
+        k = adapted(k, "k", hf, cfg.layer_rotates(kind))
+        v = adapted(v, "v", hf, False)
+    o = attend(q, k, v)
+    ao = attn.apply({"params": lp["attn"]}, o.astype(dtype), h,
+                    method="out_proj")
+    if adapted is not None:
+        ao = adapted(ao, "o", o.reshape(*o.shape[:2], -1).astype(
+            jnp.float32), False)
+    if cfg.sandwich_norm:
+        ao = norm.apply({"params": lp["post_attn_norm"]}, ao)
+    x = x + ao
+    h = norm.apply({"params": lp["mlp_norm"]}, x)
+    counters = None
+    if sparse:
+        h, counters = SparseMLP(cfg).apply({"params": lp["mlp"]}, h, valid)
+    elif "experts_up" in lp["mlp"]:  # the capacity-routed toy experts
+        h = (_moe_mlp_routed(lp["mlp"], h, cfg) if moe == "routed"
+             else _moe_mlp_cached(lp["mlp"], h, cfg))
+    else:
+        h = MLPBlock(cfg).apply({"params": lp["mlp"]}, h)
+    if cfg.sandwich_norm:
+        h = norm.apply({"params": lp["post_mlp_norm"]}, h)
+    return x + h, counters
+
+
+def _pages_of(kind: str | None) -> str:
+    """Which of a call's tables a layer of ``kind`` reads: the ring of a
+    ``sliding_attention`` layer, or the request's pages for ``max_len``."""
+    return "ring" if kind == "sliding_attention" else "pages"
+
+
+def _moe_counters(stats: list) -> jax.Array:
+    """[pairs that landed here, experts touched, most tokens on one expert]
+    over the step's expert layers (zeros for a model without any)."""
+    stats = [s for s in stats if s is not None]
+    if not stats:
+        return jnp.zeros((3,), jnp.int32)
+    return jnp.stack([
+        sum(s["pairs"] for s in stats),
+        sum(s["experts_touched"] for s in stats),
+        jnp.max(jnp.stack([s["max_expert_tokens"] for s in stats]))])
+
+
+def _walk(cfg, params, kv, x, layer_fn, shared, extras=None):
+    """``layer_fn(kind, sparse)(lp, k_pages, v_pages, x, extra, shared)``
+    over the plan, one jitted function a (kind, FFN) pair so that like
+    layers are traced once; ``shared`` is what every layer reads (tables,
+    positions), ``extras`` one more operand a layer (a tenant's factors).
+    Returns ``(x, kv, the layers' counters)``."""
+    fns, new_k, new_v, stats = {}, [], [], []
+    for i, (name, kind, sparse) in enumerate(layer_plan(cfg)):
+        fn = fns.get((kind, sparse))
+        if fn is None:
+            fn = fns[kind, sparse] = jax.jit(layer_fn(kind, sparse))
+        x, k_l, v_l, counters = fn(
+            layer_params(params, name), kv["k"][i], kv["v"][i], x,
+            None if extras is None else extras[i], shared)
+        new_k.append(k_l)
+        new_v.append(v_l)
+        stats.append(counters)
+    return x, {"k": new_k, "v": new_v}, stats
+
+
+def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
+                  adapters=None, adapter_ids=None, *,
+                  cfg: TransformerConfig, attention_impl: str = "paged",
+                  lora_scaling: float = 1.0, mesh=None, spec=None):
+    """A [S, T] token chunk for every slot (position t attends keys
+    0..ctx+t, exactly the sequential semantics).  Static shapes throughout
+    (S slots, T chunk, tables [S, max_blocks]) so each engine configuration
+    traces exactly once.  ``kv`` is ``{"k": [a layer's pages, ...], "v":
+    ...}``, ``tables`` the block tables of the layers that keep pages for
+    ``max_len``, ``win_tables`` [S, W] the sliding layers' rings.
+
+    ``attention_impl`` picks the per-layer KV read:
+
+    - ``"paged"`` (default): the fused Pallas kernel
+      (ops/paged_attention.py) reads the block table in-kernel — the
+      dense gathered view never materializes; single-query only, so T > 1
+      verify steps take the dense path below;
+    - ``"dense"``: the reference path — ``gather_blocks`` to a dense
+      [S, max_len] view, then stock ``xla_attention`` under an explicit
+      mask.  Kept as the parity oracle and the fallback.
+
+    ``adapters`` is the AdapterPool's factor pytree (None or {} when
+    serving the base model only): per q/k/v/o site, stacked ``a [L, A,
+    d_in, r]`` / ``b [L, A, r, d_out]`` factors.  Each slot gathers its
+    ``adapter_ids`` row and adds the segmented low-rank delta ``scaling *
+    (x @ A) @ B`` to that projection's output — slot 0 holds zero factors
+    (IDENTITY_ADAPTER), so base-model slots pay one gather of zeros
+    instead of a second trace.  q/k deltas are rope-rotated like the
+    projections they perturb (rope is linear, so rotating the delta IS the
+    merged-weight semantics).
+
+    Returns ``(kv, logits [S, T, V], moe counters [3])``."""
+    from ...ops.attention import xla_attention
+    from ...ops.paged_attention import paged_attention
+
+    S, T = tok.shape
+    MB = tables.shape[1]
+    bs = kv_leaf_parts(kv["k"][0])[0].shape[1]
+    if mesh is not None and spec is not None:
+        from jax.sharding import NamedSharding
+
+        sh = NamedSharding(mesh, spec)
+        kv = jax.tree.map(
+            lambda a: jax.lax.with_sharding_constraint(a, sh), kv)
+    # a layer's table holds the null block wherever it has no key to read
+    # (an unchanged block index is not copied again): past the newest key,
+    # and in a ring before the oldest its window still reaches
+    hi = jnp.where(active, (ctx_lens + T - 1) // bs, -1)
+    full = jnp.where(jnp.arange(MB)[None, :] <= hi[:, None], tables, 0)
+    shared = {"tables": {"pages": full}, "ctx_lens": ctx_lens,
+              "active": active, "adapter_ids": adapter_ids}
+    if win_tables.shape[1]:
+        lo = (ctx_lens - cfg.sliding_window + 1) // bs
+        shared["tables"]["ring"] = ring_table(win_tables, MB, lo, hi)
+
+    def layer_fn(kind, sparse):
+        window = cfg.layer_window(kind)
+
+        def fn(lp, k_l, v_l, x, ad, shared):
+            table = shared["tables"][_pages_of(kind)]
+            ctx_lens, adapter_ids = shared["ctx_lens"], shared["adapter_ids"]
+            positions = ctx_lens[:, None] + jnp.arange(T)[None, :]  # [S, T]
+            key_idx = jnp.arange(MB * bs)[None, None, :]
+
+            def attend(q, k, v):
+                nonlocal k_l, v_l
+                for t in range(T):  # static and small (1 + draft length)
+                    k_l = write_token(k_l, table, ctx_lens + t, k[:, t])
+                    v_l = write_token(v_l, table, ctx_lens + t, v[:, t])
+                if attention_impl == "paged" and T == 1:
+                    return paged_attention(
+                        q[:, 0], k_l, v_l, table, ctx_lens, window=window,
+                        mesh=mesh)[:, None]
+                # chunk position t writes at positions[s, t] then attends
+                # keys 0..positions[s, t] inclusive — the causal triangle
+                # across the chunk plus the context below it; table padding
+                # beyond a slot's blocks gathers null-block garbage this
+                # never admits
+                mask = key_idx <= positions[:, :, None]
+                if window is not None:
+                    mask &= key_idx > positions[:, :, None] - window
+                kd = gather_blocks(k_l, table, cfg.dtype, cfg.kv_heads)
+                vd = gather_blocks(v_l, table, cfg.dtype, cfg.kv_heads)
+                return xla_attention(q, kd, vd, causal=False,
+                                     mask=mask[:, None])
+
+            adapted = None
+            if ad:
+                def adapted(tensor, site, inp, rotate):
+                    if site not in ad:
+                        return tensor
+                    a = factor_rows(ad[site]["a"], adapter_ids)  # [S,d_in,r]
+                    b = factor_rows(ad[site]["b"], adapter_ids)  # [S,r,d_out]
+                    d = lora_scaling * jnp.einsum(
+                        "str,sro->sto", jnp.einsum("std,sdr->str", inp, a), b)
+                    d = d.reshape(tensor.shape)
+                    if rotate:
+                        d = rope(d, positions, cfg.rope_theta)
+                    return (tensor.astype(jnp.float32) + d).astype(
+                        tensor.dtype)
+
+            x, counters = _layer(cfg, lp, kind, sparse, x, positions,
+                                 jnp.broadcast_to(
+                                     shared["active"][:, None], (S, T)),
+                                 attend, adapted=adapted)
+            return x, k_l, v_l, counters
+
+        return fn
+
+    extras = None
+    if adapters:  # one layer's factors a layer
+        extras = [jax.tree.map(lambda a: a[i], adapters)
+                  for i in range(cfg.n_layers)]
+    # per-slot, per-chunk-offset absolute positions
+    x = _embed(params, cfg, tok, ctx_lens[:, None] + jnp.arange(T)[None, :])
+    x, kv, stats = _walk(cfg, params, kv, x, layer_fn, shared, extras)
+    return kv, _logits(params, cfg, x), _moe_counters(stats)
+
+
+def pack_step(tables, ctx_lens, tok, active, adapter_ids) -> np.ndarray:
+    """A decode step's host operands as ONE int32 array [S, MB + T + 3]
+    (tables, then the tokens, the context length, the active flag and the
+    adapter id of each slot): one upload a step where five cost 0.4 ms
+    each."""
+    return np.concatenate(
+        [tables, tok, ctx_lens[:, None], active[:, None],
+         adapter_ids[:, None]], axis=1).astype(np.int32)
+
+
+def decode_step(params, kv, packed, win_tables, adapters, rng, *,
+                cfg: TransformerConfig, sample: SampleConfig, n_tok: int = 1,
+                **kw):
+    """``decode_logits`` on the operands of ``pack_step`` (``n_tok`` is its
+    T).  Returns ``(kv, [S * T + 3] int32)``: the step's sampled tokens [S]
+    (T == 1), or the target's greedy choices [S, T] flattened (verify steps
+    are temperature-0 by contract — sampled speculative needs rejection
+    resampling), and then the step's expert counters: one array, so that
+    one fetch brings both."""
+    MB = packed.shape[1] - n_tok - 3
+    tables, tok = packed[:, :MB], packed[:, MB:MB + n_tok]
+    ctx_lens, active = packed[:, -3], packed[:, -2] > 0
+    kv, logits, counters = decode_logits(
+        params, kv, tables, win_tables, ctx_lens, tok, active, adapters,
+        packed[:, -1], cfg=cfg, **kw)
+    if n_tok == 1:
+        out = jnp.where(active, _sample(logits[:, 0], rng, sample), 0)
+    else:  # the all-logits discipline of decode.generate
+        tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, T]
+        out = jnp.where(active[:, None], tgt, 0).reshape(-1)
+    return kv, jnp.concatenate([out, counters])
+
+
+def chunk_attention(q, k_layer, v_layer, table_row, pos0, window,
+                    kv_heads: int):
+    """Causal (banded, with ``window``) attention of a chunk's queries
+    ``q`` [C, H, hd] at positions ``pos0 .. pos0 + C`` over one slot's
+    pages, the chunk's own keys already written.  Keys come a block of
+    ``KEY_BLOCK`` at a time through the table, from the first block the
+    window reaches to the chunk's last, with the online softmax of
+    ``ops/flash_attention.py``: the work follows the context a request has,
+    not ``max_len``."""
+    C, H, hd = q.shape
+    bs = kv_leaf_parts(k_layer)[0].shape[1]
+    KV = kv_heads
+    G = H // KV
+    ppb = max(1, KEY_BLOCK // bs)
+    KB = ppb * bs
+    # whole key blocks up to the last a chunk may reach (the null block
+    # past the row's end: those keys lie after every query)
+    n_kb = -(-(table_row.shape[0] * bs + C) // KB)
+    table_row = jnp.pad(table_row, (0, n_kb * ppb - table_row.shape[0]))
+    qg = q.reshape(C, KV, G, hd)
+    q_pos = pos0 + jnp.arange(C)
+    scale = 1.0 / float(np.sqrt(hd))
+
+    def body(jb, carry):
+        acc, m, l = carry
+        pages = jax.lax.dynamic_slice_in_dim(table_row, jb * ppb, ppb)
+        kb = read_pages(k_layer, pages, KV, q.dtype).reshape(KB, KV, hd)
+        vb = read_pages(v_layer, pages, KV, q.dtype).reshape(KB, KV, hd)
+        s = jnp.einsum("ckgd,tkd->kgct", qg, kb,
+                       preferred_element_type=jnp.float32) * scale
+        key_pos = jb * KB + jnp.arange(KB)
+        ok = key_pos[None, :] <= q_pos[:, None]
+        if window is not None:
+            ok &= key_pos[None, :] > q_pos[:, None] - window
+        s = jnp.where(ok[None, None], s, _NEG_BIG)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.where(ok[None, None], jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + p.sum(-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "kgct,tkd->kgcd", p.astype(vb.dtype), vb,
+            preferred_element_type=jnp.float32)
+        return acc, m_new, l
+
+    lo = 0 if window is None else jnp.maximum(pos0 - window + 1, 0) // KB
+    hi = (pos0 + C - 1) // KB
+    acc, _, l = jax.lax.fori_loop(
+        lo, hi + 1, body,
+        (jnp.zeros((KV, G, C, hd), jnp.float32),
+         jnp.full((KV, G, C), _NEG_BIG, jnp.float32),
+         jnp.zeros((KV, G, C), jnp.float32)))
+    o = acc / l[..., None]  # every row sees at least its own key
+    return o.transpose(2, 0, 1, 3).reshape(C, H, hd).astype(q.dtype)
+
+
+def pack_chunk(table_row, tokens, pos0: int, last_idx: int) -> np.ndarray:
+    """A prefill chunk's host operands as ONE int32 vector [MB + C + 2]:
+    the slot's table row, the chunk's tokens, its first position and its
+    last real row."""
+    return np.asarray([*table_row, *tokens, pos0, last_idx], np.int32)
+
+
+def prefill_chunk(params, kv, packed, win_row, *, cfg: TransformerConfig,
+                  max_blocks: int, moe_decode: str = "dense"):
+    """One [1, C] chunk of one slot's prompt (operands of ``pack_chunk``;
+    C is what ``packed`` holds beyond ``max_blocks + 2``) at positions
+    ``pos0 ..``, written into the slot's pages (the table row [max_blocks]
+    on the layers that keep pages for ``max_len``, the ring ``win_row`` [W]
+    on the sliding ones).  Every chunk of every prompt reuses ONE jitted
+    trace: the chunk length is constant and both cursors are traced
+    scalars.  The last chunk of a prompt may be right-padded: ``last_idx``
+    is its last real row, whose logits are returned ([1, V]); causal
+    masking keeps the pad rows (which sit after it) out of that row, they
+    route to no expert, and the keys they write lie past the prompt, where
+    decode writes over them before it reads.  An int8 pool quantizes the
+    chunk as it lands, so later chunks, decode and any request that reuses
+    these pages through the prefix cache all read the same (q, scale)
+    pairs.  Returns ``(kv, logits)``."""
+    MB = max_blocks
+    C = packed.shape[0] - MB - 2
+    table_row, tokens = packed[:MB], packed[MB:MB + C][None]
+    pos0, last_idx = packed[-2], packed[-1]
+    shared = {"rows": {"pages": table_row}, "pos0": pos0,
+              "last_idx": last_idx}
+    if win_row.shape[0]:
+        shared["rows"]["ring"] = win_row[jnp.arange(MB) % win_row.shape[0]]
+
+    def layer_fn(kind, sparse):
+        window = cfg.layer_window(kind)
+
+        def fn(lp, k_l, v_l, x, _, shared):
+            row, pos0 = shared["rows"][_pages_of(kind)], shared["pos0"]
+            positions = pos0 + jnp.arange(C)[None, :]
+            valid = (jnp.arange(C) <= shared["last_idx"])[None, :]
+
+            def attend(q, k, v):
+                nonlocal k_l, v_l
+                k_l = write_chunk(k_l, row, pos0, k[0])
+                v_l = write_chunk(v_l, row, pos0, v[0])
+                return chunk_attention(q[0], k_l, v_l, row, pos0, window,
+                                       cfg.kv_heads)[None]
+
+            x, _counters = _layer(cfg, lp, kind, sparse, x, positions,
+                                  valid, attend, moe=moe_decode)
+            return x, k_l, v_l, None
+
+        return fn
+
+    x = _embed(params, cfg, tokens, pos0 + jnp.arange(C)[None, :])
+    x, kv, _ = _walk(cfg, params, kv, x, layer_fn, shared)
+    last = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
+    return kv, _logits(params, cfg, last)
+
+
+def prefill_chunk_lora(params, lora, kv, packed, win_row, *,
+                       lora_spec: LoraSpec, **kw):
+    """A tenant's prefill chunk through per-tenant merged weights:
+    ``merge_lora`` runs INSIDE the jit (the rank-r matmul fuses into the
+    weight load), so ONE trace serves every tenant — the factor tree is a
+    traced operand and the merged weights never materialize on the
+    host."""
+    return prefill_chunk(merge_lora(params, lora, lora_spec), kv, packed,
+                         win_row, **kw)

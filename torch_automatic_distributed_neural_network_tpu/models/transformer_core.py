@@ -27,6 +27,7 @@ import numpy as np
 
 from ..ops.attention import attention
 from ..parallel.context import shard_activations
+from ..parallel.expert import held_expert_ffn, route_top_k
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,8 +76,67 @@ class TransformerConfig:
     # difference between fitting and OOMing GPT-2 1.3B on one 16 GB chip.
     remat_policy: Literal["dots", "nothing"] = "dots"
     rope_theta: float = 10000.0
+    # -- layer kinds as data: a model whose layers differ -------------------
+    # One entry a layer, "sliding_attention" (the causal band of
+    # ``sliding_window``) or "full_attention" (plain causal).  Given, the
+    # layers are built one by one (``layers_0`` .. in the parameter tree, no
+    # ``nn.scan``), each of its own kind, and the keys below apply; None
+    # keeps the one scanned layer every other model here has.
+    layer_types: tuple[str, ...] | None = None
+    # which layers rotate q and k when ``pos == "rope"``: "sliding" leaves
+    # the full-attention layers without any positional rotation
+    rope_layers: Literal["all", "sliding"] = "all"
+    head_size: int | None = None  # None -> d_model // n_heads
+    qk_norm: bool = False  # RMSNorm over head_dim on q and k, before rope
+    attn_gate: bool = False  # out = (attn * sigmoid(x Wg)) Wo
+    sandwich_norm: bool = False  # a norm after attention and after the FFN
+    embed_scale: bool = False  # h0 = E[tok] * sqrt(d_model)
+    # the first ``n_dense_layers`` layers have a dense FFN of ``d_ff``, the
+    # others the expert FFN below; None -> every layer is dense
+    n_dense_layers: int | None = None
+    experts_published: int = 0  # the router's width
+    experts_held: int | None = None  # held here (None -> all of them) ...
+    first_expert: int = 0  # ... starting at this one
+    experts_per_token: int = 1
+    shared_experts: int = 0
+    expert_d_ff: int | None = None  # width of one expert (and a shared one)
+    score_func: Literal["sigmoid", "softmax"] = "sigmoid"
+    route_norm: bool = True  # weights of the chosen experts sum to 1 ...
+    route_scale: float = 1.0  # ... times this
 
     def __post_init__(self):
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            bad = set(self.layer_types) - {"sliding_attention",
+                                           "full_attention"}
+            if bad or len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types needs n_layers={self.n_layers} entries of "
+                    f"'sliding_attention' / 'full_attention', got "
+                    f"{self.layer_types}")
+            if ("sliding_attention" in self.layer_types
+                    and self.sliding_window is None):
+                raise ValueError("a sliding_attention layer needs "
+                                 "sliding_window")
+            if self.n_expert_layers and not (
+                    0 <= self.first_expert
+                    and self.first_expert + self.n_experts_held
+                    <= self.experts_published
+                    and self.experts_per_token <= self.experts_published):
+                raise ValueError(
+                    f"experts {self.first_expert}.."
+                    f"{self.first_expert + self.n_experts_held} held, "
+                    f"{self.experts_per_token} a token, of "
+                    f"{self.experts_published} published")
+        else:
+            given = [k for k in ("qk_norm", "attn_gate", "sandwich_norm",
+                                 "embed_scale", "head_size",
+                                 "n_dense_layers", "experts_published")
+                     if getattr(self, k)]
+            if given:
+                raise ValueError(
+                    f"{given} describe a model built layer by layer: give "
+                    f"layer_types too (one kind a layer)")
         if self.sliding_window is not None:
             if not self.causal:
                 raise ValueError(
@@ -95,7 +155,29 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
+
+    @property
+    def n_expert_layers(self) -> int:
+        if self.layer_types is None or self.n_dense_layers is None:
+            return 0
+        return self.n_layers - self.n_dense_layers
+
+    @property
+    def n_experts_held(self) -> int:
+        return (self.experts_published if self.experts_held is None
+                else self.experts_held)
+
+    def layer_window(self, kind: str | None) -> int | None:
+        """The causal band of a layer of ``kind`` (None: the model's one
+        kind of layer)."""
+        if kind in (None, "sliding_attention"):
+            return self.sliding_window
+        return None
+
+    def layer_rotates(self, kind: str | None) -> bool:
+        return self.pos == "rope" and (
+            kind in (None, "sliding_attention") or self.rope_layers == "all")
 
     @property
     def ff_dim(self) -> int:
@@ -108,11 +190,23 @@ class TransformerConfig:
         return 4 * self.d_model
 
     def num_params(self) -> int:
-        """Analytic parameter count (embedding included once if tied)."""
+        """Analytic parameter count (embedding included once if tied);
+        for a model with ``layer_types``, of what is HELD here."""
         d, f, L, v = self.d_model, self.ff_dim, self.n_layers, self.vocab_size
         hd = self.head_dim
         attn = d * (self.n_heads * hd) + 2 * d * (self.kv_heads * hd) + (
             self.n_heads * hd) * d
+        if self.layer_types is not None:
+            attn += (d * self.n_heads * hd if self.attn_gate else 0) + (
+                2 * hd if self.qk_norm else 0)
+            norms = (4 if self.sandwich_norm else 2) * d
+            fe, E = self.expert_d_ff, self.experts_published
+            sparse = (d * E + E + 3 * d * fe * (
+                self.n_experts_held + self.shared_experts))
+            n_sparse = self.n_expert_layers
+            return ((L - n_sparse) * (attn + norms + 3 * d * f)
+                    + n_sparse * (attn + norms + sparse)
+                    + v * d * (1 if self.tie_embeddings else 2) + d)
         mlp = (3 if self.act == "swiglu" else 2) * d * f
         norms = (2 * d) * L + (d if self.final_norm else 0) + (
             d if self.embed_norm else 0)
@@ -144,9 +238,14 @@ class SelfAttention(nn.Module):
     """setup()-style so the decode path (inference/decode.py) can apply
     the q/k/v and output projections piecewise (``method='qkv'`` /
     ``method='out_proj'``) against a KV cache — ONE implementation of the
-    projection + rope math for train and decode."""
+    projection + rope math for train and decode.
+
+    ``kind`` is the layer's entry of ``cfg.layer_types`` (None where the
+    model has one kind of layer): it decides the window and whether q and
+    k are rotated."""
 
     cfg: TransformerConfig
+    kind: str | None = None
 
     def setup(self):
         cfg = self.cfg
@@ -158,30 +257,43 @@ class SelfAttention(nn.Module):
         self.q_proj = dense((cfg.n_heads, hd))
         self.k_proj = dense((cfg.kv_heads, hd))
         self.v_proj = dense((cfg.kv_heads, hd))
+        if cfg.qk_norm:
+            self.q_norm = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)
+            self.k_norm = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)
+        if cfg.attn_gate:
+            self.gate_proj = dense((cfg.n_heads, hd))
         self.o_proj = nn.DenseGeneral(
             cfg.d_model, axis=(-2, -1), dtype=cfg.dtype, use_bias=bias
         )
 
     def qkv(self, x, positions):
-        """Projected (and rope-rotated) q/k/v for a chunk at ``positions``."""
+        """Projected (normed, rope-rotated) q/k/v for a chunk at
+        ``positions``."""
         cfg = self.cfg
         q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
-        if cfg.pos == "rope":
+        if cfg.qk_norm:
+            q, k = self.q_norm(q), self.k_norm(k)
+        if cfg.layer_rotates(self.kind):
             q = rope(q, positions, cfg.rope_theta)
             k = rope(k, positions, cfg.rope_theta)
         return q, k, v
 
-    def out_proj(self, out):
+    def out_proj(self, out, x=None):
+        """``x`` is the layer's normed input, which the output gate reads
+        (``cfg.attn_gate``)."""
+        if self.cfg.attn_gate:
+            gate = nn.sigmoid(self.gate_proj(x).astype(jnp.float32))
+            out = (out.astype(jnp.float32) * gate).astype(out.dtype)
         return self.o_proj(out)
 
     def __call__(self, x, positions, mask=None):
         q, k, v = self.qkv(x, positions)
         out = attention(
             q, k, v, causal=self.cfg.causal,
-            window=self.cfg.sliding_window,
+            window=self.cfg.layer_window(self.kind),
             mask=mask, impl=self.cfg.attention_impl,
         )
-        return self.out_proj(out)
+        return self.out_proj(out, x)
 
 
 class MLPBlock(nn.Module):
@@ -189,14 +301,16 @@ class MLPBlock(nn.Module):
     — the gelu/SwiGLU feed-forward math lives here and only here."""
 
     cfg: TransformerConfig
+    width: int | None = None  # None -> cfg.ff_dim (a shared expert's differs)
 
     def setup(self):
         cfg = self.cfg
         bias = cfg.norm == "layernorm"
         dense = lambda feats: nn.Dense(feats, dtype=cfg.dtype, use_bias=bias)
+        width = self.width or cfg.ff_dim
         if cfg.act == "swiglu":
-            self.gate_proj = dense(cfg.ff_dim)
-        self.up_proj = dense(cfg.ff_dim)
+            self.gate_proj = dense(width)
+        self.up_proj = dense(width)
         self.down_proj = dense(cfg.d_model)
 
     def __call__(self, x):
@@ -206,6 +320,111 @@ class MLPBlock(nn.Module):
             h = nn.gelu(self.up_proj(x),
                         approximate=self.cfg.act != "gelu_exact")
         return self.down_proj(h)
+
+
+class Router(nn.Module):
+    """Scores over ALL the published experts, in float32 (a chip that holds
+    a share of the experts still routes over every one of them), and the
+    per-expert bias that takes part in the choice and never in the weight."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        kernel = self.param("kernel", nn.initializers.normal(0.02),
+                            (cfg.d_model, cfg.experts_published), jnp.float32)
+        e_bias = self.param("e_bias", nn.initializers.zeros,
+                            (cfg.experts_published,), jnp.float32)
+        logits = jnp.dot(x.astype(jnp.float32), kernel,
+                         precision=jax.lax.Precision.HIGHEST)
+        return route_top_k(
+            logits, e_bias, cfg.experts_per_token, score_func=cfg.score_func,
+            route_norm=cfg.route_norm, route_scale=cfg.route_scale)
+
+
+class SparseMLP(nn.Module):
+    """The expert FFN of a model built layer by layer: shared expert(s)
+    plus the chosen routed experts, no capacity and no dropped token
+    (``parallel/expert.held_expert_ffn``).  It holds experts
+    ``first_expert .. first_expert + experts_held`` of the published ones
+    and adds what those give; on one chip there is no exchange.
+
+    ``valid`` [..., tokens] marks the rows that are real (a padded chunk's
+    tail and an empty slot route nowhere, so they read no expert).  Returns
+    ``(y, stats)``: the step counters of ``held_expert_ffn``."""
+
+    cfg: TransformerConfig
+
+    def setup(self):
+        cfg = self.cfg
+        held, d, f = cfg.n_experts_held, cfg.d_model, cfg.expert_d_ff
+        init = nn.initializers.normal(0.02)
+        self.router = Router(cfg)
+        self.experts_gate = self.param("experts_gate", init, (held, d, f),
+                                       jnp.float32)
+        self.experts_up = self.param("experts_up", init, (held, d, f),
+                                     jnp.float32)
+        self.experts_down = self.param("experts_down", init, (held, f, d),
+                                       jnp.float32)
+        if cfg.shared_experts:
+            self.shared = MLPBlock(cfg, width=cfg.shared_experts * f)
+
+    def __call__(self, x, valid=None):
+        cfg = self.cfg
+        lead = x.shape[:-1]
+        rows = x.reshape(-1, x.shape[-1])
+        chosen, weights = self.router(rows)
+        cast = lambda w: w.astype(cfg.dtype)
+        y, stats = held_expert_ffn(
+            rows, chosen, weights, cast(self.experts_gate),
+            cast(self.experts_up), cast(self.experts_down),
+            first_expert=cfg.first_expert,
+            valid=None if valid is None else valid.reshape(-1))
+        y = y.reshape(*lead, -1)
+        if cfg.shared_experts:
+            y = y + self.shared(x)
+        return y, stats
+
+
+class KindDecoderLayer(nn.Module):
+    """One layer of a model whose layers differ (``cfg.layer_types``):
+    attention of this layer's ``kind``, a dense or an expert FFN, and with
+    ``cfg.sandwich_norm`` a norm after each sublayer as well as before."""
+
+    cfg: TransformerConfig
+    kind: str
+    sparse: bool
+
+    @nn.compact
+    def __call__(self, x, positions, mask=None):
+        cfg = self.cfg
+        h = make_norm(cfg, "attn_norm")(x)
+        h = SelfAttention(cfg, self.kind, name="attn")(h, positions, mask)
+        if cfg.sandwich_norm:
+            h = make_norm(cfg, "post_attn_norm")(h)
+        x = x + h
+        h = make_norm(cfg, "mlp_norm")(x)
+        if self.sparse:
+            h, _ = SparseMLP(cfg, name="mlp")(h)
+        else:
+            h = MLPBlock(cfg, name="mlp")(h)
+        if cfg.sandwich_norm:
+            h = make_norm(cfg, "post_mlp_norm")(h)
+        return x + h
+
+
+def layer_plan(cfg: TransformerConfig) -> list[tuple[str, str | None, bool]]:
+    """(parameter name, kind, has an expert FFN) of every layer, in order.
+    A model with one kind of layer (no ``layer_types``) is a plan of that one
+    kind, None: its ``layers_i`` is layer ``i`` of the scanned ``layers``
+    stack (``inference/decode.layer_params``)."""
+    if cfg.layer_types is None:
+        return [(f"layers_{i}", None, False) for i in range(cfg.n_layers)]
+    n_dense = (cfg.n_layers if cfg.n_dense_layers is None
+               else cfg.n_dense_layers)
+    return [(f"layers_{i}", kind, i >= n_dense)
+            for i, kind in enumerate(cfg.layer_types)]
 
 
 class DecoderLayer(nn.Module):
@@ -316,6 +535,8 @@ def apply_decoder_backbone(
             cfg.type_vocab_size, cfg.d_model, dtype=cfg.dtype,
             embedding_init=nn.initializers.normal(0.02), name="seg_embed",
         )(segment_ids)
+    if cfg.embed_scale:
+        x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
     if cfg.embed_norm:
         x = make_norm(cfg, "embed_norm")(x)
     x = shard_activations(x)
@@ -340,7 +561,12 @@ def apply_decoder_backbone(
         return out, aux_acc
 
     aux_total = jnp.zeros((), jnp.float32)
-    if cfg.scan_layers:
+    if cfg.layer_types is not None:
+        # layers that differ: one module a layer, each of its own kind
+        for name, kind, sparse in layer_plan(cfg):
+            x = KindDecoderLayer(cfg, kind, sparse, name=name)(
+                x, positions, mask)
+    elif cfg.scan_layers:
         def body(mdl, carry, _):
             return run_layer(mdl, *carry), None
 

@@ -368,6 +368,17 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
             "weight_bytes_compute": (sengine or {}).get(
                 "weight_bytes_compute"),
             "weight_bytes_fp32": (sengine or {}).get("weight_bytes_fp32"),
+            # layer kinds, the experts held and the pool by kind of page
+            **{k: (sengine or {}).get(k) for k in (
+                "layer_kinds", "experts_held", "experts_published",
+                "kv_bytes_full", "kv_bytes_window")},
+            # the decode steps' expert counters (engines with experts)
+            "mean_moe_pairs": _mean(e.get("moe_pairs") for e in ssteps),
+            "mean_moe_experts_touched": _mean(
+                e.get("moe_experts_touched") for e in ssteps),
+            "max_moe_expert_tokens": max(
+                _finite(e.get("moe_max_expert_tokens") for e in ssteps),
+                default=None),
             # disaggregated / sharded serving (r04 fields)
             "mode": (ssteps[-1].get("mode") if ssteps else None),
             "tp": (sengine or {}).get("tp"),
@@ -970,6 +981,24 @@ def format_report(report: dict) -> str:
                 f" GiB float32 ({sv['weights_cast']} leaves rounded once)")
         if bparts:
             lines.append("  " + "  ".join(bparts))
+        if sv.get("kv_bytes_full") is not None:
+            kinds = sv.get("layer_kinds") or ()
+            eparts = [
+                f"KV pool {sv['kv_bytes_full'] / 2**30:.2f} GiB in pages "
+                f"for max_len + {sv['kv_bytes_window'] / 2**30:.2f} GiB in "
+                f"window rings"
+                + (f" ({kinds.count('full_attention')} full, "
+                   f"{kinds.count('sliding_attention')} sliding layers)"
+                   if kinds else "")]
+            if sv.get("experts_published"):
+                eparts.append(f"experts {sv['experts_held']} held of "
+                              f"{sv['experts_published']}")
+            if sv.get("mean_moe_pairs") is not None:
+                eparts.append(
+                    f"a decode step: {sv['mean_moe_pairs']:.1f} pairs here "
+                    f"on {sv['mean_moe_experts_touched']:.1f} experts, at "
+                    f"most {sv['max_moe_expert_tokens']} tokens on one")
+            lines.append("  " + "  ".join(eparts))
         if sv.get("step_phase_mean_s"):
             lines.append(
                 "  step phases (mean ms, host): " + " ".join(

@@ -338,7 +338,15 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
        # the compute dtype at construction, and its bytes in that dtype
        # and in float32 (int8 leaves are in neither)
        opt={"weights_cast": "int", "weight_bytes_compute": "int",
-            "weight_bytes_fp32": "int"}),
+            "weight_bytes_fp32": "int",
+            # a model whose layers differ: each layer's kind (None for
+            # one scanned layer), the experts held of those published
+            # (0 without expert layers), and the pool's bytes by kind of
+            # page: pages for max_len a slot, and the sliding layers'
+            # rings (0 where every layer keeps max_len)
+            "layer_kinds": "list?", "experts_held": "int",
+            "experts_published": "int", "kv_bytes_full": "int",
+            "kv_bytes_window": "int"}),
     _s("serve.step", "one serving iteration (engine or gateway "
        "SimReplica)",
        req={"n_active": "int", "n_queued": "int", "new_tokens": "int",
@@ -357,7 +365,13 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # process, not an engine: another engine's or a thread's
             # compile in that interval is counted here too)
             "phases": "dict", "step_s": "float", "t_end": "float",
-            "n_prefill_chunks": "int", "compiles": "int"}),
+            "n_prefill_chunks": "int", "compiles": "int",
+            # a decode step's expert layers, summed: (token, expert)
+            # pairs that landed on the experts held here, experts that
+            # got any, and the most tokens one expert got; they come
+            # back with the step's tokens
+            "moe_pairs": "int", "moe_experts_touched": "int",
+            "moe_max_expert_tokens": "int"}),
     _s("serve.request_done", "per-request completion span with the "
        "full phase-attributed timeline", version=2,
        req={"rid": "int", "n_prompt": "int", "n_new": "int",

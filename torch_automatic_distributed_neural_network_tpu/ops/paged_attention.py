@@ -13,11 +13,20 @@ actually cached), the same bytes the pool stores.
 Shape of the problem (one decode token per slot):
 
     q:      [S, Hq, hd]          one query per slot
-    k/v:    [NB, bs, kvH, hd]    ONE layer of the paged pool
+    k/v:    [NB, bs, kvH * hd]   ONE layer of the paged pool, pages folded
+            [NB, bs, kvH, hd]    (int8 pools and pools sharded over a mesh)
     tables: [S, MB] int32        block ids, null-padded (kv_pool)
     ctx:    [S] int32            keys 0..ctx inclusive are valid
 
-Grid is ``(S, MB)`` with the block axis innermost ("arbitrary"
+ONE entry point, :func:`paged_attention`, and two kernels chosen by the
+shape of a page, not by the model.  A pool of folded pages is read by the
+MXU kernel (``tadnn_paged_decode_folded``, further down: grouped queries
+as two plain matmuls, 8 pages a grid step, the grid over a window's band
+only).  A page kept as ``[bs, kvH, hd]`` is read by the VPU kernel
+(``tadnn_paged_decode``), which dequantizes int8 pages on load and runs
+per shard under ``shard_map``; the rest of this text describes it.
+
+Its grid is ``(S, MB)`` with the block axis innermost ("arbitrary"
 semantics); a grid step takes one whole page, every kv head of it (the
 block shape the TPU lowering accepts — it refuses one head sliced out
 of the second-minor dimension).  VMEM scratch carries flash-style
@@ -171,8 +180,9 @@ def paged_attention(
     """Fused paged decode attention over one layer of the KV pool.
 
     ``q``: [S, Hq, hd] (one decode token per slot); ``k_pool``/``v_pool``:
-    [NB, bs, kvH, hd] or the ``{"q": int8, "scale": fp32}`` quantized
-    leaf; ``tables``: [S, MB] int32 null-padded block tables; ``ctx_lens``:
+    folded [NB, bs, kvH * hd] (the MXU kernel,
+    :func:`paged_attention_folded`), or [NB, bs, kvH, hd] or the ``{"q":
+    int8, "scale": fp32}`` quantized leaf (the VPU kernel); ``tables``: [S, MB] int32 null-padded block tables; ``ctx_lens``:
     [S] int32, keys ``0..ctx`` inclusive are attendable (the engine's
     decode-step convention: this step's key was just written at ``ctx``).
     Returns [S, Hq, hd] in ``q.dtype``.  The dense gathered view is never
@@ -193,6 +203,10 @@ def paged_attention(
 
     if interpret is None:
         interpret = _default_interpret()
+    if not isinstance(k_pool, dict) and k_pool.ndim == 3:
+        return paged_attention_folded(
+            q, k_pool, v_pool, tables, ctx_lens, window=window,
+            interpret=interpret)
     t = tensor_degree(mesh, axis)
     kvH_full = kv_leaf_parts(k_pool)[0].shape[2]
     if t > 1 and kvH_full % t == 0:
@@ -282,6 +296,144 @@ def _paged_attention_local(
     return out.swapaxes(1, 2).reshape(S, Hq, hd)
 
 
+# -- the folded kernel: grouped-query decode on the MXU --------------------------
+#
+# ``_decode_kernel`` multiplies one query row a (head, key) pair on the VPU:
+# with 48 query heads on 8 KV heads of 128 that is about 800 vector
+# operations a 16-key page, ten times what reading the page costs.  Folding
+# the KV heads into the feature axis turns the same arithmetic into two plain
+# matmuls: a page stored as ``[bs, kvH * hd]`` rows, and the queries as a
+# block-diagonal ``[Hq, kvH * hd]`` matrix (query head (h, g) holds its
+# ``hd`` numbers in the lanes of KV head h and zeros elsewhere), give
+# ``scores[Hq, keys] = Q K^T`` and ``acc[Hq, kvH * hd] += P V``; head
+# (h, g)'s output is the lanes of KV head h in its row of ``acc``.  The MXU
+# does 8 times the needed products and is still far from busy.  What the
+# kernel costs is its grid: about 46 ns a (slot, page) visited, relevant or
+# not (my chip run, PR 27: 1.23 ms a layer over 16 x 832 pages whatever the
+# contexts), so with a window the grid covers only the band a slot can still
+# see, starting at the slot's own first block of keys (``first``, a third
+# prefetched scalar), and not ``max_len``.
+
+FOLD_PAGES = 8  # pages a grid step takes (128 keys at 16 a page)
+
+
+def _folded_kernel(tables_ref, ctx_ref, first_ref, q_ref, *refs, pages: int,
+                   bs: int, window: int | None, scale: float):
+    del tables_ref
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * pages:]
+    s, j = pl.program_id(0), pl.program_id(1)
+    keys = pages * bs
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_BIG)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    ctx = ctx_ref[s]
+    start = (first_ref[s] + j) * keys  # past the table's end: not relevant
+    relevant = start <= ctx
+    if window is not None:
+        relevant = jnp.logical_and(relevant, start + keys - 1 > ctx - window)
+
+    @pl.when(relevant)
+    def _block():
+        k = jnp.concatenate([r[0] for r in k_refs], axis=0)  # [keys, F]
+        v = jnp.concatenate([r[0] for r in v_refs], axis=0)
+        q = q_ref[0]  # [Hq, F], block-diagonal
+        if q.dtype == jnp.float32:  # float32 queries ask for float32 math
+            k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+        sc = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [Hq, keys]
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        valid = pos <= ctx
+        if window is not None:
+            valid = jnp.logical_and(valid, pos > ctx - window)
+        sc = jnp.where(valid, sc, _NEG_BIG)
+        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m_new, _NEG_BIG / 2)
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def paged_attention_folded(
+    q: jax.Array,
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    tables: jax.Array,
+    ctx_lens: jax.Array,
+    *,
+    window: int | None = None,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """What ``paged_attention`` runs over one layer of a pool stored folded,
+    ``[NB, bs, kvH * hd]`` (see above): ``q`` [S, Hq, hd] with kv-major
+    heads, ``tables`` [S, MB], keys ``0..ctx`` attendable, a band of
+    ``window`` where given.  Returns [S, Hq, hd] in ``q.dtype``."""
+    if interpret is None:
+        interpret = _default_interpret()
+    S, Hq, hd = q.shape
+    NB, bs, F = k_pool.shape
+    kvH = F // hd
+    if Hq % kvH:
+        raise ValueError(f"{Hq} query heads not a multiple of "
+                         f"{kvH} kv heads")
+    G = Hq // kvH
+    MB = tables.shape[1]
+    pages = min(FOLD_PAGES, MB)
+    tables = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, -MB % pages)))
+    groups, keys = tables.shape[1] // pages, pages * bs
+    ctx_lens = ctx_lens.astype(jnp.int32)
+    if window is None:
+        first, steps = jnp.zeros_like(ctx_lens), groups
+    else:  # the band (ctx - window, ctx] spans at most this many groups
+        first = jnp.maximum(ctx_lens - window + 1, 0) // keys
+        steps = min(groups, (window - 1) // keys + 2)
+    # query head (h, g) in the lanes of KV head h, zeros elsewhere
+    own = jnp.asarray(np.arange(Hq)[:, None] // G == np.arange(kvH)[None, :],
+                      q.dtype)
+    qf = jnp.einsum("shd,hk->shkd", q, own).reshape(S, Hq, F)
+
+    def page(i):
+        return pl.BlockSpec((1, bs, F), lambda s, j, t, c, f: (
+            t[s, jnp.minimum(f[s] + j, groups - 1) * pages + i], 0, 0))
+
+    wide = pl.BlockSpec((1, Hq, F), lambda s, j, t, c, f: (s, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S, steps),
+        in_specs=[wide] + [page(i) for i in range(pages)] * 2,
+        out_specs=wide,
+        scratch_shapes=[
+            pltpu.VMEM((Hq, F), jnp.float32),
+            pltpu.VMEM((Hq, _LANES), jnp.float32),
+            pltpu.VMEM((Hq, _LANES), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_folded_kernel, pages=pages, bs=bs, window=window,
+                          scale=1.0 / float(np.sqrt(hd))),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, Hq, F), q.dtype),
+        interpret=interpret,
+        name="tadnn_paged_decode_folded",
+    )(tables, ctx_lens, first, qf, *([k_pool] * pages), *([v_pool] * pages))
+    return jnp.einsum("shkd,hk->shd", out.reshape(S, Hq, kvH, hd), own)
+
+
 def paged_attention_reference(
     q: jax.Array,
     k_pool,
@@ -305,8 +457,10 @@ def paged_attention_reference(
 
     if dtype is None:
         dtype = q.dtype
-    kd = gather_blocks(k_pool, tables, dtype)
-    vd = gather_blocks(v_pool, tables, dtype)
+    folded = not isinstance(k_pool, dict) and k_pool.ndim == 3
+    kv_heads = k_pool.shape[2] // q.shape[2] if folded else None
+    kd = gather_blocks(k_pool, tables, dtype, kv_heads)
+    vd = gather_blocks(v_pool, tables, dtype, kv_heads)
     key_idx = jnp.arange(kd.shape[1])[None, :]
     mask = key_idx <= ctx_lens[:, None]
     if window is not None:

@@ -272,3 +272,113 @@ def moe_ffn_sharded(
         check_vma=False,
     )(x, router_logits, w_up, w_down, *gate_args)
     return y, metrics
+
+
+# -- no-drop routing over the experts held here --------------------------------
+#
+# The capacity router above drops what overflows a slot buffer and needs
+# every expert on the mesh.  The layer below is the other kind: many small
+# experts, a choice over ALL the published ones, no capacity, no dropped
+# token, and a chip that is told which experts it holds and adds only what
+# those give (the share of an expert-parallel deployment; on one chip it
+# runs without its exchange).
+
+
+def route_top_k(
+    logits: jax.Array,  # [T, E] float32, over all the published experts
+    e_bias: jax.Array | None,  # [E]: takes part in the choice, never in the weight
+    top_k: int,
+    *,
+    score_func: str = "sigmoid",
+    route_norm: bool = True,
+    route_scale: float = 1.0,
+) -> tuple[jax.Array, jax.Array]:
+    """``(chosen [T, k] int32, weights [T, k] float32)``: the top ``k`` of
+    ``score + e_bias`` a token, weighted by the score alone, normalised to
+    sum to 1 (``route_norm``) and scaled."""
+    logits = logits.astype(jnp.float32)
+    if score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif score_func == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown score_func {score_func!r}")
+    select = scores if e_bias is None else scores + e_bias
+    _, chosen = jax.lax.top_k(select, top_k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if route_norm:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), weights * route_scale
+
+
+def held_expert_ffn(
+    x: jax.Array,  # [T, d]
+    chosen: jax.Array,  # [T, k] expert ids among the published ones
+    weights: jax.Array,  # [T, k]
+    w_gate: jax.Array,  # [held, d, f]
+    w_up: jax.Array,  # [held, d, f]
+    w_down: jax.Array,  # [held, f, d]
+    *,
+    first_expert: int = 0,
+    valid: jax.Array | None = None,  # [T] bool: rows that are real
+) -> tuple[jax.Array, dict]:
+    """``sum_e w_e * SwiGLU_e(x)`` over the chosen experts that are held
+    here (``first_expert .. first_expert + held``); what the others would
+    add is left out.  No capacity: every (token, expert) pair that lands
+    here is computed, however uneven the choice.
+
+    The pairs are sorted by expert and laid out in row tiles of one expert
+    each (an expert's rows padded up to a whole tile), so that the grouped
+    matmuls (``ops/grouped_matmul.py``) read exactly the experts that own a
+    tile; the results are gathered back a pair at a time and summed with
+    the routing weights in float32.  Shapes are static: ``T * k`` pairs in
+    at most ``T * k / tm + min(held, T * k)`` tiles.
+
+    Returns ``(y [T, d], stats)`` with the step's counters: ``pairs`` that
+    landed here, ``experts_touched``, ``max_expert_tokens``."""
+    from ..ops.grouped_matmul import grouped_matmul
+
+    T, d = x.shape
+    k, held = chosen.shape[1], w_up.shape[0]
+    P = T * k
+    tm = 16 if P <= 256 else 128
+    n_tiles = -(-P // tm) + min(held, P)
+    Mp = n_tiles * tm
+
+    local = chosen.reshape(P) - first_expert
+    here = (local >= 0) & (local < held)
+    if valid is not None:
+        here &= jnp.repeat(valid, k)
+    key = jnp.where(here, local, held)  # the pairs of absent experts sort last
+    order = jnp.argsort(key, stable=True)
+    sorted_key = key[order]
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    starts = jnp.cumsum(sizes) - sizes
+    tiles = (sizes + tm - 1) // tm
+    tile_ends = jnp.cumsum(tiles)
+    n_active = tile_ends[-1]
+    g = jnp.minimum(sorted_key, held - 1)
+    row = (tile_ends - tiles)[g] * tm + jnp.arange(P) - starts[g]
+    row = jnp.where(sorted_key < held, row, Mp)  # Mp: the row of zeros
+    # padded row -> token it copies (T: the row of zeros)
+    src = jnp.full((Mp + 1,), T, jnp.int32).at[row].set(
+        (order // k).astype(jnp.int32))[:Mp]
+    x_ext = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])
+    rows = x_ext[src]
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_ends, jnp.arange(n_tiles), side="right",
+                         method="compare_all"),
+        held - 1).astype(jnp.int32)
+
+    h = grouped_matmul(rows, w_up, tile_group, n_active, tm=tm, w_gate=w_gate)
+    y = grouped_matmul(h, w_down, tile_group, n_active, tm=tm)
+
+    pair_row = jnp.zeros((P,), jnp.int32).at[order].set(row.astype(jnp.int32))
+    y_ext = jnp.concatenate([y, jnp.zeros((1, d), y.dtype)])
+    per_pair = y_ext[pair_row].reshape(T, k, d).astype(jnp.float32)
+    w = jnp.where(here.reshape(T, k), weights.astype(jnp.float32), 0.0)
+    out = jnp.einsum("tk,tkd->td", w, per_pair).astype(x.dtype)
+    stats = {"pairs": here.sum().astype(jnp.int32),
+             "experts_touched": (sizes > 0).sum().astype(jnp.int32),
+             "max_expert_tokens": sizes.max().astype(jnp.int32)}
+    return out, stats

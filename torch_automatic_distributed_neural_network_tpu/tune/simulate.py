@@ -254,7 +254,7 @@ def replay_serve(
 
         def ship(slot: int, req: Request) -> float:
             # disaggregated: finished prefill pays the block handoff
-            # into the decode slice (engine: pool.ship_prefill)
+            # into the decode slice (engine: pool.record_ship)
             if not disaggregate:
                 return 0.0
             sched.record_ship(
