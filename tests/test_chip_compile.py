@@ -251,8 +251,8 @@ def test_serving_programs_update_the_pool_in_place(
     kv, win = jax.eval_shape(arrays)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     if program == "decode_step":
-        operands = (params, kv, i32(slots, MB + 4), win, {},
-                    jax.eval_shape(lambda: jax.random.key(0)))
+        operands = (params, kv, i32(slots, MB + 4), i32(2 * slots + 3), win,
+                    {}, jax.eval_shape(lambda: jax.random.key(0)))
 
         def step(params, *a):
             return programs.decode_step(

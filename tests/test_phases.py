@@ -108,6 +108,11 @@ def test_the_second_annotation_api_is_gone():
 # -- a tiny engine: events, names, compiles ----------------------------------
 
 
+# a prompt's first token stays on the device and comes with the next read:
+# only an engine that reads before it dispatches (speculative) waits for it
+READER_FIRST = {"prefill_first_token"}
+
+
 def _engine(journal):
     model = GPT2("test", vocab_size=VOCAB, max_seq_len=64,
                  dtype=jnp.float32, remat=False)
@@ -152,7 +157,7 @@ def test_serve_step_events_carry_phases_under_the_schema(served):
         # fields that were there keep their meaning
         assert s["decode_s"] <= s["step_s"] and s["prefill_s"] <= s["step_s"]
     seen = set().union(*(s["phases"] for s in steps))
-    assert seen == set(PHASES)
+    assert seen == set(PHASES) - READER_FIRST
     assert [s["t_end"] for s in steps] == sorted(s["t_end"] for s in steps)
 
 
@@ -227,7 +232,7 @@ def test_report_renders_the_step_phases(served, tmp_path):
             out._write(r)
     rep = obs_report.generate(str(path))
     srv = rep["serving"]
-    assert set(srv["step_phase_mean_s"]) == set(PHASES)
+    assert set(srv["step_phase_mean_s"]) == set(PHASES) - READER_FIRST
     assert srv["mean_step_self_s"] >= 0.0
     assert srv["steps_that_compiled"] >= 1
     text = obs_report.format_report(rep)
@@ -259,22 +264,26 @@ def test_report_tells_steps_with_a_prefill_chunk_from_decode_only(
             "prefill chunk") in obs_report.format_report(rep)
 
 
-def test_single_shot_prefill_is_not_timed_as_admit():
-    """With ``prefill_chunk=None`` the forward and the first token's wait
-    are the ``prefill_*`` phases of the admitting step (the prompt lands in
-    the request's pages as it runs: no commit)."""
+@pytest.mark.parametrize("speculative", [0, 2])
+def test_single_shot_prefill_is_not_timed_as_admit(speculative):
+    """With ``prefill_chunk=None`` the forward is the ``prefill_dispatch``
+    phase of the admitting step (the prompt lands in the request's pages as
+    it runs: no commit).  Its first token is waited for apart only where
+    the engine reads before it dispatches; otherwise it comes with the
+    step's one read."""
     j = Journal(None, validate=True, host0_only=False)
     model = GPT2("test", vocab_size=VOCAB, max_seq_len=64,
                  dtype=jnp.float32, remat=False)
     variables = model.init(jax.random.key(1), jnp.ones((1, 12), jnp.int32))
     eng = ServeEngine(model, variables, n_slots=2, max_len=64,
                       block_size=8, prefill_chunk=None, journal=j,
-                      export_cache=False)
+                      speculative=speculative, export_cache=False)
     eng.submit(_prompt(9), max_new_tokens=3)
     eng.run()
     first = j.named("serve.step")[0]
-    assert {"admit", "prefill_dispatch",
-            "prefill_first_token"} <= set(first["phases"])
+    assert {"admit", "prefill_dispatch", "decode_wait"} <= set(
+        first["phases"])
+    assert ("prefill_first_token" in first["phases"]) == bool(speculative)
     # the forward compiles inside prefill_dispatch: admit is the
     # scheduler's bookkeeping and stays far below it
     assert first["compiles"] > 0
@@ -342,7 +351,8 @@ def test_phase_annotations_nest_inside_their_steps_annotation(captured):
     assert len(steps) >= 3
     inner = [(n, s, e, st) for n, s, e, st in captured
              if n.startswith("serve.") and n != "serve.step"]
-    assert {n for n, *_ in inner} == {"serve." + p for p in PHASES}
+    assert {n for n, *_ in inner} == {
+        "serve." + p for p in set(PHASES) - READER_FIRST}
     for n, s, e, st in inner:
         lo, hi = steps[st["step"]]  # the same step number
         assert lo <= s <= e <= hi, (n, st)

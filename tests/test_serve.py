@@ -207,8 +207,11 @@ def test_continuous_batching_matches_sequential_generate(
                for p in (5, 9, 12, 7, 16)]
     max_new = 12
 
+    # float32 caches on both sides: a bf16 cache moves a logit by 1e-3,
+    # and this toy model has ties that near
     eng = ServeEngine(model, variables, n_slots=3, max_len=64,
-                      block_size=8, attention_impl=attention_impl)
+                      block_size=8, attention_impl=attention_impl,
+                      cache_dtype=jnp.float32)
     reqs = [eng.submit(p, max_new_tokens=max_new, eos_id=0)
             for p in prompts]
     done = eng.run()
@@ -220,7 +223,8 @@ def test_continuous_batching_matches_sequential_generate(
         prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
         seq, lengths = generate(
             model, variables, prompt, max_new_tokens=max_new,
-            eos_id=0, early_stop=True, return_lengths=True)
+            eos_id=0, early_stop=True, return_lengths=True,
+            cache_dtype=jnp.float32)
         n = int(lengths[0]) - len(req.prompt)
         expect = [int(t) for t in np.asarray(seq[0, len(req.prompt):
                                                  len(req.prompt) + n])]
@@ -858,7 +862,7 @@ def _base_program_operands(model, variables, program):
         kv, programs.pack_step(
             tables, np.asarray([9, 3]), rs.randint(1, VOCAB, size=(2, 1)),
             np.asarray([True, True]), np.zeros((2,), np.int32)),
-        eng.pool.win_tables, {}, jax.random.key(0))
+        programs.step_output(2), eng.pool.win_tables, {}, jax.random.key(0))
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
@@ -1018,3 +1022,348 @@ def test_engine_event_counts_the_rounded_weights():
         x.nbytes for x in held if x.dtype == jnp.float32)
     assert ev["weight_bytes_compute"] + ev["weight_bytes_fp32"] == sum(
         x.nbytes for x in held)
+
+
+# -- a step's tokens stay on the device; the host reads them one call late ----
+#
+# ``step()`` dispatches decode n + 1 and only then fetches decode n.  What may
+# not change: the tokens (greedy: those of ``generate``; sampled: those of the
+# same engine made to read before it dispatches), the count of tokens, and the
+# pages.  What lags: an EOS is seen one step late, and a token's wall time is
+# the moment of the read.
+
+
+def _f32_engine(journal=None, **kw):
+    """An engine whose cache is float32 like its weights: greedy tokens
+    are then those of ``generate`` over a float32 cache to the last one (a
+    bf16 cache moves a logit by 1e-3, enough to flip a near tie)."""
+    model, variables = _model_and_vars()
+    kw = {**dict(n_slots=3, max_len=64, block_size=8, prefill_chunk=8,
+                 cache_dtype=jnp.float32, export_cache=False), **kw}
+    return ServeEngine(model, variables, journal=journal, **kw)
+
+
+def _greedy(prompt, max_new, eos_id=None):
+    model, variables = _model_and_vars()
+    seq, lengths = generate(
+        model, variables, jnp.asarray(prompt, jnp.int32)[None, :],
+        max_new_tokens=max_new, eos_id=eos_id, cache_dtype=jnp.float32,
+        early_stop=eos_id is not None, return_lengths=True)
+    return [int(t) for t in np.asarray(seq[0, len(prompt):int(lengths[0])])]
+
+
+def _prompts(lengths, seed=42):
+    rs = np.random.RandomState(seed)
+    return [[int(t) for t in rs.randint(1, VOCAB, size=(n,))]
+            for n in lengths]
+
+
+def _same_rids(monkeypatch):
+    """Requests of the next engine count from 1000 again: a prompt's first
+    token is sampled under a key folded with its request id."""
+    import itertools
+
+    from torch_automatic_distributed_neural_network_tpu.inference.serve import (
+        scheduler as sched_mod,
+    )
+
+    monkeypatch.setattr(sched_mod, "_rid_counter", itertools.count(1000))
+
+
+@pytest.mark.parametrize("case", ["eos", "optimistic_preemption",
+                                  "prefix_hit", "sampled"])
+def test_engine_reading_one_step_late_serves_the_same_tokens(
+        case, monkeypatch):
+    monkeypatch.setenv("TADNN_DEBUG_INVARIANTS", "1")
+    prompts, max_new, eos_id, kw = _prompts((5, 9, 12, 7, 16)), 10, None, {}
+    if case == "eos":
+        # a token that greedy decoding reaches in mid-request
+        eos_id = _greedy(prompts[2], max_new)[4]
+    if case == "optimistic_preemption":
+        # 9 allocatable blocks cannot hold 3 requests of 28 tokens
+        prompts = _prompts((12, 12, 12, 12))
+        max_new = 16
+        kw = dict(admission="optimistic", num_blocks=10, max_len=32)
+    if case == "prefix_hit":
+        # the last request shares the 16 tokens of the one before it
+        prompts[3], prompts[4] = prompts[4], prompts[4] + prompts[3][:2]
+        kw = dict(prefix_cache=True, n_slots=2)
+    if case == "sampled":
+        from torch_automatic_distributed_neural_network_tpu.inference.decode import (  # noqa: E501
+            SampleConfig,
+        )
+
+        kw = dict(sample=SampleConfig(temperature=0.9))
+
+    def serve(these, ahead=None):
+        _same_rids(monkeypatch)
+        eng = _f32_engine(**kw)
+        if ahead is not None:
+            eng._ahead = ahead
+        reqs = [eng.submit(p, max_new_tokens=max_new, eos_id=eos_id)
+                for p in these]
+        done = eng.run()
+        assert len(done) == len(these) and eng.scheduler.idle()
+        assert all(r.n_inflight == 0 and r.state == "done" for r in reqs)
+        return eng, [list(r.out_tokens) for r in reqs]
+
+    eng, tokens = serve(prompts)
+    assert eng.steps_ahead > 0.8 * eng._step_count
+    if case == "sampled":
+        # alone in the engine, the first request draws what it drew in a
+        # full batch: its slot, its steps and its keys are the same
+        assert serve(prompts[:1])[1][0] == tokens[0]
+        assert len({tuple(t) for t in tokens}) == len(tokens)
+    else:
+        assert tokens == [_greedy(p, max_new, eos_id) for p in prompts]
+    # and read before dispatch, as a speculative engine does, it serves the
+    # same in the same number of steps (an EOS costs the late reader one
+    # more step: the one it dispatched before it saw the EOS)
+    twin, want = serve(prompts, ahead=0)
+    assert twin.steps_ahead == 0 and tokens == want
+    if case == "eos":
+        assert any(len(t) < max_new and t[-1] == eos_id for t in tokens)
+        assert eng.discarded_tokens > 0 == twin.discarded_tokens
+    else:
+        assert eng._step_count == twin._step_count
+    if case == "optimistic_preemption":
+        assert eng.scheduler.n_preemptions > 0
+        assert eng.pool.allocator.n_free == 9  # zero leaked blocks
+        # a victim's step in flight is thrown away with it
+        assert eng.discarded_tokens > 0
+    if case == "prefix_hit":
+        assert eng.prefix_hits == 1 and eng.prefix_cached_tokens == 16
+
+
+class _Trace:
+    """The engine's dispatches and reads in order: its step function and the
+    program that leaves a first token on the device wrapped, and
+    ``jax.device_get`` as the engine module sees it.  ``step_of`` says which
+    dispatch produced an output array (0: none yet)."""
+
+    def __init__(self, eng, monkeypatch):
+        from torch_automatic_distributed_neural_network_tpu.inference.serve import (
+            engine as engine_mod,
+        )
+
+        self.log, self.keep, self.step_of = [], [], {}
+        self.n_dispatched = 0
+        step_fn, first_fn = eng._step_fn, eng._first_fn
+        self.step_of[id(eng._out)] = 0
+        self.keep.append(eng._out)
+
+        def step(*a):
+            kv, out = step_fn(*a)
+            self.n_dispatched += 1
+            self._name(out, self.n_dispatched)
+            self.log.append(("dispatch", self.n_dispatched))
+            return kv, out
+
+        def first(prev, *a):
+            out = first_fn(prev, *a)
+            self._name(out, self.step_of[id(prev)])
+            return out
+
+        def device_get(x):
+            self.log.append(("read", self.step_of[id(x)], self.n_dispatched))
+            return jax.device_get(x)
+
+        eng._step_fn, eng._first_fn = step, first
+        fake = type("jax", (), {"device_get": staticmethod(device_get)})
+        for name in ("random", "jit", "tree", "eval_shape", "Array"):
+            setattr(fake, name, getattr(jax, name))
+        monkeypatch.setattr(engine_mod, "jax", fake)
+
+    def _name(self, out, step):
+        self.keep.append(out)  # an id is only unique while its array lives
+        self.step_of[id(out)] = step
+
+
+@pytest.mark.parametrize("speculative", [0, 2])
+def test_decode_is_dispatched_before_the_step_before_is_read(
+        speculative, monkeypatch):
+    """Whenever a slot continues, decode n + 1 goes out before n is read;
+    never with drafts to make from the tokens just produced."""
+    from torch_automatic_distributed_neural_network_tpu.obs.journal import (
+        Journal,
+    )
+
+    j = Journal(None, validate=True, host0_only=False)
+    eng = _f32_engine(journal=j, speculative=speculative, n_slots=2)
+    trace = _Trace(eng, monkeypatch)
+    for p in _prompts((5, 12, 9)):
+        eng.submit(p, max_new_tokens=6)
+    eng.run()
+    reads = [e for e in trace.log if e[0] == "read"]
+    assert trace.n_dispatched >= 5 and len(reads) >= trace.n_dispatched
+    steps = [s for s in j.named("serve.step") if s["decode_s"]]
+    (ev,) = j.named("serve.engine")
+    if speculative:
+        # every read is of the newest output: nothing is in flight behind it
+        assert all(step == newest for _, step, newest in reads)
+        assert ev["dispatch_ahead"] == 0 == eng.steps_ahead
+        assert not any(s["ahead"] for s in steps)
+        return
+    assert ev["dispatch_ahead"] == 1
+    # a read is of the step before the newest, but for the one that drains
+    # the last step (no slot continued) and the first (no step before it)
+    assert [step for _, step, _ in reads] == list(range(len(reads)))
+    assert [newest - step for _, step, newest in reads] == (
+        [1] * (len(reads) - 1) + [0])
+    assert eng.steps_ahead == trace.n_dispatched - 1
+    assert [s["ahead"] for s in steps] == [0] + [1] * (len(steps) - 2) + [0]
+    assert sum(s["ahead"] for s in steps) / len(steps) > 0.8
+
+
+def test_eos_is_seen_one_step_late_and_costs_one_slot_step(monkeypatch):
+    monkeypatch.setenv("TADNN_DEBUG_INVARIANTS", "1")
+    from torch_automatic_distributed_neural_network_tpu.obs.journal import (
+        Journal,
+    )
+
+    prompt, other = _prompts((9, 14))
+    free_run = _greedy(prompt, 12)
+    eos_id = next(t for i, t in enumerate(free_run)
+                  if 2 <= i < 10 and t not in free_run[:i])
+    want = free_run[:free_run.index(eos_id) + 1]
+    j = Journal(None, validate=True, host0_only=False)
+    eng = _f32_engine(journal=j, n_slots=2)
+    req = eng.submit(prompt, max_new_tokens=12, eos_id=eos_id)
+    bystander = eng.submit(other, max_new_tokens=12)
+    while req.state != "done":
+        eng.step()
+    # the tokens end at the EOS; the step that was in flight when the host
+    # read it is counted and thrown away, and the slot and its pages are free
+    assert req.out_tokens == want and len(req.token_walls) == len(want)
+    assert eng.discarded_tokens == 1 == sum(
+        s["discarded_tokens"] for s in j.named("serve.step"))
+    assert req.blocks == [] and req.slot is None and req.n_inflight == 0
+    assert eng.scheduler.slots.count(None) == 1
+    n_live = eng.pool.allocator.n_live
+    assert n_live == len(bystander.blocks)
+    eng.run()
+    # the write of the thrown-away step fell into the request's own page:
+    # the request beside it decoded what it decodes alone
+    assert bystander.out_tokens == _greedy(other, 12)
+    assert eng.pool.allocator.n_live == 0
+    eng.scheduler.check_invariants()
+
+
+def test_run_and_idle_drain_the_unread_step():
+    """``idle()`` is false until the host holds the last token of every
+    request, and a slot whose request ends by length goes to the next one
+    with no empty step between."""
+    eng = _f32_engine(n_slots=1)
+    m = 5
+    reqs = [eng.submit(p, max_new_tokens=m) for p in _prompts((6, 7))]
+    calls = 0
+    while not eng.scheduler.idle():
+        eng.step()
+        calls += 1
+        unread = any(r.n_inflight for r in reqs)
+        assert unread == bool(eng._rows)
+        if unread:
+            assert not eng.scheduler.idle()
+    assert all(len(r.out_tokens) == m == len(r.token_walls)
+               and r.state == "done" and r.t_done is not None for r in reqs)
+    # one chunk, m - 1 steps and the call that reads the last, per request;
+    # the second request's chunk shares the call that drains the first
+    assert calls == 2 * m - 1
+    assert [r.out_tokens for r in reqs] == [
+        _greedy(r.prompt, m) for r in reqs]
+    assert eng.discarded_tokens == 0 and eng.scheduler.draining == []
+    assert eng.run() == eng.finished and len(eng.finished) == 2
+
+
+def test_a_token_is_stamped_when_the_host_reads_it(monkeypatch):
+    """Under an injected clock: every stamp is taken after the read that
+    brought the token and before the next dispatch, a request's stamps rise
+    strictly, and its first token and the next never share one."""
+    eng = _f32_engine(n_slots=2)
+    trace = _Trace(eng, monkeypatch)
+    ticks = [0]
+
+    def clock():
+        ticks[0] += 1
+        trace.log.append(("clock", ticks[0]))
+        return float(ticks[0])
+
+    eng.scheduler.clock = clock
+    reqs = [eng.submit(p, max_new_tokens=5) for p in _prompts((5, 11, 20))]
+    for r in reqs:
+        r.t_submit = 0.0
+    emitted_before = eng.tokens_emitted
+    eng.run()
+    assert eng.tokens_emitted - emitted_before == 15
+    last = {}  # clock tick -> the kind of the engine event before it
+    for i, e in enumerate(trace.log):
+        if e[0] == "clock":
+            last[e[1]] = next((p[0] for p in reversed(trace.log[:i])
+                               if p[0] != "clock"), None)
+    for r in reqs:
+        assert len(r.token_walls) == 5 and r.t_first_token == r.token_walls[0]
+        assert all(b > a for a, b in zip(r.token_walls, r.token_walls[1:]))
+        assert r.token_walls[1] - r.token_walls[0] > 0
+        assert all(last[int(w)] == "read" for w in r.token_walls)
+        assert r.t_admit < r.t_first_token <= r.t_done
+
+
+def test_nothing_compiles_after_the_warm_up_across_admissions():
+    from torch_automatic_distributed_neural_network_tpu.obs.journal import (
+        Journal,
+    )
+
+    j = Journal(None, validate=True, host0_only=False)
+    eng = _f32_engine(journal=j, n_slots=2, admission="optimistic",
+                      num_blocks=7, max_len=32)
+    eng.submit(_prompts((9,))[0], max_new_tokens=3)
+    eng.run()
+    warm = len(j.named("serve.step"))
+    assert sum(s["compiles"] for s in j.named("serve.step")) > 0
+    for p, m in zip(_prompts((3, 12, 17, 6, 12), seed=7), (1, 14, 12, 2, 14)):
+        eng.submit(p, max_new_tokens=m)
+    eng.run()
+    later = j.named("serve.step")[warm:]
+    assert len(later) > 20 and eng.scheduler.n_preemptions > 0
+    assert sum(s["compiles"] for s in later) == 0
+    assert eng._step_fn._cache_size() == 1 == eng._first_fn._cache_size()
+    assert eng._prefill_fn._cache_size() == 1
+
+
+def test_report_prints_the_share_of_steps_dispatched_ahead(tmp_path):
+    """``tadnn report`` on a journal fixture: the share of decoding steps
+    dispatched ahead and the slot-steps thrown away, beside the phases."""
+    jp = tmp_path / "journal.jsonl"
+    recs = [{"kind": "event", "name": "serve.engine", "t": 0.0,
+             "attention_impl": "paged", "prefill_chunk": 32, "n_slots": 4,
+             "max_len": 64, "block_size": 8, "quant_kv": False,
+             "dispatch_ahead": 1}]
+    for i in range(1, 11):
+        decoding = i > 1  # the first call only admits and runs a chunk
+        recs.append({
+            "kind": "event", "name": "serve.step", "t": 0.01 * i, "step": i,
+            "n_active": 4, "n_queued": 0, "occupancy": 1.0,
+            "free_blocks": 3, "new_tokens": 4 if decoding else 0,
+            "prefill_s": 0.0, "decode_s": 0.006 if decoding else 0.0,
+            "phases": {"decode_dispatch": 0.001,
+                       "decode_wait": 0.004} if decoding else {"admit": 1e-4},
+            "step_s": 0.007, "t_end": 0.01 * i, "n_prefill_chunks": 0,
+            "compiles": 0, "ahead": int(i > 2),
+            "discarded_tokens": 2 if i == 7 else 0})
+    jp.write_text("".join(json.dumps(r) + "\n" for r in recs))
+    report = obs_report.generate(str(jp))
+    srv = report["serving"]
+    assert srv["decode_calls"] == 9
+    assert srv["steps_ahead_share"] == pytest.approx(8 / 9)
+    assert srv["discarded_tokens"] == 2 and srv["step_new_tokens"] == 36
+    text = obs_report.format_report(report)
+    assert "step phases (mean ms, host):" in text and "decode_wait" in text
+    assert ("decode dispatched ahead of the read in 88.9% of 9 decoding "
+            "step(s); 2 slot-step(s) decoded and thrown away "
+            "(5.26% of 38)") in text
+    # a journal from before the counters prints no such line
+    for r in recs:
+        r.pop("ahead", None)
+    jp.write_text("".join(json.dumps(r) + "\n" for r in recs))
+    assert "dispatched ahead" not in obs_report.format_report(
+        obs_report.generate(str(jp)))

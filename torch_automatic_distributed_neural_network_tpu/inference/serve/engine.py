@@ -59,6 +59,20 @@ needs no cleanup: positions past a slot's context are masked out of
 attention and overwritten by the next step's writes.  Accept rates
 journal as ``serve.speculate`` events.
 
+A step's tokens stay on the device, and the host reads them ONE CALL
+LATE: ``step()`` dispatches decode n + 1 (each continuing slot takes its
+token from step n's output array, where it lies) and only then fetches
+step n, so eviction, admission, chunk dispatch and the next step's
+operands are the host's work while the device decodes.  Where a write
+lands, and whether a request has used up ``max_new_tokens``, is known from
+the count of tokens DISPATCHED (``Request.n_dispatched``), never from a
+token's value.  What lags is the value alone: an ``eos_id`` is seen one
+step late (that step's token for the slot is thrown away,
+``discarded_tokens``; its KV write fell into a page the request owned), and
+a token's wall time is the moment the host holds it.  The depth is 1 or 0
+by what the engine can see: speculative drafts are a lookup over the
+tokens just produced, so ``speculative > 0`` reads before it dispatches.
+
 Telemetry: every finished request journals a ``serve.request_done``
 event carrying its full span timeline — submit -> admit (queue wait)
 -> prefill chunks (prefix-cache skip included) -> KV ship
@@ -110,13 +124,29 @@ from .scheduler import Request, Scheduler
 # the phases of one ``ServeEngine.step`` in program order: keys of the
 # ``serve.step`` event's ``phases``, annotations ``serve.<phase>``.  Only
 # ``prefill_first_token`` and ``decode_wait`` wait for the device; the
-# others are host work and dispatches.  A single-shot prefill
-# (``prefill_chunk=None``) is one ``prefill_dispatch`` and its first
-# token's ``prefill_first_token``, not part of ``admit``.  A prefill lands
-# in the request's pages as it runs: there is no commit to time
+# others are host work and dispatches.  ``decode_wait`` is the wait for the
+# step dispatched ONE CALL AGO (this call's is already queued behind it),
+# and for the first tokens of prompts that ended since.  Only an engine
+# that reads before it dispatches (``speculative > 0``) has a
+# ``prefill_first_token``, and its ``decode_wait`` is for this call's step.
+# A single-shot prefill (``prefill_chunk=None``) is one
+# ``prefill_dispatch``, not part of ``admit``.  A prefill lands in the
+# request's pages as it runs: there is no commit to time
 PHASES = ("evict", "admit", "prefill_dispatch", "prefill_first_token",
           "grow", "decode_prepare", "decode_upload",
           "decode_dispatch", "decode_wait", "emit")
+
+
+def first_token(prev, logits, where, rng, sample: SampleConfig):
+    """A prompt's first token, sampled from its last chunk's ``logits``
+    [1, V] and left on the device: ``prev`` (a decode step's output,
+    ``programs.decode_step``) with the token at its slot among the first
+    tokens.  ``where`` is [slot, request id]; the key is the request's own,
+    whatever else is running."""
+    slot, rid = where[0], where[1]
+    if sample.temperature != 0.0:  # greedy sampling reads no key
+        rng = jax.random.split(jax.random.fold_in(rng, rid))[1]
+    return prev.at[slot].set(_sample(logits, rng, sample)[0])
 
 
 @dataclasses.dataclass
@@ -318,6 +348,25 @@ class ServeEngine:
         self.tokens_emitted = 0
         self.finished: list[Request] = []
         self._prefill: dict[int, _PrefillState] = {}
+        # how many decode steps may be dispatched with their predecessor
+        # unread: 1, unless the next step's operands need the tokens' values
+        # (drafts are an n-gram lookup over them, and the accepted length
+        # sets the next context)
+        self._ahead = 0 if self.speculative else 1
+        # the newest decode step's output, on the device (the slots' first
+        # tokens, the step's, expert counters: ``programs.decode_step``),
+        # and what of it the host has yet to read: the step's (slot, request,
+        # request.preempted at dispatch) rows, and the same for prompts
+        # whose first token ``_first_fn`` has put there since
+        self._out = programs.step_output(n_slots, 1 + self.speculative)
+        self._rows: list[tuple[int, Request, int]] = []
+        self._firsts: list[tuple[int, Request, int]] = []
+        # lifetime counts: decode steps dispatched with the step before
+        # unread, and slot-steps decoded and thrown away (a step in flight
+        # when its request's EOS was read or it was preempted); step()
+        # diffs them onto serve.step
+        self.steps_ahead = 0
+        self.discarded_tokens = 0
         # the engine's programs are jitted from named functions: a
         # trace shows jit_serve_decode_step and jit_serve_prefill_chunk
         # (a functools.partial has no name: jit__unknown).  They close
@@ -332,6 +381,9 @@ class ServeEngine:
                 lora_scaling=(lora_spec.scaling if lora_spec else 1.0),
                 mesh=mesh, spec=pool_spec)
 
+        def serve_first_token(*operands):
+            return first_token(*operands, sample)
+
         def serve_prefill_chunk(*operands):
             return programs.prefill_chunk(
                 *operands, cfg=cfg, max_blocks=max_blocks,
@@ -343,6 +395,7 @@ class ServeEngine:
                 moe_decode=moe_decode, lora_spec=lora_spec)
 
         self._step_fn = jax.jit(serve_decode_step, donate_argnums=(1,))
+        self._first_fn = jax.jit(serve_first_token)
         self._prefill_fn = jax.jit(serve_prefill_chunk, donate_argnums=(1,))
         self._prefill_lora_fn = (
             jax.jit(serve_prefill_chunk_lora, donate_argnums=(2,))
@@ -379,6 +432,7 @@ class ServeEngine:
             adapter_rank=(lora_spec.rank if lora_spec else None),
             quant_adapters=bool(quant_adapters and lora_spec),
             speculative=self.speculative,
+            dispatch_ahead=self._ahead,
             prefix_cache=self._prefix_cache is not None,
             disaggregate=self.disaggregate,
             tp=tensor_degree(mesh),
@@ -435,6 +489,8 @@ class ServeEngine:
             # prefill writes the request's pages in place: another
             # program than the temp-cache trace of the same options
             "prefill_in_place": True,
+            # the decode step takes the output of the step before
+            "tokens_on_device": True,
             "lora": ([self.lora_spec.rank, self.lora_spec.scaling,
                       n_adapters, quant_adapters]
                      if self.lora_spec is not None else None),
@@ -467,8 +523,8 @@ class ServeEngine:
                    if self.adapter_pool is not None else {})
         return jax.eval_shape(lambda: (
             self.params, self.pool.kv,
-            jnp.zeros((S, MB + T + 3), jnp.int32), self.pool.win_tables,
-            factors, self._rng))
+            jnp.zeros((S, MB + T + 3), jnp.int32), self._out,
+            self.pool.win_tables, factors, self._rng))
 
     def _abstract_prefill_args(self, chunk: int | None = None) -> tuple:
         """Abstract operands of the base prefill chunk (of
@@ -608,8 +664,8 @@ class ServeEngine:
         streams the prompt through the shared chunk trace, interleaved
         with decode — or, single-shot (``prefill_chunk=None``), run the
         whole prompt now as one chunk.  The host's part is phase ``admit``;
-        a single-shot prefill's forward and first token are the
-        ``prefill_*`` phases, outside it."""
+        a single-shot prefill's forward is ``prefill_dispatch``, outside
+        it."""
         if self.prefill_chunk is None:
             # single-shot requests go straight to running, so the pin
             # happens here (before the prefill work, cheaply bounced)
@@ -650,11 +706,13 @@ class ServeEngine:
                          single_shot: bool = False) -> None:
         """One [1, C] chunk of ``req``'s prompt, written into its blocks.
         On the final chunk: pin the adapter (bouncing the request if the
-        pool is full), sample the first token, and hand the slot to
-        decode.  Single-shot, the chunk is the whole prompt padded to
-        whole pages (one trace per distinct padded length — the only
-        shape-varying compile in the serving loop) and the adapter is
-        already pinned."""
+        pool is full), sample the first token ON THE DEVICE, into the
+        newest step output, and hand the slot to decode: its first step
+        reads the token there, and the host fetches it with its next read
+        (at once only where it reads before it dispatches).  Single-shot,
+        the chunk is the whole prompt padded to whole pages (one trace per
+        distinct padded length — the only shape-varying compile in the
+        serving loop) and the adapter is already pinned."""
         st = self._prefill[req.rid]
         bs = self.pool.block_size
         C = (blocks_for_tokens(req.n_prompt, bs) * bs if single_shot
@@ -679,23 +737,23 @@ class ServeEngine:
         bounced = (done and not single_shot
                    and not self._bind_adapter(slot, req))
         if done and not bounced:
-            # the only wait of a prefill: chunks before the last are
-            # dispatched and never fenced
-            with self._phase("prefill_first_token", rid=req.rid):
-                req_rng = jax.random.fold_in(self._rng, req.rid)
-                _, first_rng = jax.random.split(req_rng)
-                first = int(jax.device_get(
-                    _sample(logits, first_rng, self.sample))[0])
+            with self._phase("prefill_dispatch", rid=req.rid, pos=st.pos):
+                self._out = self._first_fn(
+                    self._out, logits,
+                    np.asarray([slot, req.rid], np.int32), self._rng)
             self._publish_prefill(slot, req)
-            req.out_tokens = [first]
-            req.t_first_token = self.scheduler.clock()
-            req.token_walls = [req.t_first_token]
-            self.tokens_emitted += 1
+            req.n_inflight = 1
+            self._firsts.append((slot, req, req.preempted))
             req.state = "running"
             del self._prefill[req.rid]
+            if not self._ahead:
+                # no chunk is fenced, and this wait only where the next
+                # step's drafts need the token
+                self._read("prefill_first_token", self._take_unread())
         if single_shot:
             return
-        # host seconds: a dispatch, plus the wait on the last chunk only
+        # host seconds: a dispatch (and a reader-first engine's wait on the
+        # last chunk)
         chunk_s = time.monotonic() - t0
         req.prefill_chunks += 1
         req.prefill_compute_s += chunk_s
@@ -720,7 +778,7 @@ class ServeEngine:
         for req in self.scheduler.slots:
             if req is None or req.state != "running":
                 continue
-            ctx = req.n_prompt + req.n_generated - 1
+            ctx = req.n_prompt + req.n_dispatched - 1
             for t in range(1 + self.speculative):
                 bi = (ctx + t) // bs
                 if bi >= len(req.blocks):
@@ -744,6 +802,36 @@ class ServeEngine:
                     block=b, fork=nb)
 
     def _decode_all(self) -> None:
+        """Dispatch this call's decode step, THEN read what the host has
+        yet to read: the step dispatched a call ago and the first tokens
+        of prompts that ended since, which all lie in the output array
+        this call's step was handed.  An engine that reads before it
+        dispatches (``_ahead`` 0) comes here with nothing unread and reads
+        its own step.  A call with no slot to decode drains."""
+        unread = self._take_unread()
+        drafts = self._dispatch()
+        if drafts is not None and unread[1]:
+            self.steps_ahead += 1
+        if not self._ahead:
+            unread = self._take_unread()
+        self._read("decode_wait", unread, drafts)
+
+    def _take_unread(self) -> tuple:
+        """(output array, its unread step rows, its unread first tokens),
+        handed over: the engine's lists start anew."""
+        unread = (self._out, self._rows, self._firsts)
+        self._rows, self._firsts = [], []
+        return unread
+
+    def _dispatch(self) -> np.ndarray | None:
+        """One decode step for every running slot, dispatched and not
+        waited for.  Nothing here needs a token's VALUE unless the host has
+        it already: a slot's context length and pages follow from the count
+        of tokens dispatched, and its token is taken on the device from the
+        output of the step before (or from the first tokens beside it)
+        whenever the host has not read it yet.  Returns the [S, T] tokens
+        of the operand (the drafts a verify step emits against), or None
+        where no slot decodes."""
         S, MB = self.n_slots, self.max_blocks
         k_spec = self.speculative
         T = 1 + k_spec
@@ -752,7 +840,8 @@ class ServeEngine:
             ctx = np.zeros((S,), np.int32)
             tok = np.zeros((S, T), np.int32)
             ids = np.zeros((S,), np.int32)
-            act = np.zeros((S,), bool)
+            src = np.zeros((S,), np.int32)
+            rows = []
             for s, req in enumerate(self.scheduler.slots):
                 if req is None or req.state != "running":
                     # prefilling slots keep an all-null table here: the
@@ -760,16 +849,23 @@ class ServeEngine:
                     # block instead of their half-filled prompt blocks
                     continue
                 tables[s, :len(req.blocks)] = req.blocks
-                # this step writes token n_generated at absolute position
-                # n_prompt + n_generated - 1 (the first generated token
+                # this step writes token n_dispatched at absolute position
+                # n_prompt + n_dispatched - 1 (the first generated token
                 # came from prefill and was never written)
-                ctx[s] = req.n_prompt + req.n_generated - 1
-                tok[s, 0] = req.out_tokens[-1]
-                if k_spec:
-                    tok[s, 1:] = ngram_propose(
-                        req.prompt + req.out_tokens, k_spec)
+                ctx[s] = req.n_prompt + req.n_dispatched - 1
+                if not req.n_inflight:
+                    src[s] = programs.TOKEN_HOST
+                    tok[s, 0] = req.out_tokens[-1]
+                    if k_spec:
+                        tok[s, 1:] = ngram_propose(
+                            req.prompt + req.out_tokens, k_spec)
+                else:  # unread: the one token dispatched is a prompt's first
+                    src[s] = (programs.TOKEN_FIRST if req.n_dispatched == 1
+                              else programs.TOKEN_PREV)
                 ids[s] = req.adapter_idx
-                act[s] = True
+                rows.append((s, req, req.preempted))
+        if not rows:
+            return None
         with self._phase("decode_upload"):
             # greedy sampling reads no key: no fold a step for it
             step_rng = (self._rng if self.sample.temperature == 0.0
@@ -778,49 +874,84 @@ class ServeEngine:
             factors = (self.adapter_pool.factors
                        if self.adapter_pool is not None else {})
             # one upload a step: tables, tokens, contexts, flags, ids
-            packed = programs.pack_step(tables, ctx, tok, act, ids)
+            packed = programs.pack_step(tables, ctx, tok, src, ids)
         with self._phase("decode_dispatch"):
-            self.pool.kv, out = self._step_fn(
-                self.params, self.pool.kv, packed, self.pool.win_tables,
-                factors, step_rng)
-        with self._phase("decode_wait"):
-            # the expert counters ride with the tokens: one fetch
+            self.pool.kv, self._out = self._step_fn(
+                self.params, self.pool.kv, packed, self._out,
+                self.pool.win_tables, factors, step_rng)
+        for _, req, _ in rows:
+            req.n_inflight += 1
+        self._rows = rows
+        return tok
+
+    def _read(self, wait: str, unread: tuple,
+              drafts: np.ndarray | None = None) -> None:
+        """Fetch an output array (phase ``wait``) and hand its unread
+        tokens to their requests (phase ``emit``); ``unread`` is what
+        ``_take_unread`` gave."""
+        out, rows, firsts = unread
+        if not rows and not firsts:
+            return
+        S, T = self.n_slots, 1 + self.speculative
+        with self._phase(wait):
+            # first tokens and expert counters ride with the step's
+            # tokens: one fetch
             out = np.asarray(jax.device_get(out))
-            out, moe = out[:-3], out[-3:]
+            first, tokens, moe = out[:S], out[S:-3], out[-3:]
             if T > 1:
-                out = out.reshape(S, T)
-            if self.cfg.n_expert_layers:
+                tokens = tokens.reshape(S, T)
+            if rows and self.cfg.n_expert_layers:
                 self._moe = dict(zip(
                     ("moe_pairs", "moe_experts_touched",
                      "moe_max_expert_tokens"), map(int, moe)))
         with self._phase("emit"):
-            self._emit(out, tok)
+            self._emit(tokens, first, rows, firsts, drafts)
 
-    def _emit(self, out: np.ndarray, tok: np.ndarray) -> None:
-        """Append the step's tokens ``out`` to their requests; with
-        speculation, ``tok[:, 1:]`` are the drafts it verified."""
+    def _emit(self, tokens: np.ndarray, first: np.ndarray, rows: list,
+              firsts: list, drafts: np.ndarray | None) -> None:
+        """Hand what was read to the requests: ``first[slot]`` to each of
+        ``firsts``, the step's ``tokens`` to its ``rows`` (with
+        speculation, ``drafts[:, 1:]`` are the drafts it verified).  A
+        token is stamped HERE, when the host holds it.  A row whose request
+        was preempted since the dispatch, ended at an EOS the host has read
+        since, or was taken out of the scheduler's hands (a gateway's
+        cancel) is a slot-step thrown away."""
         k_spec = self.speculative
-        # one stamp per step: every token this step emits shares it (a
-        # speculative burst lands together, so its interior ITLs are 0)
+        # one stamp per read: every token it brings shares it (a
+        # speculative burst lands together, so its interior ITLs are 0); a
+        # request's first token and its next never come in one read
         now = self.scheduler.clock()
-        if not k_spec:
-            for s, req in enumerate(self.scheduler.slots):
-                if req is not None and req.state == "running":
-                    req.out_tokens.append(int(out[s]))
-                    req.token_walls.append(now)
-                    self.tokens_emitted += 1
-            return
+
+        def give(req: Request, new: list[int]) -> None:
+            req.n_inflight -= 1
+            req.out_tokens.extend(new)
+            req.token_walls.extend([now] * len(new))
+            self.tokens_emitted += len(new)
+            if req.state == "draining" and req.finished():
+                self._finish(req)
+
+        def wanted(req: Request, epoch: int) -> bool:
+            return (req.preempted == epoch and not req.finished()
+                    and req.state in ("running", "draining"))
+
+        for slot, req, epoch in firsts:
+            if wanted(req, epoch):
+                req.t_first_token = now
+                give(req, [int(first[slot])])
         drafted = accepted = n_active = 0
-        for s, req in enumerate(self.scheduler.slots):
-            if req is None or req.state != "running":
+        for slot, req, epoch in rows:
+            if not wanted(req, epoch):
+                self.discarded_tokens += 1
+                continue
+            if not k_spec:
+                give(req, [int(tokens[slot])])
                 continue
             n_active += 1
-            drafts = tok[s, 1:]
-            tgt = out[s]  # [1+k] target greedy choices over the chunk
-            a = accept_length(drafts, tgt)
+            tgt = tokens[slot]  # [1+k] target greedy choices over the chunk
+            a = accept_length(drafts[slot, 1:], tgt)
             # d_1..d_a agreed; tgt[a] is the target's own next token
             # after them (the free bonus) — 1..k+1 tokens per step
-            emit = [int(d) for d in drafts[:a]] + [int(tgt[a])]
+            emit = [int(d) for d in drafts[slot, 1:1 + a]] + [int(tgt[a])]
             drafted += k_spec
             accepted += a
             # clip to the generation budget, and stop at EOS exactly
@@ -828,9 +959,9 @@ class ServeEngine:
             emit = emit[:req.max_new_tokens - req.n_generated]
             if req.eos_id is not None and req.eos_id in emit:
                 emit = emit[:emit.index(req.eos_id) + 1]
-            req.out_tokens.extend(emit)
-            req.token_walls.extend([now] * len(emit))
-            self.tokens_emitted += len(emit)
+            give(req, emit)
+        if not n_active:
+            return
         self.spec_drafted += drafted
         self.spec_accepted += accepted
         self.journal.event(
@@ -838,11 +969,25 @@ class ServeEngine:
             n_active=n_active, drafted=drafted, accepted=accepted,
             accept_rate=(accepted / drafted if drafted else None))
 
-    def _finish(self, slot: int) -> None:
-        # evict() zeroes the prefix-cache accounting with the block
-        # table; read it while the request still owns its slot
-        cached_tokens = self.scheduler.slots[slot].cached_tokens
-        req = self.scheduler.evict(slot)
+    def _evict_ended(self, slot: int) -> None:
+        """A running request leaves its slot as soon as its END is known:
+        an EOS the host has read, or ``max_new_tokens`` DISPATCHED, the
+        last of them read or not (the next request takes the slot without
+        an empty step between; the one leaving waits in the scheduler's
+        ``draining`` for its last read)."""
+        req = self.scheduler.slots[slot]
+        if req is None or req.state != "running":
+            return
+        if req.finished():
+            self._finish(self.scheduler.vacate(slot))
+        elif req.n_dispatched >= req.max_new_tokens:
+            self.scheduler.vacate(slot)
+
+    def _finish(self, req: Request) -> None:
+        """A vacated request whose every token the host holds."""
+        # done() zeroes the prefix-cache accounting; read it first
+        cached_tokens = req.cached_tokens
+        self.scheduler.done(req)
         self.finished.append(req)
         # phase attribution: queue_s runs submit -> LAST admission (so
         # it absorbs time spent queued again after a preemption; lost_s
@@ -876,17 +1021,21 @@ class ServeEngine:
             lost_s=req.lost_s or None)
 
     def step(self) -> None:
-        """One serving iteration: evict finished, admit queued, advance
-        prefill chunks, grow/preempt (optimistic), decode every
-        decoding slot.  Colocated (default): prefill chunks INTERLEAVE
-        with decode steps — at most ``prefill_chunks_per_step`` per
-        iteration, their time serializing with decode on the one chip.
+        """One serving iteration: evict the ended, admit queued, advance
+        prefill chunks, grow/preempt (optimistic), dispatch a decode step
+        for every decoding slot and read the one dispatched a call ago (a
+        call with nothing to dispatch reads what is left).  Colocated
+        (default): prefill chunks INTERLEAVE with decode steps — at most
+        ``prefill_chunks_per_step`` per iteration, their time serializing
+        with decode on the one chip.
         Disaggregated: EVERY prefilling slot advances each step (the
         prefill slice has nothing else to do) and the step's modeled
         wall time is ``max(prefill, decode)`` — the slices run
         concurrently, only the KV-block shipment couples them."""
         sched = self.scheduler
         tokens_before = self.tokens_emitted
+        ahead_before = self.steps_ahead
+        discarded_before = self.discarded_tokens
         compiles, compile_s = self._compiles.n, self._compiles.seconds
         self._phases = phases = {}
         self._moe = {}
@@ -896,16 +1045,12 @@ class ServeEngine:
                             step=self._step_count + 1):
             with self._phase("evict"):
                 for s in range(self.n_slots):
-                    req = sched.slots[s]
-                    if (req is not None and req.state == "running"
-                            and req.finished()):
-                        self._finish(s)
+                    self._evict_ended(s)
             with self._phase("admit"):
                 admitted = sched.admit()
             for slot, req in admitted:
                 self._start_prefill(slot, req)  # more admit, by request
-                if req.state == "running" and req.finished():
-                    self._finish(slot)  # single-shot, one new token
+                self._evict_ended(slot)  # single-shot, one new token
             prefill_s = 0.0
             budget = (None if self.disaggregate
                       else self.prefill_chunks_per_step)
@@ -914,8 +1059,7 @@ class ServeEngine:
                 t0 = time.monotonic()
                 self._advance_prefill(slot, req)
                 prefill_s += time.monotonic() - t0
-                if req.state == "running" and req.finished():
-                    self._finish(slot)  # chunked, max_new_tokens == 1
+                self._evict_ended(slot)  # chunked, max_new_tokens == 1
             with self._phase("grow"):
                 for victim in sched.grow_for_step():
                     self._prefill.pop(victim.rid, None)
@@ -924,7 +1068,7 @@ class ServeEngine:
                 if sched.n_decoding and self._prefix_cache is not None:
                     self._cow_fork_writes()
             decode_s = 0.0
-            if sched.n_decoding:
+            if sched.n_decoding or self._rows or self._firsts:
                 t0 = time.monotonic()
                 self._decode_all()
                 decode_s = time.monotonic() - t0
@@ -964,8 +1108,9 @@ class ServeEngine:
             adapter_stats.update(
                 prefix_blocks=self._prefix_cache.n_blocks,
                 prefix_hit_tokens=self._prefix_cache.hit_tokens)
-        # prefill_s is dispatch time (only a prompt's last chunk waits
-        # for the device); decode_s ends in the step's device_get
+        # prefill_s is dispatch time; decode_s is this call's dispatch and
+        # the device_get of the step dispatched a call ago.  ahead: this
+        # call's step went out with that one unread
         self.journal.event(
             "serve.step", step=self._step_count,
             n_active=sched.n_active, n_queued=sched.n_queued,
@@ -979,6 +1124,8 @@ class ServeEngine:
             overlap_s=overlap_s,
             phases=phases, step_s=whole["step_s"], t_end=t_end,
             n_prefill_chunks=n_chunks, compiles=compiles,
+            ahead=self.steps_ahead - ahead_before,
+            discarded_tokens=self.discarded_tokens - discarded_before,
             **adapter_stats, **self._moe)
         if self._debug_invariants:
             sched.check_invariants()
@@ -996,8 +1143,9 @@ class ServeEngine:
         return self._occupancy_sum / self._step_count
 
     def run(self) -> list[Request]:
-        """Step until queue and slots drain; returns finished requests
-        (every submitted request, in completion order)."""
+        """Step until queue and slots drain and the host holds every
+        token; returns finished requests (every submitted request, in
+        completion order)."""
         while not self.scheduler.idle():
             self.step()
         return list(self.finished)

@@ -29,6 +29,11 @@ function a (kind, FFN) pair, so a model of 24 like layers traces one.
 Host operands go up PACKED, one int32 array a call (``pack_step``,
 ``pack_chunk``), and the step's tokens come back with its expert counters
 in one array: an upload costs about 0.4 ms on a v5e whatever its size.
+
+The step's tokens STAY on the device as well: a decode step takes the
+array the call before it returned, and a slot the host marks
+``TOKEN_PREV`` or ``TOKEN_FIRST`` decodes the token it finds there, so the
+host may dispatch step n + 1 before it has read step n (``engine.py``).
 """
 
 from __future__ import annotations
@@ -67,6 +72,11 @@ from .kv_pool import gather_blocks, read_pages, ring_table, write_chunk, \
     write_token
 
 _NEG_BIG = -0.7 * float(np.finfo(np.float32).max)
+# a slot's flag in ``pack_step``: 0 is an inactive slot; otherwise where the
+# token it decodes lies.  In the operand itself (the host has read it), in
+# the tokens of the step before (``prev[S:2S]``), or among the first tokens
+# that the engine's ``first_token`` left before them (``prev[:S]``)
+TOKEN_HOST, TOKEN_PREV, TOKEN_FIRST = 1, 2, 3
 KEY_BLOCK = 512  # keys a step of the chunk's attention takes
 
 
@@ -292,28 +302,45 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
     return kv, _logits(params, cfg, x), _moe_counters(stats)
 
 
-def pack_step(tables, ctx_lens, tok, active, adapter_ids) -> np.ndarray:
+def pack_step(tables, ctx_lens, tok, source, adapter_ids) -> np.ndarray:
     """A decode step's host operands as ONE int32 array [S, MB + T + 3]
-    (tables, then the tokens, the context length, the active flag and the
-    adapter id of each slot): one upload a step where five cost 0.4 ms
-    each."""
+    (tables, then the tokens, the context length, the flag and the adapter
+    id of each slot): one upload a step where five cost 0.4 ms each.
+    ``source`` is 0 on an inactive slot, else one of ``TOKEN_*`` (a bool
+    reads as ``TOKEN_HOST``)."""
     return np.concatenate(
-        [tables, tok, ctx_lens[:, None], active[:, None],
+        [tables, tok, ctx_lens[:, None], source[:, None],
          adapter_ids[:, None]], axis=1).astype(np.int32)
 
 
-def decode_step(params, kv, packed, win_tables, adapters, rng, *,
+def step_output(n_slots: int, n_tok: int = 1) -> jax.Array:
+    """What a decode step takes as ``prev`` before there has been one."""
+    return jnp.zeros((n_slots + n_slots * n_tok + 3,), jnp.int32)
+
+
+def decode_step(params, kv, packed, prev, win_tables, adapters, rng, *,
                 cfg: TransformerConfig, sample: SampleConfig, n_tok: int = 1,
                 **kw):
     """``decode_logits`` on the operands of ``pack_step`` (``n_tok`` is its
-    T).  Returns ``(kv, [S * T + 3] int32)``: the step's sampled tokens [S]
+    T) and on ``prev``, what the call before this one returned
+    (``step_output`` before the first).  Returns ``(kv, [S + S * T + 3]
+    int32)``: the slots' first tokens, as ``prev`` had them (the engine's
+    ``first_token`` writes them); then the step's sampled tokens [S]
     (T == 1), or the target's greedy choices [S, T] flattened (verify steps
     are temperature-0 by contract — sampled speculative needs rejection
-    resampling), and then the step's expert counters: one array, so that
-    one fetch brings both."""
+    resampling); then the step's expert counters: one array, so that one
+    fetch brings all three.  With T == 1 a slot flagged ``TOKEN_PREV`` decodes
+    its own token of ``prev`` and one flagged ``TOKEN_FIRST`` its first
+    token there, whatever the operand holds: the same shapes every step."""
+    S = packed.shape[0]
     MB = packed.shape[1] - n_tok - 3
     tables, tok = packed[:, :MB], packed[:, MB:MB + n_tok]
-    ctx_lens, active = packed[:, -3], packed[:, -2] > 0
+    ctx_lens, source = packed[:, -3], packed[:, -2]
+    active = source > 0
+    firsts = prev[:S]
+    if n_tok == 1:
+        tok = jnp.where(source == TOKEN_PREV, prev[S:2 * S], jnp.where(
+            source == TOKEN_FIRST, firsts, tok[:, 0]))[:, None]
     kv, logits, counters = decode_logits(
         params, kv, tables, win_tables, ctx_lens, tok, active, adapters,
         packed[:, -1], cfg=cfg, **kw)
@@ -322,7 +349,7 @@ def decode_step(params, kv, packed, win_tables, adapters, rng, *,
     else:  # the all-logits discipline of decode.generate
         tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, T]
         out = jnp.where(active[:, None], tgt, 0).reshape(-1)
-    return kv, jnp.concatenate([out, counters])
+    return kv, jnp.concatenate([firsts, out, counters])
 
 
 def chunk_attention(q, k_layer, v_layer, table_row, pos0, window,

@@ -138,10 +138,12 @@ def prefill_schedule(prefilling: Sequence[tuple[float | None, int]],
 def decode_needs_block(n_prompt: int, n_generated: int, n_blocks: int, *,
                        block_size: int, spec_lookahead: int = 0) -> bool:
     """True when a running request's next decode step writes KV beyond
-    its owned blocks.  This step writes from absolute position
-    ``n_prompt + n_generated - 1`` (the first generated token came from
-    prefill, before any paged write) through ``spec_lookahead``
-    positions beyond it."""
+    its owned blocks.  ``n_generated`` counts the tokens DISPATCHED so far
+    (``Request.n_dispatched``: the engine reads a step's tokens one call
+    late, and a write is placed by what was dispatched).  This step writes
+    from absolute position ``n_prompt + n_generated - 1`` (the first
+    generated token came from prefill, before any paged write) through
+    ``spec_lookahead`` positions beyond it."""
     pos = n_prompt + n_generated - 1 + spec_lookahead
     return pos // block_size >= n_blocks
 
@@ -182,14 +184,19 @@ class Request:
     # FIFO order
     priority: int = 0
 
-    # lifecycle: queued -> [prefilling ->] running -> done (preemption
-    # loops back to queued; "prefilling" only under the engine's
-    # chunked-prefill mode, where a slot streams its prompt across
-    # steps before joining decode)
+    # lifecycle: queued -> [prefilling ->] running -> [draining ->] done
+    # (preemption loops back to queued; "prefilling" only under the
+    # engine's chunked-prefill mode, where a slot streams its prompt
+    # across steps before joining decode; "draining" while a request whose
+    # last token is dispatched has left its slot and the host has yet to
+    # read that token)
     state: str = "queued"
     slot: int | None = None
     blocks: list[int] = dataclasses.field(default_factory=list)
     out_tokens: list[int] = dataclasses.field(default_factory=list)
+    # tokens the device was asked for and the host has not read yet (the
+    # engine reads a step one call late; 0 under every other driver)
+    n_inflight: int = 0
     preempted: int = 0
     # prefix-cache accounting, set at admission: the first
     # ``cached_blocks`` entries of ``blocks`` are SHARED references
@@ -231,6 +238,12 @@ class Request:
     @property
     def n_generated(self) -> int:
         return len(self.out_tokens)
+
+    @property
+    def n_dispatched(self) -> int:
+        """Tokens dispatched, read or not: what places the next KV write
+        and says whether the generation budget is used up."""
+        return len(self.out_tokens) + self.n_inflight
 
     @property
     def max_tokens_total(self) -> int:
@@ -279,6 +292,9 @@ class Scheduler:
         self.clock = clock
         self.queue: deque[Request] = deque()
         self.slots: list[Request | None] = [None] * n_slots
+        # requests out of their slot whose last token the host has yet to
+        # read (``vacate`` .. ``done``): not idle while one is here
+        self.draining: list[Request] = []
         self.n_finished = 0
         self.n_preemptions = 0
         # disaggregated serving: finished-prefill KV transfers into the
@@ -308,7 +324,9 @@ class Scheduler:
                    for r in self.slots)
 
     def idle(self) -> bool:
-        return self.n_active == 0 and not self.queue
+        """Nothing queued, nothing in a slot, and no token left unread."""
+        return (self.n_active == 0 and not self.queue
+                and not self.draining)
 
     def check_invariants(self) -> None:
         """Structural invariants; raises AssertionError on violation.
@@ -350,9 +368,9 @@ class Scheduler:
                 f"block {b}: refcount {self.allocator.refcount(b)} != "
                 f"{table_count.get(b, 0)} table holders "
                 f"+ {int(b in index_blocks)} index reference")
-        for r in self.queue:
+        for r in list(self.queue) + self.draining:
             assert not r.blocks, (
-                f"queued request {r.rid} still holds blocks")
+                f"{r.state} request {r.rid} still holds blocks")
         # adapter pins back live decode reads ONLY: a slot pins exactly
         # while running, so preemption/eviction can never leak a pin
         for r in self.slots:
@@ -360,9 +378,9 @@ class Scheduler:
                 assert r.adapter_idx == IDENTITY_ADAPTER, (
                     f"{r.state} request {r.rid} holds a pinned adapter "
                     f"reference (idx {r.adapter_idx})")
-        for r in self.queue:
+        for r in list(self.queue) + self.draining:
             assert r.adapter_idx == IDENTITY_ADAPTER, (
-                f"queued/preempted request {r.rid} holds a pinned "
+                f"{r.state} request {r.rid} holds a pinned "
                 f"adapter reference (idx {r.adapter_idx})")
         if self.adapter_pool is not None:
             want: dict[str, int] = {}
@@ -438,22 +456,36 @@ class Scheduler:
         recompute-style) — the adapter-stall path: its prefill finished
         but every adapter pool slot is pinned by other running requests.
         Counted as a preemption."""
-        req = self.slots[slot]
-        assert req is not None, f"requeue of empty slot {slot}"
-        self.unpin_adapter(req)
-        self.allocator.free(req.blocks)
-        req.blocks = []
+        return self._bounce(slot)
+
+    def _bounce(self, slot: int) -> Request:
+        """A slot's request back to the queue, to be recomputed from
+        scratch: what it generated is dropped, and so is whatever the
+        device still owes it (``n_inflight``; the engine tells such a token
+        by ``preempted`` having moved on since its dispatch)."""
+        req = self._release(slot)
         req.cached_blocks = req.cached_tokens = 0
-        req.slot = None
         req.state = "queued"
         req.out_tokens = []
         req.token_walls = []
+        req.n_inflight = 0
         if req.t_admit is not None:
             req.lost_s += max(0.0, self.clock() - req.t_admit)
         req.preempted += 1
         self.n_preemptions += 1
-        self.slots[slot] = None
         self._requeue_fifo(req)
+        return req
+
+    def _release(self, slot: int) -> Request:
+        """The slot's request out of it: adapter unpinned, blocks back to
+        the pool."""
+        req = self.slots[slot]
+        assert req is not None, f"empty slot {slot}"
+        self.unpin_adapter(req)
+        self.allocator.free(req.blocks)
+        req.blocks = []
+        req.slot = None
+        self.slots[slot] = None
         return req
 
     def match_prefix(self, req: Request) -> tuple[list[int], int]:
@@ -552,16 +584,27 @@ class Scheduler:
 
     def evict(self, slot: int) -> Request:
         """Finished request out of its slot; blocks back to the pool."""
-        req = self.slots[slot]
-        assert req is not None, f"evict of empty slot {slot}"
-        self.unpin_adapter(req)
-        self.allocator.free(req.blocks)
-        req.blocks = []
+        return self.done(self.vacate(slot))
+
+    def vacate(self, slot: int) -> Request:
+        """A request whose last token is dispatched leaves its slot, read
+        or not: slot and blocks are the next request's from now on (the
+        device runs what it is handed in order, so whoever writes those
+        pages next does so after this request's last step).  It waits in
+        ``draining``, where ``idle()`` counts it, for ``done``."""
+        req = self._release(slot)
+        req.state = "draining"
+        self.draining.append(req)
+        return req
+
+    def done(self, req: Request) -> Request:
+        """The host holds a vacated request's last token (a step still in
+        flight for it, dispatched before its EOS was read, is dropped)."""
+        self.draining.remove(req)
+        req.n_inflight = 0
         req.cached_blocks = req.cached_tokens = 0
-        req.slot = None
         req.state = "done"
         req.t_done = self.clock()
-        self.slots[slot] = None
         self.n_finished += 1
         return req
 
@@ -574,23 +617,7 @@ class Scheduler:
             [(r.t_admit, r.slot) for r in self.slots if r is not None])
         if slot is None:
             return None
-        victim = self.slots[slot]
-        assert victim is not None
-        self.unpin_adapter(victim)
-        self.allocator.free(victim.blocks)
-        victim.blocks = []
-        victim.cached_blocks = victim.cached_tokens = 0
-        victim.slot = None
-        victim.state = "queued"
-        victim.out_tokens = []
-        victim.token_walls = []
-        if victim.t_admit is not None:
-            victim.lost_s += max(0.0, self.clock() - victim.t_admit)
-        victim.preempted += 1
-        self.n_preemptions += 1
-        self.slots[slot] = None
-        self._requeue_fifo(victim)
-        return victim
+        return self._bounce(slot)
 
     def grow_for_step(self) -> list[Any]:
         """Optimistic mode: before a decode step, every running request
@@ -611,7 +638,7 @@ class Scheduler:
                     # and take no decode write this step
                     break
                 if not decode_needs_block(
-                        req.n_prompt, req.n_generated, len(req.blocks),
+                        req.n_prompt, req.n_dispatched, len(req.blocks),
                         block_size=self.block_size,
                         spec_lookahead=self.spec_lookahead):
                     break  # every write fits in owned blocks

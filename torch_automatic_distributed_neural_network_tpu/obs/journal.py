@@ -23,8 +23,9 @@ seconds go into a dict the caller owns and ride on the parent record
 interval is a ``jax.profiler.TraceAnnotation``, so under any profiler
 capture it sits on the device trace's clock::
 
+    # the step dispatched a call ago; this call's is queued behind it
     with phase(seconds, "decode_wait", "serve.decode_wait", step=n):
-        out = jax.device_get(out)
+        tokens = jax.device_get(previous_output)
 
 With no default installed, module-level ``span``/``event`` are cheap
 no-ops (a null journal).  A ``phase`` runs observed or not: its two

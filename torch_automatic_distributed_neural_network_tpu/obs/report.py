@@ -401,6 +401,21 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                 for e in phased if e.get("step_s") is not None)
             serving["steps_that_compiled"] = sum(
                 1 for e in ssteps if e.get("compiles"))
+            # the engine reads a decode step one call after dispatching
+            # it: the share of decoding calls whose step went out with
+            # the one before unread, and the slot-steps decoded and
+            # thrown away (EOS seen a step late, preemption) beside the
+            # tokens kept (engines that journal the two counters)
+            counted = [e for e in ssteps if e.get("ahead") is not None
+                       and e.get("decode_s")]
+            if counted:
+                serving["decode_calls"] = len(counted)
+                serving["steps_ahead_share"] = (
+                    sum(e["ahead"] for e in counted) / len(counted))
+                serving["discarded_tokens"] = sum(
+                    e.get("discarded_tokens") or 0 for e in counted)
+                serving["step_new_tokens"] = sum(
+                    e.get("new_tokens") or 0 for e in counted)
             # the two kinds of decoding step behind the ITL's two
             # modes: a step that also ran prefill chunks, and one that
             # only decoded
@@ -1012,6 +1027,16 @@ def format_report(report: dict) -> str:
                 + (f"; XLA built programs in this process during "
                    f"{sv['steps_that_compiled']} step(s)"
                    if sv.get("steps_that_compiled") else ""))
+        if sv.get("steps_ahead_share") is not None:
+            kept = sv.get("step_new_tokens") or 0
+            thrown = sv.get("discarded_tokens") or 0
+            lines.append(
+                f"  decode dispatched ahead of the read in "
+                f"{sv['steps_ahead_share']:.1%} of "
+                f"{sv['decode_calls']} decoding step(s); {thrown} "
+                f"slot-step(s) decoded and thrown away"
+                + (f" ({thrown / (kept + thrown):.2%} of {kept + thrown})"
+                   if kept + thrown else ""))
         if sv.get("decode_steps_with_chunk"):
             only = sv.get("mean_step_decode_only_s")
             lines.append(

@@ -346,7 +346,11 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # rings (0 where every layer keeps max_len)
             "layer_kinds": "list?", "experts_held": "int",
             "experts_published": "int", "kv_bytes_full": "int",
-            "kv_bytes_window": "int"}),
+            "kv_bytes_window": "int",
+            # decode steps the engine dispatches with the step before
+            # unread: 1, or 0 where the next step's operands need the
+            # tokens' values (speculative drafts)
+            "dispatch_ahead": "int"}),
     _s("serve.step", "one serving iteration (engine or gateway "
        "SimReplica)",
        req={"n_active": "int", "n_queued": "int", "new_tokens": "int",
@@ -366,6 +370,12 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # compile in that interval is counted here too)
             "phases": "dict", "step_s": "float", "t_end": "float",
             "n_prefill_chunks": "int", "compiles": "int",
+            # 1 when this call's decode step was dispatched with the one
+            # before it unread (the host reads a step one call late), and
+            # the slot-steps this call's read threw away: decoded for a
+            # request whose EOS the host had not read yet, or which was
+            # preempted since
+            "ahead": "int", "discarded_tokens": "int",
             # a decode step's expert layers, summed: (token, expert)
             # pairs that landed on the experts held here, experts that
             # got any, and the most tokens one expert got; they come
