@@ -149,7 +149,7 @@ def train_phase(sz: Sizes, *, label: str, devices, strategy: str, seed: int,
     data = OneBatch(SyntheticLM(vocab_size=sz.vocab, seq_len=sz.seq + 1,
                                 batch_size=sz.batch, seed=seed).batch(0))
     ad = tad.AutoDistribute(
-        # the 1.3B recipe of bench.py: per-layer full recompute bounds the
+        # the 1.3B recipe: per-layer full recompute bounds the
         # activations, so the planner's outer checkpoint stays off
         GPT2(sz.model, vocab_size=sz.vocab, max_seq_len=sz.seq,
              remat_policy="nothing"),

@@ -3,7 +3,7 @@
 The reference's third example config (BASELINE.json:9): "Transformer-base
 MT / WMT14 en-de (bucketed DDP path)".  On TPU the bucketed-allreduce
 overlap is XLA's latency-hiding scheduler's job — this config is plain DP
-and the collectives microbench (bench.py --collectives) quantifies overlap.
+and the collectives microbench (``tadnn bench``) quantifies overlap.
 
 Usage::
 

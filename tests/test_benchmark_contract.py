@@ -1,0 +1,606 @@
+"""What ``benchmark/`` takes from the program BY NAME, held in tier-1.
+
+The driver measures a PR by running ``benchmark/run.py``, and only a
+``benchmark`` PR may edit that directory.  The benchmark reads the program
+by name: engine keywords from ``benchmark/traffic/*.json``, model keys from
+``benchmark/configs/*.json``, fields of ``serve.step`` and ``serve.engine``
+events, phases, the names of the two serving programs and of the Pallas
+kernels.  A PR that renames one of them passes every other test here and
+the driver then records a ``null`` metric, or no run at all.  Each case
+below holds ONE name, so that it fails alone and says which file reads it.
+
+The names are read from the benchmark's own files when this module is
+collected (JSON, and the ``.py`` sources through ``ast``: no benchmark code
+runs then); the literal lists further down are the fields its readers
+index, each beside the file and line that does.  Nothing is written, and
+nothing here is a device number: the engines are the cells' own
+``rehearsal`` sizes on the CPU.
+"""
+
+from __future__ import annotations
+
+import ast
+import dataclasses
+import functools
+import importlib
+import importlib.util
+import inspect
+import json
+import math
+import os
+import re
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmark")
+PKG = "torch_automatic_distributed_neural_network_tpu"
+
+
+def _json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+BENCHMARK = _json(os.path.join(REPO, "BENCHMARK.json"))
+CONFIGS = {c["name"]: _json(os.path.join(REPO, c["file"]))
+           for c in BENCHMARK["configs"]}
+MIXES = {t: _json(os.path.join(BENCH, "traffic", t + ".json"))
+         for t in sorted({w["traffic"] for w in BENCHMARK["workloads"]})}
+# what runs in a cell: not the benchmark's own tests
+SOURCES = sorted(
+    os.path.join(BENCH, sub, f)
+    for sub in ("", "lib", "generators", "metrics", "reference")
+    for f in os.listdir(os.path.join(BENCH, sub)) if f.endswith(".py"))
+METRIC_FILES = sorted(f for f in os.listdir(os.path.join(BENCH, "metrics"))
+                      if f.endswith(".py"))
+
+
+def _rel(path: str) -> str:
+    return os.path.relpath(path, REPO)
+
+
+def _trees():
+    for path in SOURCES:
+        with open(path) as f:
+            yield path, ast.parse(f.read())
+
+
+def _callee(node: ast.Call) -> str | None:
+    f = node.func
+    return f.id if isinstance(f, ast.Name) else \
+        f.attr if isinstance(f, ast.Attribute) else None
+
+
+def _named(table: dict, name, where: str) -> None:
+    table.setdefault(name, []).append(where)
+
+
+# -- 1. keywords the benchmark passes to the program's callables -------------
+
+# callee as the benchmark's sources spell it -> where the program keeps it
+CALLEES = {
+    "ServeEngine": (PKG + ".inference.serve", "ServeEngine"),
+    "submit": (PKG + ".inference.serve", "ServeEngine.submit"),
+    "AutoDistribute": (PKG, "AutoDistribute"),
+    "Trainer": (PKG + ".training", "Trainer"),
+    "TrainerConfig": (PKG + ".training", "TrainerConfig"),
+    "fit": (PKG + ".training", "Trainer.fit"),
+    "SyntheticLM": (PKG + ".data.synthetic", "SyntheticLM"),
+    "Journal": (PKG + ".obs.journal", "Journal"),
+    "TransformerConfig": (PKG + ".models.transformer_core",
+                          "TransformerConfig"),
+}
+
+
+def _keywords() -> dict:
+    """(callee, keyword) -> the places of the benchmark that pass it: the
+    explicit keywords of each call in its sources, and the keys of the
+    groups of its data files that a call takes with ``**``."""
+    kws: dict = {}
+    for path, tree in _trees():
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and _callee(node) in CALLEES:
+                for kw in node.keywords:
+                    if kw.arg is not None:
+                        _named(kws, (_callee(node), kw.arg),
+                               f"{_rel(path)}:{node.lineno}")
+    for name, mix in MIXES.items():
+        where = f"benchmark/traffic/{name}.json"
+        # lib/serving.py, lib/serving_large.py: ServeEngine(**mix["engine"]);
+        # generators/train.py: AutoDistribute(**mix["autodistribute"]),
+        # TrainerConfig(**mix["trainer"]); lib/program.py: the mix's
+        # model_options go into TransformerConfig(**keys)
+        for group, callee in (("engine", "ServeEngine"),
+                              ("autodistribute", "AutoDistribute"),
+                              ("trainer", "TrainerConfig"),
+                              ("model_options", "TransformerConfig")):
+            for doc, tag in ((mix, group),
+                             (mix.get("rehearsal") or {},
+                              "rehearsal." + group)):
+                for k in doc.get(group) or ():
+                    _named(kws, (callee, k), f"{where} {tag}")
+    for name, cfg in CONFIGS.items():
+        where = f"benchmark/configs/{name}.json"
+        for doc, tag in ((cfg, "model"),
+                         (cfg.get("rehearsal") or {}, "rehearsal.model")):
+            for k in doc.get("model") or ():
+                _named(kws, ("TransformerConfig", k), f"{where} {tag}")
+    # lib/program.py:22: keys["dtype"] = jnp.dtype(config["compute_dtype"])
+    _named(kws, ("TransformerConfig", "dtype"), "benchmark/lib/program.py:22")
+    return kws
+
+
+KEYWORDS = _keywords()
+
+
+def _resolve(callee: str):
+    module, path = CALLEES[callee]
+    obj = importlib.import_module(module)
+    for part in path.split("."):
+        obj = getattr(obj, part)
+    return obj
+
+
+@pytest.mark.parametrize(
+    "callee,keyword", sorted(KEYWORDS),
+    ids=[f"{c}-{k}" for c, k in sorted(KEYWORDS)])
+def test_a_keyword_the_benchmark_passes_is_a_parameter(callee, keyword):
+    params = inspect.signature(_resolve(callee)).parameters
+    assert not any(p.kind is p.VAR_KEYWORD for p in params.values()), \
+        f"{callee} takes **kwargs: this test can no longer hold its names"
+    assert keyword in params, (
+        f"{CALLEES[callee][1]} has no parameter {keyword!r}; passed by "
+        + ", ".join(KEYWORDS[(callee, keyword)]))
+
+
+def test_the_cells_engine_keywords_were_found():
+    """The cases above are as many as the benchmark's files name; that the
+    reading itself works is held here, against the two every cell has."""
+    got = {k for c, k in KEYWORDS if c == "ServeEngine"}
+    assert {"n_slots", "max_len", "journal", "export_cache"} <= got
+    assert all(MIXES[w["traffic"]].get("kind") for w in BENCHMARK["workloads"])
+
+
+# -- 2. names the benchmark imports from the program -------------------------
+
+
+def _imports() -> dict:
+    names: dict = {}
+    for path, tree in _trees():
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.ImportFrom) and node.module
+                    and node.module.split(".")[0] == PKG):
+                for a in node.names:
+                    _named(names, (node.module, a.name),
+                           f"{_rel(path)}:{node.lineno}")
+    return names
+
+
+IMPORTS = _imports()
+
+
+@pytest.mark.parametrize(
+    "module,name", sorted(IMPORTS),
+    ids=[f"{m.removeprefix(PKG).lstrip('.') or 'package'}-{n}"
+         for m, n in sorted(IMPORTS)])
+def test_a_name_the_benchmark_imports_is_there(module, name):
+    mod = importlib.import_module(module)
+    assert hasattr(mod, name), (
+        f"{module} has no {name!r}; imported by "
+        + ", ".join(IMPORTS[(module, name)]))
+
+
+# -- 3. the cells' own rehearsal engines, served on the CPU ------------------
+
+
+@pytest.fixture(scope="module")
+def bench():
+    """``benchmark/`` importable as the benchmark imports itself (``lib``,
+    ``reference`` at top level), for this module only."""
+    before = set(sys.modules)
+    sys.path.insert(0, BENCH)
+    try:
+        yield importlib.import_module("lib.harness")
+    finally:
+        sys.path.remove(BENCH)
+        for m in set(sys.modules) - before:
+            if m.split(".")[0] in ("lib", "reference") \
+                    or m.startswith("bench_"):
+                del sys.modules[m]
+
+
+def _serve(bench, workload: str) -> dict:
+    """The cell at its ``rehearsal`` sizes, built as ``lib/serving.py``
+    builds it (weights from ``model.init``: no number is compared), a few
+    prompts served, and the record in the shape ``lib/serving.py:232-238``
+    and ``lib/serving_large.py:297-304`` give the readers.  No device
+    trace."""
+    from lib import program
+
+    from torch_automatic_distributed_neural_network_tpu.inference.serve import (
+        ServeEngine,
+    )
+    from torch_automatic_distributed_neural_network_tpu.obs.journal import (
+        Journal,
+    )
+
+    cell = bench.Cell(workload)
+    bench.apply_rehearsal(cell)
+    mix, keys = cell.mix, program.model_keys(cell.config)
+    model = program.build_model(cell.config, mix.get("model_options"))
+    variables = model.init(jax.random.key(0), np.zeros((1, 8), np.int32))
+    journal = Journal(None, host0_only=False)
+    eng = ServeEngine(model, variables, journal=journal, export_cache=False,
+                      **mix["engine"])
+    rs = np.random.RandomState(0)
+    chunk = mix["engine"]["prefill_chunk"]
+    reqs = [eng.submit([int(t) for t in rs.randint(1, keys["vocab_size"],
+                                                   size=n)],
+                       max_new_tokens=m)
+            # under a chunk, over one, over two: steps with and without one
+            for n, m in ((chunk // 3, 4), (chunk + 4, 6), (2 * chunk + 5, 3))]
+    eng.run()
+    record = {
+        "cell": cell, "chips": 1,
+        "serve_steps": journal.named("serve.step"),
+        "requests": [{
+            "t_admit": r.t_admit, "t_first": r.t_first_token,
+            "walls": list(r.token_walls), "prompt": list(r.prompt),
+            "out": list(r.out_tokens), "max_new": r.max_new_tokens,
+            "done": r.t_done is not None} for r in reqs],
+        "serve_engine": (journal.named("serve.engine") or [None])[-1],
+        "model_keys": keys, "engine": mix["engine"],
+    }
+    return {"eng": eng, "journal": journal, "requests": reqs,
+            "record": record}
+
+
+def _cell_of(config: str) -> str:
+    """The first serving cell of a configuration, by BENCHMARK.json."""
+    return next(w["name"] for w in BENCHMARK["workloads"]
+                if w["config"] == config
+                and MIXES[w["traffic"]]["kind"].startswith("serve"))
+
+
+@pytest.fixture(scope="module")
+def dense(bench):
+    return _serve(bench, _cell_of("gpt2-1p3b"))
+
+
+@pytest.fixture(scope="module")
+def experts(bench):
+    """A ``layer_types`` model with held experts."""
+    return _serve(bench, _cell_of("trinity-large-ep8"))
+
+
+# field of a ``serve.step`` event -> who indexes it (benchmark/ paths)
+STEP_FIELDS = {
+    "decode_s": "lib/readers.py:13 lib/serve_phases.py:77 lib/counts_moe.py:47",
+    "step_s": "lib/serve_phases.py:48,76 lib/serving_large.py:249",
+    "phases": "lib/serve_phases.py:46,76 lib/serving_large.py:256,278",
+    "t_end": "lib/serve_phases.py:66-67 lib/counts_moe.py:47-48",
+    "occupancy": "metrics/slot_occupancy.py:9",
+    "new_tokens": "lib/serving_large.py:264",
+    "t": "sweep.py:63",
+    "n_queued": "sweep.py:66,78",
+    "n_active": "sweep.py:66",
+}
+# the same, on a model with expert layers only
+STEP_FIELDS_EXPERTS = {
+    "moe_pairs": "lib/serving_large.py:263,271 metrics/moe_grouped_mm_roofline.py:26",
+    "moe_experts_touched": "lib/serving_large.py:276 metrics/moe_grouped_mm_roofline.py:18,25",
+    "moe_max_expert_tokens": "metrics/moe_grouped_mm_roofline.py:33",
+}
+# field of the ``serve.engine`` event (the record's ``serve_engine``)
+ENGINE_FIELDS = {
+    "kv_bytes_full": "metrics/kv_pool_gib.py:10,13,16",
+    "kv_bytes_window": "metrics/kv_pool_gib.py:10,14,16",
+    "layer_kinds": "metrics/kv_pool_gib.py:15",
+    "experts_held": "lib/serving_large.py:39-40,300 (the record's serve_engine)",
+    "experts_published": "lib/serving_large.py:39-40,300",
+}
+# attribute of the engine -> who takes it
+ENGINE_ATTRS = {
+    "submit": "lib/serving.py:63,91",
+    "run": "lib/serving.py:65",
+    "step": "lib/serving.py:101",
+    "finished": "lib/serving.py:66 sweep.py:60",
+    "scheduler": "lib/serving.py:75 (.idle(): :85,:95)",
+}
+# attribute of a request that ``submit`` returned
+REQUEST_ATTRS = {
+    "t_admit": "lib/serving.py:191 lib/serving_large.py:227",
+    "t_first_token": "lib/serving.py:191 sweep.py:70",
+    "token_walls": "lib/serving.py:192 sweep.py:73",
+    "prompt": "lib/serving.py:192",
+    "out_tokens": "lib/serving.py:193",
+    "max_new_tokens": "lib/serving.py:193",
+    "t_done": "lib/serving.py:194",
+}
+
+
+def _steps(run: dict) -> list[dict]:
+    steps = run["journal"].named("serve.step")
+    assert len(steps) >= 6, "the engine hardly stepped"
+    return steps
+
+
+@pytest.mark.parametrize("field", sorted(STEP_FIELDS))
+def test_serve_step_carries_a_field_the_benchmark_indexes(dense, experts,
+                                                          field):
+    for run in (dense, experts):
+        for s in _steps(run):
+            assert s.get(field) is not None, (
+                f"serve.step has no {field!r}; read by benchmark/ "
+                + STEP_FIELDS[field])
+
+
+@pytest.mark.parametrize("field", sorted(STEP_FIELDS_EXPERTS))
+def test_serve_step_of_an_expert_model_carries_its_counters(experts, field):
+    # they come back with a decode step's tokens, so not on every step
+    got = [s.get(field) for s in _steps(experts)]
+    assert sum(v is not None for v in got) >= len(got) // 2, (
+        f"serve.step has no {field!r}; read by benchmark/ "
+        + STEP_FIELDS_EXPERTS[field])
+    assert all(isinstance(v, int) and v >= 0 for v in got if v is not None)
+
+
+@pytest.mark.parametrize("field", sorted(ENGINE_FIELDS))
+def test_serve_engine_carries_a_field_the_benchmark_indexes(experts, field):
+    ev = experts["record"]["serve_engine"]
+    assert ev is not None, "no serve.engine event (Journal.named)"
+    assert ev.get(field) is not None, (
+        f"serve.engine has no {field!r}; read by benchmark/ "
+        + ENGINE_FIELDS[field])
+
+
+def test_serve_engine_says_what_the_cell_is_about(experts):
+    """``kv_pool_gib`` adds the two kinds of pages; a model with sliding
+    layers has both, and holds fewer experts than are published."""
+    ev = experts["record"]["serve_engine"]
+    assert ev["kv_bytes_full"] > 0 and ev["kv_bytes_window"] > 0
+    assert 0 < ev["experts_held"] < ev["experts_published"]
+    assert set(ev["layer_kinds"]) == set(
+        experts["record"]["model_keys"]["layer_types"])
+
+
+@pytest.mark.parametrize("attr", sorted(ENGINE_ATTRS))
+def test_the_engine_has_what_the_generators_take(dense, attr):
+    assert hasattr(dense["eng"], attr), (
+        f"ServeEngine has no {attr!r}; taken by benchmark/ "
+        + ENGINE_ATTRS[attr])
+
+
+def test_the_engine_drains_as_the_generators_expect(dense):
+    """``_drive`` steps until ``scheduler.idle()``; ``_warm`` clears
+    ``finished``; ``Journal.named`` gives the window's events."""
+    eng = dense["eng"]
+    assert eng.scheduler.idle() is True
+    assert {id(r) for r in dense["requests"]} <= {id(r) for r in eng.finished}
+    assert callable(dense["journal"].named)
+    assert dense["journal"].named("serve.engine")
+
+
+@pytest.mark.parametrize("attr", sorted(REQUEST_ATTRS))
+def test_a_request_has_what_the_generators_take(dense, attr):
+    for r in dense["requests"]:
+        assert getattr(r, attr, None) is not None, (
+            f"a finished request has no {attr!r}; taken by benchmark/ "
+            + REQUEST_ATTRS[attr])
+
+
+def test_a_requests_stamps_mean_what_the_generators_compute(dense):
+    """``itl_p95_ms`` is the gaps of ``token_walls``; ``serve_tokens_per_s``
+    counts them; a finished request has ``max_new_tokens`` of them."""
+    for r in dense["requests"]:
+        assert len(r.token_walls) == len(r.out_tokens) == r.max_new_tokens
+        assert list(r.token_walls) == sorted(r.token_walls)
+        assert r.t_admit <= r.t_first_token == r.token_walls[0] <= r.t_done
+
+
+# -- 4. phases, program names, kernel names -----------------------------------
+
+
+def _load(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# stdlib imports only: safe to run while this module is collected
+WAITS = _load(os.path.join(BENCH, "lib", "serve_phases.py"),
+              "benchmark_serve_phases").WAITS
+
+
+@pytest.mark.parametrize("wait", WAITS)
+def test_a_wait_the_benchmark_subtracts_is_a_phase_of_the_engine(wait):
+    from torch_automatic_distributed_neural_network_tpu.inference.serve.engine import (
+        PHASES,
+    )
+
+    assert wait in PHASES, (
+        f"benchmark/lib/serve_phases.py:18 WAITS names {wait!r}, which the "
+        f"engine does not declare ({PHASES}): serve_host_ms would count "
+        "the wait as the host's own time")
+
+
+def test_decode_wait_is_timed_on_every_step_that_reads(dense, experts):
+    # lib/serving_large.py:278 and serve_host_ms take it from ``phases``
+    for run in (dense, experts):
+        waits = [s["phases"].get("decode_wait") for s in _steps(run)]
+        assert sum(w is not None for w in waits) >= len(waits) // 2
+
+
+def _strings(pattern: str) -> dict:
+    """Every match of ``pattern`` in the text (code and docstrings) of the
+    files under benchmark/lib, metrics and generators -> where."""
+    found: dict = {}
+    for path in SOURCES:
+        if os.path.basename(os.path.dirname(path)) not in (
+                "lib", "metrics", "generators"):
+            continue
+        with open(path) as f:
+            for n, line in enumerate(f, 1):
+                for m in re.findall(pattern, line):
+                    _named(found, m, f"{_rel(path)}:{n}")
+    return found
+
+
+KERNELS = _strings(r"tadnn_[a-z0-9_]*[a-z0-9]")
+PROGRAMS = _strings(r"jit_serve_[a-z0-9_]*[a-z0-9]")
+
+
+@functools.cache
+def _pallas_names() -> frozenset[str]:
+    """The ``name=`` of every ``pallas_call`` under ``ops/`` (a name chosen
+    by a condition gives each of its strings)."""
+    names = set()
+    ops = os.path.join(REPO, PKG, "ops")
+    for f in sorted(os.listdir(ops)):
+        if not f.endswith(".py"):
+            continue
+        with open(os.path.join(ops, f)) as fh:
+            tree = ast.parse(fh.read())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) and _callee(node) == "pallas_call":
+                for kw in node.keywords:
+                    if kw.arg == "name":
+                        names |= {c.value for c in ast.walk(kw.value)
+                                  if isinstance(c, ast.Constant)
+                                  and isinstance(c.value, str)}
+    return frozenset(names)
+
+
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_a_kernel_the_benchmark_tells_by_name_is_a_pallas_call(kernel):
+    # the readers match by substring (``"tadnn_moe_grouped_mm" in name``)
+    have = _pallas_names()
+    assert any(kernel in n for n in have), (
+        f"no pallas_call under ops/ is named {kernel}* (have "
+        f"{sorted(have)}); named by " + ", ".join(KERNELS[kernel]))
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_a_program_the_benchmark_names_is_a_serving_program(dense, program):
+    eng = dense["eng"]
+    heads = [eng.compiled_decode_text().splitlines()[0],
+             eng._prefill_fn.lower(
+                 *eng._abstract_prefill_args()).as_text().splitlines()[0]]
+    names = set(re.findall(r"jit_serve_[a-z0-9_]+", " ".join(heads)))
+    assert program in names, (
+        f"the engine's programs are {sorted(names)}, not {program}; named "
+        "by " + ", ".join(PROGRAMS[program]))
+
+
+def test_the_names_were_found():
+    assert any(k.startswith("tadnn_paged_decode") for k in KERNELS)
+    assert any(k.startswith("tadnn_moe_grouped_mm") for k in KERNELS)
+    assert len(PROGRAMS) >= 2 and len(WAITS) >= 1
+
+
+# -- 5. every reader under benchmark/metrics/ reads the CPU run's record ----
+
+
+@pytest.mark.parametrize("metric_file", METRIC_FILES)
+def test_a_metric_reader_reads_the_programs_record(bench, dense, experts,
+                                                   metric_file, capsys):
+    reader = _load(os.path.join(BENCH, "metrics", metric_file),
+                   "bench_metric")
+    assert callable(getattr(reader, "read", None)), metric_file
+    name = metric_file.removesuffix(".py")
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
+    configs = {w["config"] for w in BENCHMARK["workloads"]
+               if w["name"] in entry["workloads"]}
+    for config, run in (("gpt2-1p3b", dense), ("trinity-large-ep8", experts)):
+        if config not in configs:
+            continue
+        value = reader.read(run["record"])
+        assert value is None or math.isfinite(value), (metric_file, value)
+    capsys.readouterr()  # readers print their working
+
+
+# what the program's events make readable without a device trace
+READS_ON_CPU = ("decode_step_ms.backlog", "decode_step_ms.steady",
+                "serve_host_ms.backlog", "serve_host_ms.steady",
+                "slot_occupancy", "kv_pool_gib")
+
+
+@pytest.mark.parametrize("name", READS_ON_CPU)
+def test_a_reader_of_events_alone_gives_a_number(bench, experts, name,
+                                                 capsys):
+    reader = _load(os.path.join(BENCH, "metrics", name + ".py"),
+                   "bench_metric")
+    value = reader.read(experts["record"])
+    capsys.readouterr()
+    assert value is not None and math.isfinite(value) and value > 0, name
+
+
+# -- 6. the train cell's calls into AutoDistribute and Trainer ---------------
+
+
+def test_the_train_generators_calls_hold_on_a_tiny_model(bench):
+    """``generators/train.py`` closes its window by raising StopIteration
+    from a step-indexed feed, hands ``fit`` a state, reads the plan, the
+    precision and the compile report, and is called back with (step, state,
+    metrics) where ``metrics["loss"]`` is the step's loss."""
+    import optax
+
+    import torch_automatic_distributed_neural_network_tpu as tad
+    from lib import program
+    from torch_automatic_distributed_neural_network_tpu.data.synthetic import (
+        SyntheticLM,
+    )
+    from torch_automatic_distributed_neural_network_tpu.obs.journal import (
+        Journal,
+    )
+    from torch_automatic_distributed_neural_network_tpu.training import (
+        Trainer,
+        TrainerConfig,
+        next_token_loss,
+    )
+
+    cell = bench.Cell(next(
+        w["name"] for w in BENCHMARK["workloads"]
+        if MIXES[w["traffic"]]["kind"] == "train"))
+    bench.apply_rehearsal(cell)
+    mix, keys = cell.mix, program.model_keys(cell.config)
+    model = program.build_model(cell.config, mix.get("model_options"))
+    data = SyntheticLM(vocab_size=keys["vocab_size"],
+                       seq_len=mix["seq_len"] + 1,
+                       batch_size=mix["batch_size"], seed=3)
+
+    class Feed:
+        step_indexed = True
+
+        def batch(self, i):
+            if i >= 3:
+                raise StopIteration
+            return data.batch(i)
+
+    ad = tad.AutoDistribute(
+        model, optimizer=optax.adamw(1e-3), loss_fn=next_token_loss,
+        devices=jax.devices()[:1], export_cache=False,
+        **mix["autodistribute"])
+    rng = jax.random.key(0)
+    state = ad.init(rng, data.batch(0))
+    assert ad.precision.param_dtype is not None
+    assert isinstance(ad.plan.strategy, str) and ad.plan.remat is not None
+    assert dataclasses.is_dataclass(state) and state.params is not None \
+        and state.opt_state is not None
+    seen = []
+    trainer = Trainer(
+        ad, TrainerConfig(steps=10**9, log_every=0, **mix["trainer"]),
+        callbacks=[lambda i, st, m: seen.append((i, float(m["loss"])))],
+        items_per_step=mix["batch_size"] * mix["seq_len"],
+        journal=Journal(None, host0_only=False))
+    trainer.fit(Feed(), state=state)
+    assert [i for i, _ in seen] == [1, 2, 3]
+    assert all(math.isfinite(x) for _, x in seen)
+    report = ad.compile_report(rng, data.batch(0))
+    assert (report or {}).get("per_device_peak_bytes"), \
+        "train_step_hbm_gib reads per_device_peak_bytes of compile_report"

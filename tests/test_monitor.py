@@ -1,9 +1,9 @@
 """Live serving telemetry tests: per-request span timelines from the
 engine (serve.request_done), the streaming window aggregator and its
-mergeable latency sketch (obs/live), the hysteresis SLO monitor and
-planner drift detection (obs/slo_monitor + tadnn monitor CLI),
-Journal.follow tail iteration, serve-journal merging, and report
-rendering of the new timeline/incident/drift sections."""
+mergeable latency sketch (obs/live), the hysteresis SLO monitor
+(obs/slo_monitor + tadnn monitor CLI), Journal.follow tail iteration,
+serve-journal merging, and report rendering of the timeline and
+incident sections."""
 
 import json
 import random
@@ -25,7 +25,6 @@ from torch_automatic_distributed_neural_network_tpu.obs.live import (
 from torch_automatic_distributed_neural_network_tpu.obs.slo_monitor import (
     MonitorPolicy,
     SLOMonitor,
-    drift_check,
     format_summary,
     monitor_records,
     window_prediction,
@@ -258,39 +257,6 @@ def test_slo_absence_is_violation_live():
     assert not ok and "no prediction" in violations[0]
 
 
-# -- planner drift ------------------------------------------------------------
-
-
-def test_drift_band_crosscheck_r05():
-    rec = json.load(open("SERVE_BENCH_r05.json"))
-    sink = Journal(None, host0_only=False)
-    res = drift_check(rec["value"], rec["extra"], journal=sink)
-    # the committed measurement must sit inside its own replay's 2x
-    # band (the same invariant report.check_simulate enforces)
-    assert res["within_band"] is True
-    assert 0.5 <= res["ratio"] <= 2.0
-    assert not [r for r in sink.records
-                if r["name"] == "simulate.drift"]
-    # a 10x-off measurement journals the drift event
-    res = drift_check(rec["value"] * 10, rec["extra"], journal=sink)
-    assert res["within_band"] is False
-    drifts = [r for r in sink.records if r["name"] == "simulate.drift"]
-    assert len(drifts) == 1 and drifts[0]["ratio"] > 2.0
-
-
-def test_replay_predicts_ttft_and_itl():
-    from torch_automatic_distributed_neural_network_tpu.tune.simulate import (
-        replay_bench_record,
-    )
-
-    rec = json.load(open("SERVE_BENCH_r05.json"))
-    sim = replay_bench_record(rec["extra"])
-    assert sim["ttft_p99_s"] is not None and sim["ttft_p99_s"] > 0
-    assert sim["itl_p50_s"] is not None and sim["itl_p50_s"] > 0
-    # first token cannot arrive after the whole request finished
-    assert sim["ttft_p99_s"] <= sim["p99_s"]
-
-
 # -- tadnn monitor CLI --------------------------------------------------------
 
 
@@ -350,11 +316,8 @@ def test_monitor_cli_incident_journal_renders_in_report(tmp_path):
 # -- report rendering ---------------------------------------------------------
 
 
-def test_report_renders_timeline_and_drift(tmp_path):
+def test_report_renders_timeline(tmp_path):
     recs = _degraded_journal()
-    recs.append({"kind": "event", "name": "simulate.drift", "t": 40.0,
-                 "predicted_tok_s": 100.0, "measured_tok_s": 10.0,
-                 "ratio": 0.1, "band": 2.0})
     jpath = tmp_path / "journal.jsonl"
     _write_journal(jpath, recs)
     rep = obs_report.generate(str(jpath), None)
@@ -362,10 +325,8 @@ def test_report_renders_timeline_and_drift(tmp_path):
     assert sv["ttft_p50_s"] == pytest.approx(0.05)
     assert sv["itl_p99_s"] == pytest.approx(0.01)
     assert sv["phase_mean_s"]["queue"] == pytest.approx(0.01)
-    assert rep["drift"][0]["ratio"] == pytest.approx(0.1)
     text = obs_report.format_report(rep)
     assert "timeline: ttft p50" in text
-    assert "planner drift" in text and "outside 2x band" in text
 
 
 def test_report_accepts_legacy_serve_request_name(tmp_path):

@@ -139,9 +139,8 @@ def test_search_strategy_moe_ladder(devices8):
 
 def test_compile_report_abstract_only(devices8):
     """compile_report AOT-compiles the sharded step without materializing
-    any state (the memfit path, bench.py mode=memfit / BASELINE.md row 4):
-    per-device argument bytes must reflect the fsdp=8 shard, not the full
-    model."""
+    any state: per-device argument bytes must reflect the fsdp=8 shard,
+    not the full model."""
     import numpy as np
     import optax
 

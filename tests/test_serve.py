@@ -1,8 +1,7 @@
 """tadnn serve tests: paged-KV allocator and scheduler invariants
 (cheap, host-only — tier-1), continuous-batching token parity with
 sequential generate() on the CPU sim mesh (slow), serving telemetry
-rendering through tadnn report, the serve_estimate capacity lint, and
-the SERVE_BENCH freshness family of check_bench."""
+rendering through tadnn report, and the serve_estimate capacity lint."""
 
 import dataclasses
 import json
@@ -429,68 +428,6 @@ def test_serve_estimate_dense_charges_gather_workspace():
     assert dense["max_streams"] <= paged["max_streams"]
     with pytest.raises(ValueError, match="attention_impl"):
         serve_estimate(_cfg(), budget="1MiB", attention_impl="fused")
-
-
-# -- SERVE bench freshness family ---------------------------------------------
-
-
-def _write(path, obj):
-    with open(path, "w") as f:
-        json.dump(obj, f)
-
-
-def _fresh_bench(tmp_path):
-    _write(tmp_path / "BENCH_r01.json",
-           {"metric": "tokens_per_sec", "value": 100.0})
-    _write(tmp_path / "BENCH_LAST_GOOD.json", {})
-
-
-def test_check_bench_serve_family_not_armed_without_artifacts(tmp_path):
-    _fresh_bench(tmp_path)
-    code, msgs = obs_report.check_bench(str(tmp_path))
-    assert code == 0
-    assert len(msgs) == 1  # no SERVE message before a serving round
-
-
-def test_check_bench_serve_family_fresh(tmp_path):
-    _fresh_bench(tmp_path)
-    # driver round format: bench_serve stdout wrapped under "parsed"
-    _write(tmp_path / "SERVE_BENCH_r01.json",
-           {"n": 1, "cmd": "python bench_serve.py", "rc": 0, "tail": "",
-            "parsed": {"metric": "serve_tokens_per_sec_cpu_sim",
-                       "value": 67.0}})
-    _write(tmp_path / "SERVE_LAST_GOOD.json",
-           {"serve": {"result": {"metric": "serve_tokens_per_sec_cpu_sim",
-                                 "value": 65.0},
-                      "measured_utc": "2026-08-05T00:00:00Z"}})
-    code, msgs = obs_report.check_bench(str(tmp_path))
-    assert code == 0
-    assert any("SERVE_BENCH_r01.json: fresh" in m for m in msgs)
-
-
-def test_check_bench_serve_family_stale_round_fails(tmp_path):
-    _fresh_bench(tmp_path)
-    _write(tmp_path / "SERVE_BENCH_r02.json",
-           {"metric": "serve_unmeasurable", "value": 0.0,
-            "status": "backend_unreachable", "stale": True,
-            "stale_of": "r01"})
-    code, msgs = obs_report.check_bench(str(tmp_path))
-    assert code == 1
-    assert any("stale" in m and "SERVE_BENCH_r02" in m for m in msgs)
-
-
-def test_check_bench_serve_family_regression_fails(tmp_path):
-    _fresh_bench(tmp_path)
-    _write(tmp_path / "SERVE_BENCH_r03.json",
-           {"parsed": {"metric": "serve_tokens_per_sec_cpu_sim",
-                       "value": 10.0}})
-    _write(tmp_path / "SERVE_LAST_GOOD.json",
-           {"serve": {"result": {"metric": "serve_tokens_per_sec_cpu_sim",
-                                 "value": 65.0},
-                      "measured_utc": "2026-08-05T00:00:00Z"}})
-    code, msgs = obs_report.check_bench(str(tmp_path))
-    assert code == 1
-    assert any("regressed" in m for m in msgs)
 
 
 # -- pure decision functions (scheduler refactor) -----------------------------

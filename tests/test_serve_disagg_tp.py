@@ -335,22 +335,6 @@ def test_replay_prices_kv_ship_and_dcn():
     assert taxed["wall_s"] > untaxed["wall_s"]
 
 
-def test_replay_bench_record_accepts_disaggregate_extra():
-    from torch_automatic_distributed_neural_network_tpu.tune.simulate \
-        import replay_bench_record
-
-    extra = {"streams": 8, "slots": 4, "prompt_len": 12, "max_new": 16,
-             "block_size": 8, "max_len": 64, "prefill_chunk": 32,
-             "new_tokens": 120, "disaggregate": True,
-             "breakdown": {"decode_step_ms": 2.0,
-                           "prefill_chunk_ms": 2.0}}
-    rep = replay_bench_record(extra)
-    assert rep["disaggregate"] is True
-    assert rep["n_finished"] == 8
-    assert rep["new_tokens"] == 120
-    assert rep["kv_ships"] >= 8  # every stream shipped at least once
-
-
 def test_simulate_policy_disaggregate_beats_colocated(devices8):
     """End-to-end sweep: on the same single-slice fleet the
     disaggregated policy cannot serve fewer tok/s than colocated (the

@@ -1,8 +1,7 @@
-"""Runtime tracing tests (obs/trace, obs/aggregate + journal hardening
-and the bench freshness guard): profiler capture + attribution on a real
-CPU-sim step, measured-vs-modeled collective bytes, multihost journal
-merge with seeded skew, report rendering, `tadnn report --check` exit
-codes, journal rotation and the torn-line reader."""
+"""Runtime tracing tests (obs/trace, obs/aggregate + journal hardening):
+profiler capture + attribution on a real CPU-sim step, measured-vs-modeled
+collective bytes, multihost journal merge with seeded skew, report
+rendering, journal rotation and the torn-line reader."""
 
 import json
 import os
@@ -359,105 +358,6 @@ def test_reader_skips_torn_lines_with_one_warning(tmp_path):
     with warnings.catch_warnings():
         warnings.simplefilter("error")  # second read: silent
         assert len(Journal.read(path)) == 2
-
-
-# ----------------------------------------- bench freshness guard (CLI)
-
-
-def _write_round(d, n, rec, wrapped=True):
-    payload = {"n": n, "cmd": "python bench.py", "rc": 0, "tail": "",
-               "parsed": rec} if wrapped else rec
-    p = os.path.join(d, f"BENCH_r{n:02d}.json")
-    with open(p, "w") as f:
-        json.dump(payload, f)
-    return p
-
-
-def _write_last_good(d, metric, value):
-    with open(os.path.join(d, "BENCH_LAST_GOOD.json"), "w") as f:
-        json.dump({"gpt2": {
-            "result": {"metric": metric, "value": value,
-                       "unit": "tokens/s/chip", "vs_baseline": 1.0,
-                       "extra": {}},
-            "measured_utc": "2026-07-31T01:04:15Z",
-            "device_kind": "TPU v5 lite",
-        }}, f)
-
-
-def test_check_fresh_record_passes(tmp_path):
-    _write_last_good(str(tmp_path), "gpt2_tokens", 1000.0)
-    _write_round(str(tmp_path), 6, {"metric": "gpt2_tokens",
-                                    "value": 980.0, "unit": "t/s"})
-    code, msgs = obs_report.check_bench(str(tmp_path))
-    assert code == 0 and "fresh" in msgs[0]
-    assert cli.main(["report", str(tmp_path), "--check"]) == 0
-
-
-def test_check_stale_record_fails(tmp_path):
-    _write_last_good(str(tmp_path), "gpt2_tokens", 1000.0)
-    _write_round(str(tmp_path), 6, {
-        "metric": "gpt2_backend_unreachable", "value": 0.0,
-        "status": "backend_unreachable", "stale": True, "stale_of": "r02",
-    })
-    code, msgs = obs_report.check_bench(str(tmp_path))
-    assert code == 1
-    assert "stale" in msgs[0] and "r02" in msgs[0]
-    assert cli.main(["report", str(tmp_path), "--check"]) == 1
-
-
-def test_check_picks_newest_round(tmp_path):
-    _write_last_good(str(tmp_path), "gpt2_tokens", 1000.0)
-    _write_round(str(tmp_path), 5, {"metric": "gpt2_tokens",
-                                    "value": 990.0, "unit": "t/s"})
-    _write_round(str(tmp_path), 6, {"metric": "gpt2_unmeasurable_backend_down",
-                                    "value": 0.0})
-    code, msgs = obs_report.check_bench(str(tmp_path))
-    assert code == 1 and "unmeasurable" in msgs[0]
-
-
-def test_check_regression_fails(tmp_path):
-    _write_last_good(str(tmp_path), "gpt2_tokens", 1000.0)
-    _write_round(str(tmp_path), 6, {"metric": "gpt2_tokens",
-                                    "value": 850.0, "unit": "t/s"})
-    code, msgs = obs_report.check_bench(str(tmp_path))
-    assert code == 1 and "regressed" in msgs[0]
-    # within the 10% band is fine
-    _write_round(str(tmp_path), 7, {"metric": "gpt2_tokens",
-                                    "value": 901.0, "unit": "t/s"})
-    code, _ = obs_report.check_bench(str(tmp_path))
-    assert code == 0
-
-
-def test_check_missing_record_fails(tmp_path):
-    code, msgs = obs_report.check_bench(str(tmp_path))
-    assert code == 1 and "no bench record" in msgs[0]
-
-
-def test_check_unwrapped_record_too(tmp_path):
-    # bench stdout saved directly (no driver wrapper) still checks
-    _write_round(str(tmp_path), 6, {"metric": "m", "value": 5.0},
-                 wrapped=False)
-    code, _ = obs_report.check_bench(str(tmp_path))
-    assert code == 0
-
-
-def test_repo_check_covers_the_committed_serving_rounds_alone():
-    # the repo commits serving rounds and no training rounds: --check
-    # must judge the family that exists and not fail the one that does not
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    code, msgs = obs_report.check_bench(repo)
-    assert code == 0, msgs
-    assert msgs and all(m.startswith("SERVE_BENCH_r") for m in msgs)
-    assert cli.main(["report", repo, "--check"]) == 0
-
-
-def test_check_serving_family_alone_in_a_directory(tmp_path):
-    _write_round(str(tmp_path), 1, {"metric": "serve_tokens", "value": 5.0})
-    os.rename(os.path.join(str(tmp_path), "BENCH_r01.json"),
-              os.path.join(str(tmp_path), "SERVE_BENCH_r01.json"))
-    code, msgs = obs_report.check_bench(str(tmp_path))
-    assert code == 0 and len(msgs) == 1
-    assert msgs[0].startswith("SERVE_BENCH_r01.json: fresh")
 
 
 # ----------------------------------------------- cost-model feedback

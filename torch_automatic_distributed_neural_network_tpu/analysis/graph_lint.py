@@ -29,6 +29,8 @@ COLLECTIVE_KINDS: dict[str, str] = {
     "all_gather_invariant": "gather",
     "psum": "reduce",
     "psum2": "reduce",
+    # what a psum traces to under shard_map's default check_vma=True
+    "psum_invariant": "reduce",
     "pmax": "reduce",
     "pmin": "reduce",
     "reduce_scatter": "scatter",

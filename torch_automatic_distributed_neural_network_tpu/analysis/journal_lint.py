@@ -651,7 +651,7 @@ def lint_sources(named: Sequence[tuple[str, str]], *,
 def default_paths(repo_root: pathlib.Path | str | None = None
                   ) -> list[pathlib.Path]:
     """The complete producer/consumer set: the package (+ alias) and the
-    loose top-level scripts.  tests/ and examples/ are deliberately
+    loose top-level script.  tests/ and examples/ are deliberately
     excluded — they emit synthetic kinds for their own fixtures."""
     if repo_root is None:
         repo_root = pathlib.Path(__file__).resolve().parents[2]
@@ -660,9 +660,8 @@ def default_paths(repo_root: pathlib.Path | str | None = None
     for rel in ("torch_automatic_distributed_neural_network_tpu", "tadnn"):
         if (repo_root / rel).is_dir():
             paths.append(repo_root / rel)
-    for rel in ("bench.py", "bench_serve.py", "chip_smoke.py"):
-        if (repo_root / rel).exists():
-            paths.append(repo_root / rel)
+    if (repo_root / "chip_smoke.py").exists():
+        paths.append(repo_root / "chip_smoke.py")
     return paths
 
 

@@ -284,7 +284,7 @@ def iter_py_files(paths: Iterable[pathlib.Path | str]) -> Iterator[pathlib.Path]
 
 def default_paths(repo_root: pathlib.Path | str | None = None) -> list[pathlib.Path]:
     """What ``tadnn check`` lints by default: the package, its alias,
-    tests, examples, and the loose top-level scripts — the same file set
+    tests, examples, and the loose top-level script — the same file set
     ``tests/test_def_hygiene.py`` has always guarded."""
     if repo_root is None:
         repo_root = pathlib.Path(__file__).resolve().parents[2]
@@ -294,9 +294,8 @@ def default_paths(repo_root: pathlib.Path | str | None = None) -> list[pathlib.P
                 "tests", "examples"):
         if (repo_root / rel).is_dir():
             paths.append(repo_root / rel)
-    for rel in ("bench.py", "bench_serve.py", "chip_smoke.py"):
-        if (repo_root / rel).exists():
-            paths.append(repo_root / rel)
+    if (repo_root / "chip_smoke.py").exists():
+        paths.append(repo_root / "chip_smoke.py")
     return paths
 
 

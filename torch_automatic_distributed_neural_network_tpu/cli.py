@@ -93,7 +93,7 @@ def cmd_profile(args: argparse.Namespace) -> int:
 
 
 def cmd_bench(args: argparse.Namespace) -> int:
-    """Collectives microbenchmark (allreduce bus-bw is a BASELINE metric)."""
+    """Collectives microbenchmark (allreduce bus bandwidth)."""
     from .parallel.collectives import bench_sweep
 
     ops = args.ops.split(",")
@@ -558,24 +558,9 @@ def cmd_report(args: argparse.Namespace) -> int:
     """Summarize a finished (or crashed) run from its on-disk artifacts:
     journal JSONL + MetricsLogger JSONL.  Pure file parsing — no jax
     import, so it works on a machine with no accelerator runtime.
-    ``--check`` instead runs the bench freshness guard; ``--merge``
-    joins per-host journals first (obs/aggregate)."""
+    ``--merge`` joins per-host journals first (obs/aggregate)."""
     from .obs import report as obs_report
 
-    if args.check:
-        code, msgs = obs_report.check_bench(
-            args.target, bench_path=args.bench,
-            last_good_path=args.last_good)
-        for m in msgs:
-            # per-message verdict: with two trajectories (BENCH + SERVE)
-            # one can be fresh while the other fails the aggregate code
-            print(("ok   " if ": fresh" in m else "FAIL ") + m)
-        return code
-    if getattr(args, "check_simulate", False):
-        code, msgs = obs_report.check_simulate(args.target)
-        for m in msgs:
-            print(("ok   " if "within 2x" in m else "FAIL ") + m)
-        return code
     if args.merge:
         from .obs import aggregate
 
@@ -597,13 +582,10 @@ def cmd_monitor(args: argparse.Namespace) -> int:
     """Continuous SLO monitor over a serving journal (obs/slo_monitor):
     fold ``serve.*`` events into rolling event-time windows, evaluate
     the ``--slo`` spec per window with hysteresis, journal
-    ``slo.breach`` / ``slo.recover`` incidents, and optionally compare
-    measured throughput against the simulate replay's prediction for a
-    committed bench record (``--drift``).  ``--follow`` tails a live
-    journal; the default deterministically replays a finished one —
+    ``slo.breach`` / ``slo.recover`` incidents.  ``--follow`` tails a
+    live journal; the default deterministically replays a finished one —
     with ``--check`` the exit code is the CI gate (nonzero on any
-    breach or out-of-band planner drift).  Pure file parsing unless
-    ``--drift`` is given — no accelerator needed."""
+    breach).  Pure file parsing — no accelerator needed."""
     from .obs import slo_monitor as slm
     from .obs.journal import Journal
     from .tune.slo import SLOSpec
@@ -628,12 +610,6 @@ def cmd_monitor(args: argparse.Namespace) -> int:
         breach_after=args.breach_after,
         recover_after=args.recover_after,
         n_chips=args.chips, warmup_windows=args.warmup_windows)
-    drift_extra = None
-    if args.drift:
-        with open(args.drift) as f:
-            rec = json.load(f)
-        # a full bench record or a bare extra dict both work
-        drift_extra = rec.get("extra") or rec
     # incidents land in their own sink: --replay must never append to
     # the (possibly committed) journal it is reading
     with Journal(args.incident_journal, host0_only=False,
@@ -642,8 +618,7 @@ def cmd_monitor(args: argparse.Namespace) -> int:
         records = (Journal.follow(args.journal,
                                   idle_timeout=args.idle_timeout)
                    if args.follow else Journal.read(args.journal))
-        summary = slm.monitor_records(
-            records, policy, journal=sink, drift_extra=drift_extra)
+        summary = slm.monitor_records(records, policy, journal=sink)
     if args.out:
         with open(args.out, "w") as f:
             json.dump(summary, f)
@@ -652,9 +627,7 @@ def cmd_monitor(args: argparse.Namespace) -> int:
     else:
         print(slm.format_summary(summary))
     if args.check:
-        drift_bad = ((summary.get("drift") or {}).get("within_band")
-                     is False)
-        return 1 if (summary["breaches"] or drift_bad) else 0
+        return 1 if summary["breaches"] else 0
     return 0
 
 
@@ -1789,33 +1762,17 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--metrics", default=None,
                    help="explicit MetricsLogger JSONL path")
     p.add_argument("--json", action="store_true")
-    p.add_argument("--check", action="store_true",
-                   help="bench freshness guard: exit nonzero when the "
-                        "latest BENCH_r*.json is stale-marked/missing "
-                        "or its headline regressed >10%% vs "
-                        "BENCH_LAST_GOOD.json")
-    p.add_argument("--bench", default=None,
-                   help="explicit bench record path for --check "
-                        "(default: newest BENCH_r*.json in target)")
-    p.add_argument("--last-good", default=None, dest="last_good",
-                   help="explicit BENCH_LAST_GOOD.json path for --check")
     p.add_argument("--merge", action="store_true",
                    help="merge per-host journals in the target directory "
                         "into journal.merged.jsonl before reporting")
-    p.add_argument("--check-simulate", action="store_true",
-                   dest="check_simulate",
-                   help="crosscheck the simulator against reality: "
-                        "replay the newest SERVE_BENCH record's config "
-                        "through the what-if serve replay and fail when "
-                        "prediction and measurement disagree by >2x")
     p.set_defaults(fn=cmd_report)
 
     p = sub.add_parser(
         "monitor",
         help="continuous SLO monitor over a serving journal: rolling "
              "TTFT/ITL/latency windows, slo.breach/slo.recover "
-             "incidents with hysteresis, planner drift vs the serve "
-             "replay (works offline; no accelerator needed)",
+             "incidents with hysteresis (works offline; no accelerator "
+             "needed)",
     )
     p.add_argument("journal", help="serving journal JSONL to monitor")
     p.add_argument("--slo", default=None,
@@ -1844,25 +1801,18 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--warmup-windows", type=int, default=1,
                    dest="warmup_windows",
                    help="leading traffic windows reported but not "
-                        "SLO-evaluated (they carry the jit compiles; "
-                        "same discipline as bench_serve's warm phase)")
+                        "SLO-evaluated (they carry the jit compiles)")
     p.add_argument("--chips", type=int, default=1,
                    help="chip count for tok_s_chip evaluation")
-    p.add_argument("--drift", default=None,
-                   help="SERVE_BENCH_r*.json record: compare measured "
-                        "throughput against the simulate replay's "
-                        "prediction and flag >2x planner drift")
     p.add_argument("--incident-journal", default=None,
                    dest="incident_journal",
-                   help="append slo.breach/slo.recover/simulate.drift "
-                        "events to this JSONL (renderable by tadnn "
-                        "report)")
+                   help="append slo.breach/slo.recover events to this "
+                        "JSONL (renderable by tadnn report)")
     p.add_argument("--out", default=None,
                    help="write the full monitor summary JSON here")
     p.add_argument("--json", action="store_true")
     p.add_argument("--check", action="store_true",
-                   help="exit nonzero on any breach or out-of-band "
-                        "drift — the CI gate")
+                   help="exit nonzero on any breach — the CI gate")
     p.set_defaults(fn=cmd_monitor)
 
     p = sub.add_parser(

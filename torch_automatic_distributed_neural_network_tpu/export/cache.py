@@ -196,8 +196,17 @@ class ExecutableCache:
                             (key + _PAYLOAD_EXT))
         with open(path, "rb") as f:
             payload, in_tree, out_tree = pickle.load(f)
+        # onto the devices it was compiled for: the default is every
+        # device of the backend, and a one-device program loaded so in a
+        # process that sees several rejects its arguments at the first call
+        devices = None
+        if rec.get("device_ids") is not None:
+            import jax
+
+            by_id = {d.id: d for d in jax.devices()}
+            devices = [by_id[i] for i in rec["device_ids"]]
         return serialize_executable.deserialize_and_load(
-            payload, in_tree, out_tree)
+            payload, in_tree, out_tree, execution_devices=devices)
 
     def store(self, key: str, compiled: Any, *, kind: str,
               meta: Mapping | None = None) -> dict:
@@ -219,6 +228,8 @@ class ExecutableCache:
             "env": env_fingerprint(),
             "created": time.time(),
             "payload_bytes": len(blob),
+            "device_ids": [
+                d.id for d in compiled.runtime_executable().local_devices()],
             "meta": dict(meta or {}),
         }
         tune_cache.store(key, rec, path=self.index_path, max_bytes=0)

@@ -64,7 +64,7 @@ def merge(journals: "Sequence[str] | Mapping[int, str]") -> list[dict]:
     ``{host_id: path}`` mapping.
 
     Records pass through untouched apart from the ``host`` tag —
-    serving telemetry (``serve.*``, ``slo.*``, ``simulate.drift``)
+    serving telemetry (``serve.*``, ``slo.*``)
     keeps every field, so ``tadnn report`` and ``tadnn monitor`` read
     a merged multihost serving journal exactly like a single-host one.
     """

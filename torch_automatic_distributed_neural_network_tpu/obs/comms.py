@@ -5,8 +5,7 @@ The analytic side lives in ``planner.expected_collective_bytes`` (pure
 function of plan + abstract shapes, unit-testable without devices); this
 module joins it with XLA's compiled-program ``cost_analysis`` so a run
 can report "the plan implies X bytes of collectives per step; XLA's
-executable touches Y bytes" — the observable that caught nothing in the
-BENCH_r05 incident because it did not exist.
+executable touches Y bytes".
 """
 
 from __future__ import annotations

@@ -601,15 +601,6 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                      if e.get("n_replicas") is not None]
             gw["final_replicas"] = final[-1] if final else None
         report["gateway"] = gw
-    # planner drift (obs/slo_monitor.drift_check): measured throughput
-    # left the simulate prediction's 2x band
-    drifts = [e for e in events if e.get("name") == "simulate.drift"]
-    if drifts:
-        report["drift"] = [
-            {"predicted_tok_s": e.get("predicted_tok_s"),
-             "measured_tok_s": e.get("measured_tok_s"),
-             "ratio": e.get("ratio"), "band": e.get("band")}
-            for e in drifts]
     lint_findings = [e for e in events if e.get("name") == "lint.finding"]
     lint_summary = last("lint.summary")
     lint_skipped = last("lint.skipped")
@@ -667,8 +658,7 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
     ssweep = last("simulate.sweep")
     scands = [e for e in events if e.get("name") == "simulate.candidate"]
     sdec = last("simulate.decision")
-    scross = last("simulate.crosscheck")
-    if ssweep or scands or sdec or scross:
+    if ssweep or scands or sdec:
         sim: dict[str, Any] = {}
         if ssweep:
             sim.update({k: ssweep.get(k)
@@ -688,14 +678,6 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                 ("topology", "plan", "admission", "slo_ok",
                  "slo_violations", "mfu", "tok_s_per_chip", "p99_s",
                  "hbm_headroom_frac", "survival")}
-        if scross:
-            sim["crosscheck"] = {
-                k: scross.get(k) for k in
-                ("record", "predicted_tok_s", "measured_tok_s",
-                 "tok_s_ratio", "predicted_occupancy",
-                 "measured_occupancy", "occupancy_ratio",
-                 "predicted_preemptions", "measured_preemptions",
-                 "within_2x")}
         report["simulate"] = sim
     if metrics_path and os.path.isfile(metrics_path):
         recs = _read_metrics(metrics_path)
@@ -1163,15 +1145,6 @@ def format_report(report: dict) -> str:
         if gw.get("final_replicas") is not None:
             lines.append(
                 f"  final fleet: {gw['final_replicas']} replica(s)")
-    drift = report.get("drift")
-    if drift:
-        for d in drift:
-            lines.append(
-                f"planner drift: measured "
-                f"{(d.get('measured_tok_s') or 0):.1f} tok/s vs "
-                f"predicted {(d.get('predicted_tok_s') or 0):.1f} "
-                f"(x{(d.get('ratio') or 0):.2f}, outside "
-                f"{(d.get('band') or 0):g}x band)")
     sest = report.get("serve_estimate")
     if sest:
         head = (f"serve estimate: {sest.get('max_streams')} stream(s) "
@@ -1232,16 +1205,6 @@ def format_report(report: dict) -> str:
                 f"  #{e.get('rank')} {e.get('topology')} "
                 f"{e.get('plan')} [{e.get('admission')}]  "
                 f"{mfu}  {step}  {hd}  {tok}  {p99}  {surv} " + tail)
-        cc = sim.get("crosscheck")
-        if cc:
-            lines.append(
-                f"  crosscheck vs {cc.get('record')}: "
-                f"tok/s {cc.get('predicted_tok_s')} predicted / "
-                f"{cc.get('measured_tok_s')} measured "
-                f"(ratio {cc.get('tok_s_ratio')}), "
-                f"occupancy ratio {cc.get('occupancy_ratio')}"
-                + ("" if cc.get("within_2x")
-                   else "  !! outside 2x band"))
     lint = report.get("lint")
     if lint:
         head = (f"lint ({lint.get('phase', 'check')}): "
@@ -1295,224 +1258,3 @@ def format_report(report: dict) -> str:
                 f"(static/compiled {me.get('static_over_compiled')}x)")
     return "\n".join(lines)
 
-
-# -- bench freshness guard (`tadnn report --check`) -------------------------
-
-# how much a headline value may drop vs BENCH_LAST_GOOD before the
-# check fails (the ISSUE's >10% regression gate)
-REGRESSION_TOLERANCE = 0.10
-
-
-def _load_bench_record(path: str) -> dict | None:
-    """One bench record from either bench.py stdout JSON or the driver's
-    round artifact (which wraps it under ``parsed``)."""
-    try:
-        with open(path) as f:
-            data = json.load(f)
-    except (OSError, ValueError):
-        return None
-    if isinstance(data, dict) and isinstance(data.get("parsed"), dict):
-        data = data["parsed"]
-    return data if isinstance(data, dict) else None
-
-
-def check_bench(target: str, *, bench_path: str | None = None,
-                last_good_path: str | None = None) -> tuple[int, list[str]]:
-    """The freshness guard behind ``tadnn report --check``.
-
-    Exit-nonzero conditions (each with a message):
-
-    - no bench record found (missing trajectory = the r03-r05 dark run);
-    - the latest record is stale-marked (``status:
-      "backend_unreachable"``, ``stale: true``, or an ``unmeasurable``
-      metric) — the round measured nothing;
-    - the headline value regressed more than
-    ``REGRESSION_TOLERANCE`` vs the committed BENCH_LAST_GOOD entry
-      for the same metric.
-
-    ``target`` is a directory holding ``BENCH_r*.json`` +
-    ``BENCH_LAST_GOOD.json`` (the repo root in CI); explicit paths
-    override discovery.  Returns ``(exit_code, messages)``.
-
-    A family — training (``BENCH_r*.json`` + ``BENCH_LAST_GOOD.json``)
-    or serving (``SERVE_BENCH_r*.json`` + ``SERVE_LAST_GOOD.json``) — is
-    checked whenever either of its artifacts exists in ``target``: once
-    a round has been committed it can never silently go stale, and a
-    checkout is not failed for a trajectory it never started.  A
-    directory holding neither fails as a missing training trajectory.
-    Explicit ``bench_path`` / ``last_good_path`` check the training
-    family alone.
-    """
-    import glob as _glob
-
-    d = target if os.path.isdir(target) else os.path.dirname(
-        os.path.abspath(target)) or "."
-    if bench_path is not None or last_good_path is not None:
-        return _check_bench_family(d, "BENCH", bench_path=bench_path,
-                                   last_good_path=last_good_path)
-    armed = [
-        prefix for prefix, last_good in (
-            ("BENCH", "BENCH_LAST_GOOD.json"),
-            ("SERVE_BENCH", "SERVE_LAST_GOOD.json"))
-        if _glob.glob(os.path.join(d, f"{prefix}_r*.json"))
-        or os.path.isfile(os.path.join(d, last_good))
-    ]
-    code, msgs = 0, []
-    for prefix in armed or ["BENCH"]:
-        fcode, fmsgs = _check_bench_family(
-            d, prefix, bench_path=None, last_good_path=None)
-        code = max(code, fcode)
-        msgs += fmsgs
-    return code, msgs
-
-
-def _check_bench_family(d: str, prefix: str, *,
-                        bench_path: str | None,
-                        last_good_path: str | None
-                        ) -> tuple[int, list[str]]:
-    """One trajectory's freshness check (``{prefix}_r*.json`` vs the
-    family's LAST_GOOD)."""
-    import glob as _glob
-
-    lg_name = ("BENCH_LAST_GOOD.json" if prefix == "BENCH"
-               else prefix.replace("_BENCH", "") + "_LAST_GOOD.json")
-    msgs: list[str] = []
-    if bench_path is None:
-        rounds = sorted(_glob.glob(os.path.join(d, f"{prefix}_r*.json")))
-        bench_path = rounds[-1] if rounds else None
-    if bench_path is None or not os.path.isfile(bench_path):
-        return 1, [f"no bench record ({prefix}_r*.json) found — the "
-                   + ("serving" if prefix != "BENCH" else "bench")
-                   + " trajectory is dark"]
-    rec = _load_bench_record(bench_path)
-    if rec is None:
-        return 1, [f"{bench_path}: unreadable bench record"]
-    name = os.path.basename(bench_path)
-    metric = str(rec.get("metric", ""))
-    if rec.get("status") == "backend_unreachable" or rec.get("stale"):
-        msgs.append(
-            f"{name}: stale ({rec.get('status') or 'stale-marked'}"
-            + (f", stale_of {rec['stale_of']}" if rec.get("stale_of")
-               else "")
-            + ") — this round measured nothing")
-    elif "unmeasurable" in metric:
-        msgs.append(f"{name}: unmeasurable ({metric})")
-    else:
-        lg_path = last_good_path or os.path.join(d, lg_name)
-        try:
-            with open(lg_path) as f:
-                last_good = json.load(f)
-        except (OSError, ValueError):
-            last_good = {}
-        for mode, entry in last_good.items():
-            res = (entry or {}).get("result") or {}
-            if res.get("metric") != metric or not res.get("value"):
-                continue
-            value = rec.get("value") or 0.0
-            floor = (1.0 - REGRESSION_TOLERANCE) * res["value"]
-            if value < floor:
-                msgs.append(
-                    f"{name}: {metric} = {value:g} regressed "
-                    f"{1.0 - value / res['value']:.1%} vs last good "
-                    f"{res['value']:g} ({mode}, "
-                    f"{entry.get('measured_utc', '?')})")
-            break
-    if not msgs:
-        msgs.append(f"{name}: fresh ({metric or 'no metric'}, "
-                    f"value {rec.get('value')})")
-        return 0, msgs
-    return 1, msgs
-
-
-# -- simulator crosscheck (`tadnn report --check-simulate`) ------------------
-
-# predicted/measured ratio band the replay must land in.  2x is loose on
-# purpose: the replay models scheduling exactly but step timings only to
-# a roofline, so it catches "the simulator lives in fantasy land", not
-# single-digit-percent drift (that is the --check regression gate's job).
-CROSSCHECK_BAND = 2.0
-
-
-def check_simulate(target: str) -> tuple[int, list[str]]:
-    """Falsify the what-if serve model against the newest real record.
-
-    Behind ``tadnn report --check-simulate``: finds the latest
-    ``SERVE_BENCH_r*.json`` in ``target``, replays its exact recorded
-    config (streams / slots / block size / chunking / measured per-step
-    timings) through the discrete-event scheduler replay, and compares
-    predicted vs measured throughput and occupancy.  Journals the
-    ratios as a ``simulate.crosscheck`` event (within-2x band, same
-    style as ``trace.collective``).  Exit nonzero when no record exists
-    (nothing to falsify against) or a ratio leaves the band — either
-    way the simulator's predictions should not be trusted unaudited.
-    """
-    import glob as _glob
-
-    d = target if os.path.isdir(target) else os.path.dirname(
-        os.path.abspath(target)) or "."
-    rounds = sorted(_glob.glob(os.path.join(d, "SERVE_BENCH_r*.json")))
-    if not rounds:
-        return 1, ["no serve bench record (SERVE_BENCH_r*.json) found — "
-                   "nothing to crosscheck the simulator against"]
-    path = rounds[-1]
-    rec = _load_bench_record(path)
-    if rec is None or not isinstance(rec.get("extra"), dict):
-        return 1, [f"{os.path.basename(path)}: unreadable serve bench "
-                   "record (no extra config to replay)"]
-    name = os.path.basename(path)
-    extra = rec["extra"]
-    # lazy: the replay pulls in the tune package (and with it jax);
-    # everything else in this module stays importable without it.
-    from ..tune.simulate import replay_bench_record
-
-    from . import journal
-
-    try:
-        sim = replay_bench_record(extra)
-    except (KeyError, TypeError, ValueError) as e:
-        return 1, [f"{name}: replay failed on recorded config: {e}"]
-    msgs: list[str] = []
-    within = True
-    measured_tok = rec.get("value") or 0.0
-    measured_occ = extra.get("mean_occupancy")
-    ratios: dict[str, float | None] = {"tok/s": None, "occupancy": None}
-    for label, predicted, measured in (
-            ("tok/s", sim.get("tokens_per_s"), measured_tok),
-            ("occupancy", sim.get("mean_occupancy"), measured_occ)):
-        if not measured or predicted is None:
-            msgs.append(f"{name}: {label} not comparable "
-                        f"(measured {measured!r})")
-            continue
-        ratio = predicted / measured
-        ratios[label] = round(ratio, 4)
-        ok = (1.0 / CROSSCHECK_BAND) <= ratio <= CROSSCHECK_BAND
-        within = within and ok
-        msgs.append(
-            f"{name}: {label} predicted {predicted:g} vs measured "
-            f"{measured:g}, ratio {ratio:.2f} "
-            + ("within 2x" if ok else "OUTSIDE 2x BAND"))
-    pred_pre = sim.get("preemptions", 0)
-    meas_pre = extra.get("preemptions")
-    if meas_pre is not None:
-        # count, not a rate: "within 2x" here means the replay predicts
-        # the same preemption regime (quiet pool vs thrashing pool).
-        ok = pred_pre <= 2 * max(meas_pre, 1) and \
-            meas_pre <= 2 * max(pred_pre, 1)
-        within = within and ok
-        msgs.append(
-            f"{name}: preemptions predicted {pred_pre} vs measured "
-            f"{meas_pre} " + ("within 2x" if ok else "OUTSIDE 2x BAND"))
-    journal.event(
-        "simulate.crosscheck",
-        record=name,
-        predicted_tok_s=sim.get("tokens_per_s"),
-        measured_tok_s=measured_tok or None,
-        tok_s_ratio=ratios["tok/s"],
-        predicted_occupancy=sim.get("mean_occupancy"),
-        measured_occupancy=measured_occ,
-        occupancy_ratio=ratios["occupancy"],
-        predicted_preemptions=pred_pre,
-        measured_preemptions=meas_pre,
-        within_2x=within,
-    )
-    return (0 if within else 1), msgs

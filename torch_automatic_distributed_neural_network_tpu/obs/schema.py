@@ -291,18 +291,6 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             "n_slo_ok": "int", "n_topologies": "int"}),
     _s("simulate.replay", "discrete-event serve replay result (dynamic "
        "payload)", req={"source": "str"}, open=True),
-    _s("simulate.crosscheck", "newest committed serve bench replayed; "
-       "prediction vs measurement",
-       req={"record": "str", "predicted_tok_s": "float?",
-            "measured_tok_s": "float?", "tok_s_ratio": "float?",
-            "within_2x": "bool?"},
-       opt={"predicted_occupancy": "float?",
-            "measured_occupancy": "float?", "occupancy_ratio": "float?",
-            "predicted_preemptions": "int?",
-            "measured_preemptions": "int?"}),
-    _s("simulate.drift", "live throughput outside the replay's band",
-       req={"predicted_tok_s": "float?", "measured_tok_s": "float?",
-            "ratio": "float", "band": "float"}),
 
     # -- static analysis ----------------------------------------------------
     _s("lint.finding", "one analyzer diagnosis",

@@ -8,7 +8,7 @@ SAME spec against each window's measured aggregates — one SLO
 language for planning and production, the precondition the ROADMAP's
 closed-loop autoscaling item names.
 
-Three pieces:
+Two pieces:
 
 - :class:`SLOMonitor` — per-window evaluation with hysteresis: a
   breach incident only after ``breach_after`` consecutive violating
@@ -16,11 +16,6 @@ Three pieces:
   noisy window cannot flap an alert.  Incidents are journaled as
   ``slo.breach`` / ``slo.recover`` events (renderable by ``tadnn
   report``) and collected for the summary.
-- :func:`drift_check` — planner drift: replay the committed
-  SERVE_BENCH config through ``tune/simulate`` and compare its
-  predicted throughput against the journal's measured throughput; a
-  ratio outside the 2x band journals ``simulate.drift`` — the
-  check-simulate falsification loop, run against live traffic.
 - :func:`monitor_records` — the driver: records in (a finished list
   or a live ``Journal.follow`` tail), summary dict out.  Everything is
   event-time, so ``--replay`` over a committed journal is
@@ -28,11 +23,9 @@ Three pieces:
   fails the build on any breach.
 
 The first ``warmup_windows`` traffic-bearing windows are reported but
-not SLO-evaluated: they carry the jit compiles, the same reasoning
-that makes bench_serve discard its warm phase.
+not SLO-evaluated: they carry the jit compiles.
 
-Pure stdlib (tune/simulate is imported lazily, only under drift
-checking); safe on a machine with no accelerator runtime.
+Pure stdlib; safe on a machine with no accelerator runtime.
 """
 
 from __future__ import annotations
@@ -43,10 +36,6 @@ from typing import Any, Iterable, Mapping
 from ..tune.slo import SLOSpec
 from . import journal as journal_mod
 from .live import LiveAggregator
-
-# measured/predicted throughput ratio allowed before the planner is
-# declared drifted — same band as obs/report.check_simulate
-DRIFT_BAND = 2.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,8 +90,7 @@ class SLOMonitor:
     def observe(self, window: Mapping[str, Any]) -> dict | None:
         self.n_windows += 1
         if self.n_windows <= self.policy.warmup_windows:
-            # compile-era windows: report, never judge (bench_serve
-            # discards its warm phase for the same reason)
+            # compile-era windows: report, never judge
             self.n_skipped_warmup += 1
             return None
         ok, violations = self.policy.slo.evaluate(
@@ -141,46 +129,9 @@ class SLOMonitor:
         return incident
 
 
-def drift_check(measured_tok_s: float | None,
-                extra: Mapping[str, Any], *,
-                band: float = DRIFT_BAND,
-                measured_occupancy: float | None = None,
-                journal=None) -> dict:
-    """Planner drift: measured live throughput vs the discrete-event
-    replay's prediction for the recorded config (``extra`` is a
-    SERVE_BENCH record's ``extra``).  Outside the band, a
-    ``simulate.drift`` event is journaled — the signal a closed-loop
-    autoscaler would treat as "my model of this fleet is stale"."""
-    from ..tune.simulate import replay_bench_record
-
-    sink = journal if journal is not None else journal_mod.get_default()
-    sim = replay_bench_record(extra)
-    predicted = sim.get("tokens_per_s")
-    result: dict[str, Any] = {
-        "predicted_tok_s": predicted,
-        "measured_tok_s": measured_tok_s,
-        "predicted_occupancy": sim.get("mean_occupancy"),
-        "measured_occupancy": measured_occupancy,
-        "predicted_ttft_p99_s": sim.get("ttft_p99_s"),
-        "band": band,
-        "ratio": None,
-        "within_band": None,
-    }
-    if predicted and measured_tok_s:
-        ratio = measured_tok_s / predicted
-        result["ratio"] = ratio
-        result["within_band"] = bool(1.0 / band <= ratio <= band)
-        if not result["within_band"]:
-            sink.event("simulate.drift", **{
-                k: result[k] for k in
-                ("predicted_tok_s", "measured_tok_s", "ratio", "band")})
-    return result
-
-
 def monitor_records(records: Iterable[dict],
                     policy: MonitorPolicy, *,
                     journal=None,
-                    drift_extra: Mapping[str, Any] | None = None,
                     time_field: str = "t") -> dict:
     """Drive a monitor over a record stream and summarize.
 
@@ -215,10 +166,6 @@ def monitor_records(records: Iterable[dict],
         "overall": agg.summary(),
         "windows": agg.windows,
     }
-    if drift_extra is not None:
-        summary["drift"] = drift_check(
-            summary["overall"].get("tok_s"), drift_extra,
-            journal=journal)
     return summary
 
 
@@ -263,16 +210,4 @@ def format_summary(summary: dict) -> str:
         lines.append(
             f"  {summary.get('n_evaluated', 0)} evaluated window(s), "
             f"0 incident(s)")
-    drift = summary.get("drift")
-    if drift:
-        if drift.get("within_band") is None:
-            lines.append("  drift: not comparable (no throughput "
-                         "measurement or prediction)")
-        else:
-            lines.append(
-                f"  drift: measured {drift['measured_tok_s']:.1f} vs "
-                f"predicted {drift['predicted_tok_s']:.1f} tok/s "
-                f"(x{drift['ratio']:.2f}) — "
-                + ("within" if drift["within_band"] else "OUTSIDE")
-                + f" {drift['band']:g}x band")
     return "\n".join(lines)

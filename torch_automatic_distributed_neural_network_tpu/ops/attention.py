@@ -169,9 +169,9 @@ def chunked_attention(
 def _flash_ok(q: jax.Array, k: jax.Array, mask) -> bool:
     """Auto-dispatch gate for the Pallas flash kernel: TPU backend, no
     explicit mask, a sequence long enough that block streaming wins.
-    The flash-vs-einsum speedup is not measured on the current code
-    (``bench.py mode=attention`` measures it); 512 is a conservative
-    floor set by the kernel's block size, not the perf crossover."""
+    The flash-vs-einsum speedup is not measured on the current code;
+    512 is a conservative floor set by the kernel's block size, not the
+    perf crossover."""
     if mask is not None:
         return False
     if q.shape[1] < 512 or q.shape[1] != k.shape[1]:

@@ -401,11 +401,10 @@ def _pad_to(x, target, dim):
     return jnp.pad(x, widths)
 
 
-# Per-seq (block_q, block_k) fwd+bwd winners of an earlier v5e sweep
-# (`bench.py mode=attention sweep=1`); their utilization is not measured
-# on the current code.  2048-wide q blocks, and bq>=1024 x bk>=1024
-# combinations beyond these, exceed the compile helper's VMEM budget
-# and fail to compile.
+# Per-seq (block_q, block_k) fwd+bwd winners of an earlier v5e sweep;
+# their utilization is not measured on the current code.  2048-wide q
+# blocks, and bq>=1024 x bk>=1024 combinations beyond these, exceed the
+# compile helper's VMEM budget and fail to compile.
 _MEASURED_BLOCKS = {
     2048: (512, 2048),
     8192: (512, 2048),

@@ -40,8 +40,8 @@ on bubble iterations (HLO conditionals are runtime control flow even in
 SPMD programs — each pipe rank takes its own branch, and the tensor/data
 auto-axis peers of a rank agree on the predicate, so collectives inside
 the taken branch stay consistent).  ``schedule='dense'`` keeps the
-round-2 compute-everything-and-mask behavior for A/B measurement
-(bench.py mode=pipeline records the gap).  ``schedule='1f1b'`` replaces
+round-2 compute-everything-and-mask behavior for A/B measurement.
+``schedule='1f1b'`` replaces
 AD-through-the-scan with a hand-scheduled backward (onef_oneb_grads):
 M-independent live-activation memory.
 
@@ -117,7 +117,7 @@ def spmd_pipeline(
       dense schedule burned on garbage.
     - ``'dense'`` — the round-2 behavior: every rank computes every
       iteration and bubble results are masked out.  Kept for A/B
-      measurement (bench.py mode=pipeline) and as a fallback.
+      measurement and as a fallback.
 
     Both schedules run the same ``M + S - 1`` iterations and are
     trajectory-identical (the parity test pins them); 'cond' only removes

@@ -20,7 +20,7 @@ Causal block dispatch (lax.switch per ring step):
 Scheduling note: the fori_loop body computes on the resident block and
 then rotates; whether the ppermute hop actually overlaps the next block's
 compute is the compiler's latency-hiding decision, NOT a property this
-code enforces — measured, not assumed (bench.py mode=overlap).
+code enforces, and it is not measured on the current code.
 
 This module is the *explicit-collective* tier: it must be called inside a
 ``shard_map`` region where q/k/v are sharded along ``axis_name``.  The

@@ -476,8 +476,8 @@ def enable_compilation_cache() -> str | None:
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and no
     directory is set in code; otherwise the cache goes to the fixed
     in-checkout path, created here.  Called by the entry points
-    (``chip_smoke.py``, ``tadnn run|serve|fit``, ``bench.py``,
-    ``bench_serve.py``), never at import.  Safe to call more than once.
+    (``chip_smoke.py``, ``tadnn run|serve|fit``, ``benchmark/run.py``),
+    never at import.  Safe to call more than once.
     """
     if os.environ.get("TADNN_NO_COMPILE_CACHE"):
         return None

@@ -146,8 +146,7 @@ def mse_loss(params, batch, rng, apply_fn):
 # ---------------------------------------------------------------------------
 #
 # The fp32 [B,S,V] logits tensor (plus its grad twin) dominates peak HBM
-# for large-vocab models (the Llama-8B/128k-vocab `bench.py mode=memfit`
-# compile shows it; not measured on the current code).  This loss asks
+# for large-vocab models (not measured on the current code).  This loss asks
 # the model for post-final-norm FEATURES (return_features=True), then
 # folds the LM head into the loss blockwise along the sequence under
 # jax.checkpoint: peak temp is [B, block, V] instead of [B, S, V], and

@@ -219,6 +219,8 @@ def _raw_restore_state(directory: str, step: int) -> Any:
     ckptr = ocp.StandardCheckpointer()
     try:
         meta = ckptr.metadata(path)
+        # orbax >= 0.11 wraps the item's tree in a StepMetadata
+        meta = getattr(meta, "item_metadata", meta)
         sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
         abstract = jax.tree.map(
             lambda m: jax.ShapeDtypeStruct(m.shape, m.dtype,

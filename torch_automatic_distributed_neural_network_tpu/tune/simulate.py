@@ -21,15 +21,15 @@ joins four independently-shipped models into one prediction:
 
 Candidates are ranked by an operator SLO (``tune/slo.py``), sweeps are
 cached through ``tune/cache.py``, and everything journals ``simulate.*``
-events for ``tadnn report``.  Every future real bench record becomes a
-falsification test of these predictions (``report --check-simulate``).
+events for ``tadnn report``.  No cell of ``BENCHMARK.json`` checks these
+predictions yet (ROADMAP.md, Queue 3).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Mapping, Sequence
+from typing import Any, Sequence
 
 import numpy as np
 
@@ -362,52 +362,6 @@ def replay_serve(
             "prefix_evicted_blocks": pc.evicted_blocks}
            if pc is not None else {}),
     }
-
-
-def replay_bench_record(extra: Mapping[str, Any]) -> dict:
-    """Replay a recorded SERVE_BENCH config against the current
-    scheduler policy — the ``--check-simulate`` falsification path.
-
-    Per-request decode lengths are not recorded, only the total; the
-    replay spreads ``new_tokens`` evenly across the streams (the
-    max-occupancy reading of the total — measured occupancy with
-    staggered EOS lengths sits a little below it).  Step costs come
-    from the record's measured breakdown.
-    """
-    streams = int(extra["streams"])
-    total_new = int(extra.get("new_tokens") or
-                    streams * int(extra["max_new"]))
-    base, rem = divmod(total_new, streams)
-    lens = [base + (1 if i < rem else 0) for i in range(streams)]
-    prompt = int(extra["prompt_len"])
-    max_new = int(extra["max_new"])
-    bd = extra.get("breakdown") or {}
-    requests = [(0.0, prompt, max_new, max(1, lens[i]))
-                for i in range(streams)]
-    result = replay_serve(
-        requests,
-        n_slots=int(extra["slots"]),
-        block_size=int(extra["block_size"]),
-        # max_len joined the recorded extra after r03; 64 is the bench
-        # default it ran with
-        max_len=int(extra.get("max_len") or 64),
-        admission=str(extra.get("admission") or "reserve"),
-        prefill_chunk=extra.get("prefill_chunk"),
-        spec_lookahead=int(extra.get("speculative") or 0),
-        decode_step_s=float(bd.get("decode_step_ms") or 1.0) * 1e-3,
-        prefill_chunk_s=float(bd.get("prefill_chunk_ms") or 1.0) * 1e-3,
-        # r04+ records carry the engine mode; the in-process bench ships
-        # blocks at HBM speed, so no extra kv_ship_s term here
-        disaggregate=bool(extra.get("disaggregate")),
-        # r05+ records carry the prefix-cache mix; the replay reprices
-        # the recorded hit rate instead of trusting it
-        prefix_cache=bool(extra.get("prefix_cache")),
-        shared_prefix=int(extra.get("shared_prefix") or 0),
-    )
-    obs_journal.event("simulate.replay", source="bench_record", **{
-        k: result[k] for k in ("steps", "new_tokens", "tokens_per_s",
-                               "mean_occupancy", "preemptions")})
-    return result
 
 
 @dataclasses.dataclass(frozen=True)
