@@ -251,7 +251,8 @@ def test_serving_programs_update_the_pool_in_place(
     kv, win = jax.eval_shape(arrays)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     if program == "decode_step":
-        operands = (params, kv, i32(slots, MB + 4), i32(2 * slots + 3), win,
+        operands = (params, kv, i32(slots, MB + 4),
+                    i32(2 * slots + programs.N_COUNTERS), win,
                     {}, jax.eval_shape(lambda: jax.random.key(0)))
 
         def step(params, *a):
@@ -295,26 +296,50 @@ def test_serving_programs_update_the_pool_in_place(
 # matmuls, and the two serving programs at the published widths -------------
 
 
-def test_folded_paged_decode_compiles_for_v5e(v5e):
-    """48 query heads on 8 KV heads of 128, 16 slots over pages for 13,312
-    positions: the MXU form of the decode kernel, with and without a
-    window."""
+# (slots, query heads, kv heads, blocks of 16 in max_len) of the two models
+_FOLDED = {"gpt2-1p3b": (8, 16, 16, 64), "trinity-large-ep8": (16, 48, 8, 832)}
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window4096"])
+@pytest.mark.parametrize("config", sorted(_FOLDED))
+def test_folded_paged_decode_compiles_for_v5e(v5e, config, window):
+    """The MXU form of the decode kernel at both serving cells' shapes, its
+    grid a work list of traced length built from the contexts and the
+    slots' flags.  The block tables stay a scalar-prefetch operand in their
+    own shape, ``s32[slots, max_len / block]``: what
+    ``benchmark/metrics/paged_attn_roofline.py`` tells the kernel by."""
     from torch_automatic_distributed_neural_network_tpu.ops.paged_attention import (
-        paged_attention_folded,
+        folded_work_list,
     )
 
+    slots, hq, kvh, mb = _FOLDED[config]
     one = SingleDeviceSharding(v5e[0])
     sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
-    pool = sds((16 * 832 + 1, 16, 8 * 128), jnp.bfloat16)
-    for window in (None, 4096):
-        _compile(lambda q, k, v, t, c, window=window: paged_attention_folded(
-            q, k, v, t, c, window=window, interpret=False),
-            sds((16, 48, 128), jnp.bfloat16), pool, pool,
-            sds((16, 832), jnp.int32), sds((16,), jnp.int32))
-    # float32 queries over a bf16 pool (``chip_smoke.py``'s comparison with
-    # the reference, at GPT-2 1.3B's 16 heads of 128): float32 products
+    pool = sds((slots * mb + 1, 16, kvh * 128), jnp.bfloat16)
+
+    def call(q, k, v, t, c, active):
+        work = folded_work_list(c, active, max_blocks=mb, block_size=16,
+                                window=window)
+        return paged_attention(q, k, v, t, c, window=window, work=work,
+                               interpret=False)
+
+    text = _compile(call, sds((slots, hq, 128), jnp.bfloat16), pool, pool,
+                    sds((slots, mb), jnp.int32), sds((slots,), jnp.int32),
+                    sds((slots,), jnp.bool_))
+    (kernel,) = [l for l in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in l]
+    assert "tadnn_paged_decode_folded" in kernel.split(" = ")[0]
+    assert f"s32[{slots},{mb}]" in kernel.split(" = ", 1)[1]
+
+
+def test_folded_paged_decode_compiles_for_float32_queries(v5e):
+    """float32 queries over a bf16 pool (``chip_smoke.py``'s comparison with
+    the reference, at GPT-2 1.3B's 16 heads of 128): float32 products, the
+    work list built by the kernel's own entry."""
+    one = SingleDeviceSharding(v5e[0])
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
     pool = sds((8 * 64 + 1, 16, 16 * 128), jnp.bfloat16)
-    _compile(lambda q, k, v, t, c: paged_attention_folded(
+    _compile(lambda q, k, v, t, c: paged_attention(
         q, k, v, t, c, interpret=False),
         sds((8, 16, 128), jnp.float32), pool, pool,
         sds((8, 64), jnp.int32), sds((8,), jnp.int32))

@@ -91,6 +91,120 @@ def test_kernel_null_table_slot_is_finite():
     assert bool(jnp.all(jnp.isfinite(out)))
 
 
+# -- the folded (MXU) kernel: a work list of live (slot, key group) items -----
+
+_BS, _MB = 16, 24  # 3 groups of 8 pages: 128 keys a group, max_len 384
+_MAX = _BS * _MB
+
+# name: (query heads, kv heads, contexts, the running slots or None for
+# all, window)
+_WORK_CASES = {
+    "every_slot_inactive": (4, 2, [7, 200, 0, 383], [], None),
+    "one_of_16_running": (4, 2, [0] * 11 + [300] + [0] * 4, [11], None),
+    "ctx_0": (4, 2, [0, 0], None, None),
+    "group_edges": (4, 2, [127, 128, 255, 256], None, None),
+    "every_slot_full": (4, 2, [_MAX - 1] * 3, None, None),
+    "window_band_inside_a_group": (4, 2, [300, 50, 200, 383], None, 100),
+    "window_shorter_context": (4, 2, [5, 99, 100], None, 100),
+    "window_with_idle_slots": (4, 2, [300, 250, 0, 383], [0, 3], 100),
+    "gqa_48_on_8": (48, 8, [3, 130, 383], None, None),
+    "mha_16_on_16": (16, 16, [3, 130, 383], None, 200),
+}
+
+
+def _live_groups(ctx, running, window):
+    """The host's own reckoning: a slot's first and last group."""
+    keys = 8 * _BS
+    if not running:
+        return 0, 0
+    lo = 0 if window is None else max(ctx - window + 1, 0) // keys
+    return lo, ctx // keys
+
+
+@pytest.mark.parametrize("case", sorted(_WORK_CASES))
+def test_folded_kernel_visits_only_live_groups(case):
+    """``paged_attention`` over folded pages against the dense reference on
+    ragged contexts: the kernel's grid is ``folded_work_list``, whose length
+    is the host's own count of live (slot, group) pairs (one for a slot that
+    does not run), and every page outside a listed group is poisoned, so a
+    visit to one would show."""
+    from torch_automatic_distributed_neural_network_tpu.ops.paged_attention \
+        import folded_work_list
+
+    Hq, kvH, ctxs, running, window = _WORK_CASES[case]
+    S, hd = len(ctxs), 32
+    running = list(range(S)) if running is None else running
+    rs = np.random.RandomState(len(case))
+    k = rs.randn(S * _MB + 1, _BS, kvH * hd).astype(np.float32)
+    v = rs.randn(S * _MB + 1, _BS, kvH * hd).astype(np.float32)
+    tables = 1 + rs.permutation(S * _MB).reshape(S, _MB).astype(np.int32)
+    want_items, starts = 0, []
+    for s, ctx in enumerate(ctxs):
+        lo, hi = _live_groups(ctx, s in running, window)
+        want_items += hi - lo + 1
+        starts.append(lo)
+        dead = [j for j in range(_MB) if not lo <= j // 8 <= hi]
+        v[tables[s, dead]] = np.nan
+        k[tables[s, dead]] = np.nan
+        # the engine's table holds the null block past the newest key
+        tables[s, ctx // _BS + 1:] = 0
+    q = jnp.asarray(rs.randn(S, Hq, hd), jnp.float32)
+    ctx = jnp.asarray(ctxs, jnp.int32)
+    active = jnp.asarray([s in running for s in range(S)])
+    work = folded_work_list(ctx, active, max_blocks=_MB, block_size=_BS,
+                            window=window)
+    n = int(work.n_items)
+    assert n == want_items <= work.dense == work.slot_of.shape[0] - 1
+    # slot order, ascending groups inside a slot, from the band's first
+    listed = list(zip(np.asarray(work.slot_of)[:n].tolist(),
+                      np.asarray(work.group_of)[:n].tolist()))
+    assert listed == sorted(listed) and len(set(listed)) == n
+    assert [g for (s, g), (p, _) in zip(listed, [(-1, 0)] + listed)
+            if s != p] == starts
+    got = paged_attention(q, jnp.asarray(k), jnp.asarray(v),
+                          jnp.asarray(tables), ctx, window=window, work=work)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    if running:
+        want = paged_attention_reference(
+            q, jnp.nan_to_num(jnp.asarray(k)), jnp.nan_to_num(jnp.asarray(v)),
+            jnp.asarray(tables), ctx, window=window)
+        np.testing.assert_allclose(np.asarray(got)[running],
+                                   np.asarray(want)[running], atol=1e-5)
+    if case == "every_slot_full":  # the list is the whole dense grid
+        assert n == work.dense == S * 3
+    # built where the caller gives none, every slot taken as running
+    if len(running) == S:
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(paged_attention(
+                q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables), ctx,
+                window=window)))
+
+
+def test_folded_kernel_runs_as_many_grid_steps_as_the_list_has_items():
+    """The grid's one dimension is the traced ``n_items``, no static bound:
+    what ``attn_grid_items`` counts is what the kernel runs."""
+    from torch_automatic_distributed_neural_network_tpu.ops.paged_attention \
+        import folded_work_list
+
+    S, Hq, kvH, hd = 4, 4, 2, 32
+    k = jnp.zeros((S * _MB + 1, _BS, kvH * hd), jnp.float32)
+    tables = jnp.zeros((S, _MB), jnp.int32)
+
+    def run(q, ctx, active):
+        work = folded_work_list(ctx, active, max_blocks=_MB, block_size=_BS)
+        return paged_attention(q, k, k, tables, ctx, work=work), work.n_items
+
+    args = (jnp.zeros((S, Hq, hd), jnp.float32),
+            jnp.asarray([0, 127, 128, 383], jnp.int32),
+            jnp.asarray([True, True, True, False]))
+    jaxpr = jax.make_jaxpr(run)(*args).jaxpr
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    mapping = call.params["grid_mapping"]
+    assert len(mapping.grid) == 1 and mapping.num_dynamic_grid_bounds == 1
+    assert call.invars[0] is jaxpr.outvars[1]  # the bound IS n_items
+    assert int(run(*args)[1]) == 1 + 1 + 2 + 1
+
+
 def test_reference_fp_pool_skips_dequantize_and_matches_int8():
     """gather_blocks (the reference path): fp pool returns the stored
     values untouched; int8 pool dequantizes to within the pinned

@@ -113,13 +113,13 @@ def test_the_second_annotation_api_is_gone():
 READER_FIRST = {"prefill_first_token"}
 
 
-def _engine(journal):
+def _engine(journal, block_size=8):
     model = GPT2("test", vocab_size=VOCAB, max_seq_len=64,
                  dtype=jnp.float32, remat=False)
     variables = model.init(jax.random.key(1), jnp.ones((1, 12), jnp.int32))
     return ServeEngine(model, variables, n_slots=2, max_len=64,
-                       block_size=8, prefill_chunk=8, journal=journal,
-                       export_cache=False)
+                       block_size=block_size, prefill_chunk=8,
+                       journal=journal, export_cache=False)
 
 
 def _prompt(n):
@@ -192,6 +192,44 @@ def test_first_steps_report_compiles_and_no_prompt_length_after(served):
     # one compile event for each step that compiled, no more
     assert len(events) == sum(
         1 for s in j.named("serve.step") if s["compiles"])
+
+
+def test_decode_steps_carry_the_attention_grid_they_ran(monkeypatch):
+    """``attn_grid_items`` / ``attn_grid_dense`` come back with a step's
+    tokens (one call after its dispatch): the live (slot, key group) items
+    the step's paged calls ran, which the host can reckon from the contexts
+    and flags it packed, and the ``slots x groups`` of a dense grid, over
+    the model's two layers.  Pages of 2 keys: 4 groups of 16 in 64."""
+    from torch_automatic_distributed_neural_network_tpu.inference.serve import (
+        programs,
+    )
+
+    packed, pack_step = [], programs.pack_step
+
+    def recording(tables, ctx_lens, tok, source, adapter_ids):
+        packed.append((ctx_lens.copy(), source.copy()))
+        return pack_step(tables, ctx_lens, tok, source, adapter_ids)
+
+    monkeypatch.setattr(programs, "pack_step", recording)
+    j = Journal(None, validate=True, host0_only=False)
+    eng = _engine(j, block_size=2)
+    for n, new in ((5, 30), (21, 12), (12, 3)):
+        eng.submit(_prompt(n), max_new_tokens=new)
+    eng.run()
+    steps = j.named("serve.step")
+    counted = [s for s in steps if "attn_grid_items" in s]
+    assert len(counted) == len(packed) > 20
+    # a call that read a step's tokens has that step's counters
+    assert all("attn_grid_items" in s for s in steps if s["new_tokens"]
+               and not s["n_prefill_chunks"])
+    reckoned = [2 * sum(ctx // 16 + 1 if flag else 1
+                        for ctx, flag in zip(ctx_lens, source))
+                for ctx_lens, source in packed]
+    assert [s["attn_grid_items"] for s in counted] == reckoned
+    assert {s["attn_grid_dense"] for s in counted} == {2 * 2 * 4}
+    assert all(s["attn_grid_items"] <= s["attn_grid_dense"] for s in counted)
+    assert min(reckoned) == 4 < max(reckoned)
+    assert not any(k.startswith("moe_") for s in steps for k in s)
 
 
 def test_a_step_that_compiled_freezes_the_heap():

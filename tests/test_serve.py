@@ -1267,6 +1267,44 @@ def test_nothing_compiles_after_the_warm_up_across_admissions():
     assert eng._prefill_fn._cache_size() == 1
 
 
+def test_work_list_kernel_serves_the_dense_paths_tokens_without_a_compile():
+    """A mixed-length batch whose contexts grow across key groups (8 pages
+    of 2 keys: 4 groups in ``max_len`` 64): the paged engine's greedy
+    tokens are the dense path's, the kernel's grid follows the contexts
+    (``attn_grid_items`` on the step events) and no step after the warm-up
+    compiles: the list's length is a traced value, not a shape."""
+    from torch_automatic_distributed_neural_network_tpu.obs.journal import (
+        Journal,
+    )
+
+    prompts, news = _prompts((3, 17, 30, 9), seed=5), (44, 30, 20, 12)
+    tokens = {}
+    for impl in ("paged", "dense"):
+        j = Journal(None, validate=True, host0_only=False)
+        eng = _f32_engine(journal=j, attention_impl=impl, block_size=2)
+        eng.submit(_prompts((9,))[0], max_new_tokens=3)
+        eng.run()
+        warm = len(j.named("serve.step"))
+        reqs = [eng.submit(p, max_new_tokens=m)
+                for p, m in zip(prompts, news)]
+        eng.run()
+        tokens[impl] = [r.out_tokens for r in reqs]
+        later = j.named("serve.step")[warm:]
+        assert sum(s["compiles"] for s in later) == 0
+        assert eng._step_fn._cache_size() == 1
+        grid = [(s["attn_grid_items"], s["attn_grid_dense"]) for s in later
+                if "attn_grid_items" in s]
+        assert len(grid) > 40
+        if impl == "dense":
+            assert set(grid) == {(0, 0)}
+            continue
+        # 2 layers x 3 slots x 4 groups; a slot has 1 to 3 groups live
+        assert {d for _, d in grid} == {24}
+        assert min(n for n, _ in grid) == 6 < max(n for n, _ in grid) <= 18
+    assert tokens["paged"] == tokens["dense"]
+    assert [len(t) for t in tokens["paged"]] == list(news)
+
+
 def test_report_prints_the_share_of_steps_dispatched_ahead(tmp_path):
     """``tadnn report`` on a journal fixture: the share of decoding steps
     dispatched ahead and the slot-steps thrown away, beside the phases."""
@@ -1286,10 +1324,17 @@ def test_report_prints_the_share_of_steps_dispatched_ahead(tmp_path):
                        "decode_wait": 0.004} if decoding else {"admit": 1e-4},
             "step_s": 0.007, "t_end": 0.01 * i, "n_prefill_chunks": 0,
             "compiles": 0, "ahead": int(i > 2),
-            "discarded_tokens": 2 if i == 7 else 0})
+            "discarded_tokens": 2 if i == 7 else 0,
+            # a step's counters come with its tokens, a call late
+            **({"attn_grid_items": 10 + i, "attn_grid_dense": 64}
+               if i > 2 else {})})
     jp.write_text("".join(json.dumps(r) + "\n" for r in recs))
     report = obs_report.generate(str(jp))
     srv = report["serving"]
+    assert (srv["attn_grid_items"], srv["attn_grid_dense"]) == (132, 512)
+    assert ("paged attention grid: 132 live (slot, key group) items of 512 "
+            "in a dense grid over the decode steps (0.258)"
+            ) in obs_report.format_report(report)
     assert srv["decode_calls"] == 9
     assert srv["steps_ahead_share"] == pytest.approx(8 / 9)
     assert srv["discarded_tokens"] == 2 and srv["step_new_tokens"] == 36
@@ -1301,6 +1346,9 @@ def test_report_prints_the_share_of_steps_dispatched_ahead(tmp_path):
     # a journal from before the counters prints no such line
     for r in recs:
         r.pop("ahead", None)
+        r.pop("attn_grid_items", None)
+        r.pop("attn_grid_dense", None)
     jp.write_text("".join(json.dumps(r) + "\n" for r in recs))
-    assert "dispatched ahead" not in obs_report.format_report(
-        obs_report.generate(str(jp)))
+    text = obs_report.format_report(obs_report.generate(str(jp)))
+    assert "dispatched ahead" not in text
+    assert "paged attention grid" not in text

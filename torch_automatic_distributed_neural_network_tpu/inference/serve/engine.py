@@ -453,8 +453,8 @@ class ServeEngine:
                                if self.cfg.n_expert_layers else 0),
             kv_bytes_full=self.pool.bytes_full,
             kv_bytes_window=self.pool.bytes_window)
-        # the last decode step's expert counters (serve.step carries them)
-        self._moe: dict[str, int] = {}
+        # the counters of the decode step last read (serve.step carries them)
+        self._counters: dict[str, int] = {}
 
     def _export_compiled(self, cache, tags: dict, *, num_blocks: int,
                          block_size: int, quant_kv: bool, cache_dtype,
@@ -894,16 +894,19 @@ class ServeEngine:
             return
         S, T = self.n_slots, 1 + self.speculative
         with self._phase(wait):
-            # first tokens and expert counters ride with the step's
-            # tokens: one fetch
+            # first tokens and the step's counters ride with its tokens:
+            # one fetch
             out = np.asarray(jax.device_get(out))
-            first, tokens, moe = out[:S], out[S:-3], out[-3:]
+            n = programs.N_COUNTERS
+            first, tokens, counters = out[:S], out[S:-n], out[-n:]
             if T > 1:
                 tokens = tokens.reshape(S, T)
-            if rows and self.cfg.n_expert_layers:
-                self._moe = dict(zip(
+            if rows:  # the expert layers' three only where there are any
+                skip = 0 if self.cfg.n_expert_layers else 3
+                self._counters = dict(zip(
                     ("moe_pairs", "moe_experts_touched",
-                     "moe_max_expert_tokens"), map(int, moe)))
+                     "moe_max_expert_tokens", "attn_grid_items",
+                     "attn_grid_dense")[skip:], map(int, counters[skip:])))
         with self._phase("emit"):
             self._emit(tokens, first, rows, firsts, drafts)
 
@@ -1038,7 +1041,7 @@ class ServeEngine:
         discarded_before = self.discarded_tokens
         compiles, compile_s = self._compiles.n, self._compiles.seconds
         self._phases = phases = {}
-        self._moe = {}
+        self._counters = {}
         whole: dict[str, float] = {}
         n_chunks = 0
         with _journal.phase(whole, "step_s", "serve.step",
@@ -1126,7 +1129,7 @@ class ServeEngine:
             n_prefill_chunks=n_chunks, compiles=compiles,
             ahead=self.steps_ahead - ahead_before,
             discarded_tokens=self.discarded_tokens - discarded_before,
-            **adapter_stats, **self._moe)
+            **adapter_stats, **self._counters)
         if self._debug_invariants:
             sched.check_invariants()
 

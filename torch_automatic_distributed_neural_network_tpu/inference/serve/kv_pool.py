@@ -175,8 +175,8 @@ def ring_table(win_rows: jax.Array, max_blocks: int, lo: jax.Array,
     """A window layer's table as the kernels take one: ``[S, max_blocks]``,
     logical block ``j`` of slot ``s`` at ring page ``win_rows[s, j % W]``
     for ``lo[s] <= j <= hi[s]`` and the null block elsewhere (an unchanged
-    index is not copied again, so the dead blocks cost a grid step and no
-    read)."""
+    index is not copied again, so the dead blocks of a group of pages cost
+    no read; a group with no live block is not in the kernel's work list)."""
     j = jnp.arange(max_blocks)[None, :]
     live = (j >= lo[:, None]) & (j <= hi[:, None])
     return jnp.where(live, win_rows[:, j[0] % win_rows.shape[1]], NULL_BLOCK)

@@ -151,6 +151,9 @@ def _pages_of(kind: str | None) -> str:
     return "ring" if kind == "sliding_attention" else "pages"
 
 
+N_COUNTERS = 5  # what a step's output carries after its tokens
+
+
 def _moe_counters(stats: list) -> jax.Array:
     """[pairs that landed here, experts touched, most tokens on one expert]
     over the step's expert layers (zeros for a model without any)."""
@@ -214,9 +217,16 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
     projections they perturb (rope is linear, so rotating the delta IS the
     merged-weight semantics).
 
-    Returns ``(kv, logits [S, T, V], moe counters [3])``."""
+    Returns ``(kv, logits [S, T, V], counters [N_COUNTERS])``: the expert
+    layers' three (``_moe_counters``), then the grid steps the paged calls
+    of the step ran and the ``slots x groups`` a dense grid would have run,
+    summed over the layers (zeros where no call takes a work list)."""
     from ...ops.attention import xla_attention
-    from ...ops.paged_attention import paged_attention
+    from ...ops.paged_attention import (
+        folded_work_list,
+        is_folded,
+        paged_attention,
+    )
 
     S, T = tok.shape
     MB = tables.shape[1]
@@ -237,6 +247,20 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
     if win_tables.shape[1]:
         lo = (ctx_lens - cfg.sliding_window + 1) // bs
         shared["tables"]["ring"] = ring_table(win_tables, MB, lo, hi)
+    # the folded kernel's grid, the live (slot, key group) items: one list a
+    # kind of table, built here once a step and not in every layer's call
+    kinds = [kind for _, kind, _ in layer_plan(cfg)]
+    grid = jnp.zeros((2,), jnp.int32)
+    shared["work"] = {}
+    if attention_impl == "paged" and T == 1 and is_folded(kv["k"][0]):
+        shared["work"] = {  # in the plan's order: the same text every run
+            _pages_of(kind): folded_work_list(
+                ctx_lens, active, max_blocks=MB, block_size=bs,
+                window=cfg.layer_window(kind))
+            for kind in dict.fromkeys(kinds)}
+        works = [shared["work"][_pages_of(kind)] for kind in kinds]
+        grid = jnp.stack([sum(w.n_items for w in works),
+                          jnp.int32(sum(w.dense for w in works))])
 
     def layer_fn(kind, sparse):
         window = cfg.layer_window(kind)
@@ -255,7 +279,8 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
                 if attention_impl == "paged" and T == 1:
                     return paged_attention(
                         q[:, 0], k_l, v_l, table, ctx_lens, window=window,
-                        mesh=mesh)[:, None]
+                        mesh=mesh,
+                        work=shared["work"].get(_pages_of(kind)))[:, None]
                 # chunk position t writes at positions[s, t] then attends
                 # keys 0..positions[s, t] inclusive — the causal triangle
                 # across the chunk plus the context below it; table padding
@@ -299,7 +324,8 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
     # per-slot, per-chunk-offset absolute positions
     x = _embed(params, cfg, tok, ctx_lens[:, None] + jnp.arange(T)[None, :])
     x, kv, stats = _walk(cfg, params, kv, x, layer_fn, shared, extras)
-    return kv, _logits(params, cfg, x), _moe_counters(stats)
+    return kv, _logits(params, cfg, x), jnp.concatenate(
+        [_moe_counters(stats), grid])
 
 
 def pack_step(tables, ctx_lens, tok, source, adapter_ids) -> np.ndarray:
@@ -315,7 +341,7 @@ def pack_step(tables, ctx_lens, tok, source, adapter_ids) -> np.ndarray:
 
 def step_output(n_slots: int, n_tok: int = 1) -> jax.Array:
     """What a decode step takes as ``prev`` before there has been one."""
-    return jnp.zeros((n_slots + n_slots * n_tok + 3,), jnp.int32)
+    return jnp.zeros((n_slots + n_slots * n_tok + N_COUNTERS,), jnp.int32)
 
 
 def decode_step(params, kv, packed, prev, win_tables, adapters, rng, *,
@@ -323,15 +349,16 @@ def decode_step(params, kv, packed, prev, win_tables, adapters, rng, *,
                 **kw):
     """``decode_logits`` on the operands of ``pack_step`` (``n_tok`` is its
     T) and on ``prev``, what the call before this one returned
-    (``step_output`` before the first).  Returns ``(kv, [S + S * T + 3]
-    int32)``: the slots' first tokens, as ``prev`` had them (the engine's
-    ``first_token`` writes them); then the step's sampled tokens [S]
-    (T == 1), or the target's greedy choices [S, T] flattened (verify steps
-    are temperature-0 by contract — sampled speculative needs rejection
-    resampling); then the step's expert counters: one array, so that one
-    fetch brings all three.  With T == 1 a slot flagged ``TOKEN_PREV`` decodes
-    its own token of ``prev`` and one flagged ``TOKEN_FIRST`` its first
-    token there, whatever the operand holds: the same shapes every step."""
+    (``step_output`` before the first).  Returns ``(kv, [S + S * T +
+    N_COUNTERS] int32)``: the slots' first tokens, as ``prev`` had them (the
+    engine's ``first_token`` writes them); then the step's sampled tokens
+    [S] (T == 1), or the target's greedy choices [S, T] flattened (verify
+    steps are temperature-0 by contract — sampled speculative needs
+    rejection resampling); then the step's counters (``decode_logits``): one
+    array, so that one fetch brings all three.  With T == 1 a slot flagged
+    ``TOKEN_PREV`` decodes its own token of ``prev`` and one flagged
+    ``TOKEN_FIRST`` its first token there, whatever the operand holds: the
+    same shapes every step."""
     S = packed.shape[0]
     MB = packed.shape[1] - n_tok - 3
     tables, tok = packed[:, :MB], packed[:, MB:MB + n_tok]

@@ -379,6 +379,12 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
             "max_moe_expert_tokens": max(
                 _finite(e.get("moe_max_expert_tokens") for e in ssteps),
                 default=None),
+            # the grid steps the decode steps' paged attention calls ran
+            # (their work lists' items), and a dense slots x groups grid's
+            "attn_grid_items": sum(_finite(
+                e.get("attn_grid_items") for e in ssteps)) or None,
+            "attn_grid_dense": sum(_finite(
+                e.get("attn_grid_dense") for e in ssteps)) or None,
             # disaggregated / sharded serving (r04 fields)
             "mode": (ssteps[-1].get("mode") if ssteps else None),
             "tp": (sengine or {}).get("tp"),
@@ -996,6 +1002,12 @@ def format_report(report: dict) -> str:
                     f"on {sv['mean_moe_experts_touched']:.1f} experts, at "
                     f"most {sv['max_moe_expert_tokens']} tokens on one")
             lines.append("  " + "  ".join(eparts))
+        if sv.get("attn_grid_dense"):
+            lines.append(
+                f"  paged attention grid: {sv['attn_grid_items']} live "
+                f"(slot, key group) items of {sv['attn_grid_dense']} in a "
+                f"dense grid over the decode steps "
+                f"({sv['attn_grid_items'] / sv['attn_grid_dense']:.3f})")
         if sv.get("step_phase_mean_s"):
             lines.append(
                 "  step phases (mean ms, host): " + " ".join(

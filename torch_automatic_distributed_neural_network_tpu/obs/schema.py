@@ -369,7 +369,13 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # got any, and the most tokens one expert got; they come
             # back with the step's tokens
             "moe_pairs": "int", "moe_experts_touched": "int",
-            "moe_max_expert_tokens": "int"}),
+            "moe_max_expert_tokens": "int",
+            # the grid steps the decode step's paged attention calls ran
+            # (the live (slot, key group) items of the folded kernel's
+            # work list) and the slots x groups a dense grid would have
+            # run, summed over the layers; 0 and 0 where no call takes a
+            # work list (dense attention, int8 or mesh-sharded pools)
+            "attn_grid_items": "int", "attn_grid_dense": "int"}),
     _s("serve.request_done", "per-request completion span with the "
        "full phase-attributed timeline", version=2,
        req={"rid": "int", "n_prompt": "int", "n_new": "int",

@@ -21,10 +21,11 @@ Shape of the problem (one decode token per slot):
 ONE entry point, :func:`paged_attention`, and two kernels chosen by the
 shape of a page, not by the model.  A pool of folded pages is read by the
 MXU kernel (``tadnn_paged_decode_folded``, further down: grouped queries
-as two plain matmuls, 8 pages a grid step, the grid over a window's band
-only).  A page kept as ``[bs, kvH, hd]`` is read by the VPU kernel
-(``tadnn_paged_decode``), which dequantizes int8 pages on load and runs
-per shard under ``shard_map``; the rest of this text describes it.
+as two plain matmuls, 8 pages a grid step, the grid a list of the key
+groups the slots have, of traced length).  A page kept as ``[bs, kvH, hd]``
+is read by the VPU kernel (``tadnn_paged_decode``), which dequantizes int8
+pages on load and runs per shard under ``shard_map``; the rest of this text
+describes it.
 
 Its grid is ``(S, MB)`` with the block axis innermost ("arbitrary"
 semantics); a grid step takes one whole page, every kv head of it (the
@@ -57,6 +58,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -165,6 +167,11 @@ def tensor_degree(mesh, axis: str = "tensor") -> int:
     return dict(zip(mesh.axis_names, mesh.devices.shape)).get(axis, 1)
 
 
+def is_folded(pool) -> bool:
+    """A layer's pages stored ``[NB, bs, kvH * hd]``: the MXU kernel's."""
+    return not isinstance(pool, dict) and pool.ndim == 3
+
+
 def paged_attention(
     q: jax.Array,
     k_pool,
@@ -176,6 +183,7 @@ def paged_attention(
     interpret: bool | None = None,
     mesh=None,
     axis: str = "tensor",
+    work=None,
 ) -> jax.Array:
     """Fused paged decode attention over one layer of the KV pool.
 
@@ -198,15 +206,18 @@ def paged_attention(
     result needs no cross-device combine (attention is head-parallel).
     Tables and context lengths stay replicated — any slot may reference
     any block, exactly like the unsharded pool.
+
+    ``work`` is the MXU kernel's grid (``folded_work_list``), for a caller
+    that builds it once for many layers; the VPU kernel has none.
     """
     from ..inference.quant import kv_leaf_parts
 
     if interpret is None:
         interpret = _default_interpret()
-    if not isinstance(k_pool, dict) and k_pool.ndim == 3:
+    if is_folded(k_pool):
         return paged_attention_folded(
             q, k_pool, v_pool, tables, ctx_lens, window=window,
-            interpret=interpret)
+            interpret=interpret, work=work)
     t = tensor_degree(mesh, axis)
     kvH_full = kv_leaf_parts(k_pool)[0].shape[2]
     if t > 1 and kvH_full % t == 0:
@@ -310,60 +321,119 @@ def _paged_attention_local(
 # does 8 times the needed products and is still far from busy.  What the
 # kernel costs is its grid: about 46 ns a (slot, page) visited, relevant or
 # not (my chip run, PR 27: 1.23 ms a layer over 16 x 832 pages whatever the
-# contexts), so with a window the grid covers only the band a slot can still
-# see, starting at the slot's own first block of keys (``first``, a third
-# prefetched scalar), and not ``max_len``.
+# contexts).  So the grid is a WORK LIST (``folded_work_list``): one step a
+# (slot, group of ``FOLD_PAGES`` pages) that holds a key the slot may attend,
+# in slot order and ascending group order inside a slot, its length a traced
+# value.  A slot with nothing to attend keeps one item, its group 0, so that
+# every output row is written.
 
 FOLD_PAGES = 8  # pages a grid step takes (128 keys at 16 a page)
 
 
-def _folded_kernel(tables_ref, ctx_ref, first_ref, q_ref, *refs, pages: int,
-                   bs: int, window: int | None, scale: float):
+class WorkList(NamedTuple):
+    """The folded kernel's grid: item ``w < n_items`` is group
+    ``group_of[w]`` of slot ``slot_of[w]``; ``first``/``last`` [S] are a
+    slot's first and last group (where its sums start and are written)."""
+    first: jax.Array
+    last: jax.Array
+    # [S * steps + 1], one more than a dense grid has steps: the pipeline
+    # reads item w + 1's indices while it runs item w, the last one too
+    slot_of: jax.Array
+    group_of: jax.Array
+    n_items: jax.Array   # [] int32
+
+    @property
+    def dense(self) -> int:
+        """The steps of a dense ``slots x groups`` grid: the list's bound."""
+        return self.slot_of.shape[0] - 1
+
+
+def _fold(max_blocks: int, block_size: int, window: int | None):
+    """(pages a group, groups a table row, groups a slot can have live)."""
+    pages = min(FOLD_PAGES, max_blocks)
+    groups = -(-max_blocks // pages)
+    if window is None:
+        return pages, groups, groups
+    # the band (ctx - window, ctx] spans at most this many groups
+    return pages, groups, min(groups, (window - 1) // (pages * block_size) + 2)
+
+
+def folded_work_list(ctx_lens: jax.Array, active: jax.Array | None = None, *,
+                     max_blocks: int, block_size: int,
+                     window: int | None = None) -> WorkList:
+    """The live (slot, group) items of one decode step for the layers of one
+    ``window``: groups ``0 .. ctx // keys`` of a slot, from the band's first
+    group with a window; group 0 alone for a slot that is not ``active``.
+    A few vector operations on the device: built once a step a kind of
+    layer, whatever the number of layers."""
+    pages, groups, steps = _fold(max_blocks, block_size, window)
+    keys = pages * block_size
+    ctx = jnp.maximum(ctx_lens.astype(jnp.int32), 0)
+    last = jnp.minimum(ctx // keys, groups - 1)
+    first = (jnp.zeros_like(last) if window is None
+             else jnp.minimum(jnp.maximum(ctx - window + 1, 0) // keys, last))
+    if active is not None:
+        first, last = jnp.where(active, first, 0), jnp.where(active, last, 0)
+    ends = jnp.cumsum(last - first + 1)  # a slot's items end before ends[s]
+    w = jnp.arange(ctx.shape[0] * steps + 1, dtype=jnp.int32)
+    # past n_items the list repeats the last slot's last group: never run
+    slot_of = jnp.minimum(jnp.sum(w[:, None] >= ends[None, :], axis=1),
+                          ctx.shape[0] - 1).astype(jnp.int32)
+    group_of = jnp.minimum(last[slot_of] - (ends[slot_of] - 1 - w),
+                           last[slot_of])
+    return WorkList(first, last, slot_of, group_of, ends[-1])
+
+
+def _folded_kernel(tables_ref, ctx_ref, first_ref, last_ref, slot_ref,
+                   group_ref, q_ref, *refs, pages: int, bs: int,
+                   window: int | None, scale: float):
     del tables_ref
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
     o_ref, acc_ref, m_ref, l_ref = refs[2 * pages:]
-    s, j = pl.program_id(0), pl.program_id(1)
-    keys = pages * bs
+    w = pl.program_id(0)
+    s, g = slot_ref[w], group_ref[w]
 
-    @pl.when(j == 0)
+    @pl.when(g == first_ref[s])
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_BIG)
         l_ref[:] = jnp.zeros_like(l_ref)
 
     ctx = ctx_ref[s]
-    start = (first_ref[s] + j) * keys  # past the table's end: not relevant
-    relevant = start <= ctx
+    start = g * (pages * bs)
+
+    # every item holds a key its slot attends, but for the one item of a
+    # slot with nothing to attend: all of it masked, its sums stay zero
+    k = jnp.concatenate([r[0] for r in k_refs], axis=0)  # [keys, F]
+    v = jnp.concatenate([r[0] for r in v_refs], axis=0)
+    q = q_ref[0]  # [Hq, F], block-diagonal
+    exact = None
+    if q.dtype == jnp.float32:  # float32 queries ask for float32 math:
+        # operands AND products (a float32 matmul is one bfloat16 pass by
+        # default, 1e-3 of the result: my chip run, PR 30)
+        k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+        exact = jax.lax.Precision.HIGHEST
+    sc = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), precision=exact,
+        preferred_element_type=jnp.float32) * scale  # [Hq, keys]
+    pos = start + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+    valid = pos <= ctx
     if window is not None:
-        relevant = jnp.logical_and(relevant, start + keys - 1 > ctx - window)
+        valid = jnp.logical_and(valid, pos > ctx - window)
+    sc = jnp.where(valid, sc, _NEG_BIG)
+    m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+    m_new = jnp.maximum(m_new, _NEG_BIG / 2)
+    p = jnp.exp(sc - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+        p.astype(v.dtype), v, precision=exact,
+        preferred_element_type=jnp.float32)
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(relevant)
-    def _block():
-        k = jnp.concatenate([r[0] for r in k_refs], axis=0)  # [keys, F]
-        v = jnp.concatenate([r[0] for r in v_refs], axis=0)
-        q = q_ref[0]  # [Hq, F], block-diagonal
-        if q.dtype == jnp.float32:  # float32 queries ask for float32 math
-            k, v = k.astype(jnp.float32), v.astype(jnp.float32)
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [Hq, keys]
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        valid = pos <= ctx
-        if window is not None:
-            valid = jnp.logical_and(valid, pos > ctx - window)
-        sc = jnp.where(valid, sc, _NEG_BIG)
-        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        m_new = jnp.maximum(m_new, _NEG_BIG / 2)
-        p = jnp.exp(sc - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(g == last_ref[s])
     def _finish():
         o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
                     ).astype(o_ref.dtype)
@@ -378,11 +448,15 @@ def paged_attention_folded(
     *,
     window: int | None = None,
     interpret: bool | None = None,
+    work: WorkList | None = None,
 ) -> jax.Array:
     """What ``paged_attention`` runs over one layer of a pool stored folded,
     ``[NB, bs, kvH * hd]`` (see above): ``q`` [S, Hq, hd] with kv-major
     heads, ``tables`` [S, MB], keys ``0..ctx`` attendable, a band of
-    ``window`` where given.  Returns [S, Hq, hd] in ``q.dtype``."""
+    ``window`` where given.  ``work`` is ``folded_work_list`` of the same
+    contexts and window (a caller with many layers builds it once); every
+    slot counts as running where it is built here.  Returns [S, Hq, hd] in
+    ``q.dtype``."""
     if interpret is None:
         interpret = _default_interpret()
     S, Hq, hd = q.shape
@@ -393,28 +467,26 @@ def paged_attention_folded(
                          f"{kvH} kv heads")
     G = Hq // kvH
     MB = tables.shape[1]
-    pages = min(FOLD_PAGES, MB)
+    pages = _fold(MB, bs, window)[0]
     tables = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, -MB % pages)))
-    groups, keys = tables.shape[1] // pages, pages * bs
     ctx_lens = ctx_lens.astype(jnp.int32)
-    if window is None:
-        first, steps = jnp.zeros_like(ctx_lens), groups
-    else:  # the band (ctx - window, ctx] spans at most this many groups
-        first = jnp.maximum(ctx_lens - window + 1, 0) // keys
-        steps = min(groups, (window - 1) // keys + 2)
+    if work is None:
+        work = folded_work_list(ctx_lens, max_blocks=MB, block_size=bs,
+                                window=window)
     # query head (h, g) in the lanes of KV head h, zeros elsewhere
     own = jnp.asarray(np.arange(Hq)[:, None] // G == np.arange(kvH)[None, :],
                       q.dtype)
     qf = jnp.einsum("shd,hk->shkd", q, own).reshape(S, Hq, F)
 
     def page(i):
-        return pl.BlockSpec((1, bs, F), lambda s, j, t, c, f: (
-            t[s, jnp.minimum(f[s] + j, groups - 1) * pages + i], 0, 0))
+        return pl.BlockSpec((1, bs, F), lambda w, t, c, f, l, so, go: (
+            t[so[w], go[w] * pages + i], 0, 0))
 
-    wide = pl.BlockSpec((1, Hq, F), lambda s, j, t, c, f: (s, 0, 0))
+    wide = pl.BlockSpec((1, Hq, F), lambda w, t, c, f, l, so, go: (
+        so[w], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, steps),
+        num_scalar_prefetch=6,
+        grid=(work.n_items,),
         in_specs=[wide] + [page(i) for i in range(pages)] * 2,
         out_specs=wide,
         scratch_shapes=[
@@ -430,8 +502,11 @@ def paged_attention_folded(
         out_shape=jax.ShapeDtypeStruct((S, Hq, F), q.dtype),
         interpret=interpret,
         name="tadnn_paged_decode_folded",
-    )(tables, ctx_lens, first, qf, *([k_pool] * pages), *([v_pool] * pages))
-    return jnp.einsum("shkd,hk->shd", out.reshape(S, Hq, kvH, hd), own)
+    )(tables, ctx_lens, *work[:4], qf,
+      *([k_pool] * pages), *([v_pool] * pages))
+    # float32 here too (the default rounds this sum over the 0/1 ``own``)
+    return jnp.einsum("shkd,hk->shd", out.reshape(S, Hq, kvH, hd), own,
+                      precision="highest" if q.dtype == jnp.float32 else None)
 
 
 def paged_attention_reference(
@@ -457,8 +532,7 @@ def paged_attention_reference(
 
     if dtype is None:
         dtype = q.dtype
-    folded = not isinstance(k_pool, dict) and k_pool.ndim == 3
-    kv_heads = k_pool.shape[2] // q.shape[2] if folded else None
+    kv_heads = k_pool.shape[2] // q.shape[2] if is_folded(k_pool) else None
     kd = gather_blocks(k_pool, tables, dtype, kv_heads)
     vd = gather_blocks(v_pool, tables, dtype, kv_heads)
     key_idx = jnp.arange(kd.shape[1])[None, :]
