@@ -16,7 +16,12 @@ from ``--seed``):
    finishes with its token count, the paged kernel is in the decode
    executable compiled (not interpreted), and its output is within a
    stated tolerance of ``paged_attention_reference`` on the real pool.
-3. **cache** — where the persistent compile cache is, who placed it, and
+3. **kernels** — the kernels of a hybrid model at ITS published widths,
+   which the 1.3B model does not reach: the folded paged kernel at 30 query
+   heads on 30 KV heads of 128 with every slot full, and the gated delta
+   rule's chunk and step kernels (30 heads, keys of 96, values of 192)
+   against the token-by-token recurrence, compiled and not interpreted.
+4. **cache** — where the persistent compile cache is, who placed it, and
    how many entries it held before and after.
 
 ``--chips 4`` runs, and runs only, what exists only across chips: the
@@ -348,6 +353,91 @@ def serve_phase(sz: Sizes, *, seed: int, on_chip: bool) -> None:
             gc.collect()
 
 
+def hybrid_kernels_phase(*, seed: int, on_chip: bool) -> dict:
+    """The kernels a hybrid model adds, at Olmo-Hybrid-7B's widths on the
+    chip (a tenth of them in a rehearsal), float32 in and against float32
+    oracles at the highest matmul precision."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torch_automatic_distributed_neural_network_tpu.ops import (
+        gated_delta as gd,
+        paged_attention as pa,
+    )
+
+    H, dk, dv, hd, C, S = (30, 96, 192, 128, 512, 8) if on_chip else (
+        3, 16, 24, 16, 40, 3)
+    interpret = not on_chip
+    keys = iter(jax.random.split(jax.random.key(seed + 7), 16))
+    rec = {"phase": "kernels", "heads": H}
+
+    def close(name, got, want, rtol=KERNEL_RTOL):
+        err = float(jnp.max(jnp.abs(got - want)))
+        scale = float(jnp.max(jnp.abs(want)))
+        rec[name + "_max_abs_err"], rec[name + "_max_abs"] = err, scale
+        if not (scale > 0 and math.isfinite(err) and err <= rtol * scale):
+            raise RuntimeError(f"{name}: max abs err {err:.3e} against max "
+                               f"|ref| {scale:.3e} exceeds rtol {rtol}")
+
+    # 1. the folded paged kernel, 30 on 30 heads (a folded row of 3,840),
+    # every slot full: the work list is as long as its arrays
+    MB, bs = 64, 16
+    pool = lambda: (0.5 * jax.random.normal(  # noqa: E731
+        next(keys), (S * MB + 1, bs, H * hd), jnp.float32)).astype(
+            jnp.bfloat16)
+    k0, v0 = pool(), pool()
+    tables = jnp.asarray(1 + np.arange(S * MB).reshape(S, MB), jnp.int32)
+    q = jax.random.normal(next(keys), (S, H, hd), jnp.float32)
+    for name, ctx in (("paged_30x128_full", jnp.full((S,), MB * bs - 1)),
+                      ("paged_30x128_mixed", jnp.arange(S) * 131 % (MB * bs))):
+        ctx = ctx.astype(jnp.int32)
+        out = jax.jit(lambda *a: pa.paged_attention(
+            *a, interpret=interpret))(q, k0, v0, tables, ctx)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(pa.paged_attention_reference)(q, k0, v0, tables, ctx)
+        close(name, out, ref)
+    # 2. the chunk kernel: a chunk of 512 from a state, decays as the
+    # family initialises them, beta up to 2
+    qk = lambda: gd.l2norm(jax.random.normal(  # noqa: E731
+        next(keys), (C, H, dk), jnp.float32))
+    q, k = qk() * dk ** -0.5, qk()
+    v = jax.random.normal(next(keys), (C, H, dv), jnp.float32)
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(next(keys), (C, H)))
+    A = jax.random.uniform(next(keys), (H,), minval=1e-3, maxval=16.0)
+    g = -A * jnp.exp(jax.random.uniform(
+        next(keys), (C, H), minval=math.log(1e-3), maxval=math.log(0.1)))
+    state = jax.random.normal(next(keys), (H, dk, dv), jnp.float32)
+    o_ref, s_ref = jax.jit(gd.gated_delta_recurrent)(q, k, v, g, beta, state)
+    o, s1 = jax.jit(lambda *a: gd.gated_delta_chunk_pallas(
+        *a, interpret=interpret))(q, k, v, g, beta, state)
+    close("gdn_chunk_out", o, o_ref)
+    close("gdn_chunk_state", s1, s_ref)
+    lo = lambda x: x.astype(jnp.bfloat16)  # noqa: E731 — serving's operands
+    o, s1 = jax.jit(lambda *a: gd.gated_delta_chunk_pallas(
+        *a, interpret=interpret))(lo(q), lo(k), lo(v), g, beta, state)
+    close("gdn_chunk_bf16_out", o, o_ref, rtol=0.05)
+    # 3. the step kernel: S slots over rows of a pool, in place; two slots
+    # share the null row, one row is nobody's
+    rows = jnp.asarray([(r + 2) % (S + 1) if r < S - 2 else 0
+                        for r in range(S)], jnp.int32)
+    pool0 = jax.random.normal(next(keys), (S + 2, H, dk, dv), jnp.float32)
+    idle = (rows == 0)[:, None]  # as the decode program masks them
+    args = (q[:S], k[:S], v[:S], jnp.where(idle, 0.0, g[:S]),
+            jnp.where(idle, 0.0, beta[:S]))
+    o_ref, p_ref = jax.jit(gd.gated_delta_step_xla)(*args, pool0, rows)
+    o, p1 = jax.jit(lambda *a: gd.gated_delta_step_pallas(
+        *a, interpret=interpret), donate_argnums=(5,))(
+            *args, pool0 + 0.0, rows)
+    live = np.asarray(rows) > 0
+    close("gdn_step_out", o[live], o_ref[live])
+    close("gdn_step_state", p1[rows[live]], p_ref[rows[live]])
+    for r in (0, S + 1):  # the null row and a row no slot has
+        if not bool(jnp.array_equal(p1[r], pool0[r])):
+            raise RuntimeError(f"gdn step: row {r} was written")
+    return rec
+
+
 def count_entries(path: str | None) -> int | None:
     if path is None:
         return None
@@ -360,6 +450,7 @@ def run_one_chip(sz: Sizes, seed: int, on_chip: bool) -> None:
     emit(train_phase(sz, label="train", devices=jax.devices()[:1],
                      strategy="auto", seed=seed, on_chip=on_chip))
     serve_phase(sz, seed=seed, on_chip=on_chip)
+    emit(hybrid_kernels_phase(seed=seed, on_chip=on_chip))
 
 
 def run_four_chips(sz: Sizes, seed: int, on_chip: bool) -> None:

@@ -277,6 +277,12 @@ def experts(bench):
     return _serve(bench, _cell_of("trinity-large-ep8"))
 
 
+@pytest.fixture(scope="module")
+def hybrid(bench):
+    """A ``layer_types`` model with ``linear_attention`` layers."""
+    return _serve(bench, _cell_of("olmo-hybrid-7b-pp2"))
+
+
 # field of a ``serve.step`` event -> who indexes it (benchmark/ paths)
 STEP_FIELDS = {
     "decode_s": "lib/readers.py:13 lib/serve_phases.py:77 lib/counts_moe.py:47",
@@ -284,7 +290,7 @@ STEP_FIELDS = {
     "phases": "lib/serve_phases.py:46,76 lib/serving_large.py:256,278",
     "t_end": "lib/serve_phases.py:66-67 lib/counts_moe.py:47-48",
     "occupancy": "metrics/slot_occupancy.py:9",
-    "new_tokens": "lib/serving_large.py:264",
+    "new_tokens": "lib/serving_large.py:264 metrics/gdn_step_roofline.py:17",
     "t": "sweep.py:63",
     "n_queued": "sweep.py:66,78",
     "n_active": "sweep.py:66",
@@ -302,6 +308,13 @@ ENGINE_FIELDS = {
     "layer_kinds": "metrics/kv_pool_gib.py:15",
     "experts_held": "lib/serving_large.py:39-40,300 (the record's serve_engine)",
     "experts_published": "lib/serving_large.py:39-40,300",
+}
+# the same, on a model with linear layers only
+ENGINE_FIELDS_LINEAR = {
+    "state_bytes_linear": "metrics/state_pool_gib.py:12,15,19",
+    "conv_bytes_linear": "metrics/state_pool_gib.py:16,19",
+    "kv_bytes_full": "metrics/state_pool_gib.py:17 metrics/kv_pool_gib.py:10",
+    "layer_kinds": "metrics/kv_pool_gib.py:15",
 }
 # attribute of the engine -> who takes it
 ENGINE_ATTRS = {
@@ -331,8 +344,8 @@ def _steps(run: dict) -> list[dict]:
 
 @pytest.mark.parametrize("field", sorted(STEP_FIELDS))
 def test_serve_step_carries_a_field_the_benchmark_indexes(dense, experts,
-                                                          field):
-    for run in (dense, experts):
+                                                          hybrid, field):
+    for run in (dense, experts, hybrid):
         for s in _steps(run):
             assert s.get(field) is not None, (
                 f"serve.step has no {field!r}; read by benchmark/ "
@@ -356,6 +369,29 @@ def test_serve_engine_carries_a_field_the_benchmark_indexes(experts, field):
     assert ev.get(field) is not None, (
         f"serve.engine has no {field!r}; read by benchmark/ "
         + ENGINE_FIELDS[field])
+
+
+@pytest.mark.parametrize("field", sorted(ENGINE_FIELDS_LINEAR))
+def test_serve_engine_of_a_hybrid_model_carries_its_counters(hybrid, field):
+    ev = hybrid["record"]["serve_engine"]
+    assert ev is not None, "no serve.engine event (Journal.named)"
+    assert ev.get(field) is not None, (
+        f"serve.engine has no {field!r}; read by benchmark/ "
+        + ENGINE_FIELDS_LINEAR[field])
+
+
+def test_serve_engine_says_what_a_hybrid_cell_is_about(hybrid):
+    """``state_pool_gib`` adds the states and the tails, ``kv_pool_gib`` the
+    full layers' pages: a hybrid model has both and no ring, and the
+    program's count of its layers by kind is the configuration's (what
+    ``paged_attn_roofline.by_kind`` multiplies by)."""
+    ev, eng = hybrid["record"]["serve_engine"], hybrid["eng"]
+    assert ev["state_bytes_linear"] > ev["conv_bytes_linear"] > 0
+    assert ev["kv_bytes_full"] > 0 and ev["kv_bytes_window"] == 0
+    kinds = hybrid["record"]["model_keys"]["layer_types"]
+    assert ev["layer_kinds"] == list(kinds)
+    assert eng.pool.n_full == list(kinds).count("full_attention") > 0
+    assert eng.pool.state.count(True) == list(kinds).count("linear_attention")
 
 
 def test_serve_engine_says_what_the_cell_is_about(experts):
@@ -429,9 +465,10 @@ def test_a_wait_the_benchmark_subtracts_is_a_phase_of_the_engine(wait):
         "the wait as the host's own time")
 
 
-def test_decode_wait_is_timed_on_every_step_that_reads(dense, experts):
+def test_decode_wait_is_timed_on_every_step_that_reads(dense, experts,
+                                                       hybrid):
     # lib/serving_large.py:278 and serve_host_ms take it from ``phases``
-    for run in (dense, experts):
+    for run in (dense, experts, hybrid):
         waits = [s["phases"].get("decode_wait") for s in _steps(run)]
         assert sum(w is not None for w in waits) >= len(waits) // 2
 
@@ -500,6 +537,7 @@ def test_a_program_the_benchmark_names_is_a_serving_program(dense, program):
 def test_the_names_were_found():
     assert any(k.startswith("tadnn_paged_decode") for k in KERNELS)
     assert any(k.startswith("tadnn_moe_grouped_mm") for k in KERNELS)
+    assert {"tadnn_gdn_chunk", "tadnn_gdn_step"} <= set(KERNELS)
     assert len(PROGRAMS) >= 2 and len(WAITS) >= 1
 
 
@@ -508,7 +546,8 @@ def test_the_names_were_found():
 
 @pytest.mark.parametrize("metric_file", METRIC_FILES)
 def test_a_metric_reader_reads_the_programs_record(bench, dense, experts,
-                                                   metric_file, capsys):
+                                                   hybrid, metric_file,
+                                                   capsys):
     reader = _load(os.path.join(BENCH, "metrics", metric_file),
                    "bench_metric")
     assert callable(getattr(reader, "read", None)), metric_file
@@ -516,7 +555,8 @@ def test_a_metric_reader_reads_the_programs_record(bench, dense, experts,
     entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
     configs = {w["config"] for w in BENCHMARK["workloads"]
                if w["name"] in entry["workloads"]}
-    for config, run in (("gpt2-1p3b", dense), ("trinity-large-ep8", experts)):
+    for config, run in (("gpt2-1p3b", dense), ("trinity-large-ep8", experts),
+                        ("olmo-hybrid-7b-pp2", hybrid)):
         if config not in configs:
             continue
         value = reader.read(run["record"])
@@ -538,6 +578,30 @@ def test_a_reader_of_events_alone_gives_a_number(bench, experts, name,
     value = reader.read(experts["record"])
     capsys.readouterr()
     assert value is not None and math.isfinite(value) and value > 0, name
+
+
+@pytest.mark.parametrize("name", READS_ON_CPU[::2] + ("state_pool_gib",))
+def test_a_reader_of_events_alone_gives_a_number_on_a_hybrid_model(
+        bench, hybrid, name, capsys):
+    reader = _load(os.path.join(BENCH, "metrics", name + ".py"),
+                   "bench_metric")
+    value = reader.read(hybrid["record"])
+    capsys.readouterr()
+    assert value is not None and math.isfinite(value) and value > 0, name
+
+
+def test_a_kind_that_exchanges_names_finds_them(bench):
+    """``lib/serving_long.py`` runs ``lib/serving_large.run`` with three of
+    that module's names bound to its own: they have to be there, under the
+    same parameters."""
+    large = importlib.import_module("lib.serving_large")
+    long_ = importlib.import_module("lib.serving_long")
+    for name, mine in long_.EXCHANGED.items():
+        theirs = getattr(large, name)
+        assert list(inspect.signature(theirs).parameters) \
+            == list(inspect.signature(mine).parameters), name
+    src = inspect.getsource(large.run) + inspect.getsource(large.build)
+    assert all(re.search(rf"\b{name}\(", src) for name in long_.EXCHANGED)
 
 
 # -- 6. the train cell's calls into AutoDistribute and Trainer ---------------

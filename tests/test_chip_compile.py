@@ -180,9 +180,11 @@ def test_kernel_is_named_in_the_compiled_text(kernel_texts, name, where):
 _GPT2_1P3B = dict(
     vocab_size=50257, d_model=2048, n_layers=24, n_heads=16, d_ff=8192,
     max_seq_len=2048, remat=False)
-# (slots, max_len, block, chunk) of the benchmark's serving cells
-_SERVING = {"gpt2-1p3b": (8, 1024, 16, 128),
-            "trinity-large-ep8": (16, 13312, 16, 512)}
+# (slots, max_len, block, chunk, pages in the pool: None for slots x max_len)
+# of the benchmark's serving cells
+_SERVING = {"gpt2-1p3b": (8, 1024, 16, 128, None),
+            "trinity-large-ep8": (16, 13312, 16, 512, None),
+            "olmo-hybrid-7b-pp2": (8, 33792, 16, 512, 4609)}
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
@@ -197,10 +199,13 @@ def test_serving_programs_update_the_pool_in_place(
     ``per_layer_params``), one pair of pool arrays a layer.  GPT-2 1.3B (24
     like layers, 8 slots of 1,024) and ``trinity-large-ep8`` (5 layers of
     two kinds, 32 of 256 experts, 16 slots of 13,312 beside 8.3 GiB of
-    weights) alike: no layer's weight is converted, the pool is updated in
-    place (the output aliases it), and no copy of a layer's pages is among
-    the temporaries (threaded through a layer scan, the pool was copied
-    whole every step)."""
+    weights) and ``olmo-hybrid-7b-pp2`` (16 layers, 12 of them linear: a
+    recurrent state and a convolution tail a slot beside 4,609 pages of
+    keys and values for the 4 full layers, 8 slots of 33,792 beside 9.1 GiB
+    of weights) alike: no layer's weight is converted, the pool is updated
+    in place (the output aliases it: pages, states and tails), and no copy
+    of a layer's pages or states is among the temporaries (threaded through
+    a layer scan, the pool was copied whole every step)."""
     import json
     import os
     import re
@@ -218,19 +223,21 @@ def test_serving_programs_update_the_pool_in_place(
         TransformerConfig,
     )
     from torch_automatic_distributed_neural_network_tpu.ops import (
+        gated_delta as gdn,
         grouped_matmul as gmm,
         paged_attention as paged,
     )
 
     # the default backend is the CPU here: ask for the kernels, not their
-    # interpreter, as the chip would
+    # interpreter (or their plain form), as the chip would
     monkeypatch.setattr(paged, "_default_interpret", lambda: False)
     monkeypatch.setattr(gmm, "_default_interpret", lambda: False)
+    monkeypatch.setattr(gdn, "_on_tpu", lambda: True)
     keys = _GPT2_1P3B
-    if config == "trinity-large-ep8":
+    if config != "gpt2-1p3b":
         path = os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "benchmark", "configs",
-            "trinity-large-ep8.json")
+            config + ".json")
         with open(path) as f:
             keys = json.load(f)["model"]
     cfg = TransformerConfig(**keys, dtype=jnp.bfloat16)
@@ -238,14 +245,14 @@ def test_serving_programs_update_the_pool_in_place(
                            np.zeros((1, 8), np.int32))["params"]
     params = jax.eval_shape(lambda p: decode.per_layer_params(
         decode.compute_dtype_params(p, cfg), cfg), given)
-    slots, max_len, block, chunk = _SERVING[config]
+    slots, max_len, block, chunk, pages = _SERVING[config]
     MB = blocks_for_tokens(max_len, block)
     made = {}
 
     def arrays():
         made["pool"] = PagedKVPool(
-            cfg, num_blocks=slots * MB + 1, block_size=block, n_slots=slots,
-            max_blocks=MB, prefill_chunk=chunk)
+            cfg, num_blocks=pages or slots * MB + 1, block_size=block,
+            n_slots=slots, max_blocks=MB, prefill_chunk=chunk)
         return made["pool"].kv, made["pool"].win_tables
 
     kv, win = jax.eval_shape(arrays)
@@ -260,7 +267,7 @@ def test_serving_programs_update_the_pool_in_place(
                 params, *a, cfg=cfg,
                 sample=decode.SampleConfig(temperature=0.0))
     else:
-        operands = (params, kv, i32(MB + chunk + 2), i32(win.shape[1]))
+        operands = (params, kv, i32(MB + chunk + 3), i32(win.shape[1]))
 
         def step(params, *a):
             return programs.prefill_chunk(params, *a, cfg=cfg, max_blocks=MB)
@@ -280,11 +287,27 @@ def test_serving_programs_update_the_pool_in_place(
         r"= bf16\[[^\]]*\]\S* convert\(%params__layers", l)]
     pool_bytes = made["pool"].total_bytes
     assert mem.alias_size_in_bytes >= pool_bytes  # updated in place
-    assert mem.temp_size_in_bytes < 0.2 * 2**30, mem.temp_size_in_bytes
-    page_arrays = {"bf16[%d,%d,%d]" % x.shape for x in jax.tree.leaves(kv)}
+    # (a chunk's activations at d 3,840 beside 11,520 convolved channels
+    # are 0.22 GiB; a copy of the 4.5 GB of pages would be twenty times it)
+    roomy = 0.25 if config == "olmo-hybrid-7b-pp2" else 0.2
+    assert mem.temp_size_in_bytes < roomy * 2**30, mem.temp_size_in_bytes
+    page_arrays = {("f32" if x.dtype == jnp.float32 else "bf16")
+                   + "[%s]" % ",".join(map(str, x.shape))
+                   for x in jax.tree.leaves(kv)}
     assert not [l[:100] for l in text.splitlines()
                 if " copy(" in l and any(a in l for a in page_arrays)]
-    if config == "trinity-large-ep8":
+    if config == "olmo-hybrid-7b-pp2":
+        # 12 linear layers: the step kernel in the one, the chunk kernel in
+        # the other; 4.53 GB of pages and 0.25 GB of states and tails
+        mine, other = (("tadnn_gdn_step", "tadnn_gdn_chunk")
+                       if program == "decode_step"
+                       else ("tadnn_gdn_chunk", "tadnn_gdn_step"))
+        assert text.count(mine) >= 12 and other not in text
+        assert "tadnn_moe_grouped_mm" not in text
+        assert round(made["pool"].bytes_full / 1e9, 2) == 4.53
+        assert round(sum(made["pool"].bytes_state) / 1e9, 2) == 0.25
+        assert mem.argument_size_in_bytes < 14.0 * 2**30
+    elif config == "trinity-large-ep8":
         assert text.count("tadnn_moe_grouped_mm") >= 8  # 2 kernels, 4 layers
         assert round(pool_bytes / 2**30, 2) == 1.94
         assert mem.argument_size_in_bytes < 10.5 * 2**30
@@ -297,7 +320,8 @@ def test_serving_programs_update_the_pool_in_place(
 
 
 # (slots, query heads, kv heads, blocks of 16 in max_len) of the two models
-_FOLDED = {"gpt2-1p3b": (8, 16, 16, 64), "trinity-large-ep8": (16, 48, 8, 832)}
+_FOLDED = {"gpt2-1p3b": (8, 16, 16, 64), "trinity-large-ep8": (16, 48, 8, 832),
+           "olmo-hybrid-7b-pp2": (8, 30, 30, 2112)}
 
 
 @pytest.mark.parametrize("window", [None, 4096], ids=["full", "window4096"])
@@ -313,9 +337,13 @@ def test_folded_paged_decode_compiles_for_v5e(v5e, config, window):
     )
 
     slots, hq, kvh, mb = _FOLDED[config]
+    if config == "olmo-hybrid-7b-pp2" and window:
+        pytest.skip("no sliding layer in this model")
     one = SingleDeviceSharding(v5e[0])
     sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
-    pool = sds((slots * mb + 1, 16, kvh * 128), jnp.bfloat16)
+    # (a pool of slots x max_len pages at 30 heads would be 8 GB: the
+    # cell's own 4,609 pages)
+    pool = sds((min(slots * mb + 1, 4609), 16, kvh * 128), jnp.bfloat16)
 
     def call(q, k, v, t, c, active):
         work = folded_work_list(c, active, max_blocks=mb, block_size=16,
@@ -369,3 +397,38 @@ def test_grouped_matmul_compiles_for_v5e(v5e, pairs):
                     sds((n_tiles,), jnp.int32), sds((), jnp.int32))
     assert "tadnn_moe_grouped_mm_gate_up" in text
     assert "tadnn_moe_grouped_mm_down" in text
+
+
+# -- the gated delta rule's two kernels, at Olmo-Hybrid-7B's widths -----------
+
+
+@pytest.mark.parametrize("form,dtype", [
+    ("chunk", jnp.bfloat16), ("chunk", jnp.float32), ("step", jnp.bfloat16)])
+def test_gated_delta_kernels_compile_for_v5e(v5e, form, dtype):
+    """30 heads, keys of 96 and values of 192 (neither a multiple of the
+    lane width): the chunk kernel over a prefill chunk of 512 in serving's
+    bfloat16 and in ``chip_smoke.py``'s float32, the step kernel over 8
+    slots of a pool of 9 rows, which it reads and writes in place."""
+    from torch_automatic_distributed_neural_network_tpu.ops import gated_delta as gd
+
+    one = SingleDeviceSharding(v5e[0])
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+    H, dk, dv = 30, 96, 192
+    if form == "chunk":
+        T = 512
+        text = _compile(
+            gd.gated_delta_chunk_pallas, sds((T, H, dk), dtype),
+            sds((T, H, dk), dtype), sds((T, H, dv), dtype),
+            sds((T, H), jnp.float32), sds((T, H), jnp.float32),
+            sds((H, dk, dv), jnp.float32))
+        assert "tadnn_gdn_chunk" in text
+        return
+    S = 8
+    compiled = jax.jit(gd.gated_delta_step_pallas, donate_argnums=(5,)).lower(
+        sds((S, H, dk), dtype), sds((S, H, dk), dtype), sds((S, H, dv), dtype),
+        sds((S, H), jnp.float32), sds((S, H), jnp.float32),
+        sds((S + 1, H, dk, dv), jnp.float32), sds((S,), jnp.int32)).compile()
+    assert "tadnn_gdn_step" in compiled.as_text()
+    # the pool is the output: no second copy of it
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= (S + 1) * H * dk * dv * 4

@@ -284,14 +284,19 @@ def forward_cached(
     return logits, new_cache
 
 
+_FLOAT32_LEAVES = ("router", "q_norm", "k_norm",
+                   "A_log", "dt_bias", "conv", "o_norm")
+
+
 def _cast_floats(tree: Any, dtype) -> Any:
     """Floating leaves of a (sub)tree in ``dtype``; int8 ``{"q", "scale"}``
-    leaves, a ``router``, the QK norms' gains and leaves already in
-    ``dtype`` as they are."""
+    leaves, a ``router``, the QK norms' gains, what a ``linear_attention``
+    mixer reads in float32 (its decay rates, filters and output norm) and
+    leaves already in ``dtype`` as they are."""
     if is_quantized_leaf(tree):
         return tree
     if isinstance(tree, dict):
-        return {k: v if k in ("router", "q_norm", "k_norm")
+        return {k: v if k in _FLOAT32_LEAVES
                 else _cast_floats(v, dtype) for k, v in tree.items()}
     if jnp.issubdtype(tree.dtype, jnp.floating) and tree.dtype != dtype:
         return tree.astype(dtype)

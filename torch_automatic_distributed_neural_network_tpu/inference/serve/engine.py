@@ -12,9 +12,10 @@ entry point, one trace per distinct prompt length).
 The two programs are ``programs.decode_step`` and
 ``programs.prefill_chunk`` (``serve/programs.py``): ONE pair for every
 model.  They walk the layers one by one, a model with one kind of layer
-and a model whose layers differ (``cfg.layer_types``: window and full
-attention mixed, expert FFNs) alike, the per-layer math the TRAINING
-modules applied piecewise, and every layer's pages updated in place.
+and a model whose layers differ (``cfg.layer_types``: window, full and
+linear attention mixed, expert FFNs) alike, the per-layer math the TRAINING
+modules applied piecewise, and every layer's pages, or the recurrent state
+of a ``linear_attention`` layer, updated in place.
 
 Prefill writes a prompt's keys and values straight into the request's
 blocks and attends through its table: the same math as ``generate()``'s
@@ -68,8 +69,10 @@ lands, and whether a request has used up ``max_new_tokens``, is known from
 the count of tokens DISPATCHED (``Request.n_dispatched``), never from a
 token's value.  What lags is the value alone: an ``eos_id`` is seen one
 step late (that step's token for the slot is thrown away,
-``discarded_tokens``; its KV write fell into a page the request owned), and
-a token's wall time is the moment the host holds it.  The depth is 1 or 0
+``discarded_tokens``; its KV write fell into a page the request owned, and
+on a ``linear_attention`` layer it spoiled the state of a slot whose next
+prompt starts from zeros anyway), and a token's wall time is the moment the
+host holds it.  The depth is 1 or 0
 by what the engine can see: speculative drafts are a lookup over the
 tokens just produced, so ``speculative > 0`` reads before it dispatches.
 
@@ -251,12 +254,25 @@ class ServeEngine:
         # time model changes.
         self.disaggregate = bool(disaggregate)
         kinds = self.cfg.layer_types or ()
+        # what is not served, each with its reason (the message names the
+        # option and the kind of layer)
         refused = {
             # a sliding layer's ring is written over as its window passes,
             # so a finished prompt's keys are no longer there for another
             # request to reuse
             "prefix_cache with sliding_attention layers": (
                 prefix_cache and "sliding_attention" in kinds),
+            # a hit resumes a prompt at the matched boundary, and a linear
+            # layer would need its state AT that boundary: nobody kept it
+            "prefix_cache with linear_attention layers (a hit needs the "
+            "recurrent state at the matched boundary, which is not kept)": (
+                prefix_cache and "linear_attention" in kinds),
+            # a verify step writes k drafts into the state; a rejected one
+            # cannot be taken out of it again (keys past a context are
+            # simply masked)
+            "speculative > 0 with linear_attention layers (a rejected "
+            "draft cannot be taken out of the recurrent state)": (
+                self.speculative > 0 and "linear_attention" in kinds),
             # a ring has room for ONE chunk beside the window
             "prefill_chunk=None with sliding_attention layers": (
                 prefill_chunk is None and "sliding_attention" in kinds),
@@ -267,7 +283,11 @@ class ServeEngine:
             # the expert layer has no form under a mesh: on one chip it
             # runs without its exchange
             "mesh for a model with expert layers": (
-                mesh is not None and bool(self.cfg.n_expert_layers))}
+                mesh is not None and bool(self.cfg.n_expert_layers)),
+            # the state pool and its two kernels have no sharded form
+            "mesh for a model with linear_attention layers (the recurrent "
+            "state has no sharded form)": (
+                mesh is not None and "linear_attention" in kinds)}
         if any(refused.values()):
             raise ValueError("not served: " + "; ".join(
                 k for k, v in refused.items() if v))
@@ -444,15 +464,18 @@ class ServeEngine:
                         variables["params"], self.cfg))))),
             weight_bytes_compute=held_bytes(jnp.dtype(self.cfg.dtype)),
             weight_bytes_fp32=held_bytes(jnp.float32),
-            # the layers by kind and the bytes their pages hold: pages for
-            # max_len a slot, and the sliding layers' rings
+            # the layers by kind and the bytes their caches hold: pages for
+            # max_len a slot, the sliding layers' rings, and the linear
+            # layers' recurrent states and convolution tails (a row a slot)
             layer_kinds=(list(kinds) or None),
             experts_held=(self.cfg.n_experts_held
                           if self.cfg.n_expert_layers else 0),
             experts_published=(self.cfg.experts_published
                                if self.cfg.n_expert_layers else 0),
             kv_bytes_full=self.pool.bytes_full,
-            kv_bytes_window=self.pool.bytes_window)
+            kv_bytes_window=self.pool.bytes_window,
+            state_bytes_linear=self.pool.bytes_state[0],
+            conv_bytes_linear=self.pool.bytes_state[1])
         # the counters of the decode step last read (serve.step carries them)
         self._counters: dict[str, int] = {}
 
@@ -532,7 +555,7 @@ class ServeEngine:
         C = chunk or self.prefill_chunk
         return jax.eval_shape(lambda: (
             self.params, self.pool.kv,
-            jnp.zeros((self.max_blocks + C + 2,), jnp.int32),
+            jnp.zeros((self.max_blocks + C + 3,), jnp.int32),
             self._win_rows[0]))
 
     def compiled_decode_text(self) -> str:
@@ -721,10 +744,10 @@ class ServeEngine:
             chunk = req.prompt[st.pos:st.pos + C]
             n_real = len(chunk)
             t0 = time.monotonic()
-            # one upload: table row, tokens, cursor, last real row
+            # one upload: table row, tokens, cursor, last real row, slot
             packed = programs.pack_chunk(
                 self.pool.table_row(req.blocks, self.max_blocks),
-                chunk + [0] * (C - n_real), st.pos, n_real - 1)
+                chunk + [0] * (C - n_real), st.pos, n_real - 1, slot)
             if st.lora is None:
                 self.pool.kv, logits = self._prefill_fn(
                     self.params, self.pool.kv, packed, self._win_rows[slot])

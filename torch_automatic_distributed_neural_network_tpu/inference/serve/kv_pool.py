@@ -43,18 +43,25 @@ through :func:`gather_blocks` (table-indexed gather to a dense
 engine's ``attention_impl="dense"`` path and the oracle of every kernel
 parity test.
 
-Two kinds of layer (``cfg.layer_types``).  A full-attention layer, and
-every layer of a model with one kind of layer, has the pages above: the
-allocator's ids, a request's table, room for ``max_len`` a slot.  A
-``sliding_attention`` layer needs only the last ``window`` keys, so it
-keeps a RING of ``window_pages`` pages a slot, owned by the slot for the
-engine's life: position ``p`` lives in ring page ``(p // bs) %
-window_pages``, and a page is written over once the window has passed it.
-The ring is ``ceil((window + prefill_chunk) / bs) + 1`` pages, enough for
-a prefill chunk to land while every key its first row may see is still
-there.  The kernels see a ring through an ordinary table, ``ring_table``:
-logical block ``j`` at the ring page that holds it, null outside the live
-band.  The scheduler's block accounting is the full layers' alone.
+Three kinds of layer (``cfg.layer_types``), one pair of arrays each.  A
+full-attention layer, and every layer of a model with one kind of layer,
+has the pages above: the allocator's ids, a request's table, room for
+``max_len`` a slot.  A ``sliding_attention`` layer needs only the last
+``window`` keys, so it keeps a RING of ``window_pages`` pages a slot, owned
+by the slot for the engine's life: position ``p`` lives in ring page ``(p
+// bs) % window_pages``, and a page is written over once the window has
+passed it.  The ring is ``ceil((window + prefill_chunk) / bs) + 1`` pages,
+enough for a prefill chunk to land while every key its first row may see is
+still there.  The kernels see a ring through an ordinary table,
+``ring_table``: logical block ``j`` at the ring page that holds it, null
+outside the live band.  A ``linear_attention`` layer has no keys and values
+at all: its pair is the recurrent STATE ``[n_slots + 1, H, d_k, d_v]`` in
+float32 (under ``"k"``) and the short convolution's TAIL ``[n_slots + 1, K -
+1, 2 H d_k + H d_v]`` in the compute dtype (under ``"v"``), a row a slot,
+owned by the slot like a ring; row 0 is the null row that inactive slots
+write to, as ``NULL_BLOCK`` is for pages.  A slot's row is not cleared
+between requests by the host: the chunk program starts a prompt at position
+0 from zeros.  The scheduler's block accounting is the full layers' alone.
 
 Sharding: a leaf's spec is ``cache_partition_spec`` less its layer and
 batch axes (blocks are a global resource, any slot may use any block) —
@@ -197,6 +204,16 @@ def pool_kv_bytes(cfg: TransformerConfig, num_blocks: int, block_size: int,
     return 2 * n_cells * per_cell  # k and v
 
 
+def state_row_bytes(cfg: TransformerConfig, dtype=jnp.bfloat16
+                    ) -> tuple[int, int]:
+    """(state, convolution tail) bytes of ONE slot's row in ONE
+    ``linear_attention`` layer."""
+    H, dk, dv = (cfg.linear_value_heads, cfg.linear_key_head_dim,
+                 cfg.linear_value_head_dim)
+    return (H * dk * dv * 4, (cfg.linear_conv_kernel - 1) * H * (2 * dk + dv)
+            * jnp.dtype(dtype).itemsize)
+
+
 def _zeros_side(shape, dtype, quantize: bool):
     if not quantize:
         return jnp.zeros(shape, dtype)
@@ -313,7 +330,8 @@ class PagedKVPool:
     token.
 
     ``n_slots``, ``max_blocks`` and ``prefill_chunk`` size the rings of a
-    model's ``sliding_attention`` layers; a model without them needs none.
+    model's ``sliding_attention`` layers and ``n_slots`` the rows of its
+    ``linear_attention`` layers; a model without them needs none.
     """
 
     def __init__(self, cfg: TransformerConfig, *, num_blocks: int,
@@ -332,15 +350,19 @@ class PagedKVPool:
         self.n_transfers = 0
         self.transferred_blocks = 0
         self.transferred_bytes = 0
-        # the kind of every layer's pages: True where the layer keeps a ring
-        self.ring = [kind == "sliding_attention"
-                     for kind in cfg.layer_types or (None,) * cfg.n_layers]
+        # the kind of every layer's pair: True where the layer keeps a ring,
+        # True where it keeps a recurrent state and no pages at all
+        kinds = cfg.layer_types or (None,) * cfg.n_layers
+        self.ring = [kind == "sliding_attention" for kind in kinds]
+        self.state = [kind == "linear_attention" for kind in kinds]
+        if any(self.state) and mesh is not None:
+            raise ValueError("a recurrent state has no sharded form")
         # a slot's ring, [n_slots, W] page ids (W 0: no layer has one)
         W = 0
         if any(self.ring):
             W = min(max_blocks, window_pages(
                 cfg.sliding_window, prefill_chunk or max_blocks * bs, bs))
-        n_slots = n_slots or 0
+        self.n_slots = n_slots = n_slots or 0
         self.win_tables = (
             1 + W * jnp.arange(n_slots, dtype=jnp.int32)[:, None]
             + jnp.arange(W, dtype=jnp.int32)[None, :])
@@ -350,12 +372,23 @@ class PagedKVPool:
         page = ((bs, cfg.kv_heads * cfg.head_dim) if folded
                 else (bs, cfg.kv_heads, cfg.head_dim))
 
-        def side():
-            return [_zeros_side(
-                (self.n_window_blocks if ring else num_blocks, *page),
-                dtype, self.quantize) for ring in self.ring]
+        # a linear layer's two arrays: (a slot's row, dtype) under each name
+        rows = {}
+        if any(self.state):
+            H, dk, dv = (cfg.linear_value_heads, cfg.linear_key_head_dim,
+                         cfg.linear_value_head_dim)
+            rows = {"k": ((H, dk, dv), jnp.float32),
+                    "v": ((cfg.linear_conv_kernel - 1, H * (2 * dk + dv)),
+                          cfg.dtype)}
 
-        self.kv = {"k": side(), "v": side()}
+        def side(name):
+            return [jnp.zeros((n_slots + 1, *rows[name][0]), rows[name][1])
+                    if state else _zeros_side(
+                        (self.n_window_blocks if ring else num_blocks, *page),
+                        dtype, self.quantize)
+                    for ring, state in zip(self.ring, self.state)]
+
+        self.kv = {"k": side("k"), "v": side("v")}
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -369,12 +402,17 @@ class PagedKVPool:
         return self.allocator.num_blocks
 
     @property
+    def n_full(self) -> int:
+        """Layers that keep pages for ``max_len``."""
+        return self.cfg.n_layers - self.ring.count(True) - self.state.count(
+            True)
+
+    @property
     def bytes_full(self) -> int:
         """Bytes of the layers that keep pages for ``max_len`` (every
         layer of a model with one kind of layer)."""
         return pool_kv_bytes(self.cfg, self.num_blocks, self.block_size,
-                             self.dtype, self.quantize,
-                             n_layers=self.ring.count(False))
+                             self.dtype, self.quantize, n_layers=self.n_full)
 
     @property
     def bytes_window(self) -> int:
@@ -384,8 +422,19 @@ class PagedKVPool:
             self.quantize, n_layers=self.ring.count(True))
 
     @property
+    def bytes_state(self) -> tuple[int, int]:
+        """(recurrent states, convolution tails) bytes of the
+        ``linear_attention`` layers, the null row included ((0, 0) without
+        such layers)."""
+        n = self.state.count(True) * (self.n_slots + 1)
+        if not n:
+            return 0, 0
+        state, conv = state_row_bytes(self.cfg, self.cfg.dtype)
+        return n * state, n * conv
+
+    @property
     def total_bytes(self) -> int:
-        return self.bytes_full + self.bytes_window
+        return self.bytes_full + self.bytes_window + sum(self.bytes_state)
 
     @property
     def bytes_per_block(self) -> int:
@@ -394,7 +443,7 @@ class PagedKVPool:
         the unit the block-transfer accounting charges per shipped
         block."""
         return pool_kv_bytes(self.cfg, 1, self.block_size, self.dtype,
-                             self.quantize, n_layers=self.ring.count(False))
+                             self.quantize, n_layers=self.n_full)
 
     def alloc(self, n: int) -> list[int] | None:
         return self.allocator.alloc(n)
@@ -408,16 +457,17 @@ class PagedKVPool:
         is exhausted — the caller must evict or preempt first).  The
         caller owns the table update and the release of its reference
         on ``src``; the copy itself is one scatter a leaf, no host
-        round-trip.  A ring has no block ids to share, and is left alone."""
+        round-trip.  A ring and a recurrent state have no block ids to
+        share, and are left alone."""
         got = self.allocator.acquire(1)
         if got is None:
             return None
         dst = got[0]
         for side, layers in self.kv.items():
             self.kv[side] = [
-                leaf if ring else jax.tree.map(
+                leaf if ring or state else jax.tree.map(
                     lambda x: x.at[dst].set(x[src]), leaf)
-                for leaf, ring in zip(layers, self.ring)]
+                for leaf, ring, state in zip(layers, self.ring, self.state)]
         return dst
 
     def table_row(self, blocks: list[int], max_blocks: int) -> list[int]:

@@ -2,13 +2,15 @@
 
 Both walk the model's layers one by one (``transformer_core.layer_plan``:
 a model with one kind of layer is a plan of that one kind), each layer
-with its own window, rotation and FFN, and each with its own pair of pool
-arrays (``kv_pool``: pages for ``max_len``, or a ring on a
-``sliding_attention`` layer), so a call updates every layer's pages in
-place and copies none.  The per-layer math is the TRAINING modules applied
-piecewise, the single-source-of-truth discipline of
-``decode.forward_cached``: ``SelfAttention.qkv`` / ``out_proj``,
-``MLPBlock``, ``SparseMLP``, ``make_norm``.
+with its own mixer, window, rotation and FFN, and each with its own pair of
+pool arrays (``kv_pool``: pages for ``max_len``, a ring on a
+``sliding_attention`` layer, or on a ``linear_attention`` layer the
+recurrent state and the convolution's tail, a row a slot), so a call
+updates every layer's pair in place and copies none.  The per-layer math is
+the TRAINING modules applied piecewise, the single-source-of-truth
+discipline of ``decode.forward_cached``: ``SelfAttention.qkv`` /
+``out_proj``, ``GatedDeltaMixer.qkv`` / ``out_proj``, ``MLPBlock``,
+``SparseMLP``, ``make_norm``.
 
 - The decode step takes a [S, T] token chunk for every slot: T == 1 is
   plain one-token decode, T == 1 + k a speculative verify step.  Positions
@@ -22,6 +24,15 @@ piecewise, the single-source-of-truth discipline of
   pages are the only copy (a [1, max_len] cache a request would not fit
   beside the weights at 16 slots of 13k positions), and a prefix the
   radix index matched is read where it lies.
+- A ``linear_attention`` layer reads and writes its slot's ROW instead
+  (``kv_pool``: row ``slot + 1``; row 0 takes what inactive slots write).
+  A chunk runs the gated delta rule's chunk form from the state the chunk
+  before left, or from zeros where the prompt starts (``pos0 == 0``: a
+  slot's row is never cleared by the host), and keeps the last K - 1 REAL
+  rows of its projections as the convolution's tail; the decode step runs
+  the step form, one token a slot.  Rows that are no real token (a padded
+  chunk's tail, an inactive slot) carry ``beta = 0`` and ``g = 0``, which
+  leave a state as it was.
 
 A layer is traced once a kind, not once a layer: the walk calls one jitted
 function a (kind, FFN) pair, so a model of 24 like layers traces one.
@@ -44,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...models.transformer_core import (
+    GatedDeltaMixer,
     MLPBlock,
     SelfAttention,
     SparseMLP,
@@ -52,6 +64,7 @@ from ...models.transformer_core import (
     make_norm,
     rope,
 )
+from ...ops.gated_delta import gated_delta_chunk, gated_delta_step
 from ...training.lora import LoraSpec, merge_lora
 from ..decode import (
     SampleConfig,
@@ -105,33 +118,43 @@ def _logits(params, cfg: TransformerConfig, x):
 
 def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
            moe: str = "dense", adapted=None):
-    """One layer: ``attend(q, k, v)`` writes the new keys and values and
-    returns the attention output; everything else is shared by the chunk
-    and the step.  ``adapted(tensor, site, inp, rotate)`` adds a tenant's
-    low-rank delta at a projection (decode steps with tenants).  Returns
-    ``(x, the expert FFN's counters or None)``."""
+    """One layer, its mixer by ``kind``: ``attend`` is what touches the
+    cache, everything else is shared by the chunk and the step.  On an
+    attention layer ``attend(q, k, v)`` writes the new keys and values and
+    returns the attention output; on a ``linear_attention`` layer
+    ``attend(qkv, h)`` is the state update (``qkv(h, tail)`` gives the
+    mixer's ``qkv`` on the layer's input and the convolution's tail).
+    ``adapted(tensor, site, inp, rotate)`` adds a tenant's low-rank delta at
+    a projection (decode steps with tenants).  Returns ``(x, the expert
+    FFN's counters or None)``."""
     dtype = cfg.dtype
     norm = make_norm(cfg)
-    attn = SelfAttention(cfg, kind)
     # int8 weight-only serving: only this layer's weights convert
     lp = dequantize_tree(lp, dtype)
-    h = norm.apply({"params": lp["attn_norm"]}, x)
-    q, k, v = attn.apply({"params": lp["attn"]}, h, positions, method="qkv")
-    if adapted is not None:
-        hf = h.astype(jnp.float32)
-        q = adapted(q, "q", hf, cfg.layer_rotates(kind))
-        k = adapted(k, "k", hf, cfg.layer_rotates(kind))
-        v = adapted(v, "v", hf, False)
-    o = attend(q, k, v)
-    ao = attn.apply({"params": lp["attn"]}, o.astype(dtype), h,
-                    method="out_proj")
-    if adapted is not None:
-        ao = adapted(ao, "o", o.reshape(*o.shape[:2], -1).astype(
-            jnp.float32), False)
+    h = norm.apply({"params": lp["attn_norm"]}, x) if cfg.pre_norm else x
+    if kind == "linear_attention":
+        mixer, own = GatedDeltaMixer(cfg), {"params": lp["attn"]}
+        o = attend(lambda *a: mixer.apply(own, *a, method="qkv"), h)
+        ao = mixer.apply(own, o, h, method="out_proj")
+    else:
+        attn = SelfAttention(cfg, kind)
+        q, k, v = attn.apply({"params": lp["attn"]}, h, positions,
+                             method="qkv")
+        if adapted is not None:
+            hf = h.astype(jnp.float32)
+            q = adapted(q, "q", hf, cfg.layer_rotates(kind))
+            k = adapted(k, "k", hf, cfg.layer_rotates(kind))
+            v = adapted(v, "v", hf, False)
+        o = attend(q, k, v)
+        ao = attn.apply({"params": lp["attn"]}, o.astype(dtype), h,
+                        method="out_proj")
+        if adapted is not None:
+            ao = adapted(ao, "o", o.reshape(*o.shape[:2], -1).astype(
+                jnp.float32), False)
     if cfg.sandwich_norm:
         ao = norm.apply({"params": lp["post_attn_norm"]}, ao)
     x = x + ao
-    h = norm.apply({"params": lp["mlp_norm"]}, x)
+    h = norm.apply({"params": lp["mlp_norm"]}, x) if cfg.pre_norm else x
     counters = None
     if sparse:
         h, counters = SparseMLP(cfg).apply({"params": lp["mlp"]}, h, valid)
@@ -143,6 +166,13 @@ def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
     if cfg.sandwich_norm:
         h = norm.apply({"params": lp["post_mlp_norm"]}, h)
     return x + h, counters
+
+
+def _paged(cfg) -> list[int]:
+    """The layers that keep keys and values in pages (all but the
+    ``linear_attention`` ones)."""
+    return [i for i, (_, kind, _) in enumerate(layer_plan(cfg))
+            if kind != "linear_attention"]
 
 
 def _pages_of(kind: str | None) -> str:
@@ -169,9 +199,11 @@ def _moe_counters(stats: list) -> jax.Array:
 def _walk(cfg, params, kv, x, layer_fn, shared, extras=None):
     """``layer_fn(kind, sparse)(lp, k_pages, v_pages, x, extra, shared)``
     over the plan, one jitted function a (kind, FFN) pair so that like
-    layers are traced once; ``shared`` is what every layer reads (tables,
-    positions), ``extras`` one more operand a layer (a tenant's factors).
-    Returns ``(x, kv, the layers' counters)``."""
+    layers are traced once; the pair of arrays is the layer's own
+    (``kv_pool``: pages, a ring, or a state and a tail); ``shared`` is what
+    every layer reads (tables, positions, rows), ``extras`` one more
+    operand a layer (a tenant's factors).  Returns ``(x, kv, the layers'
+    counters)``."""
     fns, new_k, new_v, stats = {}, [], [], []
     for i, (name, kind, sparse) in enumerate(layer_plan(cfg)):
         fn = fns.get((kind, sparse))
@@ -230,7 +262,9 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
 
     S, T = tok.shape
     MB = tables.shape[1]
-    bs = kv_leaf_parts(kv["k"][0])[0].shape[1]
+    paged = _paged(cfg)  # none: a model of recurrent states alone
+    pages0 = kv["k"][paged[0]] if paged else jnp.zeros((1, 1, 1))
+    bs = kv_leaf_parts(pages0)[0].shape[1]
     if mesh is not None and spec is not None:
         from jax.sharding import NamedSharding
 
@@ -243,16 +277,20 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
     hi = jnp.where(active, (ctx_lens + T - 1) // bs, -1)
     full = jnp.where(jnp.arange(MB)[None, :] <= hi[:, None], tables, 0)
     shared = {"tables": {"pages": full}, "ctx_lens": ctx_lens,
-              "active": active, "adapter_ids": adapter_ids}
+              "active": active, "adapter_ids": adapter_ids,
+              # a slot's row of a linear layer's state; the null row for
+              # a slot that does not decode
+              "rows": jnp.where(active, 1 + jnp.arange(S), 0)}
     if win_tables.shape[1]:
         lo = (ctx_lens - cfg.sliding_window + 1) // bs
         shared["tables"]["ring"] = ring_table(win_tables, MB, lo, hi)
     # the folded kernel's grid, the live (slot, key group) items: one list a
     # kind of table, built here once a step and not in every layer's call
-    kinds = [kind for _, kind, _ in layer_plan(cfg)]
+    kinds = [kind for _, kind, _ in layer_plan(cfg)
+             if kind != "linear_attention"]
     grid = jnp.zeros((2,), jnp.int32)
     shared["work"] = {}
-    if attention_impl == "paged" and T == 1 and is_folded(kv["k"][0]):
+    if attention_impl == "paged" and T == 1 and paged and is_folded(pages0):
         shared["work"] = {  # in the plan's order: the same text every run
             _pages_of(kind): folded_work_list(
                 ctx_lens, active, max_blocks=MB, block_size=bs,
@@ -264,6 +302,23 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
 
     def layer_fn(kind, sparse):
         window = cfg.layer_window(kind)
+
+        def linear_fn(lp, state, tails, x, _, shared):
+            rows, active = shared["rows"], shared["active"]
+
+            def attend(qkv, h):
+                nonlocal state, tails
+                q, k, v, g, beta, full = qkv(h, tails[rows])
+                tails = tails.at[rows].set(full[:, 1:].astype(tails.dtype))
+                live = active[:, None]
+                o, state = gated_delta_step(
+                    q[:, 0], k[:, 0], v[:, 0], jnp.where(live, g[:, 0], 0.0),
+                    jnp.where(live, beta[:, 0], 0.0), state, rows)
+                return o[:, None]
+
+            x, _ = _layer(cfg, lp, kind, sparse, x, None,
+                          jnp.broadcast_to(active[:, None], (S, T)), attend)
+            return x, state, tails, None
 
         def fn(lp, k_l, v_l, x, ad, shared):
             table = shared["tables"][_pages_of(kind)]
@@ -315,7 +370,7 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
                                  attend, adapted=adapted)
             return x, k_l, v_l, counters
 
-        return fn
+        return linear_fn if kind == "linear_attention" else fn
 
     extras = None
     if adapters:  # one layer's factors a layer
@@ -434,17 +489,19 @@ def chunk_attention(q, k_layer, v_layer, table_row, pos0, window,
     return o.transpose(2, 0, 1, 3).reshape(C, H, hd).astype(q.dtype)
 
 
-def pack_chunk(table_row, tokens, pos0: int, last_idx: int) -> np.ndarray:
-    """A prefill chunk's host operands as ONE int32 vector [MB + C + 2]:
-    the slot's table row, the chunk's tokens, its first position and its
-    last real row."""
-    return np.asarray([*table_row, *tokens, pos0, last_idx], np.int32)
+def pack_chunk(table_row, tokens, pos0: int, last_idx: int,
+               slot: int = 0) -> np.ndarray:
+    """A prefill chunk's host operands as ONE int32 vector [MB + C + 3]:
+    the slot's table row, the chunk's tokens, its first position, its last
+    real row, and the slot (whose row a ``linear_attention`` layer keeps
+    its state in)."""
+    return np.asarray([*table_row, *tokens, pos0, last_idx, slot], np.int32)
 
 
 def prefill_chunk(params, kv, packed, win_row, *, cfg: TransformerConfig,
                   max_blocks: int, moe_decode: str = "dense"):
     """One [1, C] chunk of one slot's prompt (operands of ``pack_chunk``;
-    C is what ``packed`` holds beyond ``max_blocks + 2``) at positions
+    C is what ``packed`` holds beyond ``max_blocks + 3``) at positions
     ``pos0 ..``, written into the slot's pages (the table row [max_blocks]
     on the layers that keep pages for ``max_len``, the ring ``win_row`` [W]
     on the sliding ones).  Every chunk of every prompt reuses ONE jitted
@@ -453,21 +510,48 @@ def prefill_chunk(params, kv, packed, win_row, *, cfg: TransformerConfig,
     is its last real row, whose logits are returned ([1, V]); causal
     masking keeps the pad rows (which sit after it) out of that row, they
     route to no expert, and the keys they write lie past the prompt, where
-    decode writes over them before it reads.  An int8 pool quantizes the
+    decode writes over them before it reads; on a ``linear_attention``
+    layer they leave the state alone and stay out of the convolution's
+    tail.  An int8 pool quantizes the
     chunk as it lands, so later chunks, decode and any request that reuses
     these pages through the prefix cache all read the same (q, scale)
     pairs.  Returns ``(kv, logits)``."""
     MB = max_blocks
-    C = packed.shape[0] - MB - 2
+    C = packed.shape[0] - MB - 3
     table_row, tokens = packed[:MB], packed[MB:MB + C][None]
-    pos0, last_idx = packed[-2], packed[-1]
+    pos0, last_idx = packed[-3], packed[-2]
     shared = {"rows": {"pages": table_row}, "pos0": pos0,
-              "last_idx": last_idx}
+              "last_idx": last_idx, "row": 1 + packed[-1]}
     if win_row.shape[0]:
         shared["rows"]["ring"] = win_row[jnp.arange(MB) % win_row.shape[0]]
 
     def layer_fn(kind, sparse):
         window = cfg.layer_window(kind)
+
+        def linear_fn(lp, state, tails, x, _, shared):
+            row, last_idx = shared["row"], shared["last_idx"]
+            fresh = shared["pos0"] == 0  # a prompt starts from zeros
+            valid = (jnp.arange(C) <= last_idx)[None, :]
+
+            def attend(qkv, h):
+                nonlocal state, tails
+                q, k, v, g, beta, full = qkv(
+                    h, jnp.where(fresh, 0, tails[row])[None])
+                # the tail the next call reads: the last K - 1 real rows
+                tails = tails.at[row].set(jax.lax.dynamic_slice_in_dim(
+                    full[0], last_idx + 1, tails.shape[1]).astype(
+                        tails.dtype))
+                real = valid[0][:, None]
+                o, new = gated_delta_chunk(
+                    q[0], k[0], v[0], jnp.where(real, g[0], 0.0),
+                    jnp.where(real, beta[0], 0.0),
+                    jnp.where(fresh, 0.0, state[row]))
+                state = state.at[row].set(new)
+                return o[None]
+
+            x, _ = _layer(cfg, lp, kind, sparse, x, None, valid, attend,
+                          moe=moe_decode)
+            return x, state, tails, None
 
         def fn(lp, k_l, v_l, x, _, shared):
             row, pos0 = shared["rows"][_pages_of(kind)], shared["pos0"]
@@ -485,7 +569,7 @@ def prefill_chunk(params, kv, packed, win_row, *, cfg: TransformerConfig,
                                   valid, attend, moe=moe_decode)
             return x, k_l, v_l, None
 
-        return fn
+        return linear_fn if kind == "linear_attention" else fn
 
     x = _embed(params, cfg, tokens, pos0 + jnp.arange(C)[None, :])
     x, kv, _ = _walk(cfg, params, kv, x, layer_fn, shared)

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import attention
+from ..ops.gated_delta import causal_conv, gated_delta_chunk, l2norm
 from ..parallel.context import shard_activations
 from ..parallel.expert import held_expert_ffn, route_top_k
 
@@ -77,19 +78,27 @@ class TransformerConfig:
     remat_policy: Literal["dots", "nothing"] = "dots"
     rope_theta: float = 10000.0
     # -- layer kinds as data: a model whose layers differ -------------------
-    # One entry a layer, "sliding_attention" (the causal band of
-    # ``sliding_window``) or "full_attention" (plain causal).  Given, the
-    # layers are built one by one (``layers_0`` .. in the parameter tree, no
-    # ``nn.scan``), each of its own kind, and the keys below apply; None
-    # keeps the one scanned layer every other model here has.
+    # One entry a layer: "sliding_attention" (the causal band of
+    # ``sliding_window``), "full_attention" (plain causal) or
+    # "linear_attention" (no keys and values: a gated delta rule over a
+    # recurrent state, ``GatedDeltaMixer``).  Given, the layers are built
+    # one by one (``layers_0`` .. in the parameter tree, no ``nn.scan``),
+    # each of its own kind, and the keys below apply; None keeps the one
+    # scanned layer every other model here has.
     layer_types: tuple[str, ...] | None = None
     # which layers rotate q and k when ``pos == "rope"``: "sliding" leaves
     # the full-attention layers without any positional rotation
     rope_layers: Literal["all", "sliding"] = "all"
     head_size: int | None = None  # None -> d_model // n_heads
     qk_norm: bool = False  # RMSNorm over head_dim on q and k, before rope
+    # ... or, "projection", over a token's whole q and k projection (all
+    # the heads at once, one gain a channel: OLMo 2/3)
+    qk_norm_over: Literal["head", "projection"] = "head"
     attn_gate: bool = False  # out = (attn * sigmoid(x Wg)) Wo
     sandwich_norm: bool = False  # a norm after attention and after the FFN
+    # False: no norm BEFORE a sublayer, so with ``sandwich_norm`` the two
+    # norms of a layer sit on each sublayer's output (OLMo 2/3)
+    pre_norm: bool = True
     embed_scale: bool = False  # h0 = E[tok] * sqrt(d_model)
     # the first ``n_dense_layers`` layers have a dense FFN of ``d_ff``, the
     # others the expert FFN below; None -> every layer is dense
@@ -103,17 +112,43 @@ class TransformerConfig:
     score_func: Literal["sigmoid", "softmax"] = "sigmoid"
     route_norm: bool = True  # weights of the chosen experts sum to 1 ...
     route_scale: float = 1.0  # ... times this
+    # the ``linear_attention`` layers (valid only with one): heads of keys
+    # and of values (equal here: no grouping), their sizes, the taps of the
+    # short causal convolution on q, k and v, and whether beta reaches 2
+    # (a state transition with eigenvalues down to -1)
+    linear_key_heads: int | None = None
+    linear_value_heads: int | None = None
+    linear_key_head_dim: int | None = None
+    linear_value_head_dim: int | None = None
+    linear_conv_kernel: int = 4
+    linear_neg_eigval: bool = False
 
     def __post_init__(self):
-        if self.layer_types is not None:
-            object.__setattr__(self, "layer_types", tuple(self.layer_types))
-            bad = set(self.layer_types) - {"sliding_attention",
-                                           "full_attention"}
-            if bad or len(self.layer_types) != self.n_layers:
+        kinds = self.layer_types
+        if kinds is not None:
+            object.__setattr__(self, "layer_types", tuple(kinds))
+            if set(kinds) - set(LAYER_KINDS) or len(kinds) != self.n_layers:
                 raise ValueError(
                     f"layer_types needs n_layers={self.n_layers} entries of "
-                    f"'sliding_attention' / 'full_attention', got "
-                    f"{self.layer_types}")
+                    f"{LAYER_KINDS}, got {self.layer_types}")
+        sizes = ("linear_key_heads", "linear_value_heads",
+                 "linear_key_head_dim", "linear_value_head_dim")
+        given = [k for k in sizes + ("linear_neg_eigval",) if getattr(self, k)]
+        if "linear_attention" not in (kinds or ()):
+            if given:
+                raise ValueError(f"{given} describe linear_attention "
+                                 f"layers: layer_types has none")
+        elif (not all(getattr(self, k) for k in sizes)
+              or self.linear_key_heads != self.linear_value_heads
+              or self.linear_conv_kernel < 2):
+            raise ValueError(
+                "a linear_attention layer needs linear_key_heads == "
+                "linear_value_heads, linear_key_head_dim, "
+                "linear_value_head_dim and linear_conv_kernel >= 2")
+        if kinds is not None:
+            if not self.pre_norm and not self.sandwich_norm:
+                raise ValueError("pre_norm=False leaves a layer without "
+                                 "norms: give sandwich_norm too")
             if ("sliding_attention" in self.layer_types
                     and self.sliding_window is None):
                 raise ValueError("a sliding_attention layer needs "
@@ -132,7 +167,7 @@ class TransformerConfig:
             given = [k for k in ("qk_norm", "attn_gate", "sandwich_norm",
                                  "embed_scale", "head_size",
                                  "n_dense_layers", "experts_published")
-                     if getattr(self, k)]
+                     if getattr(self, k)] + ["pre_norm"] * (not self.pre_norm)
             if given:
                 raise ValueError(
                     f"{given} describe a model built layer by layer: give "
@@ -189,6 +224,24 @@ class TransformerConfig:
             return (d + 255) // 256 * 256
         return 4 * self.d_model
 
+    def mixer_params(self, kind: str) -> int:
+        """Parameters of the mixer of a layer of ``kind`` (a model with
+        ``layer_types``): attention, or the gated delta rule's projections,
+        filters, decay and output norm."""
+        d, hd = self.d_model, self.head_dim
+        if kind == "linear_attention":
+            qk = self.linear_key_heads * self.linear_key_head_dim
+            vo = self.linear_value_heads * self.linear_value_head_dim
+            return (2 * d * qk + 2 * d * vo + vo * d  # q k, v gate, out
+                    + 2 * d * self.linear_value_heads  # beta and decay maps
+                    + 2 * self.linear_value_heads  # A_log, dt_bias
+                    + self.linear_conv_kernel * (2 * qk + vo)
+                    + self.linear_value_head_dim)  # the output norm's gain
+        q, kv = self.n_heads * hd, self.kv_heads * hd
+        normed = {"head": 2 * hd, "projection": q + kv}[self.qk_norm_over]
+        return (2 * d * q + 2 * d * kv + (d * q if self.attn_gate else 0)
+                + (normed if self.qk_norm else 0))
+
     def num_params(self) -> int:
         """Analytic parameter count (embedding included once if tied);
         for a model with ``layer_types``, of what is HELD here."""
@@ -197,15 +250,15 @@ class TransformerConfig:
         attn = d * (self.n_heads * hd) + 2 * d * (self.kv_heads * hd) + (
             self.n_heads * hd) * d
         if self.layer_types is not None:
-            attn += (d * self.n_heads * hd if self.attn_gate else 0) + (
-                2 * hd if self.qk_norm else 0)
-            norms = (4 if self.sandwich_norm else 2) * d
-            fe, E = self.expert_d_ff, self.experts_published
-            sparse = (d * E + E + 3 * d * fe * (
-                self.n_experts_held + self.shared_experts))
+            # a layer's mixer by kind, its norms, its FFN by position
             n_sparse = self.n_expert_layers
-            return ((L - n_sparse) * (attn + norms + 3 * d * f)
-                    + n_sparse * (attn + norms + sparse)
+            fe, E = self.expert_d_ff, self.experts_published
+            sparse = n_sparse and (d * E + E + 3 * d * fe * (
+                self.n_experts_held + self.shared_experts))
+            mixers = sum(self.mixer_params(kind) for kind in self.layer_types)
+            norms = (2 * self.sandwich_norm + 2 * self.pre_norm) * d
+            return (mixers + L * norms + (L - n_sparse) * 3 * d * f
+                    + n_sparse * sparse
                     + v * d * (1 if self.tie_embeddings else 2) + d)
         mlp = (3 if self.act == "swiglu" else 2) * d * f
         norms = (2 * d) * L + (d if self.final_norm else 0) + (
@@ -214,6 +267,9 @@ class TransformerConfig:
         emb += self.type_vocab_size * d
         pos = self.max_seq_len * d if self.pos == "learned" else 0
         return L * (attn + mlp) + norms + emb + pos
+
+
+LAYER_KINDS = ("sliding_attention", "full_attention", "linear_attention")
 
 
 def make_norm(cfg: TransformerConfig, name: str | None = None):
@@ -271,7 +327,10 @@ class SelfAttention(nn.Module):
         ``positions``."""
         cfg = self.cfg
         q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
-        if cfg.qk_norm:
+        if cfg.qk_norm and cfg.qk_norm_over == "projection":
+            q = self.q_norm(q.reshape(*q.shape[:-2], -1)).reshape(q.shape)
+            k = self.k_norm(k.reshape(*k.shape[:-2], -1)).reshape(k.shape)
+        elif cfg.qk_norm:
             q, k = self.q_norm(q), self.k_norm(k)
         if cfg.layer_rotates(self.kind):
             q = rope(q, positions, cfg.rope_theta)
@@ -294,6 +353,96 @@ class SelfAttention(nn.Module):
             mask=mask, impl=self.cfg.attention_impl,
         )
         return self.out_proj(out, x)
+
+
+class GatedDeltaMixer(nn.Module):
+    """The mixer of a ``linear_attention`` layer: a gated delta rule over a
+    recurrent state a head (``ops/gated_delta.py`` has the equations) in
+    the place of attention over keys and values.  setup()-style, like
+    ``SelfAttention``, so that the serving programs apply the same
+    projections piecewise (``method="qkv"`` / ``"out_proj"``) round their
+    own read and write of the cached state.
+
+    Parameters that stay float32 when the serving engine rounds the rest
+    (``decode.compute_dtype_params``): ``A_log``, ``dt_bias``, the filters
+    ``conv`` and the output norm's gain."""
+
+    cfg: TransformerConfig
+
+    def setup(self):
+        cfg = self.cfg
+        H, dk, dv = (cfg.linear_value_heads, cfg.linear_key_head_dim,
+                     cfg.linear_value_head_dim)
+        dense = lambda feats: nn.DenseGeneral(
+            feats, axis=-1, dtype=cfg.dtype, use_bias=False)
+        self.q_proj, self.k_proj = dense((H, dk)), dense((H, dk))
+        self.v_proj, self.gate_proj = dense((H, dv)), dense((H, dv))
+        self.a_proj, self.b_proj = dense(H), dense(H)
+        self.o_proj = nn.DenseGeneral(cfg.d_model, axis=(-2, -1),
+                                      dtype=cfg.dtype, use_bias=False)
+        # one filter of K taps a channel of q, k and v, side by side
+        self.conv = self.param("conv", nn.initializers.normal(0.02), (
+            cfg.linear_conv_kernel, H * (2 * dk + dv)), jnp.float32)
+
+        def a_log(key, shape):  # decay rates A uniform in (0, 16)
+            return jnp.log(jax.random.uniform(key, shape, jnp.float32,
+                                              1e-3, 16.0))
+
+        def dt_bias(key, shape):  # steps log-uniform in (0.001, 0.1)
+            dt = jnp.exp(jax.random.uniform(
+                key, shape, jnp.float32, np.log(1e-3), np.log(0.1)))
+            return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
+
+        self.A_log = self.param("A_log", a_log, (H,))
+        self.dt_bias = self.param("dt_bias", dt_bias, (H,))
+        self.o_norm = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32)
+
+    def qkv(self, x, tail=None):
+        """``x`` [B, T, d], the layer's input, and ``tail`` [B, K - 1, D],
+        the projections of the K - 1 tokens before it as ``full`` had them
+        (None: the sequence starts here).  Returns ``(q, k [B, T, H, d_k],
+        v [B, T, H, d_v], g, beta [B, T, H] float32, full [B, K - 1 + T,
+        D])``: q and k convolved, normalised a head, q scaled; ``g`` the
+        log-decay; ``full`` the tail and this call's projections, whose
+        last K - 1 rows are the next call's tail."""
+        cfg = self.cfg
+        H, dk = cfg.linear_value_heads, cfg.linear_key_head_dim
+        lead = x.shape[:2]
+        pre = jnp.concatenate(
+            [p(x).reshape(*lead, -1)
+             for p in (self.q_proj, self.k_proj, self.v_proj)], -1)
+        if tail is None:
+            tail = jnp.zeros((lead[0], cfg.linear_conv_kernel - 1,
+                              pre.shape[-1]), pre.dtype)
+        full = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
+        y = causal_conv(full, self.conv, lead[1])
+        q, k, v = jnp.split(y, [H * dk, 2 * H * dk], axis=-1)
+        q = l2norm(q.reshape(*lead, H, dk)) * dk ** -0.5
+        k = l2norm(k.reshape(*lead, H, dk))
+        v = v.reshape(*lead, H, -1)
+        beta = nn.sigmoid(self.b_proj(x).astype(jnp.float32))
+        if cfg.linear_neg_eigval:
+            beta = beta * 2.0
+        g = -jnp.exp(self.A_log) * nn.softplus(
+            self.a_proj(x).astype(jnp.float32) + self.dt_bias)
+        return (q.astype(cfg.dtype), k.astype(cfg.dtype),
+                v.astype(cfg.dtype), g, beta, full)
+
+    def out_proj(self, o, x):
+        """``o`` [B, T, H, d_v] float32, what the state gave; ``x`` the
+        layer's input, which the output gate reads."""
+        y = self.o_norm(o) * nn.silu(self.gate_proj(x).astype(jnp.float32))
+        return self.o_proj(y.astype(self.cfg.dtype))
+
+    def __call__(self, x):
+        q, k, v, g, beta, _ = self.qkv(x)
+        B, T, H, dk = q.shape
+        # a batch folds into the heads: [T, B * H, .]
+        fold = lambda a: jnp.moveaxis(a, 0, 1).reshape(T, B * H, *a.shape[3:])
+        o, _ = gated_delta_chunk(
+            fold(q), fold(k), fold(v), fold(g), fold(beta),
+            jnp.zeros((B * H, dk, v.shape[-1]), jnp.float32))
+        return self.out_proj(jnp.moveaxis(o.reshape(T, B, H, -1), 0, 1), x)
 
 
 class MLPBlock(nn.Module):
@@ -388,9 +537,11 @@ class SparseMLP(nn.Module):
 
 
 class KindDecoderLayer(nn.Module):
-    """One layer of a model whose layers differ (``cfg.layer_types``):
-    attention of this layer's ``kind``, a dense or an expert FFN, and with
-    ``cfg.sandwich_norm`` a norm after each sublayer as well as before."""
+    """One layer of a model whose layers differ (``cfg.layer_types``): the
+    mixer of this layer's ``kind`` (attention, or the gated delta rule of a
+    ``linear_attention`` layer), a dense or an expert FFN, and the norms
+    where ``cfg.pre_norm`` and ``cfg.sandwich_norm`` put them (before each
+    sublayer, after it, or both)."""
 
     cfg: TransformerConfig
     kind: str
@@ -399,12 +550,15 @@ class KindDecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x, positions, mask=None):
         cfg = self.cfg
-        h = make_norm(cfg, "attn_norm")(x)
-        h = SelfAttention(cfg, self.kind, name="attn")(h, positions, mask)
+        h = make_norm(cfg, "attn_norm")(x) if cfg.pre_norm else x
+        if self.kind == "linear_attention":
+            h = GatedDeltaMixer(cfg, name="attn")(h)
+        else:
+            h = SelfAttention(cfg, self.kind, name="attn")(h, positions, mask)
         if cfg.sandwich_norm:
             h = make_norm(cfg, "post_attn_norm")(h)
         x = x + h
-        h = make_norm(cfg, "mlp_norm")(x)
+        h = make_norm(cfg, "mlp_norm")(x) if cfg.pre_norm else x
         if self.sparse:
             h, _ = SparseMLP(cfg, name="mlp")(h)
         else:

@@ -371,7 +371,8 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
             # layer kinds, the experts held and the pool by kind of page
             **{k: (sengine or {}).get(k) for k in (
                 "layer_kinds", "experts_held", "experts_published",
-                "kv_bytes_full", "kv_bytes_window")},
+                "kv_bytes_full", "kv_bytes_window", "state_bytes_linear",
+                "conv_bytes_linear")},
             # the decode steps' expert counters (engines with experts)
             "mean_moe_pairs": _mean(e.get("moe_pairs") for e in ssteps),
             "mean_moe_experts_touched": _mean(
@@ -993,6 +994,13 @@ def format_report(report: dict) -> str:
                 + (f" ({kinds.count('full_attention')} full, "
                    f"{kinds.count('sliding_attention')} sliding layers)"
                    if kinds else "")]
+            if sv.get("state_bytes_linear"):
+                eparts.append(
+                    f"state pool {sv['state_bytes_linear'] / 2**30:.3f} GiB "
+                    f"of recurrent state + "
+                    f"{sv['conv_bytes_linear'] / 2**30:.3f} GiB of "
+                    f"convolution tails "
+                    f"({kinds.count('linear_attention')} linear layers)")
             if sv.get("experts_published"):
                 eparts.append(f"experts {sv['experts_held']} held of "
                               f"{sv['experts_published']}")

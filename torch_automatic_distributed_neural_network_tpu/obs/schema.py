@@ -331,10 +331,13 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # one scanned layer), the experts held of those published
             # (0 without expert layers), and the pool's bytes by kind of
             # page: pages for max_len a slot, and the sliding layers'
-            # rings (0 where every layer keeps max_len)
+            # rings (0 where every layer keeps max_len); beside them the
+            # linear_attention layers' recurrent states and convolution
+            # tails, a row a slot (0 without such layers)
             "layer_kinds": "list?", "experts_held": "int",
             "experts_published": "int", "kv_bytes_full": "int",
-            "kv_bytes_window": "int",
+            "kv_bytes_window": "int", "state_bytes_linear": "int",
+            "conv_bytes_linear": "int",
             # decode steps the engine dispatches with the step before
             # unread: 1, or 0 where the next step's operands need the
             # tokens' values (speculative drafts)

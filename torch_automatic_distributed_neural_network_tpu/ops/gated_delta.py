@@ -1,0 +1,373 @@
+"""The gated delta rule of a ``linear_attention`` layer: a recurrent state
+in place of keys and values.
+
+A head keeps a matrix ``S`` [d_k, d_v] in float32.  A token with query
+``q``, key ``k`` (both L2-normalised, d_k), value ``v`` (d_v), log-decay
+``g <= 0`` and write strength ``beta`` (in (0, 1), or (0, 2) where the
+model allows negative eigenvalues) does
+
+    S_t = a_t S_{t-1} + beta_t k_t (v_t - a_t S_{t-1}^T k_t)^T ,  a_t = exp(g_t)
+    o_t = S_t^T q_t
+
+(Gated DeltaNet: Yang, Kautz, Hatamizadeh 2024).  Three forms of the same
+arithmetic live here:
+
+- :func:`gated_delta_recurrent`, the equations token by token under
+  ``lax.scan``: what every other form is tested against;
+- the CHUNK form for a prompt (training's forward pass, the engine's
+  prefill chunk): sub-chunks of ``SUB_CHUNK`` tokens, inside one the
+  tokens' writes are solved together (``(I + A) U = beta (V - G K S_0)``,
+  ``A`` strictly lower triangular: the WY form of a product of
+  Householder-like factors) and the state is carried from one sub-chunk to
+  the next.  :func:`gated_delta_chunk` runs the Pallas kernel
+  ``tadnn_gdn_chunk`` (grid: heads x groups of sub-chunks) on a TPU and
+  the plain ``jax.numpy`` form
+  (:func:`gated_delta_chunk_xla`) elsewhere; state in, state out;
+- the STEP form for decode, one token a slot against a pool of states
+  ``[rows, H, d_k, d_v]`` read and written in place through a vector of
+  row ids (row 0 the null row of the slots that do not decode):
+  :func:`gated_delta_step`, the kernel ``tadnn_gdn_step`` on a TPU and
+  :func:`gated_delta_step_xla` elsewhere.
+
+The platform picks between a kernel and its plain form, as for the paged
+attention kernel; there is no switch.  State, decay, beta and every
+accumulator are float32; the matmuls' operands are in the dtype ``q`` comes
+in (bfloat16 when serving; float32 operands ask for float32 products too).
+
+Shapes: one sequence, ``q, k`` [T, H, d_k], ``v`` [T, H, d_v], ``g, beta``
+[T, H] float32, ``state`` [H, d_k, d_v] float32.  A batch folds into H.
+A row with ``beta == 0`` and ``g == 0`` leaves the state as it was (a
+padded chunk's tail, an inactive slot).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+SUB_CHUNK = 64  # tokens solved together inside a chunk
+CHUNK_GROUP = 8  # at most this many sub-chunks of a head a grid step
+STEP_HEADS = 10  # at most this many heads of a slot's state a grid step
+F32 = jnp.float32
+HI = jax.lax.Precision.HIGHEST
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _exact(dtype):
+    """float32 operands ask for float32 products (one bfloat16 pass is the
+    default on a TPU)."""
+    return HI if dtype == F32 else None
+
+
+def l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
+    """``x / ||x||_2`` over the last axis, in float32."""
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
+
+
+def causal_conv(full: jax.Array, w: jax.Array, n: int) -> jax.Array:
+    """A depthwise causal convolution of ``K`` taps, then SiLU: ``full``
+    [..., K - 1 + n, D] is the ``K - 1`` rows before the sequence (zeros at
+    its start, else the tail the cache kept) and its ``n`` rows; ``w``
+    [K, D], tap ``K - 1`` on the row itself.  Float32 sums; [..., n, D]."""
+    K = w.shape[0]
+    y = sum(w[i].astype(F32) * full[..., i:i + n, :].astype(F32)
+            for i in range(K))
+    return jax.nn.silu(y)
+
+
+# -- token by token: the oracle ------------------------------------------------
+
+
+def gated_delta_recurrent(q, k, v, g, beta, state):
+    """The equations as written, a token a scan step, in float32 at highest
+    precision.  Returns ``(o [T, H, d_v] float32, state)``."""
+    def step(S, x):
+        q, k, v, g, beta = x
+        a = jnp.exp(g)[:, None, None]
+        Sk = jnp.einsum("hkv,hk->hv", S, k, precision=HI)
+        u = beta[:, None] * (v - a[:, 0] * Sk)
+        S = a * S + k[:, :, None] * u[:, None, :]
+        return S, jnp.einsum("hkv,hk->hv", S, q, precision=HI)
+
+    state, o = jax.lax.scan(step, state.astype(F32), tuple(
+        x.astype(F32) for x in (q, k, v, g, beta)))
+    return o, state
+
+
+# -- the chunk form ---------------------------------------------------------------
+
+
+def _sub_chunk(T: int) -> int:
+    return SUB_CHUNK if T >= SUB_CHUNK else -(-T // 8) * 8
+
+
+def _chunk_operands(q, k, v, g, beta):
+    """What a sub-chunk's solve multiplies, every decay folded in here (no
+    exponential of a positive number anywhere: each is of a difference
+    ``gamma_t - gamma_j`` with ``j <= t``).  ``[H, NS, sub, .]`` each, the
+    transposed keys ``[H, NS, d_k, sub]``; the sequence padded to whole
+    sub-chunks with rows that leave the state alone."""
+    T = q.shape[0]
+    sub = _sub_chunk(T)
+    pad = -T % sub
+    op = q.dtype
+
+    def split(x):  # [T, H, ...] -> [H, NS, sub, ...]
+        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        x = x.reshape(-1, sub, *x.shape[1:])
+        return jnp.moveaxis(x, 2, 0)
+
+    q, k, v = split(q).astype(F32), split(k).astype(F32), split(v).astype(F32)
+    g, beta = split(g.astype(F32)), split(beta.astype(F32))  # [H, NS, sub]
+    gam = jnp.cumsum(g, -1)
+    diff = gam[..., :, None] - gam[..., None, :]
+    lower = jnp.tril(jnp.ones((sub, sub), bool))
+    decay = jnp.exp(jnp.where(lower, diff, -jnp.inf))  # [H, NS, sub, sub]
+    G = jnp.exp(gam)[..., None]
+    to_end = jnp.exp(gam[..., -1:] - gam)[..., None]
+    kb = beta[..., None] * k
+    return dict(
+        q=q.astype(op), qg=(G * q).astype(op), kb=kb.astype(op),
+        kbg=(G * kb).astype(op), kT=jnp.swapaxes(k, -1, -2).astype(op),
+        kdT=jnp.swapaxes(to_end * k, -1, -2).astype(op),
+        bv=(beta[..., None] * v).astype(op), decay=decay,
+        gc=jnp.exp(gam[..., -1])), T
+
+
+def _unit_lower_inverse(A, dot):
+    """``(I + A)^-1`` for strictly lower triangular ``A`` [.., n, n], by
+    halves: with ``X`` the inverse of the diagonal blocks of size ``s``
+    (zero elsewhere; the identity at ``s = 1``) and ``R`` the part of ``A``
+    in the lower-left quarter of each diagonal block of size ``2 s``, ``X -
+    X R X`` is the inverse of the diagonal blocks of size ``2 s``.  Every
+    factor is the inverse of a piece of the true system, whose entries the
+    recurrence bounds: no power of ``A`` is ever formed (``A^32`` cancels
+    catastrophically in float32 once neighbouring keys are alike).
+    ``ceil(log2 n)`` rounds of two products; shifts and masks only, so that
+    the kernel runs the same lines."""
+    n = A.shape[-1]
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    X = (r == c).astype(F32)
+    for bit in range(max(0, math.ceil(math.log2(n)))):
+        s = 1 << bit
+        quarter = ((r >> (bit + 1)) == (c >> (bit + 1))) & (
+            (r & s) != 0) & ((c & s) == 0)
+        X = X - dot(dot(X, jnp.where(quarter, A, 0.0)), X)
+    return X
+
+
+def gated_delta_chunk_xla(q, k, v, g, beta, state):
+    """The chunk form in plain ``jax.numpy``: the CPU path, and the oracle
+    of the kernel's parity tests.  Returns ``(o [T, H, d_v] float32,
+    state)``."""
+    ops, T = _chunk_operands(q, k, v, g, beta)
+    op, exact = q.dtype, _exact(q.dtype)
+    mm = functools.partial(jnp.matmul, precision=exact,
+                           preferred_element_type=F32)
+    sub = ops["decay"].shape[-1]
+    lower = jnp.tril(jnp.ones((sub, sub), bool))
+    strict = jnp.tril(lower, -1)
+    A = jnp.where(strict, ops["decay"] * mm(ops["kb"], ops["kT"]), 0.0)
+    Tm = _unit_lower_inverse(
+        A, functools.partial(jnp.matmul, precision=HI)).astype(op)
+    Wv, Wk = mm(Tm, ops["bv"]), mm(Tm, ops["kbg"]).astype(op)
+    P = jnp.where(lower, ops["decay"] * mm(ops["q"], ops["kT"]),
+                  0.0).astype(op)
+
+    def body(S, x):  # one sub-chunk of every head
+        Wv, Wk, P, qg, kdT, gc = x
+        U = Wv - mm(Wk, S.astype(op))
+        o = mm(qg, S.astype(op)) + mm(P, U.astype(op))
+        return gc[:, None, None] * S + mm(kdT, U.astype(op)), o
+
+    per = lambda x: jnp.moveaxis(x, 1, 0)  # sub-chunks lead
+    state, o = jax.lax.scan(body, state.astype(F32), (
+        per(Wv), per(Wk), per(P), per(ops["qg"]), per(ops["kdT"]),
+        per(ops["gc"])))
+    # [NS, H, sub, d_v] -> [T, H, d_v]
+    o = jnp.moveaxis(o, 1, 2).reshape(-1, *o.shape[1:2], o.shape[-1])
+    return o[:T], state
+
+
+def _chunk_kernel(q_ref, qg_ref, kb_ref, kbg_ref, kT_ref, kdT_ref, bv_ref,
+                  d_ref, gc_ref, s0_ref, o_ref, s_ref, s_scr, *, exact):
+    """One (head, group of sub-chunks) grid step; the head's state rides in
+    VMEM scratch from its first group to its last.  What a sub-chunk does
+    without the state (its key-key matrix, its solve, the products with the
+    solved factor) is written out for every sub-chunk of the group before
+    the state passes through them, so that those chains, which do not
+    depend on each other, can be scheduled side by side."""
+    n = pl.program_id(1)
+
+    @pl.when(n == 0)
+    def _load():
+        s_scr[:] = s0_ref[0]
+
+    op = q_ref.dtype
+    dot = functools.partial(jnp.dot, precision=exact,
+                            preferred_element_type=F32)
+    sub = d_ref.shape[-1]
+    r = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+    solved = []
+    for i in range(d_ref.shape[1]):  # static: the group's sub-chunks
+        kT, decay = kT_ref[0, i], d_ref[0, i]
+        A = jnp.where(r > c, decay * dot(kb_ref[0, i], kT), 0.0)
+        Tm = _unit_lower_inverse(A, functools.partial(
+            jnp.dot, precision=HI, preferred_element_type=F32)).astype(op)
+        solved.append((
+            dot(Tm, bv_ref[0, i]), dot(Tm, kbg_ref[0, i]).astype(op),
+            jnp.where(r >= c, decay * dot(q_ref[0, i], kT), 0.0).astype(op)))
+    S = s_scr[:]
+    for i, (Wv, Wk, Pqk) in enumerate(solved):
+        Sop = S.astype(op)
+        U = (Wv - dot(Wk, Sop)).astype(op)
+        o_ref[0, i] = dot(qg_ref[0, i], Sop) + dot(Pqk, U)
+        S = gc_ref[0, i] * S + dot(kdT_ref[0, i], U)
+    s_scr[:] = S
+
+    @pl.when(n == pl.num_programs(1) - 1)
+    def _store():
+        s_ref[0] = S
+
+
+def gated_delta_chunk_pallas(q, k, v, g, beta, state, *,
+                             interpret: bool = False):
+    """The chunk form as the kernel ``tadnn_gdn_chunk``: grid (heads,
+    groups of up to ``CHUNK_GROUP`` sub-chunks), the groups of a head in
+    order."""
+    ops, T = _chunk_operands(q, k, v, g, beta)
+    H, NS, sub, dk = ops["q"].shape
+    dv = ops["bv"].shape[-1]
+    gc = jnp.broadcast_to(ops["gc"][..., None, None], (H, NS, 1, dv))
+    grp = max(n for n in range(1, CHUNK_GROUP + 1) if NS % n == 0)
+
+    def blk(*tail):
+        return pl.BlockSpec((1, grp, *tail), lambda h, n: (h, n, 0, 0))
+
+    whole = pl.BlockSpec((1, dk, dv), lambda h, n: (h, 0, 0))
+    o, state = pl.pallas_call(
+        functools.partial(_chunk_kernel, exact=_exact(q.dtype)),
+        grid=(H, NS // grp),
+        in_specs=[blk(sub, dk)] * 4 + [blk(dk, sub)] * 2 + [
+            blk(sub, dv), blk(sub, sub), blk(1, dv), whole],
+        out_specs=[blk(sub, dv), whole],
+        out_shape=[jax.ShapeDtypeStruct((H, NS, sub, dv), F32),
+                   jax.ShapeDtypeStruct((H, dk, dv), F32)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="tadnn_gdn_chunk",
+    )(ops["q"], ops["qg"], ops["kb"], ops["kbg"], ops["kT"], ops["kdT"],
+      ops["bv"], ops["decay"], gc, state.astype(F32))
+    return jnp.moveaxis(o, 0, 2).reshape(NS * sub, H, dv)[:T], state
+
+
+def gated_delta_chunk(q, k, v, g, beta, state):
+    """A sequence (or a prefill chunk of one) through the gated delta rule
+    from ``state``: ``(o [T, H, d_v] float32, the state after it)``."""
+    if _on_tpu():
+        return gated_delta_chunk_pallas(q, k, v, g, beta, state)
+    return gated_delta_chunk_xla(q, k, v, g, beta, state)
+
+
+# -- the step form ---------------------------------------------------------------
+
+
+def gated_delta_step_xla(q, k, v, g, beta, pool, rows):
+    """One token a slot in plain ``jax.numpy``: ``q, k`` [S, H, d_k], ``v``
+    [S, H, d_v], ``g, beta`` [S, H]; slot ``s`` reads and writes row
+    ``rows[s]`` of ``pool`` [R, H, d_k, d_v].  Returns ``(o [S, H, d_v]
+    float32, pool)``."""
+    q, k, v, g, beta = (x.astype(F32) for x in (q, k, v, g, beta))
+    S = pool[rows]
+    a = jnp.exp(g)[..., None]
+    Sk = jnp.einsum("shkv,shk->shv", S, k, precision=HI)
+    u = beta[..., None] * (v - a * Sk)
+    S = a[..., None] * S + k[..., None] * u[..., None, :]
+    o = jnp.einsum("shkv,shk->shv", S, q, precision=HI)
+    return o, pool.at[rows].set(S)
+
+
+def _step_kernel(rows_ref, kT_ref, qT_ref, row_ref, s_ref, o_ref, out_ref,
+                 *, heads: int):
+    """A group of ``heads`` heads of one slot, on the VPU: a head's key and
+    query as columns [d_k, 1], its value, decay, beta and k.q as rows
+    [1, d_v] (rows ``c * heads + i`` of the packed operand)."""
+    del rows_ref
+    for i in range(heads):  # static
+        S = s_ref[0, i]
+        kc, qc = kT_ref[0, 0][:, i:i + 1], qT_ref[0, 0][:, i:i + 1]
+        v, a, b, kq = (row_ref[0, 0, c * heads + i:c * heads + i + 1]
+                       for c in range(4))
+        Sk = jnp.sum(S * kc, axis=0, keepdims=True)
+        Sq = jnp.sum(S * qc, axis=0, keepdims=True)
+        u = b * (v - a * Sk)
+        out_ref[0, i] = a * S + kc * u
+        o_ref[0, 0, i:i + 1] = a * Sq + kq * u
+
+
+def _head_group(H: int) -> int:
+    return max(n for n in range(1, STEP_HEADS + 1) if H % n == 0)
+
+
+def gated_delta_step_pallas(q, k, v, g, beta, pool, rows, *,
+                            interpret: bool = False):
+    """The step form as the kernel ``tadnn_gdn_step``: grid (slots, groups
+    of heads); a slot's rows of ``pool`` are read and written where they
+    lie (the pool is aliased to the output, the row ids are a scalar
+    prefetch)."""
+    S, H, dk = k.shape
+    dv = v.shape[-1]
+    hb = _head_group(H)
+    G = H // hb
+    q, k, v, g, beta = (x.astype(F32) for x in (q, k, v, g, beta))
+
+    def cols(x):  # [S, H, dk] -> [S, G, dk, hb]
+        return jnp.swapaxes(x.reshape(S, G, hb, dk), -1, -2)
+
+    wide = lambda x: jnp.broadcast_to(x[..., None], (S, H, dv))
+    packed = jnp.stack([v, wide(jnp.exp(g)), wide(beta),
+                        wide(jnp.sum(k * q, -1))], axis=2)  # [S, H, 4, dv]
+    packed = jnp.swapaxes(packed.reshape(S, G, hb, 4, dv), 2, 3).reshape(
+        S, G, 4 * hb, dv)
+    col = pl.BlockSpec((1, 1, dk, hb), lambda s, j, r: (s, j, 0, 0))
+    row4 = pl.BlockSpec((1, 1, 4 * hb, dv), lambda s, j, r: (s, j, 0, 0))
+    st = pl.BlockSpec((1, hb, dk, dv), lambda s, j, r: (r[s], j, 0, 0))
+    o, pool = pl.pallas_call(
+        functools.partial(_step_kernel, heads=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S, G),
+            in_specs=[col, col, row4, st],
+            out_specs=[pl.BlockSpec((1, 1, hb, dv),
+                                    lambda s, j, r: (s, j, 0, 0)), st]),
+        out_shape=[jax.ShapeDtypeStruct((S, G, hb, dv), F32),
+                   jax.ShapeDtypeStruct(pool.shape, F32)],
+        input_output_aliases={4: 1},  # the pool, after the row ids
+        interpret=interpret,
+        name="tadnn_gdn_step",
+    )(rows.astype(jnp.int32), cols(k), cols(q), packed, pool)
+    return o.reshape(S, H, dv), pool
+
+
+def gated_delta_step(q, k, v, g, beta, pool, rows):
+    """One decode token a slot against the pool of states, in place:
+    ``(o [S, H, d_v] float32, pool)``.  Row 0 is the null row of the
+    slots that do not decode: with ``beta = 0`` and ``g = 0`` they leave it
+    as it was.  (On a v5e the compiler stages a pool of this size through
+    on-chip memory round the call, in copies of its own: the kernel's time
+    in a trace does not hold its HBM traffic.)"""
+    if _on_tpu():
+        return gated_delta_step_pallas(q, k, v, g, beta, pool, rows)
+    return gated_delta_step_xla(q, k, v, g, beta, pool, rows)
