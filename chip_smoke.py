@@ -21,7 +21,13 @@ from ``--seed``):
    heads on 30 KV heads of 128 with every slot full, and the gated delta
    rule's chunk and step kernels (30 heads, keys of 96, values of 192)
    against the token-by-token recurrence, compiled and not interpreted.
-4. **cache** — where the persistent compile cache is, who placed it, and
+4. **fused** — the serving program in which a step's decode rows ride in
+   a prefill chunk (``programs.chunk_and_step``), at the widths of the three
+   serving configurations and a few layers of each (16 heads of 128; 48
+   query heads on 8 KV heads with a ring and experts; 30 heads of 128 with
+   state rows), against the chunk and the step as two calls on the same
+   pool: the chunk's logits, the step's tokens and every pool array.
+5. **cache** — where the persistent compile cache is, who placed it, and
    how many entries it held before and after.
 
 ``--chips 4`` runs, and runs only, what exists only across chips: the
@@ -438,6 +444,170 @@ def hybrid_kernels_phase(*, seed: int, on_chip: bool) -> dict:
     return rec
 
 
+# the three serving configurations at their published widths and a few of
+# their layers (what is cut is depth, vocabulary, the number of experts and
+# the window: no width), for ``fused_phase``
+FUSED_CUTS = {
+    "gpt2-1p3b": dict(n_layers=2, vocab_size=8192),
+    "trinity-large-ep8": dict(
+        n_layers=3, n_dense_layers=1, sliding_window=256, experts_held=8,
+        experts_published=64, layer_types=[
+            "sliding_attention", "sliding_attention", "full_attention"]),
+    "olmo-hybrid-7b-pp2": dict(
+        n_layers=3, vocab_size=8192, layer_types=[
+            "linear_attention", "linear_attention", "full_attention"]),
+}
+# bf16 layers: rows of one product taken C + S at a time against C and S
+FUSED_RTOL = 0.03
+
+
+def fused_phase(name: str, *, seed: int, on_chip: bool) -> dict:
+    """One chunk of one slot's prompt and one token of three other slots at
+    different depths: as ``prefill_chunk`` then ``decode_logits``, and as
+    ONE ``chunk_and_step``, from the same pool.  Passes when the chunk's
+    logits and every pool array agree within ``FUSED_RTOL`` of their
+    largest value and each token the fused call serves lies within it of
+    the step's best logit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torch_automatic_distributed_neural_network_tpu.inference import decode
+    from torch_automatic_distributed_neural_network_tpu.inference.serve import (
+        programs,
+    )
+    from torch_automatic_distributed_neural_network_tpu.inference.serve.kv_pool import (
+        PagedKVPool,
+    )
+    from torch_automatic_distributed_neural_network_tpu.models.transformer_core import (
+        DecoderLM,
+        TransformerConfig,
+    )
+
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmark", "configs", name + ".json")) as f:
+        config = json.load(f)
+    cuts = FUSED_CUTS[name]
+    keys = {**config["model"], **cuts}
+    S, C, bs, max_len = 4, 128, 16, 1024
+    if not on_chip:  # the configuration's rehearsal sizes at the cut's depth
+        keys = {**keys, **config["rehearsal"]["model"],
+                "n_layers": cuts["n_layers"],
+                "layer_types": cuts.get("layer_types")}
+        C, max_len = 16, 128
+    keys["max_seq_len"] = max(max_len, keys["max_seq_len"])
+    cfg = TransformerConfig(
+        **keys, dtype=jnp.bfloat16 if on_chip else jnp.float32, remat=False)
+    rtol = FUSED_RTOL if on_chip else KERNEL_RTOL
+    MB = max_len // bs
+    model = DecoderLM(cfg)
+    params = jax.jit(lambda k: decode.per_layer_params(
+        decode.compute_dtype_params(
+            model.init(k, jnp.ones((1, 8), jnp.int32))["params"], cfg),
+        cfg))(jax.random.key(seed))
+    pool = PagedKVPool(cfg, num_blocks=S * MB + 1, block_size=bs,
+                       dtype=cfg.dtype, n_slots=S, max_blocks=MB,
+                       prefill_chunk=C)
+    win, kv = pool.win_tables, pool.kv
+    rows = 1 + np.arange(S * MB).reshape(S, MB)  # a slot's pages
+    sample = decode.SampleConfig(temperature=0.0)
+    chunk = jax.jit(lambda *a: programs.prefill_chunk(
+        *a, cfg=cfg, max_blocks=MB))
+    step = jax.jit(lambda *a: programs.decode_logits(*a, cfg=cfg))
+    fused = jax.jit(lambda *a: programs.chunk_and_step(
+        *a, cfg=cfg, sample=sample, max_blocks=MB, chunk=C))
+    rs = np.random.RandomState(seed)
+    # three slots hold prompts of 1, 2 and 3 chunks (the last padded); the
+    # fourth is one chunk into a prompt that ends inside its second
+    lens = [C - 5, 2 * C - 9, 3 * C - 1, 2 * C - 3]
+    prompts = [rs.randint(1, cfg.vocab_size, size=n) for n in lens]
+    first = np.zeros((S,), np.int32)
+
+    def operands(slot, pos):
+        part = prompts[slot][pos:pos + C]
+        return programs.pack_chunk(
+            rows[slot], [*part, *[0] * (C - len(part))], pos, len(part) - 1,
+            slot)
+
+    for slot in range(S):
+        for pos in range(0, lens[slot] - (C if slot == S - 1 else 0), C):
+            kv, lg = chunk(params, kv, operands(slot, pos), win[slot])
+        first[slot] = int(jnp.argmax(lg[0]))
+    of_chunk = operands(S - 1, C)
+    tables = rows * (np.arange(S) < S - 1)[:, None]  # the chunk's slot: null
+    ctx = np.asarray(lens[:-1] + [0], np.int32)
+    src = np.asarray([programs.TOKEN_HOST, programs.TOKEN_FIRST,
+                      programs.TOKEN_PREV, 0], np.int32)
+    prev = np.zeros((2 * S + programs.N_COUNTERS,), np.int32)
+    prev[1], prev[S + 2] = first[1], first[2]
+    tok = np.where(src == programs.TOKEN_HOST, first, 0).astype(np.int32)
+    of_step = programs.pack_step(tables, ctx, tok[:, None], src,
+                                 np.zeros((S,), np.int32))
+    # two calls
+    kv_a, lg_chunk_a = chunk(params, kv, of_chunk, win[S - 1])
+    kv_a, lg_step, _ = step(params, kv_a, jnp.asarray(tables, jnp.int32),
+                            win, jnp.asarray(ctx), jnp.asarray(
+                                first * (src > 0))[:, None], jnp.asarray(
+                                    src > 0))
+    # one
+    kv_b, out, lg_chunk_b = fused(
+        params, kv, programs.pack_chunk_and_step(of_chunk, of_step),
+        jnp.asarray(prev), win[S - 1], win, jax.random.key(0))
+    rec = {"phase": "fused." + name, "rows": C + S, "layers": cfg.n_layers,
+           "rtol": rtol}
+
+    def close(what, got, want, flips=False):
+        """``flips``: a layer past the first expert FFN, where bf16 rows
+        taken C + S at a time now and then round to another 4th expert
+        than the same rows taken alone (3-4% of positions against float32,
+        PERF.md): those positions' keys and values differ, the others
+        must not."""
+        diff = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
+        err = float(jnp.max(diff))
+        scale = float(jnp.max(jnp.abs(want.astype(jnp.float32))))
+        off = float(jnp.mean(diff > rtol * scale))
+        rec.setdefault(what + "_max_abs_err", 0.0)
+        rec[what + "_max_abs_err"] = max(rec[what + "_max_abs_err"], err)
+        if flips:
+            rec[what + "_share_off"] = max(rec.get(what + "_share_off", 0.0),
+                                          off)
+        if not (scale > 0 and math.isfinite(err)
+                and (off <= 0.02 if flips else err <= rtol * scale)):
+            raise RuntimeError(f"fused {name}: {what}: max abs err {err:.3e} "
+                               f"against max |ref| {scale:.3e} exceeds rtol "
+                               f"{rtol} ({off:.2%} of the elements)")
+
+    close("chunk_logits", lg_chunk_b, lg_chunk_a)
+    plan = programs.layer_plan(cfg)
+    first_sparse = next((i for i, (_, _, sparse) in enumerate(plan)
+                         if sparse), len(plan))
+    for side in ("k", "v"):
+        for i, (a, b) in enumerate(zip(kv_a[side], kv_b[side])):
+            for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+                close("pool" if i <= first_sparse else "pool_past_experts",
+                      y, x, flips=on_chip and i > first_sparse)
+    lg = np.asarray(lg_step[:, 0], np.float32)
+    tokens = np.asarray(out[S:2 * S])
+    regret = lg.max(-1) - lg[np.arange(S), tokens]
+    rec["token_regret_max"] = float(regret[:S - 1].max())
+    rec["tokens_equal"] = int((tokens == lg.argmax(-1))[:S - 1].sum())
+    if not (regret[:S - 1] <= (0.2 if on_chip and cfg.n_expert_layers
+                               else rtol) * np.abs(lg).max()).all() \
+            or tokens[-1]:
+        raise RuntimeError(f"fused {name}: tokens {tokens.tolist()} lie "
+                           f"{regret.tolist()} under the step's best logits")
+    if on_chip:
+        text = fused.lower(
+            params, kv, programs.pack_chunk_and_step(of_chunk, of_step),
+            jnp.asarray(prev), win[S - 1], win,
+            jax.random.key(0)).compile().as_text()
+        rec["custom_calls"] = text.count("tpu_custom_call")
+        if "tadnn_paged_decode_folded" not in text:
+            raise RuntimeError(f"fused {name}: the paged kernel is not in "
+                               "the compiled program")
+    return rec
+
+
 def count_entries(path: str | None) -> int | None:
     if path is None:
         return None
@@ -451,6 +621,9 @@ def run_one_chip(sz: Sizes, seed: int, on_chip: bool) -> None:
                      strategy="auto", seed=seed, on_chip=on_chip))
     serve_phase(sz, seed=seed, on_chip=on_chip)
     emit(hybrid_kernels_phase(seed=seed, on_chip=on_chip))
+    for name in FUSED_CUTS:
+        emit(fused_phase(name, seed=seed, on_chip=on_chip))
+        gc.collect()
 
 
 def run_four_chips(sz: Sizes, seed: int, on_chip: bool) -> None:

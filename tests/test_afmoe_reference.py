@@ -349,11 +349,14 @@ def test_engine_serves_recycled_pages_and_counts_experts(tmp_path):
     assert "experts 4 held of 16" in text
     assert "pairs here on" in text and "tokens on one" in text
     # the counters come back with a step's tokens, one call after its
-    # dispatch: every step dispatched is read, by a call that decoded
+    # dispatch: every step that decoded ALONE is read, by a call that
+    # decoded (a step whose rows rode in a chunk routed the chunk's rows
+    # with them: its counters are not a decode step's, and are left out)
     steps = [s for s in journal.named("serve.step") if "moe_pairs" in s]
-    assert len(steps) == sum(
-        "decode_dispatch" in s["phases"]
-        for s in journal.named("serve.step")) > 0
+    alone = [s for s in journal.named("serve.step")
+             if "decode_dispatch" in s["phases"] and not s["fused"]]
+    assert len(steps) == len(alone) > 0
+    assert 0 < sum(s["fused"] for s in journal.named("serve.step"))
     assert all(s["decode_s"] for s in steps)
     assert all(0 <= s["moe_experts_touched"] <= s["moe_pairs"]
                <= 4 * 2 * eng.n_slots for s in steps)
@@ -430,7 +433,7 @@ def test_engine_options_serve_a_model_with_layer_kinds(option, tmp_path):
         assert all(set(leaf) == {"q", "scale"} for leaf in eng.pool.kv["k"])
     if option == "export_cache":
         assert sorted(i["kind"] for i in eng.export_info) == [
-            "serve_decode", "serve_prefill"]
+            "serve_decode", "serve_fused"]
 
 
 @pytest.mark.parametrize("quantize", [False, True])

@@ -354,9 +354,15 @@ def test_serve_step_carries_a_field_the_benchmark_indexes(dense, experts,
 
 @pytest.mark.parametrize("field", sorted(STEP_FIELDS_EXPERTS))
 def test_serve_step_of_an_expert_model_carries_its_counters(experts, field):
-    # they come back with a decode step's tokens, so not on every step
-    got = [s.get(field) for s in _steps(experts)]
-    assert sum(v is not None for v in got) >= len(got) // 2, (
+    # they come back with the tokens of a step that decoded ALONE, so not
+    # on every step: a step whose rows rode in a prefill chunk routed the
+    # chunk's rows with them, and its counters are left out (the readers
+    # set them against the kernels inside ``jit_serve_decode_step``)
+    steps = _steps(experts)
+    got = [s.get(field) for s in steps]
+    alone = sum("decode_dispatch" in s["phases"] and not s["fused"]
+                for s in steps)
+    assert sum(v is not None for v in got) == alone >= 2, (
         f"serve.step has no {field!r}; read by benchmark/ "
         + STEP_FIELDS_EXPERTS[field])
     assert all(isinstance(v, int) and v >= 0 for v in got if v is not None)

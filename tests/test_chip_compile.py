@@ -187,12 +187,14 @@ _SERVING = {"gpt2-1p3b": (8, 1024, 16, 128, None),
             "olmo-hybrid-7b-pp2": (8, 33792, 16, 512, 4609)}
 
 
-@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk",
+                                     "chunk_and_step"])
 @pytest.mark.parametrize("config", sorted(_SERVING))
 def test_serving_programs_update_the_pool_in_place(
         v5e, monkeypatch, config, program):
-    """``jit_serve_decode_step`` and ``jit_serve_prefill_chunk``
-    (``inference/serve/programs.py``: ONE pair for both models) at the
+    """``jit_serve_decode_step`` and ``jit_serve_prefill_chunk``, and the
+    chunk that carries a step's decode rows (``chunk_and_step``)
+    (``inference/serve/programs.py``: the same three for every model) at the
     geometry of the benchmark's serving cells, compiled for one described
     v5e with the operands ``ServeEngine`` hands them: the layers' weights
     already in bf16 and a subtree a layer (``decode.compute_dtype_params``,
@@ -266,6 +268,15 @@ def test_serving_programs_update_the_pool_in_place(
             return programs.decode_step(
                 params, *a, cfg=cfg,
                 sample=decode.SampleConfig(temperature=0.0))
+    elif program == "chunk_and_step":
+        operands = (params, kv, i32(MB + chunk + 3 + slots * (MB + 4)),
+                    i32(2 * slots + programs.N_COUNTERS), i32(win.shape[1]),
+                    win, jax.eval_shape(lambda: jax.random.key(0)))
+
+        def step(params, *a):
+            return programs.chunk_and_step(
+                params, *a, cfg=cfg, max_blocks=MB, chunk=chunk,
+                sample=decode.SampleConfig(temperature=0.0))
     else:
         operands = (params, kv, i32(MB + chunk + 3), i32(win.shape[1]))
 
@@ -277,7 +288,8 @@ def test_serving_programs_update_the_pool_in_place(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
         operands)).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert ("tadnn_paged_decode_folded" in text) == (program == "decode_step")
+    assert ("tadnn_paged_decode_folded" in text) == (
+        program != "prefill_chunk")
     assert "tadnn_paged_decode." not in text  # one kernel for both models
     # a weight is an entry parameter named for its path in ``params``, read
     # as it is: not converted, not copied
@@ -302,7 +314,9 @@ def test_serving_programs_update_the_pool_in_place(
         mine, other = (("tadnn_gdn_step", "tadnn_gdn_chunk")
                        if program == "decode_step"
                        else ("tadnn_gdn_chunk", "tadnn_gdn_step"))
-        assert text.count(mine) >= 12 and other not in text
+        assert text.count(mine) >= 12
+        # both kernels where a chunk carries the decode rows
+        assert (text.count(other) >= 12) == (program == "chunk_and_step")
         assert "tadnn_moe_grouped_mm" not in text
         assert round(made["pool"].bytes_full / 1e9, 2) == 4.53
         assert round(sum(made["pool"].bytes_state) / 1e9, 2) == 0.25

@@ -82,6 +82,19 @@ def test_rehearsal_serve_phases(rehearsal):
                 <= paged["kernel_rtol"] * paged["reference_max_abs"])
 
 
+@pytest.mark.parametrize("name", sorted(chip_smoke.FUSED_CUTS))
+def test_rehearsal_fused_phases(rehearsal, name):
+    """A chunk that carries a step's decode rows against the two calls, a
+    few layers of each serving configuration: the chunk's logits, every
+    pool array and the step's tokens."""
+    _, lines = rehearsal
+    (rec,) = [r for r in lines if r.get("phase") == "fused." + name]
+    assert rec["rows"] == 16 + 4 and rec["tokens_equal"] == 3
+    assert rec["token_regret_max"] == 0.0
+    assert max(rec["chunk_logits_max_abs_err"],
+               rec["pool_max_abs_err"]) <= 1e-5
+
+
 def test_rehearsal_cache_line(rehearsal):
     _, lines = rehearsal
     (cache,) = [r for r in lines if r.get("phase") == "cache"]

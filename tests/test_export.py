@@ -255,8 +255,9 @@ def test_serve_cold_warm_token_parity(tmp_path):
     cold_toks, cold_rec, cold_eng = serve_tokens(cache, model, variables)
     assert sorted(names(cold_rec)) == ["export.miss", "export.miss",
                                        "export.store", "export.store"]
+    # the chunk program of an engine whose chunks carry the decode rows
     assert {i["kind"] for i in cold_eng.export_info} == \
-        {"serve_decode", "serve_prefill"}
+        {"serve_decode", "serve_fused"}
 
     warm_toks, warm_rec, warm_eng = serve_tokens(cache, model, variables)
     assert names(warm_rec) == ["export.hit", "export.hit"]
@@ -335,7 +336,7 @@ def test_cli_export_serve_and_report_render(tmp_path, capsys):
     out = [json.loads(ln) for ln in
            capsys.readouterr().out.strip().splitlines()]
     assert {r["kind"] for r in out} == {"train_step", "serve_decode",
-                                        "serve_prefill"}
+                                        "serve_fused"}
     rep = report.generate(jpath)
     assert rep["export"]["stores"] == 3
     text = report.format_report(rep)

@@ -128,12 +128,14 @@ def _prompt(n):
 
 @pytest.fixture(scope="module")
 def served():
-    """One engine under a validating journal: prompts of 5 and 12 tokens,
-    then (alone, so that each has steps of its own) 12 again and a first
-    20.  Returns (engine, journal, records by step)."""
+    """One engine under a validating journal: prompts of 12 and 5 tokens
+    (the first's two chunks run alone, the second's with the first's decode
+    rows: one program either way), then (alone, so that each has steps of
+    its own) 12 again and a first 20.  Returns (engine, journal, records
+    by step)."""
     j = Journal(None, validate=True, host0_only=False)
     eng = _engine(j)
-    for n in (5, 12):
+    for n in (12, 5):
         eng.submit(_prompt(n), max_new_tokens=4)
     eng.run()
     marks = {}
@@ -259,6 +261,10 @@ def test_serving_programs_are_named(served):
     assert eng.compiled_decode_text().startswith(
         "HloModule jit_serve_decode_step")
     text = eng._prefill_fn.lower(*eng._abstract_prefill_args()).as_text()
+    assert "module @jit_serve_prefill_chunk" in text.splitlines()[0]
+    # the chunk that carries a step's decode rows is read under the chunk's
+    # name: it is a chunk with more rows
+    text = eng._fused_fn.lower(*eng._abstract_fused_args()).as_text()
     assert "module @jit_serve_prefill_chunk" in text.splitlines()[0]
 
 

@@ -1085,7 +1085,8 @@ class _Trace:
 
         self.log, self.keep, self.step_of = [], [], {}
         self.n_dispatched = 0
-        step_fn, first_fn = eng._step_fn, eng._first_fn
+        step_fn, first_fn, fused_fn = (eng._step_fn, eng._first_fn,
+                                       eng._fused_fn)
         self.step_of[id(eng._out)] = 0
         self.keep.append(eng._out)
 
@@ -1095,6 +1096,16 @@ class _Trace:
             self._name(out, self.n_dispatched)
             self.log.append(("dispatch", self.n_dispatched))
             return kv, out
+
+        def fused(*a):  # a step whose rows ride in a chunk is a step
+            kv, out, logits = fused_fn(*a)
+            rows = a[2][-eng.n_slots * (eng.max_blocks + 4):].reshape(
+                eng.n_slots, -1)
+            if rows[:, -2].any():  # not the engine's first chunk, alone
+                self.n_dispatched += 1
+                self._name(out, self.n_dispatched)
+                self.log.append(("dispatch", self.n_dispatched))
+            return kv, out, logits
 
         def first(prev, *a):
             out = first_fn(prev, *a)
@@ -1106,6 +1117,8 @@ class _Trace:
             return jax.device_get(x)
 
         eng._step_fn, eng._first_fn = step, first
+        if fused_fn is not None:
+            eng._fused_fn = fused
         fake = type("jax", (), {"device_get": staticmethod(device_get)})
         for name in ("random", "jit", "tree", "eval_shape", "Array"):
             setattr(fake, name, getattr(jax, name))
@@ -1264,7 +1277,10 @@ def test_nothing_compiles_after_the_warm_up_across_admissions():
     assert len(later) > 20 and eng.scheduler.n_preemptions > 0
     assert sum(s["compiles"] for s in later) == 0
     assert eng._step_fn._cache_size() == 1 == eng._first_fn._cache_size()
-    assert eng._prefill_fn._cache_size() == 1
+    # every chunk, alone or with the step's decode rows, is one program,
+    # and the chunk alone of an engine that cannot fuse was never built
+    assert eng._fused_fn._cache_size() == 1
+    assert eng._prefill_fn._cache_size() == 0 < eng.fused_steps
 
 
 def test_work_list_kernel_serves_the_dense_paths_tokens_without_a_compile():

@@ -9,13 +9,35 @@ executable compiles once and runs for the life of the server — no
 recompiles as the request mix churns (prefill is the only shape-varying
 entry point, one trace per distinct prompt length).
 
-The two programs are ``programs.decode_step`` and
-``programs.prefill_chunk`` (``serve/programs.py``): ONE pair for every
-model.  They walk the layers one by one, a model with one kind of layer
-and a model whose layers differ (``cfg.layer_types``: window, full and
+The programs are ``programs.decode_step``, ``programs.prefill_chunk``
+and ``programs.chunk_and_step`` (``serve/programs.py``): the same three for
+every model.  They walk the layers one by one, a model with one kind of
+layer and a model whose layers differ (``cfg.layer_types``: window, full and
 linear attention mixed, expert FFNs) alike, the per-layer math the TRAINING
 modules applied piecewise, and every layer's pages, or the recurrent state
 of a ``linear_attention`` layer, updated in place.
+
+Which of them a call runs is read off the engine's own state.  A call with
+a chunk to run AND a slot decoding dispatches ONE program,
+``chunk_and_step``: the chunk's C rows and the S decode rows go through the
+layers together, so the weights, an expert layer's touched experts and the
+head are read once and not twice (a trace shows it under the chunk's name,
+``jit_serve_prefill_chunk``: it is a chunk with more rows).  It is
+dispatched where the decode step is, after ``grow`` and the copy-on-write
+guard and before the step before is read; a prompt whose last chunk carried
+decode rows starts decoding in the NEXT call, with the first token this
+call's logits gave (as two calls it joins the same call's step: its second
+token moves by one call, no token changes).  While nobody decodes, a chunk
+of such an engine runs in its own place as the SAME program with every
+decode row idle (null tables, the null row: a few rows more in each
+product), so the engine builds two programs and not three: a third cost
+2 s of every start-up, an idle row costs microseconds of a prompt's first
+chunks.  An engine that is ``disaggregate``d (the chunk is another chip's),
+speculative (a verify step has 1 + k rows a slot), built with ``lora_spec``
+(the decode rows add their tenants' deltas), single-shot (a prompt's shape
+is its own) or ``moe_decode="routed"`` (capacity routing depends on the
+rows it is handed) runs the chunk and the step that were there, as two
+calls, always.  ``serve.step`` carries ``fused`` and ``fused_decode_rows``.
 
 Prefill writes a prompt's keys and values straight into the request's
 blocks and attends through its table: the same math as ``generate()``'s
@@ -381,6 +403,7 @@ class ServeEngine:
         self._out = programs.step_output(n_slots, 1 + self.speculative)
         self._rows: list[tuple[int, Request, int]] = []
         self._firsts: list[tuple[int, Request, int]] = []
+        self._rows_fused = False  # the rows rode in a chunk (``_rides``)
         # lifetime counts: decode steps dispatched with the step before
         # unread, and slot-steps decoded and thrown away (a step in flight
         # when its request's EOS was read or it was preempted); step()
@@ -417,6 +440,32 @@ class ServeEngine:
         self._step_fn = jax.jit(serve_decode_step, donate_argnums=(1,))
         self._first_fn = jax.jit(serve_first_token)
         self._prefill_fn = jax.jit(serve_prefill_chunk, donate_argnums=(1,))
+        # a step's chunk may carry its decode rows (``_rides``): the chunk
+        # program of such an engine, which a trace shows under the chunk's
+        # name, since it is a chunk with more rows.  Only where a step is
+        # one token a slot off the base weights on the chip the chunks run
+        # on, and every row's FFN is the same function (the capacity-routed
+        # form of the toy experts depends on which rows it is handed)
+        self._fused_fn = None
+        if (self.prefill_chunk is not None and not self.disaggregate
+                and not self.speculative and lora_spec is None
+                and moe_decode == "dense"):
+            chunk = self.prefill_chunk
+
+            def serve_prefill_chunk(*operands):  # noqa: F811
+                return programs.chunk_and_step(
+                    *operands, cfg=cfg, sample=sample, max_blocks=max_blocks,
+                    chunk=chunk, attention_impl=attention_impl, mesh=mesh,
+                    spec=pool_spec)
+
+            self._fused_fn = jax.jit(serve_prefill_chunk,
+                                     donate_argnums=(1,))
+        # the decode rows of a chunk that carries none: every slot idle
+        self._idle_rows = np.zeros((n_slots, max_blocks + 4), np.int32)
+        # lifetime counts: steps whose chunk carried the decode rows, and
+        # those rows; step() diffs them onto serve.step
+        self.fused_steps = 0
+        self.fused_decode_rows = 0
         self._prefill_lora_fn = (
             jax.jit(serve_prefill_chunk_lora, donate_argnums=(2,))
             if lora_spec is not None else None)
@@ -482,8 +531,9 @@ class ServeEngine:
     def _export_compiled(self, cache, tags: dict, *, num_blocks: int,
                          block_size: int, quant_kv: bool, cache_dtype,
                          n_adapters: int, quant_adapters: bool) -> None:
-        """Cache-first AOT for the two fixed-shape serve traces (decode
-        step and base prefill chunk).  Abstract args come from
+        """Cache-first AOT for the fixed-shape serve traces (decode step,
+        and the base prefill chunk or, where the engine builds one, the
+        chunk that carries a step's decode rows).  Abstract args come from
         ``jax.eval_shape`` over the exact runtime operands — nothing is
         materialized, and the traces match dispatch bit-for-bit.  The
         per-prompt-length LoRA prefill stays lazy (one trace per tenant
@@ -527,7 +577,7 @@ class ServeEngine:
             self._step_fn = aot_mod.ExportedCallable(
                 res.compiled, self._step_fn, "serve_decode")
             self.export_info.append(res.to_json())
-        if self.prefill_chunk:
+        if self.prefill_chunk and self._fused_fn is None:
             res = aot_mod.cached_compile(
                 self._prefill_fn, self._abstract_prefill_args(), cache=cache,
                 kind="serve_prefill",
@@ -536,6 +586,16 @@ class ServeEngine:
             if res is not None:
                 self._prefill_fn = aot_mod.ExportedCallable(
                     res.compiled, self._prefill_fn, "serve_prefill")
+                self.export_info.append(res.to_json())
+        if self._fused_fn is not None:
+            res = aot_mod.cached_compile(
+                self._fused_fn, self._abstract_fused_args(), cache=cache,
+                kind="serve_fused",
+                key=export_cache_mod.executable_key(
+                    "serve_fused", sig, topo_fp, program, tags))
+            if res is not None:
+                self._fused_fn = aot_mod.ExportedCallable(
+                    res.compiled, self._fused_fn, "serve_fused")
                 self.export_info.append(res.to_json())
 
     def _abstract_decode_args(self) -> tuple:
@@ -557,6 +617,16 @@ class ServeEngine:
             self.params, self.pool.kv,
             jnp.zeros((self.max_blocks + C + 3,), jnp.int32),
             self._win_rows[0]))
+
+    def _abstract_fused_args(self) -> tuple:
+        """Abstract operands of the chunk that carries a step's decode
+        rows (``programs.chunk_and_step``)."""
+        S, MB = self.n_slots, self.max_blocks
+        return jax.eval_shape(lambda: (
+            self.params, self.pool.kv,
+            jnp.zeros((MB + self.prefill_chunk + 3 + S * (MB + 4),),
+                      jnp.int32),
+            self._out, self._win_rows[0], self.pool.win_tables, self._rng))
 
     def compiled_decode_text(self) -> str:
         """Optimized HLO text of the compiled decode step (the serving
@@ -725,36 +795,59 @@ class ServeEngine:
         self._prefill[req.rid] = _PrefillState(
             pos=req.cached_tokens, lora=self._req_lora(req))
 
-    def _advance_prefill(self, slot: int, req: Request,
-                         single_shot: bool = False) -> None:
-        """One [1, C] chunk of ``req``'s prompt, written into its blocks.
-        On the final chunk: pin the adapter (bouncing the request if the
-        pool is full), sample the first token ON THE DEVICE, into the
-        newest step output, and hand the slot to decode: its first step
-        reads the token there, and the host fetches it with its next read
-        (at once only where it reads before it dispatches).  Single-shot,
-        the chunk is the whole prompt padded to whole pages (one trace per
-        distinct padded length — the only shape-varying compile in the
-        serving loop) and the adapter is already pinned."""
+    def _chunk_operands(self, slot: int, req: Request,
+                        single_shot: bool = False) -> tuple[np.ndarray, int]:
+        """``(pack_chunk's operand, how many real tokens)`` of the chunk of
+        ``req``'s prompt at its cursor: one upload (table row, tokens,
+        cursor, last real row, slot)."""
         st = self._prefill[req.rid]
         bs = self.pool.block_size
         C = (blocks_for_tokens(req.n_prompt, bs) * bs if single_shot
              else self.prefill_chunk)
+        chunk = req.prompt[st.pos:st.pos + C]
+        return programs.pack_chunk(
+            self.pool.table_row(req.blocks, self.max_blocks),
+            chunk + [0] * (C - len(chunk)), st.pos, len(chunk) - 1,
+            slot), len(chunk)
+
+    def _advance_prefill(self, slot: int, req: Request,
+                         single_shot: bool = False) -> None:
+        """One [1, C] chunk of ``req``'s prompt, written into its blocks
+        (``_chunk_ran`` is what follows).  Single-shot, the chunk is the
+        whole prompt padded to whole pages (one trace per distinct padded
+        length — the only shape-varying compile in the serving loop) and
+        the adapter is already pinned."""
+        st = self._prefill[req.rid]
         with self._phase("prefill_dispatch", rid=req.rid, pos=st.pos):
-            chunk = req.prompt[st.pos:st.pos + C]
-            n_real = len(chunk)
             t0 = time.monotonic()
-            # one upload: table row, tokens, cursor, last real row, slot
-            packed = programs.pack_chunk(
-                self.pool.table_row(req.blocks, self.max_blocks),
-                chunk + [0] * (C - n_real), st.pos, n_real - 1, slot)
-            if st.lora is None:
-                self.pool.kv, logits = self._prefill_fn(
-                    self.params, self.pool.kv, packed, self._win_rows[slot])
-            else:
+            packed, n_real = self._chunk_operands(slot, req, single_shot)
+            if st.lora is not None:
                 self.pool.kv, logits = self._prefill_lora_fn(
                     self._merge_base, st.lora, self.pool.kv, packed,
                     self._win_rows[slot])
+            elif self._fused_fn is not None:
+                # nobody decodes (``_rides``): the program that carries a
+                # step's decode rows, with none, and not a third program
+                self.pool.kv, _, logits = self._fused_fn(
+                    self.params, self.pool.kv,
+                    programs.pack_chunk_and_step(packed, self._idle_rows),
+                    self._out, self._win_rows[slot], self.pool.win_tables,
+                    self._rng)
+            else:
+                self.pool.kv, logits = self._prefill_fn(
+                    self.params, self.pool.kv, packed, self._win_rows[slot])
+        self._chunk_ran(slot, req, n_real, logits, t0, single_shot)
+
+    def _chunk_ran(self, slot: int, req: Request, n_real: int, logits,
+                   t0: float, single_shot: bool = False) -> None:
+        """A chunk of ``n_real`` tokens of ``req``'s prompt is dispatched
+        (at ``t0``): move the cursor.  On the final chunk: pin the adapter
+        (bouncing the request if the pool is full), sample the first token
+        ON THE DEVICE from the chunk's ``logits``, into the newest step
+        output, and hand the slot to decode: its first step reads the token
+        there, and the host fetches it with its next read (at once only
+        where it reads before it dispatches)."""
+        st = self._prefill[req.rid]
         st.pos += n_real
         done = st.pos >= req.n_prompt
         bounced = (done and not single_shot
@@ -785,6 +878,16 @@ class ServeEngine:
             pos=min(st.pos, req.n_prompt), n_tokens=n_real,
             seconds=chunk_s,
             done=bool(done and not bounced))
+
+    def _rides(self, plan: list) -> bool:
+        """Whether this call's decode rows ride in the last chunk of
+        ``plan``: ONE program for both where the engine can run one
+        (``_fused_fn``) and some slot decodes.  The chunk is then dispatched
+        with the step, after ``grow`` and the copy-on-write guard, and not
+        before them.  While nobody decodes a chunk runs in its own place,
+        its decode rows idle (``_advance_prefill``)."""
+        return (bool(plan) and self._fused_fn is not None
+                and self.scheduler.n_decoding > 0)
 
     def _cow_fork_writes(self) -> None:
         """Copy-on-write guard, run right before the decode step: any
@@ -824,15 +927,17 @@ class ServeEngine:
                     "serve.prefix", kind="cow", rid=req.rid,
                     block=b, fork=nb)
 
-    def _decode_all(self) -> None:
+    def _decode_all(self, rider: tuple | None = None) -> None:
         """Dispatch this call's decode step, THEN read what the host has
         yet to read: the step dispatched a call ago and the first tokens
         of prompts that ended since, which all lie in the output array
         this call's step was handed.  An engine that reads before it
         dispatches (``_ahead`` 0) comes here with nothing unread and reads
-        its own step.  A call with no slot to decode drains."""
+        its own step.  A call with no slot to decode drains.  ``rider`` is
+        the (slot, request) whose chunk the step's rows ride in
+        (``_rides``)."""
         unread = self._take_unread()
-        drafts = self._dispatch()
+        drafts = self._dispatch(rider)
         if drafts is not None and unread[1]:
             self.steps_ahead += 1
         if not self._ahead:
@@ -840,21 +945,26 @@ class ServeEngine:
         self._read("decode_wait", unread, drafts)
 
     def _take_unread(self) -> tuple:
-        """(output array, its unread step rows, its unread first tokens),
-        handed over: the engine's lists start anew."""
-        unread = (self._out, self._rows, self._firsts)
-        self._rows, self._firsts = [], []
+        """(output array, its unread step rows, its unread first tokens,
+        whether those rows rode in a chunk), handed over: the engine's lists
+        start anew."""
+        unread = (self._out, self._rows, self._firsts, self._rows_fused)
+        self._rows, self._firsts, self._rows_fused = [], [], False
         return unread
 
-    def _dispatch(self) -> np.ndarray | None:
+    def _dispatch(self, rider: tuple | None = None) -> np.ndarray | None:
         """One decode step for every running slot, dispatched and not
         waited for.  Nothing here needs a token's VALUE unless the host has
         it already: a slot's context length and pages follow from the count
         of tokens dispatched, and its token is taken on the device from the
         output of the step before (or from the first tokens beside it)
-        whenever the host has not read it yet.  Returns the [S, T] tokens
-        of the operand (the drafts a verify step emits against), or None
-        where no slot decodes."""
+        whenever the host has not read it yet.  With a ``rider`` (slot,
+        request) the step's rows go through the layers in that request's
+        next chunk, ONE program for both (``programs.chunk_and_step``), and
+        the chunk is accounted as ``_advance_prefill`` accounts one: a
+        prompt that ends in it decodes from the next call on.  Returns the
+        [S, T] tokens of the operand (the drafts a verify step emits
+        against), or None where no slot decodes."""
         S, MB = self.n_slots, self.max_blocks
         k_spec = self.speculative
         T = 1 + k_spec
@@ -887,7 +997,7 @@ class ServeEngine:
                               else programs.TOKEN_PREV)
                 ids[s] = req.adapter_idx
                 rows.append((s, req, req.preempted))
-        if not rows:
+        if not rows and rider is None:
             return None
         with self._phase("decode_upload"):
             # greedy sampling reads no key: no fold a step for it
@@ -898,13 +1008,29 @@ class ServeEngine:
                        if self.adapter_pool is not None else {})
             # one upload a step: tables, tokens, contexts, flags, ids
             packed = programs.pack_step(tables, ctx, tok, src, ids)
+            if rider is not None:  # still one upload
+                t0 = time.monotonic()
+                of_chunk, n_real = self._chunk_operands(*rider)
+                packed = programs.pack_chunk_and_step(of_chunk, packed)
         with self._phase("decode_dispatch"):
-            self.pool.kv, self._out = self._step_fn(
-                self.params, self.pool.kv, packed, self._out,
-                self.pool.win_tables, factors, step_rng)
+            if rider is None:
+                self.pool.kv, self._out = self._step_fn(
+                    self.params, self.pool.kv, packed, self._out,
+                    self.pool.win_tables, factors, step_rng)
+            else:
+                self.pool.kv, self._out, logits = self._fused_fn(
+                    self.params, self.pool.kv, packed, self._out,
+                    self._win_rows[rider[0]], self.pool.win_tables, step_rng)
         for _, req, _ in rows:
             req.n_inflight += 1
         self._rows = rows
+        if rider is not None:
+            self._rows_fused = True
+            self.fused_steps += bool(rows)
+            self.fused_decode_rows += len(rows)
+            # after the rows are the engine's: the prompt's first token goes
+            # into THIS call's output, which holds them
+            self._chunk_ran(*rider, n_real, logits, t0)
         return tok
 
     def _read(self, wait: str, unread: tuple,
@@ -912,7 +1038,7 @@ class ServeEngine:
         """Fetch an output array (phase ``wait``) and hand its unread
         tokens to their requests (phase ``emit``); ``unread`` is what
         ``_take_unread`` gave."""
-        out, rows, firsts = unread
+        out, rows, firsts, fused = unread
         if not rows and not firsts:
             return
         S, T = self.n_slots, 1 + self.speculative
@@ -924,8 +1050,11 @@ class ServeEngine:
             first, tokens, counters = out[:S], out[S:-n], out[-n:]
             if T > 1:
                 tokens = tokens.reshape(S, T)
-            if rows:  # the expert layers' three only where there are any
-                skip = 0 if self.cfg.n_expert_layers else 3
+            if rows:
+                # the expert layers' three only where there are any, and of
+                # a step that decoded alone: where the rows rode in a chunk
+                # the layers routed its rows with them
+                skip = 0 if self.cfg.n_expert_layers and not fused else 3
                 self._counters = dict(zip(
                     ("moe_pairs", "moe_experts_touched",
                      "moe_max_expert_tokens", "attn_grid_items",
@@ -1062,6 +1191,7 @@ class ServeEngine:
         tokens_before = self.tokens_emitted
         ahead_before = self.steps_ahead
         discarded_before = self.discarded_tokens
+        fused_before = self.fused_steps, self.fused_decode_rows
         compiles, compile_s = self._compiles.n, self._compiles.seconds
         self._phases = phases = {}
         self._counters = {}
@@ -1080,7 +1210,10 @@ class ServeEngine:
             prefill_s = 0.0
             budget = (None if self.disaggregate
                       else self.prefill_chunks_per_step)
-            for slot, req in sched.prefill_plan(budget):
+            plan = sched.prefill_plan(budget)
+            # the last chunk of the plan waits for the decode rows
+            rider = plan.pop() if self._rides(plan) else None
+            for slot, req in plan:
                 n_chunks += 1
                 t0 = time.monotonic()
                 self._advance_prefill(slot, req)
@@ -1093,10 +1226,14 @@ class ServeEngine:
                                        n_regenerate=victim.n_prompt)
                 if sched.n_decoding and self._prefix_cache is not None:
                     self._cow_fork_writes()
+            if rider is not None and rider[1].rid not in self._prefill:
+                rider = None  # preempted to grow another: its chunk with it
+            n_chunks += rider is not None
             decode_s = 0.0
-            if sched.n_decoding or self._rows or self._firsts:
+            if (sched.n_decoding or self._rows or self._firsts
+                    or rider is not None):
                 t0 = time.monotonic()
-                self._decode_all()
+                self._decode_all(rider)
                 decode_s = time.monotonic() - t0
         t_end = sched.clock()
         self._step_count += 1
@@ -1152,6 +1289,8 @@ class ServeEngine:
             n_prefill_chunks=n_chunks, compiles=compiles,
             ahead=self.steps_ahead - ahead_before,
             discarded_tokens=self.discarded_tokens - discarded_before,
+            fused=self.fused_steps - fused_before[0],
+            fused_decode_rows=self.fused_decode_rows - fused_before[1],
             **adapter_stats, **self._counters)
         if self._debug_invariants:
             sched.check_invariants()

@@ -1,6 +1,7 @@
-"""The two serving programs: the decode step and the prefill chunk.
+"""The serving programs: the decode step, the prefill chunk, and the chunk
+that carries a step's decode rows.
 
-Both walk the model's layers one by one (``transformer_core.layer_plan``:
+All walk the model's layers one by one (``transformer_core.layer_plan``:
 a model with one kind of layer is a plan of that one kind), each layer
 with its own mixer, window, rotation and FFN, and each with its own pair of
 pool arrays (``kv_pool``: pages for ``max_len``, a ring on a
@@ -33,6 +34,11 @@ discipline of ``decode.forward_cached``: ``SelfAttention.qkv`` /
   the step form, one token a slot.  Rows that are no real token (a padded
   chunk's tail, an inactive slot) carry ``beta = 0`` and ``g = 0``, which
   leave a state as it was.
+
+- ``chunk_and_step`` is both in one walk: the chunk's C rows and the S
+  decode rows share each layer's norms, projections, FFN and the head (the
+  weights are read once), and each group touches the cache as its own
+  program does (``_chunk_*``, ``_step_*``: one source for all three).
 
 A layer is traced once a kind, not once a layer: the walk calls one jitted
 function a (kind, FFN) pair, so a model of 24 like layers traces one.
@@ -119,11 +125,13 @@ def _logits(params, cfg: TransformerConfig, x):
 def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
            moe: str = "dense", adapted=None):
     """One layer, its mixer by ``kind``: ``attend`` is what touches the
-    cache, everything else is shared by the chunk and the step.  On an
-    attention layer ``attend(q, k, v)`` writes the new keys and values and
-    returns the attention output; on a ``linear_attention`` layer
-    ``attend(qkv, h)`` is the state update (``qkv(h, tail)`` gives the
-    mixer's ``qkv`` on the layer's input and the convolution's tail).
+    cache, everything else is shared by the rows, whatever call they are
+    of.  On an attention layer ``attend(q, k, v)`` writes the new keys and
+    values and returns the attention output; on a ``linear_attention``
+    layer ``attend(convolve, pre, g, beta)`` is the state update (``pre``,
+    ``g``, ``beta`` are the mixer's ``project`` of the layer's input, a row
+    at a time; ``convolve(pre, tail)`` its ``convolve`` of one sequence's
+    rows behind their tail).
     ``adapted(tensor, site, inp, rotate)`` adds a tenant's low-rank delta at
     a projection (decode steps with tenants).  Returns ``(x, the expert
     FFN's counters or None)``."""
@@ -134,7 +142,8 @@ def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
     h = norm.apply({"params": lp["attn_norm"]}, x) if cfg.pre_norm else x
     if kind == "linear_attention":
         mixer, own = GatedDeltaMixer(cfg), {"params": lp["attn"]}
-        o = attend(lambda *a: mixer.apply(own, *a, method="qkv"), h)
+        o = attend(lambda *a: mixer.apply(own, *a, method="convolve"),
+                   *mixer.apply(own, h, method="project"))
         ao = mixer.apply(own, o, h, method="out_proj")
     else:
         attn = SelfAttention(cfg, kind)
@@ -218,6 +227,173 @@ def _walk(cfg, params, kv, x, layer_fn, shared, extras=None):
     return x, {"k": new_k, "v": new_v}, stats
 
 
+# -- what touches the cache, a group of rows at a time -------------------------
+#
+# A call's rows are one prompt's chunk ([C] rows of ONE slot, ``chunk_*``) or
+# one token of every slot ([S, T], ``step_*``), or both (``chunk_and_step``).
+# Each function below is one group's ``attend`` of ``_layer`` on one layer's
+# pair of pool arrays, and returns ``(what the rows read, the pair)``.
+
+
+def _step_shared(cfg, kv, tables, win_tables, ctx_lens, active, adapter_ids,
+                 T: int, attention_impl: str):
+    """What every layer of a decode step reads: the tables of each kind of
+    page with the null block wherever a slot has no key to read (an
+    unchanged block index is not copied again: past the newest key, and in
+    a ring before the oldest its window still reaches), the slots' rows of
+    a linear layer's state (the null row for a slot that does not decode)
+    and the folded kernel's grid, the live (slot, key group) items: one
+    list a kind of table, built here once a step and not in every layer's
+    call.  Returns ``(shared, [grid steps the step's paged calls run, the
+    slots x groups a dense grid would])``."""
+    from ...ops.paged_attention import folded_work_list, is_folded
+
+    S, MB = tables.shape
+    paged = _paged(cfg)  # none: a model of recurrent states alone
+    pages0 = kv["k"][paged[0]] if paged else jnp.zeros((1, 1, 1))
+    bs = kv_leaf_parts(pages0)[0].shape[1]
+    hi = jnp.where(active, (ctx_lens + T - 1) // bs, -1)
+    full = jnp.where(jnp.arange(MB)[None, :] <= hi[:, None], tables, 0)
+    shared = {"tables": {"pages": full}, "ctx_lens": ctx_lens,
+              "active": active, "adapter_ids": adapter_ids,
+              "rows": jnp.where(active, 1 + jnp.arange(S), 0), "work": {}}
+    if win_tables.shape[1]:
+        lo = (ctx_lens - cfg.sliding_window + 1) // bs
+        shared["tables"]["ring"] = ring_table(win_tables, MB, lo, hi)
+    kinds = [kind for _, kind, _ in layer_plan(cfg)
+             if kind != "linear_attention"]
+    grid = jnp.zeros((2,), jnp.int32)
+    if attention_impl == "paged" and T == 1 and paged and is_folded(pages0):
+        shared["work"] = {  # in the plan's order: the same text every run
+            _pages_of(kind): folded_work_list(
+                ctx_lens, active, max_blocks=MB, block_size=bs,
+                window=cfg.layer_window(kind))
+            for kind in dict.fromkeys(kinds)}
+        works = [shared["work"][_pages_of(kind)] for kind in kinds]
+        grid = jnp.stack([sum(w.n_items for w in works),
+                          jnp.int32(sum(w.dense for w in works))])
+    return shared, grid
+
+
+def _step_attention(cfg, kind, shared, k_l, v_l, q, k, v, *,
+                    attention_impl: str, mesh):
+    """``q``, ``k``, ``v`` [S, T, heads, hd]: every slot's T tokens written
+    at its context's end, then each attends the keys up to itself."""
+    from ...ops.attention import xla_attention
+    from ...ops.paged_attention import paged_attention
+
+    T = q.shape[1]
+    table = shared["tables"][_pages_of(kind)]
+    ctx_lens, window = shared["ctx_lens"], cfg.layer_window(kind)
+    for t in range(T):  # static and small (1 + draft length)
+        k_l = write_token(k_l, table, ctx_lens + t, k[:, t])
+        v_l = write_token(v_l, table, ctx_lens + t, v[:, t])
+    if attention_impl == "paged" and T == 1:
+        return paged_attention(
+            q[:, 0], k_l, v_l, table, ctx_lens, window=window, mesh=mesh,
+            work=shared["work"].get(_pages_of(kind)))[:, None], k_l, v_l
+    # chunk position t writes at positions[s, t] then attends keys
+    # 0..positions[s, t] inclusive — the causal triangle across the chunk
+    # plus the context below it; table padding beyond a slot's blocks
+    # gathers null-block garbage this never admits
+    positions = ctx_lens[:, None] + jnp.arange(T)[None, :]  # [S, T]
+    kd = gather_blocks(k_l, table, cfg.dtype, cfg.kv_heads)
+    vd = gather_blocks(v_l, table, cfg.dtype, cfg.kv_heads)
+    key_idx = jnp.arange(kd.shape[1])[None, None, :]
+    mask = key_idx <= positions[:, :, None]
+    if window is not None:
+        mask &= key_idx > positions[:, :, None] - window
+    return xla_attention(q, kd, vd, causal=False,
+                         mask=mask[:, None]), k_l, v_l
+
+
+def _step_state(shared, state, tails, convolve, pre, g, beta):
+    """``pre`` [S, 1, D], ``g``, ``beta`` [S, 1, H]: one token a slot, the
+    step form of the rule on the slots' rows of ``state`` and ``tails``."""
+    rows, live = shared["rows"], shared["active"][:, None]
+    q, k, v, full = convolve(pre, tails[rows])
+    tails = tails.at[rows].set(full[:, 1:].astype(tails.dtype))
+    o, state = gated_delta_step(
+        q[:, 0], k[:, 0], v[:, 0], jnp.where(live, g[:, 0], 0.0),
+        jnp.where(live, beta[:, 0], 0.0), state, rows)
+    return o[:, None], state, tails
+
+
+def _tenant_delta(cfg, ad, adapter_ids, positions, lora_scaling: float):
+    """``_layer``'s ``adapted`` for a decode step's rows [S, T]: each slot
+    gathers its factors of one layer (``ad``) by its adapter id."""
+    if not ad:
+        return None
+
+    def adapted(tensor, site, inp, rotate):
+        if site not in ad:
+            return tensor
+        a = factor_rows(ad[site]["a"], adapter_ids)  # [S, d_in, r]
+        b = factor_rows(ad[site]["b"], adapter_ids)  # [S, r, d_out]
+        d = lora_scaling * jnp.einsum(
+            "str,sro->sto", jnp.einsum("std,sdr->str", inp, a), b)
+        d = d.reshape(tensor.shape)
+        if rotate:
+            d = rope(d, positions, cfg.rope_theta)
+        return (tensor.astype(jnp.float32) + d).astype(tensor.dtype)
+
+    return adapted
+
+
+def _chunk_shared(packed, win_row, max_blocks: int):
+    """What every layer of a prefill chunk reads, from the operands of
+    ``pack_chunk``: the slot's table row a kind of page, the chunk's first
+    position and last real row, the slot's row of a linear layer's state,
+    and which of its C rows are real."""
+    MB = max_blocks
+    C = packed.shape[0] - MB - 3
+    table_row, pos0, last_idx = packed[:MB], packed[-3], packed[-2]
+    shared = {"rows": {"pages": table_row}, "pos0": pos0,
+              "last_idx": last_idx, "row": 1 + packed[-1],
+              "real": jnp.arange(C) <= last_idx}
+    if win_row.shape[0]:
+        shared["rows"]["ring"] = win_row[jnp.arange(MB) % win_row.shape[0]]
+    return shared
+
+
+def _chunk_attention(cfg, kind, shared, k_l, v_l, q, k, v):
+    """``q``, ``k``, ``v`` [C, heads, hd]: the chunk's keys and values
+    written into the slot's pages, then its queries over them."""
+    row, pos0 = shared["rows"][_pages_of(kind)], shared["pos0"]
+    k_l = write_chunk(k_l, row, pos0, k)
+    v_l = write_chunk(v_l, row, pos0, v)
+    return chunk_attention(q, k_l, v_l, row, pos0, cfg.layer_window(kind),
+                           cfg.kv_heads), k_l, v_l
+
+
+def _chunk_state(shared, state, tails, convolve, pre, g, beta):
+    """``pre`` [C, D], ``g``, ``beta`` [C, H]: the chunk form of the rule
+    from the state the chunk before left in the slot's row, or from zeros
+    where the prompt starts."""
+    row, last_idx = shared["row"], shared["last_idx"]
+    fresh = shared["pos0"] == 0  # a prompt starts from zeros
+    q, k, v, full = convolve(pre[None],
+                             jnp.where(fresh, 0, tails[row])[None])
+    # the tail the next call reads: the last K - 1 real rows
+    tails = tails.at[row].set(jax.lax.dynamic_slice_in_dim(
+        full[0], last_idx + 1, tails.shape[1]).astype(tails.dtype))
+    real = shared["real"][:, None]
+    o, new = gated_delta_chunk(
+        q[0], k[0], v[0], jnp.where(real, g, 0.0),
+        jnp.where(real, beta, 0.0), jnp.where(fresh, 0.0, state[row]))
+    return o, state.at[row].set(new), tails
+
+
+def _pool_constraint(kv, mesh, spec):
+    """The pool's arrays held to their sharding under a ``mesh``."""
+    if mesh is None or spec is None:
+        return kv
+    from jax.sharding import NamedSharding
+
+    sh = NamedSharding(mesh, spec)
+    return jax.tree.map(lambda a: jax.lax.with_sharding_constraint(a, sh), kv)
+
+
 def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
                   adapters=None, adapter_ids=None, *,
                   cfg: TransformerConfig, attention_impl: str = "paged",
@@ -253,131 +429,39 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
     layers' three (``_moe_counters``), then the grid steps the paged calls
     of the step ran and the ``slots x groups`` a dense grid would have run,
     summed over the layers (zeros where no call takes a work list)."""
-    from ...ops.attention import xla_attention
-    from ...ops.paged_attention import (
-        folded_work_list,
-        is_folded,
-        paged_attention,
-    )
-
     S, T = tok.shape
-    MB = tables.shape[1]
-    paged = _paged(cfg)  # none: a model of recurrent states alone
-    pages0 = kv["k"][paged[0]] if paged else jnp.zeros((1, 1, 1))
-    bs = kv_leaf_parts(pages0)[0].shape[1]
-    if mesh is not None and spec is not None:
-        from jax.sharding import NamedSharding
-
-        sh = NamedSharding(mesh, spec)
-        kv = jax.tree.map(
-            lambda a: jax.lax.with_sharding_constraint(a, sh), kv)
-    # a layer's table holds the null block wherever it has no key to read
-    # (an unchanged block index is not copied again): past the newest key,
-    # and in a ring before the oldest its window still reaches
-    hi = jnp.where(active, (ctx_lens + T - 1) // bs, -1)
-    full = jnp.where(jnp.arange(MB)[None, :] <= hi[:, None], tables, 0)
-    shared = {"tables": {"pages": full}, "ctx_lens": ctx_lens,
-              "active": active, "adapter_ids": adapter_ids,
-              # a slot's row of a linear layer's state; the null row for
-              # a slot that does not decode
-              "rows": jnp.where(active, 1 + jnp.arange(S), 0)}
-    if win_tables.shape[1]:
-        lo = (ctx_lens - cfg.sliding_window + 1) // bs
-        shared["tables"]["ring"] = ring_table(win_tables, MB, lo, hi)
-    # the folded kernel's grid, the live (slot, key group) items: one list a
-    # kind of table, built here once a step and not in every layer's call
-    kinds = [kind for _, kind, _ in layer_plan(cfg)
-             if kind != "linear_attention"]
-    grid = jnp.zeros((2,), jnp.int32)
-    shared["work"] = {}
-    if attention_impl == "paged" and T == 1 and paged and is_folded(pages0):
-        shared["work"] = {  # in the plan's order: the same text every run
-            _pages_of(kind): folded_work_list(
-                ctx_lens, active, max_blocks=MB, block_size=bs,
-                window=cfg.layer_window(kind))
-            for kind in dict.fromkeys(kinds)}
-        works = [shared["work"][_pages_of(kind)] for kind in kinds]
-        grid = jnp.stack([sum(w.n_items for w in works),
-                          jnp.int32(sum(w.dense for w in works))])
+    kv = _pool_constraint(kv, mesh, spec)
+    shared, grid = _step_shared(cfg, kv, tables, win_tables, ctx_lens,
+                                active, adapter_ids, T, attention_impl)
+    # per-slot, per-chunk-offset absolute positions
+    positions = ctx_lens[:, None] + jnp.arange(T)[None, :]
 
     def layer_fn(kind, sparse):
-        window = cfg.layer_window(kind)
+        def fn(lp, a, b, x, ad, shared):  # a layer's pair of pool arrays
+            def attend(*rows):
+                nonlocal a, b
+                if kind == "linear_attention":
+                    o, a, b = _step_state(shared, a, b, *rows)
+                else:
+                    o, a, b = _step_attention(
+                        cfg, kind, shared, a, b, *rows,
+                        attention_impl=attention_impl, mesh=mesh)
+                return o
 
-        def linear_fn(lp, state, tails, x, _, shared):
-            rows, active = shared["rows"], shared["active"]
+            x, counters = _layer(
+                cfg, lp, kind, sparse, x, positions,
+                jnp.broadcast_to(shared["active"][:, None], (S, T)), attend,
+                adapted=_tenant_delta(cfg, ad, shared["adapter_ids"],
+                                      positions, lora_scaling))
+            return x, a, b, counters
 
-            def attend(qkv, h):
-                nonlocal state, tails
-                q, k, v, g, beta, full = qkv(h, tails[rows])
-                tails = tails.at[rows].set(full[:, 1:].astype(tails.dtype))
-                live = active[:, None]
-                o, state = gated_delta_step(
-                    q[:, 0], k[:, 0], v[:, 0], jnp.where(live, g[:, 0], 0.0),
-                    jnp.where(live, beta[:, 0], 0.0), state, rows)
-                return o[:, None]
-
-            x, _ = _layer(cfg, lp, kind, sparse, x, None,
-                          jnp.broadcast_to(active[:, None], (S, T)), attend)
-            return x, state, tails, None
-
-        def fn(lp, k_l, v_l, x, ad, shared):
-            table = shared["tables"][_pages_of(kind)]
-            ctx_lens, adapter_ids = shared["ctx_lens"], shared["adapter_ids"]
-            positions = ctx_lens[:, None] + jnp.arange(T)[None, :]  # [S, T]
-            key_idx = jnp.arange(MB * bs)[None, None, :]
-
-            def attend(q, k, v):
-                nonlocal k_l, v_l
-                for t in range(T):  # static and small (1 + draft length)
-                    k_l = write_token(k_l, table, ctx_lens + t, k[:, t])
-                    v_l = write_token(v_l, table, ctx_lens + t, v[:, t])
-                if attention_impl == "paged" and T == 1:
-                    return paged_attention(
-                        q[:, 0], k_l, v_l, table, ctx_lens, window=window,
-                        mesh=mesh,
-                        work=shared["work"].get(_pages_of(kind)))[:, None]
-                # chunk position t writes at positions[s, t] then attends
-                # keys 0..positions[s, t] inclusive — the causal triangle
-                # across the chunk plus the context below it; table padding
-                # beyond a slot's blocks gathers null-block garbage this
-                # never admits
-                mask = key_idx <= positions[:, :, None]
-                if window is not None:
-                    mask &= key_idx > positions[:, :, None] - window
-                kd = gather_blocks(k_l, table, cfg.dtype, cfg.kv_heads)
-                vd = gather_blocks(v_l, table, cfg.dtype, cfg.kv_heads)
-                return xla_attention(q, kd, vd, causal=False,
-                                     mask=mask[:, None])
-
-            adapted = None
-            if ad:
-                def adapted(tensor, site, inp, rotate):
-                    if site not in ad:
-                        return tensor
-                    a = factor_rows(ad[site]["a"], adapter_ids)  # [S,d_in,r]
-                    b = factor_rows(ad[site]["b"], adapter_ids)  # [S,r,d_out]
-                    d = lora_scaling * jnp.einsum(
-                        "str,sro->sto", jnp.einsum("std,sdr->str", inp, a), b)
-                    d = d.reshape(tensor.shape)
-                    if rotate:
-                        d = rope(d, positions, cfg.rope_theta)
-                    return (tensor.astype(jnp.float32) + d).astype(
-                        tensor.dtype)
-
-            x, counters = _layer(cfg, lp, kind, sparse, x, positions,
-                                 jnp.broadcast_to(
-                                     shared["active"][:, None], (S, T)),
-                                 attend, adapted=adapted)
-            return x, k_l, v_l, counters
-
-        return linear_fn if kind == "linear_attention" else fn
+        return fn
 
     extras = None
     if adapters:  # one layer's factors a layer
         extras = [jax.tree.map(lambda a: a[i], adapters)
                   for i in range(cfg.n_layers)]
-    # per-slot, per-chunk-offset absolute positions
-    x = _embed(params, cfg, tok, ctx_lens[:, None] + jnp.arange(T)[None, :])
+    x = _embed(params, cfg, tok, positions)
     x, kv, stats = _walk(cfg, params, kv, x, layer_fn, shared, extras)
     return kv, _logits(params, cfg, x), jnp.concatenate(
         [_moe_counters(stats), grid])
@@ -399,6 +483,20 @@ def step_output(n_slots: int, n_tok: int = 1) -> jax.Array:
     return jnp.zeros((n_slots + n_slots * n_tok + N_COUNTERS,), jnp.int32)
 
 
+def _step_operands(packed, prev, n_tok: int = 1):
+    """``pack_step``'s [S, MB + T + 3] array taken apart, each slot's token
+    from where its flag says it lies (T == 1): ``(tables, ctx_lens, tok
+    [S, T], active, the first tokens as prev had them)``."""
+    S = packed.shape[0]
+    MB = packed.shape[1] - n_tok - 3
+    tok, source = packed[:, MB:MB + n_tok], packed[:, -2]
+    firsts = prev[:S]
+    if n_tok == 1:
+        tok = jnp.where(source == TOKEN_PREV, prev[S:2 * S], jnp.where(
+            source == TOKEN_FIRST, firsts, tok[:, 0]))[:, None]
+    return packed[:, :MB], packed[:, -3], tok, source > 0, firsts
+
+
 def decode_step(params, kv, packed, prev, win_tables, adapters, rng, *,
                 cfg: TransformerConfig, sample: SampleConfig, n_tok: int = 1,
                 **kw):
@@ -414,15 +512,8 @@ def decode_step(params, kv, packed, prev, win_tables, adapters, rng, *,
     ``TOKEN_PREV`` decodes its own token of ``prev`` and one flagged
     ``TOKEN_FIRST`` its first token there, whatever the operand holds: the
     same shapes every step."""
-    S = packed.shape[0]
-    MB = packed.shape[1] - n_tok - 3
-    tables, tok = packed[:, :MB], packed[:, MB:MB + n_tok]
-    ctx_lens, source = packed[:, -3], packed[:, -2]
-    active = source > 0
-    firsts = prev[:S]
-    if n_tok == 1:
-        tok = jnp.where(source == TOKEN_PREV, prev[S:2 * S], jnp.where(
-            source == TOKEN_FIRST, firsts, tok[:, 0]))[:, None]
+    tables, ctx_lens, tok, active, firsts = _step_operands(packed, prev,
+                                                           n_tok)
     kv, logits, counters = decode_logits(
         params, kv, tables, win_tables, ctx_lens, tok, active, adapters,
         packed[:, -1], cfg=cfg, **kw)
@@ -516,65 +607,113 @@ def prefill_chunk(params, kv, packed, win_row, *, cfg: TransformerConfig,
     chunk as it lands, so later chunks, decode and any request that reuses
     these pages through the prefix cache all read the same (q, scale)
     pairs.  Returns ``(kv, logits)``."""
-    MB = max_blocks
-    C = packed.shape[0] - MB - 3
-    table_row, tokens = packed[:MB], packed[MB:MB + C][None]
-    pos0, last_idx = packed[-3], packed[-2]
-    shared = {"rows": {"pages": table_row}, "pos0": pos0,
-              "last_idx": last_idx, "row": 1 + packed[-1]}
-    if win_row.shape[0]:
-        shared["rows"]["ring"] = win_row[jnp.arange(MB) % win_row.shape[0]]
+    shared = _chunk_shared(packed, win_row, max_blocks)
+    C = shared["real"].shape[0]
+    tokens, pos0 = packed[max_blocks:max_blocks + C][None], shared["pos0"]
+    positions = pos0 + jnp.arange(C)[None, :]
 
     def layer_fn(kind, sparse):
-        window = cfg.layer_window(kind)
-
-        def linear_fn(lp, state, tails, x, _, shared):
-            row, last_idx = shared["row"], shared["last_idx"]
-            fresh = shared["pos0"] == 0  # a prompt starts from zeros
-            valid = (jnp.arange(C) <= last_idx)[None, :]
-
-            def attend(qkv, h):
-                nonlocal state, tails
-                q, k, v, g, beta, full = qkv(
-                    h, jnp.where(fresh, 0, tails[row])[None])
-                # the tail the next call reads: the last K - 1 real rows
-                tails = tails.at[row].set(jax.lax.dynamic_slice_in_dim(
-                    full[0], last_idx + 1, tails.shape[1]).astype(
-                        tails.dtype))
-                real = valid[0][:, None]
-                o, new = gated_delta_chunk(
-                    q[0], k[0], v[0], jnp.where(real, g[0], 0.0),
-                    jnp.where(real, beta[0], 0.0),
-                    jnp.where(fresh, 0.0, state[row]))
-                state = state.at[row].set(new)
+        def fn(lp, a, b, x, _, shared):  # a layer's pair of pool arrays
+            def attend(*rows):
+                nonlocal a, b
+                if kind == "linear_attention":
+                    convolve, pre, g, beta = rows
+                    o, a, b = _chunk_state(shared, a, b, convolve, pre[0],
+                                           g[0], beta[0])
+                else:
+                    o, a, b = _chunk_attention(cfg, kind, shared, a, b,
+                                               *(r[0] for r in rows))
                 return o[None]
 
-            x, _ = _layer(cfg, lp, kind, sparse, x, None, valid, attend,
-                          moe=moe_decode)
-            return x, state, tails, None
-
-        def fn(lp, k_l, v_l, x, _, shared):
-            row, pos0 = shared["rows"][_pages_of(kind)], shared["pos0"]
-            positions = pos0 + jnp.arange(C)[None, :]
-            valid = (jnp.arange(C) <= shared["last_idx"])[None, :]
-
-            def attend(q, k, v):
-                nonlocal k_l, v_l
-                k_l = write_chunk(k_l, row, pos0, k[0])
-                v_l = write_chunk(v_l, row, pos0, v[0])
-                return chunk_attention(q[0], k_l, v_l, row, pos0, window,
-                                       cfg.kv_heads)[None]
-
             x, _counters = _layer(cfg, lp, kind, sparse, x, positions,
-                                  valid, attend, moe=moe_decode)
-            return x, k_l, v_l, None
+                                  shared["real"][None], attend,
+                                  moe=moe_decode)
+            return x, a, b, None
 
-        return linear_fn if kind == "linear_attention" else fn
+        return fn
 
-    x = _embed(params, cfg, tokens, pos0 + jnp.arange(C)[None, :])
+    x = _embed(params, cfg, tokens, positions)
     x, kv, _ = _walk(cfg, params, kv, x, layer_fn, shared)
-    last = jax.lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
+    last = jax.lax.dynamic_index_in_dim(x, shared["last_idx"], axis=1,
+                                        keepdims=False)
     return kv, _logits(params, cfg, last)
+
+
+def pack_chunk_and_step(chunk: np.ndarray, step: np.ndarray) -> np.ndarray:
+    """The operands of ``pack_chunk`` and of ``pack_step`` (T == 1) as ONE
+    int32 vector: one upload for the call that takes both."""
+    return np.concatenate([chunk, step.reshape(-1)])
+
+
+def chunk_and_step(params, kv, packed, prev, win_row, win_tables, rng, *,
+                   cfg: TransformerConfig, sample: SampleConfig,
+                   max_blocks: int, chunk: int,
+                   attention_impl: str = "paged", mesh=None, spec=None):
+    """A prefill chunk of ONE slot and a decode step of the OTHERS in one
+    walk of the layers: ``prefill_chunk`` on the first ``max_blocks + chunk
+    + 3`` of ``packed`` and ``win_row``, ``decode_step`` (T == 1, no
+    tenants) on the rest, ``prev``, ``win_tables`` and ``rng``
+    (``pack_chunk_and_step``).  The C + S rows share each layer's norms,
+    projections, FFN (an expert layer routes them together: each touched
+    expert is read once) and the head; only what touches the cache differs
+    by group, and that is the two programs' own (``_chunk_*``, ``_step_*``).
+    The chunk's slot is not among the decoding ones (its flag in the step's
+    operands is 0: a null table, the null row), so no row reads what
+    another row of the call writes.  Returns ``(kv, the step's output as
+    decode_step gives it, the chunk's last real row's logits [1, V])``; the
+    expert counters in the output are of all the rows."""
+    MB, C = max_blocks, chunk
+    cut = MB + C + 3
+    tables, ctx_lens, tok, active, firsts = _step_operands(
+        packed[cut:].reshape(-1, MB + 4), prev)
+    S = active.shape[0]
+    kv = _pool_constraint(kv, mesh, spec)
+    shared = {"chunk": _chunk_shared(packed[:cut], win_row, MB)}
+    shared["step"], grid = _step_shared(
+        cfg, kv, tables, win_tables, ctx_lens, active, None, 1,
+        attention_impl)
+    pos0, real = shared["chunk"]["pos0"], shared["chunk"]["real"]
+    # one sequence of C + S rows: the chunk's, then a row a slot
+    positions = jnp.concatenate([pos0 + jnp.arange(C), ctx_lens])[None]
+    valid = jnp.concatenate([real, active])[None]
+
+    def layer_fn(kind, sparse):
+        def fn(lp, a, b, x, _, shared):  # a layer's pair of pool arrays
+            def attend(*rows):
+                nonlocal a, b
+                if kind == "linear_attention":
+                    convolve, *rows = rows
+                    oc, a, b = _chunk_state(shared["chunk"], a, b, convolve,
+                                            *(r[0, :C] for r in rows))
+                    os_, a, b = _step_state(shared["step"], a, b, convolve,
+                                            *(r[0, C:, None] for r in rows))
+                else:
+                    oc, a, b = _chunk_attention(cfg, kind, shared["chunk"],
+                                                a, b,
+                                                *(r[0, :C] for r in rows))
+                    os_, a, b = _step_attention(
+                        cfg, kind, shared["step"], a, b,
+                        *(r[0, C:, None] for r in rows),
+                        attention_impl=attention_impl, mesh=mesh)
+                return jnp.concatenate(
+                    [oc, os_[:, 0].astype(oc.dtype)])[None]
+
+            x, counters = _layer(cfg, lp, kind, sparse, x, positions, valid,
+                                 attend)
+            return x, a, b, counters
+
+        return fn
+
+    x = _embed(params, cfg, jnp.concatenate(
+        [packed[MB:MB + C], tok[:, 0]])[None], positions)
+    x, kv, stats = _walk(cfg, params, kv, x, layer_fn, shared)
+    # the head once: the S decode rows, then the chunk's last real row
+    last = jax.lax.dynamic_index_in_dim(
+        x[0], shared["chunk"]["last_idx"], keepdims=True)
+    logits = _logits(params, cfg, jnp.concatenate([x[0, C:], last]))
+    out = jnp.where(active, _sample(logits[:S], rng, sample), 0)
+    return kv, jnp.concatenate(
+        [firsts, out, _moe_counters(stats), grid]), logits[S:]
 
 
 def prefill_chunk_lora(params, lora, kv, packed, win_row, *,

@@ -397,20 +397,32 @@ class GatedDeltaMixer(nn.Module):
         self.dt_bias = self.param("dt_bias", dt_bias, (H,))
         self.o_norm = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32)
 
-    def qkv(self, x, tail=None):
-        """``x`` [B, T, d], the layer's input, and ``tail`` [B, K - 1, D],
-        the projections of the K - 1 tokens before it as ``full`` had them
+    def project(self, x):
+        """What the mixer takes from each row of ``x`` [B, T, d] alone:
+        ``(pre [B, T, D], g, beta [B, T, H] float32)``, the q, k and v
+        projections side by side before the convolution, the log-decay and
+        the write strength."""
+        cfg = self.cfg
+        pre = jnp.concatenate(
+            [p(x).reshape(*x.shape[:2], -1)
+             for p in (self.q_proj, self.k_proj, self.v_proj)], -1)
+        beta = nn.sigmoid(self.b_proj(x).astype(jnp.float32))
+        if cfg.linear_neg_eigval:
+            beta = beta * 2.0
+        g = -jnp.exp(self.A_log) * nn.softplus(
+            self.a_proj(x).astype(jnp.float32) + self.dt_bias)
+        return pre, g, beta
+
+    def convolve(self, pre, tail=None):
+        """``pre`` [B, T, D] of ``project`` and ``tail`` [B, K - 1, D], the
+        projections of the K - 1 tokens before it as ``full`` had them
         (None: the sequence starts here).  Returns ``(q, k [B, T, H, d_k],
-        v [B, T, H, d_v], g, beta [B, T, H] float32, full [B, K - 1 + T,
-        D])``: q and k convolved, normalised a head, q scaled; ``g`` the
-        log-decay; ``full`` the tail and this call's projections, whose
-        last K - 1 rows are the next call's tail."""
+        v [B, T, H, d_v], full [B, K - 1 + T, D])``: q and k convolved,
+        normalised a head, q scaled; ``full`` the tail and this call's
+        projections, whose last K - 1 rows are the next call's tail."""
         cfg = self.cfg
         H, dk = cfg.linear_value_heads, cfg.linear_key_head_dim
-        lead = x.shape[:2]
-        pre = jnp.concatenate(
-            [p(x).reshape(*lead, -1)
-             for p in (self.q_proj, self.k_proj, self.v_proj)], -1)
+        lead = pre.shape[:2]
         if tail is None:
             tail = jnp.zeros((lead[0], cfg.linear_conv_kernel - 1,
                               pre.shape[-1]), pre.dtype)
@@ -420,13 +432,15 @@ class GatedDeltaMixer(nn.Module):
         q = l2norm(q.reshape(*lead, H, dk)) * dk ** -0.5
         k = l2norm(k.reshape(*lead, H, dk))
         v = v.reshape(*lead, H, -1)
-        beta = nn.sigmoid(self.b_proj(x).astype(jnp.float32))
-        if cfg.linear_neg_eigval:
-            beta = beta * 2.0
-        g = -jnp.exp(self.A_log) * nn.softplus(
-            self.a_proj(x).astype(jnp.float32) + self.dt_bias)
         return (q.astype(cfg.dtype), k.astype(cfg.dtype),
-                v.astype(cfg.dtype), g, beta, full)
+                v.astype(cfg.dtype), full)
+
+    def qkv(self, x, tail=None):
+        """``project`` then ``convolve``, for one sequence a batch row:
+        ``(q, k, v, g, beta, full)``."""
+        pre, g, beta = self.project(x)
+        q, k, v, full = self.convolve(pre, tail)
+        return q, k, v, g, beta, full
 
     def out_proj(self, o, x):
         """``o`` [B, T, H, d_v] float32, what the state gave; ``x`` the

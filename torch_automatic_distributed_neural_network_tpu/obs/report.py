@@ -423,6 +423,24 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                     e.get("discarded_tokens") or 0 for e in counted)
                 serving["step_new_tokens"] = sum(
                     e.get("new_tokens") or 0 for e in counted)
+            # a step's decode rows may ride in its prefill chunk, one
+            # program for both (engines that journal the two counters):
+            # the share of the steps with a chunk that carried them, and
+            # of the decode rows read that rode in one (a read brings a
+            # token or a slot-step thrown away a row, and a first token a
+            # prompt whose prefill ended)
+            fused = [e for e in ssteps if e.get("fused")]
+            if fused:
+                rows_read = (
+                    sum((e.get("new_tokens") or 0)
+                        + (e.get("discarded_tokens") or 0) for e in ssteps)
+                    - sum(1 for e in schunks if e.get("done")))
+                serving["fused_steps"] = len(fused)
+                serving["fused_share_of_chunk_steps"] = len(fused) / sum(
+                    1 for e in ssteps if e.get("n_prefill_chunks"))
+                serving["fused_share_of_decode_rows"] = sum(
+                    e.get("fused_decode_rows") or 0
+                    for e in fused) / max(1, rows_read)
             # the two kinds of decoding step behind the ITL's two
             # modes: a step that also ran prefill chunks, and one that
             # only decoded
@@ -1039,6 +1057,13 @@ def format_report(report: dict) -> str:
                 f"slot-step(s) decoded and thrown away"
                 + (f" ({thrown / (kept + thrown):.2%} of {kept + thrown})"
                    if kept + thrown else ""))
+        if sv.get("fused_steps"):
+            lines.append(
+                f"  {sv['fused_steps']} prefill chunk(s) carried the decode "
+                f"rows of their step, one program for both: "
+                f"{sv['fused_share_of_chunk_steps']:.1%} of the steps with "
+                f"a chunk, {sv['fused_share_of_decode_rows']:.1%} of the "
+                f"decode rows")
         if sv.get("decode_steps_with_chunk"):
             only = sv.get("mean_step_decode_only_s")
             lines.append(

@@ -378,7 +378,14 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # work list) and the slots x groups a dense grid would have
             # run, summed over the layers; 0 and 0 where no call takes a
             # work list (dense attention, int8 or mesh-sharded pools)
-            "attn_grid_items": "int", "attn_grid_dense": "int"}),
+            "attn_grid_items": "int", "attn_grid_dense": "int",
+            # 1 where this call's decode rows went through the layers in
+            # its prefill chunk, one program for both (a colocated,
+            # non-speculative engine without tenants, a chunk to run and a
+            # slot decoding), and how many rows; the three expert counters
+            # above are then left out of the call that reads them: the
+            # layers routed the chunk's rows with them
+            "fused": "int", "fused_decode_rows": "int"}),
     _s("serve.request_done", "per-request completion span with the "
        "full phase-attributed timeline", version=2,
        req={"rid": "int", "n_prompt": "int", "n_new": "int",
