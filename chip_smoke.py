@@ -20,12 +20,15 @@ from ``--seed``):
    which the 1.3B model does not reach: the folded paged kernel at 30 query
    heads on 30 KV heads of 128 with every slot full, and the gated delta
    rule's chunk and step kernels (30 heads, keys of 96, values of 192)
-   against the token-by-token recurrence, compiled and not interpreted.
+   against the token-by-token recurrence, compiled and not interpreted;
+   and the latent decode kernel at JoyAI-LLM-Flash's widths (32 heads over
+   rows of 512 + 64 numbers, 24 slots of 34,816: empty, partly filled,
+   every slot full) against plain ``jax.numpy``, with a call's time at each.
 4. **fused** — the serving program in which a step's decode rows ride in
-   a prefill chunk (``programs.chunk_and_step``), at the widths of the three
+   a prefill chunk (``programs.chunk_and_step``), at the widths of the four
    serving configurations and a few layers of each (16 heads of 128; 48
    query heads on 8 KV heads with a ring and experts; 30 heads of 128 with
-   state rows), against the chunk and the step as two calls on the same
+   state rows; 32 heads over latent pages with experts), against the chunk and the step as two calls on the same
    pool: the chunk's logits, the step's tokens and every pool array.
 5. **cache** — where the persistent compile cache is, who placed it, and
    how many entries it held before and after.
@@ -441,10 +444,111 @@ def hybrid_kernels_phase(*, seed: int, on_chip: bool) -> dict:
     for r in (0, S + 1):  # the null row and a row no slot has
         if not bool(jnp.array_equal(p1[r], pool0[r])):
             raise RuntimeError(f"gdn step: row {r} was written")
+    latent_kernel_checks(rec, close, next(keys), on_chip)
     return rec
 
 
-# the three serving configurations at their published widths and a few of
+def latent_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:
+    """The latent decode kernel at JoyAI-LLM-Flash's widths on the chip (32
+    heads over rows of 512 + 64 numbers stored in 640 lanes, 24 slots of
+    34,816 in pages of 64): every slot empty, partly filled, and every slot
+    at ``max_len`` (the work list as long as its arrays), float32 queries
+    against plain ``jax.numpy``; then, on the chip, a call's time at each
+    (serving's bfloat16 queries, 20 calls), and a chunk's attention over a
+    16k context in the expanded form the program runs and in the absorbed
+    form it does not (PERF.md section 6 says which and why)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torch_automatic_distributed_neural_network_tpu.inference.serve import (
+        programs,
+    )
+    from torch_automatic_distributed_neural_network_tpu.ops import (
+        paged_attention as pa,
+    )
+
+    H, r, rot, n, S, MB, bs = (32, 512, 64, 128, 24, 544, 64) if on_chip \
+        else (4, 16, 4, 8, 3, 24, 4)
+    lanes, scale = -(-(r + rot) // 128) * 128, (n + rot) ** -0.5
+    keys = iter(jax.random.split(key, 8))
+    pool = jnp.pad(
+        (0.5 * jax.random.normal(next(keys), (S * MB + 1, bs, r + rot),
+                                 jnp.float32)).astype(jnp.bfloat16),
+        ((0, 0), (0, 0), (0, lanes - r - rot)))
+    none = jnp.zeros((0,), pool.dtype)
+    tables = jnp.asarray(1 + np.arange(S * MB).reshape(S, MB), jnp.int32)
+    q = jax.random.normal(next(keys), (S, H, r + rot), jnp.float32)
+    kernel = jax.jit(lambda q, pool, t, c: pa.paged_attention(
+        q, pool, none, t, c, scale=scale, value_dim=r,
+        interpret=not on_chip))
+    dense = jax.jit(lambda q, pool, t, c: pa.latent_attention_reference(
+        q[:, None], pool[t].reshape(S, MB * bs, lanes), c, scale=scale,
+        value_dim=r)[:, 0])
+    cases = (("latent_empty", jnp.zeros((S,), jnp.int32)),
+             ("latent_mixed", (jnp.arange(S) * 1531 % (MB * bs)).astype(
+                 jnp.int32)),
+             ("latent_full", jnp.full((S,), MB * bs - 1, jnp.int32)))
+    for name, ctx in cases:
+        with jax.default_matmul_precision("highest"):
+            want = dense(q, pool, tables, ctx)
+        close(name, kernel(q, pool, tables, ctx), want)
+    if not on_chip:
+        return
+    lo = q.astype(jnp.bfloat16)
+    for name, ctx in cases:
+        jax.block_until_ready(kernel(lo, pool, tables, ctx))
+        t0 = time.perf_counter()
+        for _ in range(20):
+            out = kernel(lo, pool, tables, ctx)
+        jax.block_until_ready(out)
+        rec[name + "_us_a_call"] = 1e6 * (time.perf_counter() - t0) / 20
+        rec[name + "_keys"] = int(jnp.sum(ctx + 1))
+    # a chunk of 512 queries at positions 15,872..16,383 of slot 0
+    C, pos0 = 512, 16384 - 512
+    w_uk, w_uv = (0.02 * jax.random.normal(next(keys), (r, H, n))).astype(
+        jnp.bfloat16), (0.02 * jax.random.normal(
+            next(keys), (r, H, n))).astype(jnp.bfloat16)
+    q_nope = jax.random.normal(next(keys), (C, H, n)).astype(jnp.bfloat16)
+    q_rope = jax.random.normal(next(keys), (C, H, rot)).astype(jnp.bfloat16)
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def rows_of(ids):
+        return pool[ids].reshape(-1, lanes)
+
+    def expanded(ids):
+        rows = rows_of(ids)
+        k_nope = jnp.einsum("tc,chn->thn", rows[:, :r], w_uk)
+        v = jnp.einsum("tc,chn->thn", rows[:, :r], w_uv)
+        s = (jnp.einsum("chd,thd->hct", q_nope, k_nope, **f32) + jnp.einsum(
+            "chd,td->hct", q_rope, rows[:, r:r + rot], **f32)) * scale
+        return s, lambda p: jnp.einsum("hct,thd->hcd", p.astype(v.dtype), v,
+                                       **f32)
+
+    q_lat = jnp.concatenate(
+        [jnp.einsum("chn,rhn->chr", q_nope, w_uk), q_rope], -1)
+
+    def absorbed(ids):
+        rows = rows_of(ids)
+        s = jnp.einsum("chf,tf->hct", q_lat, rows[:, :r + rot], **f32) * scale
+        return s, lambda p: jnp.einsum(
+            "hct,tr->hcr", p.astype(rows.dtype), rows[:, :r], **f32)
+
+    for name, block, dv in (("chunk_expanded", expanded, n),
+                            ("chunk_absorbed", absorbed, r)):
+        fn = jax.jit(lambda row, block=block, dv=dv: programs._over_key_blocks(
+            row, bs, C, pos0, None, (H,), dv, block))
+        jax.block_until_ready(fn(tables[0]))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            out = fn(tables[0])
+        jax.block_until_ready(out)
+        rec[f"latent_{name}_16k_ms"] = 1e3 * (time.perf_counter() - t0) / 5
+
+
+# the four serving configurations at their published widths and a few of
 # their layers (what is cut is depth, vocabulary, the number of experts and
 # the window: no width), for ``fused_phase``
 FUSED_CUTS = {
@@ -456,6 +560,9 @@ FUSED_CUTS = {
     "olmo-hybrid-7b-pp2": dict(
         n_layers=3, vocab_size=8192, layer_types=[
             "linear_attention", "linear_attention", "full_attention"]),
+    "joyai-llm-flash-ep8": dict(
+        n_layers=3, n_dense_layers=1, vocab_size=8192, experts_held=8,
+        experts_published=64, layer_types=["latent_attention"] * 3),
 }
 # bf16 layers: rows of one product taken C + S at a time against C and S
 FUSED_RTOL = 0.03
@@ -489,12 +596,14 @@ def fused_phase(name: str, *, seed: int, on_chip: bool) -> dict:
         config = json.load(f)
     cuts = FUSED_CUTS[name]
     keys = {**config["model"], **cuts}
-    S, C, bs, max_len = 4, 128, 16, 1024
+    latent = "latent_attention" in (cuts.get("layer_types") or ())
+    # (latent pages are 64 tokens at the cell: 8 page copies a kernel step)
+    S, C, bs, max_len = 4, 128, 64 if latent else 16, 1024
     if not on_chip:  # the configuration's rehearsal sizes at the cut's depth
         keys = {**keys, **config["rehearsal"]["model"],
                 "n_layers": cuts["n_layers"],
                 "layer_types": cuts.get("layer_types")}
-        C, max_len = 16, 128
+        C, bs, max_len = 16, 16, 128
     keys["max_seq_len"] = max(max_len, keys["max_seq_len"])
     cfg = TransformerConfig(
         **keys, dtype=jnp.bfloat16 if on_chip else jnp.float32, remat=False)
@@ -584,6 +693,8 @@ def fused_phase(name: str, *, seed: int, on_chip: bool) -> dict:
     for side in ("k", "v"):
         for i, (a, b) in enumerate(zip(kv_a[side], kv_b[side])):
             for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+                if not x.size:  # nothing lies beside a layer's latent pages
+                    continue
                 close("pool" if i <= first_sparse else "pool_past_experts",
                       y, x, flips=on_chip and i > first_sparse)
     lg = np.asarray(lg_step[:, 0], np.float32)
@@ -602,9 +713,11 @@ def fused_phase(name: str, *, seed: int, on_chip: bool) -> dict:
             jnp.asarray(prev), win[S - 1], win,
             jax.random.key(0)).compile().as_text()
         rec["custom_calls"] = text.count("tpu_custom_call")
-        if "tadnn_paged_decode_folded" not in text:
-            raise RuntimeError(f"fused {name}: the paged kernel is not in "
-                               "the compiled program")
+        kernel = ("tadnn_paged_decode_latent" if latent
+                  else "tadnn_paged_decode_folded")
+        if kernel not in text:
+            raise RuntimeError(f"fused {name}: {kernel} is not in the "
+                               "compiled program")
     return rec
 
 

@@ -283,6 +283,13 @@ def hybrid(bench):
     return _serve(bench, _cell_of("olmo-hybrid-7b-pp2"))
 
 
+@pytest.fixture(scope="module")
+def latent(bench):
+    """A ``layer_types`` model of ``latent_attention`` layers with held
+    experts."""
+    return _serve(bench, _cell_of("joyai-llm-flash-ep8"))
+
+
 # field of a ``serve.step`` event -> who indexes it (benchmark/ paths)
 STEP_FIELDS = {
     "decode_s": "lib/readers.py:13 lib/serve_phases.py:77 lib/counts_moe.py:47",
@@ -315,6 +322,17 @@ ENGINE_FIELDS_LINEAR = {
     "conv_bytes_linear": "metrics/state_pool_gib.py:16,19",
     "kv_bytes_full": "metrics/state_pool_gib.py:17 metrics/kv_pool_gib.py:10",
     "layer_kinds": "metrics/kv_pool_gib.py:15",
+}
+# the same, on a model with latent layers only: what ``kv_pool_gib`` adds up
+# (latent pages are pages for ``max_len``: in ``kv_bytes_full``), and what
+# says how much of it is latent and what a page's row is (``tadnn report``)
+ENGINE_FIELDS_LATENT = {
+    "kv_bytes_full": "metrics/kv_pool_gib.py:10,13,16",
+    "kv_bytes_window": "metrics/kv_pool_gib.py:10,14,16",
+    "kv_bytes_latent": "obs/report.py (the latent line beside the pool's)",
+    "latent_row": "obs/report.py (the latent line beside the pool's)",
+    "layer_kinds": "metrics/kv_pool_gib.py:15",
+    "experts_held": "lib/serving_large.py:39-40,300",
 }
 # attribute of the engine -> who takes it
 ENGINE_ATTRS = {
@@ -398,6 +416,34 @@ def test_serve_engine_says_what_a_hybrid_cell_is_about(hybrid):
     assert ev["layer_kinds"] == list(kinds)
     assert eng.pool.n_full == list(kinds).count("full_attention") > 0
     assert eng.pool.state.count(True) == list(kinds).count("linear_attention")
+
+
+@pytest.mark.parametrize("field", sorted(ENGINE_FIELDS_LATENT))
+def test_serve_engine_of_a_latent_model_carries_its_counters(latent, field):
+    ev = latent["record"]["serve_engine"]
+    assert ev is not None, "no serve.engine event (Journal.named)"
+    assert ev.get(field) is not None, (
+        f"serve.engine has no {field!r}; read by " + ENGINE_FIELDS_LATENT[field])
+
+
+def test_serve_engine_says_what_a_latent_cell_is_about(latent):
+    """Latent pages are the allocator's pages for ``max_len``: all of
+    ``kv_bytes_full`` here, which ``kv_pool_gib`` reads unedited; a row is
+    the latent and the rotated key part, stored in whole tiles of lanes; the
+    step's counters hold the latent kernel's grid and the expert layer's
+    pairs."""
+    ev, eng = latent["record"]["serve_engine"], latent["eng"]
+    keys = latent["record"]["model_keys"]
+    assert ev["kv_bytes_full"] == ev["kv_bytes_latent"] > 0
+    assert ev["kv_bytes_window"] == 0 == ev["state_bytes_linear"]
+    assert ev["latent_row"][:2] == [keys["latent_kv_rank"],
+                                    keys["latent_rope_head_dim"]]
+    assert ev["latent_row"][2] % 128 == 0
+    assert set(ev["layer_kinds"]) == {"latent_attention"}
+    assert eng.pool.n_full == keys["n_layers"] == eng.pool.latent.count(True)
+    steps = _steps(latent)
+    assert any(s.get("attn_grid_items") for s in steps)
+    assert any(s.get("moe_pairs") is not None for s in steps)
 
 
 def test_serve_engine_says_what_the_cell_is_about(experts):
@@ -544,6 +590,7 @@ def test_the_names_were_found():
     assert any(k.startswith("tadnn_paged_decode") for k in KERNELS)
     assert any(k.startswith("tadnn_moe_grouped_mm") for k in KERNELS)
     assert {"tadnn_gdn_chunk", "tadnn_gdn_step"} <= set(KERNELS)
+    assert "tadnn_paged_decode_latent" in KERNELS
     assert len(PROGRAMS) >= 2 and len(WAITS) >= 1
 
 
@@ -552,8 +599,8 @@ def test_the_names_were_found():
 
 @pytest.mark.parametrize("metric_file", METRIC_FILES)
 def test_a_metric_reader_reads_the_programs_record(bench, dense, experts,
-                                                   hybrid, metric_file,
-                                                   capsys):
+                                                   hybrid, latent,
+                                                   metric_file, capsys):
     reader = _load(os.path.join(BENCH, "metrics", metric_file),
                    "bench_metric")
     assert callable(getattr(reader, "read", None)), metric_file
@@ -562,7 +609,8 @@ def test_a_metric_reader_reads_the_programs_record(bench, dense, experts,
     configs = {w["config"] for w in BENCHMARK["workloads"]
                if w["name"] in entry["workloads"]}
     for config, run in (("gpt2-1p3b", dense), ("trinity-large-ep8", experts),
-                        ("olmo-hybrid-7b-pp2", hybrid)):
+                        ("olmo-hybrid-7b-pp2", hybrid),
+                        ("joyai-llm-flash-ep8", latent)):
         if config not in configs:
             continue
         value = reader.read(run["record"])
@@ -594,6 +642,46 @@ def test_a_reader_of_events_alone_gives_a_number_on_a_hybrid_model(
     value = reader.read(hybrid["record"])
     capsys.readouterr()
     assert value is not None and math.isfinite(value) and value > 0, name
+
+
+@pytest.mark.parametrize("name", READS_ON_CPU[::2] + ("kv_pool_gib",))
+def test_a_reader_of_events_alone_gives_a_number_on_a_latent_model(
+        bench, latent, name, capsys):
+    reader = _load(os.path.join(BENCH, "metrics", name + ".py"),
+                   "bench_metric")
+    value = reader.read(latent["record"])
+    capsys.readouterr()
+    assert value is not None and math.isfinite(value) and value > 0, name
+
+
+@pytest.mark.parametrize("name", ["latent_attn_roofline",
+                                  "latent_attn_decode_ms"])
+def test_a_latent_reader_finds_nothing_where_there_is_nothing(
+        bench, latent, experts, name, capsys):
+    """No device trace on the CPU, and no latent layer in another model: the
+    readers this configuration brought return None and do not raise (what a
+    parent commit's traced run of the cell's readers has to do)."""
+    reader = _load(os.path.join(BENCH, "metrics", name + ".py"),
+                   "bench_metric")
+    for run in (latent, experts):
+        assert reader.read(run["record"]) is None
+        assert reader.read({**run["record"], "trace": {"n_devices": 0}}) is None
+    capsys.readouterr()
+
+
+def test_the_latent_counts_are_the_arithmetic(bench):
+    """69.6 kFLOP and 1,152 B a key a layer at the published widths: 60
+    FLOP/B, under the v5e's ridge of 240."""
+    from lib import counts_mla
+
+    keys = CONFIGS["joyai-llm-flash-ep8"]["model"]
+    n, heads, row, value = counts_mla.latent_layers(keys)
+    assert (n, heads, row, value) == (20, 32, 576, 512)
+    flops = counts_mla.latent_attention_flops(1, heads, row, value)
+    bytes_ = counts_mla.latent_attention_bytes(1, row, itemsize=2)
+    assert (flops, bytes_) == (69632.0, 1152.0)
+    assert counts_mla.latent_layers(CONFIGS["gpt2-1p3b"]["model"]) \
+        == (0, 0, 0, 0)
 
 
 def test_a_kind_that_exchanges_names_finds_them(bench):

@@ -184,7 +184,8 @@ _GPT2_1P3B = dict(
 # of the benchmark's serving cells
 _SERVING = {"gpt2-1p3b": (8, 1024, 16, 128, None),
             "trinity-large-ep8": (16, 13312, 16, 512, None),
-            "olmo-hybrid-7b-pp2": (8, 33792, 16, 512, 4609)}
+            "olmo-hybrid-7b-pp2": (8, 33792, 16, 512, 4609),
+            "joyai-llm-flash-ep8": (24, 34816, 64, 512, 4097)}
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk",
@@ -204,7 +205,10 @@ def test_serving_programs_update_the_pool_in_place(
     weights) and ``olmo-hybrid-7b-pp2`` (16 layers, 12 of them linear: a
     recurrent state and a convolution tail a slot beside 4,609 pages of
     keys and values for the 4 full layers, 8 slots of 33,792 beside 9.1 GiB
-    of weights) alike: no layer's weight is converted, the pool is updated
+    of weights) and ``joyai-llm-flash-ep8`` (20 latent layers, 19 of them
+    with 32 of 256 experts: 4,097 pages of 64 latent rows stored in 640
+    lanes, one array a layer, 24 slots of 34,816 beside 6.9 GiB of weights)
+    alike: no layer's weight is converted, the pool is updated
     in place (the output aliases it: pages, states and tails), and no copy
     of a layer's pages or states is among the temporaries (threaded through
     a layer scan, the pool was copied whole every step)."""
@@ -288,13 +292,18 @@ def test_serving_programs_update_the_pool_in_place(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
         operands)).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert ("tadnn_paged_decode_folded" in text) == (
-        program != "prefill_chunk")
-    assert "tadnn_paged_decode." not in text  # one kernel for both models
+    latent = config == "joyai-llm-flash-ep8"
+    mine, other = (("tadnn_paged_decode_latent", "tadnn_paged_decode_folded")
+                   if latent else
+                   ("tadnn_paged_decode_folded", "tadnn_paged_decode_latent"))
+    assert (mine in text) == (program != "prefill_chunk")
+    assert other not in text
+    assert "tadnn_paged_decode." not in text  # one kernel a kind of page
     # a weight is an entry parameter named for its path in ``params``, read
     # as it is: not converted, not copied
-    assert re.search(r"%params__layers_1____attn____q_proj____kernel__\S* = "
-                     r"bf16\[\S* parameter\(", text)
+    weight = "kv_b_proj" if latent else "q_proj"
+    assert re.search(r"%%params__layers_1____attn____%s____kernel__\S* = "
+                     r"bf16\[\S* parameter\(" % weight, text)
     assert not [l.strip()[:120] for l in text.splitlines() if re.search(
         r"= bf16\[[^\]]*\]\S* convert\(%params__layers", l)]
     pool_bytes = made["pool"].total_bytes
@@ -305,7 +314,7 @@ def test_serving_programs_update_the_pool_in_place(
     assert mem.temp_size_in_bytes < roomy * 2**30, mem.temp_size_in_bytes
     page_arrays = {("f32" if x.dtype == jnp.float32 else "bf16")
                    + "[%s]" % ",".join(map(str, x.shape))
-                   for x in jax.tree.leaves(kv)}
+                   for x in jax.tree.leaves(kv) if x.size}
     assert not [l[:100] for l in text.splitlines()
                 if " copy(" in l and any(a in l for a in page_arrays)]
     if config == "olmo-hybrid-7b-pp2":
@@ -321,6 +330,16 @@ def test_serving_programs_update_the_pool_in_place(
         assert round(made["pool"].bytes_full / 1e9, 2) == 4.53
         assert round(sum(made["pool"].bytes_state) / 1e9, 2) == 0.25
         assert mem.argument_size_in_bytes < 14.0 * 2**30
+    elif latent:
+        # 20 latent layers' kernel calls (none in the chunk alone), the
+        # grouped matmuls of 19 expert layers; 6.25 GiB of latent pages
+        assert text.count("tadnn_paged_decode_latent") >= 20 * (
+            program != "prefill_chunk")
+        assert text.count("tadnn_moe_grouped_mm") >= 38
+        assert "tadnn_gdn" not in text
+        assert made["pool"].bytes_latent == pool_bytes
+        assert round(pool_bytes / 2**30, 2) == 6.25
+        assert mem.argument_size_in_bytes < 13.3 * 2**30
     elif config == "trinity-large-ep8":
         assert text.count("tadnn_moe_grouped_mm") >= 8  # 2 kernels, 4 layers
         assert round(pool_bytes / 2**30, 2) == 1.94
@@ -372,6 +391,43 @@ def test_folded_paged_decode_compiles_for_v5e(v5e, config, window):
                  if 'custom_call_target="tpu_custom_call"' in l]
     assert "tadnn_paged_decode_folded" in kernel.split(" = ")[0]
     assert f"s32[{slots},{mb}]" in kernel.split(" = ", 1)[1]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "float32_queries"])
+def test_latent_paged_decode_compiles_for_v5e(v5e, dtype):
+    """The latent kernel at the cell's shape: 32 heads, 24 slots of 544
+    pages of 64 rows stored in 640 lanes (512 + 64 numbers and zeros), 8
+    page copies a grid step, its grid a work list of traced length; in
+    serving's bfloat16 and with ``chip_smoke.py``'s float32 queries.  The
+    pool reaches the kernel as it lies: no copy of it among the
+    temporaries (rows of 576 did get one: the chip's layout for such an
+    array puts another axis in the lanes)."""
+    from torch_automatic_distributed_neural_network_tpu.ops.paged_attention import (
+        folded_work_list,
+        latent_pages,
+    )
+
+    slots, heads, mb, bs = 24, 32, 544, 64
+    one = SingleDeviceSharding(v5e[0])
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+    pool = sds((4097, bs, 640), jnp.bfloat16)
+
+    def call(q, k, t, c, active):
+        work = folded_work_list(c, active, max_blocks=mb, block_size=bs,
+                                pages=latent_pages(mb, bs))
+        return paged_attention(q, k, jnp.zeros((0,), k.dtype), t, c,
+                               work=work, scale=192 ** -0.5, value_dim=512,
+                               interpret=False)
+
+    compiled = jax.jit(call).lower(
+        sds((slots, heads, 576), dtype), pool, sds((slots, mb), jnp.int32),
+        sds((slots,), jnp.int32), sds((slots,), jnp.bool_)).compile()
+    (kernel,) = [l for l in compiled.as_text().splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in l]
+    assert "tadnn_paged_decode_latent" in kernel.split(" = ")[0]
+    assert kernel.count("bf16[4097,64,640]") >= 8
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**24
 
 
 def test_folded_paged_decode_compiles_for_float32_queries(v5e):

@@ -311,3 +311,102 @@ def test_engine_paged_preempted_request_recomputes_correctly():
         ref, _ = _serve(model, variables, [p], max_new=max_new,
                         attention_impl="dense")
         assert req.out_tokens == ref[0], req.rid
+
+
+# -- the latent kernel: every head over ONE row a key, a page read once ---------
+
+_LBS, _LMB = 16, 96  # 512 keys a grid step: 32 pages; 3 groups, max_len 1536
+_LMAX = _LBS * _LMB
+
+# name: (contexts, the running slots or None for all)
+_LATENT_CASES = {
+    "empty": ([0, 0, 0], None),
+    "one_key_beside_idle_slots": ([0, 700, 1535], [0]),
+    "page_boundaries": ([15, 16, 17, 31], None),
+    "group_boundaries": ([511, 512, 1023, 1024], None),
+    "every_slot_full": ([_LMAX - 1] * 3, None),
+    "ragged": ([3, 130, 1400, 77, 600], None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LATENT_CASES))
+def test_latent_kernel_matches_the_dense_form(case):
+    """``paged_attention`` over latent pages (one row a token, 20 + 4
+    numbers stored in 128 lanes, values its first 20; no second array)
+    against plain ``jax.numpy`` on ragged contexts, 6 heads: the grid is
+    ``folded_work_list`` at 32 pages an item, every page outside a listed
+    group is poisoned, and with every slot at ``max_len`` the list is as
+    long as its arrays (the case that halted the chip's core in PR 30: the
+    arrays hold one entry more than the list)."""
+    from torch_automatic_distributed_neural_network_tpu.ops.paged_attention \
+        import (
+            folded_work_list,
+            is_latent,
+            latent_attention_reference,
+            latent_pages,
+        )
+
+    ctxs, running = _LATENT_CASES[case]
+    S, Hq, row, value, lanes = len(ctxs), 6, 24, 20, 128
+    running = list(range(S)) if running is None else running
+    rs = np.random.RandomState(len(case))
+    pool = np.zeros((S * _LMB + 1, _LBS, lanes), np.float32)
+    pool[..., :row] = rs.randn(S * _LMB + 1, _LBS, row)
+    tables = 1 + rs.permutation(S * _LMB).reshape(S, _LMB).astype(np.int32)
+    pages = latent_pages(_LMB, _LBS)
+    assert pages == 32
+    want_items = 0
+    for s, ctx in enumerate(ctxs):
+        hi = ctx // (pages * _LBS) if s in running else 0
+        want_items += hi + 1
+        pool[tables[s, [j for j in range(_LMB) if j // pages > hi]]] = np.nan
+        tables[s, ctx // _LBS + 1:] = 0  # the null block past the newest key
+    pool[0] = 0.0
+    none = jnp.zeros((0,), jnp.float32)
+    assert is_latent(jnp.asarray(pool), none)
+    q = jnp.asarray(rs.randn(S, Hq, row), jnp.float32)
+    ctx = jnp.asarray(ctxs, jnp.int32)
+    active = jnp.asarray([s in running for s in range(S)])
+    work = folded_work_list(ctx, active, max_blocks=_LMB, block_size=_LBS,
+                            pages=pages)
+    n = int(work.n_items)
+    assert n == want_items <= work.dense == S * 3
+    assert work.slot_of.shape[0] == work.dense + 1
+    got = paged_attention(q, jnp.asarray(pool), none, jnp.asarray(tables),
+                          ctx, scale=0.3, value_dim=value, work=work)
+    assert got.shape == (S, Hq, value)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    dense = jnp.nan_to_num(jnp.asarray(pool))[jnp.asarray(tables)].reshape(
+        S, _LMAX, lanes)
+    want = latent_attention_reference(q[:, None], dense, ctx, scale=0.3,
+                                      value_dim=value)[:, 0]
+    np.testing.assert_allclose(np.asarray(got)[running],
+                               np.asarray(want)[running], atol=1e-5)
+    if case == "every_slot_full":
+        assert n == work.dense
+    if len(running) == S:  # built where the caller gives none
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(paged_attention(
+                q, jnp.asarray(pool), none, jnp.asarray(tables), ctx,
+                scale=0.3, value_dim=value)))
+
+
+def test_latent_kernel_is_named_and_reads_a_page_once():
+    """One ``pallas_call`` named ``tadnn_paged_decode_latent`` whose page
+    operands are the ONE pool array (8 pages of 64 tokens an item at the
+    cell's block size), its grid the traced ``n_items``."""
+    S, Hq, row = 3, 4, 24
+    pool = jnp.zeros((S * 24 + 1, 64, 128), jnp.float32)
+    tables = jnp.zeros((S, 24), jnp.int32)
+
+    def run(q, ctx):
+        return paged_attention(q, pool, jnp.zeros((0,), jnp.float32), tables,
+                               ctx, scale=1.0, value_dim=20)
+
+    jaxpr = jax.make_jaxpr(run)(jnp.zeros((S, Hq, row), jnp.float32),
+                                jnp.asarray([0, 511, 1535], jnp.int32)).jaxpr
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert "tadnn_paged_decode_latent" in str(call.params)
+    pools = [v for v in call.invars if getattr(v.aval, "shape", None)
+             == pool.shape]
+    assert len(pools) == 8  # 512 keys a step, no value pages beside them

@@ -284,15 +284,16 @@ def forward_cached(
     return logits, new_cache
 
 
-_FLOAT32_LEAVES = ("router", "q_norm", "k_norm",
+_FLOAT32_LEAVES = ("router", "q_norm", "k_norm", "q_a_norm", "kv_a_norm",
                    "A_log", "dt_bias", "conv", "o_norm")
 
 
 def _cast_floats(tree: Any, dtype) -> Any:
     """Floating leaves of a (sub)tree in ``dtype``; int8 ``{"q", "scale"}``
-    leaves, a ``router``, the QK norms' gains, what a ``linear_attention``
-    mixer reads in float32 (its decay rates, filters and output norm) and
-    leaves already in ``dtype`` as they are."""
+    leaves, a ``router``, the gains of the QK norms and of a latent layer's
+    two inner norms, what a ``linear_attention`` mixer reads in float32 (its
+    decay rates, filters and output norm) and leaves already in ``dtype`` as
+    they are."""
     if is_quantized_leaf(tree):
         return tree
     if isinstance(tree, dict):

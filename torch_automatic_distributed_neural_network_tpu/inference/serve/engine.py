@@ -12,8 +12,8 @@ entry point, one trace per distinct prompt length).
 The programs are ``programs.decode_step``, ``programs.prefill_chunk``
 and ``programs.chunk_and_step`` (``serve/programs.py``): the same three for
 every model.  They walk the layers one by one, a model with one kind of
-layer and a model whose layers differ (``cfg.layer_types``: window, full and
-linear attention mixed, expert FFNs) alike, the per-layer math the TRAINING
+layer and a model whose layers differ (``cfg.layer_types``: window, full,
+linear and latent attention, expert FFNs) alike, the per-layer math the TRAINING
 modules applied piecewise, and every layer's pages, or the recurrent state
 of a ``linear_attention`` layer, updated in place.
 
@@ -142,7 +142,7 @@ from ..decode import (
 from ..speculative import accept_length, ngram_propose
 from . import programs
 from .adapters import AdapterPool
-from .kv_pool import PagedKVPool, blocks_for_tokens
+from .kv_pool import PagedKVPool, blocks_for_tokens, stored_row
 from .prefix_cache import PrefixCache
 from .scheduler import Request, Scheduler
 
@@ -309,7 +309,18 @@ class ServeEngine:
             # the state pool and its two kernels have no sharded form
             "mesh for a model with linear_attention layers (the recurrent "
             "state has no sharded form)": (
-                mesh is not None and "linear_attention" in kinds)}
+                mesh is not None and "linear_attention" in kinds),
+            # all heads read the ONE latent row a token: there is no KV head
+            # to split over the tensor axis, and the latent kernel has no
+            # per-shard form
+            "mesh for a model with latent_attention layers (one latent row "
+            "a token has no head axis to shard)": (
+                mesh is not None and "latent_attention" in kinds),
+            # int8 pages carry a scale a (token, KV head); a latent row is a
+            # normed latent beside a rotated key part, two ranges in one
+            # row, and the latent kernel does not dequantize
+            "quant_kv with latent_attention layers (a latent row has no "
+            "int8 form)": (quant_kv and "latent_attention" in kinds)}
         if any(refused.values()):
             raise ValueError("not served: " + "; ".join(
                 k for k, v in refused.items() if v))
@@ -521,7 +532,15 @@ class ServeEngine:
                           if self.cfg.n_expert_layers else 0),
             experts_published=(self.cfg.experts_published
                                if self.cfg.n_expert_layers else 0),
+            # latent pages are pages for max_len: in kv_bytes_full too
             kv_bytes_full=self.pool.bytes_full,
+            kv_bytes_latent=self.pool.bytes_latent,
+            # a latent page's row: [the latent's rank, the rotated key part,
+            # the numbers stored a token] (None without such a layer)
+            latent_row=([self.cfg.latent_kv_rank,
+                         self.cfg.latent_rope_head_dim,
+                         *stored_row(self.cfg, "latent_attention")]
+                        if "latent_attention" in kinds else None),
             kv_bytes_window=self.pool.bytes_window,
             state_bytes_linear=self.pool.bytes_state[0],
             conv_bytes_linear=self.pool.bytes_state[1])

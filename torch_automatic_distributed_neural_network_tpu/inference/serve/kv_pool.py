@@ -43,10 +43,20 @@ through :func:`gather_blocks` (table-indexed gather to a dense
 engine's ``attention_impl="dense"`` path and the oracle of every kernel
 parity test.
 
-Three kinds of layer (``cfg.layer_types``), one pair of arrays each.  A
+Four kinds of layer (``cfg.layer_types``), one pair of arrays each.  A
 full-attention layer, and every layer of a model with one kind of layer,
 has the pages above: the allocator's ids, a request's table, room for
-``max_len`` a slot.  A ``sliding_attention`` layer needs only the last
+``max_len`` a slot.  A ``latent_attention`` layer has the same pages, ids
+and table, but a page holds ONE row a token, ``[bs, kv_rank + rope]``: the
+key-value latent with the rotated key part behind it, which is key and
+value at once (``cfg.page_row``), stored in whole tiles of 128 lanes
+(``stored_row``: 576 numbers in 640, the rest zeros).  It lies under
+``"k"``; the layer's ``"v"`` is an array of no elements.  One array and not
+the two parts apart: 512 and 64 apart are 512 + 128 lanes, the same bytes
+for twice the page copies.  And whole tiles spelled out, because the chip's
+own layout for an array whose rows are 576 wide puts another axis in the
+lanes (the compiler then copies the pool before every kernel call; my
+compile for a described v5e, PR 34).  A ``sliding_attention`` layer needs only the last
 ``window`` keys, so it keeps a RING of ``window_pages`` pages a slot, owned
 by the slot for the engine's life: position ``p`` lives in ring page ``(p
 // bs) % window_pages``, and a page is written over once the window has
@@ -81,6 +91,25 @@ from ..decode import cache_partition_spec
 from ..quant import is_quantized_leaf, kv_leaf_parts, quantize_kv
 
 NULL_BLOCK = 0  # reserved scratch target for inactive-slot writes
+LANES = 128  # a latent row is stored in whole tiles of this many numbers
+
+
+def stored_row(cfg: TransformerConfig, kind: str | None) -> tuple[int, ...]:
+    """``cfg.page_row(kind)`` as a page stores it: a latent row in whole
+    tiles of ``LANES``."""
+    row = cfg.page_row(kind)
+    if kind == "latent_attention":
+        return tuple(-(-n // LANES) * LANES for n in row)
+    return row
+
+
+def _page_rows(new: jax.Array, payload: jax.Array) -> jax.Array:
+    """``new`` [n, ...] in the trailing shape of a page of ``payload``; a
+    row narrower than a folded page's (a latent row) ends in zeros."""
+    if payload.ndim == 3:
+        new = new.reshape(new.shape[0], -1)
+        new = jnp.pad(new, ((0, 0), (0, payload.shape[2] - new.shape[1])))
+    return new.reshape(-1, *payload.shape[2:]).astype(payload.dtype)
 
 
 def blocks_for_tokens(n_tokens: int, block_size: int) -> int:
@@ -191,17 +220,18 @@ def ring_table(win_rows: jax.Array, max_blocks: int, lo: jax.Array,
 
 def pool_kv_bytes(cfg: TransformerConfig, num_blocks: int, block_size: int,
                   dtype=jnp.bfloat16, quantize: bool = False, *,
-                  n_layers: int | None = None) -> int:
+                  n_layers: int | None = None, kind: str | None = None
+                  ) -> int:
     """Global bytes of the k+v pool arrays (scales included in int8
     mode) — the static number admission control and `check --serving`
-    budget against.  ``n_layers``: of that many layers alone."""
+    budget against.  ``n_layers``: of that many layers alone, of ``kind``
+    (``stored_row``: what a token takes in a layer's pages)."""
     n_layers = cfg.n_layers if n_layers is None else n_layers
-    n_cells = n_layers * num_blocks * block_size * cfg.kv_heads
-    if quantize:
-        per_cell = cfg.head_dim * 1 + 4  # int8 payload + fp32 scale
-    else:
-        per_cell = cfg.head_dim * jnp.dtype(dtype).itemsize
-    return 2 * n_cells * per_cell  # k and v
+    n_tokens = n_layers * num_blocks * block_size
+    row = sum(stored_row(cfg, kind))
+    if quantize:  # int8 payload + an fp32 scale a head
+        return n_tokens * (row + 2 * cfg.kv_heads * 4)
+    return n_tokens * row * jnp.dtype(dtype).itemsize
 
 
 def state_row_bytes(cfg: TransformerConfig, dtype=jnp.bfloat16
@@ -285,8 +315,7 @@ def write_token(kv_layer: Any, table: jax.Array, pos: jax.Array,
             "q": kv_layer["q"].at[blk, off].set(q["q"]),
             "scale": kv_layer["scale"].at[blk, off].set(q["scale"]),
         }
-    return kv_layer.at[blk, off].set(  # in the page's own trailing shape
-        new.reshape(-1, *payload.shape[2:]).astype(payload.dtype))
+    return kv_layer.at[blk, off].set(_page_rows(new, payload))
 
 
 def write_chunk(kv_layer: Any, table_row: jax.Array, pos0: jax.Array,
@@ -312,7 +341,7 @@ def write_chunk(kv_layer: Any, table_row: jax.Array, pos0: jax.Array,
 
     def put(leaf, new):  # in the page's own trailing shape
         return leaf.at[idx].set(
-            new.reshape(*lead, *leaf.shape[2:]).astype(leaf.dtype))
+            _page_rows(new, leaf).reshape(*lead, *leaf.shape[2:]))
 
     if is_quantized_leaf(kv_layer):
         new = quantize_kv(rows)
@@ -355,8 +384,11 @@ class PagedKVPool:
         kinds = cfg.layer_types or (None,) * cfg.n_layers
         self.ring = [kind == "sliding_attention" for kind in kinds]
         self.state = [kind == "linear_attention" for kind in kinds]
+        self.latent = [kind == "latent_attention" for kind in kinds]
         if any(self.state) and mesh is not None:
             raise ValueError("a recurrent state has no sharded form")
+        if any(self.latent) and (mesh is not None or self.quantize):
+            raise ValueError("a latent page has no sharded and no int8 form")
         # a slot's ring, [n_slots, W] page ids (W 0: no layer has one)
         W = 0
         if any(self.ring):
@@ -382,11 +414,20 @@ class PagedKVPool:
                           cfg.dtype)}
 
         def side(name):
-            return [jnp.zeros((n_slots + 1, *rows[name][0]), rows[name][1])
-                    if state else _zeros_side(
-                        (self.n_window_blocks if ring else num_blocks, *page),
-                        dtype, self.quantize)
-                    for ring, state in zip(self.ring, self.state)]
+            def one(kind, ring, state, latent):
+                if state:
+                    return jnp.zeros((n_slots + 1, *rows[name][0]),
+                                     rows[name][1])
+                if latent:  # one row a token, under "k" (module docstring)
+                    return jnp.zeros(
+                        (num_blocks, bs, *stored_row(cfg, kind))
+                        if name == "k" else (0,), dtype)
+                return _zeros_side(
+                    (self.n_window_blocks if ring else num_blocks, *page),
+                    dtype, self.quantize)
+
+            return [one(*a) for a in zip(kinds, self.ring, self.state,
+                                         self.latent)]
 
         self.kv = {"k": side("k"), "v": side("v")}
         if mesh is not None:
@@ -407,12 +448,27 @@ class PagedKVPool:
         return self.cfg.n_layers - self.ring.count(True) - self.state.count(
             True)
 
+    def _bytes_paged(self, num_blocks: int) -> tuple[int, int]:
+        """Bytes of ``num_blocks`` pages in every layer that keeps pages for
+        ``max_len``: (all of them, the ``latent_attention`` layers')."""
+        n_latent = self.latent.count(True)
+        latent = n_latent and pool_kv_bytes(
+            self.cfg, num_blocks, self.block_size, self.dtype, self.quantize,
+            n_layers=n_latent, kind="latent_attention")
+        return latent + pool_kv_bytes(
+            self.cfg, num_blocks, self.block_size, self.dtype, self.quantize,
+            n_layers=self.n_full - n_latent), latent
+
     @property
     def bytes_full(self) -> int:
         """Bytes of the layers that keep pages for ``max_len`` (every
-        layer of a model with one kind of layer)."""
-        return pool_kv_bytes(self.cfg, self.num_blocks, self.block_size,
-                             self.dtype, self.quantize, n_layers=self.n_full)
+        layer of a model with one kind of layer), latent pages included."""
+        return self._bytes_paged(self.num_blocks)[0]
+
+    @property
+    def bytes_latent(self) -> int:
+        """What of ``bytes_full`` the ``latent_attention`` layers hold."""
+        return self._bytes_paged(self.num_blocks)[1]
 
     @property
     def bytes_window(self) -> int:
@@ -442,8 +498,7 @@ class PagedKVPool:
         pages for ``max_len``, k and v (scales included in int8 mode) —
         the unit the block-transfer accounting charges per shipped
         block."""
-        return pool_kv_bytes(self.cfg, 1, self.block_size, self.dtype,
-                             self.quantize, n_layers=self.n_full)
+        return self._bytes_paged(1)[0]
 
     def alloc(self, n: int) -> list[int] | None:
         return self.allocator.alloc(n)
@@ -458,16 +513,18 @@ class PagedKVPool:
         caller owns the table update and the release of its reference
         on ``src``; the copy itself is one scatter a leaf, no host
         round-trip.  A ring and a recurrent state have no block ids to
-        share, and are left alone."""
+        share, and are left alone, as is the array of no elements beside a
+        layer's latent pages."""
         got = self.allocator.acquire(1)
         if got is None:
             return None
         dst = got[0]
         for side, layers in self.kv.items():
             self.kv[side] = [
-                leaf if ring or state else jax.tree.map(
-                    lambda x: x.at[dst].set(x[src]), leaf)
-                for leaf, ring, state in zip(layers, self.ring, self.state)]
+                leaf if ring or state or (latent and side == "v")
+                else jax.tree.map(lambda x: x.at[dst].set(x[src]), leaf)
+                for leaf, ring, state, latent in zip(
+                    layers, self.ring, self.state, self.latent)]
         return dst
 
     def table_row(self, blocks: list[int], max_blocks: int) -> list[int]:

@@ -5,13 +5,15 @@ All walk the model's layers one by one (``transformer_core.layer_plan``:
 a model with one kind of layer is a plan of that one kind), each layer
 with its own mixer, window, rotation and FFN, and each with its own pair of
 pool arrays (``kv_pool``: pages for ``max_len``, a ring on a
-``sliding_attention`` layer, or on a ``linear_attention`` layer the
-recurrent state and the convolution's tail, a row a slot), so a call
+``sliding_attention`` layer, on a ``linear_attention`` layer the
+recurrent state and the convolution's tail, a row a slot, or on a
+``latent_attention`` layer pages of one latent row a token), so a call
 updates every layer's pair in place and copies none.  The per-layer math is
 the TRAINING modules applied piecewise, the single-source-of-truth
 discipline of ``decode.forward_cached``: ``SelfAttention.qkv`` /
-``out_proj``, ``GatedDeltaMixer.qkv`` / ``out_proj``, ``MLPBlock``,
-``SparseMLP``, ``make_norm``.
+``out_proj``, ``GatedDeltaMixer.qkv`` / ``out_proj``,
+``LatentAttention.project`` / ``expand`` / ``absorb`` / ``lift`` /
+``out_proj``, ``MLPBlock``, ``SparseMLP``, ``make_norm``.
 
 - The decode step takes a [S, T] token chunk for every slot: T == 1 is
   plain one-token decode, T == 1 + k a speculative verify step.  Positions
@@ -34,6 +36,16 @@ discipline of ``decode.forward_cached``: ``SelfAttention.qkv`` /
   the step form, one token a slot.  Rows that are no real token (a padded
   chunk's tail, an inactive slot) carry ``beta = 0`` and ``g = 0``, which
   leave a state as it was.
+
+- A ``latent_attention`` layer writes a token's ``[c_kv, k_r]`` row into
+  the slot's pages (the same table, the same allocator).  A chunk's queries
+  attend the EXPANDED form, a block of keys at a time: the block's latents
+  to every head's keys and values (``expand``), then scores and values a
+  head (at 512 queries over 16k keys 172 GFLOP of scores and values + 137
+  of expansion a layer, where the absorbed form is 584).  A decode row
+  attends the ABSORBED form: its query taken into the latent space, all
+  heads over the one row a key (``ops/paged_attention``'s latent kernel: a
+  page read once), the result taken out again (``lift``).
 
 - ``chunk_and_step`` is both in one walk: the chunk's C rows and the S
   decode rows share each layer's norms, projections, FFN and the head (the
@@ -62,6 +74,7 @@ import numpy as np
 
 from ...models.transformer_core import (
     GatedDeltaMixer,
+    LatentAttention,
     MLPBlock,
     SelfAttention,
     SparseMLP,
@@ -131,7 +144,10 @@ def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
     layer ``attend(convolve, pre, g, beta)`` is the state update (``pre``,
     ``g``, ``beta`` are the mixer's ``project`` of the layer's input, a row
     at a time; ``convolve(pre, tail)`` its ``convolve`` of one sequence's
-    rows behind their tail).
+    rows behind their tail); on a ``latent_attention`` layer
+    ``attend(piece, q_nope, q_rope, latent)`` writes the rows' latents and
+    returns the heads' outputs (``piece(name, *a)`` is the mixer's method
+    ``name``: ``expand``, ``absorb``, ``lift``).
     ``adapted(tensor, site, inp, rotate)`` adds a tenant's low-rank delta at
     a projection (decode steps with tenants).  Returns ``(x, the expert
     FFN's counters or None)``."""
@@ -145,6 +161,11 @@ def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
         o = attend(lambda *a: mixer.apply(own, *a, method="convolve"),
                    *mixer.apply(own, h, method="project"))
         ao = mixer.apply(own, o, h, method="out_proj")
+    elif kind == "latent_attention":
+        mixer, own = LatentAttention(cfg), {"params": lp["attn"]}
+        o = attend(lambda name, *a: mixer.apply(own, *a, method=name),
+                   *mixer.apply(own, h, positions, method="project"))
+        ao = mixer.apply(own, o.astype(dtype), method="out_proj")
     else:
         attn = SelfAttention(cfg, kind)
         q, k, v = attn.apply({"params": lp["attn"]}, h, positions,
@@ -188,6 +209,12 @@ def _pages_of(kind: str | None) -> str:
     """Which of a call's tables a layer of ``kind`` reads: the ring of a
     ``sliding_attention`` layer, or the request's pages for ``max_len``."""
     return "ring" if kind == "sliding_attention" else "pages"
+
+
+def _work_of(kind: str | None) -> str:
+    """Which of a step's work lists a layer of ``kind`` runs: a latent
+    page's kernel takes its own number of keys a grid step."""
+    return "latent" if kind == "latent_attention" else _pages_of(kind)
 
 
 N_COUNTERS = 5  # what a step's output carries after its tokens
@@ -246,7 +273,12 @@ def _step_shared(cfg, kv, tables, win_tables, ctx_lens, active, adapter_ids,
     list a kind of table, built here once a step and not in every layer's
     call.  Returns ``(shared, [grid steps the step's paged calls run, the
     slots x groups a dense grid would])``."""
-    from ...ops.paged_attention import folded_work_list, is_folded
+    from ...ops.paged_attention import (
+        FOLD_PAGES,
+        folded_work_list,
+        is_folded,
+        latent_pages,
+    )
 
     S, MB = tables.shape
     paged = _paged(cfg)  # none: a model of recurrent states alone
@@ -265,11 +297,13 @@ def _step_shared(cfg, kv, tables, win_tables, ctx_lens, active, adapter_ids,
     grid = jnp.zeros((2,), jnp.int32)
     if attention_impl == "paged" and T == 1 and paged and is_folded(pages0):
         shared["work"] = {  # in the plan's order: the same text every run
-            _pages_of(kind): folded_work_list(
+            _work_of(kind): folded_work_list(
                 ctx_lens, active, max_blocks=MB, block_size=bs,
-                window=cfg.layer_window(kind))
+                window=cfg.layer_window(kind),
+                pages=(latent_pages(MB, bs) if kind == "latent_attention"
+                       else FOLD_PAGES))
             for kind in dict.fromkeys(kinds)}
-        works = [shared["work"][_pages_of(kind)] for kind in kinds]
+        works = [shared["work"][_work_of(kind)] for kind in kinds]
         grid = jnp.stack([sum(w.n_items for w in works),
                           jnp.int32(sum(w.dense for w in works))])
     return shared, grid
@@ -305,6 +339,41 @@ def _step_attention(cfg, kind, shared, k_l, v_l, q, k, v, *,
         mask &= key_idx > positions[:, :, None] - window
     return xla_attention(q, kd, vd, causal=False,
                          mask=mask[:, None]), k_l, v_l
+
+
+def _latent_sizes(cfg) -> tuple[int, int, float]:
+    """(the latent's rank: a row's first numbers, the value; the rotated
+    key part's size behind it; the scale of a score)."""
+    r, rot = cfg.latent_kv_rank, cfg.latent_rope_head_dim
+    return r, rot, (cfg.latent_nope_head_dim + rot) ** -0.5
+
+
+def _step_latent(cfg, shared, pages, none, piece, q_nope, q_rope, latent, *,
+                 attention_impl: str):
+    """``q_nope``, ``q_rope`` [S, T, H, .], ``latent`` [S, T, F]: every
+    slot's T rows written at its context's end, then each query, absorbed,
+    attends the rows up to itself (``none`` is the array of no elements
+    that lies beside latent pages)."""
+    from ...ops.paged_attention import (
+        latent_attention_reference,
+        paged_attention,
+    )
+
+    T = latent.shape[1]
+    table, ctx_lens = shared["tables"]["pages"], shared["ctx_lens"]
+    r, _, scale = _latent_sizes(cfg)
+    for t in range(T):  # static and small (1 + draft length)
+        pages = write_token(pages, table, ctx_lens + t, latent[:, t])
+    q = piece("absorb", q_nope, q_rope)
+    if attention_impl == "paged" and T == 1:
+        o = paged_attention(
+            q[:, 0], pages, none, table, ctx_lens, scale=scale, value_dim=r,
+            work=shared["work"].get("latent"))[:, None]
+    else:  # the dense view: the oracle, and a verify step's T > 1 rows
+        o = latent_attention_reference(
+            q, gather_blocks(pages, table, cfg.dtype, 1)[:, :, 0], ctx_lens,
+            scale=scale, value_dim=r)
+    return piece("lift", o), pages
 
 
 def _step_state(shared, state, tails, convolve, pre, g, beta):
@@ -364,6 +433,33 @@ def _chunk_attention(cfg, kind, shared, k_l, v_l, q, k, v):
     v_l = write_chunk(v_l, row, pos0, v)
     return chunk_attention(q, k_l, v_l, row, pos0, cfg.layer_window(kind),
                            cfg.kv_heads), k_l, v_l
+
+
+def _chunk_latent(cfg, shared, pages, piece, q_nope, q_rope, latent):
+    """``q_nope``, ``q_rope`` [C, H, .], ``latent`` [C, F]: the chunk's
+    latent rows written into the slot's pages, then its queries over the
+    rows up to themselves, EXPANDED a block of keys at a time (the module
+    docstring has the arithmetic)."""
+    row, pos0 = shared["rows"]["pages"], shared["pos0"]
+    pages = write_chunk(pages, row, pos0, latent)
+    r, rot, scale = _latent_sizes(cfg)
+    C, H, _ = q_nope.shape
+
+    def block(ids):
+        rows = read_pages(pages, ids, 1, q_nope.dtype)
+        rows = rows.reshape(-1, rows.shape[-1])  # [keys, stored row]
+        k_nope, v = piece("expand", rows[:, :r])
+        s = (jnp.einsum("chd,thd->hct", q_nope, k_nope,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("chd,td->hct", q_rope, rows[:, r:r + rot],
+                          preferred_element_type=jnp.float32)) * scale
+        return s, lambda p: jnp.einsum(
+            "hct,thd->hcd", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+
+    o = _over_key_blocks(row, pages.shape[1], C, pos0, None, (H,),
+                         cfg.latent_value_head_dim, block)
+    return o.transpose(1, 0, 2).astype(q_nope.dtype), pages
 
 
 def _chunk_state(shared, state, tails, convolve, pre, g, beta):
@@ -442,6 +538,9 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
                 nonlocal a, b
                 if kind == "linear_attention":
                     o, a, b = _step_state(shared, a, b, *rows)
+                elif kind == "latent_attention":
+                    o, a = _step_latent(cfg, shared, a, b, *rows,
+                                        attention_impl=attention_impl)
                 else:
                     o, a, b = _step_attention(
                         cfg, kind, shared, a, b, *rows,
@@ -525,58 +624,73 @@ def decode_step(params, kv, packed, prev, win_tables, adapters, rng, *,
     return kv, jnp.concatenate([firsts, out, counters])
 
 
-def chunk_attention(q, k_layer, v_layer, table_row, pos0, window,
-                    kv_heads: int):
-    """Causal (banded, with ``window``) attention of a chunk's queries
-    ``q`` [C, H, hd] at positions ``pos0 .. pos0 + C`` over one slot's
-    pages, the chunk's own keys already written.  Keys come a block of
-    ``KEY_BLOCK`` at a time through the table, from the first block the
-    window reaches to the chunk's last, with the online softmax of
-    ``ops/flash_attention.py``: the work follows the context a request has,
-    not ``max_len``."""
-    C, H, hd = q.shape
-    bs = kv_leaf_parts(k_layer)[0].shape[1]
-    KV = kv_heads
-    G = H // KV
+def _over_key_blocks(table_row, bs: int, C: int, pos0, window, lead, dv: int,
+                     block):
+    """The online softmax of ``ops/flash_attention.py`` for a chunk's C
+    queries at positions ``pos0 .. pos0 + C`` over one slot's pages, the
+    chunk's own keys already written: keys come a block of ``KEY_BLOCK`` at
+    a time through the table, from the first block the window reaches to
+    the chunk's last, so the work follows the context a request has, not
+    ``max_len``.  ``block(page ids) -> (scores [*lead, C, keys] float32,
+    values(p) -> [*lead, C, dv] float32)`` is what the layer's kind makes of
+    a block's pages.  Returns [*lead, C, dv] float32."""
     ppb = max(1, KEY_BLOCK // bs)
     KB = ppb * bs
     # whole key blocks up to the last a chunk may reach (the null block
     # past the row's end: those keys lie after every query)
     n_kb = -(-(table_row.shape[0] * bs + C) // KB)
     table_row = jnp.pad(table_row, (0, n_kb * ppb - table_row.shape[0]))
-    qg = q.reshape(C, KV, G, hd)
     q_pos = pos0 + jnp.arange(C)
-    scale = 1.0 / float(np.sqrt(hd))
+    wide = (None,) * len(lead)
 
     def body(jb, carry):
         acc, m, l = carry
-        pages = jax.lax.dynamic_slice_in_dim(table_row, jb * ppb, ppb)
-        kb = read_pages(k_layer, pages, KV, q.dtype).reshape(KB, KV, hd)
-        vb = read_pages(v_layer, pages, KV, q.dtype).reshape(KB, KV, hd)
-        s = jnp.einsum("ckgd,tkd->kgct", qg, kb,
-                       preferred_element_type=jnp.float32) * scale
+        s, values = block(jax.lax.dynamic_slice_in_dim(table_row, jb * ppb,
+                                                       ppb))
         key_pos = jb * KB + jnp.arange(KB)
         ok = key_pos[None, :] <= q_pos[:, None]
         if window is not None:
             ok &= key_pos[None, :] > q_pos[:, None] - window
-        s = jnp.where(ok[None, None], s, _NEG_BIG)
+        s = jnp.where(ok[wide], s, _NEG_BIG)
         m_new = jnp.maximum(m, s.max(-1))
-        p = jnp.where(ok[None, None], jnp.exp(s - m_new[..., None]), 0.0)
+        p = jnp.where(ok[wide], jnp.exp(s - m_new[..., None]), 0.0)
         alpha = jnp.exp(m - m_new)
         l = l * alpha + p.sum(-1)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "kgct,tkd->kgcd", p.astype(vb.dtype), vb,
-            preferred_element_type=jnp.float32)
+        acc = acc * alpha[..., None] + values(p)
         return acc, m_new, l
 
     lo = 0 if window is None else jnp.maximum(pos0 - window + 1, 0) // KB
     hi = (pos0 + C - 1) // KB
     acc, _, l = jax.lax.fori_loop(
         lo, hi + 1, body,
-        (jnp.zeros((KV, G, C, hd), jnp.float32),
-         jnp.full((KV, G, C), _NEG_BIG, jnp.float32),
-         jnp.zeros((KV, G, C), jnp.float32)))
-    o = acc / l[..., None]  # every row sees at least its own key
+        (jnp.zeros((*lead, C, dv), jnp.float32),
+         jnp.full((*lead, C), _NEG_BIG, jnp.float32),
+         jnp.zeros((*lead, C), jnp.float32)))
+    return acc / l[..., None]  # every row sees at least its own key
+
+
+def chunk_attention(q, k_layer, v_layer, table_row, pos0, window,
+                    kv_heads: int):
+    """Causal (banded, with ``window``) attention of a chunk's queries
+    ``q`` [C, H, hd] at positions ``pos0 .. pos0 + C`` over one slot's
+    pages of keys and values (``_over_key_blocks``)."""
+    C, H, hd = q.shape
+    bs = kv_leaf_parts(k_layer)[0].shape[1]
+    KV = kv_heads
+    G = H // KV
+    qg = q.reshape(C, KV, G, hd)
+    scale = 1.0 / float(np.sqrt(hd))
+
+    def block(pages):
+        kb = read_pages(k_layer, pages, KV, q.dtype).reshape(-1, KV, hd)
+        vb = read_pages(v_layer, pages, KV, q.dtype).reshape(-1, KV, hd)
+        s = jnp.einsum("ckgd,tkd->kgct", qg, kb,
+                       preferred_element_type=jnp.float32) * scale
+        return s, lambda p: jnp.einsum(
+            "kgct,tkd->kgcd", p.astype(vb.dtype), vb,
+            preferred_element_type=jnp.float32)
+
+    o = _over_key_blocks(table_row, bs, C, pos0, window, (KV, G), hd, block)
     return o.transpose(2, 0, 1, 3).reshape(C, H, hd).astype(q.dtype)
 
 
@@ -620,6 +734,9 @@ def prefill_chunk(params, kv, packed, win_row, *, cfg: TransformerConfig,
                     convolve, pre, g, beta = rows
                     o, a, b = _chunk_state(shared, a, b, convolve, pre[0],
                                            g[0], beta[0])
+                elif kind == "latent_attention":
+                    o, a = _chunk_latent(cfg, shared, a, rows[0],
+                                         *(r[0] for r in rows[1:]))
                 else:
                     o, a, b = _chunk_attention(cfg, kind, shared, a, b,
                                                *(r[0] for r in rows))
@@ -687,6 +804,14 @@ def chunk_and_step(params, kv, packed, prev, win_row, win_tables, rng, *,
                                             *(r[0, :C] for r in rows))
                     os_, a, b = _step_state(shared["step"], a, b, convolve,
                                             *(r[0, C:, None] for r in rows))
+                elif kind == "latent_attention":
+                    piece, *rows = rows
+                    oc, a = _chunk_latent(cfg, shared["chunk"], a, piece,
+                                          *(r[0, :C] for r in rows))
+                    os_, a = _step_latent(
+                        cfg, shared["step"], a, b, piece,
+                        *(r[0, C:, None] for r in rows),
+                        attention_impl=attention_impl)
                 else:
                     oc, a, b = _chunk_attention(cfg, kind, shared["chunk"],
                                                 a, b,

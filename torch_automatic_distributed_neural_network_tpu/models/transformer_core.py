@@ -81,7 +81,9 @@ class TransformerConfig:
     # One entry a layer: "sliding_attention" (the causal band of
     # ``sliding_window``), "full_attention" (plain causal) or
     # "linear_attention" (no keys and values: a gated delta rule over a
-    # recurrent state, ``GatedDeltaMixer``).  Given, the layers are built
+    # recurrent state, ``GatedDeltaMixer``) or "latent_attention" (one
+    # low-rank latent a token in the place of per-head keys and values,
+    # ``LatentAttention``).  Given, the layers are built
     # one by one (``layers_0`` .. in the parameter tree, no ``nn.scan``),
     # each of its own kind, and the keys below apply; None keeps the one
     # scanned layer every other model here has.
@@ -122,6 +124,15 @@ class TransformerConfig:
     linear_value_head_dim: int | None = None
     linear_conv_kernel: int = 4
     linear_neg_eigval: bool = False
+    # the ``latent_attention`` layers (valid only with one): the ranks of
+    # the query's and of the key-value latent, and a head's three sizes: the
+    # part of a query and a key that is not rotated, the rotated part (ONE
+    # key part for all heads, beside the latent), and a value
+    latent_q_rank: int | None = None
+    latent_kv_rank: int | None = None
+    latent_nope_head_dim: int | None = None
+    latent_rope_head_dim: int | None = None
+    latent_value_head_dim: int | None = None
 
     def __post_init__(self):
         kinds = self.layer_types
@@ -145,6 +156,16 @@ class TransformerConfig:
                 "a linear_attention layer needs linear_key_heads == "
                 "linear_value_heads, linear_key_head_dim, "
                 "linear_value_head_dim and linear_conv_kernel >= 2")
+        given = [k for k in LATENT_SIZES if getattr(self, k)]
+        if "latent_attention" not in (kinds or ()):
+            if given:
+                raise ValueError(f"{given} describe latent_attention "
+                                 f"layers: layer_types has none")
+        elif (len(given) != len(LATENT_SIZES) or self.pos != "rope"
+              or self.latent_rope_head_dim % 2):
+            raise ValueError(
+                f"a latent_attention layer needs {LATENT_SIZES}, an even "
+                f"latent_rope_head_dim and pos='rope'")
         if kinds is not None:
             if not self.pre_norm and not self.sandwich_norm:
                 raise ValueError("pre_norm=False leaves a layer without "
@@ -210,6 +231,15 @@ class TransformerConfig:
             return self.sliding_window
         return None
 
+    def page_row(self, kind: str | None) -> tuple[int, ...]:
+        """The numbers a token takes in each array of the pair that a paged
+        layer of ``kind`` keeps: its keys and its values, the KV heads side
+        by side; on a ``latent_attention`` layer ONE array, the key-value
+        latent and the rotated key part behind it."""
+        if kind == "latent_attention":
+            return (self.latent_kv_rank + self.latent_rope_head_dim,)
+        return (self.kv_heads * self.head_dim,) * 2
+
     def layer_rotates(self, kind: str | None) -> bool:
         return self.pos == "rope" and (
             kind in (None, "sliding_attention") or self.rope_layers == "all")
@@ -237,6 +267,14 @@ class TransformerConfig:
                     + 2 * self.linear_value_heads  # A_log, dt_bias
                     + self.linear_conv_kernel * (2 * qk + vo)
                     + self.linear_value_head_dim)  # the output norm's gain
+        if kind == "latent_attention":
+            H, rq, rkv = self.n_heads, self.latent_q_rank, self.latent_kv_rank
+            nope, rot, dv = (self.latent_nope_head_dim,
+                             self.latent_rope_head_dim,
+                             self.latent_value_head_dim)
+            return (d * rq + rq + rq * H * (nope + rot)  # queries, low rank
+                    + d * (rkv + rot) + rkv  # the latent and the rotated key
+                    + rkv * H * (nope + dv) + H * dv * d)  # up, out
         q, kv = self.n_heads * hd, self.kv_heads * hd
         normed = {"head": 2 * hd, "projection": q + kv}[self.qk_norm_over]
         return (2 * d * q + 2 * d * kv + (d * q if self.attn_gate else 0)
@@ -269,7 +307,10 @@ class TransformerConfig:
         return L * (attn + mlp) + norms + emb + pos
 
 
-LAYER_KINDS = ("sliding_attention", "full_attention", "linear_attention")
+LAYER_KINDS = ("sliding_attention", "full_attention", "linear_attention",
+               "latent_attention")
+LATENT_SIZES = ("latent_q_rank", "latent_kv_rank", "latent_nope_head_dim",
+                "latent_rope_head_dim", "latent_value_head_dim")
 
 
 def make_norm(cfg: TransformerConfig, name: str | None = None):
@@ -288,6 +329,16 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def deinterleave(x: jax.Array) -> jax.Array:
+    """Rotary pairs laid out side by side, ``(x0, x1), (x2, x3), ..``
+    (``rope_interleave``), brought to the halves ``rope`` rotates: ``x0, x2,
+    .., x1, x3, ..``.  A dot product of two vectors so permuted is what it
+    was, so scores do not care in which layout rotated parts are kept."""
+    d = x.shape[-1]
+    return jnp.swapaxes(x.reshape(*x.shape[:-1], d // 2, 2), -1, -2).reshape(
+        x.shape)
 
 
 class SelfAttention(nn.Module):
@@ -459,6 +510,102 @@ class GatedDeltaMixer(nn.Module):
         return self.out_proj(jnp.moveaxis(o.reshape(T, B, H, -1), 0, 1), x)
 
 
+class LatentAttention(nn.Module):
+    """The mixer of a ``latent_attention`` layer (multi-head latent
+    attention, the DeepSeek-V3 block): queries through a low-rank
+    bottleneck, and ONE latent ``c_kv`` a token from which every head's
+    unrotated key part and value are expanded (``kv_b_proj``), beside one
+    rotated key part ``k_r`` that all heads share.  A head's score is
+    ``(q_nope . k_nope + q_rope . k_r) / sqrt(nope + rope)``.
+
+    What a cache keeps is the token's ``[RMSNorm(c_kv), rotated k_r]`` alone
+    (``cfg.page_row``: 576 numbers where 32 heads of keys and values are
+    10,240), so the serving programs apply the pieces one by one
+    (``method=``), like ``SelfAttention``'s: ``project`` (both low-rank
+    projections, their norms, the rotation of the two rotated parts),
+    ``expand`` (cached latents to per-head keys and values: a prefill chunk,
+    a key block at a time), or ``absorb`` and ``lift`` (decode: the queries
+    taken INTO the latent space, ``q_nope W_UK^T``, attention of all heads
+    over the one latent row as key and value, and the result taken out
+    again, ``. W_UV``: the same numbers, no per-head key ever built), then
+    ``out_proj``.  ``__call__`` is the expanded form over a whole
+    sequence."""
+
+    cfg: TransformerConfig
+
+    def setup(self):
+        cfg = self.cfg
+        H, rot = cfg.n_heads, cfg.latent_rope_head_dim
+        dense = lambda feats: nn.DenseGeneral(
+            feats, axis=-1, dtype=cfg.dtype, use_bias=False)
+        norm = lambda: nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)
+        self.q_a_proj, self.q_a_norm = dense(cfg.latent_q_rank), norm()
+        self.q_b_proj = dense((H, cfg.latent_nope_head_dim + rot))
+        self.kv_a_proj, self.kv_a_norm = dense(cfg.latent_kv_rank + rot), norm()
+        self.kv_b_proj = dense(
+            (H, cfg.latent_nope_head_dim + cfg.latent_value_head_dim))
+        self.o_proj = nn.DenseGeneral(cfg.d_model, axis=(-2, -1),
+                                      dtype=cfg.dtype, use_bias=False)
+
+    def project(self, x, positions):
+        """``x`` [B, T, d] at ``positions`` [B, T]: ``(q_nope [B, T, H,
+        nope], q_rope [B, T, H, rope], latent [B, T, kv_rank + rope])``,
+        the rotated parts rotated (pairs side by side as published,
+        ``deinterleave``), the latent normed: the row a cache keeps."""
+        cfg = self.cfg
+        q = self.q_b_proj(self.q_a_norm(self.q_a_proj(x)))
+        q_nope, q_rope = jnp.split(q, [cfg.latent_nope_head_dim], axis=-1)
+        c, k_r = jnp.split(self.kv_a_proj(x), [cfg.latent_kv_rank], axis=-1)
+        q_rope = rope(deinterleave(q_rope), positions, cfg.rope_theta)
+        k_r = rope(deinterleave(k_r)[:, :, None], positions,
+                   cfg.rope_theta)[:, :, 0]
+        return q_nope, q_rope, jnp.concatenate([self.kv_a_norm(c), k_r], -1)
+
+    def _up(self):
+        """``kv_b_proj``'s kernel [kv_rank, H, nope + value] apart: (W_UK,
+        W_UV), in the compute dtype."""
+        w = self.kv_b_proj.variables["params"]["kernel"].astype(self.cfg.dtype)
+        return jnp.split(w, [self.cfg.latent_nope_head_dim], axis=-1)
+
+    def expand(self, c):
+        """Normed latents ``c`` [..., kv_rank] to ``(k_nope [..., H, nope],
+        v [..., H, value])``."""
+        return tuple(jnp.split(self.kv_b_proj(c),
+                               [self.cfg.latent_nope_head_dim], axis=-1))
+
+    def absorb(self, q_nope, q_rope):
+        """A query in the latent space: ``[q_nope W_UK^T, q_rope]`` [..., H,
+        kv_rank + rope], whose product with a cached row is the head's
+        score."""
+        q_lat = jnp.einsum("...hn,chn->...hc", q_nope, self._up()[0])
+        return jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype)], -1)
+
+    def lift(self, o_lat):
+        """``o_lat`` [..., H, kv_rank], a head's probabilities over the
+        cached latents, to the head's output [..., H, value]."""
+        return jnp.einsum("...hc,chv->...hv", o_lat.astype(self.cfg.dtype),
+                          self._up()[1])
+
+    def out_proj(self, out, x=None):
+        del x  # no output gate
+        return self.o_proj(out)
+
+    def __call__(self, x, positions, mask=None):
+        cfg = self.cfg
+        q_nope, q_rope, latent = self.project(x, positions)
+        c, k_r = jnp.split(latent, [cfg.latent_kv_rank], axis=-1)
+        k_nope, v = self.expand(c)
+        q = jnp.concatenate([q_nope, q_rope], -1)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_r[:, :, None], (*k_nope.shape[:-1], k_r.shape[-1]))], -1)
+        # values are narrower than keys: padded for the one attention entry
+        pad = q.shape[-1] - v.shape[-1]
+        out = attention(
+            q, k, jnp.pad(v, ((0, 0),) * 3 + ((0, pad),)), causal=cfg.causal,
+            mask=mask, impl=cfg.attention_impl)
+        return self.out_proj(out[..., :v.shape[-1]])
+
+
 class MLPBlock(nn.Module):
     """setup()-style so decode applies it directly on cached-path chunks
     — the gelu/SwiGLU feed-forward math lives here and only here."""
@@ -552,8 +699,9 @@ class SparseMLP(nn.Module):
 
 class KindDecoderLayer(nn.Module):
     """One layer of a model whose layers differ (``cfg.layer_types``): the
-    mixer of this layer's ``kind`` (attention, or the gated delta rule of a
-    ``linear_attention`` layer), a dense or an expert FFN, and the norms
+    mixer of this layer's ``kind`` (attention, the gated delta rule of a
+    ``linear_attention`` layer, or the latent attention of a
+    ``latent_attention`` one), a dense or an expert FFN, and the norms
     where ``cfg.pre_norm`` and ``cfg.sandwich_norm`` put them (before each
     sublayer, after it, or both)."""
 
@@ -567,6 +715,8 @@ class KindDecoderLayer(nn.Module):
         h = make_norm(cfg, "attn_norm")(x) if cfg.pre_norm else x
         if self.kind == "linear_attention":
             h = GatedDeltaMixer(cfg, name="attn")(h)
+        elif self.kind == "latent_attention":
+            h = LatentAttention(cfg, name="attn")(h, positions, mask)
         else:
             h = SelfAttention(cfg, self.kind, name="attn")(h, positions, mask)
         if cfg.sandwich_norm:

@@ -372,7 +372,7 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
             **{k: (sengine or {}).get(k) for k in (
                 "layer_kinds", "experts_held", "experts_published",
                 "kv_bytes_full", "kv_bytes_window", "state_bytes_linear",
-                "conv_bytes_linear")},
+                "conv_bytes_linear", "kv_bytes_latent", "latent_row")},
             # the decode steps' expert counters (engines with experts)
             "mean_moe_pairs": _mean(e.get("moe_pairs") for e in ssteps),
             "mean_moe_experts_touched": _mean(
@@ -1012,6 +1012,13 @@ def format_report(report: dict) -> str:
                 + (f" ({kinds.count('full_attention')} full, "
                    f"{kinds.count('sliding_attention')} sliding layers)"
                    if kinds else "")]
+            if sv.get("kv_bytes_latent"):
+                rank, rot, stored = sv["latent_row"]
+                eparts.append(
+                    f"of the pages {sv['kv_bytes_latent'] / 2**30:.2f} GiB "
+                    f"latent: one row a token of {rank} + {rot} numbers, "
+                    f"stored in {stored} "
+                    f"({kinds.count('latent_attention')} latent layers)")
             if sv.get("state_bytes_linear"):
                 eparts.append(
                     f"state pool {sv['state_bytes_linear'] / 2**30:.3f} GiB "

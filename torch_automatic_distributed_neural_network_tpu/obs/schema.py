@@ -338,6 +338,10 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             "experts_published": "int", "kv_bytes_full": "int",
             "kv_bytes_window": "int", "state_bytes_linear": "int",
             "conv_bytes_linear": "int",
+            # what of kv_bytes_full the latent_attention layers' pages hold
+            # (one row a token, key and value at once), and that row:
+            # [the latent's rank, the rotated key part, numbers stored]
+            "kv_bytes_latent": "int", "latent_row": "list?",
             # decode steps the engine dispatches with the step before
             # unread: 1, or 0 where the next step's operands need the
             # tokens' values (speculative drafts)

@@ -18,8 +18,11 @@ Shape of the problem (one decode token per slot):
     tables: [S, MB] int32        block ids, null-padded (kv_pool)
     ctx:    [S] int32            keys 0..ctx inclusive are valid
 
-ONE entry point, :func:`paged_attention`, and two kernels chosen by the
-shape of a page, not by the model.  A pool of folded pages is read by the
+ONE entry point, :func:`paged_attention`, and three kernels chosen by the
+shape of a page, not by the model.  A pool of LATENT pages (one row a token
+that is key and value at once, ``[NB, bs, kv_rank + rope]``, and no second
+array) is read by ``tadnn_paged_decode_latent``, the folded kernel's body at
+other numbers: see "the latent kernel" below.  A pool of folded pages is read by the
 MXU kernel (``tadnn_paged_decode_folded``, further down: grouped queries
 as two plain matmuls, 8 pages a grid step, the grid a list of the key
 groups the slots have, of traced length).  A page kept as ``[bs, kvH, hd]``
@@ -168,8 +171,16 @@ def tensor_degree(mesh, axis: str = "tensor") -> int:
 
 
 def is_folded(pool) -> bool:
-    """A layer's pages stored ``[NB, bs, kvH * hd]``: the MXU kernel's."""
+    """A layer's pages stored ``[NB, bs, kvH * hd]``: the MXU kernel's (a
+    latent page, one row a token, is stored so too)."""
     return not isinstance(pool, dict) and pool.ndim == 3
+
+
+def is_latent(k_pool, v_pool) -> bool:
+    """A ``latent_attention`` layer's pair: pages of one row a token under
+    ``k`` and an array of no elements under ``v`` (``kv_pool``)."""
+    return (is_folded(k_pool) and not isinstance(v_pool, dict)
+            and v_pool.size == 0)
 
 
 def paged_attention(
@@ -184,6 +195,8 @@ def paged_attention(
     mesh=None,
     axis: str = "tensor",
     work=None,
+    scale: float | None = None,
+    value_dim: int | None = None,
 ) -> jax.Array:
     """Fused paged decode attention over one layer of the KV pool.
 
@@ -207,13 +220,23 @@ def paged_attention(
     Tables and context lengths stay replicated — any slot may reference
     any block, exactly like the unsharded pool.
 
-    ``work`` is the MXU kernel's grid (``folded_work_list``), for a caller
+    ``work`` is the MXU kernels' grid (``folded_work_list``), for a caller
     that builds it once for many layers; the VPU kernel has none.
+
+    A pool of latent pages (``is_latent``) takes ``q`` [S, Hq, F] already in
+    the latent space (``LatentAttention.absorb``), the ``scale`` of its
+    scores and ``value_dim``, how many of a row's first numbers are the
+    value; it returns [S, Hq, value_dim]
+    (:func:`paged_attention_latent`).
     """
     from ..inference.quant import kv_leaf_parts
 
     if interpret is None:
         interpret = _default_interpret()
+    if is_latent(k_pool, v_pool):
+        return paged_attention_latent(
+            q, k_pool, tables, ctx_lens, scale=scale, value_dim=value_dim,
+            interpret=interpret, work=work)
     if is_folded(k_pool):
         return paged_attention_folded(
             q, k_pool, v_pool, tables, ctx_lens, window=window,
@@ -348,9 +371,10 @@ class WorkList(NamedTuple):
         return self.slot_of.shape[0] - 1
 
 
-def _fold(max_blocks: int, block_size: int, window: int | None):
+def _fold(max_blocks: int, block_size: int, window: int | None,
+          pages: int = FOLD_PAGES):
     """(pages a group, groups a table row, groups a slot can have live)."""
-    pages = min(FOLD_PAGES, max_blocks)
+    pages = min(pages, max_blocks)
     groups = -(-max_blocks // pages)
     if window is None:
         return pages, groups, groups
@@ -360,13 +384,15 @@ def _fold(max_blocks: int, block_size: int, window: int | None):
 
 def folded_work_list(ctx_lens: jax.Array, active: jax.Array | None = None, *,
                      max_blocks: int, block_size: int,
-                     window: int | None = None) -> WorkList:
+                     window: int | None = None,
+                     pages: int = FOLD_PAGES) -> WorkList:
     """The live (slot, group) items of one decode step for the layers of one
     ``window``: groups ``0 .. ctx // keys`` of a slot, from the band's first
     group with a window; group 0 alone for a slot that is not ``active``.
-    A few vector operations on the device: built once a step a kind of
-    layer, whatever the number of layers."""
-    pages, groups, steps = _fold(max_blocks, block_size, window)
+    A group is ``pages`` pages (the keys a grid step takes: the kernel's
+    own number).  A few vector operations on the device: built once a step
+    a kind of layer, whatever the number of layers."""
+    pages, groups, steps = _fold(max_blocks, block_size, window, pages)
     keys = pages * block_size
     ctx = jnp.maximum(ctx_lens.astype(jnp.int32), 0)
     last = jnp.minimum(ctx // keys, groups - 1)
@@ -386,10 +412,15 @@ def folded_work_list(ctx_lens: jax.Array, active: jax.Array | None = None, *,
 
 def _folded_kernel(tables_ref, ctx_ref, first_ref, last_ref, slot_ref,
                    group_ref, q_ref, *refs, pages: int, bs: int,
-                   window: int | None, scale: float):
+                   window: int | None, scale: float,
+                   value_dim: int | None = None):
+    """``value_dim``: a latent page, read ONCE: its row is the key and its
+    first ``value_dim`` numbers are the value (no value pages among
+    ``refs``)."""
     del tables_ref
-    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
-    o_ref, acc_ref, m_ref, l_ref = refs[2 * pages:]
+    n_in = pages if value_dim else 2 * pages
+    k_refs, v_refs = refs[:pages], refs[pages:n_in]
+    o_ref, acc_ref, m_ref, l_ref = refs[n_in:]
     w = pl.program_id(0)
     s, g = slot_ref[w], group_ref[w]
 
@@ -405,8 +436,9 @@ def _folded_kernel(tables_ref, ctx_ref, first_ref, last_ref, slot_ref,
     # every item holds a key its slot attends, but for the one item of a
     # slot with nothing to attend: all of it masked, its sums stay zero
     k = jnp.concatenate([r[0] for r in k_refs], axis=0)  # [keys, F]
-    v = jnp.concatenate([r[0] for r in v_refs], axis=0)
-    q = q_ref[0]  # [Hq, F], block-diagonal
+    v = (k[:, :value_dim] if value_dim
+         else jnp.concatenate([r[0] for r in v_refs], axis=0))
+    q = q_ref[0]  # [Hq, F], block-diagonal (a latent query fills its row)
     exact = None
     if q.dtype == jnp.float32:  # float32 queries ask for float32 math:
         # operands AND products (a float32 matmul is one bfloat16 pass by
@@ -507,6 +539,108 @@ def paged_attention_folded(
     # float32 here too (the default rounds this sum over the 0/1 ``own``)
     return jnp.einsum("shkd,hk->shd", out.reshape(S, Hq, kvH, hd), own,
                       precision="highest" if q.dtype == jnp.float32 else None)
+
+
+# -- the latent kernel: every head over ONE row a key -----------------------------
+#
+# Absorbed latent attention (``models/transformer_core.LatentAttention``) is
+# multi-query attention with one twist: all ``Hq`` heads read ONE row a key
+# (``F = kv_rank + rope`` numbers: 576), and the row's first ``value_dim``
+# numbers (512) are the value as well.  So a page is read once, the queries
+# need no block-diagonal form, and the arithmetic a byte is ten times the
+# grouped-query kernel's: ``Hq (F + value_dim) 2`` operations over ``2 F``
+# bytes, 60 FLOP/B at 32 heads, still under the v5e's 240.  What it costs
+# is again its grid: a folded step (128 keys) costs 0.56-0.84 us beside its
+# bytes (my chip runs, PR 30), and 128 latent keys are 147 KB, 0.18 us of
+# HBM time.  So a step takes ``LATENT_KEYS`` keys (590 KB, 0.72 us), in as
+# few page copies as the pool's block size allows.
+
+
+LATENT_KEYS = 512  # keys a grid step takes
+
+
+def latent_pages(max_blocks: int, block_size: int) -> int:
+    """Pages a grid step of the latent kernel takes."""
+    return max(1, min(LATENT_KEYS // block_size, max_blocks))
+
+
+def paged_attention_latent(
+    q: jax.Array,
+    pool: jax.Array,
+    tables: jax.Array,
+    ctx_lens: jax.Array,
+    *,
+    scale: float,
+    value_dim: int,
+    interpret: bool | None = None,
+    work: WorkList | None = None,
+) -> jax.Array:
+    """What ``paged_attention`` runs over one layer of latent pages ``pool``
+    [NB, bs, F]: ``q`` [S, Hq, <= F] in the latent space (a stored row ends
+    in zeros where it is wider: ``kv_pool.stored_row``), ``tables`` [S, MB],
+    keys ``0..ctx`` attendable, scores ``q . row * scale``, values a row's
+    first ``value_dim`` numbers.  ``work`` is ``folded_work_list`` of the
+    same contexts with ``pages=latent_pages(MB, bs)``.  Returns [S, Hq,
+    value_dim] in ``q.dtype``: the probabilities over the cached latents,
+    which ``LatentAttention.lift`` takes to the heads' outputs."""
+    if interpret is None:
+        interpret = _default_interpret()
+    S, Hq, _ = q.shape
+    NB, bs, F = pool.shape
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, F - q.shape[2])))
+    MB = tables.shape[1]
+    pages = latent_pages(MB, bs)
+    tables = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, -MB % pages)))
+    ctx_lens = ctx_lens.astype(jnp.int32)
+    if work is None:
+        work = folded_work_list(ctx_lens, max_blocks=MB, block_size=bs,
+                                pages=pages)
+
+    def page(i):
+        return pl.BlockSpec((1, bs, F), lambda w, t, c, f, l, so, go: (
+            t[so[w], go[w] * pages + i], 0, 0))
+
+    def rows(width):
+        return pl.BlockSpec((1, Hq, width), lambda w, t, c, f, l, so, go: (
+            so[w], 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(work.n_items,),
+        in_specs=[rows(F)] + [page(i) for i in range(pages)],
+        out_specs=rows(value_dim),
+        scratch_shapes=[
+            pltpu.VMEM((Hq, value_dim), jnp.float32),
+            pltpu.VMEM((Hq, _LANES), jnp.float32),
+            pltpu.VMEM((Hq, _LANES), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_folded_kernel, pages=pages, bs=bs, window=None,
+                          scale=float(scale), value_dim=value_dim),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, Hq, value_dim), q.dtype),
+        interpret=interpret,
+        name="tadnn_paged_decode_latent",
+    )(tables, ctx_lens, *work[:4], q, *([pool] * pages))
+
+
+def latent_attention_reference(q, rows, ctx_lens, *, scale: float,
+                               value_dim: int):
+    """Pure-JAX oracle of the latent kernel, and the engine's
+    ``attention_impl="dense"`` path: ``q`` [S, T, Hq, F] over each slot's
+    dense ``rows`` [S, L, >= F], query t of slot s attending keys ``0 ..
+    ctx_lens[s] + t``.  Returns [S, T, Hq, value_dim] in ``q.dtype``."""
+    rows = rows[..., :q.shape[-1]].astype(q.dtype)
+    exact = "highest" if q.dtype == jnp.float32 else None
+    sc = jnp.einsum("sthf,slf->shtl", q, rows, precision=exact,
+                    preferred_element_type=jnp.float32) * scale
+    last = ctx_lens[:, None] + jnp.arange(q.shape[1])[None, :]  # [S, T]
+    ok = jnp.arange(rows.shape[1])[None, None, :] <= last[:, :, None]
+    p = jax.nn.softmax(jnp.where(ok[:, None], sc, _NEG_BIG), axis=-1)
+    return jnp.einsum("shtl,slv->sthv", p.astype(rows.dtype),
+                      rows[..., :value_dim], precision=exact,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
 
 
 def paged_attention_reference(
