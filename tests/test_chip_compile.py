@@ -317,6 +317,31 @@ def test_serving_programs_update_the_pool_in_place(
                    for x in jax.tree.leaves(kv) if x.size}
     assert not [l[:100] for l in text.splitlines()
                 if " copy(" in l and any(a in l for a in page_arrays)]
+    if cfg.n_expert_layers:
+        # the expert layer's glue: no scatter (the chip runs one an element
+        # at a time) and no loop, no copy of a padded [rows, d] array to
+        # append a row to it, and ONE pair of kernels a layer (no second,
+        # smaller copy of the layer beside the first)
+        glue = [l for l in text.splitlines() if "SparseMLP" in l]
+        assert glue
+        assert not [l.strip()[:120] for l in glue
+                    if " scatter(" in l or " while(" in l]
+        from torch_automatic_distributed_neural_network_tpu.parallel.expert import (
+            expert_tiles,
+        )
+        rows = {"decode_step": slots, "prefill_chunk": chunk,
+                "chunk_and_step": chunk + slots}[program]
+        tm, n_tiles = expert_tiles(rows, cfg.experts_per_token,
+                                   cfg.n_experts_held)
+        padded = [f"bf16[{n_tiles * tm + more},{cfg.d_model}]"
+                  for more in (0, 1)]
+        assert not [l.strip()[:120] for l in text.splitlines()
+                    if re.search(r" (pad|concatenate)\(", l)
+                    and any(a in l.split(" = ")[1][:40] for a in padded)]
+        for kernel in ("gate_up", "down"):
+            assert len(re.findall(
+                r"^\s*%tadnn_moe_grouped_mm_" + kernel + r"[.\d]* = ", text,
+                re.M)) == cfg.n_expert_layers
     if config == "olmo-hybrid-7b-pp2":
         # 12 linear layers: the step kernel in the one, the chunk kernel in
         # the other; 4.53 GB of pages and 0.25 GB of states and tails
@@ -465,6 +490,40 @@ def test_grouped_matmul_compiles_for_v5e(v5e, pairs):
 
     text = _compile(ffn, sds((n_tiles * tm, 3072), jnp.bfloat16), w, w, w,
                     sds((n_tiles,), jnp.int32), sds((), jnp.int32))
+    assert "tadnn_moe_grouped_mm_gate_up" in text
+    assert "tadnn_moe_grouped_mm_down" in text
+
+
+@pytest.mark.parametrize("tokens,top_k,d,f", [
+    (528, 4, 3072, 3072), (16, 4, 3072, 3072),
+    (536, 8, 2048, 768), (24, 8, 2048, 768)],
+    ids=["trinity_chunk", "trinity_step", "joyai_chunk", "joyai_step"])
+def test_grouped_matmul_gathers_its_rows_for_v5e(v5e, tokens, top_k, d, f):
+    """The gate-up kernel picking a tile's rows out of the tokens, which it
+    holds whole in VMEM in one buffer beside the experts' slabs, at the two
+    expert models' widths and both tile sizes (a chunk with the step's
+    rows, a decode step): 32 held experts."""
+    from torch_automatic_distributed_neural_network_tpu.ops.grouped_matmul import (
+        grouped_matmul,
+    )
+    from torch_automatic_distributed_neural_network_tpu.parallel.expert import (
+        expert_tiles,
+    )
+
+    one = SingleDeviceSharding(v5e[0])
+    sds = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one)  # noqa: E731
+    tm, n_tiles = expert_tiles(tokens, top_k, 32)
+
+    def ffn(x, src, wg, wu, wd, tg, na):
+        h = grouped_matmul(x, wu, tg, na, tm=tm, w_gate=wg, src=src,
+                           interpret=False)
+        return grouped_matmul(h, wd, tg, na, tm=tm, interpret=False)
+
+    text = _compile(
+        ffn, sds((tokens, d), jnp.bfloat16), sds((n_tiles * tm,), jnp.int32),
+        sds((32, d, f), jnp.bfloat16), sds((32, d, f), jnp.bfloat16),
+        sds((32, f, d), jnp.bfloat16), sds((n_tiles,), jnp.int32),
+        sds((), jnp.int32))
     assert "tadnn_moe_grouped_mm_gate_up" in text
     assert "tadnn_moe_grouped_mm_down" in text
 

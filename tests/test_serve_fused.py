@@ -14,6 +14,8 @@ the last one.
 
 from __future__ import annotations
 
+import json
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -150,6 +152,56 @@ def test_a_fused_step_serves_the_two_calls_tokens(
         alone = [s for s in steps
                  if "decode_dispatch" in s["phases"] and not s["fused"]]
         assert len([s for s in steps if "moe_pairs" in s]) == len(alone)
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "two_calls"])
+def test_live_expert_tiles_are_counted_on_every_call(models, fused, tmp_path):
+    """``moe_tiles_active`` rides with every step's tokens, a fused step's
+    too, beside the tiles that step laid out (``serve.engine`` states both
+    kinds of call's); the schema names them and ``tadnn report`` prints
+    live of laid."""
+    from torch_automatic_distributed_neural_network_tpu.obs import (
+        report as obs_report,
+        schema,
+    )
+    from torch_automatic_distributed_neural_network_tpu.parallel.expert import (
+        expert_tiles,
+    )
+
+    journal = Journal(None, validate=True, host0_only=False)
+    eng, _, _ = _serve(models, "sliding_full_experts", fused=fused,
+                       journal=journal)
+    steps = journal.named("serve.step")
+    engine, = journal.named("serve.engine")
+    path = tmp_path / "journal.jsonl"
+    path.write_text("".join(json.dumps(r) + "\n" for r in journal.records))
+    cfg = eng.cfg
+    chunk, step = (cfg.n_expert_layers * expert_tiles(
+        rows, cfg.experts_per_token, cfg.n_experts_held)[1]
+        for rows in (4 + 3, 3))  # chunk + slots, slots
+    assert engine["moe_tiles_laid"] == [chunk, step]
+    read = [s for s in steps if "moe_tiles_active" in s]
+    assert len(read) >= len(steps) - 6  # all but the calls before a read
+    assert all(0 < s["moe_tiles_active"] <= s["moe_tiles_laid"]
+               for s in read)
+    assert {s["moe_tiles_laid"] for s in read} == (
+        {chunk, step} if fused else {step})
+    # a step that decoded alone has the other three too; a fused one not
+    assert all(("moe_pairs" in s) <= ("moe_tiles_active" in s) for s in steps)
+    for event in ("serve.step", "serve.engine"):
+        assert "moe_tiles_laid" in schema.REGISTRY[event].optional
+    assert schema.REGISTRY["serve.step"].optional["moe_tiles_active"] == "int"
+    rep = obs_report.generate(str(path))
+    text = obs_report.format_report(rep)
+    by_laid = rep["serving"]["moe_tiles"]
+    assert [laid for laid, _, _ in by_laid] == sorted(
+        {chunk, step} if fused else {step}, reverse=True)
+    assert sum(n for _, n, _ in by_laid) == len(read)
+    assert all(0 < live <= laid * n for laid, n, live in by_laid)
+    assert (f"expert row tiles (laid: {chunk} a call with a chunk, {step} a "
+            f"decode-only call), live of laid a call: ") in text
+    laid, n, live = by_laid[-1]
+    assert f"{live / n:.1f} of {laid} (" in text and f"over {n} calls" in text
 
 
 @pytest.mark.parametrize("case", ["int8_kv", "sampled", "two_chunks_a_step",

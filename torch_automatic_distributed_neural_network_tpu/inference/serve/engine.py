@@ -132,6 +132,7 @@ import numpy as np
 
 from ...models.transformer_core import TransformerConfig
 from ...obs import journal as _journal
+from ...parallel.expert import expert_tiles
 from ...training.lora import LoraSpec
 from ..decode import (
     SampleConfig,
@@ -471,6 +472,16 @@ class ServeEngine:
 
             self._fused_fn = jax.jit(serve_prefill_chunk,
                                      donate_argnums=(1,))
+        # the row tiles the expert layers of a call lay out, whatever lands
+        # in them (``moe_tiles_active`` of a step counts those): [a call
+        # with a chunk, a decode-only call]
+        self._tiles_laid = [
+            self.cfg.n_expert_layers * expert_tiles(
+                rows, self.cfg.experts_per_token, self.cfg.n_experts_held)[1]
+            if rows and self.cfg.n_expert_layers else 0
+            for rows in ((self.prefill_chunk or 0)
+                         + n_slots * (self._fused_fn is not None),
+                         n_slots * (1 + self.speculative))]
         # the decode rows of a chunk that carries none: every slot idle
         self._idle_rows = np.zeros((n_slots, max_blocks + 4), np.int32)
         # lifetime counts: steps whose chunk carried the decode rows, and
@@ -532,6 +543,7 @@ class ServeEngine:
                           if self.cfg.n_expert_layers else 0),
             experts_published=(self.cfg.experts_published
                                if self.cfg.n_expert_layers else 0),
+            moe_tiles_laid=self._tiles_laid,
             # latent pages are pages for max_len: in kv_bytes_full too
             kv_bytes_full=self.pool.bytes_full,
             kv_bytes_latent=self.pool.bytes_latent,
@@ -1070,14 +1082,20 @@ class ServeEngine:
             if T > 1:
                 tokens = tokens.reshape(S, T)
             if rows:
-                # the expert layers' three only where there are any, and of
-                # a step that decoded alone: where the rows rode in a chunk
-                # the layers routed its rows with them
-                skip = 0 if self.cfg.n_expert_layers and not fused else 3
+                # the expert layers' only where there are any, and the
+                # first three of a step that decoded alone: where the rows
+                # rode in a chunk the layers routed its rows with them (the
+                # live tiles are set against the tiles that call laid)
+                experts = bool(self.cfg.n_expert_layers)
+                skip = 4 if not experts else 3 if fused else 0
                 self._counters = dict(zip(
                     ("moe_pairs", "moe_experts_touched",
-                     "moe_max_expert_tokens", "attn_grid_items",
-                     "attn_grid_dense")[skip:], map(int, counters[skip:])))
+                     "moe_max_expert_tokens", "moe_tiles_active",
+                     "attn_grid_items", "attn_grid_dense")[skip:],
+                    map(int, counters[skip:])))
+                if experts:
+                    self._counters["moe_tiles_laid"] = self._tiles_laid[
+                        0 if fused else 1]
         with self._phase("emit"):
             self._emit(tokens, first, rows, firsts, drafts)
 

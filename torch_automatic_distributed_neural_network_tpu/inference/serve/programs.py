@@ -217,19 +217,21 @@ def _work_of(kind: str | None) -> str:
     return "latent" if kind == "latent_attention" else _pages_of(kind)
 
 
-N_COUNTERS = 5  # what a step's output carries after its tokens
+N_COUNTERS = 6  # what a step's output carries after its tokens
 
 
 def _moe_counters(stats: list) -> jax.Array:
-    """[pairs that landed here, experts touched, most tokens on one expert]
-    over the step's expert layers (zeros for a model without any)."""
+    """[pairs that landed here, experts touched, most tokens on one expert,
+    row tiles that held pairs] over the step's expert layers (zeros for a
+    model without any)."""
     stats = [s for s in stats if s is not None]
     if not stats:
-        return jnp.zeros((3,), jnp.int32)
+        return jnp.zeros((4,), jnp.int32)
     return jnp.stack([
         sum(s["pairs"] for s in stats),
         sum(s["experts_touched"] for s in stats),
-        jnp.max(jnp.stack([s["max_expert_tokens"] for s in stats]))])
+        jnp.max(jnp.stack([s["max_expert_tokens"] for s in stats])),
+        sum(s["tiles_active"] for s in stats)])
 
 
 def _walk(cfg, params, kv, x, layer_fn, shared, extras=None):
@@ -522,7 +524,7 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
     merged-weight semantics).
 
     Returns ``(kv, logits [S, T, V], counters [N_COUNTERS])``: the expert
-    layers' three (``_moe_counters``), then the grid steps the paged calls
+    layers' four (``_moe_counters``), then the grid steps the paged calls
     of the step ran and the ``slots x groups`` a dense grid would have run,
     summed over the layers (zeros where no call takes a work list)."""
     S, T = tok.shape

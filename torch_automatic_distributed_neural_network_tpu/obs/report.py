@@ -380,6 +380,16 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
             "max_moe_expert_tokens": max(
                 _finite(e.get("moe_max_expert_tokens") for e in ssteps),
                 default=None),
+            # the expert layers' row tiles that held a pair, by the tiles
+            # the call laid out (the engine says what each kind of call
+            # lays): [laid a call, calls, live tiles over them]
+            "moe_tiles_laid": (sengine or {}).get("moe_tiles_laid"),
+            "moe_tiles": [
+                [laid, len(live), sum(live)]
+                for laid in sorted({e.get("moe_tiles_laid") or 0
+                                    for e in ssteps} - {0}, reverse=True)
+                for live in [[e["moe_tiles_active"] for e in ssteps
+                              if e.get("moe_tiles_laid") == laid]]],
             # the grid steps the decode steps' paged attention calls ran
             # (their work lists' items), and a dense slots x groups grid's
             "attn_grid_items": sum(_finite(
@@ -1035,6 +1045,14 @@ def format_report(report: dict) -> str:
                     f"on {sv['mean_moe_experts_touched']:.1f} experts, at "
                     f"most {sv['max_moe_expert_tokens']} tokens on one")
             lines.append("  " + "  ".join(eparts))
+        if sv.get("moe_tiles"):
+            chunk, alone = sv["moe_tiles_laid"] or (0, 0)
+            lines.append(
+                f"  expert row tiles (laid: {chunk} a call with a chunk, "
+                f"{alone} a decode-only call), live of laid a call: "
+                + ", ".join(
+                    f"{live / n:.1f} of {laid} ({live / n / laid:.3f}) "
+                    f"over {n} calls" for laid, n, live in sv["moe_tiles"]))
         if sv.get("attn_grid_dense"):
             lines.append(
                 f"  paged attention grid: {sv['attn_grid_items']} live "

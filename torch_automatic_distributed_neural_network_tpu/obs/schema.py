@@ -342,6 +342,10 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # (one row a token, key and value at once), and that row:
             # [the latent's rank, the rotated key part, numbers stored]
             "kv_bytes_latent": "int", "latent_row": "list?",
+            # the row tiles the expert layers of a call lay out, whatever
+            # lands in them: [a call with a chunk, a decode-only call],
+            # summed over the expert layers (0 and 0 without any)
+            "moe_tiles_laid": "list",
             # decode steps the engine dispatches with the step before
             # unread: 1, or 0 where the next step's operands need the
             # tokens' values (speculative drafts)
@@ -377,6 +381,12 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # back with the step's tokens
             "moe_pairs": "int", "moe_experts_touched": "int",
             "moe_max_expert_tokens": "int",
+            # the row tiles of the step's expert layers that held a pair,
+            # which is all the grouped matmuls read, multiply and write,
+            # and the tiles that step laid out (serve.engine's
+            # moe_tiles_laid: the chunk's where the rows rode in one); on
+            # EVERY call of a model with expert layers, fused ones too
+            "moe_tiles_active": "int", "moe_tiles_laid": "int",
             # the grid steps the decode step's paged attention calls ran
             # (the live (slot, key group) items of the folded kernel's
             # work list) and the slots x groups a dense grid would have

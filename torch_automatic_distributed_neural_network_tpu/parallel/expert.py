@@ -284,6 +284,29 @@ def moe_ffn_sharded(
 # runs without its exchange).
 
 
+def top_k_by_passes(select: jax.Array, scores: jax.Array,
+                    top_k: int) -> tuple[jax.Array, jax.Array]:
+    """``(chosen [T, k] int32, scores there [T, k])``: the ``top_k`` largest
+    of each row of ``select`` [T, E] in descending order, ties to the lower
+    index, which is what ``jax.lax.top_k`` picks, and ``scores`` [T, E] at
+    each choice.  By ``top_k`` passes of max-and-mask over the row: on the
+    chip ``top_k`` sorts all ``E`` scores of a row, which costs more than 8
+    passes over 256, and a gather of ``T * top_k`` single numbers is as
+    many steps, so a pass picks its score too."""
+    ids = jnp.arange(select.shape[-1], dtype=jnp.int32)
+    # a taken expert reads -inf and nothing else does, so that no pass takes
+    # one again (a score of -inf ranks with the lowest finite number)
+    select = jnp.maximum(select, jnp.finfo(select.dtype).min)
+    chosen, picked = [], []
+    for _ in range(top_k):
+        best = jnp.argmax(select, axis=-1).astype(jnp.int32)  # first of equals
+        it = ids == best[..., None]
+        chosen.append(best)
+        picked.append(jnp.where(it, scores, 0).sum(-1))
+        select = jnp.where(it, -jnp.inf, select)
+    return jnp.stack(chosen, axis=-1), jnp.stack(picked, axis=-1)
+
+
 def route_top_k(
     logits: jax.Array,  # [T, E] float32, over all the published experts
     e_bias: jax.Array | None,  # [E]: takes part in the choice, never in the weight
@@ -304,11 +327,20 @@ def route_top_k(
     else:
         raise ValueError(f"unknown score_func {score_func!r}")
     select = scores if e_bias is None else scores + e_bias
-    _, chosen = jax.lax.top_k(select, top_k)
-    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    chosen, weights = top_k_by_passes(select, scores, top_k)
     if route_norm:
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
-    return chosen.astype(jnp.int32), weights * route_scale
+    return chosen, weights * route_scale
+
+
+def expert_tiles(n_rows: int, top_k: int, held: int) -> tuple[int, int]:
+    """``(tm, n_tiles)``: the rows of a tile and the tiles
+    ``held_expert_ffn`` lays for ``n_rows`` tokens: room for every one of the
+    ``n_rows * top_k`` pairs on this chip, each held expert's last tile
+    part empty."""
+    pairs = n_rows * top_k
+    tm = 16 if pairs <= 256 else 128
+    return tm, -(-pairs // tm) + min(held, pairs)
 
 
 def held_expert_ffn(
@@ -331,54 +363,84 @@ def held_expert_ffn(
     each (an expert's rows padded up to a whole tile), so that the grouped
     matmuls (``ops/grouped_matmul.py``) read exactly the experts that own a
     tile; the results are gathered back a pair at a time and summed with
-    the routing weights in float32.  Shapes are static: ``T * k`` pairs in
-    at most ``T * k / tm + min(held, T * k)`` tiles.
+    the routing weights in float32.  Shapes are static (``expert_tiles``:
+    ``T * k`` pairs in at most ``T * k / tm + min(held, T * k)`` tiles, the
+    case of every pair on this chip), the work is not: the pairs that
+    landed here fill the first ``n_active`` tiles, which are the kernels'
+    grid, and the first kernel picks a tile's rows out of ``x`` itself, so
+    no padded copy of the tokens is written.  A tile past ``n_active`` is
+    never written and holds anything (NaN too), as does a tile's padding:
+    a pair that is not here reads row 0 and is masked AFTER the gather (row
+    0 is nobody's when no pair is here: the sum is then exact zeros).
+
+    The index arrays are built from the sort's ``order`` by compares, sums
+    and gathers of whole rows, never by a scatter or a lookup an element:
+    the chip runs either an element at a time (4,288 steps for the pairs of
+    a chunk), and a sum over a [held, pairs] mask is a few vector adds.
 
     Returns ``(y [T, d], stats)`` with the step's counters: ``pairs`` that
-    landed here, ``experts_touched``, ``max_expert_tokens``."""
+    landed here, ``experts_touched``, ``max_expert_tokens``,
+    ``tiles_active`` (``n_active``, of the ``expert_tiles`` laid)."""
     from ..ops.grouped_matmul import grouped_matmul
 
     T, d = x.shape
     k, held = chosen.shape[1], w_up.shape[0]
     P = T * k
-    tm = 16 if P <= 256 else 128
-    n_tiles = -(-P // tm) + min(held, P)
-    Mp = n_tiles * tm
+    tm, n_tiles = expert_tiles(T, k, held)
 
-    local = chosen.reshape(P) - first_expert
+    # pair p is choice p // T of token p % T: a token's pairs lie T apart,
+    # so that the combine adds k whole [T, d] slabs
+    local = chosen.T.reshape(P) - first_expert
     here = (local >= 0) & (local < held)
     if valid is not None:
-        here &= jnp.repeat(valid, k)
+        here &= jnp.tile(valid, k)
     key = jnp.where(here, local, held)  # the pairs of absent experts sort last
-    order = jnp.argsort(key, stable=True)
-    sorted_key = key[order]
-    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    order = jnp.argsort(key, stable=True)  # sorted position -> pair
+    place = jnp.argsort(order)  # pair -> sorted position
+    # [held, P], the experts leading: a sum over them is whole-vector adds
+    mine = key == jnp.arange(held, dtype=key.dtype)[:, None]
+    sizes = mine.sum(1, dtype=jnp.int32)
     starts = jnp.cumsum(sizes) - sizes
     tiles = (sizes + tm - 1) // tm
     tile_ends = jnp.cumsum(tiles)
     n_active = tile_ends[-1]
-    g = jnp.minimum(sorted_key, held - 1)
-    row = (tile_ends - tiles)[g] * tm + jnp.arange(P) - starts[g]
-    row = jnp.where(sorted_key < held, row, Mp)  # Mp: the row of zeros
-    # padded row -> token it copies (T: the row of zeros)
-    src = jnp.full((Mp + 1,), T, jnp.int32).at[row].set(
-        (order // k).astype(jnp.int32))[:Mp]
-    x_ext = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])
-    rows = x_ext[src]
+    first_row = (tile_ends - tiles) * tm  # an expert's first padded row
     tile_group = jnp.minimum(
         jnp.searchsorted(tile_ends, jnp.arange(n_tiles), side="right",
                          method="compare_all"),
         held - 1).astype(jnp.int32)
+    # padded row -> the token it copies (-1: none).  A tile's rows are ONE
+    # run of sorted positions, from ``base`` on while the expert has rows
+    # ``left``: a slice a tile, not a lookup a row ...
+    before = jnp.arange(n_tiles) * tm - first_row[tile_group]
+    base = jnp.minimum(starts[tile_group] + before, P)
+    left = sizes[tile_group] - before  # <= 0 past the last live tile
+    # ... read as the two whole blocks of tm positions it lies in (a gather
+    # of rows) and moved left by what is over, a binary digit at a time
+    # (tm is a power of two): every step a select over the whole array
+    n_blocks = -(-P // tm) + 2
+    blocks = jnp.pad(order % T, (0, n_blocks * tm - P)).reshape(n_blocks, tm)
+    block, over = base // tm, base % tm
+    run = jnp.concatenate([blocks[block], blocks[block + 1]], axis=1)
+    for bit in range(tm.bit_length() - 1):
+        run = jnp.where((over >> bit & 1)[:, None] == 1,
+                        jnp.roll(run, -(1 << bit), axis=1), run)
+    src = jnp.where(jnp.arange(tm) < left[:, None], run[:, :tm], -1)
+    src = src.reshape(-1)
 
-    h = grouped_matmul(rows, w_up, tile_group, n_active, tm=tm, w_gate=w_gate)
+    h = grouped_matmul(x, w_up, tile_group, n_active, tm=tm, w_gate=w_gate,
+                       src=src)
     y = grouped_matmul(h, w_down, tile_group, n_active, tm=tm)
 
-    pair_row = jnp.zeros((P,), jnp.int32).at[order].set(row.astype(jnp.int32))
-    y_ext = jnp.concatenate([y, jnp.zeros((1, d), y.dtype)])
-    per_pair = y_ext[pair_row].reshape(T, k, d).astype(jnp.float32)
-    w = jnp.where(here.reshape(T, k), weights.astype(jnp.float32), 0.0)
-    out = jnp.einsum("tk,tkd->td", w, per_pair).astype(x.dtype)
+    # pair -> its padded row: its sorted position, moved by its expert's
+    # padding (row 0 where the pair is not here)
+    shift = jnp.where(mine, (first_row - starts)[:, None], 0).sum(0)
+    pair_row = jnp.where(here, place + shift, 0)
+    per_pair = jnp.where(here[:, None], y[pair_row], 0).reshape(k, T, d)
+    w = jnp.where(here.reshape(k, T), weights.T.astype(jnp.float32), 0.0)
+    out = jnp.einsum("kt,ktd->td", w, per_pair.astype(jnp.float32))
     stats = {"pairs": here.sum().astype(jnp.int32),
              "experts_touched": (sizes > 0).sum().astype(jnp.int32),
-             "max_expert_tokens": sizes.max().astype(jnp.int32)}
-    return out, stats
+             "max_expert_tokens": sizes.max().astype(jnp.int32),
+             "tiles_active": n_active.astype(jnp.int32)}
+    return out.astype(x.dtype), stats
