@@ -669,6 +669,150 @@ def test_a_latent_reader_finds_nothing_where_there_is_nothing(
     capsys.readouterr()
 
 
+# -- 5b. a call by what it read (PR 37) ----------------------------------------
+
+NEW_SPAN_METRICS = ("chunk_call_ms.backlog", "decode_call_ms.backlog",
+                    "chunk_call_deep_ms.backlog", "serve_stall_ms.backlog")
+
+
+def _call(step, step_s, *, programs=1, rows=8, chunk_rows=0, chunk_pos=0,
+          **more):
+    return {"step": step, "step_s": step_s, "decode_s": step_s * 0.9,
+            "phases": {"decode_wait": 0.8 * step_s, "emit": 1e-4},
+            "gc_s": 0.0, "gc_full": 0, "compiles": 0,
+            "read": {"programs": programs, "rows": rows,
+                     "ctx_keys": 100 * rows, "chunk_rows": chunk_rows,
+                     "chunk_pos": chunk_pos}, **more}
+
+
+def _made_steps() -> list[dict]:
+    """A window by hand: nine shallow and six deep chunk calls, ten
+    decode-only calls, a read that covers 17 programs (long, and no stall),
+    one stalled decode-only call with the collector inside it, and calls
+    that read nothing."""
+    steps = [{"step": i, "step_s": 0.003, "decode_s": 0.0,
+              "phases": {"prefill_dispatch": 0.002}, "gc_s": 0.0,
+              "gc_full": 0, "compiles": 0} for i in range(1, 3)]
+    steps.append(_call(3, 0.5, programs=17, rows=0, chunk_rows=17 * 512,
+                       chunk_pos=16 * 512))
+    n = 3
+    for i in range(9):
+        n += 1
+        steps.append(_call(n, 0.024 + 0.0005 * i, chunk_rows=512,
+                           chunk_pos=512 * (i % 8)))
+    for i in range(6):
+        n += 1
+        steps.append(_call(n, 0.068 + 0.001 * i, chunk_rows=512,
+                           chunk_pos=8192 + 512 * i))
+    for i in range(10):
+        n += 1
+        steps.append(_call(n, 0.0120 + 0.0001 * i))
+    steps.append(_call(n + 1, 2.0, gc_s=1.9, gc_full=1))
+    return steps
+
+
+def _span_reader(name: str):
+    return _load(os.path.join(BENCH, "metrics", name + ".py"),
+                 "bench_metric").read
+
+
+def test_the_call_readers_read_a_hand_made_window(bench, capsys):
+    import statistics
+
+    rec = {"serve_steps": _made_steps()}
+    chunk = [s["step_s"] for s in rec["serve_steps"]
+             if s.get("read", {}).get("programs") == 1
+             and s["read"]["chunk_rows"]]
+    assert len(chunk) == 15
+    assert _span_reader("chunk_call_ms.backlog")(rec) == pytest.approx(
+        1e3 * statistics.median(chunk))
+    assert _span_reader("chunk_call_deep_ms.backlog")(rec) == pytest.approx(
+        1e3 * statistics.median(chunk[9:]))
+    decode = [0.0120 + 0.0001 * i for i in range(10)] + [2.0]
+    m = statistics.median(decode)
+    assert _span_reader("decode_call_ms.backlog")(rec) == pytest.approx(
+        1e3 * m)
+    capsys.readouterr()
+    # ONE stall: the read of 17 programs is none, however long it took
+    assert _span_reader("serve_stall_ms.backlog")(rec) == pytest.approx(
+        1e3 * (2.0 - m))
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    stalls = line["serve_stalls"]
+    assert stalls["n"] == 1 and stalls["window_gc_full"] == 1
+    assert stalls["window_gc_s"] == pytest.approx(1.9)
+    (call,) = stalls["calls"]
+    assert call["kind"] == "decode" and call["phase"] == "decode_wait"
+    assert call["gc_s"] == 1.9 and call["gc_full"] == 1
+    assert call["compiles"] == 0 and call["step_s"] == 2.0
+
+
+def test_a_window_with_four_deep_calls_has_no_deep_median(bench):
+    steps = [s for s in _made_steps()
+             if s.get("read", {}).get("chunk_pos", 0) < 8192 + 4 * 512]
+    assert _span_reader("chunk_call_deep_ms.backlog")(
+        {"serve_steps": steps}) is None
+    assert _span_reader("chunk_call_ms.backlog")(
+        {"serve_steps": steps}) is not None
+
+
+@pytest.mark.parametrize("name", NEW_SPAN_METRICS)
+def test_a_call_reader_finds_nothing_on_a_program_without_read(bench, name,
+                                                               capsys):
+    """What the parent commit's traced run of these readers has to do."""
+    old = [{k: v for k, v in s.items() if k not in ("read", "gc_s",
+                                                      "gc_full")}
+           for s in _made_steps()]
+    assert _span_reader(name)({"serve_steps": old}) is None
+    assert _span_reader(name)({"serve_steps": []}) is None
+    assert _span_reader(name)({}) is None
+    assert capsys.readouterr().out == ""
+
+
+@pytest.mark.parametrize("name", NEW_SPAN_METRICS[:2] + NEW_SPAN_METRICS[3:])
+def test_a_call_reader_reads_the_rehearsal_engines_events(
+        bench, dense, experts, hybrid, latent, name, capsys):
+    for run in (dense, experts, hybrid, latent):
+        value = _span_reader(name)(run["record"])
+        assert value is not None and math.isfinite(value) and value >= 0
+        if "stall" not in name:
+            assert value > 0
+    capsys.readouterr()
+
+
+def test_the_report_counts_calls_as_the_readers_do(bench):
+    """``tadnn report`` cannot import ``benchmark/``: its two lines are the
+    readers' arithmetic in the package's own words."""
+    from torch_automatic_distributed_neural_network_tpu.obs import report
+
+    steps = _made_steps()
+    got, rec = report._calls_by_read(steps), {"serve_steps": steps}
+    for key, name in (("chunk", "chunk_call_ms.backlog"),
+                      ("chunk_deep", "chunk_call_deep_ms.backlog"),
+                      ("decode", "decode_call_ms.backlog")):
+        assert 1e3 * got["calls"][key][0] == pytest.approx(
+            _span_reader(name)(rec))
+    assert [got["calls"][k][1] for k in ("chunk", "chunk_deep", "decode")] \
+        == [15, 6, 11]
+    assert 1e3 * got["stalls"]["lost_s"] == pytest.approx(
+        _span_reader("serve_stall_ms.backlog")(rec))
+    assert got["stalls"]["n"] == 1 and got["stalls"]["gc_full"] == 1
+    assert got["stalls"]["worst"]["phase"] == "decode_wait"
+    assert report._calls_by_read(
+        [{"step": 1, "step_s": 0.1, "phases": {}}]) == {}
+
+
+@pytest.mark.parametrize("name", NEW_SPAN_METRICS)
+def test_a_new_metric_has_its_file_and_its_cells(name):
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
+    assert name + ".py" in METRIC_FILES
+    cells = {w["name"] for w in BENCHMARK["workloads"]}
+    assert set(entry["workloads"]) <= cells and entry["workloads"]
+    e2e = next(m for m in BENCHMARK["end_to_end"]
+               if m["name"] == entry["moves"])
+    assert set(entry["workloads"]) <= set(e2e["workloads"])
+    assert entry["source"] == "program_span" and entry["layer"] == "serve step"
+
+
 def test_the_latent_counts_are_the_arithmetic(bench):
     """69.6 kFLOP and 1,152 B a key a layer at the published widths: 60
     FLOP/B, under the v5e's ridge of 240."""

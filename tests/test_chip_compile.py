@@ -292,6 +292,15 @@ def test_serving_programs_update_the_pool_in_place(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
         operands)).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
+    # the parts of a call (``programs.SCOPES``) stand in the ``op_name`` of
+    # the chip's own instructions: what a trace of the chip is read by
+    scoped = {c for name in re.findall(r'op_name="([^"]*)"', text)
+              for c in name.split("/") if c.startswith("tadnn.")}
+    absent = {"decode_step": {"tadnn.attend_chunk"},
+              "prefill_chunk": {"tadnn.attend_step"}}.get(program, set())
+    if not cfg.n_expert_layers:
+        absent.add("tadnn.ffn_expert")
+    assert scoped == set(programs.SCOPES) - absent
     latent = config == "joyai-llm-flash-ep8"
     mine, other = (("tadnn_paged_decode_latent", "tadnn_paged_decode_folded")
                    if latent else

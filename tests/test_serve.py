@@ -311,7 +311,8 @@ def test_report_renders_serving_section(tmp_path):
 
 def test_report_renders_serving_breakdown(tmp_path):
     """r02 fields: the engine-config event, per-step phase timings and
-    prefill-chunk latency land in the serving section."""
+    the count of prefill chunks (the steps' ``n_prefill_chunks``: a chunk
+    has no record of its own) land in the serving section."""
     jp = tmp_path / "journal.jsonl"
     recs = [{"kind": "event", "name": "serve.engine", "t": 0.0,
              "attention_impl": "paged", "prefill_chunk": 32,
@@ -322,11 +323,8 @@ def test_report_renders_serving_breakdown(tmp_path):
     recs += [{"kind": "event", "name": "serve.step", "t": 0.1 * i,
               "step": i, "n_active": 2, "n_queued": 0,
               "n_prefilling": 1, "occupancy": 0.5, "free_blocks": 3,
-              "prefill_s": 0.02, "decode_s": 0.01} for i in range(1, 4)]
-    recs += [{"kind": "event", "name": "serve.prefill_chunk",
-              "t": 0.05 * i, "rid": 0, "slot": 1, "pos": 32 * i,
-              "n_tokens": 32, "seconds": 0.02, "done": i == 2}
-             for i in (1, 2)]
+              "prefill_s": 0.02, "decode_s": 0.01,
+              "n_prefill_chunks": int(i < 3)} for i in range(1, 4)]
     recs += [{"kind": "event", "name": "serve.request", "t": 0.4,
               "rid": 0, "n_prompt": 40, "n_new": 6, "queue_s": 0.01,
               "prefill_s": 0.05, "decode_s": 0.2, "total_s": 0.26,
@@ -338,11 +336,11 @@ def test_report_renders_serving_breakdown(tmp_path):
     assert srv["attention_impl"] == "paged"
     assert srv["prefill_chunk"] == 32
     assert srv["mean_decode_step_s"] == pytest.approx(0.01)
-    assert srv["mean_prefill_chunk_s"] == pytest.approx(0.02)
+    assert srv["mean_prefill_step_s"] == pytest.approx(0.02)
     assert srv["n_prefill_chunks"] == 2
     text = obs_report.format_report(obs_report.generate(str(jp)))
     assert "decode impl paged" in text
-    assert "prefill chunk" in text
+    assert "prefill chunks x2 (C=32)" in text
     assert ("weights 1.50 GiB in the compute dtype + 0.25 GiB float32 "
             "(12 leaves rounded once)") in text
 

@@ -351,6 +351,7 @@ class ServeEngine:
         # reproduced exactly (bit-identical tokens either way).
         self.journal = journal or _journal.get_default()  # never None
         self._compiles = _journal.compile_counter()
+        self._gc = _journal.gc_counter()
         self._phases: dict[str, float] = {}  # this step's, by PHASES name
         self._prefix_cache = None
         # publish lease: prompts enter the radix index with this TTL
@@ -416,6 +417,14 @@ class ServeEngine:
         self._rows: list[tuple[int, Request, int]] = []
         self._firsts: list[tuple[int, Request, int]] = []
         self._rows_fused = False  # the rows rode in a chunk (``_rides``)
+        # what went out since the last read, which the read that waits for
+        # it puts on its call's event (``_sent_program``), and what this
+        # call has read so far (``serve.step``'s ``read``)
+        self._sent = self._nothing_sent()
+        # ... after the unread output was made (a chunk in its own place
+        # behind an unread step): the read AFTER that one waits for it
+        self._sent_late: dict | None = None
+        self._step_read: dict | None = None
         # lifetime counts: decode steps dispatched with the step before
         # unread, and slot-steps decoded and thrown away (a step in flight
         # when its request's EOS was read or it was preempted); step()
@@ -850,7 +859,6 @@ class ServeEngine:
         the adapter is already pinned."""
         st = self._prefill[req.rid]
         with self._phase("prefill_dispatch", rid=req.rid, pos=st.pos):
-            t0 = time.monotonic()
             packed, n_real = self._chunk_operands(slot, req, single_shot)
             if st.lora is not None:
                 self.pool.kv, logits = self._prefill_lora_fn(
@@ -867,19 +875,22 @@ class ServeEngine:
             else:
                 self.pool.kv, logits = self._prefill_fn(
                     self.params, self.pool.kv, packed, self._win_rows[slot])
-        self._chunk_ran(slot, req, n_real, logits, t0, single_shot)
+        self._sent_program(chunk=(n_real, st.pos))
+        self._chunk_ran(slot, req, n_real, logits, single_shot)
 
     def _chunk_ran(self, slot: int, req: Request, n_real: int, logits,
-                   t0: float, single_shot: bool = False) -> None:
-        """A chunk of ``n_real`` tokens of ``req``'s prompt is dispatched
-        (at ``t0``): move the cursor.  On the final chunk: pin the adapter
-        (bouncing the request if the pool is full), sample the first token
-        ON THE DEVICE from the chunk's ``logits``, into the newest step
-        output, and hand the slot to decode: its first step reads the token
-        there, and the host fetches it with its next read (at once only
-        where it reads before it dispatches)."""
+                   single_shot: bool = False) -> None:
+        """A chunk of ``n_real`` tokens of ``req``'s prompt is dispatched:
+        move the cursor.  On the final chunk: pin the adapter (bouncing the
+        request if the pool is full), sample the first token ON THE DEVICE
+        from the chunk's ``logits``, into the newest step output, and hand
+        the slot to decode: its first step reads the token there, and the
+        host fetches it with its next read (at once only where it reads
+        before it dispatches)."""
         st = self._prefill[req.rid]
         st.pos += n_real
+        if not single_shot:
+            req.prefill_chunks += 1
         done = st.pos >= req.n_prompt
         bounced = (done and not single_shot
                    and not self._bind_adapter(slot, req))
@@ -888,6 +899,11 @@ class ServeEngine:
                 self._out = self._first_fn(
                     self._out, logits,
                     np.asarray([slot, req.rid], np.int32), self._rng)
+            if self._sent_late is not None:
+                # the unread output now waits for this chunk, and so for
+                # every program that went out before it
+                self._sent = self._covers(self._sent, self._sent_late)
+                self._sent_late = None
             self._publish_prefill(slot, req)
             req.n_inflight = 1
             self._firsts.append((slot, req, req.preempted))
@@ -897,18 +913,6 @@ class ServeEngine:
                 # no chunk is fenced, and this wait only where the next
                 # step's drafts need the token
                 self._read("prefill_first_token", self._take_unread())
-        if single_shot:
-            return
-        # host seconds: a dispatch (and a reader-first engine's wait on the
-        # last chunk)
-        chunk_s = time.monotonic() - t0
-        req.prefill_chunks += 1
-        req.prefill_compute_s += chunk_s
-        self.journal.event(
-            "serve.prefill_chunk", rid=req.rid, slot=slot,
-            pos=min(st.pos, req.n_prompt), n_tokens=n_real,
-            seconds=chunk_s,
-            done=bool(done and not bounced))
 
     def _rides(self, plan: list) -> bool:
         """Whether this call's decode rows ride in the last chunk of
@@ -977,11 +981,54 @@ class ServeEngine:
 
     def _take_unread(self) -> tuple:
         """(output array, its unread step rows, its unread first tokens,
-        whether those rows rode in a chunk), handed over: the engine's lists
-        start anew."""
-        unread = (self._out, self._rows, self._firsts, self._rows_fused)
+        whether those rows rode in a chunk, what was dispatched into it
+        since the last read), handed over: the engine's lists start anew,
+        and so does the account of what went out, unless there is nothing
+        to read (what went out then waits for the read that covers it)."""
+        unread = (self._out, self._rows, self._firsts, self._rows_fused,
+                  self._sent)
+        if self._rows or self._firsts:
+            self._sent = self._sent_late or self._nothing_sent()
+            self._sent_late = None
         self._rows, self._firsts, self._rows_fused = [], [], False
         return unread
+
+    @staticmethod
+    def _nothing_sent() -> dict:
+        return {"programs": 0, "rows": 0, "ctx_keys": 0, "chunk_rows": 0,
+                "chunk_pos": 0}
+
+    @staticmethod
+    def _covers(first: dict, then: dict) -> dict:
+        """``then`` with what went out before it, ``first``, added."""
+        if not then["chunk_rows"]:
+            then["chunk_pos"] = first["chunk_pos"]
+        for k in ("programs", "rows", "ctx_keys", "chunk_rows"):
+            then[k] += first[k]
+        return then
+
+    def _sent_program(self, rows: int = 0, ctx_keys: int = 0,
+                      chunk: tuple[int, int] | None = None) -> None:
+        """One program that walks the layers went out (a first token's
+        sampler rides with its chunk and is not counted): its decode
+        ``rows`` and the sum of their context lengths, the ``(real rows,
+        first position)`` of the ``chunk`` it carried.  Host integers, for
+        the event of the call that will wait for it (``serve.step``'s
+        ``read``): they add up over what one read covers, but for
+        ``chunk_pos``, which is the last chunk's.  What goes out while an
+        output is unread (a chunk in its own place) is not waited for by
+        that output's read, unless it is a prompt's last (``_chunk_ran``)."""
+        sent = self._sent
+        if self._rows or self._firsts:
+            if self._sent_late is None:
+                self._sent_late = self._nothing_sent()
+            sent = self._sent_late
+        sent["programs"] += 1
+        sent["rows"] += rows
+        sent["ctx_keys"] += ctx_keys
+        if chunk is not None:
+            sent["chunk_rows"] += chunk[0]
+            sent["chunk_pos"] = chunk[1]
 
     def _dispatch(self, rider: tuple | None = None) -> np.ndarray | None:
         """One decode step for every running slot, dispatched and not
@@ -1005,7 +1052,7 @@ class ServeEngine:
             tok = np.zeros((S, T), np.int32)
             ids = np.zeros((S,), np.int32)
             src = np.zeros((S,), np.int32)
-            rows = []
+            rows, ctx_keys = [], 0  # (a sum in Python: no reduction's call)
             for s, req in enumerate(self.scheduler.slots):
                 if req is None or req.state != "running":
                     # prefilling slots keep an all-null table here: the
@@ -1016,7 +1063,8 @@ class ServeEngine:
                 # this step writes token n_dispatched at absolute position
                 # n_prompt + n_dispatched - 1 (the first generated token
                 # came from prefill and was never written)
-                ctx[s] = req.n_prompt + req.n_dispatched - 1
+                ctx[s] = keys = req.n_prompt + req.n_dispatched - 1
+                ctx_keys += keys
                 if not req.n_inflight:
                     src[s] = programs.TOKEN_HOST
                     tok[s, 0] = req.out_tokens[-1]
@@ -1054,6 +1102,9 @@ class ServeEngine:
                     self._win_rows[rider[0]], self.pool.win_tables, step_rng)
         for _, req, _ in rows:
             req.n_inflight += 1
+        self._sent_program(  # (before the rows are unread ones)
+            len(rows), ctx_keys, None if rider is None
+            else (n_real, self._prefill[rider[1].rid].pos))
         self._rows = rows
         if rider is not None:
             self._rows_fused = True
@@ -1061,7 +1112,9 @@ class ServeEngine:
             self.fused_decode_rows += len(rows)
             # after the rows are the engine's: the prompt's first token goes
             # into THIS call's output, which holds them
-            self._chunk_ran(*rider, n_real, logits, t0)
+            self._chunk_ran(*rider, n_real, logits)
+            # host seconds: packing the chunk's operands and the dispatch
+            rider[1].prefill_compute_s += time.monotonic() - t0
         return tok
 
     def _read(self, wait: str, unread: tuple,
@@ -1069,9 +1122,12 @@ class ServeEngine:
         """Fetch an output array (phase ``wait``) and hand its unread
         tokens to their requests (phase ``emit``); ``unread`` is what
         ``_take_unread`` gave."""
-        out, rows, firsts, fused = unread
+        out, rows, firsts, fused, sent = unread
         if not rows and not firsts:
             return
+        if self._step_read is not None:  # a call's second read
+            sent = self._covers(self._step_read, sent)
+        self._step_read = sent
         S, T = self.n_slots, 1 + self.speculative
         with self._phase(wait):
             # first tokens and the step's counters ride with its tokens:
@@ -1230,8 +1286,10 @@ class ServeEngine:
         discarded_before = self.discarded_tokens
         fused_before = self.fused_steps, self.fused_decode_rows
         compiles, compile_s = self._compiles.n, self._compiles.seconds
+        gc_s, gc_full = self._gc.total_s, self._gc.passes[self._gc.FULL]
         self._phases = phases = {}
         self._counters = {}
+        self._step_read = None
         whole: dict[str, float] = {}
         n_chunks = 0
         with _journal.phase(whole, "step_s", "serve.step",
@@ -1254,7 +1312,11 @@ class ServeEngine:
                 n_chunks += 1
                 t0 = time.monotonic()
                 self._advance_prefill(slot, req)
-                prefill_s += time.monotonic() - t0
+                # host seconds: a dispatch (and a reader-first engine's
+                # wait on the last chunk)
+                chunk_s = time.monotonic() - t0
+                prefill_s += chunk_s
+                req.prefill_compute_s += chunk_s
                 self._evict_ended(slot)  # chunked, max_new_tokens == 1
             with self._phase("grow"):
                 for victim in sched.grow_for_step():
@@ -1273,6 +1335,10 @@ class ServeEngine:
                 self._decode_all(rider)
                 decode_s = time.monotonic() - t0
         t_end = sched.clock()
+        # the collector's time lies INSIDE whichever phase allocated: no
+        # phase of its own, so that step_s less the phases stays self time
+        gc_s = self._gc.total_s - gc_s
+        gc_full = self._gc.passes[self._gc.FULL] - gc_full
         self._step_count += 1
         self._occupancy_sum += sched.n_active / self.n_slots
         self.prefill_busy_s += prefill_s
@@ -1309,8 +1375,10 @@ class ServeEngine:
                 prefix_blocks=self._prefix_cache.n_blocks,
                 prefix_hit_tokens=self._prefix_cache.hit_tokens)
         # prefill_s is dispatch time; decode_s is this call's dispatch and
-        # the device_get of the step dispatched a call ago.  ahead: this
-        # call's step went out with that one unread
+        # the device_get of the step dispatched a call ago (``read`` says
+        # what that wait covered).  ahead: this call's step went out with
+        # that one unread
+        read = {} if self._step_read is None else {"read": self._step_read}
         self.journal.event(
             "serve.step", step=self._step_count,
             n_active=sched.n_active, n_queued=sched.n_queued,
@@ -1324,6 +1392,7 @@ class ServeEngine:
             overlap_s=overlap_s,
             phases=phases, step_s=whole["step_s"], t_end=t_end,
             n_prefill_chunks=n_chunks, compiles=compiles,
+            gc_s=gc_s, gc_full=gc_full, **read,
             ahead=self.steps_ahead - ahead_before,
             discarded_tokens=self.discarded_tokens - discarded_before,
             fused=self.fused_steps - fused_before[0],

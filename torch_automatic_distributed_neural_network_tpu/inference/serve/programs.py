@@ -110,8 +110,22 @@ _NEG_BIG = -0.7 * float(np.finfo(np.float32).max)
 # that the engine's ``first_token`` left before them (``prev[:S]``)
 TOKEN_HOST, TOKEN_PREV, TOKEN_FIRST = 1, 2, 3
 KEY_BLOCK = 512  # keys a step of the chunk's attention takes
+# The parts of a call, as ``jax.named_scope``s: a component of each device
+# op's ``op_name`` (a fusion has its root's), by which a trace is read.  A
+# contract like the kernels' ``tadnn_*`` names (PERF.md section 3).
+SCOPES = (
+    "tadnn.embed",         # ``_embed``
+    "tadnn.mix_in",        # a layer's first norm and its mixer's projections
+    "tadnn.attend_chunk",  # ``_chunk_*``: a chunk's rows against the cache
+    "tadnn.attend_step",   # ``_step_*``: the decode rows against the cache
+    "tadnn.mix_out",       # the output projection, its norm, the residual
+    "tadnn.ffn",           # a dense FFN (and the toy routed experts)
+    "tadnn.ffn_expert",    # ``SparseMLP``: router, layout, kernels, combine
+    "tadnn.head",          # the final norm, the logits and the sampler
+)
 
 
+@jax.named_scope("tadnn.embed")
 def _embed(params, cfg: TransformerConfig, tok, positions):
     x = embedding_lookup(params["embed"]["embedding"], tok, cfg.dtype)
     if cfg.embed_scale:
@@ -121,6 +135,7 @@ def _embed(params, cfg: TransformerConfig, tok, positions):
     return x
 
 
+@jax.named_scope("tadnn.head")
 def _logits(params, cfg: TransformerConfig, x):
     x = make_norm(cfg).apply({"params": params["final_norm"]}, x)
     feats = x.astype(jnp.float32)
@@ -155,47 +170,57 @@ def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
     norm = make_norm(cfg)
     # int8 weight-only serving: only this layer's weights convert
     lp = dequantize_tree(lp, dtype)
-    h = norm.apply({"params": lp["attn_norm"]}, x) if cfg.pre_norm else x
-    if kind == "linear_attention":
-        mixer, own = GatedDeltaMixer(cfg), {"params": lp["attn"]}
-        o = attend(lambda *a: mixer.apply(own, *a, method="convolve"),
-                   *mixer.apply(own, h, method="project"))
-        ao = mixer.apply(own, o, h, method="out_proj")
-    elif kind == "latent_attention":
-        mixer, own = LatentAttention(cfg), {"params": lp["attn"]}
-        o = attend(lambda name, *a: mixer.apply(own, *a, method=name),
-                   *mixer.apply(own, h, positions, method="project"))
-        ao = mixer.apply(own, o.astype(dtype), method="out_proj")
-    else:
-        attn = SelfAttention(cfg, kind)
-        q, k, v = attn.apply({"params": lp["attn"]}, h, positions,
-                             method="qkv")
-        if adapted is not None:
-            hf = h.astype(jnp.float32)
-            q = adapted(q, "q", hf, cfg.layer_rotates(kind))
-            k = adapted(k, "k", hf, cfg.layer_rotates(kind))
-            v = adapted(v, "v", hf, False)
-        o = attend(q, k, v)
-        ao = attn.apply({"params": lp["attn"]}, o.astype(dtype), h,
-                        method="out_proj")
-        if adapted is not None:
-            ao = adapted(ao, "o", o.reshape(*o.shape[:2], -1).astype(
-                jnp.float32), False)
-    if cfg.sandwich_norm:
-        ao = norm.apply({"params": lp["post_attn_norm"]}, ao)
-    x = x + ao
-    h = norm.apply({"params": lp["mlp_norm"]}, x) if cfg.pre_norm else x
+    own = {"params": lp["attn"]}
+    # a mixer in three parts: its projections of the normed input (what
+    # ``attend`` takes), ``attend`` (scoped in ``_chunk_*`` / ``_step_*``),
+    # and the way back out
+    with jax.named_scope("tadnn.mix_in"):
+        h = norm.apply({"params": lp["attn_norm"]}, x) if cfg.pre_norm else x
+        if kind == "linear_attention":
+            mixer = GatedDeltaMixer(cfg)
+            rows = (lambda *a: mixer.apply(own, *a, method="convolve"),
+                    *mixer.apply(own, h, method="project"))
+        elif kind == "latent_attention":
+            mixer = LatentAttention(cfg)
+            rows = (lambda name, *a: mixer.apply(own, *a, method=name),
+                    *mixer.apply(own, h, positions, method="project"))
+        else:
+            mixer = SelfAttention(cfg, kind)
+            q, k, v = mixer.apply(own, h, positions, method="qkv")
+            if adapted is not None:
+                hf = h.astype(jnp.float32)
+                q = adapted(q, "q", hf, cfg.layer_rotates(kind))
+                k = adapted(k, "k", hf, cfg.layer_rotates(kind))
+                v = adapted(v, "v", hf, False)
+            rows = (q, k, v)
+    o = attend(*rows)
+    with jax.named_scope("tadnn.mix_out"):
+        if kind == "linear_attention":
+            ao = mixer.apply(own, o, h, method="out_proj")
+        elif kind == "latent_attention":
+            ao = mixer.apply(own, o.astype(dtype), method="out_proj")
+        else:
+            ao = mixer.apply(own, o.astype(dtype), h, method="out_proj")
+            if adapted is not None:
+                ao = adapted(ao, "o", o.reshape(*o.shape[:2], -1).astype(
+                    jnp.float32), False)
+        if cfg.sandwich_norm:
+            ao = norm.apply({"params": lp["post_attn_norm"]}, ao)
+        x = x + ao
     counters = None
-    if sparse:
-        h, counters = SparseMLP(cfg).apply({"params": lp["mlp"]}, h, valid)
-    elif "experts_up" in lp["mlp"]:  # the capacity-routed toy experts
-        h = (_moe_mlp_routed(lp["mlp"], h, cfg) if moe == "routed"
-             else _moe_mlp_cached(lp["mlp"], h, cfg))
-    else:
-        h = MLPBlock(cfg).apply({"params": lp["mlp"]}, h)
-    if cfg.sandwich_norm:
-        h = norm.apply({"params": lp["post_mlp_norm"]}, h)
-    return x + h, counters
+    with jax.named_scope("tadnn.ffn_expert" if sparse else "tadnn.ffn"):
+        h = norm.apply({"params": lp["mlp_norm"]}, x) if cfg.pre_norm else x
+        if sparse:
+            h, counters = SparseMLP(cfg).apply({"params": lp["mlp"]}, h,
+                                               valid)
+        elif "experts_up" in lp["mlp"]:  # the capacity-routed toy experts
+            h = (_moe_mlp_routed(lp["mlp"], h, cfg) if moe == "routed"
+                 else _moe_mlp_cached(lp["mlp"], h, cfg))
+        else:
+            h = MLPBlock(cfg).apply({"params": lp["mlp"]}, h)
+        if cfg.sandwich_norm:
+            h = norm.apply({"params": lp["post_mlp_norm"]}, h)
+        return x + h, counters
 
 
 def _paged(cfg) -> list[int]:
@@ -311,6 +336,7 @@ def _step_shared(cfg, kv, tables, win_tables, ctx_lens, active, adapter_ids,
     return shared, grid
 
 
+@jax.named_scope("tadnn.attend_step")
 def _step_attention(cfg, kind, shared, k_l, v_l, q, k, v, *,
                     attention_impl: str, mesh):
     """``q``, ``k``, ``v`` [S, T, heads, hd]: every slot's T tokens written
@@ -350,6 +376,7 @@ def _latent_sizes(cfg) -> tuple[int, int, float]:
     return r, rot, (cfg.latent_nope_head_dim + rot) ** -0.5
 
 
+@jax.named_scope("tadnn.attend_step")
 def _step_latent(cfg, shared, pages, none, piece, q_nope, q_rope, latent, *,
                  attention_impl: str):
     """``q_nope``, ``q_rope`` [S, T, H, .], ``latent`` [S, T, F]: every
@@ -378,6 +405,7 @@ def _step_latent(cfg, shared, pages, none, piece, q_nope, q_rope, latent, *,
     return piece("lift", o), pages
 
 
+@jax.named_scope("tadnn.attend_step")
 def _step_state(shared, state, tails, convolve, pre, g, beta):
     """``pre`` [S, 1, D], ``g``, ``beta`` [S, 1, H]: one token a slot, the
     step form of the rule on the slots' rows of ``state`` and ``tails``."""
@@ -427,6 +455,7 @@ def _chunk_shared(packed, win_row, max_blocks: int):
     return shared
 
 
+@jax.named_scope("tadnn.attend_chunk")
 def _chunk_attention(cfg, kind, shared, k_l, v_l, q, k, v):
     """``q``, ``k``, ``v`` [C, heads, hd]: the chunk's keys and values
     written into the slot's pages, then its queries over them."""
@@ -437,6 +466,7 @@ def _chunk_attention(cfg, kind, shared, k_l, v_l, q, k, v):
                            cfg.kv_heads), k_l, v_l
 
 
+@jax.named_scope("tadnn.attend_chunk")
 def _chunk_latent(cfg, shared, pages, piece, q_nope, q_rope, latent):
     """``q_nope``, ``q_rope`` [C, H, .], ``latent`` [C, F]: the chunk's
     latent rows written into the slot's pages, then its queries over the
@@ -464,6 +494,7 @@ def _chunk_latent(cfg, shared, pages, piece, q_nope, q_rope, latent):
     return o.transpose(1, 0, 2).astype(q_nope.dtype), pages
 
 
+@jax.named_scope("tadnn.attend_chunk")
 def _chunk_state(shared, state, tails, convolve, pre, g, beta):
     """``pre`` [C, D], ``g``, ``beta`` [C, H]: the chunk form of the rule
     from the state the chunk before left in the slot's row, or from zeros
@@ -618,11 +649,12 @@ def decode_step(params, kv, packed, prev, win_tables, adapters, rng, *,
     kv, logits, counters = decode_logits(
         params, kv, tables, win_tables, ctx_lens, tok, active, adapters,
         packed[:, -1], cfg=cfg, **kw)
-    if n_tok == 1:
-        out = jnp.where(active, _sample(logits[:, 0], rng, sample), 0)
-    else:  # the all-logits discipline of decode.generate
-        tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, T]
-        out = jnp.where(active[:, None], tgt, 0).reshape(-1)
+    with jax.named_scope("tadnn.head"):
+        if n_tok == 1:
+            out = jnp.where(active, _sample(logits[:, 0], rng, sample), 0)
+        else:  # the all-logits discipline of decode.generate
+            tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, T]
+            out = jnp.where(active[:, None], tgt, 0).reshape(-1)
     return kv, jnp.concatenate([firsts, out, counters])
 
 
@@ -838,7 +870,8 @@ def chunk_and_step(params, kv, packed, prev, win_row, win_tables, rng, *,
     last = jax.lax.dynamic_index_in_dim(
         x[0], shared["chunk"]["last_idx"], keepdims=True)
     logits = _logits(params, cfg, jnp.concatenate([x[0, C:], last]))
-    out = jnp.where(active, _sample(logits[:S], rng, sample), 0)
+    with jax.named_scope("tadnn.head"):
+        out = jnp.where(active, _sample(logits[:S], rng, sample), 0)
     return kv, jnp.concatenate(
         [firsts, out, _moe_counters(stats), grid]), logits[S:]
 

@@ -18,8 +18,8 @@ Buckets (the TorchTitan-style breakdown, PAPERS.md):
 construction; ``goodput`` is step / total.
 
 A measured bucket is a ``journal.phase``: under a profiler capture it
-shows on the timeline as ``train.<bucket>``, but for the two ``TIMELINE``
-renames (``measure("step")`` times the fence on a dispatched step).
+shows on the timeline as ``train.<bucket>`` (``train.step`` is the fence on
+a dispatched step; the dispatch itself is ``train.step_dispatch``).
 """
 
 from __future__ import annotations
@@ -30,7 +30,6 @@ from .journal import phase
 
 BUCKETS = ("compile", "step", "checkpoint", "eval", "trace",
            "input_stall", "idle")
-TIMELINE = {"step": "train.fence", "input_stall": "train.input"}
 
 
 class GoodputMeter:
@@ -51,8 +50,7 @@ class GoodputMeter:
         self.seconds[self._known(bucket)] += max(0.0, seconds)
 
     def measure(self, bucket: str) -> phase:
-        return phase(self.seconds, self._known(bucket),
-                     TIMELINE.get(bucket, "train." + bucket))
+        return phase(self.seconds, self._known(bucket), "train." + bucket)
 
     def total_wall_s(self) -> float:
         return time.monotonic() - self._t_start

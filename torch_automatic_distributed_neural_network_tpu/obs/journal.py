@@ -109,6 +109,63 @@ def compile_counter() -> CompileCounter:
     return _compile_counter
 
 
+class GcCounter:
+    """Seconds and passes of Python's cyclic collector in this process, by
+    generation, counted on ``gc.callbacks`` (two clock reads a pass, nothing
+    when no pass runs).  ``gc_counter()``.  A full (generation-2) pass is a
+    ``gc.full`` profiler annotation from its ``start`` to its ``stop`` as
+    well, so under a capture it lies on the host thread's lane of the
+    timeline, on the device trace's clock, inside whichever ``serve.*`` or
+    ``train.*`` phase it held up.  Like the compile count it is the
+    process's: a reader that diffs it over an interval (``serve.step``'s
+    ``gc_s``, ``gc_full``) also sees a pass that another thread's
+    allocations set off in that interval."""
+
+    FULL = 2  # the generation whose pass walks every tracked object
+
+    def __init__(self):
+        self.seconds = [0.0, 0.0, 0.0]
+        self.passes = [0, 0, 0]
+        self._t0 = 0.0
+        self._annotation = None
+
+    @property
+    def total_s(self) -> float:
+        return sum(self.seconds)
+
+    def _on(self, when: str, info: dict) -> None:
+        global _TraceAnnotation
+        gen = info["generation"]
+        if when == "start":
+            if gen == self.FULL:
+                if _TraceAnnotation is None:
+                    from jax.profiler import \
+                        TraceAnnotation as _TraceAnnotation
+                self._annotation = _TraceAnnotation("gc.full")
+                self._annotation.__enter__()
+            self._t0 = time.monotonic()
+            return
+        self.seconds[gen] += time.monotonic() - self._t0
+        self.passes[gen] += 1
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+
+
+_gc_counter: GcCounter | None = None
+
+
+def gc_counter() -> GcCounter:
+    """The process's one collector counter, registered on first call."""
+    global _gc_counter
+    if _gc_counter is None:
+        import gc
+
+        _gc_counter = GcCounter()
+        gc.callbacks.append(_gc_counter._on)
+    return _gc_counter
+
+
 def _process_index() -> int:
     """Host index, without forcing jax (or its backend) to load."""
     try:

@@ -12,6 +12,7 @@ from __future__ import annotations
 import json
 import math
 import os
+import statistics
 from typing import Any
 
 from .journal import Journal
@@ -69,6 +70,75 @@ def _finite(vals) -> list[float]:
 def _mean(vals) -> float | None:
     vals = _finite(vals)
     return sum(vals) / len(vals) if vals else None
+
+
+DEEP_POS = 8192      # a chunk from this position on is a deep one
+STALL_FLOOR_S = 0.05  # a stall is this much over its kind's median, and 3x
+KEYS_FLOOR = 1024    # decode rows that read fewer keys than this are one group
+
+
+def _calls_by_read(ssteps: list[dict]) -> dict:
+    """The serving calls by what they WAITED for (``serve.step``'s ``read``:
+    the one program that went out a call before; a read that covers several
+    is no steady-state call and is left out): ``{kind: [median step_s,
+    calls]}`` for a call whose program carried a prefill chunk, the deep
+    ones of those apart, and a decode-only one; and the stalls, a call more
+    than three times and 50 ms over the median of its kind (chunk calls by
+    4,096 positions of depth, since a chunk's attention grows with it), with
+    what the collector took inside them.  The decode-only calls also by
+    the keys a row read (``read.ctx_keys`` over ``read.rows``, in doublings
+    from ``KEYS_FLOOR``): a decode step's attention grows with them as a
+    chunk's does with its position.  ``{}`` for a journal without
+    ``read``."""
+    calls = [e for e in ssteps if (e.get("read") or {}).get("programs") == 1
+             and e.get("step_s") is not None]
+    if not calls:
+        return {}
+
+    def bucket(e) -> tuple:
+        r = e["read"]
+        return (("chunk", r["chunk_pos"] // 4096) if r["chunk_rows"]
+                else ("decode",))
+
+    def median_ms(group) -> list | None:
+        return ([statistics.median(e["step_s"] for e in group), len(group)]
+                if group else None)
+
+    chunk = [e for e in calls if e["read"]["chunk_rows"]]
+    deep = [e for e in chunk if e["read"]["chunk_pos"] >= DEEP_POS]
+    decode = [e for e in calls if not e["read"]["chunk_rows"]]
+    out: dict[str, Any] = {"calls": {k: v for k, v in (
+        ("chunk", median_ms(chunk)), ("chunk_deep", median_ms(deep)),
+        ("decode", median_ms(decode))) if v}}
+    by_keys: dict[int, list] = {}
+    for e in decode:
+        keys = e["read"]["ctx_keys"] // max(1, e["read"]["rows"])
+        lo = 0 if keys < KEYS_FLOOR else 1 << keys.bit_length() - 1
+        by_keys.setdefault(lo, []).append(e)
+    if len(by_keys) > 1:
+        out["decode_by_keys"] = {
+            f"{lo}-{max(KEYS_FLOOR, 2 * lo) - 1}": median_ms(by_keys[lo])
+            for lo in sorted(by_keys)}
+    by: dict[tuple, list] = {}
+    for e in calls:
+        by.setdefault(bucket(e), []).append(e)
+    stalled = []
+    for group in by.values():
+        m = statistics.median(e["step_s"] for e in group)
+        stalled += [(e, m) for e in group
+                    if e["step_s"] > max(3 * m, m + STALL_FLOOR_S)]
+    if stalled:
+        worst, m = max(stalled, key=lambda em: em[0]["step_s"] - em[1])
+        phases = worst.get("phases") or {}
+        out["stalls"] = {
+            "n": len(stalled),
+            "lost_s": sum(e["step_s"] - m for e, m in stalled),
+            "gc_s": sum(e.get("gc_s") or 0.0 for e, _ in stalled),
+            "gc_full": sum(e.get("gc_full") or 0 for e, _ in stalled),
+            "worst": {"step": worst.get("step"), "step_s": worst["step_s"],
+                      "median_s": m, "kind": bucket(worst)[0],
+                      "phase": max(phases, key=phases.get, default=None)}}
+    return out
 
 
 def generate(target: str, metrics_path: str | None = None) -> dict:
@@ -322,7 +392,6 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
     ssteps = [e for e in events if e.get("name") == "serve.step"]
     spreempt = [e for e in events if e.get("name") == "serve.preempt"]
     sengine = last("serve.engine")
-    schunks = [e for e in events if e.get("name") == "serve.prefill_chunk"]
     if sreqs or ssteps:
         totals = sorted(_finite(e.get("total_s") for e in sreqs))
 
@@ -358,9 +427,8 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                                         for e in ssteps),
             "mean_prefill_step_s": _mean(e.get("prefill_s")
                                          for e in ssteps),
-            "n_prefill_chunks": len(schunks) or None,
-            "mean_prefill_chunk_s": _mean(e.get("seconds")
-                                          for e in schunks),
+            "n_prefill_chunks": sum(
+                e.get("n_prefill_chunks") or 0 for e in ssteps) or None,
             "attention_impl": (sengine or {}).get("attention_impl"),
             "prefill_chunk": (sengine or {}).get("prefill_chunk"),
             # the tree the base programs take (engine construction)
@@ -436,21 +504,18 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
             # a step's decode rows may ride in its prefill chunk, one
             # program for both (engines that journal the two counters):
             # the share of the steps with a chunk that carried them, and
-            # of the decode rows read that rode in one (a read brings a
-            # token or a slot-step thrown away a row, and a first token a
-            # prompt whose prefill ended)
+            # of the decode rows read that rode in one (``read.rows``: left
+            # out for an engine from before ``read``)
             fused = [e for e in ssteps if e.get("fused")]
             if fused:
-                rows_read = (
-                    sum((e.get("new_tokens") or 0)
-                        + (e.get("discarded_tokens") or 0) for e in ssteps)
-                    - sum(1 for e in schunks if e.get("done")))
+                reads = [e["read"] for e in ssteps if e.get("read")]
                 serving["fused_steps"] = len(fused)
                 serving["fused_share_of_chunk_steps"] = len(fused) / sum(
                     1 for e in ssteps if e.get("n_prefill_chunks"))
-                serving["fused_share_of_decode_rows"] = sum(
-                    e.get("fused_decode_rows") or 0
-                    for e in fused) / max(1, rows_read)
+                if reads:
+                    serving["fused_share_of_decode_rows"] = sum(
+                        e.get("fused_decode_rows") or 0
+                        for e in fused) / max(1, sum(r["rows"] for r in reads))
             # the two kinds of decoding step behind the ITL's two
             # modes: a step that also ran prefill chunks, and one that
             # only decoded
@@ -465,6 +530,12 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                 serving["mean_step_decode_only_s"] = _mean(
                     e.get("step_s") for e in decoding
                     if not e.get("n_prefill_chunks"))
+            # the same by what a call WAITED for, which is what its time
+            # is (a step is read one call late), and the stalls
+            by_read = _calls_by_read(ssteps)
+            serving["calls_by_read"] = by_read.get("calls")
+            serving["decode_calls_by_keys"] = by_read.get("decode_by_keys")
+            serving["stalls"] = by_read.get("stalls")
         # request span timelines (r06 serve.request_done fields): TTFT
         # and inter-token latency percentiles plus the mean phase mix —
         # where a request's wall time went, attributed per phase
@@ -1000,10 +1071,9 @@ def format_report(report: dict) -> str:
         if sv.get("mean_decode_step_s") is not None:
             bparts.append(
                 f"decode step {sv['mean_decode_step_s'] * 1e3:.1f}ms")
-        if sv.get("mean_prefill_chunk_s") is not None:
+        if sv.get("n_prefill_chunks"):
             bparts.append(
-                f"prefill chunk {sv['mean_prefill_chunk_s'] * 1e3:.1f}ms"
-                f" x{sv.get('n_prefill_chunks', 0)}"
+                f"prefill chunks x{sv['n_prefill_chunks']}"
                 + (f" (C={sv['prefill_chunk']})"
                    if sv.get("prefill_chunk") else ""))
         if sv.get("weights_cast") is not None:
@@ -1087,8 +1157,10 @@ def format_report(report: dict) -> str:
                 f"  {sv['fused_steps']} prefill chunk(s) carried the decode "
                 f"rows of their step, one program for both: "
                 f"{sv['fused_share_of_chunk_steps']:.1%} of the steps with "
-                f"a chunk, {sv['fused_share_of_decode_rows']:.1%} of the "
-                f"decode rows")
+                f"a chunk"
+                + (f", {sv['fused_share_of_decode_rows']:.1%} of the decode "
+                   f"rows" if sv.get("fused_share_of_decode_rows") is not None
+                   else ""))
         if sv.get("decode_steps_with_chunk"):
             only = sv.get("mean_step_decode_only_s")
             lines.append(
@@ -1098,6 +1170,27 @@ def format_report(report: dict) -> str:
                 " ms a step"
                 + (f" against {only * 1e3:.2f} ms decode-only"
                    if only is not None else ""))
+        if sv.get("calls_by_read"):
+            names = {"chunk": "a chunk", "decode": "a decode step alone",
+                     "chunk_deep": f"a chunk from position {DEEP_POS} on"}
+            lines.append(
+                "  a call by the one program it waited for (median ms): "
+                + ", ".join(f"{names[k]} {m * 1e3:.2f} ({n} calls)"
+                            for k, (m, n) in sv["calls_by_read"].items()))
+        if sv.get("decode_calls_by_keys"):
+            lines.append(
+                "  a decode step alone by the keys a row read (median ms): "
+                + ", ".join(f"{k} {m * 1e3:.2f} ({n} calls)" for k, (m, n)
+                            in sv["decode_calls_by_keys"].items()))
+        if sv.get("stalls"):
+            st, w = sv["stalls"], sv["stalls"]["worst"]
+            lines.append(
+                f"  {st['n']} stalled call(s) lost {st['lost_s'] * 1e3:.1f} "
+                f"ms; Python's collector ran {st['gc_s'] * 1e3:.1f} ms "
+                f"inside them ({st['gc_full']} full pass(es)); the longest, "
+                f"step {w['step']}, took {w['step_s'] * 1e3:.1f} ms where "
+                f"its kind ({w['kind']}) takes {w['median_s'] * 1e3:.2f}"
+                + (f", most of it in {w['phase']}" if w["phase"] else ""))
         if sv.get("mode") == "disaggregated" or (sv.get("tp") or 1) > 1:
             dparts = [f"mode {sv.get('mode') or 'colocated'}"]
             if (sv.get("tp") or 1) > 1:

@@ -355,6 +355,12 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
        req={"n_active": "int", "n_queued": "int", "new_tokens": "int",
             "occupancy": "float", "free_blocks": "int"},
        opt={"step": "int", "n_prefilling": "int", "prefill_s": "float",
+            # prefill_s: host seconds round the call's chunks that went out
+            # in their own place, dispatch time.  decode_s: since PR 28 the
+            # dispatch of this call's step (its chunk too where the rows
+            # ride in one) and the device_get of what went out a call ago:
+            # the step's PERIOD less the call's other work, of a call with
+            # a chunk and of a decode-only one alike (``read`` parts them)
             "decode_s": "float", "mode": "str", "overlap_s": "float",
             "adapters_resident": "int", "adapters_pinned": "int",
             "prefix_blocks": "int", "prefix_hit_tokens": "int",
@@ -369,6 +375,23 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # compile in that interval is counted here too)
             "phases": "dict", "step_s": "float", "t_end": "float",
             "n_prefill_chunks": "int", "compiles": "int",
+            # what this call's wait was for: the programs that went out
+            # since the read before it (1 in steady state; a prompt's
+            # chunks go out unread while nobody decodes), the decode rows
+            # they carried and the sum of those rows' context lengths, the
+            # real rows of the chunks they carried (0: decode-only) and
+            # the last chunk's first position (the keys its attention
+            # reads beyond its own): {"programs", "rows", "ctx_keys",
+            # "chunk_rows", "chunk_pos"}, host integers known at
+            # dispatch.  Absent where the call read nothing.  A chunk that
+            # went out in its own place behind an unread step is on the
+            # read after that step's, which is the one that waits for it
+            "read": "dict",
+            # Python's cyclic collector inside the call: its seconds (all
+            # generations; they lie inside whichever phase allocated) and
+            # its full (generation-2) passes.  The process's, like
+            # compiles
+            "gc_s": "float", "gc_full": "int",
             # 1 when this call's decode step was dispatched with the one
             # before it unread (the host reads a step one call late), and
             # the slot-steps this call's read threw away: decoded for a
@@ -413,10 +436,6 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             "replica": "str"}),
     _s("serve.preempt", "optimistic-growth preemption recycled a slot",
        req={"rid": "int", "n_regenerate": "int"}),
-    _s("serve.prefill_chunk", "one chunked-prefill advance (seconds: host "
-       "time, a dispatch; only a prompt's last chunk waits for the device)",
-       req={"rid": "int", "slot": "int", "pos": "int", "n_tokens": "int",
-            "seconds": "float", "done": "bool"}),
     _s("serve.kv_ship", "disaggregated prefill shipped KV blocks into "
        "a decode slot",
        req={"rid": "int", "slot": "int", "n_blocks": "int",
