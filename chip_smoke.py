@@ -23,7 +23,9 @@ from ``--seed``):
    against the token-by-token recurrence, compiled and not interpreted;
    and the latent decode kernel at JoyAI-LLM-Flash's widths (32 heads over
    rows of 512 + 64 numbers, 24 slots of 34,816: empty, partly filled,
-   every slot full) against plain ``jax.numpy``, with a call's time at each.
+   every slot full) against plain ``jax.numpy``, with a call's time at each,
+   and a chunk's attention over 16k keys as the one kernel against the
+   plain form, with the time of both.
 4. **fused** — the serving program in which a step's decode rows ride in
    a prefill chunk (``programs.chunk_and_step``), at the widths of the four
    serving configurations and a few layers of each (16 heads of 128; 48
@@ -455,8 +457,10 @@ def latent_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:
     at ``max_len`` (the work list as long as its arrays), float32 queries
     against plain ``jax.numpy``; then, on the chip, a call's time at each
     (serving's bfloat16 queries, 20 calls), and a chunk's attention over a
-    16k context in the expanded form the program runs and in the absorbed
-    form it does not (PERF.md section 6 says which and why)."""
+    16k context in the expanded form the program runs (block by block in
+    ``jax.numpy``, then as the one kernel a chip runs, one against the
+    other) and in the absorbed form it does not (PERF.md section 6 says
+    which and why)."""
     import time
 
     import jax
@@ -546,6 +550,19 @@ def latent_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:
             out = fn(tables[0])
         jax.block_until_ready(out)
         rec[f"latent_{name}_16k_ms"] = 1e3 * (time.perf_counter() - t0) / 5
+    # the expanded form again as the ONE kernel the program runs on the chip
+    # (``tadnn_latent_chunk``): against the blocks above, and its time
+    kern = jax.jit(lambda row: pa.latent_chunk_attention(
+        q_nope, q_rope, pool, row, pos0, w_uk, w_uv, scale=scale))
+    plain = jax.jit(lambda row: programs._over_key_blocks(
+        row, bs, C, pos0, None, (H,), n, expanded))(tables[0])
+    close("latent_chunk_kernel", kern(tables[0]).astype(jnp.float32),
+          plain.transpose(1, 0, 2), rtol=1e-2)  # (a bfloat16 output's ulp)
+    t0 = time.perf_counter()
+    for _ in range(20):
+        out = kern(tables[0])
+    jax.block_until_ready(out)
+    rec["latent_chunk_kernel_16k_ms"] = 1e3 * (time.perf_counter() - t0) / 20
 
 
 # the four serving configurations at their published widths and a few of

@@ -813,6 +813,81 @@ def test_a_new_metric_has_its_file_and_its_cells(name):
     assert entry["source"] == "program_span" and entry["layer"] == "serve step"
 
 
+# -- 5c. the chunk's latent attention as one kernel (PR 38) ---------------------
+
+
+def test_the_chunk_kernels_metric_has_its_file_and_its_cell(bench, latent,
+                                                            experts, capsys):
+    """``latent_chunk_attn_ms``: its reader, its ``per_layer`` entry (the
+    attention kernels' layer, the device trace, moves the cell's one
+    end-to-end metric) and the latent cell alone on its list; like its
+    neighbours it finds nothing where there is nothing (no trace on the CPU,
+    no such kernel on a parent commit) and does not raise."""
+    name = "latent_chunk_attn_ms"
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
+    assert name + ".py" in METRIC_FILES
+    assert entry == {
+        "name": name, "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "attention kernels",
+        "moves": "serve_tokens_per_s",
+        "workloads": ["joyai-llm-flash-ep8.serve-backlog-deep"]}
+    assert BENCHMARK["per_layer"][-1] == entry  # appended, nothing moved
+    reader = _span_reader(name)
+    for run in (latent, experts):
+        assert reader(run["record"]) is None
+        assert reader({**run["record"], "trace": {"n_devices": 0}}) is None
+    capsys.readouterr()
+
+
+def test_the_chunk_kernels_name_is_its_own(bench):
+    """``latent_attn_roofline`` and ``paged_attn_roofline*`` tell the decode
+    kernels by substring: the chunk kernel's name matches none of them, and
+    its reader's pattern matches no decode kernel."""
+    from lib import counts_mla
+
+    have = _pallas_names()
+    assert "tadnn_latent_chunk" in have and "tadnn_latent_chunk" in KERNELS
+    assert counts_mla.KERNEL not in "tadnn_latent_chunk"
+    assert "tadnn_paged_decode" not in "tadnn_latent_chunk"
+    assert not [n for n in have - {"tadnn_latent_chunk"}
+                if "tadnn_latent_chunk" in n]
+
+
+def test_the_chunk_kernels_counters_have_their_reader(latent, dense,
+                                                      tmp_path):
+    """``chunk_attention`` on ``serve.engine`` and ``chunk_key_blocks`` on
+    ``serve.step`` are in the schema, and ``tadnn report`` is their reader.
+    On the CPU the plain form is what an engine runs: every kind says
+    ``"blocks"`` and no call counts a key block."""
+    from torch_automatic_distributed_neural_network_tpu.obs import (
+        report,
+        schema,
+    )
+
+    specs = schema.REGISTRY
+    assert specs["serve.engine"].optional["chunk_attention"] == "dict?"
+    assert specs["serve.step"].optional["chunk_key_blocks"] == "int"
+    ev = latent["record"]["serve_engine"]
+    assert ev["chunk_attention"] == {"latent_attention": "blocks"}
+    assert latent["eng"].chunk_attention == ev["chunk_attention"]
+    assert dense["record"]["serve_engine"]["chunk_attention"] \
+        == {"full_attention": "blocks"}
+    assert not [s for s in _steps(latent) if "chunk_key_blocks" in s]
+    # a journal of an engine whose chunks ran the kernel: two chunk calls
+    steps = [dict(s) for s in _steps(latent)]
+    chunked = [s for s in steps if s.get("n_prefill_chunks")][:2]
+    for s, blocks in zip(chunked, (20, 60)):
+        s["chunk_key_blocks"] = blocks
+    path = tmp_path / "journal.jsonl"
+    path.write_text("".join(json.dumps(r) + "\n" for r in (
+        {**ev, "chunk_attention": {"latent_attention": "kernel"}}, *steps)))
+    made = report.generate(str(path))
+    assert made["serving"]["chunk_key_blocks"] == [2, 80]
+    assert ("a chunk's attention in a kernel (latent_attention layers): "
+            "40.0 key blocks a chunk call over the layers (2 calls)"
+            ) in report.format_report(made)
+
+
 def test_the_latent_counts_are_the_arithmetic(bench):
     """69.6 kFLOP and 1,152 B a key a layer at the published widths: 60
     FLOP/B, under the v5e's ridge of 240."""

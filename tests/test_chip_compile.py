@@ -134,6 +134,46 @@ def test_paged_decode_tp_compiles_for_v5e(v5e, quantized):
                      quantized))
 
 
+# -- a chunk's latent attention: one kernel a layer ---------------------------
+
+
+def _latent_chunk(q_nope, q_rope, pool, row, pos0, w_uk, w_uv):
+    from torch_automatic_distributed_neural_network_tpu.ops.paged_attention import (
+        latent_chunk_attention,
+    )
+
+    return latent_chunk_attention(q_nope, q_rope, pool, row, pos0, w_uk,
+                                  w_uv, scale=192 ** -0.5, interpret=False)
+
+
+def _latent_chunk_args(one, dtype):
+    """The cell's shapes: a chunk of 512 rows of 32 heads (128 + 64), 544
+    table entries over 4,097 pages of 64 rows stored in 640 lanes, and
+    ``kv_b_proj``'s two halves [512, 32, 128]."""
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+    return (sds((512, 32, 128), dtype), sds((512, 32, 64), dtype),
+            sds((4097, 64, 640), jnp.bfloat16), sds((544,), jnp.int32),
+            sds((), jnp.int32), sds((512, 32, 128), dtype),
+            sds((512, 32, 128), dtype))
+
+
+def test_latent_chunk_kernel_compiles_for_v5e(v5e):
+    """``tadnn_latent_chunk`` at the cell's shapes, in serving's bfloat16
+    (float32 chunks take the plain form): 8 page copies a key block through
+    the table row, a group of heads' weights, scores and sums in VMEM under
+    the limit the call sets.  The pool reaches the kernel as it lies, the
+    scores are no array of the program: its temporaries are the transposed
+    queries, weights and output, a few MB."""
+    compiled = jax.jit(_latent_chunk).lower(*_latent_chunk_args(
+        SingleDeviceSharding(v5e[0]), jnp.bfloat16)).compile()
+    (kernel,) = [l for l in compiled.as_text().splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in l]
+    assert "tadnn_latent_chunk" in kernel.split(" = ")[0]
+    assert kernel.count("bf16[4097,64,640]") >= 8
+    assert "[32,512,512]" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**25
+
+
 # -- kernel names: what a device trace tells the kernels apart by ------------
 
 
@@ -157,7 +197,8 @@ def kernel_texts(v5e):
             _compile(
                 lambda q, k, v, t, c: paged_attention(q, k, v, t, c,
                                                       interpret=False),
-                *_paged_args(lambda spec: one, 16, 16, False)))
+                *_paged_args(lambda spec: one, 16, 16, False)),
+            _compile(_latent_chunk, *_latent_chunk_args(one, jnp.bfloat16)))
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
         cc.reset_cache()
@@ -165,7 +206,8 @@ def kernel_texts(v5e):
 
 @pytest.mark.parametrize("name,where", [
     ("tadnn_flash_fwd", 0), ("tadnn_flash_bwd_dkv", 0),
-    ("tadnn_flash_bwd_dq", 0), ("tadnn_paged_decode", 1)])
+    ("tadnn_flash_bwd_dq", 0), ("tadnn_paged_decode", 1),
+    ("tadnn_latent_chunk", 2)])
 def test_kernel_is_named_in_the_compiled_text(kernel_texts, name, where):
     """Each ``pallas_call`` carries a ``name``: it becomes part of the
     Mosaic custom call's instruction name, which is what a profile of the
@@ -369,6 +411,14 @@ def test_serving_programs_update_the_pool_in_place(
         # grouped matmuls of 19 expert layers; 6.25 GiB of latent pages
         assert text.count("tadnn_paged_decode_latent") >= 20 * (
             program != "prefill_chunk")
+        # a chunk's attention is ONE kernel a layer: no loop over key
+        # blocks, no [32, 512, 512] scores among the program's arrays
+        chunks = len(re.findall(r"^\s*%tadnn_latent_chunk[.\d]* = ", text,
+                                re.M))
+        assert chunks == 20 * (program != "decode_step")
+        assert "[32,512,512]" not in text
+        assert not [l.strip()[:120] for l in text.splitlines()
+                    if " while(" in l and "attend_chunk" in l]
         assert text.count("tadnn_moe_grouped_mm") >= 38
         assert "tadnn_gdn" not in text
         assert made["pool"].bytes_latent == pool_bytes

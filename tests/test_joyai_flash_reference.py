@@ -548,11 +548,15 @@ SERVED = {"chunked": {}, "single_shot": {"prefill_chunk": None},
           "disaggregate": {"disaggregate": True},
           "speculative": {"speculative": 2},
           "prefix_cache": {"prefix_cache": True},
-          "two_chunks_a_step": {"prefill_chunks_per_step": 2}}
+          "two_chunks_a_step": {"prefill_chunks_per_step": 2},
+          # the chunk's attention as ONE kernel a layer, as on the chip (the
+          # interpreter here; key blocks of 8 so that chunks cross them)
+          "chunk_kernel": {}}
 
 
 @pytest.mark.parametrize("option", sorted(SERVED))
-def test_engine_serves_the_references_first_choice(option, tmp_path):
+def test_engine_serves_the_references_first_choice(option, tmp_path,
+                                                   monkeypatch):
     """The engine itself, scheduler and all: six requests over three slots
     (slots are reused, chunks and decode steps interleave and ride in one
     call, the last chunks are padded), each served token the reference's
@@ -560,6 +564,13 @@ def test_engine_serves_the_references_first_choice(option, tmp_path):
     is served with."""
     flat = _params()
     journal = Journal(None, host0_only=False)
+    if option == "chunk_kernel":
+        from torch_automatic_distributed_neural_network_tpu.ops import (
+            paged_attention as paged,
+        )
+
+        monkeypatch.setattr(paged, "LATENT_KEYS", 8)
+        monkeypatch.setattr(paged, "latent_chunk_tiles", lambda *a: True)
     eng = _engine(flat, journal, **SERVED[option])
     reqs = [eng.submit([int(t) for t in _tokens(n, 10 + i)], max_new_tokens=m)
             for i, (n, m) in enumerate(SHAPES)]
@@ -576,13 +587,29 @@ def test_engine_serves_the_references_first_choice(option, tmp_path):
             == eng.pool.transferred_blocks * eng.pool.bytes_per_block > 0
     if option == "speculative":
         assert eng.spec_accepted > 0
+    # the counters the kernel brought: which form a chunk attends in, and
+    # on every call that dispatched a chunk the key blocks its four layers'
+    # kernel calls ran (blocks of 8 keys: the chunk's last position's)
+    ev = journal.named("serve.engine")[-1]
+    form = {"chunk_kernel": "kernel", "single_shot": None}.get(
+        option, "blocks")
+    assert ev["chunk_attention"] == (form and {"latent_attention": form})
+    counted = [s for s in steps if "chunk_key_blocks" in s]
+    if option == "chunk_kernel":
+        chunks = sum(-(-n // CHUNK) for n, _ in SHAPES)
+        assert sum(s["n_prefill_chunks"] for s in counted) == chunks \
+            == sum(s.get("n_prefill_chunks", 0) for s in steps)
+        assert sum(s["chunk_key_blocks"] for s in counted) == 4 * sum(
+            (pos + CHUNK - 1) // 8 + 1
+            for n, _ in SHAPES for pos in range(0, n, CHUNK))
+    else:
+        assert not counted
     if option != "chunked":
         return
     # the kernel's grid: work lists of the live (slot, 512-key group) items
     assert sum(s.get("attn_grid_items", 0) for s in steps) > 0
     assert all(s["attn_grid_items"] <= s["attn_grid_dense"]
                for s in steps if s.get("attn_grid_dense"))
-    ev = journal.named("serve.engine")[-1]
     assert ev["layer_kinds"] == KEYS["layer_types"]
     assert ev["kv_bytes_full"] == ev["kv_bytes_latent"] \
         == eng.pool.bytes_latent == 4 * 73 * BS * 128 * 4

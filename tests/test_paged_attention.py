@@ -410,3 +410,136 @@ def test_latent_kernel_is_named_and_reads_a_page_once():
     pools = [v for v in call.invars if getattr(v.aval, "shape", None)
              == pool.shape]
     assert len(pools) == 8  # 512 keys a step, no value pages beside them
+
+
+# -- the chunk kernel: a prompt chunk's queries over the slot's latent pages ----
+
+_CC, _CBS, _CMB, _CKEYS = 16, 4, 16, 8  # a chunk of 16, 2 pages a key block
+# name: (the chunk's first position, its real rows, shuffled pages)
+_CHUNK_CASES = {
+    "prompt_start": (0, _CC, True),
+    "inside_a_key_block": (4, _CC, True),
+    "blocks_deep": (32, _CC, True),
+    "ragged_last_chunk": (16, 5, True),
+    "pages_in_order": (16, _CC, False),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_CHUNK_CASES))
+def test_latent_chunk_kernel_matches_the_plain_form_and_the_module(
+        monkeypatch, case, dtype):
+    """``programs._chunk_latent`` through ``tadnn_latent_chunk`` (the
+    interpreter; key blocks of 8, so a chunk of 16 crosses several) against
+    the same call through ``_over_key_blocks``, and both against
+    ``LatentAttention.__call__`` over the whole sequence: a chunk that
+    starts a prompt, one that starts inside a key block, one several blocks
+    deep, a padded last chunk (its real rows are compared; the table holds
+    the null block past the prompt's pages), the slot's pages out of order
+    in the pool and in order.  Every page past the chunk's last key block
+    is poisoned: the grid follows the context."""
+    from torch_automatic_distributed_neural_network_tpu.inference.serve import (
+        programs,
+    )
+    from torch_automatic_distributed_neural_network_tpu.inference.serve.kv_pool import (
+        write_chunk,
+    )
+    from torch_automatic_distributed_neural_network_tpu.models.transformer_core import (
+        LatentAttention,
+        TransformerConfig,
+    )
+    from torch_automatic_distributed_neural_network_tpu.ops import (
+        paged_attention as paged,
+    )
+
+    pos0, n_real, shuffled = _CHUNK_CASES[case]
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=48, n_layers=1, n_heads=4, d_ff=64,
+        max_seq_len=128, pos="rope", norm="rmsnorm",
+        layer_types=("latent_attention",), latent_q_rank=24,
+        latent_kv_rank=16, latent_nope_head_dim=8, latent_rope_head_dim=4,
+        latent_value_head_dim=8, dtype=dtype, remat=False)
+    monkeypatch.setattr(paged, "LATENT_KEYS", _CKEYS)
+    monkeypatch.setattr(programs, "KEY_BLOCK", _CKEYS)
+    T, lanes = pos0 + n_real, 128
+    rs = np.random.RandomState(len(case))
+    x = jnp.asarray(rs.randn(1, pos0 + _CC, cfg.d_model), dtype)
+    positions = jnp.arange(pos0 + _CC)[None]
+    mixer = LatentAttention(cfg)
+    own = mixer.init(jax.random.key(2), x, positions)
+    own = jax.tree.map(lambda a: a * 2.0, own)  # scores that tell keys apart
+    piece = lambda name, *a: mixer.apply(own, *a, method=name)  # noqa: E731
+    q_nope, q_rope, latent = piece("project", x, positions)
+    # the slot's pages: those of the prompt's T tokens, the null block after
+    n_pages = -(-T // _CBS)
+    ids = 1 + (rs.permutation(_CMB) if shuffled else np.arange(_CMB))
+    row = jnp.asarray(np.where(np.arange(_CMB) < n_pages, ids, 0), jnp.int32)
+    pool = np.zeros((_CMB + 1, _CBS, lanes), np.float32)
+    hi = (pos0 + _CC - 1) // _CKEYS  # the chunk's last key block
+    pool[ids[(hi + 1) * (_CKEYS // _CBS):]] = np.nan
+    pool = jnp.asarray(pool, dtype)
+    if pos0:  # what the chunks before this one wrote
+        pool = write_chunk(pool, row, 0, latent[0, :pos0])
+    shared = {"rows": {"pages": row}, "pos0": jnp.int32(pos0)}
+    chunk = (q_nope[0, pos0:], q_rope[0, pos0:], latent[0, pos0:])
+
+    plain, pages = programs._chunk_latent(cfg, shared, pool, piece, *chunk)
+    monkeypatch.setattr(paged, "latent_chunk_tiles", lambda *a: True)
+    assert programs.chunk_attention_form(
+        cfg, "latent_attention", _CC, _CBS) == "kernel"
+    jaxpr = jax.make_jaxpr(lambda *a: programs._chunk_latent(
+        cfg, shared, pool, piece, *a))(*chunk).jaxpr
+    assert [e.primitive.name for e in jaxpr.eqns].count("pallas_call") == 1
+    got, pages_k = programs._chunk_latent(cfg, shared, pool, piece, *chunk)
+    np.testing.assert_array_equal(
+        np.nan_to_num(np.asarray(pages, np.float32)),
+        np.nan_to_num(np.asarray(pages_k, np.float32)))
+    assert got.shape == plain.shape == (_CC, 4, 8) and got.dtype == dtype
+    f32 = lambda a: np.asarray(a, np.float32)[:n_real]  # noqa: E731
+    assert np.all(np.isfinite(f32(got)))
+    # (as shares of the largest number compared: bfloat16 rounds a head's
+    # output of 20 to 0.06, and the module's softmax is another program's)
+    tight, loose = (2e-5, 1e-4) if dtype == jnp.float32 else (1e-2, 3e-2)
+    assert np.abs(f32(got) - f32(plain)).max() \
+        <= tight * np.abs(f32(plain)).max()
+    with jax.default_matmul_precision("highest"):
+        whole = mixer.apply(own, x[:, :T], positions[:, :T])[0, pos0:]
+        out = piece("out_proj", got[None].astype(dtype))[0]
+    assert np.abs(f32(out) - f32(whole)).max() \
+        <= loose * np.abs(f32(whole)).max()
+
+
+def test_latent_chunk_kernel_is_named_and_its_grid_is_traced():
+    """One ``pallas_call`` named ``tadnn_latent_chunk`` (a name no reader of
+    the decode kernels matches) whose page operands are the ONE pool array
+    (8 pages of 64 tokens a key block at the cell's block size) and whose
+    grid is (groups of heads, the key blocks the chunk reaches): a traced
+    number, which ``latent_chunk_key_blocks`` gives the engine's counter."""
+    from torch_automatic_distributed_neural_network_tpu.ops import (
+        paged_attention as paged,
+    )
+
+    C, H, bs, MB = 128, 8, 64, 24
+    pool = jnp.zeros((MB + 1, bs, 128), jnp.float32)
+
+    def run(q_nope, q_rope, pos0):
+        return paged.latent_chunk_attention(
+            q_nope, q_rope, pool, jnp.zeros((MB,), jnp.int32), pos0,
+            jnp.zeros((20, H, 16)), jnp.zeros((20, H, 16)), scale=1.0)
+
+    jaxpr = jax.make_jaxpr(run)(jnp.zeros((C, H, 16)), jnp.zeros((C, H, 4)),
+                                jnp.int32(0)).jaxpr
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert "tadnn_latent_chunk" in str(call.params["name"])
+    assert "tadnn_paged_decode" not in str(call.params["name"])
+    pools = [v for v in call.invars if getattr(v.aval, "shape", None)
+             == pool.shape]
+    assert len(pools) == 8
+    grid = call.params["grid_mapping"].grid
+    assert grid[0] == H // paged.LATENT_CHUNK_HEADS
+    assert not isinstance(grid[1], int)  # follows pos0, not max_len
+    assert [paged.latent_chunk_key_blocks(p, 512, 544, 64)
+            for p in (0, 512, 8192, 32256)] == [1, 2, 17, 64]
+    # on the CPU the plain form is what runs, whatever the shapes
+    assert not paged.latent_chunk_tiles(512, 64, 32, 512, 128, jnp.bfloat16)

@@ -130,8 +130,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.transformer_core import TransformerConfig
+from ...models.transformer_core import TransformerConfig, layer_plan
 from ...obs import journal as _journal
+from ...ops.paged_attention import latent_chunk_key_blocks, tensor_degree
 from ...parallel.expert import expert_tiles
 from ...training.lora import LoraSpec
 from ..decode import (
@@ -491,6 +492,21 @@ class ServeEngine:
             for rows in ((self.prefill_chunk or 0)
                          + n_slots * (self._fused_fn is not None),
                          n_slots * (1 + self.speculative))]
+        # how a chunk attends, a kind of layer that keeps pages: what the
+        # programs pick from the same inputs (``chunk_attention_form``),
+        # asked once here; and the layers whose chunk is ONE kernel call,
+        # whose key blocks ``serve.step`` counts (``chunk_key_blocks``).
+        # (A single-shot engine's chunk is as long as its prompt: not said)
+        paged = [kind for _, kind, _ in layer_plan(self.cfg)
+                 if kind != "linear_attention"] * bool(self.prefill_chunk)
+        self.chunk_attention = {
+            kind or "full_attention": programs.chunk_attention_form(
+                self.cfg, kind, self.prefill_chunk, block_size)
+            for kind in dict.fromkeys(paged)}
+        self._kernel_layers = sum(
+            self.chunk_attention[kind or "full_attention"] == "kernel"
+            for kind in paged)
+        self.chunk_key_blocks = 0  # lifetime; step() diffs it
         # the decode rows of a chunk that carries none: every slot idle
         self._idle_rows = np.zeros((n_slots, max_blocks + 4), np.int32)
         # lifetime counts: steps whose chunk carried the decode rows, and
@@ -515,8 +531,6 @@ class ServeEngine:
                 quant_kv=bool(quant_kv), cache_dtype=cache_dtype,
                 n_adapters=n_adapters,
                 quant_adapters=bool(quant_adapters))
-        from ...ops.paged_attention import tensor_degree
-
         given = jax.tree.leaves(variables["params"])
         held = jax.tree.leaves(self.params)
 
@@ -553,6 +567,7 @@ class ServeEngine:
             experts_published=(self.cfg.experts_published
                                if self.cfg.n_expert_layers else 0),
             moe_tiles_laid=self._tiles_laid,
+            chunk_attention=self.chunk_attention or None,
             # latent pages are pages for max_len: in kv_bytes_full too
             kv_bytes_full=self.pool.bytes_full,
             kv_bytes_latent=self.pool.bytes_latent,
@@ -1029,6 +1044,13 @@ class ServeEngine:
         if chunk is not None:
             sent["chunk_rows"] += chunk[0]
             sent["chunk_pos"] = chunk[1]
+            # (of what this call DISPATCHED, not of ``read``) the grid
+            # steps a group of heads of the chunk's kernel calls ran
+            if self._kernel_layers:
+                self.chunk_key_blocks += (
+                    self._kernel_layers * latent_chunk_key_blocks(
+                        chunk[1], self.prefill_chunk, self.max_blocks,
+                        self.pool.block_size))
 
     def _dispatch(self, rider: tuple | None = None) -> np.ndarray | None:
         """One decode step for every running slot, dispatched and not
@@ -1285,6 +1307,7 @@ class ServeEngine:
         ahead_before = self.steps_ahead
         discarded_before = self.discarded_tokens
         fused_before = self.fused_steps, self.fused_decode_rows
+        key_blocks_before = self.chunk_key_blocks
         compiles, compile_s = self._compiles.n, self._compiles.seconds
         gc_s, gc_full = self._gc.total_s, self._gc.passes[self._gc.FULL]
         self._phases = phases = {}
@@ -1397,6 +1420,9 @@ class ServeEngine:
             discarded_tokens=self.discarded_tokens - discarded_before,
             fused=self.fused_steps - fused_before[0],
             fused_decode_rows=self.fused_decode_rows - fused_before[1],
+            **({"chunk_key_blocks":
+                self.chunk_key_blocks - key_blocks_before}
+               if self._kernel_layers and n_chunks else {}),
             **adapter_stats, **self._counters)
         if self._debug_invariants:
             sched.check_invariants()

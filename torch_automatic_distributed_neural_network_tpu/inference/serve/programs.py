@@ -42,10 +42,19 @@ discipline of ``decode.forward_cached``: ``SelfAttention.qkv`` /
   attend the EXPANDED form, a block of keys at a time: the block's latents
   to every head's keys and values (``expand``), then scores and values a
   head (at 512 queries over 16k keys 172 GFLOP of scores and values + 137
-  of expansion a layer, where the absorbed form is 584).  A decode row
-  attends the ABSORBED form: its query taken into the latent space, all
-  heads over the one row a key (``ops/paged_attention``'s latent kernel: a
-  page read once), the result taken out again (``lift``).
+  of expansion a layer, where the absorbed form is 584).  On the chip that
+  is ONE kernel a layer (``ops/paged_attention``'s ``tadnn_latent_chunk``:
+  a key block expanded in VMEM a head at a time, the softmax of one head
+  beside the matmuls of the next; 2.04 ms at 16k keys), wherever
+  ``chunk_attention_form`` finds shapes it tiles; everywhere else, and as
+  the oracle, ``jax.numpy`` over key blocks (``_over_key_blocks``: 2.53 ms
+  there, 76 us a key block where its matmuls need 49, because three passes
+  of vector work over a block's ``[32, 512, 512]`` float32 scores run
+  BETWEEN its matmuls; the scores themselves stay in the chip's VMEM; my
+  chip runs, PR 38).  A decode row attends the ABSORBED form: its query
+  taken into the latent space, all heads over the one row a key (the
+  latent decode kernel: a page read once), the result taken out again
+  (``lift``).
 
 - ``chunk_and_step`` is both in one walk: the chunk's C rows and the S
   decode rows share each layer's norms, projections, FFN and the head (the
@@ -455,6 +464,24 @@ def _chunk_shared(packed, win_row, max_blocks: int):
     return shared
 
 
+def chunk_attention_form(cfg, kind: str | None, chunk: int,
+                         block_size: int) -> str:
+    """How a chunk of ``chunk`` rows attends on a layer of ``kind`` that
+    keeps pages of ``block_size``: ``"kernel"`` where it is ONE call of
+    ``ops/paged_attention``'s ``tadnn_latent_chunk`` (a latent layer, on the
+    chip, at shapes the kernel tiles), else ``"blocks"``, ``jax.numpy`` over
+    key blocks (``_over_key_blocks``: the plain form, and the oracle).  By
+    what the program sees in its input, nothing else: ``_chunk_latent`` asks
+    here, and so does the engine for its ``chunk_attention`` counter."""
+    from ...ops.paged_attention import latent_chunk_tiles
+
+    if kind == "latent_attention" and latent_chunk_tiles(
+            chunk, block_size, cfg.n_heads, cfg.latent_kv_rank,
+            cfg.latent_nope_head_dim, cfg.dtype):
+        return "kernel"
+    return "blocks"
+
+
 @jax.named_scope("tadnn.attend_chunk")
 def _chunk_attention(cfg, kind, shared, k_l, v_l, q, k, v):
     """``q``, ``k``, ``v`` [C, heads, hd]: the chunk's keys and values
@@ -471,11 +498,23 @@ def _chunk_latent(cfg, shared, pages, piece, q_nope, q_rope, latent):
     """``q_nope``, ``q_rope`` [C, H, .], ``latent`` [C, F]: the chunk's
     latent rows written into the slot's pages, then its queries over the
     rows up to themselves, EXPANDED a block of keys at a time (the module
-    docstring has the arithmetic)."""
+    docstring has the arithmetic): in one kernel where
+    ``chunk_attention_form`` says so, else block by block below."""
+    from ...ops.paged_attention import latent_chunk_attention
+
     row, pos0 = shared["rows"]["pages"], shared["pos0"]
     pages = write_chunk(pages, row, pos0, latent)
     r, rot, scale = _latent_sizes(cfg)
     C, H, _ = q_nope.shape
+    if chunk_attention_form(cfg, "latent_attention", C,
+                            pages.shape[1]) == "kernel":
+        # the pages go on behind the kernel's output: whatever writes them
+        # next (the same call's decode rows) waits for the kernel, which
+        # reads them where they lie (left free, the compiler once put that
+        # write first and gave the kernel a copy of the layer's pool)
+        return jax.lax.optimization_barrier((latent_chunk_attention(
+            q_nope, q_rope, pages, row, pos0, *piece("up"),
+            scale=scale), pages))
 
     def block(ids):
         rows = read_pages(pages, ids, 1, q_nope.dtype)

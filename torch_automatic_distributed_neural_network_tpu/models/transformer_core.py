@@ -561,7 +561,7 @@ class LatentAttention(nn.Module):
                    cfg.rope_theta)[:, :, 0]
         return q_nope, q_rope, jnp.concatenate([self.kv_a_norm(c), k_r], -1)
 
-    def _up(self):
+    def up(self):
         """``kv_b_proj``'s kernel [kv_rank, H, nope + value] apart: (W_UK,
         W_UV), in the compute dtype."""
         w = self.kv_b_proj.variables["params"]["kernel"].astype(self.cfg.dtype)
@@ -577,14 +577,14 @@ class LatentAttention(nn.Module):
         """A query in the latent space: ``[q_nope W_UK^T, q_rope]`` [..., H,
         kv_rank + rope], whose product with a cached row is the head's
         score."""
-        q_lat = jnp.einsum("...hn,chn->...hc", q_nope, self._up()[0])
+        q_lat = jnp.einsum("...hn,chn->...hc", q_nope, self.up()[0])
         return jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype)], -1)
 
     def lift(self, o_lat):
         """``o_lat`` [..., H, kv_rank], a head's probabilities over the
         cached latents, to the head's output [..., H, value]."""
         return jnp.einsum("...hc,chv->...hv", o_lat.astype(self.cfg.dtype),
-                          self._up()[1])
+                          self.up()[1])
 
     def out_proj(self, out, x=None):
         del x  # no output gate

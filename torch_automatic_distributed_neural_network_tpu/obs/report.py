@@ -530,6 +530,13 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                 serving["mean_step_decode_only_s"] = _mean(
                     e.get("step_s") for e in decoding
                     if not e.get("n_prefill_chunks"))
+            # the key blocks a chunk call's attention kernels ran (engines
+            # whose chunk attends in a kernel): [calls, blocks over them]
+            blocks = _finite(e.get("chunk_key_blocks") for e in ssteps)
+            serving["chunk_attention"] = (sengine or {}).get(
+                "chunk_attention")
+            serving["chunk_key_blocks"] = ([len(blocks), sum(blocks)]
+                                           if blocks else None)
             # the same by what a call WAITED for, which is what its time
             # is (a step is read one call late), and the stalls
             by_read = _calls_by_read(ssteps)
@@ -1177,6 +1184,14 @@ def format_report(report: dict) -> str:
                 "  a call by the one program it waited for (median ms): "
                 + ", ".join(f"{names[k]} {m * 1e3:.2f} ({n} calls)"
                             for k, (m, n) in sv["calls_by_read"].items()))
+        if sv.get("chunk_key_blocks"):
+            n, blocks = sv["chunk_key_blocks"]
+            kernel = [k for k, v in (sv.get("chunk_attention") or {}).items()
+                      if v == "kernel"]
+            lines.append(
+                f"  a chunk's attention in a kernel ({', '.join(kernel)} "
+                f"layers): {blocks / n:.1f} key blocks a chunk call over "
+                f"the layers ({n} calls)")
         if sv.get("decode_calls_by_keys"):
             lines.append(
                 "  a decode step alone by the keys a row read (median ms): "

@@ -346,6 +346,11 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # lands in them: [a call with a chunk, a decode-only call],
             # summed over the expert layers (0 and 0 without any)
             "moe_tiles_laid": "list",
+            # how a prefill chunk attends, a kind of layer that keeps
+            # pages: "kernel" (one call of tadnn_latent_chunk a layer) or
+            # "blocks" (jax.numpy over key blocks), decided at build by
+            # what the programs see (None: a single-shot engine)
+            "chunk_attention": "dict?",
             # decode steps the engine dispatches with the step before
             # unread: 1, or 0 where the next step's operands need the
             # tokens' values (speculative drafts)
@@ -422,7 +427,12 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # slot decoding), and how many rows; the three expert counters
             # above are then left out of the call that reads them: the
             # layers routed the chunk's rows with them
-            "fused": "int", "fused_decode_rows": "int"}),
+            "fused": "int", "fused_decode_rows": "int",
+            # on a call that dispatched a chunk whose attention is a
+            # kernel (serve.engine's chunk_attention): the key blocks its
+            # calls ran, over the layers (host arithmetic from the chunk's
+            # first position: a kernel call's grid steps a group of heads)
+            "chunk_key_blocks": "int"}),
     _s("serve.request_done", "per-request completion span with the "
        "full phase-attributed timeline", version=2,
        req={"rid": "int", "n_prompt": "int", "n_new": "int",

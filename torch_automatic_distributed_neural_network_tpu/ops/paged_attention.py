@@ -18,11 +18,13 @@ Shape of the problem (one decode token per slot):
     tables: [S, MB] int32        block ids, null-padded (kv_pool)
     ctx:    [S] int32            keys 0..ctx inclusive are valid
 
-ONE entry point, :func:`paged_attention`, and three kernels chosen by the
-shape of a page, not by the model.  A pool of LATENT pages (one row a token
-that is key and value at once, ``[NB, bs, kv_rank + rope]``, and no second
-array) is read by ``tadnn_paged_decode_latent``, the folded kernel's body at
-other numbers: see "the latent kernel" below.  A pool of folded pages is read by the
+ONE entry point for decode, :func:`paged_attention`, and three kernels
+chosen by the shape of a page, not by the model (a prefill chunk on latent
+pages has a kernel of its own, :func:`latent_chunk_attention`: "the chunk
+kernel" below).  A pool of LATENT pages (one row a token that is key and
+value at once, ``[NB, bs, kv_rank + rope]``, and no second array) is read
+by ``tadnn_paged_decode_latent``, the folded kernel's body at other
+numbers: see "the latent kernel" below.  A pool of folded pages is read by the
 MXU kernel (``tadnn_paged_decode_folded``, further down: grouped queries
 as two plain matmuls, 8 pages a grid step, the grid a list of the key
 groups the slots have, of traced length).  A page kept as ``[bs, kvH, hd]``
@@ -623,6 +625,207 @@ def paged_attention_latent(
         interpret=interpret,
         name="tadnn_paged_decode_latent",
     )(tables, ctx_lens, *work[:4], q, *([pool] * pages))
+
+
+# -- the chunk kernel: a prompt chunk's queries over the slot's latent pages ------
+#
+# A chunk's C queries attend the EXPANDED form (at 512 queries x 32 heads the
+# absorbed form is twice the work), a block of ``LATENT_KEYS`` keys at a time.
+# In ``jax.numpy`` (``programs._over_key_blocks``) a key block is six fusions:
+# the expansion, two score products, and three passes of vector work over
+# the block's ``[32, 512, 512]`` float32 scores (mask and row maximum; ``exp``
+# and row sum; ``exp`` AGAIN inside the values' product).  The 32 MiB of
+# scores never reach HBM (the compiler keeps them in the chip's 128 MiB of
+# VMEM between the fusions), but the matmuls WAIT for the vector passes: 76
+# us a key block where the matmuls alone take 49 (my chip runs, PR 38:
+# PERF.md section 6).  ``tadnn_latent_chunk`` is the same arithmetic as ONE
+# program, so that a head's softmax runs beside the next head's matmuls: its
+# grid is (groups of ``LATENT_CHUNK_HEADS`` heads) x (the key blocks up to the
+# chunk's last: a traced number), a group's slices of ``kv_b_proj`` stay in
+# VMEM over its key blocks, a block's latents come through the table as the
+# decode kernel's do and are expanded there a head at a time, and scores,
+# running maximum, sum and accumulator are float32 in VMEM.  Scores are held
+# TRANSPOSED, ``[keys, C]``: the softmax's maximum and sum over the keys are
+# then plain vector operations down the sublanes (across lanes they took
+# twice the vector time), and a row's statistics are one lane-dense ``[1,
+# C]``.  62 us a key block, 2.04 ms where the plain form takes 2.53 at 16k
+# keys; the matmuls alone, in whole passes of the MXU, take 59.  The same
+# operations in the same precision as the plain form, which stays the oracle
+# and the path of every shape the kernel does not tile.
+
+LATENT_CHUNK_HEADS = 8  # heads a grid step of the chunk kernel takes (16
+# were slower by 1.7x: the unrolled program outgrows the instruction memory)
+_LATENT_CHUNK_VMEM = 64 * 2**20  # of a v5e's 128 MiB; the default 16 hold no step
+
+
+def latent_chunk_tiles(chunk: int, block_size: int, heads: int, rank: int,
+                       nope: int, dtype) -> bool:
+    """Whether ``latent_chunk_attention`` takes a chunk of these shapes
+    here: compiled (not on the CPU), whole tiles of queries, pages that
+    make up a key block, whole groups of heads, a latent and a head's
+    unrotated part in whole tiles of lanes, in bfloat16 (the kernel
+    multiplies float32 too, which the interpreter's tests use; compiled, its
+    HIGHEST products unroll to 15 MB of program: the plain form's)."""
+    return (not _default_interpret() and chunk % _LANES == 0
+            and LATENT_KEYS % block_size == 0
+            and heads % LATENT_CHUNK_HEADS == 0
+            and rank % _LANES == 0 and nope % _LANES == 0
+            and jnp.dtype(dtype) == jnp.bfloat16)
+
+
+def latent_chunk_key_blocks(pos0, chunk: int, max_blocks: int,
+                            block_size: int):
+    """The key blocks a chunk of ``chunk`` rows at ``pos0`` attends, the
+    first to the one it wrote: the kernel's grid steps a group of heads
+    (``pos0`` an int for the engine's counter, traced for the grid)."""
+    keys = latent_pages(max_blocks, block_size) * block_size
+    return (pos0 + chunk - 1) // keys + 1
+
+
+def _expand_latent(lat, wk, wvt, rank: int, exact):
+    """What a block's keys and values are on a latent layer, for one head:
+    ``lat`` [keys, F] rows as stored, ``wk`` [rank, nope] and ``wvt`` [dv,
+    rank] the head's slices of ``kv_b_proj``.  Keys ``[k_nope | the rotated
+    part and the row's zeros]`` [keys, nope + F - rank] and values
+    TRANSPOSED [dv, keys], both rounded as ``LatentAttention.expand`` rounds
+    them."""
+    c = lat[:, :rank]
+    k = jnp.dot(c, wk, precision=exact,
+                preferred_element_type=jnp.float32).astype(lat.dtype)
+    vt = jax.lax.dot_general(wvt, c, (((1,), (1,)), ((), ())),
+                             precision=exact,
+                             preferred_element_type=jnp.float32)
+    return jnp.concatenate([k, lat[:, rank:]], axis=1), vt.astype(lat.dtype)
+
+
+def _latent_chunk_kernel(table_ref, pos_ref, qt_ref, wk_ref, wvt_ref, *refs,
+                         pages: int, rank: int, scale: float):
+    """One (group of heads, key block) step: ``qt_ref`` [heads, nope + F -
+    rank, C] (a head's unrotated and rotated parts, zeros behind them,
+    queries in the lanes), ``wk_ref`` [rank, heads * nope], ``wvt_ref``
+    [heads, dv, rank], then the block's ``pages`` pages, the output [heads,
+    dv, C] and the float32 scratch: the accumulator [heads, dv, C], the
+    running maximum and sum [heads, 8, C] (a row each, sublane-broadcast)."""
+    del table_ref
+    page_refs = refs[:pages]
+    o_ref, acc_ref, m_ref, l_ref = refs[pages:]
+    j, nj = pl.program_id(1), pl.num_programs(1)
+    heads, dt = qt_ref.shape[0], qt_ref.dtype
+    nope = wk_ref.shape[1] // heads
+    exact = jax.lax.Precision.HIGHEST if dt == jnp.float32 else None
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_BIG)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def block(masked: bool):
+        lat = jnp.concatenate([r[0] for r in page_refs], axis=0).astype(dt)
+        keys = lat.shape[0]
+        for h in range(heads):  # static: a head's softmax runs beside the
+            # next head's matmuls
+            kx, vt = _expand_latent(
+                lat, wk_ref[:, h * nope:(h + 1) * nope], wvt_ref[h], rank,
+                exact)
+            st = jnp.dot(kx, qt_ref[h], precision=exact,
+                         preferred_element_type=jnp.float32) * scale
+            if masked:  # [keys, C]: key k of the block against query c
+                k_pos = j * keys + jax.lax.broadcasted_iota(
+                    jnp.int32, st.shape, 0)
+                q_pos = pos_ref[0] + jax.lax.broadcasted_iota(
+                    jnp.int32, st.shape, 1)
+                st = jnp.where(k_pos <= q_pos, st, _NEG_BIG)
+            m_prev, l_prev = m_ref[h][:1], l_ref[h][:1]  # [1, C]
+            # (block 0 comes first and every query sees key 0: a masked
+            # score's exp underflows to 0 against a real maximum)
+            m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+            pt = jnp.exp(st - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_prev * alpha + jnp.sum(pt, axis=0, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                vt, pt.astype(dt), precision=exact,
+                preferred_element_type=jnp.float32)
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+    # only a block that holds a key after the chunk's first query needs the
+    # causal compare; the blocks before it are visible to every query
+    reaches = (j + 1) * pages * page_refs[0].shape[1] - 1 > pos_ref[0]
+    pl.when(reaches)(lambda: block(True))
+    pl.when(jnp.logical_not(reaches))(lambda: block(False))
+
+    @pl.when(j == nj - 1)
+    def _finish():
+        o_ref[:] = (acc_ref[:] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+def latent_chunk_attention(q_nope, q_rope, pool, table_row, pos0, w_uk, w_uv,
+                           *, scale: float,
+                           interpret: bool | None = None) -> jax.Array:
+    """A prefill chunk's attention on a ``latent_attention`` layer, the
+    chunk's own rows already written: ``q_nope`` [C, H, nope], ``q_rope``
+    [C, H, rope] at positions ``pos0 .. pos0 + C`` over ONE slot's latent
+    pages ``pool`` [NB, bs, F] through its ``table_row`` [MB], each key
+    block expanded on the chip by ``w_uk`` [rank, H, nope], ``w_uv`` [rank,
+    H, dv] (``LatentAttention.up``).  Query ``c`` attends keys ``0 .. pos0 +
+    c``; scores ``(q_nope . k_nope + q_rope . k_r) * scale``.  Returns [C,
+    H, dv] in the queries' dtype."""
+    if interpret is None:
+        interpret = _default_interpret()
+    C, H, nope = q_nope.shape
+    NB, bs, F = pool.shape
+    rank, dv = w_uk.shape[0], w_uv.shape[2]
+    heads = min(LATENT_CHUNK_HEADS, H)
+    MB = table_row.shape[0]
+    pages = latent_pages(MB, bs)
+    # whole key blocks up to the last a chunk may reach (the null block past
+    # the row's end: those keys lie after every query)
+    n_kb = -(-(MB * bs + C) // (pages * bs))
+    table_row = jnp.pad(table_row.astype(jnp.int32),
+                        (0, n_kb * pages - MB))
+    pos0 = jnp.asarray(pos0, jnp.int32).reshape(1)
+    # a head's query one COLUMN of [nope | rope | zeros] against a key's row
+    # [k_nope | the stored row past the latent]
+    dt = q_nope.dtype
+    q = jnp.concatenate([q_nope, q_rope.astype(dt)], axis=-1)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, F - rank - q_rope.shape[2])))
+    qt = q.transpose(1, 2, 0)  # [H, nope + F - rank, C]
+
+    def group(a, b):
+        return pl.BlockSpec((heads, a, b), lambda g, j, t, p: (g, 0, 0))
+
+    def page(i):
+        return pl.BlockSpec((1, bs, F), lambda g, j, t, p: (
+            t[j * pages + i], 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(H // heads, latent_chunk_key_blocks(pos0[0], C, MB, bs)),
+        in_specs=[group(*qt.shape[1:]),
+                  pl.BlockSpec((rank, heads * nope),
+                               lambda g, j, t, p: (0, g)),
+                  group(dv, rank)] + [page(i) for i in range(pages)],
+        out_specs=group(dv, C),
+        scratch_shapes=[
+            pltpu.VMEM((heads, dv, C), jnp.float32),
+            pltpu.VMEM((heads, 8, C), jnp.float32),
+            pltpu.VMEM((heads, 8, C), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_chunk_kernel, pages=pages, rank=rank,
+                          scale=float(scale)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((H, dv, C), dt),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_LATENT_CHUNK_VMEM),
+        interpret=interpret,
+        name="tadnn_latent_chunk",
+    )(table_row, pos0, qt, w_uk.astype(dt).reshape(rank, H * nope),
+      w_uv.astype(dt).transpose(1, 2, 0), *([pool] * pages))
+    return out.transpose(2, 0, 1)
 
 
 def latent_attention_reference(q, rows, ctx_lens, *, scale: float,
