@@ -705,7 +705,7 @@ def fused_phase(name: str, *, seed: int, on_chip: bool) -> dict:
 
     close("chunk_logits", lg_chunk_b, lg_chunk_a)
     plan = programs.layer_plan(cfg)
-    first_sparse = next((i for i, (_, _, sparse) in enumerate(plan)
+    first_sparse = next((i for i, (_, _, sparse, _) in enumerate(plan)
                          if sparse), len(plan))
     for side in ("k", "v"):
         for i, (a, b) in enumerate(zip(kv_a[side], kv_b[side])):

@@ -290,6 +290,13 @@ def latent(bench):
     return _serve(bench, _cell_of("joyai-llm-flash-ep8"))
 
 
+@pytest.fixture(scope="module")
+def shortcut(bench):
+    """A model of shortcut-connected double layers: latent mixers, dense
+    FFNs, an expert branch across each pair, zero-compute experts."""
+    return _serve(bench, _cell_of("longcat-flash-omni-ep32"))
+
+
 # field of a ``serve.step`` event -> who indexes it (benchmark/ paths)
 STEP_FIELDS = {
     "decode_s": "lib/readers.py:13 lib/serve_phases.py:77 lib/counts_moe.py:47",
@@ -813,6 +820,69 @@ def test_a_new_metric_has_its_file_and_its_cells(name):
     assert entry["source"] == "program_span" and entry["layer"] == "serve step"
 
 
+# -- 5b'. zero-compute experts and the shortcut branch (PR 39) ------------------
+
+ROUTED_METRICS = ("zero_expert_share", "moe_live_pairs_per_row")
+
+
+@pytest.mark.parametrize("name", ROUTED_METRICS)
+def test_a_routed_metric_has_its_file_and_its_cell(name):
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
+    assert name + ".py" in METRIC_FILES
+    assert entry == {
+        "name": name, "unit": {"zero_expert_share": "%"}.get(name, "pairs"),
+        "better": "higher", "source": "program_counter",
+        "layer": "expert layer", "moves": "serve_tokens_per_s",
+        "workloads": ["longcat-flash-omni-ep32.serve-backlog-deep-routed"]}
+    assert entry in BENCHMARK["per_layer"][-2:]  # appended, nothing moved
+
+
+def test_the_routed_readers_read_the_programs_counters(bench, shortcut,
+                                                        capsys):
+    """``moe_zero_pairs`` and ``moe_rows`` on every ``serve.step`` that read
+    a step of a model with expert layers (``moe_pairs`` on those whose rows
+    decoded alone), and what the two readers make of them: at the
+    rehearsal's sizes (top 3 of 16 + 8, 2 expert branches) a share between
+    0 and 100% and at most 3 live pairs a row."""
+    rec = shortcut["record"]
+    read = [s for s in rec["serve_steps"] if "moe_rows" in s]
+    assert read and all("moe_zero_pairs" in s for s in read)
+    assert any("moe_pairs" in s for s in read)
+    assert not [s for s in read if "moe_pairs" in s and s["moe_rows"] > 4]
+    ev = rec["serve_engine"]
+    assert (ev["zero_experts"], ev["shortcut_experts"]) == (8, True)
+    share = _span_reader("zero_expert_share")(rec)
+    line = json.loads(capsys.readouterr().out.strip())["zero_experts"]
+    assert line["expert_layers"] == 2 and line["rows"] == sum(
+        s["moe_rows"] for s in read)
+    assert share == pytest.approx(
+        100 * line["zero_pairs"] / (3 * 2 * line["rows"])) and 0 < share < 100
+    live = _span_reader("moe_live_pairs_per_row")(rec)
+    line = json.loads(capsys.readouterr().out.strip())["moe_live_pairs"]
+    assert line["due_a_row"] == 3 * 4 / 24
+    assert 0 <= live <= 3 and live == line["pairs"] / (2 * line["rows"])
+
+
+@pytest.mark.parametrize("name", ROUTED_METRICS)
+def test_a_routed_reader_finds_nothing_where_there_is_nothing(
+        bench, shortcut, experts, dense, name, capsys):
+    """No zero-compute expert (a model that routes over its experts alone
+    reads no share, and its live pairs only where the program counts rows),
+    no expert layer, a program without the counters (a parent commit's
+    events): ``None``, and no error."""
+    reader = _span_reader(name)
+    assert reader(dense["record"]) is None
+    if name == "zero_expert_share":
+        assert reader(experts["record"]) is None
+    rec = shortcut["record"]
+    old = [{k: v for k, v in s.items()
+            if k not in ("moe_zero_pairs", "moe_rows")}
+           for s in rec["serve_steps"]]
+    assert reader({**rec, "serve_steps": old}) is None
+    assert reader({**rec, "serve_steps": []}) is None
+    capsys.readouterr()
+
+
 # -- 5c. the chunk's latent attention as one kernel (PR 38) ---------------------
 
 
@@ -830,8 +900,10 @@ def test_the_chunk_kernels_metric_has_its_file_and_its_cell(bench, latent,
         "name": name, "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "attention kernels",
         "moves": "serve_tokens_per_s",
-        "workloads": ["joyai-llm-flash-ep8.serve-backlog-deep"]}
-    assert BENCHMARK["per_layer"][-1] == entry  # appended, nothing moved
+        "workloads": ["joyai-llm-flash-ep8.serve-backlog-deep",
+                      "longcat-flash-omni-ep32.serve-backlog-deep-routed"]}
+    # appended, nothing moved (PR 39 appended its two behind it)
+    assert BENCHMARK["per_layer"][-3] == entry
     reader = _span_reader(name)
     for run in (latent, experts):
         assert reader(run["record"]) is None

@@ -227,7 +227,8 @@ _GPT2_1P3B = dict(
 _SERVING = {"gpt2-1p3b": (8, 1024, 16, 128, None),
             "trinity-large-ep8": (16, 13312, 16, 512, None),
             "olmo-hybrid-7b-pp2": (8, 33792, 16, 512, 4609),
-            "joyai-llm-flash-ep8": (24, 34816, 64, 512, 4097)}
+            "joyai-llm-flash-ep8": (24, 34816, 64, 512, 4097),
+            "longcat-flash-omni-ep32": (24, 34816, 64, 512, 4097)}
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk",
@@ -250,6 +251,9 @@ def test_serving_programs_update_the_pool_in_place(
     of weights) and ``joyai-llm-flash-ep8`` (20 latent layers, 19 of them
     with 32 of 256 experts: 4,097 pages of 64 latent rows stored in 640
     lanes, one array a layer, 24 slots of 34,816 beside 6.9 GiB of weights)
+    and ``longcat-flash-omni-ep32`` (8 sublayers of 64 latent heads at d 6,144
+    with a dense FFN each, an expert branch of 16 of 512 experts across each
+    pair: 2.5 GiB of latent pages beside 10.1 GiB of weights)
     alike: no layer's weight is converted, the pool is updated
     in place (the output aliases it: pages, states and tails), and no copy
     of a layer's pages or states is among the temporaries (threaded through
@@ -343,7 +347,7 @@ def test_serving_programs_update_the_pool_in_place(
     if not cfg.n_expert_layers:
         absent.add("tadnn.ffn_expert")
     assert scoped == set(programs.SCOPES) - absent
-    latent = config == "joyai-llm-flash-ep8"
+    latent = "latent_attention" in (cfg.layer_types or ())
     mine, other = (("tadnn_paged_decode_latent", "tadnn_paged_decode_folded")
                    if latent else
                    ("tadnn_paged_decode_folded", "tadnn_paged_decode_latent"))
@@ -407,23 +411,27 @@ def test_serving_programs_update_the_pool_in_place(
         assert round(sum(made["pool"].bytes_state) / 1e9, 2) == 0.25
         assert mem.argument_size_in_bytes < 14.0 * 2**30
     elif latent:
-        # 20 latent layers' kernel calls (none in the chunk alone), the
-        # grouped matmuls of 19 expert layers; 6.25 GiB of latent pages
-        assert text.count("tadnn_paged_decode_latent") >= 20 * (
+        # the latent layers' kernel calls (none in the chunk alone: 20, or
+        # 8 sublayers), the grouped matmuls of the expert layers (19, or 4
+        # branches); 6.25 or 2.5 GiB of latent pages
+        n_latent, heads = cfg.n_layers, cfg.n_heads
+        assert text.count("tadnn_paged_decode_latent") >= n_latent * (
             program != "prefill_chunk")
         # a chunk's attention is ONE kernel a layer: no loop over key
-        # blocks, no [32, 512, 512] scores among the program's arrays
+        # blocks, no [heads, 512, 512] scores among the program's arrays
         chunks = len(re.findall(r"^\s*%tadnn_latent_chunk[.\d]* = ", text,
                                 re.M))
-        assert chunks == 20 * (program != "decode_step")
-        assert "[32,512,512]" not in text
+        assert chunks == n_latent * (program != "decode_step")
+        assert f"[{heads},512,512]" not in text
         assert not [l.strip()[:120] for l in text.splitlines()
                     if " while(" in l and "attend_chunk" in l]
-        assert text.count("tadnn_moe_grouped_mm") >= 38
+        assert text.count("tadnn_moe_grouped_mm") >= 2 * cfg.n_expert_layers
         assert "tadnn_gdn" not in text
         assert made["pool"].bytes_latent == pool_bytes
-        assert round(pool_bytes / 2**30, 2) == 6.25
-        assert mem.argument_size_in_bytes < 13.3 * 2**30
+        gib, held = {"joyai-llm-flash-ep8": (6.25, 13.3),
+                     "longcat-flash-omni-ep32": (2.5, 12.8)}[config]
+        assert round(pool_bytes / 2**30, 2) == gib
+        assert mem.argument_size_in_bytes < held * 2**30
     elif config == "trinity-large-ep8":
         assert text.count("tadnn_moe_grouped_mm") >= 8  # 2 kernels, 4 layers
         assert round(pool_bytes / 2**30, 2) == 1.94

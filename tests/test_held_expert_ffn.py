@@ -85,7 +85,9 @@ def _layer_case(T, k, choose, *, valid=None, poison=False):
         assert {n: int(v) for n, v in stats.items()} == {
             "pairs": sizes.sum(), "experts_touched": (sizes > 0).sum(),
             "max_expert_tokens": sizes.max(),
-            "tiles_active": (-(-sizes // tm)).sum()}
+            "tiles_active": (-(-sizes // tm)).sum(),
+            # no zero-compute expert in this layer; the rows that are real
+            "zero_pairs": 0, "rows": T if mask is None else mask.sum()}
         assert int(stats["tiles_active"]) <= n_tiles
         return stats
 

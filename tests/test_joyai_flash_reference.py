@@ -27,15 +27,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import serve_by_hand
 
 from torch_automatic_distributed_neural_network_tpu.inference import decode
 from torch_automatic_distributed_neural_network_tpu.inference.serve import (
     ServeEngine,
-    programs,
 )
 from torch_automatic_distributed_neural_network_tpu.inference.serve.kv_pool import (
     PagedKVPool,
-    blocks_for_tokens,
 )
 from torch_automatic_distributed_neural_network_tpu.models.transformer_core import (
     DecoderLM,
@@ -289,94 +288,10 @@ def test_the_latents_norms_stay_float32_when_the_rest_is_rounded():
 # -- the three serving programs, driven by hand --------------------------------
 
 
-class Served:
-    """The programs over a pool of ``n_slots`` slots, as the engine drives
-    them: ``prefill(slot, tokens)`` in chunks, ``decode({slot: token})`` one
-    step, ``fused(slot, chunk, {slot: token})`` both in one call."""
-
-    def __init__(self, flat: dict, n_slots: int = 3, max_len: int = 96,
-                 cache=jnp.float32, impl: str = "paged"):
-        self.cfg = TransformerConfig(**KEYS, dtype=jnp.float32, remat=False)
-        self.params = weights.nest(flat)
-        self.n_slots, self.MB = n_slots, blocks_for_tokens(max_len, BS)
-        self.pool = PagedKVPool(
-            self.cfg, num_blocks=n_slots * self.MB + 1, block_size=BS,
-            dtype=cache, n_slots=n_slots, max_blocks=self.MB,
-            prefill_chunk=CHUNK)
-        self.kv = self.pool.kv
-        self.rows = {s: self.pool.table_row(self.pool.alloc(self.MB), self.MB)
-                     for s in range(n_slots)}
-        self.ctx = {}
-        self._chunk = jax.jit(lambda *a: programs.prefill_chunk(
-            *a, cfg=self.cfg, max_blocks=self.MB))
-        self._step = jax.jit(lambda *a: programs.decode_logits(
-            *a, cfg=self.cfg, attention_impl=impl))
-        self._fused = jax.jit(lambda *a: programs.chunk_and_step(
-            *a, cfg=self.cfg, max_blocks=self.MB, chunk=CHUNK,
-            sample=decode.SampleConfig(temperature=0.0),
-            attention_impl=impl))
-
-    def _packed_chunk(self, slot, tokens, pos):
-        part = list(tokens)
-        return programs.pack_chunk(
-            self.rows[slot], part + [0] * (CHUNK - len(part)), pos,
-            len(part) - 1, slot)
-
-    def chunks(self, slot: int, tokens):
-        """A chunk a ``next``: ``{its last real position: logits}``."""
-        tokens = list(tokens)
-        self.ctx[slot] = len(tokens)
-        for pos in range(0, len(tokens), CHUNK):
-            part = tokens[pos:pos + CHUNK]
-            self.kv, lg = self._chunk(
-                self.params, self.kv, self._packed_chunk(slot, part, pos),
-                self.pool.win_tables[slot])
-            yield {pos + len(part) - 1: np.asarray(lg[0])}
-
-    def prefill(self, slot: int, tokens) -> dict:
-        return {p: r for c in self.chunks(slot, tokens) for p, r in c.items()}
-
-    def _step_operands(self, toks: dict):
-        S = self.n_slots
-        tables = np.zeros((S, self.MB), np.int32)
-        ctx, tok = np.zeros((S,), np.int32), np.zeros((S, 1), np.int32)
-        active = np.zeros((S,), bool)
-        for s, t in toks.items():
-            tables[s], ctx[s], tok[s, 0], active[s] = (
-                self.rows[s], self.ctx[s], t, True)
-            self.ctx[s] += 1
-        return tables, ctx, tok, active
-
-    def decode(self, toks: dict) -> dict:
-        tables, ctx, tok, active = self._step_operands(toks)
-        self.kv, lg, _ = self._step(
-            self.params, self.kv, jnp.asarray(tables), self.pool.win_tables,
-            jnp.asarray(ctx), jnp.asarray(tok), jnp.asarray(active))
-        return {s: np.asarray(lg[s, 0]) for s in toks}
-
-    def fused(self, slot: int, part, pos: int, toks: dict):
-        """One chunk of ``slot`` at ``pos`` with the decode rows ``toks`` of
-        other slots in it: ``(the chunk's last row's logits, {slot: the
-        token its decode row was served})``."""
-        tables, ctx, tok, active = self._step_operands(toks)
-        step = programs.pack_step(
-            tables, ctx, tok, active.astype(np.int32),
-            np.zeros((self.n_slots,), np.int32))
-        self.ctx[slot] = pos + len(part)
-        self.kv, out, lg = self._fused(
-            self.params, self.kv, programs.pack_chunk_and_step(
-                self._packed_chunk(slot, part, pos), step),
-            programs.step_output(self.n_slots), self.pool.win_tables[slot],
-            self.pool.win_tables, jax.random.key(0))
-        out = np.asarray(out)
-        return np.asarray(lg[0]), {
-            s: int(out[self.n_slots + s]) for s in toks}
-
-    def sequence(self, slot: int, seq, n_prompt: int) -> dict:
-        out = self.prefill(slot, seq[:n_prompt])
-        for pos in range(n_prompt, len(seq)):
-            out[pos] = self.decode({slot: seq[pos]})[slot]
-        return out
+def Served(flat: dict, **kw):
+    """``serve_by_hand.Served`` over this file's model and page sizes."""
+    return serve_by_hand.Served(KEYS, weights.nest(flat), chunk=CHUNK,
+                                block=BS, **kw)
 
 
 def _close(got: dict, want: np.ndarray, what: str = ""):

@@ -311,9 +311,9 @@ def compute_dtype_params(params: Any, cfg: TransformerConfig) -> Any:
     stack (on a v5e they were 11 of the 26.5 ms of the GPT-2 1.3B decode
     step and 11 of a prefill chunk's 17.5).
 
-    Rounded: under ``layers`` everything in ``attn`` and ``mlp`` but the
-    router, which is the kernels and biases of ``nn.Dense`` /
-    ``nn.DenseGeneral`` with ``dtype=cfg.dtype`` (flax's ``promote_dtype``
+    Rounded: under ``layers`` everything in ``attn``, ``mlp`` and ``moe``
+    (a shortcut branch's experts) but the router, which is the kernels and
+    biases of ``nn.Dense`` / ``nn.DenseGeneral`` with ``dtype=cfg.dtype`` (flax's ``promote_dtype``
     rounds both) and the expert stacks.  The arithmetic is the same: the
     same rounding of the same leaf, and the TPU's compiler does round
     these (it moves the converts out of the scan, over the whole stacks).
@@ -334,8 +334,8 @@ def compute_dtype_params(params: Any, cfg: TransformerConfig) -> Any:
     dtype = jnp.dtype(cfg.dtype)
 
     def rounded(layer):
-        return {k: _cast_floats(v, dtype) if k in ("attn", "mlp") else v
-                for k, v in layer.items()}
+        return {k: _cast_floats(v, dtype) if k in ("attn", "mlp", "moe")
+                else v for k, v in layer.items()}
 
     if "layers" in params:
         return {**params, "layers": rounded(params["layers"])}

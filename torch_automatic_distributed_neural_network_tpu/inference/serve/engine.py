@@ -497,7 +497,7 @@ class ServeEngine:
         # asked once here; and the layers whose chunk is ONE kernel call,
         # whose key blocks ``serve.step`` counts (``chunk_key_blocks``).
         # (A single-shot engine's chunk is as long as its prompt: not said)
-        paged = [kind for _, kind, _ in layer_plan(self.cfg)
+        paged = [kind for _, kind, *_ in layer_plan(self.cfg)
                  if kind != "linear_attention"] * bool(self.prefill_chunk)
         self.chunk_attention = {
             kind or "full_attention": programs.chunk_attention_form(
@@ -566,6 +566,11 @@ class ServeEngine:
                           if self.cfg.n_expert_layers else 0),
             experts_published=(self.cfg.experts_published
                                if self.cfg.n_expert_layers else 0),
+            # the block: the router's zero-compute outputs behind the
+            # published ones, and whether the expert FFN is a shortcut
+            # branch over a pair of sublayers (the plan's open / close)
+            zero_experts=self.cfg.zero_experts,
+            shortcut_experts=self.cfg.shortcut_experts,
             moe_tiles_laid=self._tiles_laid,
             chunk_attention=self.chunk_attention or None,
             # latent pages are pages for max_len: in kv_bytes_full too
@@ -1164,11 +1169,14 @@ class ServeEngine:
                 # first three of a step that decoded alone: where the rows
                 # rode in a chunk the layers routed its rows with them (the
                 # live tiles are set against the tiles that call laid)
+                # (the zero-compute pairs and the rows routed count a
+                # call's rows whatever they rode in: on both)
                 experts = bool(self.cfg.n_expert_layers)
-                skip = 4 if not experts else 3 if fused else 0
+                skip = 6 if not experts else 3 if fused else 0
                 self._counters = dict(zip(
                     ("moe_pairs", "moe_experts_touched",
                      "moe_max_expert_tokens", "moe_tiles_active",
+                     "moe_zero_pairs", "moe_rows",
                      "attn_grid_items", "attn_grid_dense")[skip:],
                     map(int, counters[skip:])))
                 if experts:

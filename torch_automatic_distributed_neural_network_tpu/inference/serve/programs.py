@@ -61,8 +61,17 @@ discipline of ``decode.forward_cached``: ``SelfAttention.qkv`` /
   weights are read once), and each group touches the cache as its own
   program does (``_chunk_*``, ``_step_*``: one source for all three).
 
+- Under ``cfg.shortcut_experts`` a plan entry is a SUBLAYER (a mixer and
+  its dense FFN) and the expert FFN a branch across a pair of them: the
+  entry that opens it routes its FFN's normed input through ``SparseMLP``
+  (the subtree ``moe``) and the entry that closes it adds the result behind
+  its own FFN (``transformer_core.ffn_sublayer``: the same order as the
+  model's forward); ``_walk`` carries the result from the one to the other,
+  in all three programs.
+
 A layer is traced once a kind, not once a layer: the walk calls one jitted
-function a (kind, FFN) pair, so a model of 24 like layers traces one.
+function a (kind, FFN, branch) triple, so a model of 24 like layers traces
+one.
 
 Host operands go up PACKED, one int32 array a call (``pack_step``,
 ``pack_chunk``), and the step's tokens come back with its expert counters
@@ -88,6 +97,7 @@ from ...models.transformer_core import (
     SelfAttention,
     SparseMLP,
     TransformerConfig,
+    ffn_sublayer,
     layer_plan,
     make_norm,
     rope,
@@ -130,6 +140,7 @@ SCOPES = (
     "tadnn.mix_out",       # the output projection, its norm, the residual
     "tadnn.ffn",           # a dense FFN (and the toy routed experts)
     "tadnn.ffn_expert",    # ``SparseMLP``: router, layout, kernels, combine
+                           # (a shortcut branch's too, inside ``tadnn.ffn``)
     "tadnn.head",          # the final norm, the logits and the sampler
 )
 
@@ -160,7 +171,7 @@ def _logits(params, cfg: TransformerConfig, x):
 
 
 def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
-           moe: str = "dense", adapted=None):
+           moe: str = "dense", adapted=None, branch=None, carried=None):
     """One layer, its mixer by ``kind``: ``attend`` is what touches the
     cache, everything else is shared by the rows, whatever call they are
     of.  On an attention layer ``attend(q, k, v)`` writes the new keys and
@@ -173,8 +184,11 @@ def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
     returns the heads' outputs (``piece(name, *a)`` is the mixer's method
     ``name``: ``expand``, ``absorb``, ``lift``).
     ``adapted(tensor, site, inp, rotate)`` adds a tenant's low-rank delta at
-    a projection (decode steps with tenants).  Returns ``(x, the expert
-    FFN's counters or None)``."""
+    a projection (decode steps with tenants).  ``branch`` and ``carried``
+    are the plan entry's and what an open shortcut branch holds
+    (``transformer_core.ffn_sublayer``, which orders this half of the layer
+    for the model's own forward as well).  Returns ``(x, the expert FFN's
+    counters or None, what is carried on)``."""
     dtype = cfg.dtype
     norm = make_norm(cfg)
     # int8 weight-only serving: only this layer's weights convert
@@ -216,26 +230,32 @@ def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
         if cfg.sandwich_norm:
             ao = norm.apply({"params": lp["post_attn_norm"]}, ao)
         x = x + ao
-    counters = None
+
+    def dense(u):
+        if "experts_up" in lp["mlp"]:  # the capacity-routed toy experts
+            return (_moe_mlp_routed(lp["mlp"], u, cfg) if moe == "routed"
+                    else _moe_mlp_cached(lp["mlp"], u, cfg))
+        return MLPBlock(cfg).apply({"params": lp["mlp"]}, u)
+
+    def experts(u):
+        if sparse:  # in the FFN's place, under its scope below
+            return SparseMLP(cfg).apply({"params": lp["mlp"]}, u, valid)
+        with jax.named_scope("tadnn.ffn_expert"):  # a shortcut branch
+            return SparseMLP(cfg).apply({"params": lp["moe"]}, u, valid)
+
     with jax.named_scope("tadnn.ffn_expert" if sparse else "tadnn.ffn"):
-        h = norm.apply({"params": lp["mlp_norm"]}, x) if cfg.pre_norm else x
-        if sparse:
-            h, counters = SparseMLP(cfg).apply({"params": lp["mlp"]}, h,
-                                               valid)
-        elif "experts_up" in lp["mlp"]:  # the capacity-routed toy experts
-            h = (_moe_mlp_routed(lp["mlp"], h, cfg) if moe == "routed"
-                 else _moe_mlp_cached(lp["mlp"], h, cfg))
-        else:
-            h = MLPBlock(cfg).apply({"params": lp["mlp"]}, h)
-        if cfg.sandwich_norm:
-            h = norm.apply({"params": lp["post_mlp_norm"]}, h)
-        return x + h, counters
+        return ffn_sublayer(
+            cfg, x, sparse, branch, carried,
+            norm=lambda x: norm.apply({"params": lp["mlp_norm"]}, x),
+            dense=dense, experts=experts,
+            post_norm=lambda h: norm.apply({"params": lp["post_mlp_norm"]},
+                                           h))
 
 
 def _paged(cfg) -> list[int]:
     """The layers that keep keys and values in pages (all but the
     ``linear_attention`` ones)."""
-    return [i for i, (_, kind, _) in enumerate(layer_plan(cfg))
+    return [i for i, (_, kind, *_) in enumerate(layer_plan(cfg))
             if kind != "linear_attention"]
 
 
@@ -251,39 +271,45 @@ def _work_of(kind: str | None) -> str:
     return "latent" if kind == "latent_attention" else _pages_of(kind)
 
 
-N_COUNTERS = 6  # what a step's output carries after its tokens
+N_COUNTERS = 8  # what a step's output carries after its tokens
 
 
 def _moe_counters(stats: list) -> jax.Array:
     """[pairs that landed here, experts touched, most tokens on one expert,
-    row tiles that held pairs] over the step's expert layers (zeros for a
-    model without any)."""
+    row tiles that held pairs, pairs on zero-compute experts] over the
+    step's expert layers, then the valid rows a layer routed (the same in
+    every layer: the call's); zeros for a model without any."""
     stats = [s for s in stats if s is not None]
     if not stats:
-        return jnp.zeros((4,), jnp.int32)
+        return jnp.zeros((6,), jnp.int32)
     return jnp.stack([
         sum(s["pairs"] for s in stats),
         sum(s["experts_touched"] for s in stats),
         jnp.max(jnp.stack([s["max_expert_tokens"] for s in stats])),
-        sum(s["tiles_active"] for s in stats)])
+        sum(s["tiles_active"] for s in stats),
+        sum(s["zero_pairs"] for s in stats),
+        stats[0]["rows"]])
 
 
 def _walk(cfg, params, kv, x, layer_fn, shared, extras=None):
-    """``layer_fn(kind, sparse)(lp, k_pages, v_pages, x, extra, shared)``
-    over the plan, one jitted function a (kind, FFN) pair so that like
-    layers are traced once; the pair of arrays is the layer's own
-    (``kv_pool``: pages, a ring, or a state and a tail); ``shared`` is what
-    every layer reads (tables, positions, rows), ``extras`` one more
-    operand a layer (a tenant's factors).  Returns ``(x, kv, the layers'
+    """``layer_fn(kind, sparse, branch)(lp, k_pages, v_pages, x, extra,
+    shared, carried)`` over the plan, one jitted function a (kind, FFN,
+    branch) triple so that like layers are traced once; the pair of arrays
+    is the layer's own (``kv_pool``: pages, a ring, or a state and a tail);
+    ``shared`` is what every layer reads (tables, positions, rows),
+    ``extras`` one more operand a layer (a tenant's factors); ``carried`` is
+    what an entry that opens a shortcut branch hands to the one that closes
+    it (None between any other two).  Returns ``(x, kv, the layers'
     counters)``."""
     fns, new_k, new_v, stats = {}, [], [], []
-    for i, (name, kind, sparse) in enumerate(layer_plan(cfg)):
-        fn = fns.get((kind, sparse))
+    carried = None
+    for i, (name, *entry) in enumerate(layer_plan(cfg)):
+        fn = fns.get(tuple(entry))
         if fn is None:
-            fn = fns[kind, sparse] = jax.jit(layer_fn(kind, sparse))
-        x, k_l, v_l, counters = fn(
+            fn = fns[tuple(entry)] = jax.jit(layer_fn(*entry))
+        x, k_l, v_l, counters, carried = fn(
             layer_params(params, name), kv["k"][i], kv["v"][i], x,
-            None if extras is None else extras[i], shared)
+            None if extras is None else extras[i], shared, carried)
         new_k.append(k_l)
         new_v.append(v_l)
         stats.append(counters)
@@ -328,7 +354,7 @@ def _step_shared(cfg, kv, tables, win_tables, ctx_lens, active, adapter_ids,
     if win_tables.shape[1]:
         lo = (ctx_lens - cfg.sliding_window + 1) // bs
         shared["tables"]["ring"] = ring_table(win_tables, MB, lo, hi)
-    kinds = [kind for _, kind, _ in layer_plan(cfg)
+    kinds = [kind for _, kind, *_ in layer_plan(cfg)
              if kind != "linear_attention"]
     grid = jnp.zeros((2,), jnp.int32)
     if attention_impl == "paged" and T == 1 and paged and is_folded(pages0):
@@ -594,7 +620,7 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
     merged-weight semantics).
 
     Returns ``(kv, logits [S, T, V], counters [N_COUNTERS])``: the expert
-    layers' four (``_moe_counters``), then the grid steps the paged calls
+    layers' six (``_moe_counters``), then the grid steps the paged calls
     of the step ran and the ``slots x groups`` a dense grid would have run,
     summed over the layers (zeros where no call takes a work list)."""
     S, T = tok.shape
@@ -604,8 +630,8 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
     # per-slot, per-chunk-offset absolute positions
     positions = ctx_lens[:, None] + jnp.arange(T)[None, :]
 
-    def layer_fn(kind, sparse):
-        def fn(lp, a, b, x, ad, shared):  # a layer's pair of pool arrays
+    def layer_fn(kind, sparse, branch):
+        def fn(lp, a, b, x, ad, shared, carried):  # a, b: the layer's pair
             def attend(*rows):
                 nonlocal a, b
                 if kind == "linear_attention":
@@ -619,12 +645,13 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
                         attention_impl=attention_impl, mesh=mesh)
                 return o
 
-            x, counters = _layer(
+            x, counters, carried = _layer(
                 cfg, lp, kind, sparse, x, positions,
                 jnp.broadcast_to(shared["active"][:, None], (S, T)), attend,
                 adapted=_tenant_delta(cfg, ad, shared["adapter_ids"],
-                                      positions, lora_scaling))
-            return x, a, b, counters
+                                      positions, lora_scaling),
+                branch=branch, carried=carried)
+            return x, a, b, counters, carried
 
         return fn
 
@@ -799,8 +826,8 @@ def prefill_chunk(params, kv, packed, win_row, *, cfg: TransformerConfig,
     tokens, pos0 = packed[max_blocks:max_blocks + C][None], shared["pos0"]
     positions = pos0 + jnp.arange(C)[None, :]
 
-    def layer_fn(kind, sparse):
-        def fn(lp, a, b, x, _, shared):  # a layer's pair of pool arrays
+    def layer_fn(kind, sparse, branch):
+        def fn(lp, a, b, x, _, shared, carried):  # a, b: the layer's pair
             def attend(*rows):
                 nonlocal a, b
                 if kind == "linear_attention":
@@ -815,10 +842,10 @@ def prefill_chunk(params, kv, packed, win_row, *, cfg: TransformerConfig,
                                                *(r[0] for r in rows))
                 return o[None]
 
-            x, _counters = _layer(cfg, lp, kind, sparse, x, positions,
-                                  shared["real"][None], attend,
-                                  moe=moe_decode)
-            return x, a, b, None
+            x, _counters, carried = _layer(
+                cfg, lp, kind, sparse, x, positions, shared["real"][None],
+                attend, moe=moe_decode, branch=branch, carried=carried)
+            return x, a, b, None, carried
 
         return fn
 
@@ -867,8 +894,8 @@ def chunk_and_step(params, kv, packed, prev, win_row, win_tables, rng, *,
     positions = jnp.concatenate([pos0 + jnp.arange(C), ctx_lens])[None]
     valid = jnp.concatenate([real, active])[None]
 
-    def layer_fn(kind, sparse):
-        def fn(lp, a, b, x, _, shared):  # a layer's pair of pool arrays
+    def layer_fn(kind, sparse, branch):
+        def fn(lp, a, b, x, _, shared, carried):  # a, b: the layer's pair
             def attend(*rows):
                 nonlocal a, b
                 if kind == "linear_attention":
@@ -896,9 +923,10 @@ def chunk_and_step(params, kv, packed, prev, win_row, win_tables, rng, *,
                 return jnp.concatenate(
                     [oc, os_[:, 0].astype(oc.dtype)])[None]
 
-            x, counters = _layer(cfg, lp, kind, sparse, x, positions, valid,
-                                 attend)
-            return x, a, b, counters
+            x, counters, carried = _layer(
+                cfg, lp, kind, sparse, x, positions, valid, attend,
+                branch=branch, carried=carried)
+            return x, a, b, counters, carried
 
         return fn
 

@@ -114,6 +114,17 @@ class TransformerConfig:
     score_func: Literal["sigmoid", "softmax"] = "sigmoid"
     route_norm: bool = True  # weights of the chosen experts sum to 1 ...
     route_scale: float = 1.0  # ... times this
+    # zero-compute experts: the router's LAST ``zero_experts`` outputs (its
+    # width is ``experts_published + zero_experts``) have no weights; a token
+    # that picks one gets ``w * u``, its own normed input.  Nobody's share
+    # of an expert-parallel layer: every chip adds them for its own tokens
+    zero_experts: int = 0
+    # the expert FFN as a SHORTCUT branch over a pair of sublayers: the plan
+    # has one entry a sublayer (mixer + dense FFN); entry 2j's experts read
+    # its FFN's normed input and their result is added after entry 2j + 1's
+    # FFN (``layer_plan``: "open", "close").  ``n_dense_layers`` then counts
+    # the entries without experts of their own, the closing ones: n_layers / 2
+    shortcut_experts: bool = False
     # the ``linear_attention`` layers (valid only with one): heads of keys
     # and of values (equal here: no grouping), their sizes, the taps of the
     # short causal convolution on q, k and v, and whether beta reaches 2
@@ -133,6 +144,11 @@ class TransformerConfig:
     latent_nope_head_dim: int | None = None
     latent_rope_head_dim: int | None = None
     latent_value_head_dim: int | None = None
+    # constants on the two latents' norms (``mla_scale_q_lora``,
+    # ``mla_scale_kv_lora``: sqrt(d_model / rank)); what a page stores is
+    # the scaled latent
+    latent_q_scale: float = 1.0
+    latent_kv_scale: float = 1.0
 
     def __post_init__(self):
         kinds = self.layer_types
@@ -178,16 +194,27 @@ class TransformerConfig:
                     0 <= self.first_expert
                     and self.first_expert + self.n_experts_held
                     <= self.experts_published
-                    and self.experts_per_token <= self.experts_published):
+                    and self.experts_per_token <= self.router_width):
                 raise ValueError(
                     f"experts {self.first_expert}.."
                     f"{self.first_expert + self.n_experts_held} held, "
                     f"{self.experts_per_token} a token, of "
                     f"{self.experts_published} published")
+            if self.zero_experts and not self.n_expert_layers:
+                raise ValueError("zero_experts widen a router: the model "
+                                 "has no expert layer")
+            if self.shortcut_experts and (
+                    self.n_layers % 2
+                    or self.n_dense_layers != self.n_layers // 2):
+                raise ValueError(
+                    "shortcut_experts pairs the sublayers: an even n_layers "
+                    "and n_dense_layers == n_layers // 2 (the closing "
+                    "sublayers have no experts of their own)")
         else:
             given = [k for k in ("qk_norm", "attn_gate", "sandwich_norm",
                                  "embed_scale", "head_size",
-                                 "n_dense_layers", "experts_published")
+                                 "n_dense_layers", "experts_published",
+                                 "zero_experts", "shortcut_experts")
                      if getattr(self, k)] + ["pre_norm"] * (not self.pre_norm)
             if given:
                 raise ValueError(
@@ -223,6 +250,12 @@ class TransformerConfig:
     def n_experts_held(self) -> int:
         return (self.experts_published if self.experts_held is None
                 else self.experts_held)
+
+    @property
+    def router_width(self) -> int:
+        """The router's outputs: the published experts, then the
+        zero-compute ones."""
+        return self.experts_published + self.zero_experts
 
     def layer_window(self, kind: str | None) -> int | None:
         """The causal band of a layer of ``kind`` (None: the model's one
@@ -290,12 +323,14 @@ class TransformerConfig:
         if self.layer_types is not None:
             # a layer's mixer by kind, its norms, its FFN by position
             n_sparse = self.n_expert_layers
-            fe, E = self.expert_d_ff, self.experts_published
+            fe, E = self.expert_d_ff, self.router_width
             sparse = n_sparse and (d * E + E + 3 * d * fe * (
                 self.n_experts_held + self.shared_experts))
             mixers = sum(self.mixer_params(kind) for kind in self.layer_types)
             norms = (2 * self.sandwich_norm + 2 * self.pre_norm) * d
-            return (mixers + L * norms + (L - n_sparse) * 3 * d * f
+            # a shortcut branch lies BESIDE its sublayers' dense FFNs
+            n_dense = L if self.shortcut_experts else L - n_sparse
+            return (mixers + L * norms + n_dense * 3 * d * f
                     + n_sparse * sparse
                     + v * d * (1 if self.tie_embeddings else 2) + d)
         mlp = (3 if self.act == "swiglu" else 2) * d * f
@@ -538,28 +573,42 @@ class LatentAttention(nn.Module):
         H, rot = cfg.n_heads, cfg.latent_rope_head_dim
         dense = lambda feats: nn.DenseGeneral(
             feats, axis=-1, dtype=cfg.dtype, use_bias=False)
-        norm = lambda: nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)
-        self.q_a_proj, self.q_a_norm = dense(cfg.latent_q_rank), norm()
+        # a norm followed by a constant gives float32, which ``_scaled``
+        # rounds once, with the constant
+        norm = lambda scale: nn.RMSNorm(
+            epsilon=cfg.norm_eps,
+            dtype=cfg.dtype if scale == 1.0 else jnp.float32)
+        self.q_a_proj = dense(cfg.latent_q_rank)
+        self.q_a_norm = norm(cfg.latent_q_scale)
         self.q_b_proj = dense((H, cfg.latent_nope_head_dim + rot))
-        self.kv_a_proj, self.kv_a_norm = dense(cfg.latent_kv_rank + rot), norm()
+        self.kv_a_proj = dense(cfg.latent_kv_rank + rot)
+        self.kv_a_norm = norm(cfg.latent_kv_scale)
         self.kv_b_proj = dense(
             (H, cfg.latent_nope_head_dim + cfg.latent_value_head_dim))
         self.o_proj = nn.DenseGeneral(cfg.d_model, axis=(-2, -1),
                                       dtype=cfg.dtype, use_bias=False)
 
+    def _scaled(self, y, scale: float):
+        """A latent's norm times its constant (``latent_q_scale``,
+        ``latent_kv_scale``), in the compute dtype."""
+        return y if scale == 1.0 else (y * scale).astype(self.cfg.dtype)
+
     def project(self, x, positions):
         """``x`` [B, T, d] at ``positions`` [B, T]: ``(q_nope [B, T, H,
         nope], q_rope [B, T, H, rope], latent [B, T, kv_rank + rope])``,
         the rotated parts rotated (pairs side by side as published,
-        ``deinterleave``), the latent normed: the row a cache keeps."""
+        ``deinterleave``), the latent normed and scaled: the row a cache
+        keeps."""
         cfg = self.cfg
-        q = self.q_b_proj(self.q_a_norm(self.q_a_proj(x)))
+        q = self.q_b_proj(self._scaled(self.q_a_norm(self.q_a_proj(x)),
+                                       cfg.latent_q_scale))
         q_nope, q_rope = jnp.split(q, [cfg.latent_nope_head_dim], axis=-1)
         c, k_r = jnp.split(self.kv_a_proj(x), [cfg.latent_kv_rank], axis=-1)
+        c = self._scaled(self.kv_a_norm(c), cfg.latent_kv_scale)
         q_rope = rope(deinterleave(q_rope), positions, cfg.rope_theta)
         k_r = rope(deinterleave(k_r)[:, :, None], positions,
                    cfg.rope_theta)[:, :, 0]
-        return q_nope, q_rope, jnp.concatenate([self.kv_a_norm(c), k_r], -1)
+        return q_nope, q_rope, jnp.concatenate([c, k_r], -1)
 
     def up(self):
         """``kv_b_proj``'s kernel [kv_rank, H, nope + value] apart: (W_UK,
@@ -633,9 +682,10 @@ class MLPBlock(nn.Module):
 
 
 class Router(nn.Module):
-    """Scores over ALL the published experts, in float32 (a chip that holds
-    a share of the experts still routes over every one of them), and the
-    per-expert bias that takes part in the choice and never in the weight."""
+    """Scores over ALL the published experts and the zero-compute ones
+    behind them (``cfg.router_width``), in float32 (a chip that holds a share
+    of the experts still routes over every one of them), and the per-expert
+    bias that takes part in the choice and never in the weight."""
 
     cfg: TransformerConfig
 
@@ -643,9 +693,9 @@ class Router(nn.Module):
     def __call__(self, x):
         cfg = self.cfg
         kernel = self.param("kernel", nn.initializers.normal(0.02),
-                            (cfg.d_model, cfg.experts_published), jnp.float32)
+                            (cfg.d_model, cfg.router_width), jnp.float32)
         e_bias = self.param("e_bias", nn.initializers.zeros,
-                            (cfg.experts_published,), jnp.float32)
+                            (cfg.router_width,), jnp.float32)
         logits = jnp.dot(x.astype(jnp.float32), kernel,
                          precision=jax.lax.Precision.HIGHEST)
         return route_top_k(
@@ -658,11 +708,14 @@ class SparseMLP(nn.Module):
     plus the chosen routed experts, no capacity and no dropped token
     (``parallel/expert.held_expert_ffn``).  It holds experts
     ``first_expert .. first_expert + experts_held`` of the published ones
-    and adds what those give; on one chip there is no exchange.
+    and adds what those give; on one chip there is no exchange.  A pair on
+    a zero-compute expert (``cfg.zero_experts``) adds ``w * x`` for the
+    chip's own tokens and reads no weight.
 
     ``valid`` [..., tokens] marks the rows that are real (a padded chunk's
-    tail and an empty slot route nowhere, so they read no expert).  Returns
-    ``(y, stats)``: the step counters of ``held_expert_ffn``."""
+    tail and an empty slot route nowhere, so they read no expert and add no
+    zero-compute term).  Returns ``(y, stats)``: the step counters of
+    ``held_expert_ffn``."""
 
     cfg: TransformerConfig
 
@@ -690,11 +743,43 @@ class SparseMLP(nn.Module):
             rows, chosen, weights, cast(self.experts_gate),
             cast(self.experts_up), cast(self.experts_down),
             first_expert=cfg.first_expert,
-            valid=None if valid is None else valid.reshape(-1))
+            valid=None if valid is None else valid.reshape(-1),
+            n_experts=cfg.experts_published if cfg.zero_experts else None)
         y = y.reshape(*lead, -1)
         if cfg.shared_experts:
             y = y + self.shared(x)
         return y, stats
+
+
+def ffn_sublayer(cfg: TransformerConfig, x, sparse: bool, branch, carried,
+                 *, norm, dense, experts, post_norm):
+    """The second half of a layer of a model with ``layer_types``, from the
+    stream ``x`` behind the mixer's residual add: ONE description of the
+    order for ``KindDecoderLayer`` and the serving programs' ``_layer``,
+    which hand in their own way of applying each part.  ``norm(x)`` is the
+    FFN's norm, ``dense(u)`` the dense FFN, ``experts(u) -> (y, stats)`` the
+    expert FFN, ``post_norm(h)`` the sandwich norm behind the FFN.
+
+    ``sparse``: the expert FFN stands in the dense one's place.  ``branch``
+    (``layer_plan``): an entry that OPENS a shortcut branch also hands its
+    FFN's normed input to the experts and carries their result; the entry
+    that CLOSES it adds what was carried behind its own FFN, so the branch
+    spans the mixer and the FFN between them.  Returns ``(x, the experts'
+    stats or None, what is carried on)``."""
+    u = norm(x) if cfg.pre_norm else x
+    stats = None
+    if branch == "open":
+        carried, stats = experts(u)
+    if sparse:
+        h, stats = experts(u)
+    else:
+        h = dense(u)
+    if cfg.sandwich_norm:
+        h = post_norm(h)
+    x = x + h
+    if branch == "close":
+        x, carried = x + carried, None
+    return x, stats, carried
 
 
 class KindDecoderLayer(nn.Module):
@@ -703,14 +788,17 @@ class KindDecoderLayer(nn.Module):
     ``linear_attention`` layer, or the latent attention of a
     ``latent_attention`` one), a dense or an expert FFN, and the norms
     where ``cfg.pre_norm`` and ``cfg.sandwich_norm`` put them (before each
-    sublayer, after it, or both)."""
+    sublayer, after it, or both).  With a ``branch`` (``layer_plan``) the
+    layer takes and returns what a shortcut branch carries beside ``x``;
+    the branch's experts are the subtree ``moe``."""
 
     cfg: TransformerConfig
     kind: str
     sparse: bool
+    branch: str | None = None
 
     @nn.compact
-    def __call__(self, x, positions, mask=None):
+    def __call__(self, x, positions, mask=None, carried=None):
         cfg = self.cfg
         h = make_norm(cfg, "attn_norm")(x) if cfg.pre_norm else x
         if self.kind == "linear_attention":
@@ -721,27 +809,35 @@ class KindDecoderLayer(nn.Module):
             h = SelfAttention(cfg, self.kind, name="attn")(h, positions, mask)
         if cfg.sandwich_norm:
             h = make_norm(cfg, "post_attn_norm")(h)
-        x = x + h
-        h = make_norm(cfg, "mlp_norm")(x) if cfg.pre_norm else x
-        if self.sparse:
-            h, _ = SparseMLP(cfg, name="mlp")(h)
-        else:
-            h = MLPBlock(cfg, name="mlp")(h)
-        if cfg.sandwich_norm:
-            h = make_norm(cfg, "post_mlp_norm")(h)
-        return x + h
+        x, _, carried = ffn_sublayer(
+            cfg, x + h, self.sparse, self.branch, carried,
+            norm=lambda x: make_norm(cfg, "mlp_norm")(x),
+            dense=lambda u: MLPBlock(cfg, name="mlp")(u),
+            experts=lambda u: SparseMLP(
+                cfg, name="mlp" if self.sparse else "moe")(u),
+            post_norm=lambda h: make_norm(cfg, "post_mlp_norm")(h))
+        return x if self.branch is None else (x, carried)
 
 
-def layer_plan(cfg: TransformerConfig) -> list[tuple[str, str | None, bool]]:
-    """(parameter name, kind, has an expert FFN) of every layer, in order.
-    A model with one kind of layer (no ``layer_types``) is a plan of that one
-    kind, None: its ``layers_i`` is layer ``i`` of the scanned ``layers``
-    stack (``inference/decode.layer_params``)."""
+def layer_plan(cfg: TransformerConfig
+               ) -> list[tuple[str, str | None, bool, str | None]]:
+    """(parameter name, kind, has an expert FFN in the dense one's place,
+    branch) of every layer, in order.  A model with one kind of layer (no
+    ``layer_types``) is a plan of that one kind, None: its ``layers_i`` is
+    layer ``i`` of the scanned ``layers`` stack
+    (``inference/decode.layer_params``).  ``branch`` is None but under
+    ``cfg.shortcut_experts``, whose entries are SUBLAYERS (a mixer and its
+    dense FFN each): the even ones "open" an expert branch and the odd ones
+    "close" it (``ffn_sublayer``)."""
     if cfg.layer_types is None:
-        return [(f"layers_{i}", None, False) for i in range(cfg.n_layers)]
+        return [(f"layers_{i}", None, False, None)
+                for i in range(cfg.n_layers)]
+    if cfg.shortcut_experts:
+        return [(f"layers_{i}", kind, False, ("open", "close")[i % 2])
+                for i, kind in enumerate(cfg.layer_types)]
     n_dense = (cfg.n_layers if cfg.n_dense_layers is None
                else cfg.n_dense_layers)
-    return [(f"layers_{i}", kind, i >= n_dense)
+    return [(f"layers_{i}", kind, i >= n_dense, None)
             for i, kind in enumerate(cfg.layer_types)]
 
 
@@ -881,9 +977,13 @@ def apply_decoder_backbone(
     aux_total = jnp.zeros((), jnp.float32)
     if cfg.layer_types is not None:
         # layers that differ: one module a layer, each of its own kind
-        for name, kind, sparse in layer_plan(cfg):
-            x = KindDecoderLayer(cfg, kind, sparse, name=name)(
-                x, positions, mask)
+        carried = None  # what an open shortcut branch holds
+        for name, kind, sparse, branch in layer_plan(cfg):
+            layer = KindDecoderLayer(cfg, kind, sparse, branch, name=name)
+            if branch is None:
+                x = layer(x, positions, mask)
+            else:
+                x, carried = layer(x, positions, mask, carried)
     elif cfg.scan_layers:
         def body(mdl, carry, _):
             return run_layer(mdl, *carry), None

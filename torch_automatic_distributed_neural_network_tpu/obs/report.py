@@ -439,7 +439,7 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
             # layer kinds, the experts held and the pool by kind of page
             **{k: (sengine or {}).get(k) for k in (
                 "layer_kinds", "experts_held", "experts_published",
-                "kv_bytes_full", "kv_bytes_window", "state_bytes_linear",
+                "zero_experts", "shortcut_experts", "kv_bytes_full", "kv_bytes_window", "state_bytes_linear",
                 "conv_bytes_linear", "kv_bytes_latent", "latent_row")},
             # the decode steps' expert counters (engines with experts)
             "mean_moe_pairs": _mean(e.get("moe_pairs") for e in ssteps),
@@ -448,6 +448,11 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
             "max_moe_expert_tokens": max(
                 _finite(e.get("moe_max_expert_tokens") for e in ssteps),
                 default=None),
+            # the calls' pairs on zero-compute experts and the rows routed
+            "moe_zero_pairs": sum(_finite(
+                e.get("moe_zero_pairs") for e in ssteps)) or None,
+            "moe_rows": sum(_finite(
+                e.get("moe_rows") for e in ssteps)) or None,
             # the expert layers' row tiles that held a pair, by the tiles
             # the call laid out (the engine says what each kind of call
             # lays): [laid a call, calls, live tiles over them]
@@ -1114,14 +1119,25 @@ def format_report(report: dict) -> str:
                     f"convolution tails "
                     f"({kinds.count('linear_attention')} linear layers)")
             if sv.get("experts_published"):
-                eparts.append(f"experts {sv['experts_held']} held of "
-                              f"{sv['experts_published']}")
+                eparts.append(
+                    f"experts {sv['experts_held']} held of "
+                    f"{sv['experts_published']}"
+                    + (f" + {sv['zero_experts']} zero-compute"
+                       if sv.get("zero_experts") else "")
+                    + (" (a shortcut branch over two sublayers)"
+                       if sv.get("shortcut_experts") else ""))
             if sv.get("mean_moe_pairs") is not None:
                 eparts.append(
                     f"a decode step: {sv['mean_moe_pairs']:.1f} pairs here "
                     f"on {sv['mean_moe_experts_touched']:.1f} experts, at "
                     f"most {sv['max_moe_expert_tokens']} tokens on one")
             lines.append("  " + "  ".join(eparts))
+        if sv.get("moe_zero_pairs"):
+            lines.append(
+                f"  zero-compute experts: {sv['moe_zero_pairs']} pairs over "
+                f"{sv['moe_rows']} routed rows "
+                f"({sv['moe_zero_pairs'] / sv['moe_rows']:.2f} a row, all "
+                f"expert layers)")
         if sv.get("moe_tiles"):
             chunk, alone = sv["moe_tiles_laid"] or (0, 0)
             lines.append(

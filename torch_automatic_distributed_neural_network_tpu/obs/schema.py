@@ -346,6 +346,11 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # lands in them: [a call with a chunk, a decode-only call],
             # summed over the expert layers (0 and 0 without any)
             "moe_tiles_laid": "list",
+            # the block: the router's zero-compute outputs behind the
+            # published experts (a pair on one adds w * x and reads no
+            # weight), and whether the expert FFN is a shortcut branch
+            # over a pair of sublayers (layer_kinds then lists sublayers)
+            "zero_experts": "int", "shortcut_experts": "bool",
             # how a prefill chunk attends, a kind of layer that keeps
             # pages: "kernel" (one call of tadnn_latent_chunk a layer) or
             # "blocks" (jax.numpy over key blocks), decided at build by
@@ -415,6 +420,11 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # moe_tiles_laid: the chunk's where the rows rode in one); on
             # EVERY call of a model with expert layers, fused ones too
             "moe_tiles_active": "int", "moe_tiles_laid": "int",
+            # the call's pairs on zero-compute experts, summed over the
+            # expert layers, and the valid rows a layer routed (chunk rows
+            # and decode rows together): on every call that is read of a
+            # model with expert layers, fused ones too
+            "moe_zero_pairs": "int", "moe_rows": "int",
             # the grid steps the decode step's paged attention calls ran
             # (the live (slot, key group) items of the folded kernel's
             # work list) and the slots x groups a dense grid would have

@@ -335,10 +335,11 @@ def route_top_k(
 
 def expert_tiles(n_rows: int, top_k: int, held: int) -> tuple[int, int]:
     """``(tm, n_tiles)``: the rows of a tile and the tiles
-    ``held_expert_ffn`` lays for ``n_rows`` tokens: room for every one of the
-    ``n_rows * top_k`` pairs on this chip, each held expert's last tile
-    part empty."""
-    pairs = n_rows * top_k
+    ``held_expert_ffn`` lays for ``n_rows`` tokens: room for every pair that
+    CAN land on this chip (a token's choices are ``top_k`` different
+    experts, so at most ``held`` of them are held here), each held expert's
+    last tile part empty."""
+    pairs = n_rows * min(top_k, held)
     tm = 16 if pairs <= 256 else 128
     return tm, -(-pairs // tm) + min(held, pairs)
 
@@ -353,6 +354,7 @@ def held_expert_ffn(
     *,
     first_expert: int = 0,
     valid: jax.Array | None = None,  # [T] bool: rows that are real
+    n_experts: int | None = None,  # ids from here on are zero-compute experts
 ) -> tuple[jax.Array, dict]:
     """``sum_e w_e * SwiGLU_e(x)`` over the chosen experts that are held
     here (``first_expert .. first_expert + held``); what the others would
@@ -378,9 +380,17 @@ def held_expert_ffn(
     the chip runs either an element at a time (4,288 steps for the pairs of
     a chunk), and a sum over a [held, pairs] mask is a few vector adds.
 
+    A pair on an id at or past ``n_experts`` chose a ZERO-COMPUTE expert,
+    which gives back its input: a row gets ``(the sum of its weights on such
+    ids) * x``, in float32, beside the matmuls' result and outside them; such
+    a pair is never sorted into a tile (its id lies past every held expert).
+    Every chip adds this term for its own tokens.
+
     Returns ``(y [T, d], stats)`` with the step's counters: ``pairs`` that
     landed here, ``experts_touched``, ``max_expert_tokens``,
-    ``tiles_active`` (``n_active``, of the ``expert_tiles`` laid)."""
+    ``tiles_active`` (``n_active``, of the ``expert_tiles`` laid),
+    ``zero_pairs`` (the valid rows' pairs on zero-compute experts) and
+    ``rows`` (the valid rows)."""
     from ..ops.grouped_matmul import grouped_matmul
 
     T, d = x.shape
@@ -439,8 +449,17 @@ def held_expert_ffn(
     per_pair = jnp.where(here[:, None], y[pair_row], 0).reshape(k, T, d)
     w = jnp.where(here.reshape(k, T), weights.T.astype(jnp.float32), 0.0)
     out = jnp.einsum("kt,ktd->td", w, per_pair.astype(jnp.float32))
+    real = jnp.ones((T,), bool) if valid is None else valid
+    zero_pairs = jnp.int32(0)
+    if n_experts is not None:
+        zero = (chosen >= n_experts) & real[:, None]
+        w_zero = jnp.where(zero, weights.astype(jnp.float32), 0.0).sum(-1)
+        out = out + w_zero[:, None] * x.astype(jnp.float32)
+        zero_pairs = zero.sum().astype(jnp.int32)
     stats = {"pairs": here.sum().astype(jnp.int32),
              "experts_touched": (sizes > 0).sum().astype(jnp.int32),
              "max_expert_tokens": sizes.max().astype(jnp.int32),
-             "tiles_active": n_active.astype(jnp.int32)}
+             "tiles_active": n_active.astype(jnp.int32),
+             "zero_pairs": zero_pairs,
+             "rows": real.sum().astype(jnp.int32)}
     return out.astype(x.dtype), stats
