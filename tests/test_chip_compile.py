@@ -217,6 +217,37 @@ def test_kernel_is_named_in_the_compiled_text(kernel_texts, name, where):
     assert any(name in c for c in calls), (name, calls)
 
 
+def _operands(line: str) -> int:
+    """Operands of a custom call, counted by their ``%`` references, as
+    ``benchmark/lib/trace.n_operands`` counts them in a trace."""
+    body = line[line.index("custom-call(") + len("custom-call("):]
+    depth = 1
+    for i, ch in enumerate(body):
+        depth += (ch == "(") - (ch == ")")
+        if not depth:
+            return body[:i].count("%")
+    raise AssertionError(line)
+
+
+def test_flash_calls_keep_the_shape_the_roofline_metric_reads(kernel_texts):
+    """The cell's own call (``[16, 1024, 16, 128]`` bf16, causal), forward
+    and backward, compiled for the v5e: the contract that
+    ``benchmark/metrics/flash_attn_roofline.py`` reads.  That reader tells
+    a forward call by its THREE operands and gives every other Mosaic call
+    half of a backward pass's least time, so the forward takes q, k, v and
+    nothing else (no scalar-prefetch operand), and the backward is TWO
+    calls (dk/dv and dq, six operands each), not one fused kernel."""
+    calls = {l.split(" = ")[0].strip().lstrip("%"): _operands(l)
+             for l in kernel_texts[0].splitlines()
+             if 'custom_call_target="tpu_custom_call"' in l}
+    by_kernel = {name: [n for call, n in calls.items() if name in call]
+                 for name in ("tadnn_flash_fwd", "tadnn_flash_bwd_dkv",
+                              "tadnn_flash_bwd_dq")}
+    assert by_kernel == {"tadnn_flash_fwd": [3], "tadnn_flash_bwd_dkv": [6],
+                         "tadnn_flash_bwd_dq": [6]}, calls
+    assert len(calls) == 3, calls
+
+
 # -- the two serving programs, for the tree and the pool the engine holds ------
 
 _GPT2_1P3B = dict(

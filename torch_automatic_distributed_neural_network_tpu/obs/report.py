@@ -166,6 +166,12 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                           for k in ("strategy", "mesh", "remat", "precision",
                                     "zero1")
                           if plan.get(k) is not None}
+    flash = last("flash.plan")
+    if flash:
+        report["flash_plan"] = {
+            k: flash.get(k)
+            for k in ("seq", "head_dim", "block_q", "block_k",
+                      "tiles_visited", "tiles_square", "tiles_masked")}
     decision = last("tune.decision")
     hit = last("tune.cache_hit")
     fallback = last("tune.fallback")
@@ -918,6 +924,13 @@ def format_report(report: dict) -> str:
         if tr.get("final_loss") is not None:
             parts.append(f"final loss {tr['final_loss']:.4f}")
         lines.append("training: " + "  ".join(parts))
+    fl = report.get("flash_plan")
+    if fl:
+        lines.append(
+            "  flash tiles visited / square, masked: "
+            f"{fl['tiles_visited']} / {fl['tiles_square']}, "
+            f"{fl['tiles_masked']}  (seq {fl['seq']}, head_dim "
+            f"{fl['head_dim']}, tiles {fl['block_q']} x {fl['block_k']})")
     good = report.get("goodput")
     if good and good.get("fractions"):
         fr = good["fractions"]

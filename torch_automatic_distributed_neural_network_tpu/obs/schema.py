@@ -129,6 +129,11 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
        req={"data_degree": "int", "predicted_allgather_bytes": "number",
             "predicted_reduce_scatter_bytes": "number",
             "compiled_bytes": "number?"}),
+    _s("flash.plan", "what a flash-attention call does, from its shapes "
+       "(ops/flash_attention.flash_plan); once a trace of the entry",
+       req={"seq": "int", "head_dim": "int", "block_q": "int",
+            "block_k": "int", "tiles_visited": "int",
+            "tiles_square": "int", "tiles_masked": "int"}),
     _s("compile", "first XLA compile of a jitted fn (event from the "
        "jit cache; span from AOT paths); fn=serve: every program XLA "
        "built or loaded in the process during one engine step",
