@@ -446,8 +446,64 @@ def hybrid_kernels_phase(*, seed: int, on_chip: bool) -> dict:
     for r in (0, S + 1):  # the null row and a row no slot has
         if not bool(jnp.array_equal(p1[r], pool0[r])):
             raise RuntimeError(f"gdn step: row {r} was written")
+    kda_kernel_checks(rec, close, next(keys), on_chip)
     latent_kernel_checks(rec, close, next(keys), on_chip)
     return rec
+
+
+def kda_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:
+    """The kernels of a delta rule whose decay is a vector a head, at
+    Kimi-Linear's widths on the chip (32 heads of 128 and 128; a tenth of
+    them in a rehearsal): ``tadnn_kda_chunk`` over a chunk of 512 from a
+    state, float32 and serving's bfloat16 operands, and ``tadnn_kda_step``
+    over the slots' rows of a pool in place, against the token-by-token
+    recurrence; decays a channel as the family initialises them, with one
+    channel that forgets in a token beside one that never does."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torch_automatic_distributed_neural_network_tpu.ops import (
+        gated_delta as gd,
+    )
+
+    H, dk, dv, C, S = (32, 128, 128, 512, 8) if on_chip else (3, 16, 24, 40, 3)
+    interpret = not on_chip
+    keys = iter(jax.random.split(key, 8))
+    qk = lambda: gd.l2norm(jax.random.normal(  # noqa: E731
+        next(keys), (C, H, dk), jnp.float32))
+    q, k = qk() * dk ** -0.5, qk()
+    v = jax.random.normal(next(keys), (C, H, dv), jnp.float32)
+    beta = jax.nn.sigmoid(jax.random.normal(next(keys), (C, H)))
+    A = jax.random.uniform(next(keys), (H, 1), minval=1e-3, maxval=16.0)
+    g = -A * jnp.exp(jax.random.uniform(
+        next(keys), (C, H, dk), minval=math.log(1e-3), maxval=math.log(0.1)))
+    g = g.at[:, :, 0].set(-60.0).at[:, :, 1].set(0.0)
+    state = jax.random.normal(next(keys), (H, dk, dv), jnp.float32)
+    o_ref, s_ref = jax.jit(gd.gated_delta_recurrent)(q, k, v, g, beta, state)
+    chunk = jax.jit(lambda *a: gd.kda_chunk_pallas(*a, interpret=interpret))
+    o, s1 = chunk(q, k, v, g, beta, state)
+    close("kda_chunk_out", o, o_ref)
+    close("kda_chunk_state", s1, s_ref)
+    lo = lambda x: x.astype(jnp.bfloat16)  # noqa: E731 — serving's operands
+    o, s1 = chunk(lo(q), lo(k), lo(v), g, beta, state)
+    close("kda_chunk_bf16_out", o, o_ref, rtol=0.05)
+    rows = jnp.asarray([(r + 2) % (S + 1) if r < S - 2 else 0
+                        for r in range(S)], jnp.int32)
+    pool0 = jax.random.normal(next(keys), (S + 2, H, dk, dv), jnp.float32)
+    idle = rows == 0  # as the decode program masks them
+    args = (q[:S], k[:S], v[:S], jnp.where(idle[:, None, None], 0.0, g[:S]),
+            jnp.where(idle[:, None], 0.0, beta[:S]))
+    o_ref, p_ref = jax.jit(gd.kda_step_xla)(*args, pool0, rows)
+    o, p1 = jax.jit(lambda *a: gd.kda_step_pallas(
+        *a, interpret=interpret), donate_argnums=(5,))(
+            *args, pool0 + 0.0, rows)
+    live = np.asarray(rows) > 0
+    close("kda_step_out", o[live], o_ref[live])
+    close("kda_step_state", p1[rows[live]], p_ref[rows[live]])
+    for r in (0, S + 1):  # the null row and a row no slot has
+        if not bool(jnp.array_equal(p1[r], pool0[r])):
+            raise RuntimeError(f"kda step: row {r} was written")
 
 
 def latent_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:
@@ -565,7 +621,7 @@ def latent_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:
     rec["latent_chunk_kernel_16k_ms"] = 1e3 * (time.perf_counter() - t0) / 20
 
 
-# the four serving configurations at their published widths and a few of
+# the five serving configurations at their published widths and a few of
 # their layers (what is cut is depth, vocabulary, the number of experts and
 # the window: no width), for ``fused_phase``
 FUSED_CUTS = {
@@ -580,6 +636,10 @@ FUSED_CUTS = {
     "joyai-llm-flash-ep8": dict(
         n_layers=3, n_dense_layers=1, vocab_size=8192, experts_held=8,
         experts_published=64, layer_types=["latent_attention"] * 3),
+    "kimi-linear-48b-ep8": dict(
+        n_layers=3, n_dense_layers=1, vocab_size=8192, experts_held=8,
+        experts_published=64, layer_types=[
+            "linear_attention", "linear_attention", "latent_attention"]),
 }
 # bf16 layers: rows of one product taken C + S at a time against C and S
 FUSED_RTOL = 0.03
@@ -730,11 +790,15 @@ def fused_phase(name: str, *, seed: int, on_chip: bool) -> dict:
             jnp.asarray(prev), win[S - 1], win,
             jax.random.key(0)).compile().as_text()
         rec["custom_calls"] = text.count("tpu_custom_call")
-        kernel = ("tadnn_paged_decode_latent" if latent
-                  else "tadnn_paged_decode_folded")
-        if kernel not in text:
-            raise RuntimeError(f"fused {name}: {kernel} is not in the "
-                               "compiled program")
+        kernels = ["tadnn_paged_decode_latent" if latent
+                   else "tadnn_paged_decode_folded"]
+        if "linear_attention" in (cfg.layer_types or ()):
+            rule = "kda" if cfg.linear_decay == "channel" else "gdn"
+            kernels += [f"tadnn_{rule}_chunk", f"tadnn_{rule}_step"]
+        for kernel in kernels:
+            if kernel not in text:
+                raise RuntimeError(f"fused {name}: {kernel} is not in the "
+                                   "compiled program")
     return rec
 
 

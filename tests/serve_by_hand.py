@@ -1,7 +1,9 @@
 """The three serving programs over a small pool, driven by hand as the
 engine drives them: what the reference tests of the models with
 ``layer_types`` share (``test_joyai_flash_reference.py``,
-``test_longcat_flash_reference.py``)."""
+``test_longcat_flash_reference.py``, ``test_kimi_linear_reference.py``: a
+pool of pages, of a linear layer's state rows and tails, or of both; the
+slot's row goes up with a chunk's operands)."""
 
 from __future__ import annotations
 

@@ -297,6 +297,13 @@ def shortcut(bench):
     return _serve(bench, _cell_of("longcat-flash-omni-ep32"))
 
 
+@pytest.fixture(scope="module")
+def mixed(bench):
+    """A model of KDA layers (``linear_attention``, a decay a channel) and
+    latent layers with held experts: state rows AND latent pages."""
+    return _serve(bench, _cell_of("kimi-linear-48b-ep8"))
+
+
 # field of a ``serve.step`` event -> who indexes it (benchmark/ paths)
 STEP_FIELDS = {
     "decode_s": "lib/readers.py:13 lib/serve_phases.py:77 lib/counts_moe.py:47",
@@ -597,6 +604,7 @@ def test_the_names_were_found():
     assert any(k.startswith("tadnn_paged_decode") for k in KERNELS)
     assert any(k.startswith("tadnn_moe_grouped_mm") for k in KERNELS)
     assert {"tadnn_gdn_chunk", "tadnn_gdn_step"} <= set(KERNELS)
+    assert {"tadnn_kda_chunk", "tadnn_kda_step"} <= set(KERNELS)
     assert "tadnn_paged_decode_latent" in KERNELS
     assert len(PROGRAMS) >= 2 and len(WAITS) >= 1
 
@@ -606,7 +614,7 @@ def test_the_names_were_found():
 
 @pytest.mark.parametrize("metric_file", METRIC_FILES)
 def test_a_metric_reader_reads_the_programs_record(bench, dense, experts,
-                                                   hybrid, latent,
+                                                   hybrid, latent, mixed,
                                                    metric_file, capsys):
     reader = _load(os.path.join(BENCH, "metrics", metric_file),
                    "bench_metric")
@@ -617,7 +625,8 @@ def test_a_metric_reader_reads_the_programs_record(bench, dense, experts,
                if w["name"] in entry["workloads"]}
     for config, run in (("gpt2-1p3b", dense), ("trinity-large-ep8", experts),
                         ("olmo-hybrid-7b-pp2", hybrid),
-                        ("joyai-llm-flash-ep8", latent)):
+                        ("joyai-llm-flash-ep8", latent),
+                        ("kimi-linear-48b-ep8", mixed)):
         if config not in configs:
             continue
         value = reader.read(run["record"])
@@ -834,7 +843,7 @@ def test_a_routed_metric_has_its_file_and_its_cell(name):
         "better": "higher", "source": "program_counter",
         "layer": "expert layer", "moves": "serve_tokens_per_s",
         "workloads": ["longcat-flash-omni-ep32.serve-backlog-deep-routed"]}
-    assert entry in BENCHMARK["per_layer"][-2:]  # appended, nothing moved
+    assert entry in BENCHMARK["per_layer"][33:35]  # appended, nothing moved
 
 
 def test_the_routed_readers_read_the_programs_counters(bench, shortcut,
@@ -901,9 +910,10 @@ def test_the_chunk_kernels_metric_has_its_file_and_its_cell(bench, latent,
         "source": "device_trace", "layer": "attention kernels",
         "moves": "serve_tokens_per_s",
         "workloads": ["joyai-llm-flash-ep8.serve-backlog-deep",
-                      "longcat-flash-omni-ep32.serve-backlog-deep-routed"]}
+                      "longcat-flash-omni-ep32.serve-backlog-deep-routed",
+                      "kimi-linear-48b-ep8.serve-backlog-reasoning"]}
     # appended, nothing moved (PR 39 appended its two behind it)
-    assert BENCHMARK["per_layer"][-3] == entry
+    assert BENCHMARK["per_layer"][32] == entry
     reader = _span_reader(name)
     for run in (latent, experts):
         assert reader(run["record"]) is None
@@ -1053,3 +1063,183 @@ def test_the_train_generators_calls_hold_on_a_tiny_model(bench):
     report = ad.compile_report(rng, data.batch(0))
     assert (report or {}).get("per_device_peak_bytes"), \
         "train_step_hbm_gib reads per_device_peak_bytes of compile_report"
+
+
+# -- 9. a pool of state rows and latent pages; the KDA kernels' readers (PR 41) --
+
+KDA_METRICS = ("kda_chunk_ms", "kda_step_ms", "kda_chunk_roofline",
+               "kda_step_roofline")
+KDA_CELL = "kimi-linear-48b-ep8.serve-backlog-reasoning"
+# what the cell's readers index on the events of a model of both kinds
+MIXED_FIELDS = {
+    "linear_mixer": "obs/report.py (the state line: whose the decay is)",
+    "state_bytes_linear": "metrics/state_pool_gib.py:12,15,19",
+    "conv_bytes_linear": "metrics/state_pool_gib.py:16,19",
+    "kv_bytes_latent": "obs/report.py (the latent line beside the pool's)",
+    "latent_row": "obs/report.py (the latent line beside the pool's)",
+    "kv_bytes_full": "metrics/kv_pool_gib.py:10,13,16",
+    "layer_kinds": "metrics/kv_pool_gib.py:15",
+}
+
+
+@pytest.mark.parametrize("field", sorted(MIXED_FIELDS))
+def test_serve_engine_of_a_mixed_pool_carries_its_counters(mixed, field):
+    ev = mixed["record"]["serve_engine"]
+    assert ev is not None and ev.get(field) is not None, (
+        f"serve.engine has no {field!r}; read by " + MIXED_FIELDS[field])
+
+
+def test_serve_engine_says_what_a_mixed_cell_is_about(mixed, hybrid):
+    """Rows and latent pages in one pool: the pages for ``max_len`` are the
+    latent layers' alone, the states and tails the linear layers'; the rule
+    is the gated delta rule with a decay a CHANNEL (a head's on the hybrid
+    model); and ``state_rows`` rides on the steps that read decode rows: the
+    slots that decoded times the linear layers."""
+    ev, eng = mixed["record"]["serve_engine"], mixed["eng"]
+    kinds = list(mixed["record"]["model_keys"]["layer_types"])
+    n_lin = kinds.count("linear_attention")
+    assert ev["linear_mixer"] == ["gated_delta", "channel"]
+    assert hybrid["record"]["serve_engine"]["linear_mixer"] == [
+        "gated_delta", "head"]
+    assert ev["kv_bytes_full"] == ev["kv_bytes_latent"] > 0
+    assert ev["state_bytes_linear"] > ev["conv_bytes_linear"] > 0
+    assert ev["layer_kinds"] == kinds
+    assert eng.pool.n_full == kinds.count("latent_attention") > 0
+    assert eng.pool.state.count(True) == n_lin > 0
+    rows = [s["state_rows"] for s in _steps(mixed) if "state_rows" in s]
+    assert rows and all(r % n_lin == 0 and r > 0 for r in rows)
+    assert max(rows) <= n_lin * mixed["record"]["engine"]["n_slots"]
+    kinds = hybrid["record"]["model_keys"]["layer_types"]
+    rows = [s["state_rows"] for s in _steps(hybrid) if "state_rows" in s]
+    assert rows and all(
+        r % list(kinds).count("linear_attention") == 0 for r in rows)
+
+
+@pytest.mark.parametrize("field", ["linear_mixer", "state_rows"])
+def test_the_new_fields_are_in_the_schema(field):
+    from torch_automatic_distributed_neural_network_tpu.obs import schema
+
+    with open(schema.__file__) as f:
+        assert f'"{field}"' in f.read()
+
+
+@pytest.mark.parametrize("name", KDA_METRICS)
+def test_a_kda_metric_has_its_file_and_its_cell(name):
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
+    assert entry["workloads"] == [KDA_CELL]
+    assert entry["moves"] == "serve_tokens_per_s"
+    assert entry["layer"] == "linear attention"
+    assert entry["source"] == "device_trace"
+    assert (entry["unit"], entry["better"]) == (
+        ("%", "higher") if name.endswith("roofline") else ("ms", "lower"))
+    assert name + ".py" in METRIC_FILES
+    assert entry in BENCHMARK["per_layer"][35:39]  # appended, nothing moved
+    serve = next(m for m in BENCHMARK["end_to_end"]
+                 if m["name"] == "serve_tokens_per_s")
+    assert KDA_CELL in serve["workloads"]
+
+
+@pytest.mark.parametrize("name", KDA_METRICS)
+def test_a_kda_reader_finds_nothing_where_there_is_nothing(
+        bench, mixed, hybrid, name, capsys):
+    """No device trace on the CPU, a trace of no device, and a model whose
+    linear layers run the scalar rule's kernels: ``None``, and no raise."""
+    reader = _load(os.path.join(BENCH, "metrics", name + ".py"),
+                   "bench_metric")
+    for run in (mixed, hybrid):
+        assert reader.read(run["record"]) is None
+        assert reader.read({**run["record"], "trace": {"n_devices": 0}}) is None
+    other = ("%tadnn_gdn_step.1 = f32[8,3,10,192] custom-call()", 10, 500)
+    rec = {**mixed["record"], "peaks": {"flops_per_s": 197e12,
+                                        "hbm_bytes_per_s": 819e9},
+           "trace_mono": (0.0, 1e9),
+           "trace": {"n_devices": 1, "ops": {"d": [other]},
+                     "modules": {"d": [("jit_serve_prefill_chunk(1)", 0,
+                                        1000)]},
+                     "module_seconds": {"jit_serve_prefill_chunk": [1e-6]}}}
+    assert reader.read(rec) is None
+    capsys.readouterr()
+
+
+def test_the_kda_readers_read_a_hand_made_trace(bench, mixed, capsys):
+    """Two runs of the chunk's program and one decode step; in each the
+    three linear layers' step kernel takes 40 us a layer, with a staged
+    copy of a layer's pool open for 60 us round the first (the union is
+    counted: 100 us in that run), and the chunk kernel 200 us a layer.  The
+    shares are the least time of ``counts_kda`` over those times."""
+    from lib import counts_kda
+
+    rec0, keys = mixed["record"], mixed["record"]["model_keys"]
+    n, H, dk, dv = 3, keys["linear_value_heads"], \
+        keys["linear_key_head_dim"], keys["linear_value_head_dim"]
+    S, C = rec0["engine"]["n_slots"], rec0["engine"]["prefill_chunk"]
+    pool = f"f32[{S + 1},{H},{dk},{dv}]"
+    us = 1000
+    mods = [("jit_serve_prefill_chunk(7)", 0, 2000 * us),
+            ("jit_serve_prefill_chunk(7)", 3000 * us, 5000 * us),
+            ("jit_serve_decode_step(9)", 6000 * us, 7000 * us)]
+    ops = []
+    for m, (_, lo, _hi) in enumerate(mods):
+        for i in range(n):
+            at = lo + (100 + 300 * i) * us
+            ops.append((f"%tadnn_kda_step.{i} = (f32[{S},4,8,{dv}], {pool}) "
+                        f"custom-call()", at, at + 40 * us))
+            if m < 2:
+                ops.append((f"%tadnn_kda_chunk.{i} = f32[{H},1,{C},{dv}] "
+                            f"custom-call()", at + 50 * us, at + 250 * us))
+        ops.append((f"%copy-start.{m} = ({pool}, {pool}) copy-start()",
+                    lo + 80 * us, lo + 81 * us))
+        ops.append((f"%copy-done.{m} = {pool} copy-done()", lo + 139 * us,
+                    lo + 140 * us))
+    steps = [{"state_rows": n * rows, "t_end": t}
+             for rows, t in ((2, 0.5), (4, 1.5), (3, 99.0))]
+    rec = {**rec0, "peaks": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+           "trace_mono": (0.0, 2.0), "serve_steps": steps,
+           "trace": {"n_devices": 1, "ops": {"d": ops}, "modules": {"d": mods},
+                     "module_seconds": {
+                         "jit_serve_prefill_chunk": [2e-3, 2e-3],
+                         "jit_serve_decode_step": [1e-3]}}}
+    read = lambda name: _load(os.path.join(  # noqa: E731
+        BENCH, "metrics", name + ".py"), "bench_metric").read(rec)
+    assert read("kda_chunk_ms") == pytest.approx(3 * 0.2)
+    # a run: 40 + (the copy's window 80..140 joined to the kernel's
+    # 100..140) + 40 + 40 = 140 us over the three layers
+    assert read("kda_step_ms") == pytest.approx(0.14)
+    assert counts_kda.traced_state_rows(rec) == (3.0, 2)  # the third is outside
+    prompts = [len(q["prompt"]) for q in rec0["requests"]]
+    fill = sum(prompts) / (C * sum(-(-p // C) for p in prompts))
+    chunk = read("kda_chunk_roofline")
+    step = read("kda_step_roofline")
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+             if l.startswith("{")]
+    least = lambda tokens, seqs: max(  # noqa: E731
+        n * counts_kda.recurrence_flops(tokens, H, dk, dv) / 197e12,
+        n * counts_kda.recurrence_bytes(tokens, seqs, H, dk, dv, itemsize=2)
+        / 819e9)
+    assert chunk == pytest.approx(100 * least(C * fill, 1.0) / 0.6e-3)
+    assert step == pytest.approx(100 * least(3.0, 3.0) / 0.14e-3)
+    assert any("kda_chunk" in l for l in lines)
+    assert any(l.get("kda_step", {}).get("steps") == 2 for l in lines)
+    assert 0 < chunk < 100 and 0 < step < 100
+
+
+def test_the_kda_counts_are_the_arithmetic(bench):
+    """7 d_k d_v operations a token a head; q, k, v, the output, beta and
+    the d_k float32 decays once a token a head; the state in and out once a
+    sequence: at the cell's widths a decode row is bound by its state."""
+    from lib import counts_gdn, counts_kda
+
+    keys = CONFIGS["kimi-linear-48b-ep8"]["model"]
+    assert counts_gdn.linear_layers(keys) == (12, 32, 128, 128)
+    assert counts_kda.recurrence_flops(1, 32, 128, 128) == 7 * 32 * 128 * 128
+    per_token = 32 * (4 * 128 * 2 + 128 * 4 + 4)
+    state = 2 * 32 * 128 * 128 * 4
+    assert counts_kda.recurrence_bytes(1, 1, 32, 128, 128, itemsize=2) \
+        == per_token + state == 49_280 + 4_194_304
+    # the scalar rule's count reads ONE decay a head where this reads d_k
+    assert counts_kda.recurrence_bytes(5, 0, 32, 128, 128, itemsize=2) \
+        - counts_gdn.recurrence_bytes(5, 0, 32, 128, 128, itemsize=2) \
+        == 5 * 32 * 127 * 4
+    # 0.87 operations a byte a decode row: memory binds it
+    assert counts_kda.recurrence_flops(1, 32, 128, 128) / (
+        per_token + state) < 1

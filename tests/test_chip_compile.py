@@ -259,7 +259,8 @@ _SERVING = {"gpt2-1p3b": (8, 1024, 16, 128, None),
             "trinity-large-ep8": (16, 13312, 16, 512, None),
             "olmo-hybrid-7b-pp2": (8, 33792, 16, 512, 4609),
             "joyai-llm-flash-ep8": (24, 34816, 64, 512, 4097),
-            "longcat-flash-omni-ep32": (24, 34816, 64, 512, 4097)}
+            "longcat-flash-omni-ep32": (24, 34816, 64, 512, 4097),
+            "kimi-linear-48b-ep8": (96, 36864, 64, 512, 6145)}
 
 
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk",
@@ -284,7 +285,12 @@ def test_serving_programs_update_the_pool_in_place(
     lanes, one array a layer, 24 slots of 34,816 beside 6.9 GiB of weights)
     and ``longcat-flash-omni-ep32`` (8 sublayers of 64 latent heads at d 6,144
     with a dense FFN each, an expert branch of 16 of 512 experts across each
-    pair: 2.5 GiB of latent pages beside 10.1 GiB of weights)
+    pair: 2.5 GiB of latent pages beside 10.1 GiB of weights) and
+    ``kimi-linear-48b-ep8`` (12 KDA layers, each a state pool of 97 rows of
+    [32, 128, 128] float32, 203 MB, and 4 latent layers of 6,145 pages, 15
+    of the 16 with 32 of 256 experts: 96 slots of 36,864 beside 7.9 GiB of
+    weights; Step 0 of ISSUE 41: no copy of a 203 MB pool round its step
+    kernel)
     alike: no layer's weight is converted, the pool is updated
     in place (the output aliases it: pages, states and tails), and no copy
     of a layer's pages or states is among the temporaries (threaded through
@@ -387,7 +393,8 @@ def test_serving_programs_update_the_pool_in_place(
     assert "tadnn_paged_decode." not in text  # one kernel a kind of page
     # a weight is an entry parameter named for its path in ``params``, read
     # as it is: not converted, not copied
-    weight = "kv_b_proj" if latent else "q_proj"
+    weight = ("kv_b_proj" if latent and "linear_attention" not in
+              cfg.layer_types else "q_proj")
     assert re.search(r"%%params__layers_1____attn____%s____kernel__\S* = "
                      r"bf16\[\S* parameter\(" % weight, text)
     assert not [l.strip()[:120] for l in text.splitlines() if re.search(
@@ -396,13 +403,22 @@ def test_serving_programs_update_the_pool_in_place(
     assert mem.alias_size_in_bytes >= pool_bytes  # updated in place
     # (a chunk's activations at d 3,840 beside 11,520 convolved channels
     # are 0.22 GiB; a copy of the 4.5 GB of pages would be twenty times it)
-    roomy = 0.25 if config == "olmo-hybrid-7b-pp2" else 0.2
+    # (kimi: 96 + 512 rows at d 2,304 through 15 expert layers and 12 KDA
+    # layers' [32, 8, 16, 16, 128] pairwise decays: 0.51 GiB in the chunk
+    # that carries the rows; a copy of ONE state pool would be 0.19 more)
+    roomy = {"olmo-hybrid-7b-pp2": 0.25,
+             "kimi-linear-48b-ep8": 0.55}.get(config, 0.2)
     assert mem.temp_size_in_bytes < roomy * 2**30, mem.temp_size_in_bytes
     page_arrays = {("f32" if x.dtype == jnp.float32 else "bf16")
                    + "[%s]" % ",".join(map(str, x.shape))
                    for x in jax.tree.leaves(kv) if x.size}
+    # (the 7 MB of a KDA layer's convolution tails at 97 rows the compiler
+    # moves into on-chip memory round their gather, ``S(1)``, and lays out
+    # anew behind their scatter: 0.3 ms a call over 12 layers, PERF.md
+    # section 7; its 203 MB state pool it does not copy: Step 0 of ISSUE 41)
+    staged = {"bf16[97,3,12288]"} & page_arrays
     assert not [l[:100] for l in text.splitlines()
-                if " copy(" in l and any(a in l for a in page_arrays)]
+                if " copy(" in l and any(a in l for a in page_arrays - staged)]
     if cfg.n_expert_layers:
         # the expert layer's glue: no scatter (the chip runs one an element
         # at a time) and no loop, no copy of a padded [rows, d] array to
@@ -441,6 +457,27 @@ def test_serving_programs_update_the_pool_in_place(
         assert round(made["pool"].bytes_full / 1e9, 2) == 4.53
         assert round(sum(made["pool"].bytes_state) / 1e9, 2) == 0.25
         assert mem.argument_size_in_bytes < 14.0 * 2**30
+    elif config == "kimi-linear-48b-ep8":
+        # 12 KDA layers and 4 latent ones: the step kernel wherever rows
+        # decode, the chunk kernel wherever a chunk runs, and the latent
+        # layers' own two; the scalar rule's kernels nowhere
+        steps = len(re.findall(r"^\s*%tadnn_kda_step[.\d]* = ", text, re.M))
+        chunks = len(re.findall(r"^\s*%tadnn_kda_chunk[.\d]* = ", text,
+                                re.M))
+        assert steps == 12 * (program != "prefill_chunk")
+        assert chunks == 12 * (program != "decode_step")
+        assert "tadnn_gdn" not in text
+        assert len(re.findall(r"^\s*%tadnn_latent_chunk[.\d]* = ", text,
+                              re.M)) == 4 * (program != "decode_step")
+        assert text.count("tadnn_paged_decode_latent") >= 4 * (
+            program != "prefill_chunk")
+        assert text.count("tadnn_moe_grouped_mm") >= 2 * 15
+        # the pool: 2.01 GB of latent pages, 2.53 GB of states and tails
+        assert made["pool"].bytes_latent == made["pool"].bytes_full
+        assert round(made["pool"].bytes_full / 1e9, 2) == 2.01
+        assert round(sum(made["pool"].bytes_state) / 1e9, 2) == 2.53
+        assert f"f32[{slots + 1},32,128,128]" in page_arrays
+        assert mem.argument_size_in_bytes < 12.6 * 2**30
     elif latent:
         # the latent layers' kernel calls (none in the chunk alone: 20, or
         # 8 sublayers), the grouped matmuls of the expert layers (19, or 4
@@ -629,33 +666,41 @@ def test_grouped_matmul_gathers_its_rows_for_v5e(v5e, tokens, top_k, d, f):
 # -- the gated delta rule's two kernels, at Olmo-Hybrid-7B's widths -----------
 
 
+@pytest.mark.parametrize("rule", ["gdn", "kda"])
 @pytest.mark.parametrize("form,dtype", [
     ("chunk", jnp.bfloat16), ("chunk", jnp.float32), ("step", jnp.bfloat16)])
-def test_gated_delta_kernels_compile_for_v5e(v5e, form, dtype):
-    """30 heads, keys of 96 and values of 192 (neither a multiple of the
-    lane width): the chunk kernel over a prefill chunk of 512 in serving's
-    bfloat16 and in ``chip_smoke.py``'s float32, the step kernel over 8
-    slots of a pool of 9 rows, which it reads and writes in place."""
+def test_gated_delta_kernels_compile_for_v5e(v5e, form, dtype, rule):
+    """``gdn``: 30 heads, keys of 96 and values of 192 (neither a multiple
+    of the lane width): the chunk kernel over a prefill chunk of 512 in
+    serving's bfloat16 and in ``chip_smoke.py``'s float32, the step kernel
+    over 8 slots of a pool of 9 rows, which it reads and writes in place.
+    ``kda``: the kernels of a decay a channel at Kimi-Linear's widths, 32
+    heads of 128 and 128, 96 slots of a pool of 97 rows (203 MB)."""
     from torch_automatic_distributed_neural_network_tpu.ops import gated_delta as gd
 
     one = SingleDeviceSharding(v5e[0])
     sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
-    H, dk, dv = 30, 96, 192
+    H, dk, dv, S = (30, 96, 192, 8) if rule == "gdn" else (32, 128, 128, 96)
+    decays = lambda n: (n, H) if rule == "gdn" else (n, H, dk)  # noqa: E731
+    chunk, step = ((gd.gated_delta_chunk_pallas, gd.gated_delta_step_pallas)
+                   if rule == "gdn" else
+                   (gd.kda_chunk_pallas, gd.kda_step_pallas))
     if form == "chunk":
         T = 512
         text = _compile(
-            gd.gated_delta_chunk_pallas, sds((T, H, dk), dtype),
+            chunk, sds((T, H, dk), dtype),
             sds((T, H, dk), dtype), sds((T, H, dv), dtype),
-            sds((T, H), jnp.float32), sds((T, H), jnp.float32),
+            sds(decays(T), jnp.float32), sds((T, H), jnp.float32),
             sds((H, dk, dv), jnp.float32))
-        assert "tadnn_gdn_chunk" in text
+        assert f"tadnn_{rule}_chunk" in text
         return
-    S = 8
-    compiled = jax.jit(gd.gated_delta_step_pallas, donate_argnums=(5,)).lower(
+    compiled = jax.jit(step, donate_argnums=(5,)).lower(
         sds((S, H, dk), dtype), sds((S, H, dk), dtype), sds((S, H, dv), dtype),
-        sds((S, H), jnp.float32), sds((S, H), jnp.float32),
+        sds(decays(S), jnp.float32), sds((S, H), jnp.float32),
         sds((S + 1, H, dk, dv), jnp.float32), sds((S,), jnp.int32)).compile()
-    assert "tadnn_gdn_step" in compiled.as_text()
+    assert f"tadnn_{rule}_step" in compiled.as_text()
+    assert not [l for l in compiled.as_text().splitlines()
+                if " copy(" in l and f"f32[{S + 1},{H},{dk},{dv}]" in l]
     # the pool is the output: no second copy of it
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= (S + 1) * H * dk * dv * 4

@@ -1,7 +1,10 @@
 """The gated delta rule (``ops/gated_delta.py``): the chunk form and the step
 form, each as plain ``jax.numpy`` and as its Pallas kernel in the
-interpreter, against the token-by-token recurrence.  Float32 throughout, so
-the tolerance is rounding alone: 1e-5 of values of order one."""
+interpreter, against the token-by-token recurrence; with a decay a head
+(``decay="head"``: ``tadnn_gdn_chunk``, ``tadnn_gdn_step``) and a decay a key
+channel (``"channel"``: ``tadnn_kda_chunk``, ``tadnn_kda_step``).  Float32
+throughout, so the tolerance is rounding alone: 1e-5 of values of order
+one."""
 
 import jax
 import jax.numpy as jnp
@@ -14,11 +17,15 @@ H, DK, DV = 3, 16, 24
 TOL = dict(rtol=1e-5, atol=1e-5)
 
 
+DECAYS = ["head", "channel"]
+
+
 def inputs(T: int, seed: int = 0, *, neg_eigval: bool = True,
-           heads: int = H, dk: int = DK, dv: int = DV):
+           heads: int = H, dk: int = DK, dv: int = DV, decay: str = "head"):
     """Queries, keys and values as a layer makes them, and decays from the
     family's initialisation (A uniform in (0, 16), dt log-uniform in
-    (0.001, 0.1)): most heads forget slowly, so a lost carry shows."""
+    (0.001, 0.1)): most heads forget slowly, so a lost carry shows.  With
+    ``decay="channel"`` a step ``dt`` a key channel: ``g`` [T, heads, dk]."""
     ks = jax.random.split(jax.random.key(seed), 7)
     q = gd.l2norm(jax.random.normal(ks[0], (T, heads, dk))) * dk ** -0.5
     k = gd.l2norm(jax.random.normal(ks[1], (T, heads, dk)))
@@ -26,39 +33,54 @@ def inputs(T: int, seed: int = 0, *, neg_eigval: bool = True,
     beta = jax.nn.sigmoid(jax.random.normal(ks[3], (T, heads)))
     if neg_eigval:
         beta = 2.0 * beta
-    A = jax.random.uniform(ks[4], (heads,), minval=1e-3, maxval=16.0)
-    dt = jnp.exp(jax.random.uniform(ks[5], (T, heads), minval=np.log(1e-3),
-                                    maxval=np.log(0.1)))
+    wide = (dk,) if decay == "channel" else ()
+    A = jax.random.uniform(ks[4], (heads,) + (1,) * len(wide), minval=1e-3,
+                           maxval=16.0)
+    dt = jnp.exp(jax.random.uniform(ks[5], (T, heads) + wide,
+                                    minval=np.log(1e-3), maxval=np.log(0.1)))
     state = jax.random.normal(ks[6], (heads, dk, dv))
     return q, k, v, -A * dt, beta, state
 
 
+def _by_decay(head, channel):
+    """The form of the rule that ``g``'s rank asks for."""
+    return lambda *a, **kw: (channel if a[3].ndim == 3 else head)(*a, **kw)
+
+
 CHUNK_FORMS = {
-    "xla": gd.gated_delta_chunk_xla,
-    "pallas": lambda *a: gd.gated_delta_chunk_pallas(*a, interpret=True),
+    "xla": _by_decay(gd.gated_delta_chunk_xla, gd.kda_chunk_xla),
+    "pallas": lambda *a: _by_decay(
+        gd.gated_delta_chunk_pallas, gd.kda_chunk_pallas)(*a, interpret=True),
 }
 
 
+def rows_of(keep, x):
+    """``x`` [T, ...] with zeros where ``keep`` [T] is false."""
+    return jnp.where(keep.reshape(-1, *(1,) * (x.ndim - 1)), x, 0.0)
+
+
+@pytest.mark.parametrize("decay", DECAYS)
 @pytest.mark.parametrize("form", sorted(CHUNK_FORMS))
 @pytest.mark.parametrize("neg_eigval", [True, False])
 @pytest.mark.parametrize("T", [1, 5, 64, 100, 130])
-def test_chunk_form_is_the_recurrence(form, neg_eigval, T):
+def test_chunk_form_is_the_recurrence(form, neg_eigval, T, decay):
     """Lengths that are no whole sub-chunk, one shorter than a sub-chunk,
     and several sub-chunks; beta up to 2 and up to 1."""
-    args = inputs(T, seed=T, neg_eigval=neg_eigval)
+    args = inputs(T, seed=T, neg_eigval=neg_eigval, decay=decay)
     o_ref, s_ref = gd.gated_delta_recurrent(*args)
     o, s = CHUNK_FORMS[form](*args)
     np.testing.assert_allclose(o, o_ref, **TOL)
     np.testing.assert_allclose(s, s_ref, **TOL)
 
 
+@pytest.mark.parametrize("decay", DECAYS)
 @pytest.mark.parametrize("form", sorted(CHUNK_FORMS))
-def test_neighbouring_keys_that_are_alike(form):
+def test_neighbouring_keys_that_are_alike(form, decay):
     """Keys that all point nearly the same way with beta near 2, as a slowly
     varying residual stream makes them: the solve inside a sub-chunk must
     not form powers of the key-key matrix (they reach 1e9 and cancel; a
     first form of this file was out by 1e-2 at the end of a sub-chunk)."""
-    q, k, v, g, beta, state = inputs(128, seed=4)
+    q, k, v, g, beta, state = inputs(128, seed=4, decay=decay)
     base = jax.random.normal(jax.random.key(1), (1, H, DK))
     k = gd.l2norm(base + 0.05 * k)
     beta = 1.9 + 0.1 * beta / 2.0
@@ -78,11 +100,12 @@ def test_decay_is_near_one_in_these_tests():
     assert float(jnp.median(jnp.exp(g))) > 0.9
 
 
+@pytest.mark.parametrize("decay", DECAYS)
 @pytest.mark.parametrize("form", sorted(CHUNK_FORMS))
-def test_state_carries_over_chunk_calls(form):
+def test_state_carries_over_chunk_calls(form, decay):
     """Three calls of 70, 64 and 23 tokens, each from the state the one
     before left, are one call of 157."""
-    q, k, v, g, beta, state = inputs(157, seed=3)
+    q, k, v, g, beta, state = inputs(157, seed=3, decay=decay)
     o_ref, s_ref = gd.gated_delta_recurrent(q, k, v, g, beta, state)
     outs, at = [], 0
     for n in (70, 64, 23):
@@ -95,14 +118,15 @@ def test_state_carries_over_chunk_calls(form):
     np.testing.assert_allclose(state, s_ref, **TOL)
 
 
+@pytest.mark.parametrize("decay", DECAYS)
 @pytest.mark.parametrize("form", sorted(CHUNK_FORMS))
-def test_rows_without_beta_and_decay_leave_the_state_alone(form):
+def test_rows_without_beta_and_decay_leave_the_state_alone(form, decay):
     """A padded chunk's tail: beta 0 and g 0."""
-    q, k, v, g, beta, state = inputs(40, seed=5)
+    q, k, v, g, beta, state = inputs(40, seed=5, decay=decay)
     n = 27
-    keep = (jnp.arange(40) < n)[:, None]
-    o, s = CHUNK_FORMS[form](q, k, v, jnp.where(keep, g, 0.0),
-                             jnp.where(keep, beta, 0.0), state)
+    keep = jnp.arange(40) < n
+    o, s = CHUNK_FORMS[form](q, k, v, rows_of(keep, g), rows_of(keep, beta),
+                             state)
     o_ref, s_ref = gd.gated_delta_recurrent(
         q[:n], k[:n], v[:n], g[:n], beta[:n], state)
     np.testing.assert_allclose(o[:n], o_ref, **TOL)
@@ -110,14 +134,16 @@ def test_rows_without_beta_and_decay_leave_the_state_alone(form):
 
 
 STEP_FORMS = {
-    "xla": gd.gated_delta_step_xla,
-    "pallas": lambda *a: gd.gated_delta_step_pallas(*a, interpret=True),
+    "xla": _by_decay(gd.gated_delta_step_xla, gd.kda_step_xla),
+    "pallas": lambda *a: _by_decay(
+        gd.gated_delta_step_pallas, gd.kda_step_pallas)(*a, interpret=True),
 }
 
 
+@pytest.mark.parametrize("decay", DECAYS)
 @pytest.mark.parametrize("form", sorted(STEP_FORMS))
 @pytest.mark.parametrize("heads", [3, 22])
-def test_step_form_is_the_recurrence(form, heads):
+def test_step_form_is_the_recurrence(form, heads, decay):
     """Four slots over rows 3, 1, 0, 4 of a pool of six, six tokens each:
     every slot's row follows its own recurrence; the slot on the null row
     (beta 0 and g 0, as the decode program gives a slot that does not
@@ -126,16 +152,17 @@ def test_step_form_is_the_recurrence(form, heads):
     ``STEP_HEADS``: 3 at once, 22 as 11 pairs)."""
     S, T = 4, 6
     rows = jnp.asarray([3, 1, 0, 4], jnp.int32)
-    live = (rows > 0)[:, None]
-    per = [inputs(T, seed=10 + s, heads=heads) for s in range(S)]
+    live = rows > 0
+    per = [inputs(T, seed=10 + s, heads=heads, decay=decay)
+           for s in range(S)]
     pool0 = jax.random.normal(jax.random.key(9), (6, heads, DK, DV))
     pool = pool0
     outs = []
     for t in range(T):
         q, k, v, g, beta = (jnp.stack([p[i][t] for p in per])
                             for i in range(5))
-        o, pool = STEP_FORMS[form](q, k, v, jnp.where(live, g, 0.0),
-                                   jnp.where(live, beta, 0.0), pool, rows)
+        o, pool = STEP_FORMS[form](q, k, v, rows_of(live, g),
+                                   rows_of(live, beta), pool, rows)
         outs.append(o)
     for s in (0, 1, 3):
         q, k, v, g, beta, _ = per[s]
@@ -148,10 +175,11 @@ def test_step_form_is_the_recurrence(form, heads):
         np.testing.assert_array_equal(pool[r], pool0[r])
 
 
-def test_chunk_then_steps_is_one_sequence():
+@pytest.mark.parametrize("decay", DECAYS)
+def test_chunk_then_steps_is_one_sequence(decay):
     """Prefill in a chunk, then decode a token at a time from the state it
     left: the recurrence over the whole sequence."""
-    q, k, v, g, beta, state = inputs(90, seed=21)
+    q, k, v, g, beta, state = inputs(90, seed=21, decay=decay)
     o_ref, s_ref = gd.gated_delta_recurrent(q, k, v, g, beta, state)
     n = 83
     o, s = gd.gated_delta_chunk(q[:n], k[:n], v[:n], g[:n], beta[:n], state)
@@ -166,12 +194,13 @@ def test_chunk_then_steps_is_one_sequence():
     np.testing.assert_allclose(pool[1], s_ref, **TOL)
 
 
-def test_bf16_operands_keep_a_float32_state():
+@pytest.mark.parametrize("decay", DECAYS)
+def test_bf16_operands_keep_a_float32_state(decay):
     """Serving's dtypes: bfloat16 q, k, v in, float32 state and output out,
     within bfloat16's rounding of the float32 answer."""
-    q, k, v, g, beta, state = inputs(100, seed=2)
+    q, k, v, g, beta, state = inputs(100, seed=2, decay=decay)
     lo = lambda x: x.astype(jnp.bfloat16)
-    o, s = gd.gated_delta_chunk_xla(lo(q), lo(k), lo(v), g, beta, state)
+    o, s = CHUNK_FORMS["xla"](lo(q), lo(k), lo(v), g, beta, state)
     o_ref, s_ref = gd.gated_delta_recurrent(q, k, v, g, beta, state)
     assert o.dtype == s.dtype == jnp.float32
     np.testing.assert_allclose(o, o_ref, rtol=0.05, atol=0.05)
@@ -191,3 +220,37 @@ def test_causal_conv_is_a_convolution_with_its_tail():
     second = gd.causal_conv(pad[7:], w, T - 7)
     np.testing.assert_allclose(jnp.concatenate([first, second]), want,
                                rtol=1e-6)
+
+
+def test_a_vector_decay_with_equal_channels_is_the_scalar_rule():
+    """``g`` [T, H, d_k] with every channel of a head the head's number is
+    the rule of ``g`` [T, H] to the letter: in the oracle, and in each form
+    against it."""
+    q, k, v, g, beta, state = inputs(100, seed=6)
+    wide = jnp.broadcast_to(g[..., None], (*g.shape, DK))
+    o_ref, s_ref = gd.gated_delta_recurrent(q, k, v, g, beta, state)
+    o, s = gd.gated_delta_recurrent(q, k, v, wide, beta, state)
+    np.testing.assert_array_equal(o, o_ref)
+    np.testing.assert_array_equal(s, s_ref)
+    for form in CHUNK_FORMS.values():
+        o, s = form(q, k, v, wide, beta, state)
+        np.testing.assert_allclose(o, o_ref, **TOL)
+        np.testing.assert_allclose(s, s_ref, **TOL)
+
+
+@pytest.mark.parametrize("form", sorted(CHUNK_FORMS))
+@pytest.mark.parametrize("T", [23, 64, 130])
+def test_a_channel_that_forgets_beside_one_that_never_does(form, T):
+    """Adversarial decays a channel: channel 0 forgets all it held in one
+    token (g = -60: ``exp(-g)`` is 1e26 and its 64th power past float32),
+    channel 1 never forgets (g = 0), channel 2 hardly (-1e-6), the others
+    as the family draws them.  No exponential of a positive number is
+    formed anywhere, so every number is finite and the recurrence's."""
+    q, k, v, g, beta, state = inputs(T, seed=8, decay="channel")
+    g = g.at[:, :, 0].set(-60.0).at[:, :, 1].set(0.0).at[:, :, 2].set(-1e-6)
+    o_ref, s_ref = gd.gated_delta_recurrent(q, k, v, g, beta, state)
+    o, s = CHUNK_FORMS[form](q, k, v, g, beta, state)
+    assert np.isfinite(o).all() and np.isfinite(s).all()
+    np.testing.assert_allclose(o, o_ref, **TOL)
+    np.testing.assert_allclose(s, s_ref, **TOL)
+    assert float(jnp.abs(s[:, 0]).max()) < 2.1  # one token's write, alone

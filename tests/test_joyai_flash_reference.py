@@ -662,9 +662,11 @@ def test_a_latent_pool_refuses_what_it_has_no_form_for():
 
 
 @pytest.mark.parametrize("bad,reason", [
-    ({"latent_q_rank": None}, "a latent_attention layer needs"),
+    # (no query rank is a model now: queries without a bottleneck; and so
+    # is a latent layer that does not rotate: test_kimi_linear_reference.py)
+    ({"latent_kv_rank": None}, "a latent_attention layer needs"),
     ({"latent_rope_head_dim": 3}, "even latent_rope_head_dim"),
-    ({"pos": "learned"}, "pos='rope'"),
+    ({"latent_nope_head_dim": None}, "queries without a bottleneck"),
     ({"layer_types": ["full_attention"] * 4}, "layer_types has none"),
 ])
 def test_config_refuses_what_it_cannot_build(bad, reason):

@@ -278,6 +278,7 @@ class ServeEngine:
         # time model changes.
         self.disaggregate = bool(disaggregate)
         kinds = self.cfg.layer_types or ()
+        self._n_linear = list(kinds).count("linear_attention")
         # what is not served, each with its reason (the message names the
         # option and the kind of layer)
         refused = {
@@ -584,7 +585,11 @@ class ServeEngine:
                         if "latent_attention" in kinds else None),
             kv_bytes_window=self.pool.bytes_window,
             state_bytes_linear=self.pool.bytes_state[0],
-            conv_bytes_linear=self.pool.bytes_state[1])
+            conv_bytes_linear=self.pool.bytes_state[1],
+            # which rule the linear layers run: [the rule, whose the decay
+            # is: a head's or a channel's] (None without such a layer)
+            linear_mixer=(["gated_delta", self.cfg.linear_decay]
+                          if self._n_linear else None))
         # the counters of the decode step last read (serve.step carries them)
         self._counters: dict[str, int] = {}
 
@@ -1182,6 +1187,11 @@ class ServeEngine:
                 if experts:
                     self._counters["moe_tiles_laid"] = self._tiles_laid[
                         0 if fused else 1]
+                if self._n_linear:
+                    # the slots whose row of a state pool the call's step
+                    # kernel read and wrote, over the linear layers: known
+                    # here, so not one more number in every model's output
+                    self._counters["state_rows"] = len(rows) * self._n_linear
         with self._phase("emit"):
             self._emit(tokens, first, rows, firsts, drafts)
 

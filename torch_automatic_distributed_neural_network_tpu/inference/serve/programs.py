@@ -440,16 +440,25 @@ def _step_latent(cfg, shared, pages, none, piece, q_nope, q_rope, latent, *,
     return piece("lift", o), pages
 
 
+def _where_rows(keep, x):
+    """``x`` [rows, ...] with zeros on the rows ``keep`` [rows] leaves out:
+    ``beta = 0`` and ``g = 0`` (a head's or a channel's) leave a state as
+    it was."""
+    return jnp.where(keep.reshape(-1, *(1,) * (x.ndim - 1)), x, 0.0)
+
+
 @jax.named_scope("tadnn.attend_step")
 def _step_state(shared, state, tails, convolve, pre, g, beta):
-    """``pre`` [S, 1, D], ``g``, ``beta`` [S, 1, H]: one token a slot, the
-    step form of the rule on the slots' rows of ``state`` and ``tails``."""
-    rows, live = shared["rows"], shared["active"][:, None]
+    """``pre`` [S, 1, D], ``g``, ``beta`` [S, 1, H] (``g`` [S, 1, H, d_k]
+    where the decay is a channel's: the rule's forms pick their kernel by
+    it): one token a slot, the step form of the rule on the slots' rows of
+    ``state`` and ``tails``."""
+    rows, live = shared["rows"], shared["active"]
     q, k, v, full = convolve(pre, tails[rows])
     tails = tails.at[rows].set(full[:, 1:].astype(tails.dtype))
     o, state = gated_delta_step(
-        q[:, 0], k[:, 0], v[:, 0], jnp.where(live, g[:, 0], 0.0),
-        jnp.where(live, beta[:, 0], 0.0), state, rows)
+        q[:, 0], k[:, 0], v[:, 0], _where_rows(live, g[:, 0]),
+        _where_rows(live, beta[:, 0]), state, rows)
     return o[:, None], state, tails
 
 
@@ -561,8 +570,8 @@ def _chunk_latent(cfg, shared, pages, piece, q_nope, q_rope, latent):
 
 @jax.named_scope("tadnn.attend_chunk")
 def _chunk_state(shared, state, tails, convolve, pre, g, beta):
-    """``pre`` [C, D], ``g``, ``beta`` [C, H]: the chunk form of the rule
-    from the state the chunk before left in the slot's row, or from zeros
+    """``pre`` [C, D], ``g``, ``beta`` [C, H] (``g`` [C, H, d_k] where the
+    decay is a channel's): the chunk form of the rule from the state the chunk before left in the slot's row, or from zeros
     where the prompt starts."""
     row, last_idx = shared["row"], shared["last_idx"]
     fresh = shared["pos0"] == 0  # a prompt starts from zeros
@@ -571,10 +580,10 @@ def _chunk_state(shared, state, tails, convolve, pre, g, beta):
     # the tail the next call reads: the last K - 1 real rows
     tails = tails.at[row].set(jax.lax.dynamic_slice_in_dim(
         full[0], last_idx + 1, tails.shape[1]).astype(tails.dtype))
-    real = shared["real"][:, None]
+    real = shared["real"]
     o, new = gated_delta_chunk(
-        q[0], k[0], v[0], jnp.where(real, g, 0.0),
-        jnp.where(real, beta, 0.0), jnp.where(fresh, 0.0, state[row]))
+        q[0], k[0], v[0], _where_rows(real, g), _where_rows(real, beta),
+        jnp.where(fresh, 0.0, state[row]))
     return o, state.at[row].set(new), tails
 
 

@@ -81,7 +81,8 @@ class TransformerConfig:
     # One entry a layer: "sliding_attention" (the causal band of
     # ``sliding_window``), "full_attention" (plain causal) or
     # "linear_attention" (no keys and values: a gated delta rule over a
-    # recurrent state, ``GatedDeltaMixer``) or "latent_attention" (one
+    # recurrent state, ``GatedDeltaMixer``, whose decay is a head's or a
+    # channel's by the ``linear_*`` keys below) or "latent_attention" (one
     # low-rank latent a token in the place of per-head keys and values,
     # ``LatentAttention``).  Given, the layers are built
     # one by one (``layers_0`` .. in the parameter tree, no ``nn.scan``),
@@ -135,10 +136,21 @@ class TransformerConfig:
     linear_value_head_dim: int | None = None
     linear_conv_kernel: int = 4
     linear_neg_eigval: bool = False
+    # which gated delta rule: the log-decay one number a head (Gated
+    # DeltaNet) or one a key CHANNEL of a head (Kimi Delta Attention: the
+    # state's rows forget each at its own rate); the decay's and the output
+    # gate's projections through a bottleneck of this width (None: one
+    # matrix each); and the output gate's activation
+    linear_decay: Literal["head", "channel"] = "head"
+    linear_decay_rank: int | None = None
+    linear_gate_rank: int | None = None
+    linear_gate_act: Literal["silu", "sigmoid"] = "silu"
     # the ``latent_attention`` layers (valid only with one): the ranks of
-    # the query's and of the key-value latent, and a head's three sizes: the
-    # part of a query and a key that is not rotated, the rotated part (ONE
-    # key part for all heads, beside the latent), and a value
+    # the query's (None: no bottleneck, one ``q_proj``) and of the key-value
+    # latent, and a head's three sizes: the part of a query and a key that
+    # is not rotated, the rotated part (ONE key part for all heads, beside
+    # the latent; on a layer that does not rotate, ``layer_rotates``, it is
+    # one more unrotated part that the heads share), and a value
     latent_q_rank: int | None = None
     latent_kv_rank: int | None = None
     latent_nope_head_dim: int | None = None
@@ -160,7 +172,11 @@ class TransformerConfig:
                     f"{LAYER_KINDS}, got {self.layer_types}")
         sizes = ("linear_key_heads", "linear_value_heads",
                  "linear_key_head_dim", "linear_value_head_dim")
-        given = [k for k in sizes + ("linear_neg_eigval",) if getattr(self, k)]
+        given = [k for k in sizes + ("linear_neg_eigval", "linear_decay_rank",
+                                     "linear_gate_rank") if getattr(self, k)]
+        given += [k for k, v in (("linear_decay", "head"),
+                                 ("linear_gate_act", "silu"))
+                  if getattr(self, k) != v]
         if "linear_attention" not in (kinds or ()):
             if given:
                 raise ValueError(f"{given} describe linear_attention "
@@ -172,16 +188,22 @@ class TransformerConfig:
                 "a linear_attention layer needs linear_key_heads == "
                 "linear_value_heads, linear_key_head_dim, "
                 "linear_value_head_dim and linear_conv_kernel >= 2")
-        given = [k for k in LATENT_SIZES if getattr(self, k)]
+        given = [k for k in ("latent_q_rank",) + LATENT_SIZES
+                 if getattr(self, k)]
         if "latent_attention" not in (kinds or ()):
             if given:
                 raise ValueError(f"{given} describe latent_attention "
                                  f"layers: layer_types has none")
-        elif (len(given) != len(LATENT_SIZES) or self.pos != "rope"
-              or self.latent_rope_head_dim % 2):
+        elif not all(getattr(self, k) for k in LATENT_SIZES):
             raise ValueError(
-                f"a latent_attention layer needs {LATENT_SIZES}, an even "
-                f"latent_rope_head_dim and pos='rope'")
+                f"a latent_attention layer needs {LATENT_SIZES} "
+                f"(latent_q_rank None: queries without a bottleneck)")
+        elif (self.layer_rotates("latent_attention")
+              and self.latent_rope_head_dim % 2):
+            raise ValueError(
+                "a latent_attention layer that rotates (pos='rope', "
+                "rope_layers='all') needs an even latent_rope_head_dim; "
+                "rope_layers='sliding' leaves it without any rotation")
         if kinds is not None:
             if not self.pre_norm and not self.sandwich_norm:
                 raise ValueError("pre_norm=False leaves a layer without "
@@ -293,11 +315,19 @@ class TransformerConfig:
         filters, decay and output norm."""
         d, hd = self.d_model, self.head_dim
         if kind == "linear_attention":
+            LH = self.linear_value_heads
             qk = self.linear_key_heads * self.linear_key_head_dim
-            vo = self.linear_value_heads * self.linear_value_head_dim
-            return (2 * d * qk + 2 * d * vo + vo * d  # q k, v gate, out
-                    + 2 * d * self.linear_value_heads  # beta and decay maps
-                    + 2 * self.linear_value_heads  # A_log, dt_bias
+            vo = LH * self.linear_value_head_dim
+            # a decay a head or a channel, each map whole or through a
+            # bottleneck
+            decays = qk if self.linear_decay == "channel" else LH
+            through = lambda rank, out: (d * out if rank is None
+                                         else rank * (d + out))
+            return (2 * d * qk + d * vo + vo * d  # q k, v, out
+                    + through(self.linear_gate_rank, vo)  # the output gate
+                    + through(self.linear_decay_rank, decays)
+                    + d * LH  # beta
+                    + LH + decays  # A_log, dt_bias
                     + self.linear_conv_kernel * (2 * qk + vo)
                     + self.linear_value_head_dim)  # the output norm's gain
         if kind == "latent_attention":
@@ -305,7 +335,9 @@ class TransformerConfig:
             nope, rot, dv = (self.latent_nope_head_dim,
                              self.latent_rope_head_dim,
                              self.latent_value_head_dim)
-            return (d * rq + rq + rq * H * (nope + rot)  # queries, low rank
+            queries = (d * H * (nope + rot) if rq is None
+                       else d * rq + rq + rq * H * (nope + rot))
+            return (queries  # whole, or through a normed bottleneck
                     + d * (rkv + rot) + rkv  # the latent and the rotated key
                     + rkv * H * (nope + dv) + H * dv * d)  # up, out
         q, kv = self.n_heads * hd, self.kv_heads * hd
@@ -344,7 +376,7 @@ class TransformerConfig:
 
 LAYER_KINDS = ("sliding_attention", "full_attention", "linear_attention",
                "latent_attention")
-LATENT_SIZES = ("latent_q_rank", "latent_kv_rank", "latent_nope_head_dim",
+LATENT_SIZES = ("latent_kv_rank", "latent_nope_head_dim",
                 "latent_rope_head_dim", "latent_value_head_dim")
 
 
@@ -444,7 +476,15 @@ class SelfAttention(nn.Module):
 class GatedDeltaMixer(nn.Module):
     """The mixer of a ``linear_attention`` layer: a gated delta rule over a
     recurrent state a head (``ops/gated_delta.py`` has the equations) in
-    the place of attention over keys and values.  setup()-style, like
+    the place of attention over keys and values.  Two published mixers by
+    the configuration's data: Gated DeltaNet (``linear_decay == "head"``:
+    one log-decay a head a token, ``a_proj``; the output gate one matrix
+    and SiLU) and Kimi Delta Attention (``"channel"``: a log-decay a key
+    channel, ``g`` [B, T, H, d_k], from a low-rank pair ``f_a_proj``,
+    ``f_b_proj`` of ``linear_decay_rank``, ``dt_bias`` a channel; the output
+    gate a low-rank pair ``g_a_proj``, ``g_b_proj`` and a sigmoid).  The
+    state, the tail and every method's signature are the same: the serving
+    programs do not know which they apply.  setup()-style, like
     ``SelfAttention``, so that the serving programs apply the same
     projections piecewise (``method="qkv"`` / ``"out_proj"``) round their
     own read and write of the cached state.
@@ -462,8 +502,18 @@ class GatedDeltaMixer(nn.Module):
         dense = lambda feats: nn.DenseGeneral(
             feats, axis=-1, dtype=cfg.dtype, use_bias=False)
         self.q_proj, self.k_proj = dense((H, dk)), dense((H, dk))
-        self.v_proj, self.gate_proj = dense((H, dv)), dense((H, dv))
-        self.a_proj, self.b_proj = dense(H), dense(H)
+        self.v_proj, self.b_proj = dense((H, dv)), dense(H)
+        decays = (H, dk) if cfg.linear_decay == "channel" else (H,)
+        if cfg.linear_decay_rank is None:
+            self.a_proj = dense(decays)
+        else:
+            self.f_a_proj = dense(cfg.linear_decay_rank)
+            self.f_b_proj = dense(decays)
+        if cfg.linear_gate_rank is None:
+            self.gate_proj = dense((H, dv))
+        else:
+            self.g_a_proj = dense(cfg.linear_gate_rank)
+            self.g_b_proj = dense((H, dv))
         self.o_proj = nn.DenseGeneral(cfg.d_model, axis=(-2, -1),
                                       dtype=cfg.dtype, use_bias=False)
         # one filter of K taps a channel of q, k and v, side by side
@@ -480,14 +530,15 @@ class GatedDeltaMixer(nn.Module):
             return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
 
         self.A_log = self.param("A_log", a_log, (H,))
-        self.dt_bias = self.param("dt_bias", dt_bias, (H,))
+        self.dt_bias = self.param("dt_bias", dt_bias, (int(np.prod(decays)),))
         self.o_norm = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32)
 
     def project(self, x):
         """What the mixer takes from each row of ``x`` [B, T, d] alone:
         ``(pre [B, T, D], g, beta [B, T, H] float32)``, the q, k and v
-        projections side by side before the convolution, the log-decay and
-        the write strength."""
+        projections side by side before the convolution, the log-decay
+        (``[B, T, H, d_k]`` where it is a channel's) and the write
+        strength."""
         cfg = self.cfg
         pre = jnp.concatenate(
             [p(x).reshape(*x.shape[:2], -1)
@@ -495,8 +546,10 @@ class GatedDeltaMixer(nn.Module):
         beta = nn.sigmoid(self.b_proj(x).astype(jnp.float32))
         if cfg.linear_neg_eigval:
             beta = beta * 2.0
-        g = -jnp.exp(self.A_log) * nn.softplus(
-            self.a_proj(x).astype(jnp.float32) + self.dt_bias)
+        a = (self.a_proj(x) if cfg.linear_decay_rank is None
+             else self.f_b_proj(self.f_a_proj(x))).astype(jnp.float32)
+        rate = jnp.exp(self.A_log).reshape(-1, *(1,) * (a.ndim - 3))
+        g = -rate * nn.softplus(a + self.dt_bias.reshape(a.shape[2:]))
         return pre, g, beta
 
     def convolve(self, pre, tail=None):
@@ -531,8 +584,11 @@ class GatedDeltaMixer(nn.Module):
     def out_proj(self, o, x):
         """``o`` [B, T, H, d_v] float32, what the state gave; ``x`` the
         layer's input, which the output gate reads."""
-        y = self.o_norm(o) * nn.silu(self.gate_proj(x).astype(jnp.float32))
-        return self.o_proj(y.astype(self.cfg.dtype))
+        cfg = self.cfg
+        gate = (self.gate_proj(x) if cfg.linear_gate_rank is None
+                else self.g_b_proj(self.g_a_proj(x))).astype(jnp.float32)
+        act = nn.sigmoid if cfg.linear_gate_act == "sigmoid" else nn.silu
+        return self.o_proj((self.o_norm(o) * act(gate)).astype(cfg.dtype))
 
     def __call__(self, x):
         q, k, v, g, beta, _ = self.qkv(x)
@@ -548,10 +604,12 @@ class GatedDeltaMixer(nn.Module):
 class LatentAttention(nn.Module):
     """The mixer of a ``latent_attention`` layer (multi-head latent
     attention, the DeepSeek-V3 block): queries through a low-rank
-    bottleneck, and ONE latent ``c_kv`` a token from which every head's
+    bottleneck (or, ``latent_q_rank`` None, one ``q_proj``), and ONE latent ``c_kv`` a token from which every head's
     unrotated key part and value are expanded (``kv_b_proj``), beside one
     rotated key part ``k_r`` that all heads share.  A head's score is
-    ``(q_nope . k_nope + q_rope . k_r) / sqrt(nope + rope)``.
+    ``(q_nope . k_nope + q_rope . k_r) / sqrt(nope + rope)``.  On a layer
+    that does not rotate (``cfg.layer_rotates``) the two "rotated" parts are
+    used as they are projected: the layer sees no position at all.
 
     What a cache keeps is the token's ``[RMSNorm(c_kv), rotated k_r]`` alone
     (``cfg.page_row``: 576 numbers where 32 heads of keys and values are
@@ -578,9 +636,12 @@ class LatentAttention(nn.Module):
         norm = lambda scale: nn.RMSNorm(
             epsilon=cfg.norm_eps,
             dtype=cfg.dtype if scale == 1.0 else jnp.float32)
-        self.q_a_proj = dense(cfg.latent_q_rank)
-        self.q_a_norm = norm(cfg.latent_q_scale)
-        self.q_b_proj = dense((H, cfg.latent_nope_head_dim + rot))
+        if cfg.latent_q_rank is None:
+            self.q_proj = dense((H, cfg.latent_nope_head_dim + rot))
+        else:
+            self.q_a_proj = dense(cfg.latent_q_rank)
+            self.q_a_norm = norm(cfg.latent_q_scale)
+            self.q_b_proj = dense((H, cfg.latent_nope_head_dim + rot))
         self.kv_a_proj = dense(cfg.latent_kv_rank + rot)
         self.kv_a_norm = norm(cfg.latent_kv_scale)
         self.kv_b_proj = dense(
@@ -597,17 +658,21 @@ class LatentAttention(nn.Module):
         """``x`` [B, T, d] at ``positions`` [B, T]: ``(q_nope [B, T, H,
         nope], q_rope [B, T, H, rope], latent [B, T, kv_rank + rope])``,
         the rotated parts rotated (pairs side by side as published,
-        ``deinterleave``), the latent normed and scaled: the row a cache
-        keeps."""
+        ``deinterleave``) where the layer rotates, the latent normed and
+        scaled: the row a cache keeps."""
         cfg = self.cfg
-        q = self.q_b_proj(self._scaled(self.q_a_norm(self.q_a_proj(x)),
-                                       cfg.latent_q_scale))
+        if cfg.latent_q_rank is None:
+            q = self.q_proj(x)
+        else:
+            q = self.q_b_proj(self._scaled(
+                self.q_a_norm(self.q_a_proj(x)), cfg.latent_q_scale))
         q_nope, q_rope = jnp.split(q, [cfg.latent_nope_head_dim], axis=-1)
         c, k_r = jnp.split(self.kv_a_proj(x), [cfg.latent_kv_rank], axis=-1)
         c = self._scaled(self.kv_a_norm(c), cfg.latent_kv_scale)
-        q_rope = rope(deinterleave(q_rope), positions, cfg.rope_theta)
-        k_r = rope(deinterleave(k_r)[:, :, None], positions,
-                   cfg.rope_theta)[:, :, 0]
+        if cfg.layer_rotates("latent_attention"):
+            q_rope = rope(deinterleave(q_rope), positions, cfg.rope_theta)
+            k_r = rope(deinterleave(k_r)[:, :, None], positions,
+                       cfg.rope_theta)[:, :, 0]
         return q_nope, q_rope, jnp.concatenate([c, k_r], -1)
 
     def up(self):

@@ -445,8 +445,11 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
             # layer kinds, the experts held and the pool by kind of page
             **{k: (sengine or {}).get(k) for k in (
                 "layer_kinds", "experts_held", "experts_published",
-                "zero_experts", "shortcut_experts", "kv_bytes_full", "kv_bytes_window", "state_bytes_linear",
-                "conv_bytes_linear", "kv_bytes_latent", "latent_row")},
+                "zero_experts", "shortcut_experts", "kv_bytes_full",
+                "kv_bytes_window", "state_bytes_linear", "conv_bytes_linear",
+                "linear_mixer", "kv_bytes_latent", "latent_row")},
+            # the state rows a call's step kernels read and wrote
+            "mean_state_rows": _mean(e.get("state_rows") for e in ssteps),
             # the decode steps' expert counters (engines with experts)
             "mean_moe_pairs": _mean(e.get("moe_pairs") for e in ssteps),
             "mean_moe_experts_touched": _mean(
@@ -1130,7 +1133,11 @@ def format_report(report: dict) -> str:
                     f"of recurrent state + "
                     f"{sv['conv_bytes_linear'] / 2**30:.3f} GiB of "
                     f"convolution tails "
-                    f"({kinds.count('linear_attention')} linear layers)")
+                    f"({kinds.count('linear_attention')} linear layers)"
+                    + (", {0}: a decay a {1}".format(*sv["linear_mixer"])
+                       if sv.get("linear_mixer") else "")
+                    + (f", {sv['mean_state_rows']:.1f} state rows a call"
+                       if sv.get("mean_state_rows") is not None else ""))
             if sv.get("experts_published"):
                 eparts.append(
                     f"experts {sv['experts_held']} held of "

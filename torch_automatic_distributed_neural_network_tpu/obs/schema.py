@@ -343,6 +343,9 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             "experts_published": "int", "kv_bytes_full": "int",
             "kv_bytes_window": "int", "state_bytes_linear": "int",
             "conv_bytes_linear": "int",
+            # which rule those layers run: [the rule, "head" or "channel":
+            # whose the decay is] (None without a linear layer)
+            "linear_mixer": "list?",
             # what of kv_bytes_full the latent_attention layers' pages hold
             # (one row a token, key and value at once), and that row:
             # [the latent's rank, the rotated key part, numbers stored]
@@ -430,6 +433,11 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # and decode rows together): on every call that is read of a
             # model with expert layers, fused ones too
             "moe_zero_pairs": "int", "moe_rows": "int",
+            # the slots whose state row the call's step kernel read and
+            # wrote, summed over the linear_attention layers (decode rows x
+            # linear layers); with the step's tokens, on a model with such
+            # layers alone
+            "state_rows": "int",
             # the grid steps the decode step's paged attention calls ran
             # (the live (slot, key group) items of the folded kernel's
             # work list) and the slots x groups a dense grid would have

@@ -29,13 +29,28 @@ arithmetic live here:
   :func:`gated_delta_step`, the kernel ``tadnn_gdn_step`` on a TPU and
   :func:`gated_delta_step_xla` elsewhere.
 
+A decay may also be a VECTOR a head: ``g`` [T, H, d_k], one log-decay a key
+channel, ``a_t = exp(g_t)`` in R^{d_k} (Kimi Delta Attention):
+
+    S_t = (I - beta_t k_t k_t^T) Diag(a_t) S_{t-1} + beta_t k_t v_t^T
+
+which with all of a head's channels equal is the rule above to the letter.
+Every entry point takes either (by ``g``'s rank); the recurrence is the same
+lines.  The chunk form is not: between two tokens of a sub-chunk the decay
+no longer factors out of ``k_t . k_j`` (``kda_products`` forms ``sum_c k_t[c]
+k_j[c] exp(Gamma_t[c] - Gamma_j[c])`` block by block), so the chip gets
+kernels of its own, ``tadnn_kda_chunk`` and ``tadnn_kda_step``
+(:func:`kda_chunk_pallas`, :func:`kda_step_pallas`), and the scalar rule's
+kernels stay as they are.
+
 The platform picks between a kernel and its plain form, as for the paged
 attention kernel; there is no switch.  State, decay, beta and every
 accumulator are float32; the matmuls' operands are in the dtype ``q`` comes
 in (bfloat16 when serving; float32 operands ask for float32 products too).
 
 Shapes: one sequence, ``q, k`` [T, H, d_k], ``v`` [T, H, d_v], ``g, beta``
-[T, H] float32, ``state`` [H, d_k, d_v] float32.  A batch folds into H.
+[T, H] float32 (``g`` [T, H, d_k] where the decay is a channel's),
+``state`` [H, d_k, d_v] float32.  A batch folds into H.
 A row with ``beta == 0`` and ``g == 0`` leaves the state as it was (a
 padded chunk's tail, an inactive slot).
 """
@@ -53,6 +68,7 @@ from jax.experimental.pallas import tpu as pltpu
 SUB_CHUNK = 64  # tokens solved together inside a chunk
 CHUNK_GROUP = 8  # at most this many sub-chunks of a head a grid step
 STEP_HEADS = 10  # at most this many heads of a slot's state a grid step
+KDA_BLOCK = 16  # tokens whose channel-wise decays are formed pair by pair
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
 
@@ -89,13 +105,14 @@ def causal_conv(full: jax.Array, w: jax.Array, n: int) -> jax.Array:
 
 def gated_delta_recurrent(q, k, v, g, beta, state):
     """The equations as written, a token a scan step, in float32 at highest
-    precision.  Returns ``(o [T, H, d_v] float32, state)``."""
+    precision; ``g`` [T, H], or [T, H, d_k] for a decay a channel.  Returns
+    ``(o [T, H, d_v] float32, state)``."""
     def step(S, x):
         q, k, v, g, beta = x
-        a = jnp.exp(g)[:, None, None]
-        Sk = jnp.einsum("hkv,hk->hv", S, k, precision=HI)
-        u = beta[:, None] * (v - a[:, 0] * Sk)
-        S = a * S + k[:, :, None] * u[:, None, :]
+        # the state decayed: by a number a head, or a row (key channel) each
+        S = jnp.exp(g if g.ndim == 2 else g[:, None])[..., None] * S
+        u = beta[:, None] * (v - jnp.einsum("hkv,hk->hv", S, k, precision=HI))
+        S = S + k[:, :, None] * u[:, None, :]
         return S, jnp.einsum("hkv,hk->hv", S, q, precision=HI)
 
     state, o = jax.lax.scan(step, state.astype(F32), tuple(
@@ -110,6 +127,36 @@ def _sub_chunk(T: int) -> int:
     return SUB_CHUNK if T >= SUB_CHUNK else -(-T // 8) * 8
 
 
+def _split(x, sub: int):
+    """``x`` [T, H, ...] padded to whole sub-chunks of ``sub`` tokens with
+    rows of zeros (which leave a state alone): [H, NS, sub, ...]."""
+    x = jnp.pad(x, ((0, -x.shape[0] % sub),) + ((0, 0),) * (x.ndim - 1))
+    x = x.reshape(-1, sub, *x.shape[1:])
+    return jnp.moveaxis(x, 2, 0)
+
+
+def _pass_state(state, Wv, Wk, P, qg, kdT, gc, op, T: int):
+    """The state through the sub-chunks, one after the other, every head at
+    once: ``Wv``, ``Wk`` the solved factors, ``P`` the decayed q.k products,
+    ``gc`` a sub-chunk's whole decay (anything that broadcasts against a
+    head's [d_k, d_v] state).  ``(o [T, H, d_v] float32, state)``."""
+    mm = functools.partial(jnp.matmul, precision=_exact(op),
+                           preferred_element_type=F32)
+
+    def body(S, x):  # one sub-chunk of every head
+        Wv, Wk, P, qg, kdT, gc = x
+        U = Wv - mm(Wk, S.astype(op))
+        o = mm(qg, S.astype(op)) + mm(P, U.astype(op))
+        return gc * S + mm(kdT, U.astype(op)), o
+
+    per = lambda x: jnp.moveaxis(x, 1, 0)  # sub-chunks lead
+    state, o = jax.lax.scan(body, state.astype(F32), tuple(
+        per(x) for x in (Wv, Wk, P, qg, kdT, gc)))
+    # [NS, H, sub, d_v] -> [T, H, d_v]
+    o = jnp.moveaxis(o, 1, 2).reshape(-1, *o.shape[1:2], o.shape[-1])
+    return o[:T], state
+
+
 def _chunk_operands(q, k, v, g, beta):
     """What a sub-chunk's solve multiplies, every decay folded in here (no
     exponential of a positive number anywhere: each is of a difference
@@ -118,16 +165,9 @@ def _chunk_operands(q, k, v, g, beta):
     sub-chunks with rows that leave the state alone."""
     T = q.shape[0]
     sub = _sub_chunk(T)
-    pad = -T % sub
     op = q.dtype
-
-    def split(x):  # [T, H, ...] -> [H, NS, sub, ...]
-        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
-        x = x.reshape(-1, sub, *x.shape[1:])
-        return jnp.moveaxis(x, 2, 0)
-
-    q, k, v = split(q).astype(F32), split(k).astype(F32), split(v).astype(F32)
-    g, beta = split(g.astype(F32)), split(beta.astype(F32))  # [H, NS, sub]
+    q, k, v, g, beta = (_split(x, sub).astype(F32)
+                        for x in (q, k, v, g, beta))  # g, beta [H, NS, sub]
     gam = jnp.cumsum(g, -1)
     diff = gam[..., :, None] - gam[..., None, :]
     lower = jnp.tril(jnp.ones((sub, sub), bool))
@@ -183,20 +223,8 @@ def gated_delta_chunk_xla(q, k, v, g, beta, state):
     Wv, Wk = mm(Tm, ops["bv"]), mm(Tm, ops["kbg"]).astype(op)
     P = jnp.where(lower, ops["decay"] * mm(ops["q"], ops["kT"]),
                   0.0).astype(op)
-
-    def body(S, x):  # one sub-chunk of every head
-        Wv, Wk, P, qg, kdT, gc = x
-        U = Wv - mm(Wk, S.astype(op))
-        o = mm(qg, S.astype(op)) + mm(P, U.astype(op))
-        return gc[:, None, None] * S + mm(kdT, U.astype(op)), o
-
-    per = lambda x: jnp.moveaxis(x, 1, 0)  # sub-chunks lead
-    state, o = jax.lax.scan(body, state.astype(F32), (
-        per(Wv), per(Wk), per(P), per(ops["qg"]), per(ops["kdT"]),
-        per(ops["gc"])))
-    # [NS, H, sub, d_v] -> [T, H, d_v]
-    o = jnp.moveaxis(o, 1, 2).reshape(-1, *o.shape[1:2], o.shape[-1])
-    return o[:T], state
+    return _pass_state(state, Wv, Wk, P, ops["qg"], ops["kdT"],
+                       ops["gc"][..., None, None], op, T)
 
 
 def _chunk_kernel(q_ref, qg_ref, kb_ref, kbg_ref, kT_ref, kdT_ref, bv_ref,
@@ -276,10 +304,164 @@ def gated_delta_chunk_pallas(q, k, v, g, beta, state, *,
 
 def gated_delta_chunk(q, k, v, g, beta, state):
     """A sequence (or a prefill chunk of one) through the gated delta rule
-    from ``state``: ``(o [T, H, d_v] float32, the state after it)``."""
-    if _on_tpu():
-        return gated_delta_chunk_pallas(q, k, v, g, beta, state)
-    return gated_delta_chunk_xla(q, k, v, g, beta, state)
+    from ``state``: ``(o [T, H, d_v] float32, the state after it)``; the
+    decay a head's (``g`` [T, H]) or a channel's (``g`` [T, H, d_k])."""
+    if g.ndim == 3:
+        form = kda_chunk_pallas if _on_tpu() else kda_chunk_xla
+    else:
+        form = gated_delta_chunk_pallas if _on_tpu() else gated_delta_chunk_xla
+    return form(q, k, v, g, beta, state)
+
+
+# -- the chunk form, a decay a channel -------------------------------------------
+
+
+def kda_products(q, kb, k, gam, op):
+    """What a vector decay puts in the place of ``decay * (kb kT)`` and
+    ``decay * (q kT)``: for a sub-chunk's rows ``q, kb, k`` [.., n, d_k] and
+    their cumulative log-decays ``gam`` [.., n, d_k], all float32,
+
+        A[t, j] = sum_c kb_t[c] k_j[c] exp(gam_t[c] - gam_j[c])   (j < t)
+        P[t, j] = sum_c q_t[c]  k_j[c] exp(gam_t[c] - gam_j[c])   (j <= t)
+
+    and zero above, [.., n, n] float32, with no exponential of a positive
+    number.  The rows go in blocks of ``KDA_BLOCK``.  A block's rows against
+    every EARLIER row pass through the block's first row ``r``: ``(x_t
+    exp(gam_t - gam_r)) . (k_j exp(gam_r - gam_j))``, both exponents <= 0,
+    one matmul with operands in ``op`` (the dtype q came in).  Inside a
+    block ``exp(gam_t - gam_j)`` is formed pair by pair ([blk, blk, d_k]:
+    elementwise and a sum, float32)."""
+    n = k.shape[-2]
+    blk = KDA_BLOCK if n % KDA_BLOCK == 0 else 8
+    mm = functools.partial(jnp.matmul, precision=_exact(op),
+                           preferred_element_type=F32)
+    lower = jnp.tril(jnp.ones((blk, blk), bool))[..., None]
+    rows_a, rows_p = [], []
+    for lo in range(0, n, blk):
+        sl = slice(lo, lo + blk)
+        g_blk = gam[..., sl, :]
+        pair = jnp.exp(jnp.where(
+            lower, g_blk[..., :, None, :] - g_blk[..., None, :, :], -jnp.inf))
+        kp = k[..., None, sl, :] * pair  # [.., t, j, c]
+        parts = [jnp.sum(x[..., sl, None, :] * kp, -1) for x in (kb, q)]
+        if lo:  # the earlier rows, through the block's first
+            ref = gam[..., lo:lo + 1, :]
+            back = jnp.swapaxes(k[..., :lo, :] * jnp.exp(
+                ref - gam[..., :lo, :]), -1, -2).astype(op)
+            into = jnp.exp(g_blk - ref)
+            parts = [jnp.concatenate(
+                [mm((x[..., sl, :] * into).astype(op), back), inner], -1)
+                for x, inner in zip((kb, q), parts)]
+        wide = [(0, 0)] * (k.ndim - 1) + [(0, n - lo - blk)]
+        rows_a.append(jnp.pad(parts[0], wide))
+        rows_p.append(jnp.pad(parts[1], wide))
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return (jnp.where(r > c, jnp.concatenate(rows_a, -2), 0.0),
+            jnp.concatenate(rows_p, -2))
+
+
+def _kda_operands(q, k, v, g, beta):
+    """``_chunk_operands`` for ``g`` [T, H, d_k]: ``[H, NS, sub, .]`` each,
+    the keys that write the state transposed ``[H, NS, d_k, sub]``, the
+    sub-chunk's decay a column ``[H, NS, d_k, 1]``; ``A`` and ``P`` are
+    ``kda_products``'s."""
+    T = q.shape[0]
+    op = q.dtype
+    q, k, v, g, beta = (_split(x, _sub_chunk(T)).astype(F32)
+                        for x in (q, k, v, g, beta))
+    gam = jnp.cumsum(g, -2)
+    G = jnp.exp(gam)
+    kb = beta[..., None] * k
+    A, P = kda_products(q, kb, k, gam, op)
+    to_end = jnp.exp(gam[..., -1:, :] - gam)
+    return dict(
+        A=A, P=P.astype(op), bv=(beta[..., None] * v).astype(op),
+        kbg=(G * kb).astype(op), qg=(G * q).astype(op),
+        kdT=jnp.swapaxes(to_end * k, -1, -2).astype(op),
+        gc=G[..., -1, :, None]), T
+
+
+def kda_chunk_xla(q, k, v, g, beta, state):
+    """The chunk form for a decay a channel in plain ``jax.numpy``: the CPU
+    path, and the oracle of ``tadnn_kda_chunk``'s parity tests.  The solve
+    and the state's passage through the sub-chunks are the scalar rule's.
+    Returns ``(o [T, H, d_v] float32, state)``."""
+    ops, T = _kda_operands(q, k, v, g, beta)
+    op = q.dtype
+    mm = functools.partial(jnp.matmul, precision=_exact(op),
+                           preferred_element_type=F32)
+    Tm = _unit_lower_inverse(
+        ops["A"], functools.partial(jnp.matmul, precision=HI)).astype(op)
+    Wv, Wk = mm(Tm, ops["bv"]), mm(Tm, ops["kbg"]).astype(op)
+    return _pass_state(state, Wv, Wk, ops["P"], ops["qg"], ops["kdT"],
+                       ops["gc"], op, T)
+
+
+def _kda_chunk_kernel(A_ref, P_ref, bv_ref, kbg_ref, qg_ref, kdT_ref, gc_ref,
+                      s0_ref, o_ref, s_ref, s_scr, *, exact):
+    """One (head, group of sub-chunks) grid step, as ``_chunk_kernel``: the
+    sub-chunks' solves first, side by side, then the state through them,
+    each of its rows (key channels) decayed by its own factor (``gc``, a
+    column)."""
+    n = pl.program_id(1)
+
+    @pl.when(n == 0)
+    def _load():
+        s_scr[:] = s0_ref[0]
+
+    op = P_ref.dtype
+    dot = functools.partial(jnp.dot, precision=exact,
+                            preferred_element_type=F32)
+    solved = []
+    for i in range(A_ref.shape[1]):  # static: the group's sub-chunks
+        Tm = _unit_lower_inverse(A_ref[0, i], functools.partial(
+            jnp.dot, precision=HI, preferred_element_type=F32)).astype(op)
+        solved.append((dot(Tm, bv_ref[0, i]),
+                       dot(Tm, kbg_ref[0, i]).astype(op)))
+    S = s_scr[:]
+    for i, (Wv, Wk) in enumerate(solved):
+        Sop = S.astype(op)
+        U = (Wv - dot(Wk, Sop)).astype(op)
+        o_ref[0, i] = dot(qg_ref[0, i], Sop) + dot(P_ref[0, i], U)
+        S = gc_ref[0, i] * S + dot(kdT_ref[0, i], U)
+    s_scr[:] = S
+
+    @pl.when(n == pl.num_programs(1) - 1)
+    def _store():
+        s_ref[0] = S
+
+
+def kda_chunk_pallas(q, k, v, g, beta, state, *, interpret: bool = False):
+    """The chunk form for a decay a channel as the kernel
+    ``tadnn_kda_chunk``: grid (heads, groups of up to ``CHUNK_GROUP``
+    sub-chunks), the groups of a head in order, its state in VMEM between
+    them.  ``kda_products`` runs before it, in ``jax.numpy``."""
+    ops, T = _kda_operands(q, k, v, g, beta)
+    H, NS, sub, dk = ops["qg"].shape
+    dv = ops["bv"].shape[-1]
+    grp = max(n for n in range(1, CHUNK_GROUP + 1) if NS % n == 0)
+
+    def blk(*tail):
+        return pl.BlockSpec((1, grp, *tail), lambda h, n: (h, n, 0, 0))
+
+    whole = pl.BlockSpec((1, dk, dv), lambda h, n: (h, 0, 0))
+    o, state = pl.pallas_call(
+        functools.partial(_kda_chunk_kernel, exact=_exact(q.dtype)),
+        grid=(H, NS // grp),
+        in_specs=[blk(sub, sub)] * 2 + [blk(sub, dv)] + [blk(sub, dk)] * 2 + [
+            blk(dk, sub), blk(dk, 1), whole],
+        out_specs=[blk(sub, dv), whole],
+        out_shape=[jax.ShapeDtypeStruct((H, NS, sub, dv), F32),
+                   jax.ShapeDtypeStruct((H, dk, dv), F32)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="tadnn_kda_chunk",
+    )(ops["A"], ops["P"], ops["bv"], ops["kbg"], ops["qg"], ops["kdT"],
+      ops["gc"], state.astype(F32))
+    return jnp.moveaxis(o, 0, 2).reshape(NS * sub, H, dv)[:T], state
 
 
 # -- the step form ---------------------------------------------------------------
@@ -363,11 +545,86 @@ def gated_delta_step_pallas(q, k, v, g, beta, pool, rows, *,
 
 def gated_delta_step(q, k, v, g, beta, pool, rows):
     """One decode token a slot against the pool of states, in place:
-    ``(o [S, H, d_v] float32, pool)``.  Row 0 is the null row of the
+    ``(o [S, H, d_v] float32, pool)``; the decay a head's (``g`` [S, H]) or
+    a channel's (``g`` [S, H, d_k]).  Row 0 is the null row of the
     slots that do not decode: with ``beta = 0`` and ``g = 0`` they leave it
-    as it was.  (On a v5e the compiler stages a pool of this size through
-    on-chip memory round the call, in copies of its own: the kernel's time
-    in a trace does not hold its HBM traffic.)"""
-    if _on_tpu():
-        return gated_delta_step_pallas(q, k, v, g, beta, pool, rows)
-    return gated_delta_step_xla(q, k, v, g, beta, pool, rows)
+    as it was.  (On a v5e the compiler stages a pool of the scalar rule's
+    size through on-chip memory round the call, in copies of its own: the
+    kernel's time in a trace does not hold its HBM traffic.)"""
+    if g.ndim == 3:
+        form = kda_step_pallas if _on_tpu() else kda_step_xla
+    else:
+        form = gated_delta_step_pallas if _on_tpu() else gated_delta_step_xla
+    return form(q, k, v, g, beta, pool, rows)
+
+
+# -- the step form, a decay a channel ---------------------------------------------
+
+
+def kda_step_xla(q, k, v, g, beta, pool, rows):
+    """``gated_delta_step_xla`` for ``g`` [S, H, d_k]: a slot's state rows
+    decayed each by its own factor, then the same write and read."""
+    q, k, v, g, beta = (x.astype(F32) for x in (q, k, v, g, beta))
+    S = jnp.exp(g)[..., None] * pool[rows]
+    u = beta[..., None] * (v - jnp.einsum("shkv,shk->shv", S, k,
+                                          precision=HI))
+    S = S + k[..., None] * u[..., None, :]
+    o = jnp.einsum("shkv,shk->shv", S, q, precision=HI)
+    return o, pool.at[rows].set(S)
+
+
+def _kda_step_kernel(rows_ref, kT_ref, qT_ref, aT_ref, row_ref, s_ref, o_ref,
+                     out_ref, *, heads: int):
+    """A group of ``heads`` heads of one slot, on the VPU, as
+    ``_step_kernel``: a head's key, query AND decay as columns [d_k, 1], its
+    value, beta and k.q as rows [1, d_v] (rows ``c * heads + i`` of the
+    packed operand)."""
+    del rows_ref
+    for i in range(heads):  # static
+        kc, qc, ac = (ref[0, 0][:, i:i + 1]
+                      for ref in (kT_ref, qT_ref, aT_ref))
+        v, b, kq = (row_ref[0, 0, c * heads + i:c * heads + i + 1]
+                    for c in range(3))
+        S = ac * s_ref[0, i]  # every row by its channel's decay
+        u = b * (v - jnp.sum(S * kc, axis=0, keepdims=True))
+        out_ref[0, i] = S + kc * u
+        o_ref[0, 0, i:i + 1] = jnp.sum(S * qc, axis=0, keepdims=True) + kq * u
+
+
+def kda_step_pallas(q, k, v, g, beta, pool, rows, *,
+                    interpret: bool = False):
+    """The step form for a decay a channel as the kernel ``tadnn_kda_step``:
+    grid (slots, groups of heads); a slot's rows of ``pool`` are read and
+    written where they lie (the pool is aliased to the output, the row ids
+    are a scalar prefetch)."""
+    S, H, dk = k.shape
+    dv = v.shape[-1]
+    hb = _head_group(H)
+    G = H // hb
+    q, k, v, g, beta = (x.astype(F32) for x in (q, k, v, g, beta))
+
+    def cols(x):  # [S, H, dk] -> [S, G, dk, hb]
+        return jnp.swapaxes(x.reshape(S, G, hb, dk), -1, -2)
+
+    wide = lambda x: jnp.broadcast_to(x[..., None], (S, H, dv))
+    packed = jnp.stack([v, wide(beta), wide(jnp.sum(k * q, -1))], axis=2)
+    packed = jnp.swapaxes(packed.reshape(S, G, hb, 3, dv), 2, 3).reshape(
+        S, G, 3 * hb, dv)
+    col = pl.BlockSpec((1, 1, dk, hb), lambda s, j, r: (s, j, 0, 0))
+    row3 = pl.BlockSpec((1, 1, 3 * hb, dv), lambda s, j, r: (s, j, 0, 0))
+    st = pl.BlockSpec((1, hb, dk, dv), lambda s, j, r: (r[s], j, 0, 0))
+    o, pool = pl.pallas_call(
+        functools.partial(_kda_step_kernel, heads=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S, G),
+            in_specs=[col, col, col, row3, st],
+            out_specs=[pl.BlockSpec((1, 1, hb, dv),
+                                    lambda s, j, r: (s, j, 0, 0)), st]),
+        out_shape=[jax.ShapeDtypeStruct((S, G, hb, dv), F32),
+                   jax.ShapeDtypeStruct(pool.shape, F32)],
+        input_output_aliases={5: 1},  # the pool, after the row ids
+        interpret=interpret,
+        name="tadnn_kda_step",
+    )(rows.astype(jnp.int32), cols(k), cols(q), cols(jnp.exp(g)), packed,
+      pool)
+    return o.reshape(S, H, dv), pool
