@@ -404,10 +404,11 @@ def test_serving_programs_update_the_pool_in_place(
     # (a chunk's activations at d 3,840 beside 11,520 convolved channels
     # are 0.22 GiB; a copy of the 4.5 GB of pages would be twenty times it)
     # (kimi: 96 + 512 rows at d 2,304 through 15 expert layers and 12 KDA
-    # layers' [32, 8, 16, 16, 128] pairwise decays: 0.51 GiB in the chunk
-    # that carries the rows; a copy of ONE state pool would be 0.19 more)
+    # layers: 0.31 GiB in the chunk that carries the rows, 0.51 while
+    # ``kda_products`` ran before the kernel; a copy of ONE state pool would
+    # be 0.19 more)
     roomy = {"olmo-hybrid-7b-pp2": 0.25,
-             "kimi-linear-48b-ep8": 0.55}.get(config, 0.2)
+             "kimi-linear-48b-ep8": 0.35}.get(config, 0.2)
     assert mem.temp_size_in_bytes < roomy * 2**30, mem.temp_size_in_bytes
     page_arrays = {("f32" if x.dtype == jnp.float32 else "bf16")
                    + "[%s]" % ",".join(map(str, x.shape))
@@ -467,6 +468,19 @@ def test_serving_programs_update_the_pool_in_place(
         assert steps == 12 * (program != "prefill_chunk")
         assert chunks == 12 * (program != "decode_step")
         assert "tadnn_gdn" not in text
+        # the chunk kernel forms the channel-wise decays' products itself,
+        # from q, k, v as the convolution leaves them, [512, 32 x 128]: no
+        # pairwise value of ``kda_products`` in the program, and no copy or
+        # transpose of such rows (the latent layers' [512, 32, 128] aside).
+        # (In the chunk ALONE the compiler writes a layer's log-decays out
+        # of their projection column-major and turns them round, 8 MB a
+        # layer; in the chunk that carries the decode rows, the one a full
+        # engine runs, it does not.)
+        assert not re.search(r"f32\[[\d,]*16,16,128\]", text)
+        moved = [l.strip()[:160] for l in text.splitlines() if re.search(
+            r"= \w+\[512,4096\]\S* (copy|transpose)\(", l)]
+        assert not [l for l in moved if "= bf16" in l], moved
+        assert len(moved) <= 12 * (program == "prefill_chunk"), moved
         assert len(re.findall(r"^\s*%tadnn_latent_chunk[.\d]* = ", text,
                               re.M)) == 4 * (program != "decode_step")
         assert text.count("tadnn_paged_decode_latent") >= 4 * (
@@ -704,3 +718,47 @@ def test_gated_delta_kernels_compile_for_v5e(v5e, form, dtype, rule):
     # the pool is the output: no second copy of it
     assert compiled.memory_analysis().alias_size_in_bytes \
         >= (S + 1) * H * dk * dv * 4
+
+
+@pytest.mark.parametrize("T", [512, 454])
+def test_kda_chunk_is_one_kernel_with_nothing_prepared_for_it(v5e, T):
+    """A decay a channel at Kimi-Linear's widths, a whole prefill chunk and
+    the traced window's mean one (a padded tail), with q, k, v and g as the
+    mixer's convolution and projections leave them (``[T, 32 x 128]``, cut
+    into heads by a reshape) and ``o`` as its output projection takes it:
+    the compiled chunk form is ONE ``tadnn_kda_chunk`` call, with no
+    pairwise ``[.., 16, 16, 128]`` value (``kda_products`` is the CPU
+    path's), no copy or transpose of the rows on either side of the kernel,
+    and no other op but the padding of a tail."""
+    import re
+
+    from torch_automatic_distributed_neural_network_tpu.ops import gated_delta as gd
+
+    one = SingleDeviceSharding(v5e[0])
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+    H, dk, dv = 32, 128, 128
+
+    def mixer(q, k, v, g, beta, state):
+        heads = lambda x: x.reshape(T, H, -1)  # noqa: E731
+        o, state = gd.kda_chunk_pallas(heads(q), heads(k), heads(v), heads(g),
+                                       beta, state)
+        return o.reshape(T, H * dv), state
+
+    text = _compile(
+        mixer, sds((T, H * dk), jnp.bfloat16), sds((T, H * dk), jnp.bfloat16),
+        sds((T, H * dv), jnp.bfloat16), sds((T, H * dk), jnp.float32),
+        sds((T, H), jnp.float32), sds((H, dk, dv), jnp.float32))
+    lines = text.splitlines()
+    assert len(re.findall(r"^\s*(?:ROOT )?%tadnn_kda_chunk[.\d]* = ", text,
+                          re.M)) == 1
+    assert not re.search(r"f32\[[\d,]*16,16,128\]", text)
+    # (beta [T, 32] aside: the compiler lays that entry parameter out
+    # column-major and turns it round, 64 kB)
+    moved = [l.strip()[:160] for l in lines
+             if re.search(r"(copy|transpose)\S*\(", l.split(" = ")[-1][:80])
+             and not re.search(rf"= f32\[\d+,{H}\]", l)]
+    assert not moved, moved
+    others = [l.strip()[:160] for l in lines if re.search(
+        r" = \S+ (fusion|pad|slice|concatenate)\(", l)]
+    # a tail: five operands padded to 512 rows, the output cut to 454
+    assert len(others) <= (0 if T % gd.SUB_CHUNK == 0 else 6), others

@@ -254,3 +254,93 @@ def test_a_channel_that_forgets_beside_one_that_never_does(form, T):
     np.testing.assert_allclose(o, o_ref, **TOL)
     np.testing.assert_allclose(s, s_ref, **TOL)
     assert float(jnp.abs(s[:, 0]).max()) < 2.1  # one token's write, alone
+
+
+# -- the chunk kernel of a decay a channel: everything formed on the chip ------
+
+
+def _kda_case(case: str):
+    """``(args, oracle, rows)`` of a case of the test below: the kernel's
+    operands, what it must return (``(o, state)`` from an oracle that is not
+    the kernel) and how many of ``o``'s rows count."""
+    if case.startswith("T"):  # a padded tail, several groups of sub-chunks
+        T = int(case[1:])
+        args = inputs(T, seed=T, heads=2, decay="channel")
+        return args, gd.gated_delta_recurrent(*args), T
+    if case.startswith("wide"):  # Kimi-Linear's widths: nothing is padded
+        T = int(case[4:])
+        args = inputs(T, seed=T, heads=2, dk=128, dv=128, decay="channel")
+        return args, gd.gated_delta_recurrent(*args), T
+    if case == "steep":  # log-decays down to -30 a token a channel
+        q, k, v, g, beta, state = inputs(150, seed=12, decay="channel")
+        u = jax.random.uniform(jax.random.key(13), g.shape)
+        args = (q, k, v, -30.0 * u * u, beta, state)
+        return args, gd.gated_delta_recurrent(*args), 150
+    if case == "idle_rows":  # a padded chunk's tail: beta 0 and g 0
+        q, k, v, g, beta, state = inputs(100, seed=14, decay="channel")
+        keep = jnp.arange(100) < 71
+        args = (q, k, v, rows_of(keep, g), rows_of(keep, beta), state)
+        return args, gd.gated_delta_recurrent(
+            q[:71], k[:71], v[:71], g[:71], beta[:71], state), 71
+    assert case == "equal_channels"  # the scalar rule, to the letter
+    q, k, v, g, beta, state = inputs(200, seed=15)
+    wide = jnp.broadcast_to(g[..., None], (*g.shape, DK))
+    return (q, k, v, wide, beta, state), gd.gated_delta_chunk_xla(
+        q, k, v, g, beta, state), 200
+
+
+@pytest.mark.parametrize("case", [
+    "T8", "T70", "T454", "T512", "T1024", "wide70", "wide512", "carried",
+    "steep", "idle_rows", "equal_channels", "bf16_T454", "bf16_wide70",
+    "bf16_steep"])
+def test_kda_chunk_kernel_forms_its_decays_itself(case):
+    """``tadnn_kda_chunk`` in the interpreter, from q, k, v, g, beta as the
+    mixer hands them over (the halving through reference rows, the running
+    sums and every scaling inside it), against the recurrence token by token
+    and against ``kda_chunk_xla``, whose ``kda_products`` forms the decays
+    pair by pair: lengths with a padded tail and with several groups of
+    sub-chunks; a state carried from a chunk before; decays steep enough
+    that any positive exponent would overflow; rows that leave the state
+    alone; and all of a head's channels equal, which is the scalar rule's
+    ``gated_delta_chunk_xla``.  The ``bf16_`` cases are serving's dtypes
+    (bfloat16 q, k, v; the levels' products stay float32): within
+    bfloat16's rounding of the recurrence on the float32 inputs, closer to
+    ``kda_chunk_xla`` on the same bfloat16 inputs, and no further from the
+    recurrence than that form is."""
+    kernel = lambda *a: gd.kda_chunk_pallas(*a, interpret=True)  # noqa: E731
+    if case.startswith("bf16_"):
+        (q, k, v, *rest), (o_ref, s_ref), n = _kda_case(case[5:])
+        args = (*(x.astype(jnp.bfloat16) for x in (q, k, v)), *rest)
+        o, s = kernel(*args)
+        assert o.dtype == s.dtype == jnp.float32
+        assert np.isfinite(o).all() and np.isfinite(s).all()
+        np.testing.assert_allclose(o, o_ref, rtol=0.05, atol=0.05)
+        np.testing.assert_allclose(s, s_ref, rtol=0.05, atol=0.08)
+        o_xla, s_xla = gd.kda_chunk_xla(*args)
+        np.testing.assert_allclose(o, o_xla, rtol=0.01, atol=0.01)
+        np.testing.assert_allclose(s, s_xla, rtol=0.01, atol=0.01)
+        rms = lambda x, ref: float(jnp.sqrt(jnp.mean((x - ref) ** 2)))  # noqa: E731
+        # no further from the recurrence than the pairwise float32 blocks
+        # (bfloat16 operands at the levels read 1.10 times in the output)
+        assert rms(o, o_ref) <= 1.05 * rms(o_xla, o_ref)
+        assert rms(s, s_ref) <= 1.05 * rms(s_xla, s_ref)
+        return
+    if case == "carried":  # 454 + 70 tokens are one sequence of 524
+        q, k, v, g, beta, state = inputs(524, seed=11, heads=2,
+                                         decay="channel")
+        o_ref, s_ref = gd.gated_delta_recurrent(q, k, v, g, beta, state)
+        first = slice(0, 454)
+        o1, mid = kernel(q[first], k[first], v[first], g[first], beta[first],
+                         state)
+        args = (q[454:], k[454:], v[454:], g[454:], beta[454:], mid)
+        o, s = kernel(*args)
+        np.testing.assert_allclose(jnp.concatenate([o1, o]), o_ref, **TOL)
+        np.testing.assert_allclose(s, s_ref, **TOL)
+        want, n = (o_ref[454:], s_ref), 70
+    else:
+        args, want, n = _kda_case(case)
+        o, s = kernel(*args)
+    assert np.isfinite(o).all() and np.isfinite(s).all()
+    for o_ref, s_ref in (want, gd.kda_chunk_xla(*args)):
+        np.testing.assert_allclose(o[:n], o_ref[:n], **TOL)
+        np.testing.assert_allclose(s, s_ref, **TOL)

@@ -37,11 +37,33 @@ channel, ``a_t = exp(g_t)`` in R^{d_k} (Kimi Delta Attention):
 which with all of a head's channels equal is the rule above to the letter.
 Every entry point takes either (by ``g``'s rank); the recurrence is the same
 lines.  The chunk form is not: between two tokens of a sub-chunk the decay
-no longer factors out of ``k_t . k_j`` (``kda_products`` forms ``sum_c k_t[c]
-k_j[c] exp(Gamma_t[c] - Gamma_j[c])`` block by block), so the chip gets
-kernels of its own, ``tadnn_kda_chunk`` and ``tadnn_kda_step``
-(:func:`kda_chunk_pallas`, :func:`kda_step_pallas`), and the scalar rule's
-kernels stay as they are.
+no longer factors out of ``k_t . k_j``, so ``A[t, j] = sum_c kb_t[c] k_j[c]
+exp(Gamma_t[c] - Gamma_j[c])`` (and ``P`` with ``q``) has to be formed with
+no exponential of a positive number, and the chip gets kernels of its own,
+``tadnn_kda_chunk`` and ``tadnn_kda_step`` (:func:`kda_chunk_pallas`,
+:func:`kda_step_pallas`); the scalar rule's kernels stay as they are.
+
+- On the chip ONE kernel a layer a chunk does all of it
+  (``_kda_chunk_kernel``): it reads ``q, k, v, g, beta`` as the mixer's
+  projections leave them (``[T, H d]``, a head a block of 128 lanes) and
+  writes ``o`` so; nothing is prepared or relaid in XLA.  Inside, a
+  sub-chunk's exponents are sums of its log-decays taken on the MXU (a 0/1
+  matrix times ``g``, exact), and ``A`` and ``P`` are built BY HALVES, with
+  the masks of ``_unit_lower_inverse``: at level ``s`` = 1, 2, .. 32 the
+  rows of a block's upper half against the rows of its lower half THROUGH A
+  REFERENCE ROW, the upper half's first, ``exp(Gamma_t - Gamma_r) .
+  exp(Gamma_r - Gamma_j)``, both exponents <= 0: one exponential of a
+  [64, d_k] tile and one product a level, no pairwise tensor.  Every
+  level's product is float32 at highest precision, whatever dtype ``q``
+  came in: no coarser than ``kda_products`` anywhere (its pairs inside a
+  block are float32 sums; its blocks against earlier ones take bfloat16
+  operands when serving), because the served tokens' regret, whose widest
+  position has little room under its limit, is what settles the levels'
+  precision (``PERF.md`` section 6, PR 42).  The solve is float32 at highest
+  precision, as before.
+- ``kda_products`` (the same sums in blocks of ``KDA_BLOCK``, pair by pair
+  inside a block) with ``kda_chunk_xla`` is the CPU path and the oracle of
+  the kernel's parity tests.
 
 The platform picks between a kernel and its plain form, as for the paged
 attention kernel; there is no switch.  State, decay, beta and every
@@ -398,33 +420,109 @@ def kda_chunk_xla(q, k, v, g, beta, state):
                        ops["gc"], op, T)
 
 
-def _kda_chunk_kernel(A_ref, P_ref, bv_ref, kbg_ref, qg_ref, kdT_ref, gc_ref,
-                      s0_ref, o_ref, s_ref, s_scr, *, exact):
-    """One (head, group of sub-chunks) grid step, as ``_chunk_kernel``: the
-    sub-chunks' solves first, side by side, then the state through them,
-    each of its rows (key channels) decayed by its own factor (``gc``, a
-    column)."""
-    n = pl.program_id(1)
+def _pieces(x):
+    """Float32 ``x`` as three bfloat16 arrays, each the rounding of what the
+    ones before left: their sum is ``x`` to the last bit."""
+    out = []
+    for _ in range(3):
+        out.append(x.astype(jnp.bfloat16))
+        x = x - out[-1].astype(F32)
+    return out
+
+
+def _kda_chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, o_ref, s_ref,
+                      s_scr, *, sub: int, exact):
+    """One (head, group of sub-chunks) grid step, as ``_chunk_kernel``, from
+    the rows as the mixer made them: ``q, k, g`` [rows, d_k] and ``v``
+    [rows, d_v] of this head, ``beta`` [rows, H] of every head.  Every decay
+    is formed here.  A sub-chunk's exponents are SUMS of its log-decays, all
+    of them one product of a 0/1 matrix with ``g`` (exact: ``g`` goes in as
+    its three bfloat16 pieces, the sums are float32): the running sum
+    ``gam``, and a block a level of the halving (below), so no exponent of
+    ``A`` or ``P`` is a difference of two large numbers and none is
+    positive.  (``last - gam``, the decay from a row to the sub-chunk's
+    end, IS such a difference, as ``_kda_operands`` has it: at most a
+    rounding above 0.)  Then the sub-chunks' solves, side by side, then the
+    state through them, each of its rows decayed by its own factor.
+
+    ``A[t, j] = sum_c kb_t[c] k_j[c] exp(gam_t[c] - gam_j[c])`` (and ``P``
+    with ``q``) BY HALVES, the masks of ``_unit_lower_inverse``: at level
+    ``s`` (1, 2, .. ``sub / 2``) the rows ``t`` of the upper half of a block
+    of ``2 s`` (``t & s``) against the rows ``j`` of its lower half, through
+    the upper half's first row ``r``: ``exp(gam_t - gam_r) . exp(gam_r -
+    gam_j)``, both exponents sums of log-decays.  A row is a reader or a key
+    at a level, never both, so a level is ONE exponential of a [sub, d_k]
+    tile, three scalings and one product ``[kb e; q e] (k e)^T`` masked to
+    the level's quarters, which are disjoint and cover every ``j < t`` once;
+    ``P``'s diagonal is a row sum.  The levels' products and the solve's
+    are float32 at highest precision whatever ``q``'s dtype (with bfloat16
+    operands at the levels one served position's regret passed its limit);
+    the products with the state take operands in the dtype ``q`` came in."""
+    h, n = pl.program_id(0), pl.program_id(1)
 
     @pl.when(n == 0)
     def _load():
         s_scr[:] = s0_ref[0]
 
-    op = P_ref.dtype
+    op = q_ref.dtype
     dot = functools.partial(jnp.dot, precision=exact,
                             preferred_element_type=F32)
+
+    def over(x, y, axis: int, precision=exact):  # the same axis of both
+        return jax.lax.dot_general(x, y, (((axis,), (axis,)), ((), ())),
+                                   precision=precision,
+                                   preferred_element_type=F32)
+
+    r = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+    bits = max(0, math.ceil(math.log2(sub)))
+    sums, quarters = [c <= r], []  # which log-decays an exponent sums
+    for bit in range(bits):
+        s = 1 << bit
+        ref = ((r >> (bit + 1)) << (bit + 1)) + s
+        upper = (r & s) != 0
+        sums.append((c > jnp.where(upper, ref, r))
+                    & (c <= jnp.where(upper, r, ref)))
+        quarters.append(upper & ((c & s) == 0)
+                        & ((r >> (bit + 1)) == (c >> (bit + 1))))
+    # [(1 + bits) sub, sub] of 0 and 1
+    sums = jnp.concatenate(sums, 0).astype(jnp.bfloat16)
+    dk = k_ref.shape[-1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1))
+    heads = jax.lax.broadcasted_iota(jnp.int32, b_ref.shape, 1)
+    beta_h = jnp.sum(jnp.where(heads == h, b_ref[:], 0.0), 1, keepdims=True)
+    groups = [slice(i * sub, (i + 1) * sub)
+              for i in range(q_ref.shape[0] // sub)]
     solved = []
-    for i in range(A_ref.shape[1]):  # static: the group's sub-chunks
-        Tm = _unit_lower_inverse(A_ref[0, i], functools.partial(
+    for rows in groups:  # static: the group's sub-chunks
+        q, k = q_ref[rows].astype(F32), k_ref[rows].astype(F32)
+        beta = beta_h[rows]
+        kb = beta * k
+        E = sum(jnp.dot(sums, piece, preferred_element_type=F32)
+                for piece in _pieces(g_ref[rows]))  # every entry <= 0
+        A = jnp.zeros((sub, sub), F32)
+        P = jnp.where(r == c, jnp.sum(q * k, 1, keepdims=True), 0.0)
+        for bit in range(bits):
+            e = jnp.exp(E[(1 + bit) * sub:(2 + bit) * sub])
+            X = over(jnp.concatenate([kb * e, q * e], 0), k * e, 1, HI)
+            A = A + jnp.where(quarters[bit], X[:sub], 0.0)
+            P = P + jnp.where(quarters[bit], X[sub:], 0.0)
+        Tm = _unit_lower_inverse(A, functools.partial(
             jnp.dot, precision=HI, preferred_element_type=F32)).astype(op)
-        solved.append((dot(Tm, bv_ref[0, i]),
-                       dot(Tm, kbg_ref[0, i]).astype(op)))
+        gam = E[:sub]
+        G, last = jnp.exp(gam), gam[sub - 1:]
+        gc = jnp.sum(jnp.where(eye, jnp.exp(last), 0.0), 1, keepdims=True)
+        solved.append((
+            dot(Tm, (beta * v_ref[rows].astype(F32)).astype(op)),
+            dot(Tm, (G * kb).astype(op)).astype(op), P.astype(op),
+            (G * q).astype(op), (jnp.exp(last - gam) * k).astype(op), gc))
     S = s_scr[:]
-    for i, (Wv, Wk) in enumerate(solved):
+    for rows, (Wv, Wk, Pqk, qg, kd, gc) in zip(groups, solved):
         Sop = S.astype(op)
         U = (Wv - dot(Wk, Sop)).astype(op)
-        o_ref[0, i] = dot(qg_ref[0, i], Sop) + dot(P_ref[0, i], U)
-        S = gc_ref[0, i] * S + dot(kdT_ref[0, i], U)
+        o_ref[rows] = dot(qg, Sop) + dot(Pqk, U)
+        S = gc * S + over(kd, U, 0)
     s_scr[:] = S
 
     @pl.when(n == pl.num_programs(1) - 1)
@@ -436,32 +534,47 @@ def kda_chunk_pallas(q, k, v, g, beta, state, *, interpret: bool = False):
     """The chunk form for a decay a channel as the kernel
     ``tadnn_kda_chunk``: grid (heads, groups of up to ``CHUNK_GROUP``
     sub-chunks), the groups of a head in order, its state in VMEM between
-    them.  ``kda_products`` runs before it, in ``jax.numpy``."""
-    ops, T = _kda_operands(q, k, v, g, beta)
-    H, NS, sub, dk = ops["qg"].shape
-    dv = ops["bv"].shape[-1]
+    them.  The kernel reads ``q, k, v, g`` as they come, ``[T, H d]`` with a
+    head's ``d`` channels a block of lanes, and writes ``o`` so; nothing is
+    prepared before it but the padding of ``T`` to whole sub-chunks with
+    rows that leave the state alone.  Widths that are no whole tile of 128
+    lanes are padded with channels of zeros, which change nothing: a path
+    for the CPU tests' small widths only, every served model's are whole
+    tiles."""
+    T, H, dk = k.shape
+    dv = v.shape[-1]
+    if dk % 128 or dv % 128:
+        wide = lambda x, *dims: jnp.pad(x, [(0, 0)] * (x.ndim - len(dims)) + [
+            (0, -d % 128) for d in dims])
+        o, new = kda_chunk_pallas(
+            wide(q, dk), wide(k, dk), wide(v, dv), wide(g, dk), beta,
+            wide(state, dk, dv), interpret=interpret)
+        return o[..., :dv], new[:, :dk, :dv]
+    sub = _sub_chunk(T)
+    rows = lambda x: jnp.pad(x.reshape(T, -1), ((0, -T % sub), (0, 0)))
+    NS = -(-T // sub)
     grp = max(n for n in range(1, CHUNK_GROUP + 1) if NS % n == 0)
 
-    def blk(*tail):
-        return pl.BlockSpec((1, grp, *tail), lambda h, n: (h, n, 0, 0))
+    def tile(d):  # a group's rows of head h
+        return pl.BlockSpec((grp * sub, d), lambda h, n: (n, h))
 
     whole = pl.BlockSpec((1, dk, dv), lambda h, n: (h, 0, 0))
     o, state = pl.pallas_call(
-        functools.partial(_kda_chunk_kernel, exact=_exact(q.dtype)),
+        functools.partial(_kda_chunk_kernel, sub=sub, exact=_exact(q.dtype)),
         grid=(H, NS // grp),
-        in_specs=[blk(sub, sub)] * 2 + [blk(sub, dv)] + [blk(sub, dk)] * 2 + [
-            blk(dk, sub), blk(dk, 1), whole],
-        out_specs=[blk(sub, dv), whole],
-        out_shape=[jax.ShapeDtypeStruct((H, NS, sub, dv), F32),
+        in_specs=[tile(dk), tile(dk), tile(dv), tile(dk),
+                  pl.BlockSpec((grp * sub, H), lambda h, n: (n, 0)), whole],
+        out_specs=[tile(dv), whole],
+        out_shape=[jax.ShapeDtypeStruct((NS * sub, H * dv), F32),
                    jax.ShapeDtypeStruct((H, dk, dv), F32)],
         scratch_shapes=[pltpu.VMEM((dk, dv), F32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="tadnn_kda_chunk",
-    )(ops["A"], ops["P"], ops["bv"], ops["kbg"], ops["qg"], ops["kdT"],
-      ops["gc"], state.astype(F32))
-    return jnp.moveaxis(o, 0, 2).reshape(NS * sub, H, dv)[:T], state
+    )(rows(q), rows(k), rows(v), rows(g.astype(F32)), rows(beta.astype(F32)),
+      state.astype(F32))
+    return o[:T].reshape(T, H, dv), state
 
 
 # -- the step form ---------------------------------------------------------------
