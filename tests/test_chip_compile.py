@@ -12,6 +12,7 @@ compile that passes is not a chip run.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # keep libtpu's logs out of /tmp
 
@@ -531,6 +532,12 @@ _FOLDED = {"gpt2-1p3b": (8, 16, 16, 64), "trinity-large-ep8": (16, 48, 8, 832),
            "olmo-hybrid-7b-pp2": (8, 30, 30, 2112)}
 
 
+def _operand_shapes(custom_call: str) -> list[str]:
+    """The shapes of a ``tpu_custom_call``'s operands, in order."""
+    return re.findall(r"\w+\[[\d,]*\]", custom_call.split(
+        "operand_layout_constraints={")[1].split("}}")[0])
+
+
 @pytest.mark.parametrize("window", [None, 4096], ids=["full", "window4096"])
 @pytest.mark.parametrize("config", sorted(_FOLDED))
 def test_folded_paged_decode_compiles_for_v5e(v5e, config, window):
@@ -558,13 +565,20 @@ def test_folded_paged_decode_compiles_for_v5e(v5e, config, window):
         return paged_attention(q, k, v, t, c, window=window, work=work,
                                interpret=False)
 
-    text = _compile(call, sds((slots, hq, 128), jnp.bfloat16), pool, pool,
-                    sds((slots, mb), jnp.int32), sds((slots,), jnp.int32),
-                    sds((slots,), jnp.bool_))
-    (kernel,) = [l for l in text.splitlines()
+    compiled = jax.jit(call).lower(
+        sds((slots, hq, 128), jnp.bfloat16), pool, pool,
+        sds((slots, mb), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots,), jnp.bool_)).compile()
+    (kernel,) = [l for l in compiled.as_text().splitlines()
                  if 'custom_call_target="tpu_custom_call"' in l]
     assert "tadnn_paged_decode_folded" in kernel.split(" = ")[0]
-    assert f"s32[{slots},{mb}]" in kernel.split(" = ", 1)[1]
+    operands = _operand_shapes(kernel)
+    assert f"s32[{slots},{mb}]" in operands
+    # each pool ONE operand, left where it lies: the kernel copies an item's
+    # pages itself, into buffers that fit the default VMEM limit (no
+    # ``vmem_limit_bytes``) at olmo-hybrid-7b-pp2's page of 3,840 lanes too
+    assert operands.count(f"bf16[{pool.shape[0]},16,{kvh * 128}]") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**22
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
@@ -572,11 +586,11 @@ def test_folded_paged_decode_compiles_for_v5e(v5e, config, window):
 def test_latent_paged_decode_compiles_for_v5e(v5e, dtype):
     """The latent kernel at the cell's shape: 32 heads, 24 slots of 544
     pages of 64 rows stored in 640 lanes (512 + 64 numbers and zeros), 8
-    page copies a grid step, its grid a work list of traced length; in
-    serving's bfloat16 and with ``chip_smoke.py``'s float32 queries.  The
-    pool reaches the kernel as it lies: no copy of it among the
-    temporaries (rows of 576 did get one: the chip's layout for such an
-    array puts another axis in the lanes)."""
+    page copies a grid step made by the kernel itself, its grid a work list
+    of traced length; in serving's bfloat16 and with ``chip_smoke.py``'s
+    float32 queries.  The pool reaches the kernel as it lies, ONE operand:
+    no copy of it among the temporaries (rows of 576 did get one: the
+    chip's layout for such an array puts another axis in the lanes)."""
     from torch_automatic_distributed_neural_network_tpu.ops.paged_attention import (
         folded_work_list,
         latent_pages,
@@ -600,7 +614,9 @@ def test_latent_paged_decode_compiles_for_v5e(v5e, dtype):
     (kernel,) = [l for l in compiled.as_text().splitlines()
                  if 'custom_call_target="tpu_custom_call"' in l]
     assert "tadnn_paged_decode_latent" in kernel.split(" = ")[0]
-    assert kernel.count("bf16[4097,64,640]") >= 8
+    operands = _operand_shapes(kernel)
+    assert operands.count("bf16[4097,64,640]") == 1
+    assert f"s32[{slots},{mb}]" in operands
     assert compiled.memory_analysis().temp_size_in_bytes < 2**24
 
 

@@ -392,9 +392,10 @@ def test_latent_kernel_matches_the_dense_form(case):
 
 
 def test_latent_kernel_is_named_and_reads_a_page_once():
-    """One ``pallas_call`` named ``tadnn_paged_decode_latent`` whose page
-    operands are the ONE pool array (8 pages of 64 tokens an item at the
-    cell's block size), its grid the traced ``n_items``."""
+    """One ``pallas_call`` named ``tadnn_paged_decode_latent`` that takes the
+    ONE pool array ONCE (the kernel copies an item's 8 pages of 64 tokens
+    itself into a ``[2, 8, 64, F]`` buffer; no value pages beside them), its
+    grid the traced ``n_items``."""
     S, Hq, row = 3, 4, 24
     pool = jnp.zeros((S * 24 + 1, 64, 128), jnp.float32)
     tables = jnp.zeros((S, 24), jnp.int32)
@@ -409,7 +410,128 @@ def test_latent_kernel_is_named_and_reads_a_page_once():
     assert "tadnn_paged_decode_latent" in str(call.params)
     pools = [v for v in call.invars if getattr(v.aval, "shape", None)
              == pool.shape]
-    assert len(pools) == 8  # 512 keys a step, no value pages beside them
+    assert len(pools) == 1
+    assert "f32[2,8,64,128]" in str(call.params["jaxpr"])
+    assert call.params["grid_mapping"].num_dynamic_grid_bounds == 1
+
+
+# -- both MXU kernels fetch an item's pages themselves: the copies' order ------
+
+# name: (latent, query heads, kv heads, contexts, running slots or None for
+# all, window).  Folded: 128 keys an item, max_len 384; latent: 512, 1,536.
+_FETCH_CASES = {
+    "one_item": (False, 4, 2, [100], None, None),
+    "one_item_latent": (True, 6, None, [300], None, None),
+    "list_fills_its_arrays": (False, 4, 2, [_MAX - 1] * 3, None, None),
+    "list_fills_its_arrays_latent": (True, 6, None, [_LMAX - 1] * 3, None,
+                                     None),
+    "idle_slots_between_running": (False, 4, 2, [383, 0, 200, 0, 0, 300],
+                                   [0, 2, 5], None),
+    "idle_slots_between_running_latent": (True, 6, None,
+                                          [1535, 0, 600, 0, 0, 1100],
+                                          [0, 2, 5], None),
+    "window": (False, 4, 2, [300, 50, 383, 129], None, 100),
+    "group_edges": (False, 4, 2, [127, 128, 255, 256, 0], None, None),
+    "group_edges_latent": (True, 6, None, [511, 512, 1023, 1024, 0], None,
+                           None),
+    "gqa_48_on_8": (False, 48, 8, [3, 130, 383], None, None),
+    "mha_16_on_16_window": (False, 16, 16, [3, 130, 383], None, 200),
+    "latent_row_of_640_lanes": (True, 8, None, [700, 15], None, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FETCH_CASES))
+def test_mxu_kernels_fetch_their_pages_without_a_race(case, capfd):
+    """Both entries under the TPU interpreter that runs a copy only when it
+    is waited for, fills unwritten memory with NaN and follows every read and
+    write with a vector clock: item w + 1's copies are started before item
+    w's are waited for, the first item's in a prologue, none past the list.
+    A copy that is not waited for before its buffer is read leaves NaN, one
+    that lands in a buffer still read is a race; pages outside the listed
+    groups are NaN too.  The one item of a slot that does not run holds the
+    null block alone and is skipped, fetch and arithmetic.  Then with every
+    copy run as it is started: a copy nobody waits for (one started past the
+    list's end) leaves its semaphore counted up when the kernel ends, which
+    the interpreter prints."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call as tpu
+    from jax.experimental.pallas import tpu as pltpu
+
+    from torch_automatic_distributed_neural_network_tpu.ops.paged_attention \
+        import (
+            folded_work_list,
+            latent_attention_reference,
+            latent_pages,
+        )
+
+    latent, Hq, kvH, ctxs, running, window = _FETCH_CASES[case]
+    S = len(ctxs)
+    running = list(range(S)) if running is None else running
+    rs = np.random.RandomState(len(case))
+    if latent:
+        bs, mb, pages = _LBS, _LMB, latent_pages(_LMB, _LBS)
+        lanes, row, value = (640, 576, 512) if "640" in case else (128, 24, 20)
+        pools = [np.zeros((S * mb + 1, bs, lanes), np.float32)]
+        pools[0][..., :row] = rs.randn(S * mb + 1, bs, row)
+        q = jnp.asarray(rs.randn(S, Hq, row), jnp.float32)
+    else:
+        bs, mb, pages, hd = _BS, _MB, 8, 32
+        pools = [rs.randn(S * mb + 1, bs, kvH * hd).astype(np.float32)
+                 for _ in range(2)]
+        q = jnp.asarray(rs.randn(S, Hq, hd), jnp.float32)
+    keys = pages * bs
+    tables = 1 + rs.permutation(S * mb).reshape(S, mb).astype(np.int32)
+    want_items = 0
+    for s, c in enumerate(ctxs):
+        lo, hi = 0, 0
+        if s in running:
+            hi = c // keys
+            lo = 0 if window is None else max(c - window + 1, 0) // keys
+        want_items += hi - lo + 1
+        for pool in pools:
+            pool[tables[s, [j for j in range(mb)
+                            if not lo <= j // pages <= hi]]] = np.nan
+        # the engine's table: the null block past the newest key, and in
+        # every entry of a slot that does not run (``programs._step_shared``)
+        tables[s, (c // bs + 1) * (s in running):] = 0
+    for pool in pools:
+        pool[0] = 0.0
+    ctx = jnp.asarray(ctxs, jnp.int32)
+    active = jnp.asarray([s in running for s in range(S)])
+    work = folded_work_list(ctx, active, max_blocks=mb, block_size=bs,
+                            window=window, pages=pages)
+    assert int(work.n_items) == want_items
+    if "fills" in case:
+        assert want_items == work.dense == work.slot_of.shape[0] - 1
+    pools, tables = [jnp.asarray(pool) for pool in pools], jnp.asarray(tables)
+
+    def run(interpret):
+        if latent:
+            return paged_attention(
+                q, pools[0], jnp.zeros((0,), jnp.float32), tables, ctx,
+                scale=0.3, value_dim=value, work=work, interpret=interpret)
+        return paged_attention(q, *pools, tables, ctx, window=window,
+                               work=work, interpret=interpret)
+
+    got = run(pltpu.InterpretParams(detect_races=True))
+    assert not tpu.races.races_found
+    if latent:
+        dense = jnp.nan_to_num(pools[0])[tables].reshape(S, mb * bs, lanes)
+        want = latent_attention_reference(q[:, None], dense, ctx, scale=0.3,
+                                          value_dim=value)[:, 0]
+    else:
+        want = paged_attention_reference(
+            q, *[jnp.nan_to_num(pool) for pool in pools], tables, ctx,
+            window=window)
+    np.testing.assert_allclose(np.asarray(got)[running],
+                               np.asarray(want)[running], atol=1e-5)
+    # an item of null pages alone is neither fetched nor multiplied (its
+    # buffer would be read unwritten, NaN here): its row is zeros
+    idle = [s for s in range(S) if s not in running]
+    assert not np.asarray(got)[idle].any()
+    capfd.readouterr()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(
+        run(pltpu.InterpretParams(dma_execution_mode="eager"))))
+    assert "non-zero count" not in capfd.readouterr().out
 
 
 # -- the chunk kernel: a prompt chunk's queries over the slot's latent pages ----

@@ -26,8 +26,9 @@ value at once, ``[NB, bs, kv_rank + rope]``, and no second array) is read
 by ``tadnn_paged_decode_latent``, the folded kernel's body at other
 numbers: see "the latent kernel" below.  A pool of folded pages is read by the
 MXU kernel (``tadnn_paged_decode_folded``, further down: grouped queries
-as two plain matmuls, 8 pages a grid step, the grid a list of the key
-groups the slots have, of traced length).  A page kept as ``[bs, kvH, hd]``
+as two plain matmuls, 8 pages a grid step that the kernel copies itself,
+the grid a list of the key groups the slots have, of traced length).  A
+page kept as ``[bs, kvH, hd]``
 is read by the VPU kernel (``tadnn_paged_decode``), which dequantizes int8
 pages on load and runs per shard under ``shard_map``; the rest of this text
 describes it.
@@ -351,6 +352,43 @@ def _paged_attention_local(
 # in slot order and ascending group order inside a slot, its length a traced
 # value.  A slot with nothing to attend keeps one item, its group 0, so that
 # every output row is written.
+#
+# An item's pages are FETCHED BY THE KERNEL (PR 44): each pool is one
+# operand left in HBM, and at item w the body starts item w + 1's copies
+# (one ``make_async_copy`` a page a pool, the page read from the table in
+# scalar memory) into the other half of a ``[2, pages, bs, F]`` buffer,
+# then waits ONCE a pool for item w's, started a step ago.  As ``pages``
+# ``BlockSpec`` operands a pool the pipeline round the body cost 0.046 us
+# an operand a step on the scalar core (index map, compare, conditional
+# descriptor, wait) and the bytes hid under THAT: us an item on the chip,
+# the kernel alone, one layer (my chip runs, PR 44; ``same``: every table
+# entry one page, which the pipeline does not copy again; ``stub``: no
+# matmuls; bit for bit the same output in every row):
+#
+#   shape (an item's bytes)           pipeline  same   stub  both | by hand  stub
+#   trinity-large-ep8 48/8 (0.64 us)    1.426  1.426  1.166 1.165 |  0.964  0.827
+#   gpt2-1p3b 16/16       (1.28 us)    1.599  1.502  1.550 1.144 |  1.531  1.538
+#   olmo-hybrid-7b 30/30  (2.40 us)    2.650  1.745  2.647 1.158 |  2.647  2.642
+#   latent 32 heads, 8 x 64 (0.80 us)  1.197  1.162  0.980 0.747 |  1.151  0.966
+#
+# Where the starts stand: at the top of the body; after the wait they cost
+# 0.24 us an item more (the copies then begin behind the semaphore's wait
+# and no longer under the whole item).  A wait a copy or one a buffer, the
+# starts under a branch or unconditional (the last item fetching itself
+# again), a buffer ``[2, pages * bs, F]`` or a page a leading index: all
+# within 0.5% of each other.  What is left by hand is a step's own latency,
+# about 0.68 us whatever it fetches (4 | 8 | 16 pages a step: 0.823 | 0.964
+# | 1.489 us on trinity's shape, 0.794 | 1.151 | 1.830 on the latent one):
+# twice the keys a step would be 23% and 20% less a key there and nothing
+# on the two shapes whose bytes bind (``PERF.md`` section 7.4(a)).  One
+# thing the pipeline did in silence has to be said by hand: it did not copy
+# a block index that repeats, and the table holds the null block wherever a
+# slot has no key to read, so an idle slot's item cost no bytes.  By hand
+# every page is a copy (gpt2-1p3b's steady cell, one or two of 8 slots
+# running, read its token gaps 1-3% longer), so an item whose entries are
+# ALL the null block is skipped, fetch and arithmetic: a layer's call with
+# 1 of 8 slots running 52.1 (pipeline) | 52.5 | 50.2 us, and 0.7% dearer
+# where no slot idles.
 
 FOLD_PAGES = 8  # pages a grid step takes (128 keys at 16 a page)
 
@@ -416,15 +454,49 @@ def _folded_kernel(tables_ref, ctx_ref, first_ref, last_ref, slot_ref,
                    group_ref, q_ref, *refs, pages: int, bs: int,
                    window: int | None, scale: float,
                    value_dim: int | None = None):
-    """``value_dim``: a latent page, read ONCE: its row is the key and its
-    first ``value_dim`` numbers are the value (no value pages among
-    ``refs``)."""
-    del tables_ref
-    n_in = pages if value_dim else 2 * pages
-    k_refs, v_refs = refs[:pages], refs[pages:n_in]
-    o_ref, acc_ref, m_ref, l_ref = refs[n_in:]
-    w = pl.program_id(0)
+    """One item of the work list.  ``refs``: the pools as they lie in HBM
+    (key pages and value pages; ``value_dim``: ONE pool of latent pages,
+    whose row is the key and whose first ``value_dim`` numbers are the
+    value), the output block, the float32 sums, then a ``[2, pages, bs, F]``
+    buffer a pool and the DMA semaphores ``[pools, 2]``.  The kernel fetches
+    its pages itself: item w + 1's copies are started before item w's are
+    waited for, so they run under item w's matmuls; every copy started is
+    waited for in the same call, and none is started past the list.  An
+    item whose table entries are all the null block is skipped whole (an
+    idle slot's: its row is written as zeros)."""
+    n_pools = 1 if value_dim else 2
+    pools = refs[:n_pools]
+    o_ref, acc_ref, m_ref, l_ref = refs[n_pools:n_pools + 4]
+    bufs, sem = refs[n_pools + 4:-1], refs[-1]
+    w, n_items = pl.program_id(0), pl.num_programs(0)
     s, g = slot_ref[w], group_ref[w]
+
+    def page_ids(item):
+        si, first_page = slot_ref[item], group_ref[item] * pages
+        return [tables_ref[si, first_page + i] for i in range(pages)]
+
+    def holds_a_page(ids):
+        # the table has the null block (0) wherever a slot has no key to
+        # read: an idle slot's one item is all of it, and is neither
+        # fetched nor multiplied (through ``BlockSpec``s a block index that
+        # repeats was not copied again; by hand it would be).  Any other
+        # item holds a key its slot attends, or is masked whole and leaves
+        # the sums zero
+        return functools.reduce(jnp.bitwise_or, ids) != 0
+
+    def fetch(item, b):
+        ids = page_ids(item)
+
+        @pl.when(holds_a_page(ids))
+        def _start():
+            for i, page in enumerate(ids):
+                for p in range(n_pools):
+                    pltpu.make_async_copy(pools[p].at[page], bufs[p].at[b, i],
+                                          sem.at[p, b]).start()
+
+    pl.when(w == 0)(lambda: fetch(0, 0))
+    # (the list's arrays hold one entry more than the list: PR 30's fault)
+    pl.when(w + 1 < n_items)(lambda: fetch(w + 1, (w + 1) % 2))
 
     @pl.when(g == first_ref[s])
     def _init():
@@ -432,45 +504,90 @@ def _folded_kernel(tables_ref, ctx_ref, first_ref, last_ref, slot_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_BIG)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    ctx = ctx_ref[s]
-    start = g * (pages * bs)
+    @pl.when(holds_a_page(page_ids(w)))
+    def _attend():
+        ctx = ctx_ref[s]
+        start = g * (pages * bs)
+        b = w % 2
+        for p in range(n_pools):  # ONE wait a buffer: its pages' bytes, summed
+            pltpu.make_async_copy(bufs[p].at[b], bufs[p].at[b],
+                                  sem.at[p, b]).wait()
 
-    # every item holds a key its slot attends, but for the one item of a
-    # slot with nothing to attend: all of it masked, its sums stay zero
-    k = jnp.concatenate([r[0] for r in k_refs], axis=0)  # [keys, F]
-    v = (k[:, :value_dim] if value_dim
-         else jnp.concatenate([r[0] for r in v_refs], axis=0))
-    q = q_ref[0]  # [Hq, F], block-diagonal (a latent query fills its row)
-    exact = None
-    if q.dtype == jnp.float32:  # float32 queries ask for float32 math:
-        # operands AND products (a float32 matmul is one bfloat16 pass by
-        # default, 1e-3 of the result: my chip run, PR 30)
-        k, v = k.astype(jnp.float32), v.astype(jnp.float32)
-        exact = jax.lax.Precision.HIGHEST
-    sc = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), precision=exact,
-        preferred_element_type=jnp.float32) * scale  # [Hq, keys]
-    pos = start + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-    valid = pos <= ctx
-    if window is not None:
-        valid = jnp.logical_and(valid, pos > ctx - window)
-    sc = jnp.where(valid, sc, _NEG_BIG)
-    m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-    m_new = jnp.maximum(m_new, _NEG_BIG / 2)
-    p = jnp.exp(sc - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
-        p.astype(v.dtype), v, precision=exact,
-        preferred_element_type=jnp.float32)
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        k = bufs[0][b].reshape(pages * bs, -1)  # [keys, F]
+        v = (k[:, :value_dim] if value_dim
+             else bufs[1][b].reshape(pages * bs, -1))
+        q = q_ref[0]  # [Hq, F], block-diagonal (a latent query fills its row)
+        exact = None
+        if q.dtype == jnp.float32:  # float32 queries ask for float32 math:
+            # operands AND products (a float32 matmul is one bfloat16 pass by
+            # default, 1e-3 of the result: my chip run, PR 30)
+            k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+            exact = jax.lax.Precision.HIGHEST
+        sc = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=exact,
+            preferred_element_type=jnp.float32) * scale  # [Hq, keys]
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        valid = pos <= ctx
+        if window is not None:
+            valid = jnp.logical_and(valid, pos > ctx - window)
+        sc = jnp.where(valid, sc, _NEG_BIG)
+        m_prev, l_prev = m_ref[:, :1], l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m_new, _NEG_BIG / 2)
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(
+            p.astype(v.dtype), v, precision=exact,
+            preferred_element_type=jnp.float32)
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(g == last_ref[s])
     def _finish():
         o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
                     ).astype(o_ref.dtype)
+
+
+def _fetching_call(q, pools, tables, ctx_lens, work: WorkList, *, pages: int,
+                   out_width: int, interpret, **kernel):
+    """``_folded_kernel`` over ``work``: ``q`` [S, Hq, F] and the output
+    [S, Hq, out_width] are blocks that follow an item's slot; each of
+    ``pools`` [NB, bs, F] is ONE operand left where it lies; ``tables`` [S,
+    MB] is padded to whole groups of ``pages``."""
+    S, Hq, F = q.shape
+    bs = pools[0].shape[1]
+    tables = jnp.pad(tables.astype(jnp.int32),
+                     ((0, 0), (0, -tables.shape[1] % pages)))
+
+    def rows(width):
+        return pl.BlockSpec((1, Hq, width), lambda w, t, c, f, l, so, go: (
+            so[w], 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(work.n_items,),
+        in_specs=[rows(F)] + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+        out_specs=rows(out_width),
+        scratch_shapes=[
+            pltpu.VMEM((Hq, out_width), jnp.float32),
+            pltpu.VMEM((Hq, _LANES), jnp.float32),
+            pltpu.VMEM((Hq, _LANES), jnp.float32),
+            *[pltpu.VMEM((2, pages, bs, F), pool.dtype) for pool in pools],
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_folded_kernel, pages=pages, bs=bs, **kernel),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, Hq, out_width), q.dtype),
+        # item w + 1's pages land while item w runs: the items in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=("tadnn_paged_decode_latent" if kernel.get("value_dim")
+              else "tadnn_paged_decode_folded"),
+    )(tables, ctx_lens, *work[:4], q, *pools)
 
 
 def paged_attention_folded(
@@ -502,7 +619,6 @@ def paged_attention_folded(
     G = Hq // kvH
     MB = tables.shape[1]
     pages = _fold(MB, bs, window)[0]
-    tables = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, -MB % pages)))
     ctx_lens = ctx_lens.astype(jnp.int32)
     if work is None:
         work = folded_work_list(ctx_lens, max_blocks=MB, block_size=bs,
@@ -511,33 +627,10 @@ def paged_attention_folded(
     own = jnp.asarray(np.arange(Hq)[:, None] // G == np.arange(kvH)[None, :],
                       q.dtype)
     qf = jnp.einsum("shd,hk->shkd", q, own).reshape(S, Hq, F)
-
-    def page(i):
-        return pl.BlockSpec((1, bs, F), lambda w, t, c, f, l, so, go: (
-            t[so[w], go[w] * pages + i], 0, 0))
-
-    wide = pl.BlockSpec((1, Hq, F), lambda w, t, c, f, l, so, go: (
-        so[w], 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(work.n_items,),
-        in_specs=[wide] + [page(i) for i in range(pages)] * 2,
-        out_specs=wide,
-        scratch_shapes=[
-            pltpu.VMEM((Hq, F), jnp.float32),
-            pltpu.VMEM((Hq, _LANES), jnp.float32),
-            pltpu.VMEM((Hq, _LANES), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_folded_kernel, pages=pages, bs=bs, window=window,
-                          scale=1.0 / float(np.sqrt(hd))),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, Hq, F), q.dtype),
-        interpret=interpret,
-        name="tadnn_paged_decode_folded",
-    )(tables, ctx_lens, *work[:4], qf,
-      *([k_pool] * pages), *([v_pool] * pages))
+    out = _fetching_call(
+        qf, (k_pool, v_pool), tables, ctx_lens, work, pages=pages,
+        out_width=F, interpret=interpret, window=window,
+        scale=1.0 / float(np.sqrt(hd)))
     # float32 here too (the default rounds this sum over the 0/1 ``own``)
     return jnp.einsum("shkd,hk->shd", out.reshape(S, Hq, kvH, hd), own,
                       precision="highest" if q.dtype == jnp.float32 else None)
@@ -552,10 +645,13 @@ def paged_attention_folded(
 # need no block-diagonal form, and the arithmetic a byte is ten times the
 # grouped-query kernel's: ``Hq (F + value_dim) 2`` operations over ``2 F``
 # bytes, 60 FLOP/B at 32 heads, still under the v5e's 240.  What it costs
-# is again its grid: a folded step (128 keys) costs 0.56-0.84 us beside its
-# bytes (my chip runs, PR 30), and 128 latent keys are 147 KB, 0.18 us of
-# HBM time.  So a step takes ``LATENT_KEYS`` keys (590 KB, 0.72 us), in as
-# few page copies as the pool's block size allows.
+# is again its grid: a step costs about 0.7 us of its own whatever it
+# fetches (the table above), and 128 latent keys are 164 KB, 0.20 us of
+# HBM time.  So a step takes ``LATENT_KEYS`` keys (655 KB in rows of 640
+# lanes, 0.80 us), in as few page copies as the pool's block size allows:
+# 1.15 us an item by hand at 24 slots x 32 heads x 5.5k keys (1.20 through
+# the pipeline; 1.19 | 1.25 at 64 heads), of it the matmuls' 0.19 that the
+# copies do not cover (my chip runs, PR 44).
 
 
 LATENT_KEYS = 512  # keys a grid step takes
@@ -587,44 +683,18 @@ def paged_attention_latent(
     which ``LatentAttention.lift`` takes to the heads' outputs."""
     if interpret is None:
         interpret = _default_interpret()
-    S, Hq, _ = q.shape
-    NB, bs, F = pool.shape
+    _, bs, F = pool.shape
     q = jnp.pad(q, ((0, 0), (0, 0), (0, F - q.shape[2])))
     MB = tables.shape[1]
     pages = latent_pages(MB, bs)
-    tables = jnp.pad(tables.astype(jnp.int32), ((0, 0), (0, -MB % pages)))
     ctx_lens = ctx_lens.astype(jnp.int32)
     if work is None:
         work = folded_work_list(ctx_lens, max_blocks=MB, block_size=bs,
                                 pages=pages)
-
-    def page(i):
-        return pl.BlockSpec((1, bs, F), lambda w, t, c, f, l, so, go: (
-            t[so[w], go[w] * pages + i], 0, 0))
-
-    def rows(width):
-        return pl.BlockSpec((1, Hq, width), lambda w, t, c, f, l, so, go: (
-            so[w], 0, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(work.n_items,),
-        in_specs=[rows(F)] + [page(i) for i in range(pages)],
-        out_specs=rows(value_dim),
-        scratch_shapes=[
-            pltpu.VMEM((Hq, value_dim), jnp.float32),
-            pltpu.VMEM((Hq, _LANES), jnp.float32),
-            pltpu.VMEM((Hq, _LANES), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_folded_kernel, pages=pages, bs=bs, window=None,
-                          scale=float(scale), value_dim=value_dim),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, Hq, value_dim), q.dtype),
-        interpret=interpret,
-        name="tadnn_paged_decode_latent",
-    )(tables, ctx_lens, *work[:4], q, *([pool] * pages))
+    return _fetching_call(
+        q, (pool,), tables, ctx_lens, work, pages=pages, out_width=value_dim,
+        interpret=interpret, window=None, scale=float(scale),
+        value_dim=value_dim)
 
 
 # -- the chunk kernel: a prompt chunk's queries over the slot's latent pages ------
