@@ -34,3 +34,35 @@ def devices8():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 simulated devices, got {devs}"
     return devs
+
+
+# -- compiling for a described chip (``test_chip_compile_*.py``) -------------
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Four described v5e devices (no hardware)."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu on this machine
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    return topo.devices
+
+
+@pytest.fixture
+def _no_compile_cache():
+    """A described-device executable can be written to the persistent
+    cache but not read back without a chip (the next compile warns and
+    redoes it), so the cache stays off around these compiles (a module
+    asks for it with ``pytestmark = pytest.mark.usefixtures``)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()

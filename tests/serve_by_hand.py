@@ -116,3 +116,24 @@ class Served:
         for pos in range(n_prompt, len(seq)):
             out[pos] = self.decode({slot: seq[pos]})[slot]
         return out
+
+
+def chunk_alone(eng):
+    """``programs.prefill_chunk`` at ``eng``'s sizes, jitted as a
+    speculative or a tenant engine holds it (operands:
+    ``eng._abstract_prefill_args()``)."""
+    cfg, max_blocks = eng.cfg, eng.max_blocks
+
+    def serve_prefill_chunk(*operands):
+        return programs.prefill_chunk(*operands, cfg=cfg,
+                                      max_blocks=max_blocks)
+
+    return jax.jit(serve_prefill_chunk, donate_argnums=(1,))
+
+
+def as_two_calls(eng):
+    """A plain engine made to run its chunk and its step as two calls: the
+    fused program taken away and the chunk alone put beside the step (the
+    twin that a fused engine's tokens are compared with)."""
+    eng._fused_fn, eng._prefill_fn = None, chunk_alone(eng)
+    return eng

@@ -368,15 +368,14 @@ def test_unsupported_options_are_refused_at_construction():
     """What a model with sliding layers or expert layers is not served
     with, each for its own reason (the message gives it): prefix reuse
     needs a finished prompt's keys to stay, and a ring has written over
-    them; a ring has room for one chunk, not a whole prompt; the adapter
-    pool factorizes a scanned stack."""
+    them; the adapter pool factorizes a scanned stack."""
     from torch_automatic_distributed_neural_network_tpu.training.lora import (
         LoraSpec,
     )
 
     model = _model(KEYS)
     variables = {"params": weights.nest(_params(KEYS))}
-    for bad in ({"prefix_cache": True}, {"prefill_chunk": None},
+    for bad in ({"prefix_cache": True},
                 {"lora_spec": LoraSpec(rank=2, alpha=4.0)}):
         with pytest.raises(ValueError, match=next(iter(bad))):
             ServeEngine(model, variables, n_slots=2, max_len=64,
@@ -400,14 +399,13 @@ def _engine_tokens(flat, **kw):
 
 
 @pytest.mark.parametrize("option", [
-    "speculative", "disaggregate", "dense", "chunk_of_no_whole_pages",
+    "speculative", "dense", "chunk_of_no_whole_pages",
     "quant_kv", "export_cache"])
 def test_engine_options_serve_a_model_with_layer_kinds(option, tmp_path):
     """The options of the shared programs on a model whose layers differ:
     each serves the reference's first choice at every position (int8 KV:
     a choice within its quantization error of the first)."""
     kw = {"speculative": {"speculative": 2},
-          "disaggregate": {"disaggregate": True},
           "dense": {"attention_impl": "dense"},
           # a page of 8 and a chunk of 4: written a token at a time
           "chunk_of_no_whole_pages": {"block_size": 8},
@@ -427,8 +425,6 @@ def test_engine_options_serve_a_model_with_layer_kinds(option, tmp_path):
         assert regret.max() <= limit, (option, regret.max())
     if option == "speculative":
         assert eng.spec_drafted > 0
-    if option == "disaggregate":
-        assert eng.pool.transferred_blocks > 0
     if option == "quant_kv":
         assert all(set(leaf) == {"q", "scale"} for leaf in eng.pool.kv["k"])
     if option == "export_cache":

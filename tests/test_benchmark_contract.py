@@ -592,8 +592,8 @@ def test_a_kernel_the_benchmark_tells_by_name_is_a_pallas_call(kernel):
 def test_a_program_the_benchmark_names_is_a_serving_program(dense, program):
     eng = dense["eng"]
     heads = [eng.compiled_decode_text().splitlines()[0],
-             eng._prefill_fn.lower(
-                 *eng._abstract_prefill_args()).as_text().splitlines()[0]]
+             eng._fused_fn.lower(
+                 *eng._abstract_fused_args()).as_text().splitlines()[0]]
     names = set(re.findall(r"jit_serve_[a-z0-9_]+", " ".join(heads)))
     assert program in names, (
         f"the engine's programs are {sorted(names)}, not {program}; named "

@@ -49,7 +49,7 @@ def test_alias_resolution_and_names_for():
 
 def test_registry_markdown_lists_kinds_and_aliases():
     md = schema.registry_markdown()
-    assert "| `serve.request_done` | 2 |" in md
+    assert "| `serve.request_done` | 3 |" in md
     assert "`serve.request`" in md  # the alias note
     assert "`gateway.replan`" in md
 

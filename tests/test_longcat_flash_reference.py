@@ -628,13 +628,11 @@ def _regret(flat, req) -> float:
 
 
 SHAPES = [(5, 20), (23, 30), (14, 17), (30, 8), (3, 3), (41, 12)]
-SERVED = {"chunked": {}, "single_shot": {"prefill_chunk": None},
+SERVED = {"chunked": {},
           "optimistic": {"admission": "optimistic"},
           "dense": {"attention_impl": "dense"},
-          "disaggregate": {"disaggregate": True},
           "speculative": {"speculative": 2},
-          "prefix_cache": {"prefix_cache": True},
-          "two_chunks_a_step": {"prefill_chunks_per_step": 2}}
+          "prefix_cache": {"prefix_cache": True}}
 
 
 @pytest.mark.parametrize("option", sorted(SERVED))
@@ -657,7 +655,7 @@ def test_engine_serves_the_references_first_choice(option, tmp_path):
         assert len(r.out_tokens) == m
         assert _regret(flat, r) <= ATOL, (option, n, m)
     steps = journal.named("serve.step")
-    fuses = option not in ("single_shot", "disaggregate", "speculative")
+    fuses = option != "speculative"
     assert (sum(s.get("fused", 0) for s in steps) > 3) == fuses
     # the two new counters on every call that read a step, whether its
     # rows rode in a chunk (the call laid the chunk's tiles) or not; the

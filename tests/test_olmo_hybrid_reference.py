@@ -382,19 +382,17 @@ SHAPES = [(5, 20), (23, 30), (14, 17), (30, 8), (3, 3), (41, 12)]
 
 
 @pytest.mark.parametrize("option", [
-    "chunked", "single_shot", "quant_kv", "optimistic", "dense",
-    "disaggregate"])
+    "chunked", "quant_kv", "optimistic", "dense"])
 def test_engine_serves_the_references_first_choice(option, tmp_path):
     """The engine itself, scheduler and all: six requests over three slots
     (slots are reused, chunks and decode steps interleave, the last chunks
     are padded), each served token the reference's first choice at its
     position (int8 KV on the full layers: within its quantization error of
     the first)."""
-    kw = {"chunked": {}, "single_shot": {"prefill_chunk": None},
+    kw = {"chunked": {},
           "quant_kv": {"quant_kv": True},
           "optimistic": {"admission": "optimistic"},
-          "dense": {"attention_impl": "dense"},
-          "disaggregate": {"disaggregate": True}}[option]
+          "dense": {"attention_impl": "dense"}}[option]
     flat = _params()
     journal = Journal(None, host0_only=False)
     eng = _engine(flat, journal, **kw)
@@ -435,8 +433,8 @@ def test_a_preempted_request_restarts_and_serves_the_same_tokens():
     flat = _params()
     shapes = [(20, 30), (22, 28), (18, 30)]
     alone = []
+    eng = _engine(flat)  # one engine, a request at a time: each alone in it
     for i, (n, m) in enumerate(shapes):
-        eng = _engine(flat)
         r = eng.submit([int(t) for t in _tokens(n, 40 + i)], max_new_tokens=m)
         eng.run()
         alone.append(r.out_tokens)

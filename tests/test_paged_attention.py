@@ -8,8 +8,8 @@ sweep covers block sizes {8, 16}, fp and int8 KV pools, ragged slot
 lengths, sliding windows, GQA, and inactive (null-table) slots.  The
 engine-level tests pin token parity between ``attention_impl="paged"``
 and ``"dense"`` through real serving traffic — including a
-preempted-then-recomputed request — and chunked-vs-single-shot prefill
-parity through the same slots.
+preempted-then-recomputed request — and chunked prefill against
+``generate()``'s one forward over the prompt, through the same slots.
 """
 
 import jax
@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from torch_automatic_distributed_neural_network_tpu.inference import generate
 from torch_automatic_distributed_neural_network_tpu.inference.quant import (
     quantize_kv,
 )
@@ -263,19 +264,27 @@ def test_engine_paged_matches_dense_tokens(quant_kv):
     assert got_p == got_d
 
 
-def test_engine_chunked_prefill_matches_single_shot():
+def test_engine_chunked_prefill_matches_generate():
     """A prompt streamed in [1, C] chunks must emit the same tokens as
-    the legacy single-shot prefill — and a chunk that doesn't divide
-    the prompt exercises the padded final chunk."""
+    ``generate()``, whose prefill is one forward over the whole prompt —
+    and a chunk that doesn't divide the prompt exercises the padded final
+    chunk.  (Float32 caches on both sides: greedy tokens to the last.)"""
     model, variables = _model_and_vars()
     rs = np.random.RandomState(4)
     prompts = [[int(t) for t in rs.randint(1, VOCAB, size=(p,))]
                for p in (5, 13, 16)]
-    single, _ = _serve(model, variables, prompts, prefill_chunk=None)
+    whole = []
+    for p in prompts:
+        seq, lengths = generate(
+            model, variables, jnp.asarray(p, jnp.int32)[None, :],
+            max_new_tokens=6, eos_id=0, cache_dtype=jnp.float32,
+            early_stop=True, return_lengths=True)
+        whole.append([int(t) for t in np.asarray(
+            seq[0, len(p):int(lengths[0])])])
     for chunk in (8, 32):
         chunked, eng = _serve(model, variables, prompts,
-                              prefill_chunk=chunk)
-        assert chunked == single, (chunk, chunked, single)
+                              prefill_chunk=chunk, cache_dtype=jnp.float32)
+        assert chunked == whole, (chunk, chunked, whole)
         assert eng.prefill_chunk == chunk  # divides max_len: no snap
 
 

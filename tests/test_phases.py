@@ -40,6 +40,8 @@ from torch_automatic_distributed_neural_network_tpu.training import (
     softmax_xent_loss,
 )
 
+from serve_by_hand import as_two_calls, chunk_alone
+
 VOCAB = 128
 
 
@@ -218,7 +220,7 @@ def _chunky_run(traffic=((21, 9), (12, 6), (27, 4)), fused=True,
                       prefill_chunk=8, journal=j, export_cache=False,
                       **engine_kw)
     if not fused:
-        eng._fused_fn = None
+        as_two_calls(eng)
     sent = {"_step_fn": [], "_prefill_fn": [], "_fused_fn": []}
     for name, calls in sent.items():
         fn = getattr(eng, name)
@@ -449,8 +451,6 @@ def test_serving_programs_are_named(served):
     eng, _, _ = served
     assert eng.compiled_decode_text().startswith(
         "HloModule jit_serve_decode_step")
-    text = eng._prefill_fn.lower(*eng._abstract_prefill_args()).as_text()
-    assert "module @jit_serve_prefill_chunk" in text.splitlines()[0]
     # the chunk that carries a step's decode rows is read under the chunk's
     # name: it is a chunk with more rows
     text = eng._fused_fn.lower(*eng._abstract_fused_args()).as_text()
@@ -499,9 +499,12 @@ def kind_engines():
     return out
 
 
-PROGRAM_ARGS = {"decode_step": ("_step_fn", "_abstract_decode_args"),
-                "prefill_chunk": ("_prefill_fn", "_abstract_prefill_args"),
-                "chunk_and_step": ("_fused_fn", "_abstract_fused_args")}
+# (the chunk alone is no program of these engines: as a speculative or a
+# tenant engine holds it)
+PROGRAM_ARGS = {
+    "decode_step": (lambda e: e._step_fn, "_abstract_decode_args"),
+    "prefill_chunk": (chunk_alone, "_abstract_prefill_args"),
+    "chunk_and_step": (lambda e: e._fused_fn, "_abstract_fused_args")}
 
 
 @pytest.mark.parametrize("program", sorted(PROGRAM_ARGS))
@@ -517,7 +520,7 @@ def test_a_programs_parts_are_scoped_by_name(kind_engines, kind, program):
 
     eng = kind_engines[kind]
     fn, args = PROGRAM_ARGS[program]
-    text = getattr(eng, fn).lower(*getattr(eng, args)()).as_text(
+    text = fn(eng).lower(*getattr(eng, args)()).as_text(
         debug_info=True)
     # a component of an op's name: ``"jit(..)/tadnn.head/dot_general"``, and
     # inside a layer's own function ``"tadnn.mix_in/LayerNorm/sub"``
@@ -688,34 +691,6 @@ def test_report_lists_a_stall_with_the_collector_beside_it(tmp_path):
             "1400.0 ms inside them (1 full pass(es)); the longest, step 7, "
             "took 1512.0 ms where its kind (decode) takes 12.00, most of "
             "it in emit") in text
-
-
-@pytest.mark.parametrize("speculative", [0, 2])
-def test_single_shot_prefill_is_not_timed_as_admit(speculative):
-    """With ``prefill_chunk=None`` the forward is the ``prefill_dispatch``
-    phase of the admitting step (the prompt lands in the request's pages as
-    it runs: no commit).  Its first token is waited for apart only where
-    the engine reads before it dispatches; otherwise it comes with the
-    step's one read."""
-    j = Journal(None, validate=True, host0_only=False)
-    model = GPT2("test", vocab_size=VOCAB, max_seq_len=64,
-                 dtype=jnp.float32, remat=False)
-    variables = model.init(jax.random.key(1), jnp.ones((1, 12), jnp.int32))
-    eng = ServeEngine(model, variables, n_slots=2, max_len=64,
-                      block_size=8, prefill_chunk=None, journal=j,
-                      speculative=speculative, export_cache=False)
-    eng.submit(_prompt(9), max_new_tokens=3)
-    eng.run()
-    first = j.named("serve.step")[0]
-    assert {"admit", "prefill_dispatch", "decode_wait"} <= set(
-        first["phases"])
-    assert ("prefill_first_token" in first["phases"]) == bool(speculative)
-    # the forward compiles inside prefill_dispatch: admit is the
-    # scheduler's bookkeeping and stays far below it
-    assert first["compiles"] > 0
-    assert first["phases"]["admit"] < first["phases"]["prefill_dispatch"]
-    assert sum(first["phases"].values()) <= first["step_s"]
-    assert first["n_prefill_chunks"] == 0 and first["prefill_s"] == 0.0
 
 
 # -- the timeline: a profiler capture on the CPU -----------------------------

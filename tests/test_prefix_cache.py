@@ -287,17 +287,6 @@ def test_prefix_cache_bitwise_parity_int8_kv(devices8):
 
 
 @pytest.mark.slow
-def test_prefix_cache_bitwise_parity_disaggregated(devices8):
-    # disaggregated publish happens at KV-ship time, not commit
-    shared, uniques = _mix(seed=5)
-    on = _run_engine(shared, uniques, prefix_cache=True,
-                     disaggregate=True)
-    off = _run_engine(shared, uniques, prefix_cache=False,
-                      disaggregate=True)
-    assert on == off
-
-
-@pytest.mark.slow
 def test_prefix_cache_parity_under_preemption(devices8):
     # optimistic admission over a tight pool: preempted requests
     # recompute through the cache (their republished blocks may even

@@ -42,6 +42,8 @@ from torch_automatic_distributed_neural_network_tpu.training.lora import (
     LoraSpec,
 )
 
+from serve_by_hand import chunk_alone
+
 VOCAB = 128
 
 
@@ -637,7 +639,7 @@ def test_fuzz_scheduler_matches_pure_functions():
             assert len(admitted) == planned, ctx
             for _slot, req in admitted:
                 req.state = "prefilling"  # chunked-prefill mode
-            budget = [1, 2, None][int(rs.randint(3))]
+            budget = [1, 2, 3][int(rs.randint(3))]
             prefilling = [(r.t_admit, s)
                           for s, r in enumerate(sched.slots)
                           if r is not None and r.state == "prefilling"]
@@ -788,7 +790,7 @@ def _base_program_operands(model, variables, program):
     kv = jax.tree.map(
         lambda x: jnp.asarray(rs.normal(size=x.shape), x.dtype), eng.pool.kv)
     if program == "prefill_chunk":  # the second chunk of a prompt, 6 real
-        return eng._prefill_fn.__wrapped__, (
+        return chunk_alone(eng).__wrapped__, (
             kv, programs.pack_chunk(
                 [1, 2] + [0] * 6, rs.randint(1, VOCAB, size=(8,)), 8, 5),
             eng.pool.win_tables[0])
@@ -1276,9 +1278,9 @@ def test_nothing_compiles_after_the_warm_up_across_admissions():
     assert sum(s["compiles"] for s in later) == 0
     assert eng._step_fn._cache_size() == 1 == eng._first_fn._cache_size()
     # every chunk, alone or with the step's decode rows, is one program,
-    # and the chunk alone of an engine that cannot fuse was never built
+    # and the engine holds no chunk alone beside it
     assert eng._fused_fn._cache_size() == 1
-    assert eng._prefill_fn._cache_size() == 0 < eng.fused_steps
+    assert eng._prefill_fn is None and eng.fused_steps > 0
 
 
 def test_work_list_kernel_serves_the_dense_paths_tokens_without_a_compile():

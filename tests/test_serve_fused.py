@@ -1,10 +1,11 @@
 """A step's decode rows ride in its prefill chunk: ONE program for both
-(``programs.chunk_and_step``) wherever a colocated, non-speculative engine
-without tenants has a chunk to run and a slot decoding.
+(``programs.chunk_and_step``) wherever a non-speculative engine without
+tenants has a chunk to run and a slot decoding.
 
 What may not change is what is served: the tokens of the chunk and the step
-as two calls (here the same engine with the fused program taken away, the
-path every other engine runs), for a GPT-2-shaped model, a model of sliding
+as two calls (here the same engine with the fused program taken away and
+the chunk alone in its place, the path a speculative or a tenant engine
+runs), for a GPT-2-shaped model, a model of sliding
 and full layers with held experts, and one with ``linear_attention``
 layers.  What moves: a prompt whose last chunk carried decode rows starts
 decoding in the next call, with the first token that call's logits gave.
@@ -33,6 +34,7 @@ from torch_automatic_distributed_neural_network_tpu.training.lora import (
     LoraSpec,
 )
 
+from serve_by_hand import as_two_calls
 from test_serve import VOCAB, _f32_engine, _greedy, _model_and_vars, _prompts
 
 # a model of sliding and full layers, rotary on the sliding ones, a dense
@@ -98,7 +100,7 @@ def _serve(models, family, *, fused=True, journal=None, **kw):
         "n_slots": 3, "max_len": 64, "cache_dtype": jnp.float32,
         "export_cache": False, **own, **kw})
     if not fused:
-        eng._fused_fn = None  # the chunk and the step as two calls
+        as_two_calls(eng)
     rs = np.random.RandomState(11)
     reqs = [eng.submit([int(t) for t in rs.randint(1, vocab, size=n)],
                        max_new_tokens=m)
@@ -111,13 +113,29 @@ def _serve(models, family, *, fused=True, journal=None, **kw):
     return eng, reqs, waiting
 
 
+@pytest.fixture(scope="module")
+def served(models):
+    """``served(family, fused)``: a family's requests through its engine (or
+    its two-call twin), served ONCE for the cases that only read the run:
+    ``_serve``'s three and the journal, the invariants audited every step."""
+    made = {}
+
+    def get(family, fused=True):
+        if (family, fused) not in made:
+            journal = Journal(None, validate=True, host0_only=False)
+            with pytest.MonkeyPatch.context() as env:
+                env.setenv("TADNN_DEBUG_INVARIANTS", "1")
+                made[family, fused] = (*_serve(
+                    models, family, fused=fused, journal=journal), journal)
+        return made[family, fused]
+
+    return get
+
+
 @pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_a_fused_step_serves_the_two_calls_tokens(
-        models, family, monkeypatch):
-    monkeypatch.setenv("TADNN_DEBUG_INVARIANTS", "1")
-    journal = Journal(None, validate=True, host0_only=False)
-    eng, reqs, waiting = _serve(models, family, journal=journal)
-    twin, want, twin_waiting = _serve(models, family, fused=False)
+def test_a_fused_step_serves_the_two_calls_tokens(served, family):
+    eng, reqs, waiting, journal = served(family)
+    twin, want, twin_waiting, _ = served(family, fused=False)
     assert [r.out_tokens for r in reqs] == [r.out_tokens for r in want]
     assert all(len(r.out_tokens) == m for r, m in zip(reqs, MAX_NEW))
     assert twin.fused_steps == 0 == twin.fused_decode_rows
@@ -155,7 +173,7 @@ def test_a_fused_step_serves_the_two_calls_tokens(
 
 
 @pytest.mark.parametrize("fused", [True, False], ids=["fused", "two_calls"])
-def test_live_expert_tiles_are_counted_on_every_call(models, fused, tmp_path):
+def test_live_expert_tiles_are_counted_on_every_call(served, fused, tmp_path):
     """``moe_tiles_active`` rides with every step's tokens, a fused step's
     too, beside the tiles that step laid out (``serve.engine`` states both
     kinds of call's); the schema names them and ``tadnn report`` prints
@@ -168,9 +186,7 @@ def test_live_expert_tiles_are_counted_on_every_call(models, fused, tmp_path):
         expert_tiles,
     )
 
-    journal = Journal(None, validate=True, host0_only=False)
-    eng, _, _ = _serve(models, "sliding_full_experts", fused=fused,
-                       journal=journal)
+    eng, _, _, journal = served("sliding_full_experts", fused)
     steps = journal.named("serve.step")
     engine, = journal.named("serve.engine")
     path = tmp_path / "journal.jsonl"
@@ -204,14 +220,12 @@ def test_live_expert_tiles_are_counted_on_every_call(models, fused, tmp_path):
     assert f"{live / n:.1f} of {laid} (" in text and f"over {n} calls" in text
 
 
-@pytest.mark.parametrize("case", ["int8_kv", "sampled", "two_chunks_a_step",
-                                  "dense_attention"])
+@pytest.mark.parametrize("case", ["int8_kv", "sampled", "dense_attention"])
 def test_fused_steps_under_the_engines_other_options(
         models, case, monkeypatch):
-    """Options that change what a row reads or how many chunks a call runs:
-    the fused call follows them through the same entries (``paged_attention``
-    for an int8 pool's VPU kernel, the dense gather, ``_sample`` under the
-    step's key), and with two chunks a call the last one carries the rows."""
+    """Options that change what a row reads: the fused call follows them
+    through the same entries (``paged_attention`` for an int8 pool's VPU
+    kernel, the dense gather, ``_sample`` under the step's key)."""
     import itertools
 
     from torch_automatic_distributed_neural_network_tpu.inference.decode import (
@@ -223,7 +237,6 @@ def test_fused_steps_under_the_engines_other_options(
 
     kw = {"int8_kv": dict(quant_kv=True),
           "sampled": dict(sample=SampleConfig(temperature=0.8)),
-          "two_chunks_a_step": dict(prefill_chunks_per_step=2),
           "dense_attention": dict(attention_impl="dense")}[case]
     got = []
     for fused in (True, False):
@@ -234,10 +247,6 @@ def test_fused_steps_under_the_engines_other_options(
                               **kw)
         got.append([r.out_tokens for r in reqs])
         assert bool(eng.fused_steps) == fused
-        if fused and case == "two_chunks_a_step":
-            both = [s for s in journal.named("serve.step")
-                    if s["n_prefill_chunks"] == 2]
-            assert both and any(s["fused"] for s in both)
     if case == "sampled":
         # the same keys: a step's, whichever program the rows are in, and
         # a request's own for its first token.  A prompt that ends in a
@@ -256,24 +265,17 @@ def _tenant_engine(**kw):
         "cache_dtype": jnp.float32, "export_cache": False, **kw})
 
 
-@pytest.mark.parametrize("engine", ["speculative", "tenant", "disaggregated",
-                                    "single_shot", "routed_toy_experts"])
+@pytest.mark.parametrize("engine", ["speculative", "tenant"])
 def test_engines_that_cannot_fuse_run_the_two_calls(engine):
     """Read off the engine's own state: a verify step has 1 + k rows a
-    slot, a tenant's rows add deltas the chunk's must not see, a prefill
-    slice is another chip's, a single-shot prompt has a shape of its own,
-    and capacity routing depends on which rows it is handed.  None builds
-    the fused program, none runs a fused step, and each serves what it
-    served before: the greedy tokens."""
+    slot, a tenant's rows add deltas the chunk's must not see.  Neither
+    builds the fused program, neither runs a fused step, and each serves
+    what it served before: the greedy tokens."""
     journal = Journal(None, validate=True, host0_only=False)
     make = {"speculative": lambda: _f32_engine(journal, speculative=2),
-            "tenant": lambda: _tenant_engine(journal=journal),
-            "disaggregated": lambda: _f32_engine(journal, disaggregate=True),
-            "single_shot": lambda: _f32_engine(journal, prefill_chunk=None),
-            "routed_toy_experts": lambda: _f32_engine(
-                journal, moe_decode="routed")}[engine]
+            "tenant": lambda: _tenant_engine(journal=journal)}[engine]
     eng = make()
-    assert eng._fused_fn is None
+    assert eng._fused_fn is None and eng._prefill_fn is not None
     prompts = _prompts((5, 21, 12, 16, 9))
     reqs = [eng.submit(p, max_new_tokens=7) for p in prompts]
     eng.run()
@@ -355,15 +357,14 @@ def test_a_copy_on_write_fork_in_a_fused_step():
     assert other.out_tokens == _greedy(second, 4)
 
 
-def test_report_prints_the_fused_shares(tmp_path, models):
+def test_report_prints_the_fused_shares(tmp_path, served):
     import json
 
     from torch_automatic_distributed_neural_network_tpu.obs import (
         report as obs_report,
     )
 
-    journal = Journal(None, validate=True, host0_only=False)
-    eng, _reqs, _ = _serve(models, "gpt2", journal=journal)
+    eng, _reqs, _, journal = served("gpt2")
     path = tmp_path / "journal.jsonl"
     path.write_text("".join(json.dumps(r) + "\n" for r in journal.records))
     rep = obs_report.generate(str(path))
@@ -376,8 +377,7 @@ def test_report_prints_the_fused_shares(tmp_path, models):
     text = obs_report.format_report(rep)
     assert "carried the decode rows" in text
     # an engine that ran none prints no such line
-    journal = Journal(None, validate=True, host0_only=False)
-    _serve(models, "gpt2", fused=False, journal=journal)
+    journal = served("gpt2", fused=False)[3]
     path.write_text("".join(json.dumps(r) + "\n" for r in journal.records))
     rep = obs_report.generate(str(path))
     assert "fused_steps" not in rep["serving"]

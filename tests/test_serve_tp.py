@@ -1,22 +1,14 @@
-"""Disaggregated and TP-sharded serving parity pins (ISSUE 13).
+"""TP-sharded serving parity pins (ISSUE 13) on the 8-device CPU sim.
 
-Two token-parity families on the 8-device CPU sim:
-
-- **disaggregated == colocated**: splitting prefill onto its own slice
-  changes the TIME model only — the phases touch disjoint state (temp
-  prefill caches vs the paged pool), so every request must emit exactly
-  the same greedy tokens, including through optimistic-admission
-  preemption and recompute;
-- **TP == unsharded**: shard_map-ing the paged kernel, KV pool and
-  adapter pool over a 2-device tensor axis is a pure re-layout of the
-  same arithmetic (attention is kv-head-parallel, adapter b factors
-  split the channels the projection already splits), so kernel outputs
-  and engine tokens must match the single-device run — fp and int8 KV,
-  GQA, adapters included.
+**TP == unsharded**: shard_map-ing the paged kernel, KV pool and adapter
+pool over a 2-device tensor axis is a pure re-layout of the same
+arithmetic (attention is kv-head-parallel, adapter b factors split the
+channels the projection already splits), so kernel outputs and engine
+tokens must match the single-device run — fp and int8 KV, GQA, adapters
+included.
 
 Plus the capacity-lint fix (serve_estimate charges adapter + KV pool
-per TP shard) and the discrete-event replay's disaggregated mode
-(ship accounting, max-vs-sum wall, DCN pricing knobs).
+per TP shard) and the discrete-event replay's DCN tax on a decode step.
 """
 
 import jax
@@ -141,66 +133,6 @@ def test_kernel_indivisible_heads_falls_back(devices8):
     assert float(jnp.max(jnp.abs(got - want))) == 0.0
 
 
-# -- engine: disaggregated == colocated ---------------------------------------
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("attention_impl", ["paged", "dense"])
-def test_disaggregated_matches_colocated(devices8, attention_impl):
-    model, variables = _model_and_vars()
-    prompts = _prompts()
-    base, _ = _serve(model, variables, prompts,
-                     attention_impl=attention_impl)
-    dis, eng = _serve(model, variables, prompts,
-                      attention_impl=attention_impl, disaggregate=True)
-    assert dis == base
-    # every finished prefill shipped its blocks exactly once, and the
-    # scheduler accrued the same counters the pool did
-    assert eng.pool.n_transfers == len(prompts)
-    assert eng.scheduler.n_kv_ships == len(prompts)
-    assert eng.scheduler.shipped_blocks == eng.pool.transferred_blocks > 0
-    assert (eng.pool.transferred_bytes
-            == eng.pool.transferred_blocks * eng.pool.bytes_per_block)
-
-
-@pytest.mark.slow
-def test_disaggregated_matches_colocated_int8_kv(devices8):
-    model, variables = _model_and_vars()
-    prompts = _prompts(seed=5)
-    base, _ = _serve(model, variables, prompts, quant_kv=True)
-    dis, _ = _serve(model, variables, prompts, quant_kv=True,
-                    disaggregate=True)
-    assert dis == base
-
-
-@pytest.mark.slow
-def test_disaggregated_preempted_then_recomputed_parity(devices8):
-    """Optimistic admission over a too-small pool forces preempt +
-    recompute; the recomputed prefill re-ships and the tokens still
-    match the colocated run exactly."""
-    model, variables = _model_and_vars()
-
-    def run(disaggregate):
-        eng = ServeEngine(model, variables, n_slots=4, max_len=32,
-                          block_size=8, num_blocks=10,
-                          admission="optimistic", prefill_chunk=8,
-                          disaggregate=disaggregate)
-        for _ in range(4):
-            eng.submit([3] * 12, max_new_tokens=12, eos_id=None)
-        done = eng.run()
-        eng.scheduler.check_invariants()
-        return done, eng
-
-    base_done, _ = run(False)
-    dis_done, eng = run(True)
-    assert eng.scheduler.n_preemptions > 0
-    assert ([r.out_tokens for r in sorted(base_done, key=lambda r: r.rid)]
-            == [r.out_tokens for r in sorted(dis_done, key=lambda r: r.rid)])
-    # a preempted request prefills (and ships) more than once
-    assert eng.pool.n_transfers > 4
-    assert eng.pool.allocator.n_free == 9  # zero leaked blocks
-
-
 # -- engine: TP=2 == unsharded ------------------------------------------------
 
 
@@ -220,8 +152,7 @@ def test_engine_tp2_matches_unsharded(devices8, quant_kv):
 @pytest.mark.slow
 def test_engine_tp2_with_adapters_matches_unsharded(devices8):
     """TP=2 with a sharded adapter pool (b factors split over the
-    tensor axis), fp32 and int8 factors, disaggregated on top — all
-    token-identical to the plain single-device engine."""
+    tensor axis), fp32 and int8 factors — all token-identical to the plain single-device engine."""
     model, variables = _model_and_vars()
     spec = LoraSpec(rank=4)
     adapters = [(f"t{i}", random_adapter(variables["params"], spec,
@@ -234,8 +165,7 @@ def test_engine_tp2_with_adapters_matches_unsharded(devices8):
                          quant_adapters=quant_adapters)
         tp, eng = _serve(model, variables, prompts, adapters=adapters,
                          spec=spec, n_adapters=4,
-                         quant_adapters=quant_adapters, mesh=mesh,
-                         disaggregate=True)
+                         quant_adapters=quant_adapters, mesh=mesh)
         assert tp == base, f"quant_adapters={quant_adapters}"
         # the wide factor really landed sharded
         b = eng.adapter_pool.factors["q"]["b"]
@@ -290,70 +220,17 @@ def test_serve_estimate_tp_shard_clears_ml006():
     assert f4 == []
 
 
-# -- replay: disaggregated mode -----------------------------------------------
+# -- replay: the DCN tax -----------------------------------------------------
 
 
-def _flat_requests(n=6, prompt=32, max_new=16, decode=16):
-    return [(0.0, prompt, max_new, decode) for _ in range(n)]
-
-
-def test_replay_disaggregate_overlaps_phases():
-    """Same traffic, same step costs: the disaggregated wall is the
-    per-step max of the phases, so it must land strictly under the
-    colocated sum whenever both phases are busy — with identical token
-    and scheduling outcomes."""
-    reqs = _flat_requests()
+def test_replay_prices_dcn():
+    """A tp group that spans slices pays ``dcn_step_s`` on every decode
+    step: the same tokens, a longer wall."""
+    reqs = [(0.0, 32, 16, 16) for _ in range(4)]
     kw = dict(n_slots=4, block_size=8, max_len=64, prefill_chunk=8,
               decode_step_s=1e-3, prefill_chunk_s=1e-3)
-    co = replay_serve(reqs, **kw)
-    di = replay_serve(reqs, disaggregate=True, **kw)
-    assert not co["disaggregate"] and di["disaggregate"]
-    assert di["n_finished"] == co["n_finished"] == len(reqs)
-    assert di["new_tokens"] == co["new_tokens"]
-    assert di["wall_s"] < co["wall_s"]
-    assert di["kv_ships"] == len(reqs)
-    assert di["shipped_blocks"] == len(reqs) * 4  # 32 tokens / 8-blocks
-    assert co["kv_ships"] == 0
-    # busy time is conserved: overlap hides it, never deletes it
-    assert di["decode_busy_s"] == pytest.approx(
-        co["decode_busy_s"], rel=0.2)
-
-
-def test_replay_prices_kv_ship_and_dcn():
-    reqs = _flat_requests(n=4)
-    kw = dict(n_slots=4, block_size=8, max_len=64, prefill_chunk=8,
-              decode_step_s=1e-3, prefill_chunk_s=1e-3)
-    base = replay_serve(reqs, disaggregate=True, **kw)
-    shipped = replay_serve(reqs, disaggregate=True, kv_ship_s=5e-3, **kw)
-    taxed = replay_serve(reqs, dcn_step_s=5e-4, **kw)
-    # the ship charge lands on the prefill side, the DCN tax on decode
-    assert shipped["prefill_busy_s"] == pytest.approx(
-        base["prefill_busy_s"] + 4 * 5e-3)
-    assert shipped["wall_s"] > base["wall_s"]
     untaxed = replay_serve(reqs, **kw)
-    assert taxed["decode_busy_s"] > untaxed["decode_busy_s"]
+    taxed = replay_serve(reqs, dcn_step_s=5e-4, **kw)
+    assert taxed["new_tokens"] == untaxed["new_tokens"]
+    assert taxed["steps"] == untaxed["steps"]
     assert taxed["wall_s"] > untaxed["wall_s"]
-
-
-def test_simulate_policy_disaggregate_beats_colocated(devices8):
-    """End-to-end sweep: on the same single-slice fleet the
-    disaggregated policy cannot serve fewer tok/s than colocated (the
-    step wall is max instead of sum, and nothing else changes)."""
-    import dataclasses
-
-    from torch_automatic_distributed_neural_network_tpu.tune.simulate \
-        import SimulatePolicy, simulate
-
-    model, variables = _model_and_vars()
-    abstract = jax.eval_shape(lambda: variables["params"])
-    pol = SimulatePolicy(slots=4, max_len=64, block_size=8,
-                         admissions=("reserve",), slicings=(1,),
-                         grad_accums=(1,), use_cache=False, top_k=4)
-    co = simulate(abstract, ["v5e-8"], model_cfg=model.cfg, policy=pol)
-    di = simulate(abstract, ["v5e-8"], model_cfg=model.cfg,
-                  policy=dataclasses.replace(pol, disaggregate=True))
-    tok = {p["plan"]: p["tok_s_per_chip"] for p in co["predictions"]
-           if p["tok_s_per_chip"] is not None}
-    for p in di["predictions"]:
-        if p["tok_s_per_chip"] is not None and p["plan"] in tok:
-            assert p["tok_s_per_chip"] >= tok[p["plan"]] - 1e-6

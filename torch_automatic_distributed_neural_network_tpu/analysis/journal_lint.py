@@ -735,7 +735,7 @@ def produce(j, rid):
     j.event("serve.request_done", rid=rid, n_prompt=7, n_new=3,
             queue_s=0.0, total_s=0.5, tokens_per_s=6.0, preempted=0,
             ttft_s=0.1, itl_s=[0.01, 0.02], prefill_s=0.1, decode_s=0.4,
-            itl_mean_s=0.015, kv_ship_s=None, cached_tokens=0,
+            itl_mean_s=0.015, cached_tokens=0,
             prefill_chunks=1, prefill_compute_s=0.1, lost_s=0.0,
             replica="r0")
     with j.span("ckpt.wait", sharded=True):

@@ -30,10 +30,11 @@ def serve_trace_check(
     block_size: int = 8,
     quant_kv: bool = False,
     attention_impl: str = "paged",
-    prefill_chunk: int | None = 32,
+    prefill_chunk: int = 32,
     compute_dtype: Any = None,
 ) -> tuple[list[Finding], dict]:
-    """Build a ServeEngine and lint its decode + prefill traces.
+    """Build a ServeEngine and lint the two traces it holds: the decode
+    step and the chunk that carries a step's decode rows.
 
     Returns ``(findings, stats)`` where ``stats`` carries per-trace
     equation/collective counts for the JSON output.  The engine is
@@ -81,6 +82,5 @@ def serve_trace_check(
 
     # the exact operand tuples _export_compiled feeds eval_shape
     lint_one("decode", eng._step_fn, eng._abstract_decode_args())
-    if eng.prefill_chunk:
-        lint_one("prefill", eng._prefill_fn, eng._abstract_prefill_args())
+    lint_one("prefill", eng._fused_fn, eng._abstract_fused_args())
     return findings, stats

@@ -400,7 +400,6 @@ def cmd_simulate(args: argparse.Namespace) -> int:
             max_len=int(getattr(args, "max_len", None) or 256),
             prefill_chunk=(int(getattr(args, "prefill_chunk", None) or 32)
                            or None),
-            disaggregate=bool(getattr(args, "disaggregate", False)),
             prefix_cache=bool(getattr(args, "prefix_cache", False)),
             measured_overlap=measured_overlap,
             preemption_rate_per_h=float(
@@ -1128,7 +1127,7 @@ def cmd_serve(args: argparse.Namespace) -> int:
             block_size=args.block_size or 16,
             quant_kv=args.quant_kv,
             attention_impl=args.attention_impl,
-            prefill_chunk=args.prefill_chunk or None,
+            prefill_chunk=args.prefill_chunk,
             admission=args.admission,
             lora_spec=lora_spec,
             # +1: slot 0 is the identity adapter
@@ -1136,7 +1135,6 @@ def cmd_serve(args: argparse.Namespace) -> int:
             quant_adapters=args.quant_adapters,
             speculative=args.speculative,
             mesh=mesh,
-            disaggregate=bool(getattr(args, "disaggregate", False)),
             prefix_cache=bool(getattr(args, "prefix_cache", False)),
             journal=jnl,
         )
@@ -1197,7 +1195,6 @@ def cmd_serve(args: argparse.Namespace) -> int:
             "spec_accept_rate": (
                 round(eng.spec_accepted / eng.spec_drafted, 4)
                 if eng.spec_drafted else None),
-            "disaggregate": eng.disaggregate,
             "prefix_cache": eng.prefix_cache is not None,
             "prefix_hit_rate": (
                 round(eng.prefix_cached_tokens
@@ -1212,12 +1209,6 @@ def cmd_serve(args: argparse.Namespace) -> int:
             "cow_forks": (eng.cow_forks
                           if eng.prefix_cache is not None else None),
             "tp": serve_tp,
-            "kv_ships": eng.pool.n_transfers,
-            "shipped_blocks": eng.pool.transferred_blocks,
-            "shipped_bytes": eng.pool.transferred_bytes,
-            "prefill_busy_s": round(eng.prefill_busy_s, 4),
-            "decode_busy_s": round(eng.decode_busy_s, 4),
-            "overlapped_wall_s": round(eng.overlapped_wall_s, 4),
             "journal": args.journal,
         }
     print(json.dumps(summary))
@@ -1665,11 +1656,6 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--prefill-chunk", type=int, default=32,
                    dest="prefill_chunk",
                    help="chunked-prefill size (0 = single-shot prefill)")
-    p.add_argument("--disaggregate", action="store_true",
-                   help="simulate disaggregated prefill/decode serving "
-                        "replicas: prefill on its own slice, KV blocks "
-                        "shipped over DCN on multislice fleets, step "
-                        "wall = max(prefill, decode)")
     p.add_argument("--prefix-cache", action="store_true",
                    dest="prefix_cache",
                    help="price cross-request prefix reuse in the replay "
@@ -1850,8 +1836,7 @@ def main(argv: list[str] | None = None) -> int:
                         "gather_blocks reference path")
     p.add_argument("--prefill-chunk", type=int, default=32,
                    dest="prefill_chunk",
-                   help="chunked-prefill chunk size (0 = legacy "
-                        "single-shot prefill)")
+                   help="chunked-prefill chunk size")
     p.add_argument("--admission", default="reserve",
                    choices=("reserve", "optimistic"),
                    help="block admission policy (scheduler.py)")
@@ -1869,20 +1854,13 @@ def main(argv: list[str] | None = None) -> int:
                    default=0, metavar="K",
                    help="speculative decoding with K n-gram draft "
                         "tokens per step (bare flag = 4; greedy only)")
-    p.add_argument("--disaggregate", action="store_true",
-                   help="disaggregated prefill/decode: prefill runs as "
-                        "a dedicated worker loop (uncapped chunks per "
-                        "step), finished KV blocks ship into decode "
-                        "slots through the pool, and decode steps no "
-                        "longer interleave prefill; token-identical to "
-                        "colocated")
     p.add_argument("--prefix-cache", action="store_true",
                    dest="prefix_cache",
                    help="cross-request prefix reuse: radix-index full "
                         "prompt blocks by chained content hash; admitted "
                         "requests skip prefill over their cached prefix "
                         "(copy-on-write blocks, token-identical to "
-                        "cache-off; needs --prefill-chunk)")
+                        "cache-off)")
     p.add_argument("--shared-prefix", type=int, default=0,
                    dest="shared_prefix", metavar="N",
                    help="draw the first N prompt tokens once and share "
@@ -1893,7 +1871,7 @@ def main(argv: list[str] | None = None) -> int:
                    metavar="N",
                    help="tensor-parallel degree: shard KV-pool / "
                         "adapter-pool heads and the paged decode kernel "
-                        "over the first N devices (kv_heads % N == 0 "
+                        "over the first N devices (kv_heads %% N == 0 "
                         "to shard the kernel)")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--journal", default=None,

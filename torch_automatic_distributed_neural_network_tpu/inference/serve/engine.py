@@ -6,8 +6,8 @@ to a running request or inactive (null block table, masked sampling).
 Each call advances EVERY active request by one token; between calls the
 scheduler evicts finished requests and admits queued ones, so the step
 executable compiles once and runs for the life of the server — no
-recompiles as the request mix churns (prefill is the only shape-varying
-entry point, one trace per distinct prompt length).
+recompiles as the request mix churns (a prompt streams through one
+fixed-shape chunk program, whatever its length).
 
 The programs are ``programs.decode_step``, ``programs.prefill_chunk``
 and ``programs.chunk_and_step`` (``serve/programs.py``): the same three for
@@ -32,12 +32,12 @@ of such an engine runs in its own place as the SAME program with every
 decode row idle (null tables, the null row: a few rows more in each
 product), so the engine builds two programs and not three: a third cost
 2 s of every start-up, an idle row costs microseconds of a prompt's first
-chunks.  An engine that is ``disaggregate``d (the chunk is another chip's),
-speculative (a verify step has 1 + k rows a slot), built with ``lora_spec``
-(the decode rows add their tenants' deltas), single-shot (a prompt's shape
-is its own) or ``moe_decode="routed"`` (capacity routing depends on the
-rows it is handed) runs the chunk and the step that were there, as two
-calls, always.  ``serve.step`` carries ``fused`` and ``fused_decode_rows``.
+chunks.  An engine fuses unless a step's rows are not one token a slot
+off the base weights: a speculative engine (a verify step has 1 + k rows a
+slot) and one built with ``lora_spec`` (a tenant's rows add its delta) hold
+``prefill_chunk`` (or its tenant form) beside the decode step instead and
+run the two as two calls.  ``serve.step`` carries ``fused`` and
+``fused_decode_rows``.
 
 Prefill writes a prompt's keys and values straight into the request's
 blocks and attends through its table: the same math as ``generate()``'s
@@ -45,14 +45,11 @@ prefill over the same stored values, which is what makes token-parity
 with sequential generation testable (greedy decoding is deterministic;
 for stochastic sampling the engine is reproducible under its own rng but
 not per-request-identical to ``generate()``, since one categorical call
-samples all slots).  By default prefill is CHUNKED: the prompt streams
-through one jitted [1, C]-chunk trace (C snapped to a divisor of
-max_len), one chunk per engine step per prefilling slot, INTERLEAVED
-with decode — a long prompt no longer stalls every running request for
-its whole prefill, and no per-prompt-length retrace exists.
-``prefill_chunk=None`` is the single-shot prefill: the same program over
-the whole prompt as one chunk at admission (padded to whole pages, one
-trace per distinct padded length).
+samples all slots).  Prefill is CHUNKED: the prompt streams through one
+jitted [1, C]-chunk trace (C = ``prefill_chunk`` snapped to a divisor of
+max_len), one chunk per engine step, INTERLEAVED with decode — a long
+prompt does not stall every running request for its whole prefill, and no
+per-prompt-length retrace exists.
 
 The decode-step attention is config-gated (``attention_impl``):
 ``"paged"`` (default) runs the fused Pallas kernel that reads the
@@ -100,9 +97,8 @@ tokens just produced, so ``speculative > 0`` reads before it dispatches.
 
 Telemetry: every finished request journals a ``serve.request_done``
 event carrying its full span timeline — submit -> admit (queue wait)
--> prefill chunks (prefix-cache skip included) -> KV ship
-(disaggregated) -> first token (TTFT) -> per-token inter-token
-latencies -> preempt/recompute tax -> finish — and every step a
+-> prefill chunks (prefix-cache skip included) -> first token (TTFT)
+-> per-token inter-token latencies -> preempt/recompute tax -> finish — and every step a
 ``serve.step`` event (slot occupancy, free blocks, tokens emitted,
 adapter residency, and ``phases``: the host seconds of each phase of
 the iteration, see ``PHASES``) through ``obs.journal``.  Each phase is
@@ -156,9 +152,8 @@ from .scheduler import Request, Scheduler
 # and for the first tokens of prompts that ended since.  Only an engine
 # that reads before it dispatches (``speculative > 0``) has a
 # ``prefill_first_token``, and its ``decode_wait`` is for this call's step.
-# A single-shot prefill (``prefill_chunk=None``) is one
-# ``prefill_dispatch``, not part of ``admit``.  A prefill lands in the
-# request's pages as it runs: there is no commit to time
+# A prefill lands in the request's pages as it runs: there is no commit
+# to time
 PHASES = ("evict", "admit", "prefill_dispatch", "prefill_first_token",
           "grow", "decode_prepare", "decode_upload",
           "decode_dispatch", "decode_wait", "emit")
@@ -218,22 +213,17 @@ class ServeEngine:
                  cache_dtype=jnp.bfloat16,
                  sample: SampleConfig | None = None,
                  admission: str = "reserve",
-                 moe_decode: str = "dense",
                  attention_impl: str = "paged",
-                 prefill_chunk: int | None = 32,
-                 prefill_chunks_per_step: int = 1,
+                 prefill_chunk: int = 32,
                  lora_spec: LoraSpec | None = None,
                  n_adapters: int = 8,
                  quant_adapters: bool = False,
                  speculative: int = 0,
                  prefix_cache: bool = False,
-                 prefix_ttl_s: float | None = None,
                  mesh=None,
-                 disaggregate: bool = False,
                  rng: jax.Array | None = None,
                  journal: Any = None,
-                 export_cache: Any = None,
-                 export_tags: Any = None):
+                 export_cache: Any = None):
         if attention_impl not in ("paged", "dense"):
             raise ValueError(
                 f"unknown attention_impl {attention_impl!r} "
@@ -249,7 +239,6 @@ class ServeEngine:
         self.sample = sample or SampleConfig(temperature=0.0)
         self.n_slots = n_slots
         self.max_len = max_len
-        self.moe_decode = moe_decode
         self.attention_impl = attention_impl
         self.speculative = int(speculative)
         if self.speculative < 0:
@@ -259,24 +248,14 @@ class ServeEngine:
                 "speculative decoding is greedy-only (the accept rule "
                 "compares against the target's argmax; sampled variants "
                 "need rejection resampling) — use temperature=0.0")
-        if prefill_chunk is not None:
-            # snap the chunk to a divisor of max_len, so the cursor can
-            # never run past it (learned positions past the table's end
-            # would clamp and silently corrupt a chunk's embeddings)
-            prefill_chunk = math.gcd(
-                min(int(prefill_chunk), max_len), max_len)
-        self.prefill_chunk = prefill_chunk
-        self.prefill_chunks_per_step = max(1, int(prefill_chunks_per_step))
+        if prefill_chunk < 1:
+            raise ValueError(f"prefill_chunk={prefill_chunk} must be >= 1")
+        # snap the chunk to a divisor of max_len, so the cursor can never
+        # run past it (learned positions past the table's end would clamp
+        # and silently corrupt a chunk's embeddings)
+        self.prefill_chunk = math.gcd(
+            min(int(prefill_chunk), max_len), max_len)
         self.mesh = mesh
-        # disaggregated mode: prefill runs on its own mesh slice, so a
-        # step's prefill chunks don't serialize with decode — every
-        # prefilling slot advances each step (no chunks-per-step cap),
-        # finished KV is accounted by pool.record_ship, and the step's
-        # modeled wall time is max(prefill, decode) instead of the sum.
-        # Token-identical to colocated: the phases touch disjoint state
-        # (a prefilling slot's pages vs the decoding slots'), so only the
-        # time model changes.
-        self.disaggregate = bool(disaggregate)
         kinds = self.cfg.layer_types or ()
         self._n_linear = list(kinds).count("linear_attention")
         # what is not served, each with its reason (the message names the
@@ -298,9 +277,6 @@ class ServeEngine:
             "speculative > 0 with linear_attention layers (a rejected "
             "draft cannot be taken out of the recurrent state)": (
                 self.speculative > 0 and "linear_attention" in kinds),
-            # a ring has room for ONE chunk beside the window
-            "prefill_chunk=None with sliding_attention layers": (
-                prefill_chunk is None and "sliding_attention" in kinds),
             # the adapter pool factorizes the projections of a scanned
             # ``layers`` stack
             "lora_spec for a model with layer_types": (
@@ -347,7 +323,7 @@ class ServeEngine:
         # prompt-prefix blocks; matched prefixes are ref'd into the new
         # request's table and their chunks skipped (the later chunks
         # attend to the reused blocks through the table, where they lie).
-        # Chunked-prefill only.  Match alignment: block granularity in fp mode; in int8 mode
+        # Match alignment: block granularity in fp mode; in int8 mode
         # additionally snapped to prefill-chunk boundaries, so the
         # cache-off run's chunk partition of the recomputed suffix is
         # reproduced exactly (bit-identical tokens either way).
@@ -356,17 +332,8 @@ class ServeEngine:
         self._gc = _journal.gc_counter()
         self._phases: dict[str, float] = {}  # this step's, by PHASES name
         self._prefix_cache = None
-        # publish lease: prompts enter the radix index with this TTL
-        # (clock units), so stale preambles age out instead of pinning
-        # leaves until pressure eviction; None = no expiry (legacy)
-        self.prefix_ttl_s = prefix_ttl_s
         match_align = None
         if prefix_cache:
-            if prefill_chunk is None:
-                raise ValueError(
-                    "prefix_cache requires chunked prefill "
-                    "(prefill_chunk=None is the legacy single-shot "
-                    "path, which cannot resume from a cached prefix)")
             self._prefix_cache = PrefixCache(
                 block_size=block_size, allocator=self.pool.allocator,
                 journal=self.journal)
@@ -391,12 +358,6 @@ class ServeEngine:
             os.environ.get("TADNN_DEBUG_INVARIANTS", "") not in ("", "0"))
         self._step_count = 0
         self._occupancy_sum = 0.0
-        # per-phase busy time, the bench's per-slice breakdown: what
-        # each slice spent working, and what the steps would cost
-        # end-to-end under the disaggregated overlap model
-        self.prefill_busy_s = 0.0
-        self.decode_busy_s = 0.0
-        self.overlapped_wall_s = 0.0
         self.spec_drafted = 0   # lifetime draft-token counters (k > 0)
         self.spec_accepted = 0
         # lifetime generated-token count; step() diffs it to put a
@@ -450,32 +411,20 @@ class ServeEngine:
         def serve_first_token(*operands):
             return first_token(*operands, sample)
 
-        def serve_prefill_chunk(*operands):
-            return programs.prefill_chunk(
-                *operands, cfg=cfg, max_blocks=max_blocks,
-                moe_decode=moe_decode)
-
-        def serve_prefill_chunk_lora(*operands):
-            return programs.prefill_chunk_lora(
-                *operands, cfg=cfg, max_blocks=max_blocks,
-                moe_decode=moe_decode, lora_spec=lora_spec)
-
         self._step_fn = jax.jit(serve_decode_step, donate_argnums=(1,))
         self._first_fn = jax.jit(serve_first_token)
-        self._prefill_fn = jax.jit(serve_prefill_chunk, donate_argnums=(1,))
-        # a step's chunk may carry its decode rows (``_rides``): the chunk
-        # program of such an engine, which a trace shows under the chunk's
-        # name, since it is a chunk with more rows.  Only where a step is
-        # one token a slot off the base weights on the chip the chunks run
-        # on, and every row's FFN is the same function (the capacity-routed
-        # form of the toy experts depends on which rows it is handed)
-        self._fused_fn = None
-        if (self.prefill_chunk is not None and not self.disaggregate
-                and not self.speculative and lora_spec is None
-                and moe_decode == "dense"):
+        # the engine's chunk program, ONE of two (a trace shows either as
+        # jit_serve_prefill_chunk).  Where a step is one token a slot off
+        # the base weights, the chunk that may carry the step's decode rows
+        # (``_rides``): a chunk with more rows.  A speculative engine (1 + k
+        # rows a slot) and one with tenants (their deltas on their rows)
+        # hold the chunk alone, and run it and the step as two calls (a
+        # tenant's chunk through its own merged weights)
+        self._fused_fn = self._prefill_fn = self._prefill_lora_fn = None
+        if not self.speculative and lora_spec is None:
             chunk = self.prefill_chunk
 
-            def serve_prefill_chunk(*operands):  # noqa: F811
+            def serve_prefill_chunk(*operands):
                 return programs.chunk_and_step(
                     *operands, cfg=cfg, sample=sample, max_blocks=max_blocks,
                     chunk=chunk, attention_impl=attention_impl, mesh=mesh,
@@ -483,23 +432,37 @@ class ServeEngine:
 
             self._fused_fn = jax.jit(serve_prefill_chunk,
                                      donate_argnums=(1,))
+        else:
+            def serve_prefill_chunk(*operands):
+                return programs.prefill_chunk(
+                    *operands, cfg=cfg, max_blocks=max_blocks)
+
+            self._prefill_fn = jax.jit(serve_prefill_chunk,
+                                       donate_argnums=(1,))
+            if lora_spec is not None:
+                def serve_prefill_chunk_lora(*operands):
+                    return programs.prefill_chunk_lora(
+                        *operands, cfg=cfg, max_blocks=max_blocks,
+                        lora_spec=lora_spec)
+
+                self._prefill_lora_fn = jax.jit(serve_prefill_chunk_lora,
+                                                donate_argnums=(2,))
         # the row tiles the expert layers of a call lay out, whatever lands
         # in them (``moe_tiles_active`` of a step counts those): [a call
         # with a chunk, a decode-only call]
         self._tiles_laid = [
             self.cfg.n_expert_layers * expert_tiles(
                 rows, self.cfg.experts_per_token, self.cfg.n_experts_held)[1]
-            if rows and self.cfg.n_expert_layers else 0
-            for rows in ((self.prefill_chunk or 0)
+            if self.cfg.n_expert_layers else 0
+            for rows in (self.prefill_chunk
                          + n_slots * (self._fused_fn is not None),
                          n_slots * (1 + self.speculative))]
         # how a chunk attends, a kind of layer that keeps pages: what the
         # programs pick from the same inputs (``chunk_attention_form``),
         # asked once here; and the layers whose chunk is ONE kernel call,
-        # whose key blocks ``serve.step`` counts (``chunk_key_blocks``).
-        # (A single-shot engine's chunk is as long as its prompt: not said)
+        # whose key blocks ``serve.step`` counts (``chunk_key_blocks``)
         paged = [kind for _, kind, *_ in layer_plan(self.cfg)
-                 if kind != "linear_attention"] * bool(self.prefill_chunk)
+                 if kind != "linear_attention"]
         self.chunk_attention = {
             kind or "full_attention": programs.chunk_attention_form(
                 self.cfg, kind, self.prefill_chunk, block_size)
@@ -514,9 +477,6 @@ class ServeEngine:
         # those rows; step() diffs them onto serve.step
         self.fused_steps = 0
         self.fused_decode_rows = 0
-        self._prefill_lora_fn = (
-            jax.jit(serve_prefill_chunk_lora, donate_argnums=(2,))
-            if lora_spec is not None else None)
         # AOT executable cache (export/): replica spin-up goes
         # cache-first on the two fixed-shape serve traces, so a warm
         # replica deserializes the decode step and the prefill chunk
@@ -527,8 +487,7 @@ class ServeEngine:
         _cache = _export_cache_mod.resolve(export_cache)
         if _cache is not None:
             self._export_compiled(
-                _cache, dict(export_tags or {}),
-                num_blocks=num_blocks, block_size=block_size,
+                _cache, num_blocks=num_blocks, block_size=block_size,
                 quant_kv=bool(quant_kv), cache_dtype=cache_dtype,
                 n_adapters=n_adapters,
                 quant_adapters=bool(quant_adapters))
@@ -549,7 +508,6 @@ class ServeEngine:
             speculative=self.speculative,
             dispatch_ahead=self._ahead,
             prefix_cache=self._prefix_cache is not None,
-            disaggregate=self.disaggregate,
             tp=tensor_degree(mesh),
             # of the tree the base programs take: leaves rounded to the
             # compute dtype at construction, and its bytes by dtype
@@ -593,7 +551,7 @@ class ServeEngine:
         # the counters of the decode step last read (serve.step carries them)
         self._counters: dict[str, int] = {}
 
-    def _export_compiled(self, cache, tags: dict, *, num_blocks: int,
+    def _export_compiled(self, cache, *, num_blocks: int,
                          block_size: int, quant_kv: bool, cache_dtype,
                          n_adapters: int, quant_adapters: bool) -> None:
         """Cache-first AOT for the fixed-shape serve traces (decode step,
@@ -619,7 +577,6 @@ class ServeEngine:
             "block_size": block_size, "num_blocks": num_blocks,
             "attention_impl": self.attention_impl,
             "speculative": self.speculative,
-            "moe_decode": self.moe_decode,
             "quant_kv": quant_kv,
             "cache_dtype": str(np.dtype(cache_dtype)),
             "sample": dataclasses.asdict(self.sample),
@@ -637,17 +594,17 @@ class ServeEngine:
             self._step_fn, self._abstract_decode_args(), cache=cache,
             kind="serve_decode",
             key=export_cache_mod.executable_key(
-                "serve_decode", sig, topo_fp, program, tags))
+                "serve_decode", sig, topo_fp, program))
         if res is not None:
             self._step_fn = aot_mod.ExportedCallable(
                 res.compiled, self._step_fn, "serve_decode")
             self.export_info.append(res.to_json())
-        if self.prefill_chunk and self._fused_fn is None:
+        if self._prefill_fn is not None:
             res = aot_mod.cached_compile(
                 self._prefill_fn, self._abstract_prefill_args(), cache=cache,
                 kind="serve_prefill",
                 key=export_cache_mod.executable_key(
-                    "serve_prefill", sig, topo_fp, program, tags))
+                    "serve_prefill", sig, topo_fp, program))
             if res is not None:
                 self._prefill_fn = aot_mod.ExportedCallable(
                     res.compiled, self._prefill_fn, "serve_prefill")
@@ -657,7 +614,7 @@ class ServeEngine:
                 self._fused_fn, self._abstract_fused_args(), cache=cache,
                 kind="serve_fused",
                 key=export_cache_mod.executable_key(
-                    "serve_fused", sig, topo_fp, program, tags))
+                    "serve_fused", sig, topo_fp, program))
             if res is not None:
                 self._fused_fn = aot_mod.ExportedCallable(
                     res.compiled, self._fused_fn, "serve_fused")
@@ -674,13 +631,11 @@ class ServeEngine:
             jnp.zeros((S, MB + T + 3), jnp.int32), self._out,
             self.pool.win_tables, factors, self._rng))
 
-    def _abstract_prefill_args(self, chunk: int | None = None) -> tuple:
-        """Abstract operands of the base prefill chunk (of
-        ``prefill_chunk`` tokens unless ``chunk`` says otherwise)."""
-        C = chunk or self.prefill_chunk
+    def _abstract_prefill_args(self) -> tuple:
+        """Abstract operands of the base prefill chunk."""
         return jax.eval_shape(lambda: (
             self.params, self.pool.kv,
-            jnp.zeros((self.max_blocks + C + 3,), jnp.int32),
+            jnp.zeros((self.max_blocks + self.prefill_chunk + 3,), jnp.int32),
             self._win_rows[0]))
 
     def _abstract_fused_args(self) -> tuple:
@@ -788,22 +743,8 @@ class ServeEngine:
 
     def _publish_prefill(self, slot: int, req: Request) -> None:
         """A finished prefill: its keys and values already lie in the
-        request's blocks (the chunks wrote them there).  Disaggregated mode
-        accounts the blocks it computed (not a prefix-cache hit's reused
-        ones) as shipped to the decode slice — the block/byte transfer
-        that becomes DCN traffic when the prefill slice is a distinct pod
-        slice — and journals the shipment.  Then the request's full prompt
-        blocks are published into the radix index (for disaggregated
-        serving that IS ship time: a block is only advertised for reuse
-        once it is resident in the decode slice's pool)."""
-        if self.disaggregate:
-            full = blocks_for_tokens(req.n_prompt, self.pool.block_size)
-            n_blocks = full - req.cached_blocks
-            moved = self.pool.record_ship(n_blocks)
-            self.scheduler.record_ship(slot, n_blocks)
-            self.journal.event(
-                "serve.kv_ship", rid=req.rid, slot=slot,
-                n_blocks=n_blocks, bytes=moved)
+        request's blocks (the chunks wrote them there); its full prompt
+        blocks are published into the radix index."""
         if self._prefix_cache is not None:
             # publish every FULL prompt block: decode writes start at
             # position n_prompt, so these rows are immutable (CoW
@@ -811,80 +752,59 @@ class ServeEngine:
             n_pub = req.n_prompt // self.pool.block_size
             new = self._prefix_cache.insert(
                 req.prompt[:n_pub * self.pool.block_size],
-                req.blocks[:n_pub], ttl_s=self.prefix_ttl_s)
+                req.blocks[:n_pub])
             if new:
                 self.journal.event(
                     "serve.prefix", kind="publish", rid=req.rid,
                     n_blocks=new)
 
     def _start_prefill(self, slot: int, req: Request) -> None:
-        """Admission entry point: flip the slot to "prefilling" so step()
-        streams the prompt through the shared chunk trace, interleaved
-        with decode — or, single-shot (``prefill_chunk=None``), run the
-        whole prompt now as one chunk.  The host's part is phase ``admit``;
-        a single-shot prefill's forward is ``prefill_dispatch``, outside
-        it."""
-        if self.prefill_chunk is None:
-            # single-shot requests go straight to running, so the pin
-            # happens here (before the prefill work, cheaply bounced)
-            with self._phase("admit", rid=req.rid):
-                bound = self._bind_adapter(slot, req)
-                if bound:
-                    self._seed_prefill(req)
-            if bound:
-                self._advance_prefill(slot, req, single_shot=True)
-            return
+        """Admission entry point (phase ``admit``): flip the slot to
+        "prefilling" so step() streams the prompt through the shared chunk
+        trace, interleaved with decode, and seed the prefill's cursor.  A
+        prefix-cache hit starts it after the matched blocks — the chunk
+        trace then computes only the uncached suffix, attending to the
+        reused blocks through the request's table exactly as the original
+        prefill's later chunks attended to them."""
         with self._phase("admit", rid=req.rid):
-            self._seed_prefill(req)
+            req.state = "prefilling"
+            if self._prefix_cache is not None:
+                self.prefix_queries += 1
+                if req.cached_tokens:
+                    self.prefix_hits += 1
+                    self.prefix_cached_tokens += req.cached_tokens
+                    C = self.prefill_chunk
+                    self.prefix_saved_chunks += (
+                        -(-req.n_prompt // C)
+                        - -(-(req.n_prompt - req.cached_tokens) // C))
+                self.journal.event(
+                    "serve.prefix", kind="match", rid=req.rid,
+                    hit=bool(req.cached_tokens),
+                    cached_tokens=req.cached_tokens,
+                    cached_blocks=req.cached_blocks)
+            self._prefill[req.rid] = _PrefillState(
+                pos=req.cached_tokens, lora=self._req_lora(req))
 
-    def _seed_prefill(self, req: Request) -> None:
-        """A prefill's cursor.  A prefix-cache hit starts it after the
-        matched blocks — the chunk trace then computes only the uncached
-        suffix, attending to the reused blocks through the request's table
-        exactly as the original prefill's later chunks attended to them."""
-        req.state = "prefilling"
-        if self._prefix_cache is not None:
-            self.prefix_queries += 1
-            if req.cached_tokens:
-                self.prefix_hits += 1
-                self.prefix_cached_tokens += req.cached_tokens
-                C = self.prefill_chunk
-                self.prefix_saved_chunks += (
-                    -(-req.n_prompt // C)
-                    - -(-(req.n_prompt - req.cached_tokens) // C))
-            self.journal.event(
-                "serve.prefix", kind="match", rid=req.rid,
-                hit=bool(req.cached_tokens),
-                cached_tokens=req.cached_tokens,
-                cached_blocks=req.cached_blocks)
-        self._prefill[req.rid] = _PrefillState(
-            pos=req.cached_tokens, lora=self._req_lora(req))
-
-    def _chunk_operands(self, slot: int, req: Request,
-                        single_shot: bool = False) -> tuple[np.ndarray, int]:
+    def _chunk_operands(self, slot: int,
+                        req: Request) -> tuple[np.ndarray, int]:
         """``(pack_chunk's operand, how many real tokens)`` of the chunk of
         ``req``'s prompt at its cursor: one upload (table row, tokens,
         cursor, last real row, slot)."""
         st = self._prefill[req.rid]
-        bs = self.pool.block_size
-        C = (blocks_for_tokens(req.n_prompt, bs) * bs if single_shot
-             else self.prefill_chunk)
+        C = self.prefill_chunk
         chunk = req.prompt[st.pos:st.pos + C]
         return programs.pack_chunk(
             self.pool.table_row(req.blocks, self.max_blocks),
             chunk + [0] * (C - len(chunk)), st.pos, len(chunk) - 1,
             slot), len(chunk)
 
-    def _advance_prefill(self, slot: int, req: Request,
-                         single_shot: bool = False) -> None:
+    def _advance_prefill(self, slot: int, req: Request) -> None:
         """One [1, C] chunk of ``req``'s prompt, written into its blocks
-        (``_chunk_ran`` is what follows).  Single-shot, the chunk is the
-        whole prompt padded to whole pages (one trace per distinct padded
-        length — the only shape-varying compile in the serving loop) and
-        the adapter is already pinned."""
+        (``_chunk_ran`` is what follows), by whichever chunk program the
+        engine holds."""
         st = self._prefill[req.rid]
         with self._phase("prefill_dispatch", rid=req.rid, pos=st.pos):
-            packed, n_real = self._chunk_operands(slot, req, single_shot)
+            packed, n_real = self._chunk_operands(slot, req)
             if st.lora is not None:
                 self.pool.kv, logits = self._prefill_lora_fn(
                     self._merge_base, st.lora, self.pool.kv, packed,
@@ -901,10 +821,10 @@ class ServeEngine:
                 self.pool.kv, logits = self._prefill_fn(
                     self.params, self.pool.kv, packed, self._win_rows[slot])
         self._sent_program(chunk=(n_real, st.pos))
-        self._chunk_ran(slot, req, n_real, logits, single_shot)
+        self._chunk_ran(slot, req, n_real, logits)
 
-    def _chunk_ran(self, slot: int, req: Request, n_real: int, logits,
-                   single_shot: bool = False) -> None:
+    def _chunk_ran(self, slot: int, req: Request, n_real: int,
+                   logits) -> None:
         """A chunk of ``n_real`` tokens of ``req``'s prompt is dispatched:
         move the cursor.  On the final chunk: pin the adapter (bouncing the
         request if the pool is full), sample the first token ON THE DEVICE
@@ -914,12 +834,8 @@ class ServeEngine:
         before it dispatches)."""
         st = self._prefill[req.rid]
         st.pos += n_real
-        if not single_shot:
-            req.prefill_chunks += 1
-        done = st.pos >= req.n_prompt
-        bounced = (done and not single_shot
-                   and not self._bind_adapter(slot, req))
-        if done and not bounced:
+        req.prefill_chunks += 1
+        if st.pos >= req.n_prompt and self._bind_adapter(slot, req):
             with self._phase("prefill_dispatch", rid=req.rid, pos=st.pos):
                 self._out = self._first_fn(
                     self._out, logits,
@@ -940,8 +856,8 @@ class ServeEngine:
                 self._read("prefill_first_token", self._take_unread())
 
     def _rides(self, plan: list) -> bool:
-        """Whether this call's decode rows ride in the last chunk of
-        ``plan``: ONE program for both where the engine can run one
+        """Whether this call's decode rows ride in the chunk of ``plan``:
+        ONE program for both where the engine can run one
         (``_fused_fn``) and some slot decodes.  The chunk is then dispatched
         with the step, after ``grow`` and the copy-on-write guard, and not
         before them.  While nobody decodes a chunk runs in its own place,
@@ -1300,8 +1216,6 @@ class ServeEngine:
                     if req.t_first_token else None),
             itl_s=itl_s,
             itl_mean_s=(sum(itl_s) / len(itl_s) if itl_s else None),
-            kv_ship_s=((req.t_kv_shipped - req.t_admit)
-                       if req.t_kv_shipped and req.t_admit else None),
             cached_tokens=cached_tokens or None,
             prefill_chunks=req.prefill_chunks or None,
             prefill_compute_s=(round(req.prefill_compute_s, 6)
@@ -1310,16 +1224,11 @@ class ServeEngine:
 
     def step(self) -> None:
         """One serving iteration: evict the ended, admit queued, advance
-        prefill chunks, grow/preempt (optimistic), dispatch a decode step
-        for every decoding slot and read the one dispatched a call ago (a
-        call with nothing to dispatch reads what is left).  Colocated
-        (default): prefill chunks INTERLEAVE with decode steps — at most
-        ``prefill_chunks_per_step`` per iteration, their time serializing
-        with decode on the one chip.
-        Disaggregated: EVERY prefilling slot advances each step (the
-        prefill slice has nothing else to do) and the step's modeled
-        wall time is ``max(prefill, decode)`` — the slices run
-        concurrently, only the KV-block shipment couples them."""
+        the oldest prefill by one chunk, grow/preempt (optimistic),
+        dispatch a decode step for every decoding slot and read the one
+        dispatched a call ago (a call with nothing to dispatch reads what
+        is left).  Prefill chunks INTERLEAVE with decode steps, one a
+        call: their time serializes with decode on the one chip."""
         sched = self.scheduler
         tokens_before = self.tokens_emitted
         ahead_before = self.steps_ahead
@@ -1342,12 +1251,9 @@ class ServeEngine:
                 admitted = sched.admit()
             for slot, req in admitted:
                 self._start_prefill(slot, req)  # more admit, by request
-                self._evict_ended(slot)  # single-shot, one new token
             prefill_s = 0.0
-            budget = (None if self.disaggregate
-                      else self.prefill_chunks_per_step)
-            plan = sched.prefill_plan(budget)
-            # the last chunk of the plan waits for the decode rows
+            plan = sched.prefill_plan(1)
+            # the chunk waits for the decode rows
             rider = plan.pop() if self._rides(plan) else None
             for slot, req in plan:
                 n_chunks += 1
@@ -1358,7 +1264,7 @@ class ServeEngine:
                 chunk_s = time.monotonic() - t0
                 prefill_s += chunk_s
                 req.prefill_compute_s += chunk_s
-                self._evict_ended(slot)  # chunked, max_new_tokens == 1
+                self._evict_ended(slot)  # max_new_tokens == 1
             with self._phase("grow"):
                 for victim in sched.grow_for_step():
                     self._prefill.pop(victim.rid, None)
@@ -1382,17 +1288,10 @@ class ServeEngine:
         gc_full = self._gc.passes[self._gc.FULL] - gc_full
         self._step_count += 1
         self._occupancy_sum += sched.n_active / self.n_slots
-        self.prefill_busy_s += prefill_s
-        self.decode_busy_s += decode_s
-        # the step's cost under this mode's concurrency model: one chip
-        # serializes the phases; distinct slices overlap them
-        overlap_s = (max(prefill_s, decode_s) if self.disaggregate
-                     else prefill_s + decode_s)
-        self.overlapped_wall_s += overlap_s
         compiles = self._compiles.n - compiles
         if compiles:
-            # a program built in this step (a first step, a single-shot
-            # prompt of a new length) stalled every stream for this long
+            # a program built in this step (an engine's first calls)
+            # stalled every stream for this long
             self.journal.event(
                 "compile", fn="serve",
                 dur_s=self._compiles.seconds - compile_s)
@@ -1428,9 +1327,6 @@ class ServeEngine:
             occupancy=sched.n_active / self.n_slots,
             free_blocks=self.pool.allocator.n_free,
             prefill_s=prefill_s, decode_s=decode_s,
-            mode=("disaggregated" if self.disaggregate
-                  else "colocated"),
-            overlap_s=overlap_s,
             phases=phases, step_s=whole["step_s"], t_end=t_end,
             n_prefill_chunks=n_chunks, compiles=compiles,
             gc_s=gc_s, gc_full=gc_full, **read,

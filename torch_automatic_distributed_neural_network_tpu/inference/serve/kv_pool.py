@@ -374,11 +374,6 @@ class PagedKVPool:
         self.quantize = bool(quantize)
         self.allocator = BlockAllocator(num_blocks)
         self.spec = None
-        # prefill->decode block-transfer accounting (disaggregated
-        # serving: see record_ship)
-        self.n_transfers = 0
-        self.transferred_blocks = 0
-        self.transferred_bytes = 0
         # the kind of every layer's pair: True where the layer keeps a ring,
         # True where it keeps a recurrent state and no pages at all
         kinds = cfg.layer_types or (None,) * cfg.n_layers
@@ -495,9 +490,7 @@ class PagedKVPool:
     @property
     def bytes_per_block(self) -> int:
         """Global bytes one block id holds across the layers that keep
-        pages for ``max_len``, k and v (scales included in int8 mode) —
-        the unit the block-transfer accounting charges per shipped
-        block."""
+        pages for ``max_len``, k and v (scales included in int8 mode)."""
         return self._bytes_paged(1)[0]
 
     def alloc(self, n: int) -> list[int] | None:
@@ -534,16 +527,3 @@ class PagedKVPool:
                 f"{len(blocks)} blocks exceed table width {max_blocks}")
         return list(blocks) + [NULL_BLOCK] * (max_blocks - len(blocks))
 
-    def record_ship(self, n_blocks: int) -> int:
-        """Block-transfer accounting of the disaggregated engine: a
-        finished prefill's ``n_blocks`` handed to the decode slice.  The
-        prefill program writes the pages where decode reads them (the pool
-        write IS the transfer when both slices share one process); what
-        this adds is the metric: blocks and bytes shipped at pool storage
-        precision, i.e. what crosses the wire when prefill and decode live
-        on distinct mesh slices.  Returns the bytes moved."""
-        moved = n_blocks * self.bytes_per_block
-        self.n_transfers += 1
-        self.transferred_blocks += n_blocks
-        self.transferred_bytes += moved
-        return moved

@@ -107,7 +107,6 @@ from ...training.lora import LoraSpec, merge_lora
 from ..decode import (
     SampleConfig,
     _moe_mlp_cached,
-    _moe_mlp_routed,
     _sample,
     layer_params,
 )
@@ -138,7 +137,7 @@ SCOPES = (
     "tadnn.attend_chunk",  # ``_chunk_*``: a chunk's rows against the cache
     "tadnn.attend_step",   # ``_step_*``: the decode rows against the cache
     "tadnn.mix_out",       # the output projection, its norm, the residual
-    "tadnn.ffn",           # a dense FFN (and the toy routed experts)
+    "tadnn.ffn",           # a dense FFN (and the toy experts)
     "tadnn.ffn_expert",    # ``SparseMLP``: router, layout, kernels, combine
                            # (a shortcut branch's too, inside ``tadnn.ffn``)
     "tadnn.head",          # the final norm, the logits and the sampler
@@ -171,7 +170,7 @@ def _logits(params, cfg: TransformerConfig, x):
 
 
 def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
-           moe: str = "dense", adapted=None, branch=None, carried=None):
+           adapted=None, branch=None, carried=None):
     """One layer, its mixer by ``kind``: ``attend`` is what touches the
     cache, everything else is shared by the rows, whatever call they are
     of.  On an attention layer ``attend(q, k, v)`` writes the new keys and
@@ -232,9 +231,8 @@ def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
         x = x + ao
 
     def dense(u):
-        if "experts_up" in lp["mlp"]:  # the capacity-routed toy experts
-            return (_moe_mlp_routed(lp["mlp"], u, cfg) if moe == "routed"
-                    else _moe_mlp_cached(lp["mlp"], u, cfg))
+        if "experts_up" in lp["mlp"]:  # the toy experts, every one run
+            return _moe_mlp_cached(lp["mlp"], u, cfg)
         return MLPBlock(cfg).apply({"params": lp["mlp"]}, u)
 
     def experts(u):
@@ -813,8 +811,10 @@ def pack_chunk(table_row, tokens, pos0: int, last_idx: int,
 
 
 def prefill_chunk(params, kv, packed, win_row, *, cfg: TransformerConfig,
-                  max_blocks: int, moe_decode: str = "dense"):
-    """One [1, C] chunk of one slot's prompt (operands of ``pack_chunk``;
+                  max_blocks: int):
+    """The chunk alone, for the engines whose steps cannot carry it
+    (speculative, and tenants through ``prefill_chunk_lora``).
+    One [1, C] chunk of one slot's prompt (operands of ``pack_chunk``;
     C is what ``packed`` holds beyond ``max_blocks + 3``) at positions
     ``pos0 ..``, written into the slot's pages (the table row [max_blocks]
     on the layers that keep pages for ``max_len``, the ring ``win_row`` [W]
@@ -853,7 +853,7 @@ def prefill_chunk(params, kv, packed, win_row, *, cfg: TransformerConfig,
 
             x, _counters, carried = _layer(
                 cfg, lp, kind, sparse, x, positions, shared["real"][None],
-                attend, moe=moe_decode, branch=branch, carried=carried)
+                attend, branch=branch, carried=carried)
             return x, a, b, None, carried
 
         return fn

@@ -122,17 +122,12 @@ def admission_plan(queued: Sequence[tuple], n_free_slots: int,
 
 
 def prefill_schedule(prefilling: Sequence[tuple[float | None, int]],
-                     max_chunks: int | None) -> list[int]:
+                     max_chunks: int) -> list[int]:
     """Which prefilling slots advance a chunk this step: FIFO by
     ``(t_admit, slot)``, at most ``max_chunks`` of them — the cap
-    bounds how much prefill work can delay a step's decode.
-    ``max_chunks=None`` means no cap: disaggregated serving runs
-    prefill on its own mesh slice, so every prefilling slot advances
-    each step without stealing decode time."""
+    bounds how much prefill work can delay a step's decode."""
     order = sorted(((t or 0.0), s) for t, s in prefilling)
-    if max_chunks is not None:
-        order = order[:max_chunks]
-    return [s for _, s in order]
+    return [s for _, s in order[:max_chunks]]
 
 
 def decode_needs_block(n_prompt: int, n_generated: int, n_blocks: int, *,
@@ -214,9 +209,6 @@ class Request:
     t_submit: float = dataclasses.field(default_factory=time.monotonic)
     t_admit: float | None = None
     t_first_token: float | None = None
-    # disaggregated serving: when this request's prefill KV blocks were
-    # shipped from the prefill slice into the decode slice's pool
-    t_kv_shipped: float | None = None
     t_done: float | None = None
     # per-token emission stamps (scheduler clock): consecutive diffs
     # are the inter-token latencies; cleared with out_tokens on
@@ -297,10 +289,6 @@ class Scheduler:
         self.draining: list[Request] = []
         self.n_finished = 0
         self.n_preemptions = 0
-        # disaggregated serving: finished-prefill KV transfers into the
-        # decode slice (record_ship), mirrored by the replay simulator
-        self.n_kv_ships = 0
-        self.shipped_blocks = 0
 
     # -- introspection -------------------------------------------------------
 
@@ -557,25 +545,11 @@ class Scheduler:
             admitted.append((slot, req))
         return admitted
 
-    def record_ship(self, slot: int, n_blocks: int) -> None:
-        """Account one finished prefill's KV-block transfer into decode
-        (disaggregated serving: the prefill slice hands ``n_blocks`` to
-        the decode slice's pool).  Stamps the request and the running
-        totals — the same counters the discrete-event replay accrues,
-        so predicted and measured ship traffic are comparable."""
-        req = self.slots[slot]
-        assert req is not None, f"record_ship on empty slot {slot}"
-        req.t_kv_shipped = self.clock()
-        self.n_kv_ships += 1
-        self.shipped_blocks += int(n_blocks)
-
-    def prefill_plan(self, max_chunks: int | None
-                     ) -> list[tuple[int, Request]]:
+    def prefill_plan(self, max_chunks: int) -> list[tuple[int, Request]]:
         """The prefilling slots due a chunk this step: FIFO by
         admission time, at most ``max_chunks`` of them.  The engine
         advances each returned slot by exactly one chunk, so this cap
-        bounds how much prefill work can delay a step's decode
-        (``None`` = uncapped, the disaggregated prefill slice)."""
+        bounds how much prefill work can delay a step's decode."""
         by_slot = {r.slot: r for r in self.slots
                    if r is not None and r.state == "prefilling"}
         order = prefill_schedule(

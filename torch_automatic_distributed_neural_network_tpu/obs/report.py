@@ -478,11 +478,8 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                 e.get("attn_grid_items") for e in ssteps)) or None,
             "attn_grid_dense": sum(_finite(
                 e.get("attn_grid_dense") for e in ssteps)) or None,
-            # disaggregated / sharded serving (r04 fields)
-            "mode": (ssteps[-1].get("mode") if ssteps else None),
+            # sharded serving
             "tp": (sengine or {}).get("tp"),
-            "overlapped_wall_s": (sum(_finite(
-                e.get("overlap_s") for e in ssteps)) or None),
         }
         # where the host's time in a step goes (engine.PHASES): mean
         # seconds of each phase over the steps in which it ran, and the
@@ -578,13 +575,6 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
         if any(v is not None for v in phase_means.values()):
             serving["phase_mean_s"] = {
                 k: v for k, v in phase_means.items() if v is not None}
-        ships = [e for e in events if e.get("name") == "serve.kv_ship"]
-        if ships:
-            serving["kv_ships"] = len(ships)
-            serving["shipped_blocks"] = int(sum(
-                _finite(e.get("n_blocks") for e in ships)))
-            serving["shipped_bytes"] = int(sum(
-                _finite(e.get("bytes") for e in ships)))
         spec = [e for e in events if e.get("name") == "serve.speculate"]
         if spec:
             drafted = sum(_finite(e.get("drafted") for e in spec))
@@ -1242,19 +1232,8 @@ def format_report(report: dict) -> str:
                 f"step {w['step']}, took {w['step_s'] * 1e3:.1f} ms where "
                 f"its kind ({w['kind']}) takes {w['median_s'] * 1e3:.2f}"
                 + (f", most of it in {w['phase']}" if w["phase"] else ""))
-        if sv.get("mode") == "disaggregated" or (sv.get("tp") or 1) > 1:
-            dparts = [f"mode {sv.get('mode') or 'colocated'}"]
-            if (sv.get("tp") or 1) > 1:
-                dparts.append(f"tp {sv['tp']}")
-            if sv.get("overlapped_wall_s") is not None:
-                dparts.append(
-                    f"overlapped wall {sv['overlapped_wall_s']:.2f}s")
-            if sv.get("kv_ships"):
-                dparts.append(
-                    f"kv ships {sv['kv_ships']} "
-                    f"({sv.get('shipped_blocks', 0)} block(s), "
-                    f"{sv.get('shipped_bytes', 0) / 1024:.0f} KiB)")
-            lines.append("  " + "  ".join(dparts))
+        if (sv.get("tp") or 1) > 1:
+            lines.append(f"  tp {sv['tp']}")
         if sv.get("spec_rounds"):
             rate = sv.get("spec_accept_rate")
             lines.append(

@@ -321,10 +321,11 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
 
     # -- serving engine -----------------------------------------------------
     _s("serve.engine", "engine construction: the serving configuration",
+       version=2,
        req={"n_slots": "int", "max_len": "int", "block_size": "int",
             "quant_kv": "bool", "attention_impl": "str",
-            "prefill_chunk": "int?", "speculative": "int",
-            "disaggregate": "bool", "tp": "int", "prefix_cache": "bool",
+            "prefill_chunk": "int", "speculative": "int",
+            "tp": "int", "prefix_cache": "bool",
             "n_adapters": "int", "adapter_rank": "int?",
             "quant_adapters": "bool"},
        # the tree the base programs take: leaves the engine rounded to
@@ -362,14 +363,14 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # how a prefill chunk attends, a kind of layer that keeps
             # pages: "kernel" (one call of tadnn_latent_chunk a layer) or
             # "blocks" (jax.numpy over key blocks), decided at build by
-            # what the programs see (None: a single-shot engine)
+            # what the programs see (None: every layer a linear one)
             "chunk_attention": "dict?",
             # decode steps the engine dispatches with the step before
             # unread: 1, or 0 where the next step's operands need the
             # tokens' values (speculative drafts)
             "dispatch_ahead": "int"}),
     _s("serve.step", "one serving iteration (engine or gateway "
-       "SimReplica)",
+       "SimReplica)", version=2,
        req={"n_active": "int", "n_queued": "int", "new_tokens": "int",
             "occupancy": "float", "free_blocks": "int"},
        opt={"step": "int", "n_prefilling": "int", "prefill_s": "float",
@@ -379,7 +380,7 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # ride in one) and the device_get of what went out a call ago:
             # the step's PERIOD less the call's other work, of a call with
             # a chunk and of a decode-only one alike (``read`` parts them)
-            "decode_s": "float", "mode": "str", "overlap_s": "float",
+            "decode_s": "float",
             "adapters_resident": "int", "adapters_pinned": "int",
             "prefix_blocks": "int", "prefix_hit_tokens": "int",
             "replica": "str", "prefill_chunks": "int",
@@ -457,22 +458,18 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # first position: a kernel call's grid steps a group of heads)
             "chunk_key_blocks": "int"}),
     _s("serve.request_done", "per-request completion span with the "
-       "full phase-attributed timeline", version=2,
+       "full phase-attributed timeline", version=3,
        req={"rid": "int", "n_prompt": "int", "n_new": "int",
             "queue_s": "float?", "total_s": "float?",
             "tokens_per_s": "float?", "preempted": "int",
             "ttft_s": "float?", "itl_s": "list"},
        opt={"prefill_s": "float?", "decode_s": "float?",
-            "itl_mean_s": "float?", "kv_ship_s": "float?",
+            "itl_mean_s": "float?",
             "cached_tokens": "int?", "prefill_chunks": "int?",
             "prefill_compute_s": "float?", "lost_s": "float?",
             "replica": "str"}),
     _s("serve.preempt", "optimistic-growth preemption recycled a slot",
        req={"rid": "int", "n_regenerate": "int"}),
-    _s("serve.kv_ship", "disaggregated prefill shipped KV blocks into "
-       "a decode slot",
-       req={"rid": "int", "slot": "int", "n_blocks": "int",
-            "bytes": "int"}),
     _s("serve.speculate", "speculative draft-and-verify round",
        req={"step": "int", "k": "int", "n_active": "int",
             "drafted": "int", "accepted": "int",

@@ -146,8 +146,6 @@ def replay_serve(
     spec_lookahead: int = 0,
     decode_step_s: float = 1e-3,
     prefill_chunk_s: float = 1e-3,
-    disaggregate: bool = False,
-    kv_ship_s: float = 0.0,
     dcn_step_s: float = 0.0,
     prefix_cache: bool = False,
     shared_prefix: int = 0,
@@ -165,15 +163,8 @@ def replay_serve(
     the policy's admission/preemption/occupancy behavior priced in
     seconds.
 
-    ``disaggregate`` mirrors the engine's split-slice mode: every
-    prefilling slot advances each step (no chunks-per-step cap), each
-    finished prefill pays ``kv_ship_s`` to hand its KV blocks to the
-    decode slice (``Scheduler.record_ship`` accounting, same counters
-    the live engine accrues), and a step's wall time is
-    ``max(prefill_side, decode_side)`` — the slices run concurrently —
-    instead of their sum.  ``dcn_step_s`` prices per-decode-step
-    cross-slice collectives (a tp group spanning slices); it is added
-    on the decode side in both modes.
+    ``dcn_step_s`` prices per-decode-step cross-slice collectives (a tp
+    group spanning slices); it is added to every decode step.
 
     ``prefix_cache`` drives a REAL :class:`PrefixCache` (the engine's
     radix index, same eviction and admission interplay): prompts are
@@ -218,8 +209,6 @@ def replay_serve(
 
     steps = 0
     occ_sum = 0.0
-    prefill_busy = 0.0
-    decode_busy = 0.0
     while steps < max_steps:
         # arrivals due by now join the queue (bench-style all-up-front
         # submission is just every arrival at t=0)
@@ -249,23 +238,11 @@ def replay_serve(
                     and req.finished()):
                 done.append(sched.evict(s))
                 progressed = True
-        step_pf_s = 0.0
-        step_dec_s = 0.0
-
-        def ship(slot: int, req: Request) -> float:
-            # disaggregated: finished prefill pays the block handoff
-            # into the decode slice (engine: pool.record_ship)
-            if not disaggregate:
-                return 0.0
-            sched.record_ship(
-                slot, blocks_for_tokens(req.n_prompt, block_size))
-            return kv_ship_s
-
+        step_s = 0.0  # one chip serializes the phases
         for slot, req in sched.admit():
             progressed = True
             if chunk is None:
-                step_pf_s += prefill_chunk_s  # one full prompt forward
-                step_pf_s += ship(slot, req)
+                step_s += prefill_chunk_s  # one full prompt forward
                 emit(req)  # single-shot prefill: first token now
                 req.t_first_token = clock[0]
                 if req.finished():
@@ -275,19 +252,17 @@ def replay_serve(
                 # a prefix-cache hit starts the cursor after the
                 # matched blocks — the skipped chunks are the savings
                 prefill_pos[req.rid] = req.cached_tokens
-        budget = None if disaggregate else prefill_chunks_per_step
-        for slot, req in sched.prefill_plan(budget):
+        for slot, req in sched.prefill_plan(prefill_chunks_per_step):
             pos = prefill_pos[req.rid]
             pos += min(chunk, req.n_prompt - pos)
             prefill_pos[req.rid] = pos
-            step_pf_s += prefill_chunk_s
+            step_s += prefill_chunk_s
             progressed = True
             if pos >= req.n_prompt:
                 del prefill_pos[req.rid]
-                step_pf_s += ship(slot, req)
                 if pc is not None:
-                    # publish full prompt blocks (engine: at commit /
-                    # KV-ship time)
+                    # publish full prompt blocks (engine: as the last
+                    # chunk is dispatched)
                     n_pub = req.n_prompt // block_size
                     pc.insert(req.prompt[:n_pub * block_size],
                               req.blocks[:n_pub])
@@ -303,15 +278,10 @@ def replay_serve(
             for req in sched.slots:
                 if req is not None and req.state == "running":
                     emit(req)
-            step_dec_s += decode_step_s + dcn_step_s
+            step_s += decode_step_s + dcn_step_s
             progressed = True
         steps += 1
         occ_sum += sched.n_active / n_slots
-        prefill_busy += step_pf_s
-        decode_busy += step_dec_s
-        # one chip serializes the phases; distinct slices overlap them
-        step_s = (max(step_pf_s, step_dec_s) if disaggregate
-                  else step_pf_s + step_dec_s)
         clock[0] += step_s
 
         if not progressed:
@@ -339,11 +309,6 @@ def replay_serve(
         "tokens_per_s": (new_tokens / wall) if wall > 0 else 0.0,
         "mean_occupancy": (occ_sum / steps) if steps else 0.0,
         "preemptions": int(sched.n_preemptions),
-        "disaggregate": bool(disaggregate),
-        "prefill_busy_s": prefill_busy,
-        "decode_busy_s": decode_busy,
-        "kv_ships": int(sched.n_kv_ships),
-        "shipped_blocks": int(sched.shipped_blocks),
         "p50_s": float(np.percentile(totals, 50)) if totals else None,
         "p99_s": float(np.percentile(totals, 99)) if totals else None,
         "p99_admission_wait_s": (float(np.percentile(waits, 99))
@@ -391,10 +356,6 @@ class SimulatePolicy:
     max_len: int = 256
     prefill_chunk: int | None = 32
     spec_lookahead: int = 0
-    # disaggregated prefill/decode serving replicas (engine
-    # --disaggregate): prefill on its own slice, KV blocks shipped over
-    # DCN on multislice fleets, step wall = max(prefill, decode)
-    disaggregate: bool = False
     quant_kv: bool = False
     # cross-request prefix caching (engine --prefix-cache): the replay
     # drives the real radix index over TrafficMix.shared_prefix traffic
@@ -605,32 +566,24 @@ def simulate(
                                    traffic.prompt_mean)),
                             tensor=tensor)
                     # multi-slice serving tax (measured step costs came
-                    # from single-slice runs, so these apply either way):
+                    # from single-slice runs, so it applies either way):
                     # a tp group wider than one slice pays two DCN
                     # all-reduces of the [slots, d_model] activations
                     # per layer per decode step
                     dcn_s = 0.0
-                    ship_s = 0.0
-                    if topo.is_multislice:
+                    if (topo.is_multislice
+                            and tensor > topo.devices_per_slice):
                         d = getattr(model_cfg, "d_model",
                                     model_cfg.kv_heads
                                     * model_cfg.head_dim)
-                        if tensor > topo.devices_per_slice:
-                            step_bytes = (2 * model_cfg.n_layers
-                                          * slots * d * 2)
-                            dcn_s = (step_bytes / chip.dcn_bytes_per_s
-                                     + 2 * model_cfg.n_layers
-                                     * chip.dcn_latency_s)
-                        if policy.disaggregate:
-                            # a finished prompt's KV crosses slices
-                            ship_s = (kv_tok * traffic.prompt_mean
-                                      / max(1, tensor)
-                                      / chip.dcn_bytes_per_s
-                                      + chip.dcn_latency_s)
+                        step_bytes = (2 * model_cfg.n_layers
+                                      * slots * d * 2)
+                        dcn_s = (step_bytes / chip.dcn_bytes_per_s
+                                 + 2 * model_cfg.n_layers
+                                 * chip.dcn_latency_s)
                     rk = (adm, slots, serve_est["num_blocks"],
                           round(dec_s, 9), round(pf_s, 9),
-                          policy.disaggregate, policy.prefix_cache,
-                          round(ship_s, 9), round(dcn_s, 9))
+                          policy.prefix_cache, round(dcn_s, 9))
                     if rk not in replay_memo:
                         replay_memo[rk] = replay_serve(
                             requests, n_slots=slots,
@@ -641,20 +594,17 @@ def simulate(
                             prefill_chunk=policy.prefill_chunk,
                             spec_lookahead=policy.spec_lookahead,
                             decode_step_s=dec_s, prefill_chunk_s=pf_s,
-                            disaggregate=policy.disaggregate,
-                            kv_ship_s=ship_s, dcn_step_s=dcn_s,
+                            dcn_step_s=dcn_s,
                             prefix_cache=policy.prefix_cache,
                             shared_prefix=traffic.shared_prefix)
                         obs_journal.event(
                             "simulate.replay", admission=adm,
                             slots=slots, decode_step_ms=dec_s * 1e3,
-                            disaggregate=policy.disaggregate,
                             dcn_step_ms=dcn_s * 1e3,
-                            kv_ship_ms=ship_s * 1e3,
                             **{k: replay_memo[rk][k] for k in
                                ("steps", "tokens_per_s",
                                 "mean_occupancy", "preemptions",
-                                "stalled", "kv_ships")})
+                                "stalled")})
                     rep = replay_memo[rk]
                     pred.update(
                         tok_s_per_chip=round(
