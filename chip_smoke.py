@@ -447,6 +447,7 @@ def hybrid_kernels_phase(*, seed: int, on_chip: bool) -> dict:
         if not bool(jnp.array_equal(p1[r], pool0[r])):
             raise RuntimeError(f"gdn step: row {r} was written")
     kda_kernel_checks(rec, close, next(keys), on_chip)
+    scan_kernel_checks(rec, close, next(keys), on_chip)
     latent_kernel_checks(rec, close, next(keys), on_chip)
     return rec
 
@@ -504,6 +505,115 @@ def kda_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:
     for r in (0, S + 1):  # the null row and a row no slot has
         if not bool(jnp.array_equal(p1[r], pool0[r])):
             raise RuntimeError(f"kda step: row {r} was written")
+
+
+def scan_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:
+    """What a decoder-hybrid-decoder adds, at Phi-4-mini-flash's widths on
+    the chip (5,120 channels of 16 state numbers, 64 slots; 40 query heads
+    on 20 KV heads of 64 in pages of 64 tokens; a fraction of them in a
+    rehearsal): ``tadnn_ssm_chunk`` over a chunk of 512 from a state and
+    ``tadnn_ssm_step`` over the slots' rows of a pool in place, against the
+    token-by-token recurrence, steps and rates as the family initialises
+    them; and differential attention's decode (the folded kernel at another
+    wiring: a query head on ITS key head, over the pair's two value heads)
+    against plain ``jax.numpy``, full and over a window of 512.  On the chip
+    also a call's time of each."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from torch_automatic_distributed_neural_network_tpu.ops import (
+        paged_attention as pa,
+        ssm,
+    )
+    from torch_automatic_distributed_neural_network_tpu.ops.attention import (
+        diff_heads,
+    )
+
+    n, N, C, S = (5120, 16, 512, 64) if on_chip else (256, 8, 40, 3)
+    interpret = not on_chip
+    keys = iter(jax.random.split(key, 16))
+
+    def timed(name, fn, *args):
+        if not on_chip:
+            return
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(20):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        rec[name + "_call_ms"] = 1e3 * (time.perf_counter() - t0) / 20
+
+    c = jax.nn.silu(jax.random.normal(next(keys), (C, n), jnp.float32))
+    delta = jnp.exp(jax.random.uniform(
+        next(keys), (C, n), minval=math.log(1e-3), maxval=math.log(0.1)))
+    A = -jax.random.uniform(next(keys), (N, n), minval=1e-3, maxval=16.0)
+    B, Cm = (jax.random.normal(next(keys), (C, N), jnp.float32)
+             for _ in range(2))
+    D = jax.random.normal(next(keys), (n,), jnp.float32)
+    h0 = jax.random.normal(next(keys), (N, n), jnp.float32)
+    y_ref, h_ref = jax.jit(ssm.ssm_recurrent)(c, delta, A, B, Cm, D, h0)
+    chunk = jax.jit(lambda *a: ssm.ssm_chunk_pallas(*a, interpret=interpret))
+    y, h1 = chunk(c, delta, A, B, Cm, D, h0)
+    close("ssm_chunk_out", y, y_ref)
+    close("ssm_chunk_state", h1, h_ref)
+    timed("ssm_chunk", chunk, c, delta, A, B, Cm, D, h0)
+    rows = jnp.asarray([(r + 2) % (S + 1) if r < S - 2 else 0
+                        for r in range(S)], jnp.int32)
+    pool0 = jax.random.normal(next(keys), (S + 2, N, n), jnp.float32)
+    idle = (rows == 0)[:, None]  # as the decode program masks them
+    args = (c[:S], jnp.where(idle, 0.0, delta[:S]), A, B[:S], Cm[:S], D)
+    y_ref, p_ref = jax.jit(ssm.ssm_step_xla)(*args, pool0, rows)
+    step = jax.jit(lambda *a: ssm.ssm_step_pallas(*a, interpret=interpret),
+                   donate_argnums=(6,))
+    y, p1 = step(*args, pool0 + 0.0, rows)
+    live = np.asarray(rows) > 0
+    close("ssm_step_out", y[live], y_ref[live])
+    close("ssm_step_state", p1[rows[live]], p_ref[rows[live]])
+    for r in (0, S + 1):  # the null row and a row no slot has
+        if not bool(jnp.array_equal(p1[r], pool0[r])):
+            raise RuntimeError(f"ssm step: row {r} was written")
+    if on_chip:  # (not donated: a call's time with the pool where it lies)
+        timed("ssm_step", jax.jit(lambda *a: ssm.ssm_step_pallas(*a)[0]),
+              *args, pool0, rows)
+
+    H, KV, hd, bs, MB, Sd = (40, 20, 64, 64, 48, 8) if on_chip else (
+        8, 4, 16, 4, 10, 3)
+    pages = lambda: (0.5 * jax.random.normal(  # noqa: E731
+        next(keys), (Sd * MB + 1, bs, KV * hd), jnp.float32)).astype(
+            jnp.bfloat16)
+    k0, v0 = pages(), pages()
+    tables = jnp.asarray(1 + np.arange(Sd * MB).reshape(Sd, MB), jnp.int32)
+    q = jax.random.normal(next(keys), (Sd, H, hd), jnp.float32)
+    ctx = (jnp.arange(Sd) * 977 % (MB * bs)).astype(jnp.int32).at[0].set(
+        MB * bs - 1)
+    key_of, values_of = diff_heads(H, KV)
+
+    def plain(q, k0, v0, tables, ctx, window):
+        heads = lambda p: p[tables].astype(jnp.float32).reshape(  # noqa: E731
+            Sd, MB * bs, KV, hd)
+        kd, vd = heads(k0), heads(v0)
+        s = jnp.einsum("shd,sthd->sht", q, kd[:, :, key_of]) / math.sqrt(hd)
+        pos = jnp.arange(MB * bs)[None, None, :]
+        ok = pos <= ctx[:, None, None]
+        if window is not None:
+            ok &= pos > ctx[:, None, None] - window
+        w = jax.nn.softmax(jnp.where(ok, s, -jnp.inf), axis=-1)
+        return jnp.einsum(
+            "sht,sthiv->shiv", w, vd[:, :, values_of]).reshape(Sd, H, 2 * hd)
+
+    for name, window in (("diff_decode_full", None),
+                         ("diff_decode_window", 8 * bs)):
+        run = jax.jit(lambda *a, w=window: pa.paged_attention(
+            *a, window=w, diff=True, interpret=interpret))
+        out = run(q, k0, v0, tables, ctx)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda *a, w=window: plain(*a, w))(
+                q, k0, v0, tables, ctx)
+        close(name, out, ref)
+        timed(name, run, q.astype(jnp.bfloat16), k0, v0, tables, ctx)
 
 
 def latent_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:

@@ -1243,3 +1243,252 @@ def test_the_kda_counts_are_the_arithmetic(bench):
     # 0.87 operations a byte a decode row: memory binds it
     assert counts_kda.recurrence_flops(1, 32, 128, 128) / (
         per_token + state) < 1
+
+
+# -- 10. a decoder-hybrid-decoder: scan rows, ONE shared full cache (PR 46) -------
+
+FLASH_METRICS = ("ssm_chunk_ms", "ssm_chunk_roofline", "ssm_step_ms",
+                 "ssm_step_roofline", "diff_attn_decode_ms",
+                 "diff_attn_roofline", "cross_rows_share")
+FLASH_CELL = "phi4-mini-flash-3p8b.serve-backlog-reasoning-s64"
+# what the cell's readers index on the events of such a model
+FLASH_FIELDS = {
+    "state_bytes_linear": "metrics/state_pool_gib.py:12,15,19",
+    "conv_bytes_linear": "metrics/state_pool_gib.py:16,19",
+    "kv_bytes_full": "metrics/kv_pool_gib.py:10,13,16",
+    "kv_bytes_window": "metrics/kv_pool_gib.py:10,14,16",
+    "layer_kinds": "metrics/kv_pool_gib.py:15",
+    "attention_form": "obs/report.py (the attention line)",
+    "cross_start": "obs/report.py (the cross-decoder line)",
+    "paged_sets": "obs/report.py (the cross-decoder line)",
+    "shared_readers": "obs/report.py (the cross-decoder line)",
+}
+PEAKS = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+
+
+@pytest.fixture(scope="module")
+def flash(bench):
+    """A model of state-space layers, windowed and full differential
+    attention, memory units and layers that read another layer's pages."""
+    return _serve(bench, _cell_of("phi4-mini-flash-3p8b"))
+
+
+@pytest.mark.parametrize("field", sorted(FLASH_FIELDS))
+def test_serve_engine_of_a_hybrid_decoder_carries_its_counters(flash, field):
+    ev = flash["record"]["serve_engine"]
+    assert ev is not None and ev.get(field) is not None, (
+        f"serve.engine has no {field!r}; read by " + FLASH_FIELDS[field])
+
+
+def test_serve_engine_says_what_the_hybrid_decoder_cell_is_about(flash):
+    """Nine kinds of cache would be the naive count for the eight layers of
+    the rehearsal; the pool holds two paged sets (a ring, one full layer's
+    pages) and two state rows, the cross-decoder's four layers nothing; the
+    bytes say so; ``state_rows`` counts the decode rows over the state-space
+    layers, and ``read`` the rows that ran each decoder."""
+    ev, eng = flash["record"]["serve_engine"], flash["eng"]
+    kinds = list(flash["record"]["model_keys"]["layer_types"])
+    n_scan = kinds.count("state_space")
+    assert ev["attention_form"] == "differential"
+    assert ev["cross_start"] == 4 and ev["linear_mixer"] is None
+    assert (ev["paged_sets"], ev["shared_readers"]) == (2, 2)
+    pool = eng.pool
+    assert (pool.n_full, pool.state.count(True), pool.none.count(True)) \
+        == (1, n_scan, 4)
+    assert ev["kv_bytes_full"] == pool.bytes_per_block * pool.num_blocks
+    assert ev["state_bytes_linear"] == n_scan * (
+        flash["record"]["engine"]["n_slots"] + 1) * 8 * 128 * 4
+    rows = [s["state_rows"] for s in _steps(flash) if "state_rows" in s]
+    assert rows and all(r % n_scan == 0 and r > 0 for r in rows)
+    S, C = (flash["record"]["engine"][k] for k in ("n_slots",
+                                                   "prefill_chunk"))
+    reads = [s["read"] for s in _steps(flash)
+             if s.get("read") and s["read"]["programs"] == 1]
+    assert {(r["self_rows"], r["cross_rows"]) for r in reads} \
+        == {(S, S), (C + S, 1 + S)}
+
+
+@pytest.mark.parametrize("field", ["attention_form", "cross_start",
+                                   "paged_sets", "shared_readers",
+                                   "cross_rows", "self_rows"])
+def test_the_hybrid_decoders_fields_are_in_the_schema(field):
+    from torch_automatic_distributed_neural_network_tpu.obs import schema
+
+    with open(schema.__file__) as f:
+        assert f'"{field}"' in f.read()
+
+
+@pytest.mark.parametrize("name", FLASH_METRICS)
+def test_a_hybrid_decoder_metric_has_its_file_and_its_cell(name):
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
+    assert entry["workloads"] == [FLASH_CELL]
+    assert entry["moves"] == "serve_tokens_per_s"
+    layer, source = {
+        "ssm": ("state-space scan", "device_trace"),
+        "dif": ("attention kernels", "device_trace"),
+        "cro": ("serve step", "program_counter")}[name[:3]]
+    assert (entry["layer"], entry["source"]) == (layer, source)
+    assert (entry["unit"], entry["better"]) == (
+        ("%", "higher") if name.endswith("roofline")
+        else ("%", "lower") if name == "cross_rows_share" else ("ms", "lower"))
+    assert name + ".py" in METRIC_FILES
+    assert entry in BENCHMARK["per_layer"][39:46]  # appended, nothing moved
+    serve = next(m for m in BENCHMARK["end_to_end"]
+                 if m["name"] == "serve_tokens_per_s")
+    assert serve["workloads"][-1] == FLASH_CELL
+    assert BENCHMARK["workloads"][-1]["name"] == FLASH_CELL
+    assert BENCHMARK["workloads"][-1]["chips"] == 1
+    # the engine-wide metrics whose readers find something there; neither
+    # count of the paged kernel's bytes holds for a shared cache
+    listed = {m["name"] for m in BENCHMARK["per_layer"]
+              if FLASH_CELL in m.get("workloads", ())}
+    assert listed == set(FLASH_METRICS) | {
+        "slot_occupancy", "decode_step_ms.backlog", "device_idle_share.serve",
+        "prefill_chunk_device_ms.backlog", "serve_host_ms.backlog",
+        "kv_pool_gib", "state_pool_gib", "chunk_call_ms.backlog",
+        "decode_call_ms.backlog", "serve_stall_ms.backlog"}
+
+
+def _flash_reader(name):
+    return _load(os.path.join(BENCH, "metrics", name + ".py"), "bench_metric")
+
+
+@pytest.mark.parametrize("name", FLASH_METRICS)
+def test_a_hybrid_decoder_reader_finds_nothing_where_there_is_nothing(
+        bench, flash, mixed, dense, name, capsys):
+    """No device trace on the CPU, a trace of no device, a trace without the
+    kernels (the parent's program could not build this model; another
+    model's record has no scan layers, no differential attention and no
+    ``self_rows``): ``None``, and no raise."""
+    reader = _flash_reader(name)
+    other = ("%tadnn_kda_step.1 = f32[8,3,10,192] custom-call()", 10, 500)
+    traced = {"peaks": PEAKS, "trace_mono": (0.0, 1e9),
+              "trace": {"n_devices": 1, "ops": {"d": [other]},
+                        "modules": {"d": [("jit_serve_prefill_chunk(1)", 0,
+                                           1000)]},
+                        "module_seconds": {"jit_serve_prefill_chunk": [1e-6]}}}
+    for run in (mixed, dense):
+        assert reader.read(run["record"]) is None
+        assert reader.read({**run["record"], **traced}) is None
+    if name != "cross_rows_share":  # a counter: read off the chip too
+        assert reader.read(flash["record"]) is None
+        assert reader.read({**flash["record"],
+                            "trace": {"n_devices": 0}}) is None
+        assert reader.read({**flash["record"], **traced}) is None
+    capsys.readouterr()
+
+
+def test_the_events_readers_give_numbers_on_the_hybrid_decoder(bench, flash):
+    rec = flash["record"]
+    got = _flash_reader("cross_rows_share").read(rec)
+    reads = [s["read"] for s in rec["serve_steps"] if s.get("read")]
+    assert got == pytest.approx(100 * sum(r["cross_rows"] for r in reads)
+                                / sum(r["self_rows"] for r in reads))
+    assert 0 < got < 100
+    assert _flash_reader("kv_pool_gib").read(rec) == pytest.approx(
+        (flash["eng"].pool.bytes_full + flash["eng"].pool.bytes_window)
+        / 2**30)
+    assert _flash_reader("state_pool_gib").read(rec) == pytest.approx(
+        sum(flash["eng"].pool.bytes_state) / 2**30)
+
+
+def test_the_hybrid_decoders_readers_read_a_hand_made_trace(bench, flash,
+                                                            capsys):
+    """Two runs of the chunk's program and one decode step; in each the two
+    state-space layers' step kernel takes 40 us a layer, with a staged copy
+    of a layer's pool open for 60 us round the first (the union is counted:
+    100 us in that run), the chunk kernel 200 us a layer, and the four
+    layers that attend pages 30 us each.  The shares are the least time of
+    ``counts_ssm`` and ``counts_diff_attn`` over those times."""
+    from lib import counts_diff_attn, counts_ssm
+
+    rec0, keys = flash["record"], flash["record"]["model_keys"]
+    n, d_in, N = counts_ssm.scan_layers(keys)
+    assert (n, d_in, N) == (2, 128, 8)
+    S, C = rec0["engine"]["n_slots"], rec0["engine"]["prefill_chunk"]
+    pool = f"f32[{S + 1},{N},{d_in}]"
+    us = 1000
+    mods = [("jit_serve_prefill_chunk(7)", 0, 2000 * us),
+            ("jit_serve_prefill_chunk(7)", 3000 * us, 5000 * us),
+            ("jit_serve_decode_step(9)", 6000 * us, 7000 * us)]
+    ops = []
+    for m, (_, lo, _hi) in enumerate(mods):
+        for i in range(n):
+            at = lo + (100 + 300 * i) * us
+            ops.append((f"%tadnn_ssm_step.{i} = (f32[{S},1,{d_in}], {pool}) "
+                        f"custom-call()", at, at + 40 * us))
+            if m < 2:
+                ops.append((f"%tadnn_ssm_chunk.{i} = (f32[{C},{d_in}], "
+                            f"f32[{N},{d_in}]) custom-call()", at + 50 * us,
+                            at + 250 * us))
+        for i in range(4):
+            at = lo + (1000 + 100 * i) * us
+            ops.append((f"%tadnn_paged_decode_folded.{i} = bf16[{S},4,32] "
+                        f"custom-call()", at, at + 30 * us))
+        ops.append((f"%copy-start.{m} = ({pool}, {pool}) copy-start()",
+                    lo + 80 * us, lo + 81 * us))
+        ops.append((f"%copy-done.{m} = {pool} copy-done()", lo + 139 * us,
+                    lo + 140 * us))
+    steps = [{"state_rows": n * rows, "t_end": t}
+             for rows, t in ((2, 0.5), (4, 1.5), (3, 99.0))]
+    reqs = [{"prompt": [1] * 20, "walls": [0.1, 0.5, 1.0, 50.0],
+             "t_admit": 0.0}]
+    rec = {**rec0, "peaks": PEAKS, "trace_mono": (0.0, 2.0),
+           "serve_steps": steps, "requests": reqs,
+           "trace": {"n_devices": 1, "ops": {"d": ops}, "modules": {"d": mods},
+                     "module_seconds": {
+                         "jit_serve_prefill_chunk": [2e-3, 2e-3],
+                         "jit_serve_decode_step": [1e-3]}}}
+    read = lambda name: _flash_reader(name).read(rec)  # noqa: E731
+    assert read("ssm_chunk_ms") == pytest.approx(2 * 0.2)
+    # a run: (the copy's window 80..140 joined to the kernel's 100..140) +
+    # 40 = 100 us over the two layers
+    assert read("ssm_step_ms") == pytest.approx(0.1)
+    assert counts_ssm.traced_state_rows(rec) == (3.0, 2)
+    assert read("diff_attn_decode_ms") == pytest.approx(4 * 0.03)
+    chunk, step = read("ssm_chunk_roofline"), read("ssm_step_roofline")
+    attn = read("diff_attn_roofline")
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+             if l.startswith("{")]
+    least = lambda tokens, seqs: max(  # noqa: E731
+        n * counts_ssm.scan_flops(tokens, d_in, N) / 197e12,
+        n * counts_ssm.scan_bytes(tokens, seqs, d_in, N, itemsize=2) / 819e9)
+    fill = 20 / (C * -(-20 // C))  # one prompt of 20, its last chunk padded
+    assert chunk == pytest.approx(100 * least(C * fill, 1.0) / 0.4e-3)
+    assert step == pytest.approx(100 * least(3.0, 3.0) / 0.1e-3)
+    # two decode tokens inside the traced part, at contexts 21 and 22: the
+    # full layer and its two readers read all of them, the ring min(c, 16)
+    assert counts_diff_attn.traced_contexts(rec) == [21, 22]
+    assert counts_diff_attn.layers(keys) == (3, 1, 16)
+    read_keys = 3 * 43 + 1 * 32
+    assert counts_diff_attn.keys_read([21, 22], keys) == read_keys
+    H, KV, hd = keys["n_heads"], keys["n_kv_heads"], 16
+    assert attn == pytest.approx(100 * max(
+        6 * read_keys * H * hd / 197e12,
+        2 * read_keys * KV * hd * 2 / 819e9) / (12 * 30e-6))
+    assert any("ssm_chunk" in l for l in lines)
+    assert any(l.get("ssm_step", {}).get("steps") == 2 for l in lines)
+    assert any(l.get("diff_attn", {}).get("calls") == 12 for l in lines)
+    assert 0 < chunk < 100 and 0 < step < 100 and 0 < attn < 100
+
+
+def test_the_hybrid_decoders_counts_are_the_arithmetic(bench):
+    """7 N + 3 operations a token a channel; c, Delta and the output once a
+    token, the state in and out once a sequence: at the cell's widths a
+    decode row's nine scans move 5.9 MB and are bound by their states.  A
+    decode token at context c reads 8 c + 8 min(c, 512) keys of 5,120 B."""
+    from lib import counts_diff_attn, counts_ssm
+
+    keys = CONFIGS["phi4-mini-flash-3p8b"]["model"]
+    assert counts_ssm.scan_layers(keys) == (9, 5120, 16)
+    assert counts_ssm.scan_flops(1, 5120, 16) == 5120 * 115
+    per_token, state = 5120 * 10 + 128, 2 * 16 * 5120 * 4
+    assert counts_ssm.scan_bytes(1, 1, 5120, 16, itemsize=2) \
+        == per_token + state == 51_328 + 655_360
+    assert round(9 * (per_token + state) / 1e6, 1) == 6.4
+    assert counts_ssm.scan_flops(1, 5120, 16) / (per_token + state) < 1
+    assert counts_diff_attn.layers(keys) == (8, 8, 512)
+    assert counts_diff_attn.keys_read([100, 2700], keys) \
+        == 8 * 2800 + 8 * (100 + 512)
+    assert counts_diff_attn.decode_bytes(1, 20, 64, itemsize=2) == 5120
+    assert counts_diff_attn.decode_flops(1, 40, 64) == 6 * 40 * 64

@@ -479,3 +479,64 @@ def test_kda_chunk_is_one_kernel_with_nothing_prepared_for_it(v5e, T):
         r" = \S+ (fusion|pad|slice|concatenate)\(", l)]
     # a tail: five operands padded to 512 rows, the output cut to 454
     assert len(others) <= (0 if T % gd.SUB_CHUNK == 0 else 6), others
+
+
+# -- the selective scan's two kernels, at Phi-4-mini-flash's widths -----------
+
+
+@pytest.mark.parametrize("form", ["chunk", "step"])
+def test_ssm_kernels_compile_for_v5e(v5e, form):
+    """5,120 channels of 16 state numbers: the chunk kernel over a prefill
+    chunk of 512 tokens (ten blocks of 512 lanes, the chunk's ``B`` and ``C``
+    as columns), the step kernel over 64 slots of a pool of 65 rows (21 MB),
+    which it reads and writes in place."""
+    from torch_automatic_distributed_neural_network_tpu.ops import ssm
+
+    one = SingleDeviceSharding(v5e[0])
+    sds = lambda s, d=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        s, d, sharding=one)
+    n, N, S, T = 5120, 16, 64, 512
+    if form == "chunk":
+        text = _compile(
+            ssm.ssm_chunk_pallas, sds((T, n), jnp.bfloat16), sds((T, n)),
+            sds((N, n)), sds((T, N)), sds((T, N)), sds((n,)), sds((N, n)))
+        assert "tadnn_ssm_chunk" in text
+        return
+    compiled = jax.jit(ssm.ssm_step_pallas, donate_argnums=(6,)).lower(
+        sds((S, n), jnp.bfloat16), sds((S, n)), sds((N, n)), sds((S, N)),
+        sds((S, N)), sds((n,)), sds((S + 1, N, n)),
+        sds((S,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tadnn_ssm_step" in text
+    assert not [l for l in text.splitlines()
+                if " copy(" in l and f"f32[{S + 1},{N},{n}]" in l]
+    # the pool is the output: no second copy of it
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= (S + 1) * N * n * 4
+
+
+def test_differential_paged_decode_compiles_for_v5e(v5e):
+    """Differential attention's decode at Phi-4-mini-flash's widths: 40
+    query heads on 20 KV heads of 64, a folded page of 64 tokens x 1,280
+    lanes, 64 slots of 544 pages: the folded MXU kernel at another wiring
+    of its lanes (a query head in the lanes of ITS key head; the 128 lanes
+    of the pair's two value heads taken of the product), ONE call that
+    reads a page once; full attention and the window of 512."""
+    from torch_automatic_distributed_neural_network_tpu.ops.paged_attention import (
+        paged_attention_folded,
+    )
+
+    one = SingleDeviceSharding(v5e[0])
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one)  # noqa: E731
+    S, H, KV, hd, bs, MB, NB = 64, 40, 20, 64, 64, 544, 6145
+    for window in (None, 512):
+        text = _compile(
+            lambda q, k, v, t, c: paged_attention_folded(
+                q, k, v, t, c, window=window, interpret=False, diff=True),
+            sds((S, H, hd), jnp.bfloat16), sds((NB, bs, KV * hd), jnp.bfloat16),
+            sds((NB, bs, KV * hd), jnp.bfloat16), sds((S, MB), jnp.int32),
+            sds((S,), jnp.int32))
+        assert len(re.findall(r"^\s*%tadnn_paged_decode_folded[.\d]* = ",
+                              text, re.M)) == 1
+        assert not [l for l in text.splitlines()
+                    if " copy(" in l and f"bf16[{NB},{bs},{KV * hd}]" in l]

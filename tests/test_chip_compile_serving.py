@@ -28,13 +28,15 @@ _SERVING = {"gpt2-1p3b": (8, 1024, 16, 128, None),
             "olmo-hybrid-7b-pp2": (8, 33792, 16, 512, 4609),
             "joyai-llm-flash-ep8": (24, 34816, 64, 512, 4097),
             "longcat-flash-omni-ep32": (24, 34816, 64, 512, 4097),
-            "kimi-linear-48b-ep8": (96, 36864, 64, 512, 6145)}
+            "kimi-linear-48b-ep8": (96, 36864, 64, 512, 6145),
+            "phi4-mini-flash-3p8b": (64, 34816, 64, 512, 6145)}
 
 
 # the chunk alone is a program of the speculative and the tenant engines: a
 # configuration that refuses both (``lora_spec`` with ``layer_types``,
 # ``speculative`` with linear layers) has no engine that runs it
-_NO_CHUNK_ALONE = ("olmo-hybrid-7b-pp2", "kimi-linear-48b-ep8")
+_NO_CHUNK_ALONE = ("olmo-hybrid-7b-pp2", "kimi-linear-48b-ep8",
+                   "phi4-mini-flash-3p8b")
 
 
 def cases(configs) -> dict:
@@ -105,6 +107,7 @@ def serving_program_updates_the_pool_in_place(
         gated_delta as gdn,
         grouped_matmul as gmm,
         paged_attention as paged,
+        ssm,
     )
 
     # the default backend is the CPU here: ask for the kernels, not their
@@ -112,6 +115,7 @@ def serving_program_updates_the_pool_in_place(
     monkeypatch.setattr(paged, "_default_interpret", lambda: False)
     monkeypatch.setattr(gmm, "_default_interpret", lambda: False)
     monkeypatch.setattr(gdn, "_on_tpu", lambda: True)
+    monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
     keys = _GPT2_1P3B
     if config != "gpt2-1p3b":
         path = os.path.join(os.path.dirname(os.path.dirname(
@@ -207,7 +211,8 @@ def serving_program_updates_the_pool_in_place(
     # moves into on-chip memory round their gather, ``S(1)``, and lays out
     # anew behind their scatter: 0.3 ms a call over 12 layers, PERF.md
     # section 7; its 203 MB state pool it does not copy: Step 0 of ISSUE 41)
-    staged = {"bf16[97,3,12288]"} & page_arrays
+    # (a state-space layer's 2 MB of tails at 65 rows likewise: 9 layers)
+    staged = {"bf16[97,3,12288]", "bf16[65,3,5120]"} & page_arrays
     assert not [l[:100] for l in text.splitlines()
                 if " copy(" in l and any(a in l for a in page_arrays - staged)]
     if cfg.n_expert_layers:
@@ -235,7 +240,35 @@ def serving_program_updates_the_pool_in_place(
             assert len(re.findall(
                 r"^\s*%tadnn_moe_grouped_mm_" + kernel + r"[.\d]* = ", text,
                 re.M)) == cfg.n_expert_layers
-    if config == "olmo-hybrid-7b-pp2":
+    if config == "phi4-mini-flash-3p8b":
+        # 9 state-space layers: the step kernel wherever rows decode, the
+        # chunk kernel wherever a chunk runs; 16 attention layers: ONE
+        # folded decode call each (the differential pair costs no second
+        # read of a page), on 9 sets of pages
+        count = lambda name: len(re.findall(  # noqa: E731
+            r"^\s*%" + name + r"[.\d]* = ", text, re.M))
+        assert count("tadnn_ssm_step") == 9
+        assert count("tadnn_ssm_chunk") == 9 * (program != "decode_step")
+        assert count("tadnn_paged_decode_folded") == 16
+        assert "tadnn_gdn" not in text and "tadnn_kda" not in text
+        assert "tadnn_moe_grouped_mm" not in text
+        pool = made["pool"]
+        assert (pool.n_full, pool.ring.count(True), pool.state.count(True),
+                pool.none.count(True)) == (1, 8, 9, 14)
+        # a token costs 5,120 B of pages (layer 17 alone); a slot 44.6 MB of
+        # rings and 3.2 MB of state and tails
+        assert pool.bytes_per_block == 64 * 5120
+        assert round(pool.bytes_full / 1e9, 2) == 2.01
+        assert round(pool.bytes_window / 1e9, 2) == 2.85
+        assert round(pool.bytes_window / slots / 1e6, 1) == 44.6
+        assert round(sum(pool.bytes_state) / (slots + 1) / 1e6, 1) == 3.2
+        assert f"f32[{slots + 1},16,5120]" in page_arrays
+        assert mem.argument_size_in_bytes < 14.2 * 2**30
+        # a chunk's rows but one stop before layer 18: the cross-decoder's
+        # FFNs multiply slots + 1 rows, no matrix of the chunk's 576
+        if program == "chunk_and_step":
+            assert f"bf16[{slots + 1},10240]" in text
+    elif config == "olmo-hybrid-7b-pp2":
         # 12 linear layers: the step kernel in the one, the chunk kernel in
         # the other; 4.53 GB of pages and 0.25 GB of states and tails
         mine, other = (("tadnn_gdn_step", "tadnn_gdn_chunk")

@@ -407,7 +407,8 @@ def test_the_configurations_in_the_benchmark_keep_their_trees_and_plans():
             [(kind, sparse, branch) for _, kind, sparse, branch in plan],
             {name: sorted(tree[name]) for name, *_ in plan})
     old = {"trinity-large-ep8": 5, "olmo-hybrid-7b-pp2": 16,
-           "joyai-llm-flash-ep8": 20, "kimi-linear-48b-ep8": 16}
+           "joyai-llm-flash-ep8": 20, "kimi-linear-48b-ep8": 16,
+           "phi4-mini-flash-3p8b": 32}  # PR 46: no branch either
     assert set(seen) == set(old) | {"longcat-flash-omni-ep32"}
     plan, trees = seen["kimi-linear-48b-ep8"]  # PR 41: no branch either
     assert plan == [("latent_attention" if i % 4 == 3 else "linear_attention",

@@ -285,15 +285,20 @@ def forward_cached(
 
 
 _FLOAT32_LEAVES = ("router", "q_norm", "k_norm", "q_a_norm", "kv_a_norm",
-                   "A_log", "dt_bias", "conv", "o_norm")
+                   "A_log", "dt_bias", "conv", "o_norm",
+                   # a state_space mixer's skip and filter bias; differential
+                   # attention's four vectors and its pairs' norm
+                   "D", "conv_bias", "lambda_q1", "lambda_k1", "lambda_q2",
+                   "lambda_k2", "sub_norm")
 
 
 def _cast_floats(tree: Any, dtype) -> Any:
     """Floating leaves of a (sub)tree in ``dtype``; int8 ``{"q", "scale"}``
     leaves, a ``router``, the gains of the QK norms and of a latent layer's
-    two inner norms, what a ``linear_attention`` mixer reads in float32 (its
-    decay rates, filters and output norm) and leaves already in ``dtype`` as
-    they are."""
+    two inner norms, what a ``linear_attention`` or a ``state_space`` mixer
+    reads in float32 (its decay rates, filters and output norm; the skip
+    ``D``), differential attention's lambdas and pair norm, and leaves
+    already in ``dtype`` as they are."""
     if is_quantized_leaf(tree):
         return tree
     if isinstance(tree, dict):

@@ -140,7 +140,8 @@ from ..decode import (
 from ..speculative import accept_length, ngram_propose
 from . import programs
 from .adapters import AdapterPool
-from .kv_pool import PagedKVPool, blocks_for_tokens, stored_row
+from .kv_pool import STATE_KINDS, PagedKVPool, blocks_for_tokens, \
+    stored_row
 from .prefix_cache import PrefixCache
 from .scheduler import Request, Scheduler
 
@@ -257,7 +258,9 @@ class ServeEngine:
             min(int(prefill_chunk), max_len), max_len)
         self.mesh = mesh
         kinds = self.cfg.layer_types or ()
-        self._n_linear = list(kinds).count("linear_attention")
+        # the layers that keep a recurrent state, a row a slot
+        self._n_linear = sum(kind in STATE_KINDS for kind in kinds)
+        diff = self.cfg.diff_attention
         # what is not served, each with its reason (the message names the
         # option and the kind of layer)
         refused = {
@@ -299,7 +302,28 @@ class ServeEngine:
             # normed latent beside a rotated key part, two ranges in one
             # row, and the latent kernel does not dequantize
             "quant_kv with latent_attention layers (a latent row has no "
-            "int8 form)": (quant_kv and "latent_attention" in kinds)}
+            "int8 form)": (quant_kv and "latent_attention" in kinds),
+            # as for a linear layer: a hit would need the scan's state and
+            # the convolution's tail AT the matched boundary
+            "prefix_cache with state_space layers (a hit needs the scan's "
+            "state at the matched boundary, which is not kept)": (
+                prefix_cache and "state_space" in kinds),
+            "speculative > 0 with state_space layers (a rejected draft "
+            "cannot be taken out of the scan's state)": (
+                self.speculative > 0 and "state_space" in kinds),
+            "mesh for a model with state_space layers (the scan's state "
+            "and its two kernels have no sharded form)": (
+                mesh is not None and "state_space" in kinds),
+            # the differential pair reads both value heads of a pair of KV
+            # heads side by side in a FOLDED page (the MXU kernel at another
+            # wiring of its lanes); int8 pages and pages sharded by KV head
+            # are kept unfolded, for the VPU kernel, which has no such form
+            "quant_kv with diff_attention (the differential pair reads "
+            "folded pages; int8 pages have no such form)": (
+                quant_kv and diff),
+            "mesh with diff_attention (the differential pair reads folded "
+            "pages; a pool sharded by KV head has no such form)": (
+                mesh is not None and diff)}
         if any(refused.values()):
             raise ValueError("not served: " + "; ".join(
                 k for k, v in refused.items() if v))
@@ -383,6 +407,11 @@ class ServeEngine:
         # what went out since the last read, which the read that waits for
         # it puts on its call's event (``_sent_program``), and what this
         # call has read so far (``serve.step``'s ``read``)
+        # where the model ends in layers that keep no cache, the rows of a
+        # program that ran the layers before ``cfg.cross_start`` and those
+        # that ran the ones from it on, by program: read off the programs'
+        # traces once they are built, below
+        self._program_rows: dict[str, tuple[int, int]] = {}
         self._sent = self._nothing_sent()
         # ... after the unread output was made (a chunk in its own place
         # behind an unread step): the read AFTER that one waits for it
@@ -447,6 +476,17 @@ class ServeEngine:
 
                 self._prefill_lora_fn = jax.jit(serve_prefill_chunk_lora,
                                                 donate_argnums=(2,))
+        if self.cfg.cross_start < self.cfg.n_layers:
+            for name, fn, operands in (
+                    ("step", self._step_fn, self._abstract_decode_args),
+                    ("chunk", self._prefill_fn, self._abstract_prefill_args),
+                    ("chunk_and_step", self._fused_fn,
+                     self._abstract_fused_args)):
+                if fn is not None:
+                    took = programs.rows_walked(fn, operands())
+                    self._program_rows[name] = (
+                        took[0], took[self.cfg.cross_start])
+            self._sent = self._nothing_sent()
         # the row tiles the expert layers of a call lay out, whatever lands
         # in them (``moe_tiles_active`` of a step counts those): [a call
         # with a chunk, a decode-only call]
@@ -461,8 +501,7 @@ class ServeEngine:
         # programs pick from the same inputs (``chunk_attention_form``),
         # asked once here; and the layers whose chunk is ONE kernel call,
         # whose key blocks ``serve.step`` counts (``chunk_key_blocks``)
-        paged = [kind for _, kind, *_ in layer_plan(self.cfg)
-                 if kind != "linear_attention"]
+        paged = programs.page_readers(self.cfg)
         self.chunk_attention = {
             kind or "full_attention": programs.chunk_attention_form(
                 self.cfg, kind, self.prefill_chunk, block_size)
@@ -547,7 +586,22 @@ class ServeEngine:
             # which rule the linear layers run: [the rule, whose the decay
             # is: a head's or a channel's] (None without such a layer)
             linear_mixer=(["gated_delta", self.cfg.linear_decay]
-                          if self._n_linear else None))
+                          if "linear_attention" in kinds else None),
+            # the form the attention layers run: "differential" (adjacent
+            # head pairs, two softmaxes subtracted, over values twice a
+            # key's width; its decode reads a folded page once, the MXU
+            # kernel at another wiring) or "softmax"
+            attention_form=("differential" if diff else "softmax"),
+            # a decoder-hybrid-decoder's layout: the first layer of the
+            # run of layers that keep no cache (a chunk's rows but one
+            # stop before it), the layers that hold paged keys and values
+            # of their own and those that read another layer's
+            cross_start=(self.cfg.cross_start
+                         if self.cfg.cross_start < self.cfg.n_layers
+                         else None),
+            paged_sets=(self.pool.n_full + self.pool.ring.count(True)
+                        if kinds else None),
+            shared_readers=(list(kinds).count("shared_attention") or None))
         # the counters of the decode step last read (serve.step carries them)
         self._counters: dict[str, int] = {}
 
@@ -820,7 +874,9 @@ class ServeEngine:
             else:
                 self.pool.kv, logits = self._prefill_fn(
                     self.params, self.pool.kv, packed, self._win_rows[slot])
-        self._sent_program(chunk=(n_real, st.pos))
+        self._sent_program(
+            chunk=(n_real, st.pos),
+            program="chunk" if self._fused_fn is None else "chunk_and_step")
         self._chunk_ran(slot, req, n_real, logits)
 
     def _chunk_ran(self, slot: int, req: Request, n_real: int,
@@ -934,37 +990,46 @@ class ServeEngine:
         self._rows, self._firsts, self._rows_fused = [], [], False
         return unread
 
-    @staticmethod
-    def _nothing_sent() -> dict:
+    def _nothing_sent(self) -> dict:
+        cross = ({"self_rows": 0, "cross_rows": 0} if self._program_rows
+                 else {})
         return {"programs": 0, "rows": 0, "ctx_keys": 0, "chunk_rows": 0,
-                "chunk_pos": 0}
+                "chunk_pos": 0, **cross}
 
     @staticmethod
     def _covers(first: dict, then: dict) -> dict:
         """``then`` with what went out before it, ``first``, added."""
         if not then["chunk_rows"]:
             then["chunk_pos"] = first["chunk_pos"]
-        for k in ("programs", "rows", "ctx_keys", "chunk_rows"):
+        for k in first.keys() - {"chunk_pos"}:
             then[k] += first[k]
         return then
 
     def _sent_program(self, rows: int = 0, ctx_keys: int = 0,
-                      chunk: tuple[int, int] | None = None) -> None:
+                      chunk: tuple[int, int] | None = None,
+                      program: str = "step") -> None:
         """One program that walks the layers went out (a first token's
         sampler rides with its chunk and is not counted): its decode
         ``rows`` and the sum of their context lengths, the ``(real rows,
         first position)`` of the ``chunk`` it carried.  Host integers, for
         the event of the call that will wait for it (``serve.step``'s
         ``read``): they add up over what one read covers, but for
-        ``chunk_pos``, which is the last chunk's.  What goes out while an
-        output is unread (a chunk in its own place) is not waited for by
-        that output's read, unless it is a prompt's last (``_chunk_ran``)."""
+        ``chunk_pos``, which is the last chunk's.  ``program`` names which
+        of the three it was: where the model ends in a cross-decoder, the
+        rows that ran the layers before it and the rows that ran it, as its
+        walk noted them (``self_rows``, ``cross_rows``).  What goes out
+        while an output is unread (a chunk in its own place) is not waited
+        for by that output's read, unless it is a prompt's last
+        (``_chunk_ran``)."""
         sent = self._sent
         if self._rows or self._firsts:
             if self._sent_late is None:
                 self._sent_late = self._nothing_sent()
             sent = self._sent_late
         sent["programs"] += 1
+        if self._program_rows:
+            sent["self_rows"] += self._program_rows[program][0]
+            sent["cross_rows"] += self._program_rows[program][1]
         sent["rows"] += rows
         sent["ctx_keys"] += ctx_keys
         if chunk is not None:
@@ -1052,7 +1117,8 @@ class ServeEngine:
             req.n_inflight += 1
         self._sent_program(  # (before the rows are unread ones)
             len(rows), ctx_keys, None if rider is None
-            else (n_real, self._prefill[rider[1].rid].pos))
+            else (n_real, self._prefill[rider[1].rid].pos),
+            "step" if rider is None else "chunk_and_step")
         self._rows = rows
         if rider is not None:
             self._rows_fused = True
@@ -1105,7 +1171,8 @@ class ServeEngine:
                         0 if fused else 1]
                 if self._n_linear:
                     # the slots whose row of a state pool the call's step
-                    # kernel read and wrote, over the linear layers: known
+                    # kernel read and wrote, over the layers that keep one
+                    # (linear_attention, state_space): known
                     # here, so not one more number in every model's output
                     self._counters["state_rows"] = len(rows) * self._n_linear
         with self._phase("emit"):

@@ -43,7 +43,8 @@ through :func:`gather_blocks` (table-indexed gather to a dense
 engine's ``attention_impl="dense"`` path and the oracle of every kernel
 parity test.
 
-Four kinds of layer (``cfg.layer_types``), one pair of arrays each.  A
+Seven kinds of layer (``cfg.layer_types``), one pair of arrays each (the
+last three kinds are described at the end of this paragraph).  A
 full-attention layer, and every layer of a model with one kind of layer,
 has the pages above: the allocator's ids, a request's table, room for
 ``max_len`` a slot.  A ``latent_attention`` layer has the same pages, ids
@@ -71,7 +72,16 @@ float32 (under ``"k"``) and the short convolution's TAIL ``[n_slots + 1, K -
 owned by the slot like a ring; row 0 is the null row that inactive slots
 write to, as ``NULL_BLOCK`` is for pages.  A slot's row is not cleared
 between requests by the host: the chunk program starts a prompt at position
-0 from zeros.  The scheduler's block accounting is the full layers' alone.
+0 from zeros.  A ``state_space`` layer has the same two rows a slot at other
+shapes: the selective scan's state ``[n_slots + 1, N, d_in]`` in float32
+(channels in the lanes: the published ``[d_in, N]`` transposed, or a row of
+16 numbers would be stored in a tile of 128) and the convolution's tail
+``[n_slots + 1, K - 1, d_in]``.  A ``gated_memory`` and a
+``shared_attention`` layer keep NOTHING: both of their entries are arrays of
+no elements, and a ``shared_attention`` layer reads the pages of the
+``full_attention`` layer before it (``cfg.source_layer``), which are counted
+once, wherever bytes are counted.  The scheduler's block accounting is the
+full layers' alone.
 
 Sharding: a leaf's spec is ``cache_partition_spec`` less its layer and
 batch axes (blocks are a global resource, any slot may use any block) —
@@ -86,7 +96,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ...models.transformer_core import TransformerConfig
+from ...models.transformer_core import SSM_CONV_TAPS, TransformerConfig
 from ..decode import cache_partition_spec
 from ..quant import is_quantized_leaf, kv_leaf_parts, quantize_kv
 
@@ -234,14 +244,29 @@ def pool_kv_bytes(cfg: TransformerConfig, num_blocks: int, block_size: int,
     return n_tokens * row * jnp.dtype(dtype).itemsize
 
 
-def state_row_bytes(cfg: TransformerConfig, dtype=jnp.bfloat16
-                    ) -> tuple[int, int]:
-    """(state, convolution tail) bytes of ONE slot's row in ONE
-    ``linear_attention`` layer."""
+def state_rows(cfg: TransformerConfig, kind: str = "linear_attention"
+               ) -> tuple[tuple[int, ...], tuple[int, ...]]:
+    """(state, convolution tail): the shapes of ONE slot's row in ONE layer
+    of ``kind`` that keeps a recurrent state (``STATE_KINDS``)."""
+    if kind == "state_space":
+        return ((cfg.ssm_state, cfg.ssm_inner),
+                (SSM_CONV_TAPS - 1, cfg.ssm_inner))
     H, dk, dv = (cfg.linear_value_heads, cfg.linear_key_head_dim,
                  cfg.linear_value_head_dim)
-    return (H * dk * dv * 4, (cfg.linear_conv_kernel - 1) * H * (2 * dk + dv)
-            * jnp.dtype(dtype).itemsize)
+    return (H, dk, dv), (cfg.linear_conv_kernel - 1, H * (2 * dk + dv))
+
+
+def state_row_bytes(cfg: TransformerConfig, dtype=jnp.bfloat16,
+                    kind: str = "linear_attention") -> tuple[int, int]:
+    """(state, convolution tail) bytes of ONE slot's row in ONE layer of
+    ``kind``: the state float32, the tail in ``dtype``."""
+    state, tail = state_rows(cfg, kind)
+    return (math.prod(state) * 4,
+            math.prod(tail) * jnp.dtype(dtype).itemsize)
+
+
+STATE_KINDS = ("linear_attention", "state_space")  # a row a slot, no pages
+NO_CACHE_KINDS = ("gated_memory", "shared_attention")  # nothing at all
 
 
 def _zeros_side(shape, dtype, quantize: bool):
@@ -377,9 +402,12 @@ class PagedKVPool:
         # the kind of every layer's pair: True where the layer keeps a ring,
         # True where it keeps a recurrent state and no pages at all
         kinds = cfg.layer_types or (None,) * cfg.n_layers
+        self.kinds = kinds
         self.ring = [kind == "sliding_attention" for kind in kinds]
-        self.state = [kind == "linear_attention" for kind in kinds]
+        self.state = [kind in STATE_KINDS for kind in kinds]
         self.latent = [kind == "latent_attention" for kind in kinds]
+        # no arrays of its own (a shared layer reads its source's pages)
+        self.none = [kind in NO_CACHE_KINDS for kind in kinds]
         if any(self.state) and mesh is not None:
             raise ValueError("a recurrent state has no sharded form")
         if any(self.latent) and (mesh is not None or self.quantize):
@@ -399,20 +427,15 @@ class PagedKVPool:
         page = ((bs, cfg.kv_heads * cfg.head_dim) if folded
                 else (bs, cfg.kv_heads, cfg.head_dim))
 
-        # a linear layer's two arrays: (a slot's row, dtype) under each name
-        rows = {}
-        if any(self.state):
-            H, dk, dv = (cfg.linear_value_heads, cfg.linear_key_head_dim,
-                         cfg.linear_value_head_dim)
-            rows = {"k": ((H, dk, dv), jnp.float32),
-                    "v": ((cfg.linear_conv_kernel - 1, H * (2 * dk + dv)),
-                          cfg.dtype)}
-
         def side(name):
-            def one(kind, ring, state, latent):
-                if state:
-                    return jnp.zeros((n_slots + 1, *rows[name][0]),
-                                     rows[name][1])
+            def one(kind, ring, state, latent, none):
+                if none:
+                    return jnp.zeros((0,), dtype)
+                if state:  # the state under "k", the tail under "v"
+                    row = state_rows(cfg, kind)[name == "v"]
+                    return jnp.zeros(
+                        (n_slots + 1, *row),
+                        jnp.float32 if name == "k" else cfg.dtype)
                 if latent:  # one row a token, under "k" (module docstring)
                     return jnp.zeros(
                         (num_blocks, bs, *stored_row(cfg, kind))
@@ -422,7 +445,7 @@ class PagedKVPool:
                     dtype, self.quantize)
 
             return [one(*a) for a in zip(kinds, self.ring, self.state,
-                                         self.latent)]
+                                         self.latent, self.none)]
 
         self.kv = {"k": side("k"), "v": side("v")}
         if mesh is not None:
@@ -439,9 +462,10 @@ class PagedKVPool:
 
     @property
     def n_full(self) -> int:
-        """Layers that keep pages for ``max_len``."""
-        return self.cfg.n_layers - self.ring.count(True) - self.state.count(
-            True)
+        """Layers that keep pages for ``max_len`` (a layer that reads
+        another's pages keeps none)."""
+        return self.cfg.n_layers - sum(
+            map(any, zip(self.ring, self.state, self.none)))
 
     def _bytes_paged(self, num_blocks: int) -> tuple[int, int]:
         """Bytes of ``num_blocks`` pages in every layer that keeps pages for
@@ -474,14 +498,13 @@ class PagedKVPool:
 
     @property
     def bytes_state(self) -> tuple[int, int]:
-        """(recurrent states, convolution tails) bytes of the
-        ``linear_attention`` layers, the null row included ((0, 0) without
-        such layers)."""
-        n = self.state.count(True) * (self.n_slots + 1)
-        if not n:
-            return 0, 0
-        state, conv = state_row_bytes(self.cfg, self.cfg.dtype)
-        return n * state, n * conv
+        """(recurrent states, convolution tails) bytes of the layers that
+        keep a row a slot (``STATE_KINDS``), the null row included ((0, 0)
+        without such layers)."""
+        rows = [state_row_bytes(self.cfg, self.cfg.dtype, kind)
+                for kind, state in zip(self.kinds, self.state) if state]
+        return tuple((self.n_slots + 1) * sum(r[i] for r in rows)
+                     for i in (0, 1))
 
     @property
     def total_bytes(self) -> int:
@@ -506,18 +529,18 @@ class PagedKVPool:
         caller owns the table update and the release of its reference
         on ``src``; the copy itself is one scatter a leaf, no host
         round-trip.  A ring and a recurrent state have no block ids to
-        share, and are left alone, as is the array of no elements beside a
-        layer's latent pages."""
+        share, and are left alone, as are the arrays of no elements (beside
+        a layer's latent pages; of a layer that keeps nothing)."""
         got = self.allocator.acquire(1)
         if got is None:
             return None
         dst = got[0]
         for side, layers in self.kv.items():
             self.kv[side] = [
-                leaf if ring or state or (latent and side == "v")
+                leaf if ring or state or none or (latent and side == "v")
                 else jax.tree.map(lambda x: x.at[dst].set(x[src]), leaf)
-                for leaf, ring, state, latent in zip(
-                    layers, self.ring, self.state, self.latent)]
+                for leaf, ring, state, latent, none in zip(
+                    layers, self.ring, self.state, self.latent, self.none)]
         return dst
 
     def table_row(self, blocks: list[int], max_blocks: int) -> list[int]:

@@ -56,6 +56,25 @@ discipline of ``decode.forward_cached``: ``SelfAttention.qkv`` /
   latent decode kernel: a page read once), the result taken out again
   (``lift``).
 
+- A ``state_space`` layer (``ops/ssm.py``: a selective scan) keeps a row a
+  slot too, at other shapes, under the same discipline (``_step_scan``,
+  ``_chunk_scan``: the null row, a prompt from zeros, the tail across a chunk
+  boundary; rows that are no real token carry ``Delta = 0``).  Its scan
+  output, before the gate, is the token's MEMORY, which ``_walk`` hands down
+  the plan to the ``gated_memory`` layers behind it (``memory``, beside
+  ``carried``).  A ``shared_attention`` layer keeps nothing: its queries
+  attend the pages of the ``full_attention`` layer before it
+  (``cfg.source_layer``), written earlier in the same call, and write none.
+
+- Where the model ends in layers that keep no cache (``cfg.cross_start``: a
+  cross-decoder of ``gated_memory`` and ``shared_attention`` layers), a
+  chunk's rows stop before them, but for the row whose logits are wanted
+  (``last_idx``): nothing a later token reads is written there.  ``_walk``
+  narrows ``x`` and the memory at that layer, in ``prefill_chunk`` to one
+  row and in ``chunk_and_step`` to that row and the decode rows (a fixed
+  shape; on a chunk that is not a prompt's last the row's result is not
+  read).  A decode step runs every layer on every row.
+
 - ``chunk_and_step`` is both in one walk: the chunk's C rows and the S
   decode rows share each layer's norms, projections, FFN and the head (the
   weights are read once), and each group touches the cache as its own
@@ -85,6 +104,7 @@ host may dispatch step n + 1 before it has read step n (``engine.py``).
 
 from __future__ import annotations
 
+import math
 
 import jax
 import jax.numpy as jnp
@@ -92,7 +112,9 @@ import numpy as np
 
 from ...models.transformer_core import (
     GatedDeltaMixer,
+    GatedMemory,
     LatentAttention,
+    MambaMixer,
     MLPBlock,
     SelfAttention,
     SparseMLP,
@@ -103,6 +125,7 @@ from ...models.transformer_core import (
     rope,
 )
 from ...ops.gated_delta import gated_delta_chunk, gated_delta_step
+from ...ops.ssm import ssm_chunk, ssm_step
 from ...training.lora import LoraSpec, merge_lora
 from ..decode import (
     SampleConfig,
@@ -118,8 +141,8 @@ from ..quant import (
     kv_leaf_parts,
 )
 from .adapters import factor_rows
-from .kv_pool import gather_blocks, read_pages, ring_table, write_chunk, \
-    write_token
+from .kv_pool import NO_CACHE_KINDS, STATE_KINDS, gather_blocks, \
+    read_pages, ring_table, write_chunk, write_token
 
 _NEG_BIG = -0.7 * float(np.finfo(np.float32).max)
 # a slot's flag in ``pack_step``: 0 is an inactive slot; otherwise where the
@@ -170,7 +193,7 @@ def _logits(params, cfg: TransformerConfig, x):
 
 
 def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
-           adapted=None, branch=None, carried=None):
+           adapted=None, branch=None, carried=None, memory=None, depth=0):
     """One layer, its mixer by ``kind``: ``attend`` is what touches the
     cache, everything else is shared by the rows, whatever call they are
     of.  On an attention layer ``attend(q, k, v)`` writes the new keys and
@@ -181,13 +204,20 @@ def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
     rows behind their tail); on a ``latent_attention`` layer
     ``attend(piece, q_nope, q_rope, latent)`` writes the rows' latents and
     returns the heads' outputs (``piece(name, *a)`` is the mixer's method
-    ``name``: ``expand``, ``absorb``, ``lift``).
+    ``name``: ``expand``, ``absorb``, ``lift``); on a ``state_space`` layer
+    ``attend(piece, a)`` is the scan over the rows' projections ``a``
+    (``piece``: ``convolve``, ``scan_inputs``, ``rates``) and what it
+    returns becomes the ``memory``; a ``gated_memory`` layer touches no
+    cache and reads ``memory``; a ``shared_attention`` layer's ``attend(q,
+    None, None)`` writes nothing.  ``depth`` is the layer's index
+    (differential attention's constant: an operand, so that the layers of a
+    kind share a trace).
     ``adapted(tensor, site, inp, rotate)`` adds a tenant's low-rank delta at
     a projection (decode steps with tenants).  ``branch`` and ``carried``
     are the plan entry's and what an open shortcut branch holds
     (``transformer_core.ffn_sublayer``, which orders this half of the layer
     for the model's own forward as well).  Returns ``(x, the expert FFN's
-    counters or None, what is carried on)``."""
+    counters or None, what is carried on, the memory)``."""
     dtype = cfg.dtype
     norm = make_norm(cfg)
     # int8 weight-only serving: only this layer's weights convert
@@ -206,6 +236,12 @@ def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
             mixer = LatentAttention(cfg)
             rows = (lambda name, *a: mixer.apply(own, *a, method=name),
                     *mixer.apply(own, h, positions, method="project"))
+        elif kind == "state_space":
+            mixer = MambaMixer(cfg)
+            a, z = mixer.apply(own, h, method="project")
+            rows = (lambda name, *a: mixer.apply(own, *a, method=name), a)
+        elif kind == "gated_memory":
+            rows = None  # no cache to touch
         else:
             mixer = SelfAttention(cfg, kind)
             q, k, v = mixer.apply(own, h, positions, method="qkv")
@@ -215,13 +251,20 @@ def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
                 k = adapted(k, "k", hf, cfg.layer_rotates(kind))
                 v = adapted(v, "v", hf, False)
             rows = (q, k, v)
-    o = attend(*rows)
+    o = None if rows is None else attend(*rows)
     with jax.named_scope("tadnn.mix_out"):
         if kind == "linear_attention":
             ao = mixer.apply(own, o, h, method="out_proj")
         elif kind == "latent_attention":
             ao = mixer.apply(own, o.astype(dtype), method="out_proj")
+        elif kind == "state_space":
+            memory = o  # before the gate: what the memory units read
+            ao = mixer.apply(own, o, z, method="out_proj")
+        elif kind == "gated_memory":
+            ao = GatedMemory(cfg).apply(own, h, memory)
         else:
+            if cfg.diff_attention:  # the pairs' difference, their norm
+                o = mixer.apply(own, o, depth, method="differ")
             ao = mixer.apply(own, o.astype(dtype), h, method="out_proj")
             if adapted is not None:
                 ao = adapted(ao, "o", o.reshape(*o.shape[:2], -1).astype(
@@ -242,24 +285,33 @@ def _layer(cfg, lp, kind, sparse, x, positions, valid, attend, *,
             return SparseMLP(cfg).apply({"params": lp["moe"]}, u, valid)
 
     with jax.named_scope("tadnn.ffn_expert" if sparse else "tadnn.ffn"):
-        return ffn_sublayer(
+        return *ffn_sublayer(
             cfg, x, sparse, branch, carried,
             norm=lambda x: norm.apply({"params": lp["mlp_norm"]}, x),
             dense=dense, experts=experts,
             post_norm=lambda h: norm.apply({"params": lp["post_mlp_norm"]},
-                                           h))
+                                           h)), memory
 
 
 def _paged(cfg) -> list[int]:
-    """The layers that keep keys and values in pages (all but the
-    ``linear_attention`` ones)."""
+    """The layers that keep keys and values in pages (not those that keep
+    a row a slot, nor those that keep nothing)."""
     return [i for i, (_, kind, *_) in enumerate(layer_plan(cfg))
-            if kind != "linear_attention"]
+            if kind not in STATE_KINDS + NO_CACHE_KINDS]
+
+
+def page_readers(cfg) -> list[str | None]:
+    """The kind of every layer that attends pages, its own or another
+    layer's (``shared_attention``): a paged call each, a decode step."""
+    return [kind for _, kind, *_ in layer_plan(cfg)
+            if kind not in STATE_KINDS + ("gated_memory",)]
 
 
 def _pages_of(kind: str | None) -> str:
     """Which of a call's tables a layer of ``kind`` reads: the ring of a
-    ``sliding_attention`` layer, or the request's pages for ``max_len``."""
+    ``sliding_attention`` layer, or the request's pages for ``max_len`` (a
+    ``shared_attention`` layer: its source's, a ``full_attention``
+    layer's)."""
     return "ring" if kind == "sliding_attention" else "pages"
 
 
@@ -289,29 +341,67 @@ def _moe_counters(stats: list) -> jax.Array:
         stats[0]["rows"]])
 
 
-def _walk(cfg, params, kv, x, layer_fn, shared, extras=None):
-    """``layer_fn(kind, sparse, branch)(lp, k_pages, v_pages, x, extra,
-    shared, carried)`` over the plan, one jitted function a (kind, FFN,
-    branch) triple so that like layers are traced once; the pair of arrays
-    is the layer's own (``kv_pool``: pages, a ring, or a state and a tail);
+def _walk(cfg, params, kv, x, layer_fn, shared, extras=None, narrow=None):
+    """``layer_fn(kind, sparse, branch, narrowed)(lp, k_pages, v_pages, x,
+    extra, shared, carried, memory, depth)`` over the plan, one jitted
+    function a (kind, FFN, branch, narrowed) entry so that like layers are
+    traced once; the pair of arrays is the layer's own (``kv_pool``: pages, a
+    ring, or a state and a tail), or on a ``shared_attention`` layer its
+    source's as the source left them in this call (it writes nothing);
     ``shared`` is what every layer reads (tables, positions, rows),
     ``extras`` one more operand a layer (a tenant's factors); ``carried`` is
     what an entry that opens a shortcut branch hands to the one that closes
-    it (None between any other two).  Returns ``(x, kv, the layers'
-    counters)``."""
+    it (None between any other two), ``memory`` the scan output of the last
+    ``state_space`` layer, for the ``gated_memory`` layers behind it.
+    ``narrow(rows)``: where the model ends in layers that keep no cache
+    (``cfg.cross_start``), the rows that go on through them, of ``x`` and of
+    the memory; the layers from there on are built ``narrowed``.  Returns
+    ``(x, kv, the layers' counters)``.  Under ``rows_walked`` the rows of
+    ``x`` that each layer took are noted as the walk is traced."""
     fns, new_k, new_v, stats = {}, [], [], []
-    carried = None
+    carried = memory = None
+    cut = cfg.n_layers if narrow is None else cfg.cross_start
+    took = []
     for i, (name, *entry) in enumerate(layer_plan(cfg)):
-        fn = fns.get(tuple(entry))
+        if i == cut:
+            x, memory = narrow(x), narrow(memory)
+        took.append(math.prod(x.shape[:-1]))
+        key = (*entry, i >= cut)
+        fn = fns.get(key)
         if fn is None:
-            fn = fns[tuple(entry)] = jax.jit(layer_fn(*entry))
-        x, k_l, v_l, counters, carried = fn(
-            layer_params(params, name), kv["k"][i], kv["v"][i], x,
-            None if extras is None else extras[i], shared, carried)
-        new_k.append(k_l)
-        new_v.append(v_l)
+            fn = fns[key] = jax.jit(layer_fn(*key))
+        src = i if entry[0] != "shared_attention" else cfg.source_layer(i)
+        pools = ((kv["k"][i], kv["v"][i]) if src == i
+                 else (new_k[src], new_v[src]))
+        x, k_l, v_l, counters, carried, memory = fn(
+            layer_params(params, name), *pools, x,
+            None if extras is None else extras[i], shared, carried, memory,
+            jnp.int32(i))
+        new_k.append(k_l if src == i else kv["k"][i])
+        new_v.append(v_l if src == i else kv["v"][i])
         stats.append(counters)
+    if _WALKS is not None:
+        _WALKS.append(took)
     return x, {"k": new_k, "v": new_v}, stats
+
+
+_WALKS: list[list[int]] | None = None
+
+
+def rows_walked(program, operands) -> list[int]:
+    """The rows of ``x`` that each layer of the plan takes in ``program`` (a
+    jitted serving program, not yet traced) on abstract ``operands``: read
+    off ``_walk`` itself while the program is traced, and not reckoned
+    from the program's name and shape: a ``narrow`` that narrows nothing
+    shows here."""
+    global _WALKS
+    _WALKS = []
+    try:
+        jax.eval_shape(program, *operands)
+        (took,) = _WALKS
+    finally:
+        _WALKS = None
+    return took
 
 
 # -- what touches the cache, a group of rows at a time -------------------------
@@ -352,8 +442,7 @@ def _step_shared(cfg, kv, tables, win_tables, ctx_lens, active, adapter_ids,
     if win_tables.shape[1]:
         lo = (ctx_lens - cfg.sliding_window + 1) // bs
         shared["tables"]["ring"] = ring_table(win_tables, MB, lo, hi)
-    kinds = [kind for _, kind, *_ in layer_plan(cfg)
-             if kind != "linear_attention"]
+    kinds = page_readers(cfg)
     grid = jnp.zeros((2,), jnp.int32)
     if attention_impl == "paged" and T == 1 and paged and is_folded(pages0):
         shared["work"] = {  # in the plan's order: the same text every run
@@ -373,20 +462,25 @@ def _step_shared(cfg, kv, tables, win_tables, ctx_lens, active, adapter_ids,
 def _step_attention(cfg, kind, shared, k_l, v_l, q, k, v, *,
                     attention_impl: str, mesh):
     """``q``, ``k``, ``v`` [S, T, heads, hd]: every slot's T tokens written
-    at its context's end, then each attends the keys up to itself."""
-    from ...ops.attention import xla_attention
+    at its context's end, then each attends the keys up to itself.  ``k``
+    None (a ``shared_attention`` layer): the pair is another layer's, which
+    wrote this call's tokens; nothing is written.  With
+    ``cfg.diff_attention`` what comes back is [S, T, H, 2 hd], each query
+    head over its pair of value heads (``ops.attention.diff_heads``)."""
+    from ...ops.attention import diff_plain_heads, xla_attention
     from ...ops.paged_attention import paged_attention
 
     T = q.shape[1]
     table = shared["tables"][_pages_of(kind)]
     ctx_lens, window = shared["ctx_lens"], cfg.layer_window(kind)
-    for t in range(T):  # static and small (1 + draft length)
+    for t in range(T if k is not None else 0):  # static and small
         k_l = write_token(k_l, table, ctx_lens + t, k[:, t])
         v_l = write_token(v_l, table, ctx_lens + t, v[:, t])
     if attention_impl == "paged" and T == 1:
         return paged_attention(
             q[:, 0], k_l, v_l, table, ctx_lens, window=window, mesh=mesh,
-            work=shared["work"].get(_pages_of(kind)))[:, None], k_l, v_l
+            work=shared["work"].get(_pages_of(kind)),
+            diff=cfg.diff_attention)[:, None], k_l, v_l
     # chunk position t writes at positions[s, t] then attends keys
     # 0..positions[s, t] inclusive — the causal triangle across the chunk
     # plus the context below it; table padding beyond a slot's blocks
@@ -398,6 +492,10 @@ def _step_attention(cfg, kind, shared, k_l, v_l, q, k, v, *,
     mask = key_idx <= positions[:, :, None]
     if window is not None:
         mask &= key_idx > positions[:, :, None] - window
+    if cfg.diff_attention:  # 2 H plain heads: a query head, a value head
+        o = xla_attention(*diff_plain_heads(q, kd, vd), causal=False,
+                          mask=mask[:, None])
+        return o.reshape(*q.shape[:-1], -1), k_l, v_l
     return xla_attention(q, kd, vd, causal=False,
                          mask=mask[:, None]), k_l, v_l
 
@@ -460,6 +558,22 @@ def _step_state(shared, state, tails, convolve, pre, g, beta):
     return o[:, None], state, tails
 
 
+@jax.named_scope("tadnn.attend_step")
+def _step_scan(shared, state, tails, piece, a):
+    """``a`` [S, 1, d_in], a ``state_space`` layer's projections: one token
+    a slot, the step form of the selective scan on the slots' rows of
+    ``state`` and ``tails``.  Returns the scan's output [S, 1, d_in]
+    float32."""
+    rows, live = shared["rows"], shared["active"]
+    c, full = piece("convolve", a, tails[rows])
+    tails = tails.at[rows].set(full[:, 1:].astype(tails.dtype))
+    delta, B, C = piece("scan_inputs", c[:, 0])
+    A, D = piece("rates")
+    y, state = ssm_step(c[:, 0], _where_rows(live, delta), A, B, C, D, state,
+                        rows)
+    return y[:, None], state, tails
+
+
 def _tenant_delta(cfg, ad, adapter_ids, positions, lora_scaling: float):
     """``_layer``'s ``adapted`` for a decode step's rows [S, T]: each slot
     gathers its factors of one layer (``ad``) by its adapter id."""
@@ -485,7 +599,9 @@ def _chunk_shared(packed, win_row, max_blocks: int):
     """What every layer of a prefill chunk reads, from the operands of
     ``pack_chunk``: the slot's table row a kind of page, the chunk's first
     position and last real row, the slot's row of a linear layer's state,
-    and which of its C rows are real."""
+    and which of its C rows are real.  Under ``"last"`` the same for the
+    chunk's last real row ALONE, a chunk of one row at its position: what
+    the layers behind ``cfg.cross_start`` run (``_walk``'s ``narrow``)."""
     MB = max_blocks
     C = packed.shape[0] - MB - 3
     table_row, pos0, last_idx = packed[:MB], packed[-3], packed[-2]
@@ -494,7 +610,19 @@ def _chunk_shared(packed, win_row, max_blocks: int):
               "real": jnp.arange(C) <= last_idx}
     if win_row.shape[0]:
         shared["rows"]["ring"] = win_row[jnp.arange(MB) % win_row.shape[0]]
+    shared["last"] = {**shared, "pos0": pos0 + last_idx,
+                      "last_idx": jnp.zeros_like(last_idx),
+                      "real": jnp.ones((1,), bool)}
     return shared
+
+
+def _narrow_chunk(rows, last_idx, C: int):
+    """Of ``rows`` [1, C + S, ...] (a chunk's C rows, then S decode rows)
+    the chunk's row ``last_idx`` and the decode rows: [1, 1 + S, ...]."""
+    if rows is None:
+        return None
+    return jnp.concatenate([jax.lax.dynamic_slice_in_dim(
+        rows, last_idx, 1, axis=1), rows[:, C:]], axis=1)
 
 
 def chunk_attention_form(cfg, kind: str | None, chunk: int,
@@ -518,12 +646,15 @@ def chunk_attention_form(cfg, kind: str | None, chunk: int,
 @jax.named_scope("tadnn.attend_chunk")
 def _chunk_attention(cfg, kind, shared, k_l, v_l, q, k, v):
     """``q``, ``k``, ``v`` [C, heads, hd]: the chunk's keys and values
-    written into the slot's pages, then its queries over them."""
+    written into the slot's pages, then its queries over them (``k`` None,
+    a ``shared_attention`` layer: over another layer's pages, which hold
+    the chunk's already)."""
     row, pos0 = shared["rows"][_pages_of(kind)], shared["pos0"]
-    k_l = write_chunk(k_l, row, pos0, k)
-    v_l = write_chunk(v_l, row, pos0, v)
+    if k is not None:
+        k_l = write_chunk(k_l, row, pos0, k)
+        v_l = write_chunk(v_l, row, pos0, v)
     return chunk_attention(q, k_l, v_l, row, pos0, cfg.layer_window(kind),
-                           cfg.kv_heads), k_l, v_l
+                           cfg.kv_heads, cfg.diff_attention), k_l, v_l
 
 
 @jax.named_scope("tadnn.attend_chunk")
@@ -585,6 +716,26 @@ def _chunk_state(shared, state, tails, convolve, pre, g, beta):
     return o, state.at[row].set(new), tails
 
 
+@jax.named_scope("tadnn.attend_chunk")
+def _chunk_scan(shared, state, tails, piece, a):
+    """``a`` [C, d_in], a ``state_space`` layer's projections of ONE slot's
+    chunk: the chunk form of the selective scan from the state the chunk
+    before left in the slot's row, or from zeros where the prompt starts.
+    Returns the scan's output [C, d_in] float32."""
+    row, last_idx = shared["row"], shared["last_idx"]
+    fresh = shared["pos0"] == 0  # a prompt starts from zeros
+    c, full = piece("convolve", a[None],
+                    jnp.where(fresh, 0, tails[row])[None])
+    # the tail the next call reads: the last K - 1 real rows
+    tails = tails.at[row].set(jax.lax.dynamic_slice_in_dim(
+        full[0], last_idx + 1, tails.shape[1]).astype(tails.dtype))
+    delta, B, C = piece("scan_inputs", c[0])
+    A, D = piece("rates")
+    y, new = ssm_chunk(c[0], _where_rows(shared["real"], delta), A, B, C, D,
+                       jnp.where(fresh, 0.0, state[row]))
+    return y, state.at[row].set(new), tails
+
+
 def _pool_constraint(kv, mesh, spec):
     """The pool's arrays held to their sharding under a ``mesh``."""
     if mesh is None or spec is None:
@@ -637,12 +788,14 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
     # per-slot, per-chunk-offset absolute positions
     positions = ctx_lens[:, None] + jnp.arange(T)[None, :]
 
-    def layer_fn(kind, sparse, branch):
-        def fn(lp, a, b, x, ad, shared, carried):  # a, b: the layer's pair
-            def attend(*rows):
+    def layer_fn(kind, sparse, branch, _narrowed):
+        def fn(lp, a, b, x, ad, shared, carried, memory, depth):
+            def attend(*rows):  # a, b: the layer's pair
                 nonlocal a, b
                 if kind == "linear_attention":
                     o, a, b = _step_state(shared, a, b, *rows)
+                elif kind == "state_space":
+                    o, a, b = _step_scan(shared, a, b, *rows)
                 elif kind == "latent_attention":
                     o, a = _step_latent(cfg, shared, a, b, *rows,
                                         attention_impl=attention_impl)
@@ -652,13 +805,13 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
                         attention_impl=attention_impl, mesh=mesh)
                 return o
 
-            x, counters, carried = _layer(
+            x, counters, carried, memory = _layer(
                 cfg, lp, kind, sparse, x, positions,
                 jnp.broadcast_to(shared["active"][:, None], (S, T)), attend,
                 adapted=_tenant_delta(cfg, ad, shared["adapter_ids"],
                                       positions, lora_scaling),
-                branch=branch, carried=carried)
-            return x, a, b, counters, carried
+                branch=branch, carried=carried, memory=memory, depth=depth)
+            return x, a, b, counters, carried, memory
 
         return fn
 
@@ -777,16 +930,37 @@ def _over_key_blocks(table_row, bs: int, C: int, pos0, window, lead, dv: int,
 
 
 def chunk_attention(q, k_layer, v_layer, table_row, pos0, window,
-                    kv_heads: int):
+                    kv_heads: int, diff: bool = False):
     """Causal (banded, with ``window``) attention of a chunk's queries
     ``q`` [C, H, hd] at positions ``pos0 .. pos0 + C`` over one slot's
-    pages of keys and values (``_over_key_blocks``)."""
+    pages of keys and values (``_over_key_blocks``).  ``diff``:
+    differential attention's first half (``ops.attention.diff_heads``): the
+    KV heads in pairs, a pair's ``H / kvH`` query-head pairs each head over
+    ITS key head of the pair against both value heads, 2 hd wide: [C, H,
+    2 hd] float32, for ``SelfAttention.differ``."""
     C, H, hd = q.shape
     bs = kv_leaf_parts(k_layer)[0].shape[1]
     KV = kv_heads
     G = H // KV
-    qg = q.reshape(C, KV, G, hd)
     scale = 1.0 / float(np.sqrt(hd))
+    if diff:
+        qp = q.reshape(C, KV // 2, G, 2, hd)  # group, pair, which of the two
+
+        def pair_block(pages):
+            kb = read_pages(k_layer, pages, KV, q.dtype).reshape(
+                -1, KV // 2, 2, hd)
+            vb = read_pages(v_layer, pages, KV, q.dtype).reshape(
+                -1, KV // 2, 2 * hd)
+            s = jnp.einsum("cgjid,tgid->gjict", qp, kb,
+                           preferred_element_type=jnp.float32) * scale
+            return s, lambda p: jnp.einsum(
+                "gjict,tgv->gjicv", p.astype(vb.dtype), vb,
+                preferred_element_type=jnp.float32)
+
+        o = _over_key_blocks(table_row, bs, C, pos0, window, (KV // 2, G, 2),
+                             2 * hd, pair_block)
+        return o.transpose(3, 0, 1, 2, 4).reshape(C, H, 2 * hd)
+    qg = q.reshape(C, KV, G, hd)
 
     def block(pages):
         kb = read_pages(k_layer, pages, KV, q.dtype).reshape(-1, KV, hd)
@@ -835,31 +1009,43 @@ def prefill_chunk(params, kv, packed, win_row, *, cfg: TransformerConfig,
     tokens, pos0 = packed[max_blocks:max_blocks + C][None], shared["pos0"]
     positions = pos0 + jnp.arange(C)[None, :]
 
-    def layer_fn(kind, sparse, branch):
-        def fn(lp, a, b, x, _, shared, carried):  # a, b: the layer's pair
-            def attend(*rows):
+    def layer_fn(kind, sparse, branch, narrowed):
+        def fn(lp, a, b, x, _, shared, carried, memory, depth):
+            sh = shared["last"] if narrowed else shared  # the rows' own
+
+            def attend(*rows):  # a, b: the layer's pair
                 nonlocal a, b
                 if kind == "linear_attention":
                     convolve, pre, g, beta = rows
-                    o, a, b = _chunk_state(shared, a, b, convolve, pre[0],
+                    o, a, b = _chunk_state(sh, a, b, convolve, pre[0],
                                            g[0], beta[0])
+                elif kind == "state_space":
+                    o, a, b = _chunk_scan(sh, a, b, rows[0], rows[1][0])
                 elif kind == "latent_attention":
-                    o, a = _chunk_latent(cfg, shared, a, rows[0],
+                    o, a = _chunk_latent(cfg, sh, a, rows[0],
                                          *(r[0] for r in rows[1:]))
                 else:
-                    o, a, b = _chunk_attention(cfg, kind, shared, a, b,
-                                               *(r[0] for r in rows))
+                    o, a, b = _chunk_attention(
+                        cfg, kind, sh, a, b,
+                        *(None if r is None else r[0] for r in rows))
                 return o[None]
 
-            x, _counters, carried = _layer(
-                cfg, lp, kind, sparse, x, positions, shared["real"][None],
-                attend, branch=branch, carried=carried)
-            return x, a, b, None, carried
+            x, _counters, carried, memory = _layer(
+                cfg, lp, kind, sparse, x,
+                sh["pos0"] + jnp.arange(x.shape[1])[None, :],
+                sh["real"][None], attend, branch=branch, carried=carried,
+                memory=memory, depth=depth)
+            return x, a, b, None, carried, memory
 
         return fn
 
+    # behind ``cfg.cross_start`` only the row whose logits are returned
+    narrow = lambda rows: None if rows is None else (
+        jax.lax.dynamic_slice_in_dim(rows, shared["last_idx"], 1, axis=1))
     x = _embed(params, cfg, tokens, positions)
-    x, kv, _ = _walk(cfg, params, kv, x, layer_fn, shared)
+    x, kv, _ = _walk(cfg, params, kv, x, layer_fn, shared, narrow=narrow)
+    if cfg.cross_start < cfg.n_layers:
+        return kv, _logits(params, cfg, x[:, 0])
     last = jax.lax.dynamic_index_in_dim(x, shared["last_idx"], axis=1,
                                         keepdims=False)
     return kv, _logits(params, cfg, last)
@@ -903,48 +1089,71 @@ def chunk_and_step(params, kv, packed, prev, win_row, win_tables, rng, *,
     positions = jnp.concatenate([pos0 + jnp.arange(C), ctx_lens])[None]
     valid = jnp.concatenate([real, active])[None]
 
-    def layer_fn(kind, sparse, branch):
-        def fn(lp, a, b, x, _, shared, carried):  # a, b: the layer's pair
-            def attend(*rows):
+    last_idx = shared["chunk"]["last_idx"]
+
+    def layer_fn(kind, sparse, branch, narrowed):
+        # the chunk's rows of the call's: all C, or behind
+        # ``cfg.cross_start`` the one whose logits are wanted
+        n = 1 if narrowed else C
+
+        def fn(lp, a, b, x, _, shared, carried, memory, depth):
+            chunk, step = shared["chunk"], shared["step"]
+            if narrowed:
+                chunk = chunk["last"]
+
+            def attend(*rows):  # a, b: the layer's pair
                 nonlocal a, b
+                ours = lambda rows: (None if r is None else r[0, :n]
+                                     for r in rows)
+                theirs = lambda rows: (None if r is None else r[0, n:, None]
+                                       for r in rows)
                 if kind == "linear_attention":
                     convolve, *rows = rows
-                    oc, a, b = _chunk_state(shared["chunk"], a, b, convolve,
-                                            *(r[0, :C] for r in rows))
-                    os_, a, b = _step_state(shared["step"], a, b, convolve,
-                                            *(r[0, C:, None] for r in rows))
+                    oc, a, b = _chunk_state(chunk, a, b, convolve,
+                                            *ours(rows))
+                    os_, a, b = _step_state(step, a, b, convolve,
+                                            *theirs(rows))
+                elif kind == "state_space":
+                    piece, *rows = rows
+                    oc, a, b = _chunk_scan(chunk, a, b, piece, *ours(rows))
+                    os_, a, b = _step_scan(step, a, b, piece, *theirs(rows))
                 elif kind == "latent_attention":
                     piece, *rows = rows
-                    oc, a = _chunk_latent(cfg, shared["chunk"], a, piece,
-                                          *(r[0, :C] for r in rows))
+                    oc, a = _chunk_latent(cfg, chunk, a, piece, *ours(rows))
                     os_, a = _step_latent(
-                        cfg, shared["step"], a, b, piece,
-                        *(r[0, C:, None] for r in rows),
+                        cfg, step, a, b, piece, *theirs(rows),
                         attention_impl=attention_impl)
                 else:
-                    oc, a, b = _chunk_attention(cfg, kind, shared["chunk"],
-                                                a, b,
-                                                *(r[0, :C] for r in rows))
+                    oc, a, b = _chunk_attention(cfg, kind, chunk, a, b,
+                                                *ours(rows))
                     os_, a, b = _step_attention(
-                        cfg, kind, shared["step"], a, b,
-                        *(r[0, C:, None] for r in rows),
+                        cfg, kind, step, a, b, *theirs(rows),
                         attention_impl=attention_impl, mesh=mesh)
                 return jnp.concatenate(
                     [oc, os_[:, 0].astype(oc.dtype)])[None]
 
-            x, counters, carried = _layer(
-                cfg, lp, kind, sparse, x, positions, valid, attend,
-                branch=branch, carried=carried)
-            return x, a, b, counters, carried
+            x, counters, carried, memory = _layer(
+                cfg, lp, kind, sparse, x,
+                _narrow_chunk(positions, last_idx, C) if narrowed
+                else positions,
+                _narrow_chunk(valid, last_idx, C) if narrowed else valid,
+                attend, branch=branch, carried=carried, memory=memory,
+                depth=depth)
+            return x, a, b, counters, carried, memory
 
         return fn
 
     x = _embed(params, cfg, jnp.concatenate(
         [packed[MB:MB + C], tok[:, 0]])[None], positions)
-    x, kv, stats = _walk(cfg, params, kv, x, layer_fn, shared)
+    x, kv, stats = _walk(
+        cfg, params, kv, x, layer_fn, shared,
+        narrow=lambda rows: _narrow_chunk(rows, last_idx, C))
     # the head once: the S decode rows, then the chunk's last real row
-    last = jax.lax.dynamic_index_in_dim(
-        x[0], shared["chunk"]["last_idx"], keepdims=True)
+    if cfg.cross_start < cfg.n_layers:  # narrowed: that row lies first
+        C = 1
+        last = x[0, :1]
+    else:
+        last = jax.lax.dynamic_index_in_dim(x[0], last_idx, keepdims=True)
     logits = _logits(params, cfg, jnp.concatenate([x[0, C:], last]))
     with jax.named_scope("tadnn.head"):
         out = jnp.where(active, _sample(logits[:S], rng, sample), 0)

@@ -25,8 +25,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.attention import attention
+from ..ops.attention import attention, diff_plain_heads
 from ..ops.gated_delta import causal_conv, gated_delta_chunk, l2norm
+from ..ops.ssm import ssm_chunk
 from ..parallel.context import shard_activations
 from ..parallel.expert import held_expert_ffn, route_top_k
 
@@ -45,7 +46,9 @@ class TransformerConfig:
     # 'gelu_exact' is the erf formulation (HF BERT's hidden_act='gelu');
     # plain 'gelu' is the tanh approximation (GPT-2's gelu_new)
     act: Literal["gelu", "gelu_exact", "swiglu"] = "gelu"
-    pos: Literal["learned", "rope"] = "learned"
+    # "none": no positional signal at all (a model whose recurrent layers
+    # carry the order: nothing is added to the embedding, nothing rotated)
+    pos: Literal["learned", "rope", "none"] = "learned"
     # False -> bidirectional self-attention: the same backbone serves
     # encoder-only families (BERT, models/bert.py)
     causal: bool = True
@@ -84,7 +87,14 @@ class TransformerConfig:
     # recurrent state, ``GatedDeltaMixer``, whose decay is a head's or a
     # channel's by the ``linear_*`` keys below) or "latent_attention" (one
     # low-rank latent a token in the place of per-head keys and values,
-    # ``LatentAttention``).  Given, the layers are built
+    # ``LatentAttention``), or one of a decoder-hybrid-decoder's three:
+    # "state_space" (a selective scan over a diagonal state, ``MambaMixer``,
+    # the ``ssm_*`` keys), "gated_memory" (no cache at all: the scan output
+    # of the nearest ``state_space`` layer BEFORE it, of the same token,
+    # gated by the layer's own input, ``GatedMemory``) and
+    # "shared_attention" (queries and an output projection alone: it
+    # attends the keys and values of the nearest ``full_attention`` layer
+    # before it, whose cache is the only one).  Given, the layers are built
     # one by one (``layers_0`` .. in the parameter tree, no ``nn.scan``),
     # each of its own kind, and the keys below apply; None keeps the one
     # scanned layer every other model here has.
@@ -161,6 +171,23 @@ class TransformerConfig:
     # the scaled latent
     latent_q_scale: float = 1.0
     latent_kv_scale: float = 1.0
+    # the ``state_space`` layers (valid only with one; a ``gated_memory``
+    # layer is as wide as their scan): the inner channels, the numbers a
+    # channel's state holds and the bottleneck of the step size's
+    # projection (the causal depthwise convolution has ``SSM_CONV_TAPS``
+    # taps and a bias)
+    ssm_inner: int | None = None
+    ssm_state: int | None = None
+    ssm_dt_rank: int | None = None
+    # differential attention on every attention layer (sliding, full,
+    # shared): query heads in ADJACENT pairs, a pair's two softmaxes (each
+    # over its own key head of a pair of KV heads) subtracted, over values
+    # twice a key's width (the pair's two value heads side by side);
+    # ``SelfAttention.differ`` has the rest
+    diff_attention: bool = False
+    # biases in the FFN: None -> a model with LayerNorm has them (GPT-2),
+    # one with RMSNorm has none; the attention projections' go by the norm
+    mlp_bias: bool | None = None
 
     def __post_init__(self):
         kinds = self.layer_types
@@ -204,6 +231,27 @@ class TransformerConfig:
                 "a latent_attention layer that rotates (pos='rope', "
                 "rope_layers='all') needs an even latent_rope_head_dim; "
                 "rope_layers='sliding' leaves it without any rotation")
+        given = [k for k in SSM_SIZES if getattr(self, k)]
+        if "state_space" not in (kinds or ()):
+            if given:
+                raise ValueError(f"{given} describe state_space layers: "
+                                 f"layer_types has none")
+        elif not all(getattr(self, k) for k in SSM_SIZES):
+            raise ValueError(f"a state_space layer needs {SSM_SIZES}")
+        for i, kind in enumerate(kinds or ()):
+            # what a layer without a cache of its own reads lies BEFORE it
+            if kind in SOURCE_KINDS and self.source_layer(i) is None:
+                raise ValueError(
+                    f"layer {i} ({kind}) needs a {SOURCE_KINDS[kind]} layer "
+                    f"before it")
+        if self.diff_attention and (
+                kinds is None or self.n_heads % 2 or self.kv_heads % 2
+                or self.n_heads % self.kv_heads or self.qk_norm
+                or self.attn_gate):
+            raise ValueError(
+                "diff_attention pairs adjacent heads of a model with "
+                "layer_types: even n_heads and n_kv_heads, no qk_norm, no "
+                "attn_gate")
         if kinds is not None:
             if not self.pre_norm and not self.sandwich_norm:
                 raise ValueError("pre_norm=False leaves a layer without "
@@ -236,7 +284,8 @@ class TransformerConfig:
             given = [k for k in ("qk_norm", "attn_gate", "sandwich_norm",
                                  "embed_scale", "head_size",
                                  "n_dense_layers", "experts_published",
-                                 "zero_experts", "shortcut_experts")
+                                 "zero_experts", "shortcut_experts",
+                                 "diff_attention")
                      if getattr(self, k)] + ["pre_norm"] * (not self.pre_norm)
             if given:
                 raise ValueError(
@@ -279,6 +328,39 @@ class TransformerConfig:
         zero-compute ones."""
         return self.experts_published + self.zero_experts
 
+    @property
+    def has_mlp_bias(self) -> bool:
+        return (self.norm == "layernorm" if self.mlp_bias is None
+                else self.mlp_bias)
+
+    def source_layer(self, i: int) -> int | None:
+        """The layer whose cache (a ``shared_attention`` layer: the pages of
+        the nearest ``full_attention`` layer before it) or whose scan output
+        of the same token (a ``gated_memory`` layer: the nearest
+        ``state_space`` layer before it) layer ``i`` reads; None for a layer
+        that reads its own, or where no such layer lies before it."""
+        want = SOURCE_KINDS.get(self.layer_types[i])
+        return next((j for j in range(i - 1, -1, -1)
+                     if self.layer_types[j] == want), None)
+
+    @property
+    def cross_start(self) -> int:
+        """The first layer of the model's last run of layers that keep NO
+        cache of their own (``gated_memory``, ``shared_attention``: a
+        cross-decoder); ``n_layers`` where the last layer keeps one.  A
+        prompt's rows need not run these layers, but for the row whose
+        logits are wanted: nothing a later token reads is written there."""
+        kinds = self.layer_types or ()
+        i = len(kinds)
+        while i and kinds[i - 1] in SOURCE_KINDS:
+            i -= 1
+        return i if kinds else self.n_layers
+
+    def lambda_init(self, depth):
+        """Differential attention's constant of layer ``depth`` (0-based; a
+        number or a traced scalar): ``0.8 - 0.6 exp(-0.3 depth)``."""
+        return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(depth, jnp.float32))
+
     def layer_window(self, kind: str | None) -> int | None:
         """The causal band of a layer of ``kind`` (None: the model's one
         kind of layer)."""
@@ -314,6 +396,14 @@ class TransformerConfig:
         ``layer_types``): attention, or the gated delta rule's projections,
         filters, decay and output norm."""
         d, hd = self.d_model, self.head_dim
+        if kind == "state_space":
+            n, N, R = self.ssm_inner, self.ssm_state, self.ssm_dt_rank
+            return (d * 2 * n + n * d  # in, out
+                    + (SSM_CONV_TAPS + 1) * n  # filters, their bias
+                    + n * (R + 2 * N) + R * n  # the token's maps, the step
+                    + n + N * n + n)  # dt_bias, A_log, D
+        if kind == "gated_memory":
+            return 2 * d * self.ssm_inner
         if kind == "linear_attention":
             LH = self.linear_value_heads
             qk = self.linear_key_heads * self.linear_key_head_dim
@@ -341,9 +431,14 @@ class TransformerConfig:
                     + d * (rkv + rot) + rkv  # the latent and the rotated key
                     + rkv * H * (nope + dv) + H * dv * d)  # up, out
         q, kv = self.n_heads * hd, self.kv_heads * hd
+        if kind == "shared_attention":
+            kv = 0  # queries and the way out alone
         normed = {"head": 2 * hd, "projection": q + kv}[self.qk_norm_over]
         return (2 * d * q + 2 * d * kv + (d * q if self.attn_gate else 0)
-                + (normed if self.qk_norm else 0))
+                + (normed if self.qk_norm else 0)
+                + (q + 2 * kv + d if self.norm == "layernorm" else 0)
+                # four vectors of lambda, the pair's norm over 2 hd
+                + (6 * hd if self.diff_attention else 0))
 
     def num_params(self) -> int:
         """Analytic parameter count (embedding included once if tied);
@@ -359,12 +454,14 @@ class TransformerConfig:
             sparse = n_sparse and (d * E + E + 3 * d * fe * (
                 self.n_experts_held + self.shared_experts))
             mixers = sum(self.mixer_params(kind) for kind in self.layer_types)
-            norms = (2 * self.sandwich_norm + 2 * self.pre_norm) * d
+            a_norm = d * (2 if self.norm == "layernorm" else 1)  # its bias
+            norms = (2 * self.sandwich_norm + 2 * self.pre_norm) * a_norm
             # a shortcut branch lies BESIDE its sublayers' dense FFNs
             n_dense = L if self.shortcut_experts else L - n_sparse
-            return (mixers + L * norms + n_dense * 3 * d * f
+            dense = 3 * d * f + (2 * f + d if self.has_mlp_bias else 0)
+            return (mixers + L * norms + n_dense * dense
                     + n_sparse * sparse
-                    + v * d * (1 if self.tie_embeddings else 2) + d)
+                    + v * d * (1 if self.tie_embeddings else 2) + a_norm)
         mlp = (3 if self.act == "swiglu" else 2) * d * f
         norms = (2 * d) * L + (d if self.final_norm else 0) + (
             d if self.embed_norm else 0)
@@ -375,7 +472,13 @@ class TransformerConfig:
 
 
 LAYER_KINDS = ("sliding_attention", "full_attention", "linear_attention",
-               "latent_attention")
+               "latent_attention", "state_space", "gated_memory",
+               "shared_attention")
+# a kind that keeps no cache of its own -> the kind of layer it reads
+SOURCE_KINDS = {"gated_memory": "state_space",
+                "shared_attention": "full_attention"}
+SSM_SIZES = ("ssm_inner", "ssm_state", "ssm_dt_rank")
+SSM_CONV_TAPS = 4  # Mamba-1's; a key the day a configuration gives another
 LATENT_SIZES = ("latent_kv_rank", "latent_nope_head_dim",
                 "latent_rope_head_dim", "latent_value_head_dim")
 
@@ -429,22 +532,34 @@ class SelfAttention(nn.Module):
             feats, axis=-1, dtype=cfg.dtype, use_bias=bias
         )
         self.q_proj = dense((cfg.n_heads, hd))
-        self.k_proj = dense((cfg.kv_heads, hd))
-        self.v_proj = dense((cfg.kv_heads, hd))
+        if self.kind != "shared_attention":  # another layer's keys and values
+            self.k_proj = dense((cfg.kv_heads, hd))
+            self.v_proj = dense((cfg.kv_heads, hd))
         if cfg.qk_norm:
             self.q_norm = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)
             self.k_norm = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)
         if cfg.attn_gate:
             self.gate_proj = dense((cfg.n_heads, hd))
+        if cfg.diff_attention:
+            vec = lambda name: self.param(
+                name, nn.initializers.normal(0.1), (hd,), jnp.float32)
+            self.lambdas = tuple(vec(f"lambda_{n}")
+                                 for n in ("q1", "k1", "q2", "k2"))
+            self.sub_norm = nn.RMSNorm(epsilon=cfg.norm_eps,
+                                       dtype=jnp.float32)
         self.o_proj = nn.DenseGeneral(
             cfg.d_model, axis=(-2, -1), dtype=cfg.dtype, use_bias=bias
         )
 
     def qkv(self, x, positions):
         """Projected (normed, rope-rotated) q/k/v for a chunk at
-        ``positions``."""
+        ``positions``; on a ``shared_attention`` layer, which has no keys
+        and values of its own, ``(q, None, None)``."""
         cfg = self.cfg
-        q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
+        q = self.q_proj(x)
+        if self.kind == "shared_attention":
+            return q, None, None
+        k, v = self.k_proj(x), self.v_proj(x)
         if cfg.qk_norm and cfg.qk_norm_over == "projection":
             q = self.q_norm(q.reshape(*q.shape[:-2], -1)).reshape(q.shape)
             k = self.k_norm(k.reshape(*k.shape[:-2], -1)).reshape(k.shape)
@@ -455,6 +570,24 @@ class SelfAttention(nn.Module):
             k = rope(k, positions, cfg.rope_theta)
         return q, k, v
 
+    def differ(self, o, depth):
+        """Differential attention's second half.  ``o`` [..., H, 2 hd]
+        float32 is what each query head's softmax gave over its pair of
+        value heads (``diff_heads``); heads ``2j`` and ``2j + 1`` are pair
+        ``j``: ``(o_2j - lambda o_2j+1)`` through an RMSNorm over the
+        ``2 hd`` (one gain for all pairs), times ``1 - lambda_init``;
+        ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init`` with
+        ``lambda_init`` the layer's (``cfg.lambda_init(depth)``).  Returns
+        [..., H, hd], the pairs' results as ``o_proj`` takes them."""
+        cfg = self.cfg
+        q1, k1, q2, k2 = self.lambdas
+        init = cfg.lambda_init(depth)
+        lam = jnp.exp(jnp.sum(q1 * k1)) - jnp.exp(jnp.sum(q2 * k2)) + init
+        o = o.astype(jnp.float32).reshape(*o.shape[:-2], -1, 2, o.shape[-1])
+        d = self.sub_norm(o[..., 0, :] - lam * o[..., 1, :]) * (1.0 - init)
+        return d.reshape(*d.shape[:-2], cfg.n_heads, cfg.head_dim).astype(
+            cfg.dtype)
+
     def out_proj(self, out, x=None):
         """``x`` is the layer's normed input, which the output gate reads
         (``cfg.attn_gate``)."""
@@ -463,14 +596,136 @@ class SelfAttention(nn.Module):
             out = (out.astype(jnp.float32) * gate).astype(out.dtype)
         return self.o_proj(out)
 
-    def __call__(self, x, positions, mask=None):
+    def __call__(self, x, positions, mask=None, kv=None, depth=0):
+        """``kv``: the keys and values a ``shared_attention`` layer attends
+        (its source layer's).  Returns ``(output, (k, v))``: the keys and
+        values attended, for the layers that share them."""
+        cfg = self.cfg
         q, k, v = self.qkv(x, positions)
+        if self.kind == "shared_attention":
+            k, v = kv
+        heads = diff_plain_heads(q, k, v) if cfg.diff_attention else (q, k, v)
         out = attention(
-            q, k, v, causal=self.cfg.causal,
-            window=self.cfg.layer_window(self.kind),
-            mask=mask, impl=self.cfg.attention_impl,
-        )
-        return self.out_proj(out, x)
+            *heads, causal=cfg.causal, window=cfg.layer_window(self.kind),
+            mask=mask, impl=cfg.attention_impl)
+        if cfg.diff_attention:
+            out = self.differ(
+                out.reshape(*q.shape[:-1], 2 * cfg.head_dim), depth)
+        return self.out_proj(out, x), (k, v)
+
+
+class MambaMixer(nn.Module):
+    """The mixer of a ``state_space`` layer (Mamba-1; ``ops/ssm.py`` has the
+    recurrence): ``[a, z] = W_in x``; ``c = silu(conv(a) + b)``, a causal
+    depthwise convolution of ``SSM_CONV_TAPS`` taps; ``[delta, B, C] = W_x
+    c``; ``Delta = softplus(W_dt delta + dt_bias)``; the selective scan
+    gives ``y``; out ``= W_out(y * silu(z))``.  ``y``, before the gate, is
+    also the token's MEMORY that later ``gated_memory`` layers read.
+    setup()-style: the serving programs apply the pieces (``method=``)
+    round their own read and write of the cached state and tail.
+
+    Parameters that stay float32 when the serving engine rounds the rest
+    (``decode.compute_dtype_params``): ``A_log`` [N, d_in] (the published
+    ``[d_in, N]`` transposed: channels in the lanes), ``dt_bias``, ``D``,
+    the filters ``conv`` and ``conv_bias``."""
+
+    cfg: TransformerConfig
+
+    def setup(self):
+        cfg = self.cfg
+        n, N, R = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+        dense = lambda feats: nn.Dense(feats, dtype=cfg.dtype, use_bias=False)
+        self.in_proj, self.x_proj = dense(2 * n), dense(R + 2 * N)
+        self.dt_proj, self.o_proj = dense(n), dense(cfg.d_model)
+        self.conv = self.param("conv", nn.initializers.normal(0.02),
+                               (SSM_CONV_TAPS, n), jnp.float32)
+        self.conv_bias = self.param("conv_bias", nn.initializers.zeros, (n,),
+                                    jnp.float32)
+
+        def a_log(key, shape):  # A = 1 .. N a channel (S4D-real)
+            del key
+            return jnp.log(jnp.broadcast_to(
+                jnp.arange(1, N + 1, dtype=jnp.float32)[:, None], shape))
+
+        self.A_log = self.param("A_log", a_log, (N, n))
+        self.dt_bias = self.param("dt_bias", dt_bias_init, (n,))
+        self.D = self.param("D", nn.initializers.ones, (n,), jnp.float32)
+
+    def project(self, x):
+        """``x`` [..., d] -> ``(a, z)`` [..., d_in] each: the scan's input
+        before its convolution, and the gate."""
+        a, z = jnp.split(self.in_proj(x), 2, axis=-1)
+        return a, z
+
+    def convolve(self, a, tail=None):
+        """``a`` [B, T, d_in] of ``project`` and ``tail`` [B, K - 1, d_in],
+        the K - 1 rows before it (None: the sequence starts here).  Returns
+        ``(c [B, T, d_in], full [B, K - 1 + T, d_in])``; ``full``'s last K -
+        1 rows are the next call's tail."""
+        cfg = self.cfg
+        if tail is None:
+            tail = jnp.zeros((a.shape[0], SSM_CONV_TAPS - 1, a.shape[-1]),
+                             a.dtype)
+        full = jnp.concatenate([tail.astype(a.dtype), a], axis=1)
+        c = causal_conv(full, self.conv, a.shape[1], self.conv_bias)
+        return c.astype(cfg.dtype), full
+
+    def scan_inputs(self, c):
+        """``c`` [..., d_in] -> ``(Delta [..., d_in], B, C [..., N])``, all
+        float32: what the scan takes of a token beside ``c`` itself."""
+        cfg = self.cfg
+        N, R = cfg.ssm_state, cfg.ssm_dt_rank
+        dbc = self.x_proj(c)
+        delta = nn.softplus(self.dt_proj(dbc[..., :R]).astype(jnp.float32)
+                            + self.dt_bias)
+        B, C = jnp.split(dbc[..., R:].astype(jnp.float32), [N], axis=-1)
+        return delta, B, C
+
+    def rates(self):
+        """``(A [N, d_in] < 0, D [d_in])``."""
+        return -jnp.exp(self.A_log), self.D
+
+    def out_proj(self, y, z):
+        """``y`` [..., d_in] float32, what the scan gave; ``z`` the gate."""
+        return self.o_proj((y * nn.silu(z.astype(jnp.float32))).astype(
+            self.cfg.dtype))
+
+    def __call__(self, x):
+        """``(the layer's output [B, T, d], y [B, T, d_in] float32)``."""
+        a, z = self.project(x)
+        c, _ = self.convolve(a)
+        delta, B, C = self.scan_inputs(c)
+        A, D = self.rates()
+        h0 = jnp.zeros(A.shape, jnp.float32)
+        y = jax.vmap(lambda c, dt, b, cc: ssm_chunk(c, dt, A, b, cc, D, h0)[0]
+                     )(c, delta, B, C)
+        return self.out_proj(y, z), y
+
+
+class GatedMemory(nn.Module):
+    """The mixer of a ``gated_memory`` layer (a Gated Memory Unit): the
+    memory ``m`` [..., d_in] float32, the scan output of the nearest
+    ``state_space`` layer before this one for the SAME token, gated by the
+    layer's own input: ``W_2(m * silu(W_1 x))``.  No state, no cache."""
+
+    cfg: TransformerConfig
+
+    def setup(self):
+        cfg = self.cfg
+        dense = lambda feats: nn.Dense(feats, dtype=cfg.dtype, use_bias=False)
+        self.in_proj, self.o_proj = dense(cfg.ssm_inner), dense(cfg.d_model)
+
+    def __call__(self, x, m):
+        gate = nn.silu(self.in_proj(x).astype(jnp.float32))
+        return self.o_proj((m * gate).astype(self.cfg.dtype))
+
+
+def dt_bias_init(key, shape):
+    """A recurrent mixer's ``dt_bias``: steps log-uniform in (0.001, 0.1),
+    through the inverse of the softplus that reads them."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, jnp.float32, np.log(1e-3), np.log(0.1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 class GatedDeltaMixer(nn.Module):
@@ -524,13 +779,9 @@ class GatedDeltaMixer(nn.Module):
             return jnp.log(jax.random.uniform(key, shape, jnp.float32,
                                               1e-3, 16.0))
 
-        def dt_bias(key, shape):  # steps log-uniform in (0.001, 0.1)
-            dt = jnp.exp(jax.random.uniform(
-                key, shape, jnp.float32, np.log(1e-3), np.log(0.1)))
-            return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
-
         self.A_log = self.param("A_log", a_log, (H,))
-        self.dt_bias = self.param("dt_bias", dt_bias, (int(np.prod(decays)),))
+        self.dt_bias = self.param("dt_bias", dt_bias_init,
+                                  (int(np.prod(decays)),))
         self.o_norm = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32)
 
     def project(self, x):
@@ -729,7 +980,7 @@ class MLPBlock(nn.Module):
 
     def setup(self):
         cfg = self.cfg
-        bias = cfg.norm == "layernorm"
+        bias = cfg.has_mlp_bias
         dense = lambda feats: nn.Dense(feats, dtype=cfg.dtype, use_bias=bias)
         width = self.width or cfg.ff_dim
         if cfg.act == "swiglu":
@@ -861,17 +1112,31 @@ class KindDecoderLayer(nn.Module):
     kind: str
     sparse: bool
     branch: str | None = None
+    depth: int = 0  # the layer's index (differential attention's constant)
 
     @nn.compact
-    def __call__(self, x, positions, mask=None, carried=None):
+    def __call__(self, x, positions, mask=None, carried=None, passed=None):
+        """``passed``: what layers before this one hand to those that keep
+        nothing of their own, ``{"memory": a state_space layer's scan
+        output, "kv": a full_attention layer's keys and values}``; the
+        layer returns it with its own in.  Returns ``(x, carried,
+        passed)``."""
         cfg = self.cfg
+        passed = dict(passed or {})
         h = make_norm(cfg, "attn_norm")(x) if cfg.pre_norm else x
         if self.kind == "linear_attention":
             h = GatedDeltaMixer(cfg, name="attn")(h)
         elif self.kind == "latent_attention":
             h = LatentAttention(cfg, name="attn")(h, positions, mask)
+        elif self.kind == "state_space":
+            h, passed["memory"] = MambaMixer(cfg, name="attn")(h)
+        elif self.kind == "gated_memory":
+            h = GatedMemory(cfg, name="attn")(h, passed["memory"])
         else:
-            h = SelfAttention(cfg, self.kind, name="attn")(h, positions, mask)
+            h, kv = SelfAttention(cfg, self.kind, name="attn")(
+                h, positions, mask, passed.get("kv"), self.depth)
+            if self.kind == "full_attention":
+                passed["kv"] = kv
         if cfg.sandwich_norm:
             h = make_norm(cfg, "post_attn_norm")(h)
         x, _, carried = ffn_sublayer(
@@ -881,7 +1146,7 @@ class KindDecoderLayer(nn.Module):
             experts=lambda u: SparseMLP(
                 cfg, name="mlp" if self.sparse else "moe")(u),
             post_norm=lambda h: make_norm(cfg, "post_mlp_norm")(h))
-        return x if self.branch is None else (x, carried)
+        return x, carried, passed
 
 
 def layer_plan(cfg: TransformerConfig
@@ -927,7 +1192,7 @@ class DecoderLayer(nn.Module):
         # post-norm (original transformer / BERT): sublayer on the raw
         # stream, norm AFTER the residual add; pre-norm: norm first
         h = x if post else make_norm(cfg, "attn_norm")(x)
-        h = SelfAttention(cfg, name="attn")(h, positions, mask)
+        h, _ = SelfAttention(cfg, name="attn")(h, positions, mask)
         if cfg.dropout_rate:
             h = nn.Dropout(cfg.dropout_rate, deterministic=not self.has_rng("dropout"))(h)
         x = x + h
@@ -1043,12 +1308,11 @@ def apply_decoder_backbone(
     if cfg.layer_types is not None:
         # layers that differ: one module a layer, each of its own kind
         carried = None  # what an open shortcut branch holds
-        for name, kind, sparse, branch in layer_plan(cfg):
-            layer = KindDecoderLayer(cfg, kind, sparse, branch, name=name)
-            if branch is None:
-                x = layer(x, positions, mask)
-            else:
-                x, carried = layer(x, positions, mask, carried)
+        passed = None  # a scan's output, a full layer's keys and values
+        for i, (name, kind, sparse, branch) in enumerate(layer_plan(cfg)):
+            x, carried, passed = KindDecoderLayer(
+                cfg, kind, sparse, branch, depth=i, name=name)(
+                    x, positions, mask, carried, passed)
     elif cfg.scan_layers:
         def body(mdl, carry, _):
             return run_layer(mdl, *carry), None

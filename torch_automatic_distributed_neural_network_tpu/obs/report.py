@@ -67,6 +67,10 @@ def _finite(vals) -> list[float]:
             if isinstance(v, (int, float)) and math.isfinite(v)]
 
 
+def _ratio(num, den) -> float | None:
+    return num / den if den else None
+
+
 def _mean(vals) -> float | None:
     vals = _finite(vals)
     return sum(vals) / len(vals) if vals else None
@@ -447,7 +451,16 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                 "layer_kinds", "experts_held", "experts_published",
                 "zero_experts", "shortcut_experts", "kv_bytes_full",
                 "kv_bytes_window", "state_bytes_linear", "conv_bytes_linear",
-                "linear_mixer", "kv_bytes_latent", "latent_row")},
+                "linear_mixer", "kv_bytes_latent", "latent_row",
+                "attention_form", "cross_start", "paged_sets",
+                "shared_readers")},
+            # rows that ran the cross-decoder over rows that ran the layers
+            # before it (``read.cross_rows`` / ``read.self_rows``)
+            "cross_rows_share": _ratio(
+                sum((e.get("read") or {}).get("cross_rows", 0)
+                    for e in ssteps),
+                sum((e.get("read") or {}).get("self_rows", 0)
+                    for e in ssteps)),
             # the state rows a call's step kernels read and wrote
             "mean_state_rows": _mean(e.get("state_rows") for e in ssteps),
             # the decode steps' expert counters (engines with experts)
@@ -1123,11 +1136,29 @@ def format_report(report: dict) -> str:
                     f"of recurrent state + "
                     f"{sv['conv_bytes_linear'] / 2**30:.3f} GiB of "
                     f"convolution tails "
-                    f"({kinds.count('linear_attention')} linear layers)"
+                    + " and ".join(
+                        f"({kinds.count(kind)} {name} layers)"
+                        for kind, name in (("linear_attention", "linear"),
+                                           ("state_space", "state-space"))
+                        if kind in kinds)
                     + (", {0}: a decay a {1}".format(*sv["linear_mixer"])
                        if sv.get("linear_mixer") else "")
                     + (f", {sv['mean_state_rows']:.1f} state rows a call"
                        if sv.get("mean_state_rows") is not None else ""))
+            if sv.get("cross_start") is not None:
+                eparts.append(
+                    f"cross-decoder from layer {sv['cross_start']}: "
+                    f"{sv['paged_sets']} paged sets for "
+                    f"{sv['paged_sets'] + sv['shared_readers']} attention "
+                    f"layers ({sv['shared_readers']} read another layer's "
+                    f"pages)"
+                    + (f", its layers ran {sv['cross_rows_share']:.1%} of "
+                       f"the rows the layers before it ran (read.cross_rows "
+                       f"/ read.self_rows)"
+                       if sv.get("cross_rows_share") is not None else ""))
+            if sv.get("attention_form") == "differential":
+                eparts.append("attention: differential (pairs of heads, two "
+                              "softmaxes subtracted, values twice as wide)")
             if sv.get("experts_published"):
                 eparts.append(
                     f"experts {sv['experts_held']} held of "

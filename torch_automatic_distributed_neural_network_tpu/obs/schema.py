@@ -351,6 +351,17 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # (one row a token, key and value at once), and that row:
             # [the latent's rank, the rotated key part, numbers stored]
             "kv_bytes_latent": "int", "latent_row": "list?",
+            # the form the attention layers run ("softmax", or
+            # "differential": adjacent head pairs, two softmaxes subtracted
+            # over values twice a key's width, decoded by the folded MXU
+            # kernel at another wiring of its lanes); and a
+            # decoder-hybrid-decoder's layout: the first layer of the last
+            # run of layers that keep no cache (None: the last layer keeps
+            # one), the layers that hold paged keys and values of their own,
+            # and those that read another layer's (state_bytes_linear and
+            # conv_bytes_linear count a state_space layer's rows too)
+            "attention_form": "str", "cross_start": "int?",
+            "paged_sets": "int?", "shared_readers": "int?",
             # the row tiles the expert layers of a call lay out, whatever
             # lands in them: [a call with a chunk, a decode-only call],
             # summed over the expert layers (0 and 0 without any)
@@ -402,9 +413,17 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # the last chunk's first position (the keys its attention
             # reads beyond its own): {"programs", "rows", "ctx_keys",
             # "chunk_rows", "chunk_pos"}, host integers known at
-            # dispatch.  Absent where the call read nothing.  A chunk that
-            # went out in its own place behind an unread step is on the
-            # read after that step's, which is the one that waits for it
+            # dispatch; where the model ends in layers that keep no cache
+            # (serve.engine's cross_start) also "self_rows" and
+            # "cross_rows": the rows that entered the programs' first
+            # layer and the rows that entered layer cross_start, as the
+            # programs' walks noted them when they were traced
+            # (programs.rows_walked; a chunk's rows but the one whose
+            # logits are wanted stop there: a decode step S and S, a chunk
+            # that carries a step C + S and 1 + S).  Absent where the call
+            # read nothing.  A chunk that went out in its own place behind
+            # an unread step is on the read after that step's, which is
+            # the one that waits for it
             "read": "dict",
             # Python's cyclic collector inside the call: its seconds (all
             # generations; they lie inside whichever phase allocated) and
@@ -435,9 +454,9 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # model with expert layers, fused ones too
             "moe_zero_pairs": "int", "moe_rows": "int",
             # the slots whose state row the call's step kernel read and
-            # wrote, summed over the linear_attention layers (decode rows x
-            # linear layers); with the step's tokens, on a model with such
-            # layers alone
+            # wrote, summed over the layers that keep one (linear_attention,
+            # state_space: decode rows x those layers); with the step's
+            # tokens, on a model with such layers alone
             "state_rows": "int",
             # the grid steps the decode step's paged attention calls ran
             # (the live (slot, key group) items of the folded kernel's

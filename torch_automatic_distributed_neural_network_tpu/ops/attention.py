@@ -40,6 +40,29 @@ Impl = Literal["xla", "chunked", "flash", "ring", "auto"]
 CHUNKED_MIN_SEQ = 1024
 
 
+def diff_heads(n_heads: int, kv_heads: int) -> tuple[np.ndarray, np.ndarray]:
+    """Differential attention's wiring: ``(key_of [H], values_of [H, 2])``.
+    Query heads ``2j, 2j + 1`` are pair ``j``; KV heads ``2g, 2g + 1`` are
+    group ``g``, which serves the ``H / kvH`` pairs ``j // (H / kvH) == g``.
+    Query head ``2j + i`` scores key head ``2g + i`` and reads BOTH value
+    heads of the group, side by side (values twice as wide as keys)."""
+    h = np.arange(n_heads)
+    g = h // (2 * (n_heads // kv_heads))
+    return 2 * g + h % 2, np.stack([2 * g, 2 * g + 1], axis=-1)
+
+
+def diff_plain_heads(q, k, v):
+    """Differential attention's first half as 2 H PLAIN heads of hd, for an
+    entry that takes keys and values of one width: ``q`` [B, S, H, hd],
+    ``k``, ``v`` [B, T, kvH, hd] -> every query head twice, against its key
+    head, once with each of its pair's two value heads.  What attention
+    returns for them, [B, S, 2 H, hd], reshaped to [B, S, H, 2 hd] is a
+    head's softmax over its values of 2 hd."""
+    key_of, values_of = diff_heads(q.shape[2], k.shape[2])
+    return (jnp.repeat(q, 2, axis=2), k[:, :, np.repeat(key_of, 2)],
+            v[:, :, values_of.reshape(-1)])
+
+
 def _check_window(window, causal):
     """Shared by every attention entry point: a window only makes sense
     as a causal band, and window < 1 would mask EVERY key — with the

@@ -111,15 +111,17 @@ def l2norm(x: jax.Array, eps: float = 1e-6) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + eps)
 
 
-def causal_conv(full: jax.Array, w: jax.Array, n: int) -> jax.Array:
-    """A depthwise causal convolution of ``K`` taps, then SiLU: ``full``
+def causal_conv(full: jax.Array, w: jax.Array, n: int,
+                bias: jax.Array | None = None) -> jax.Array:
+    """A depthwise causal convolution of ``K`` taps (and a ``bias`` [D]
+    where the mixer has one), then SiLU: ``full``
     [..., K - 1 + n, D] is the ``K - 1`` rows before the sequence (zeros at
     its start, else the tail the cache kept) and its ``n`` rows; ``w``
     [K, D], tap ``K - 1`` on the row itself.  Float32 sums; [..., n, D]."""
     K = w.shape[0]
     y = sum(w[i].astype(F32) * full[..., i:i + n, :].astype(F32)
             for i in range(K))
-    return jax.nn.silu(y)
+    return jax.nn.silu(y if bias is None else y + bias.astype(F32))
 
 
 # -- token by token: the oracle ------------------------------------------------

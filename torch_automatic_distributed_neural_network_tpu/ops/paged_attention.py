@@ -200,6 +200,7 @@ def paged_attention(
     work=None,
     scale: float | None = None,
     value_dim: int | None = None,
+    diff: bool = False,
 ) -> jax.Array:
     """Fused paged decode attention over one layer of the KV pool.
 
@@ -231,6 +232,11 @@ def paged_attention(
     scores and ``value_dim``, how many of a row's first numbers are the
     value; it returns [S, Hq, value_dim]
     (:func:`paged_attention_latent`).
+
+    ``diff``: differential attention's first half on folded pages
+    (``ops.attention.diff_heads``): a query head scores ITS key head of a
+    pair of KV heads and reads both of the pair's value heads; returns
+    [S, Hq, 2 hd] (:func:`paged_attention_folded`).
     """
     from ..inference.quant import kv_leaf_parts
 
@@ -243,7 +249,10 @@ def paged_attention(
     if is_folded(k_pool):
         return paged_attention_folded(
             q, k_pool, v_pool, tables, ctx_lens, window=window,
-            interpret=interpret, work=work)
+            interpret=interpret, work=work, diff=diff)
+    if diff:
+        raise ValueError("differential attention reads folded pages alone "
+                         "(no int8 and no sharded pool)")
     t = tensor_degree(mesh, axis)
     kvH_full = kv_leaf_parts(k_pool)[0].shape[2]
     if t > 1 and kvH_full % t == 0:
@@ -600,6 +609,7 @@ def paged_attention_folded(
     window: int | None = None,
     interpret: bool | None = None,
     work: WorkList | None = None,
+    diff: bool = False,
 ) -> jax.Array:
     """What ``paged_attention`` runs over one layer of a pool stored folded,
     ``[NB, bs, kvH * hd]`` (see above): ``q`` [S, Hq, hd] with kv-major
@@ -607,7 +617,16 @@ def paged_attention_folded(
     ``window`` where given.  ``work`` is ``folded_work_list`` of the same
     contexts and window (a caller with many layers builds it once); every
     slot counts as running where it is built here.  Returns [S, Hq, hd] in
-    ``q.dtype``."""
+    ``q.dtype``.
+
+    ``diff`` (differential attention, ``ops.attention.diff_heads``) is the
+    SAME kernel call at another wiring of the lanes, and reads every page
+    once, as grouped queries do: a query head's row lies in the lanes of
+    the key head it scores (head ``2j + i`` on key head ``2g + i``), and
+    what is taken of the kernel's ``[Hq, kvH * hd]`` product is the 2 hd
+    lanes of the group's TWO value heads, which lie side by side in a
+    folded page.  Returns [S, Hq, 2 hd]: scores over hd against values of
+    2 hd that a pair of key heads share."""
     if interpret is None:
         interpret = _default_interpret()
     S, Hq, hd = q.shape
@@ -623,16 +642,24 @@ def paged_attention_folded(
     if work is None:
         work = folded_work_list(ctx_lens, max_blocks=MB, block_size=bs,
                                 window=window)
-    # query head (h, g) in the lanes of KV head h, zeros elsewhere
-    own = jnp.asarray(np.arange(Hq)[:, None] // G == np.arange(kvH)[None, :],
-                      q.dtype)
+    # query head (h, g) in the lanes of KV head h, zeros elsewhere; its
+    # output in the lanes of the value heads it reads (one, or a pair)
+    key_of, wide = np.arange(Hq) // G, 1
+    if diff:
+        from .attention import diff_heads
+
+        key_of, wide = diff_heads(Hq, kvH)[0], 2
+    own = jnp.asarray(key_of[:, None] == np.arange(kvH)[None, :], q.dtype)
+    mine = own if not diff else jnp.asarray(
+        key_of[:, None] // 2 == np.arange(kvH // 2)[None, :], q.dtype)
     qf = jnp.einsum("shd,hk->shkd", q, own).reshape(S, Hq, F)
     out = _fetching_call(
         qf, (k_pool, v_pool), tables, ctx_lens, work, pages=pages,
         out_width=F, interpret=interpret, window=window,
         scale=1.0 / float(np.sqrt(hd)))
     # float32 here too (the default rounds this sum over the 0/1 ``own``)
-    return jnp.einsum("shkd,hk->shd", out.reshape(S, Hq, kvH, hd), own,
+    return jnp.einsum("shkd,hk->shd",
+                      out.reshape(S, Hq, kvH // wide, wide * hd), mine,
                       precision="highest" if q.dtype == jnp.float32 else None)
 
 
