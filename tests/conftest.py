@@ -36,6 +36,20 @@ def devices8():
     return devs
 
 
+@pytest.fixture
+def small_items(monkeypatch):
+    """The MXU decode kernels' work list at its least item, 8 folded pages
+    and 512 latent keys a grid step, whatever a page weighs
+    (``ops.paged_attention.ITEM_BYTES`` at 1): the tests' pages of a few KB
+    then make the several items a slot that pages of 128 KB and more make
+    on the chip, where 1 MiB an item would make one."""
+    from torch_automatic_distributed_neural_network_tpu.ops import (
+        paged_attention,
+    )
+
+    monkeypatch.setattr(paged_attention, "ITEM_BYTES", 1)
+
+
 # -- compiling for a described chip (``test_chip_compile_*.py``) -------------
 
 

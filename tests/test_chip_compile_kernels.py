@@ -243,12 +243,15 @@ def _operand_shapes(custom_call: str) -> list[str]:
 @pytest.mark.parametrize("config", sorted(_FOLDED))
 def test_folded_paged_decode_compiles_for_v5e(v5e, config, window):
     """The MXU form of the decode kernel at both serving cells' shapes, its
-    grid a work list of traced length built from the contexts and the
-    slots' flags.  The block tables stay a scalar-prefetch operand in their
+    grid a work list of traced length built from the contexts, the slots'
+    flags and the pools' own shape (``item_pages``: 16 pages a step on
+    trinity-large-ep8's pages of 64 KB and 17 items of 16 over its window's
+    band, 8 on the other two: the geometry the cells run).  The block tables stay a scalar-prefetch operand in their
     own shape, ``s32[slots, max_len / block]``: what
     ``benchmark/metrics/paged_attn_roofline.py`` tells the kernel by."""
     from torch_automatic_distributed_neural_network_tpu.ops.paged_attention import (
         folded_work_list,
+        item_pages,
     )
 
     slots, hq, kvh, mb = _FOLDED[config]
@@ -261,7 +264,7 @@ def test_folded_paged_decode_compiles_for_v5e(v5e, config, window):
     pool = sds((min(slots * mb + 1, 4609), 16, kvh * 128), jnp.bfloat16)
 
     def call(q, k, v, t, c, active):
-        work = folded_work_list(c, active, max_blocks=mb, block_size=16,
+        work = folded_work_list(c, active, pools=(k, v), max_blocks=mb,
                                 window=window)
         return paged_attention(q, k, v, t, c, window=window, work=work,
                                interpret=False)
@@ -274,7 +277,16 @@ def test_folded_paged_decode_compiles_for_v5e(v5e, config, window):
                  if 'custom_call_target="tpu_custom_call"' in l]
     assert "tadnn_paged_decode_folded" in kernel.split(" = ")[0]
     operands = _operand_shapes(kernel)
-    assert f"s32[{slots},{mb}]" in operands
+    # (a window's items start wherever a band does: its table is padded by
+    # one item's pages, and ``paged_attn_roofline.by_kind`` tells the kernel
+    # by its name)
+    pages, items = item_pages((pool, pool), mb, window)
+    assert (pages, items) == {
+        ("gpt2-1p3b", None): (8, 8), ("gpt2-1p3b", 4096): (8, 8),
+        ("trinity-large-ep8", None): (16, 52),
+        ("trinity-large-ep8", 4096): (16, 17),
+        ("olmo-hybrid-7b-pp2", None): (8, 264)}[config, window]
+    assert f"s32[{slots},{mb + pages * bool(window)}]" in operands
     # each pool ONE operand, left where it lies: the kernel copies an item's
     # pages itself, into buffers that fit the default VMEM limit (no
     # ``vmem_limit_bytes``) at olmo-hybrid-7b-pp2's page of 3,840 lanes too
@@ -286,7 +298,7 @@ def test_folded_paged_decode_compiles_for_v5e(v5e, config, window):
                          ids=["bf16", "float32_queries"])
 def test_latent_paged_decode_compiles_for_v5e(v5e, dtype):
     """The latent kernel at the cell's shape: 32 heads, 24 slots of 544
-    pages of 64 rows stored in 640 lanes (512 + 64 numbers and zeros), 8
+    pages of 64 rows stored in 640 lanes (512 + 64 numbers and zeros), 16
     page copies a grid step made by the kernel itself, its grid a work list
     of traced length; in serving's bfloat16 and with ``chip_smoke.py``'s
     float32 queries.  The pool reaches the kernel as it lies, ONE operand:
@@ -294,7 +306,6 @@ def test_latent_paged_decode_compiles_for_v5e(v5e, dtype):
     chip's layout for such an array puts another axis in the lanes)."""
     from torch_automatic_distributed_neural_network_tpu.ops.paged_attention import (
         folded_work_list,
-        latent_pages,
     )
 
     slots, heads, mb, bs = 24, 32, 544, 64
@@ -303,8 +314,7 @@ def test_latent_paged_decode_compiles_for_v5e(v5e, dtype):
     pool = sds((4097, bs, 640), jnp.bfloat16)
 
     def call(q, k, t, c, active):
-        work = folded_work_list(c, active, max_blocks=mb, block_size=bs,
-                                pages=latent_pages(mb, bs))
+        work = folded_work_list(c, active, pools=(k,), max_blocks=mb)
         return paged_attention(q, k, jnp.zeros((0,), k.dtype), t, c,
                                work=work, scale=192 ** -0.5, value_dim=512,
                                interpret=False)

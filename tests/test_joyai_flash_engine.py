@@ -56,6 +56,8 @@ def test_engine_serves_the_references_first_choice(option, tmp_path,
         )
 
         monkeypatch.setattr(paged, "LATENT_KEYS", 8)
+        monkeypatch.setattr(paged, "LATENT_CHUNK_KEYS", 8)
+        monkeypatch.setattr(paged, "ITEM_BYTES", 1)
         monkeypatch.setattr(paged, "latent_chunk_tiles", lambda *a: True)
     eng = _engine(flat, journal, **SERVED[option])
     reqs = [eng.submit([int(t) for t in _tokens(n, 10 + i)], max_new_tokens=m)

@@ -702,8 +702,10 @@ def test_the_counters_are_in_the_schema():
         == "int"
     assert engine.optional["zero_experts"] == "int"
     assert engine.optional["shortcut_experts"] == "bool"
-    assert programs.N_COUNTERS == 8
-    assert programs.step_output(5).shape == (5 + 5 + 8,)
+    assert step.optional["attn_pages_copied"] \
+        == step.optional["attn_pages_live"] == "int"
+    assert programs.N_COUNTERS == 10
+    assert programs.step_output(5).shape == (5 + 5 + 10,)
 
 
 def test_the_branch_keeps_the_experts_scope():

@@ -92,10 +92,28 @@ def test_kernel_null_table_slot_is_finite():
     assert bool(jnp.all(jnp.isfinite(out)))
 
 
-# -- the folded (MXU) kernel: a work list of live (slot, key group) items -----
+# -- the folded (MXU) kernel: a work list of (slot, first page) items ---------
 
-_BS, _MB = 16, 24  # 3 groups of 8 pages: 128 keys a group, max_len 384
+_BS, _MB = 16, 24  # 3 items of 8 pages on a full layer: 128 keys, max_len 384
 _MAX = _BS * _MB
+
+
+def _first_pages(ctx, running, window, pages, bs, mb):
+    """The host's own reckoning of a slot's items, as their first pages: the
+    entries ``lo .. ctx // bs`` in steps of ``pages`` from ``lo``, the
+    band's first entry under a window; one item at 0 for an idle slot."""
+    if not running:
+        return [0]
+    lo = 0 if window is None else max(ctx - window + 1, 0) // bs
+    return list(range(lo, min(ctx // bs, mb - 1) + 1, pages))
+
+
+def _poison_unlisted(pools, tables, s, firsts, pages):
+    """NaN in every page of slot ``s`` that no listed item reads."""
+    read = {j for p0 in firsts for j in range(p0, p0 + pages)}
+    dead = [j for j in range(tables.shape[1]) if j not in read]
+    for pool in pools:
+        pool[tables[s, dead]] = np.nan
 
 # name: (query heads, kv heads, contexts, the running slots or None for
 # all, window)
@@ -113,24 +131,17 @@ _WORK_CASES = {
 }
 
 
-def _live_groups(ctx, running, window):
-    """The host's own reckoning: a slot's first and last group."""
-    keys = 8 * _BS
-    if not running:
-        return 0, 0
-    lo = 0 if window is None else max(ctx - window + 1, 0) // keys
-    return lo, ctx // keys
-
-
 @pytest.mark.parametrize("case", sorted(_WORK_CASES))
-def test_folded_kernel_visits_only_live_groups(case):
+def test_folded_kernel_visits_only_live_groups(case, small_items):
     """``paged_attention`` over folded pages against the dense reference on
-    ragged contexts: the kernel's grid is ``folded_work_list``, whose length
-    is the host's own count of live (slot, group) pairs (one for a slot that
-    does not run), and every page outside a listed group is poisoned, so a
-    visit to one would show."""
+    ragged contexts: the kernel's grid is ``folded_work_list``, whose items
+    are the host's own reckoning (8 pages from entry 0 on a full layer; the
+    band cut evenly from its first entry under a window: 100 keys are one
+    item of 8, 200 two of 7; one item for a slot that does not run), and
+    every page outside a listed item is poisoned, so a visit to one would
+    show."""
     from torch_automatic_distributed_neural_network_tpu.ops.paged_attention \
-        import folded_work_list
+        import folded_work_list, item_pages
 
     Hq, kvH, ctxs, running, window = _WORK_CASES[case]
     S, hd = len(ctxs), 32
@@ -139,29 +150,26 @@ def test_folded_kernel_visits_only_live_groups(case):
     k = rs.randn(S * _MB + 1, _BS, kvH * hd).astype(np.float32)
     v = rs.randn(S * _MB + 1, _BS, kvH * hd).astype(np.float32)
     tables = 1 + rs.permutation(S * _MB).reshape(S, _MB).astype(np.int32)
-    want_items, starts = 0, []
+    pages, steps = item_pages((k, v), _MB, window)
+    assert (pages, steps) == {None: (8, 3), 100: (8, 1), 200: (7, 2)}[window]
+    want = []
     for s, ctx in enumerate(ctxs):
-        lo, hi = _live_groups(ctx, s in running, window)
-        want_items += hi - lo + 1
-        starts.append(lo)
-        dead = [j for j in range(_MB) if not lo <= j // 8 <= hi]
-        v[tables[s, dead]] = np.nan
-        k[tables[s, dead]] = np.nan
+        firsts = _first_pages(ctx, s in running, window, pages, _BS, _MB)
+        want += [(s, p0) for p0 in firsts]
+        _poison_unlisted((k, v), tables, s, firsts, pages)
         # the engine's table holds the null block past the newest key
         tables[s, ctx // _BS + 1:] = 0
     q = jnp.asarray(rs.randn(S, Hq, hd), jnp.float32)
     ctx = jnp.asarray(ctxs, jnp.int32)
     active = jnp.asarray([s in running for s in range(S)])
-    work = folded_work_list(ctx, active, max_blocks=_MB, block_size=_BS,
+    work = folded_work_list(ctx, active, pools=(k, v), max_blocks=_MB,
                             window=window)
     n = int(work.n_items)
-    assert n == want_items <= work.dense == work.slot_of.shape[0] - 1
-    # slot order, ascending groups inside a slot, from the band's first
-    listed = list(zip(np.asarray(work.slot_of)[:n].tolist(),
-                      np.asarray(work.group_of)[:n].tolist()))
-    assert listed == sorted(listed) and len(set(listed)) == n
-    assert [g for (s, g), (p, _) in zip(listed, [(-1, 0)] + listed)
-            if s != p] == starts
+    assert n == len(want) <= work.dense == work.slot_of.shape[0] - 1
+    assert work.dense == S * steps
+    # slot order, ascending inside a slot, from the band's first entry
+    assert list(zip(np.asarray(work.slot_of)[:n].tolist(),
+                    np.asarray(work.page0_of)[:n].tolist())) == want
     got = paged_attention(q, jnp.asarray(k), jnp.asarray(v),
                           jnp.asarray(tables), ctx, window=window, work=work)
     assert bool(jnp.all(jnp.isfinite(got)))
@@ -181,7 +189,8 @@ def test_folded_kernel_visits_only_live_groups(case):
                 window=window)))
 
 
-def test_folded_kernel_runs_as_many_grid_steps_as_the_list_has_items():
+def test_folded_kernel_runs_as_many_grid_steps_as_the_list_has_items(
+        small_items):
     """The grid's one dimension is the traced ``n_items``, no static bound:
     what ``attn_grid_items`` counts is what the kernel runs."""
     from torch_automatic_distributed_neural_network_tpu.ops.paged_attention \
@@ -192,7 +201,7 @@ def test_folded_kernel_runs_as_many_grid_steps_as_the_list_has_items():
     tables = jnp.zeros((S, _MB), jnp.int32)
 
     def run(q, ctx, active):
-        work = folded_work_list(ctx, active, max_blocks=_MB, block_size=_BS)
+        work = folded_work_list(ctx, active, pools=(k, k), max_blocks=_MB)
         return paged_attention(q, k, k, tables, ctx, work=work), work.n_items
 
     args = (jnp.zeros((S, Hq, hd), jnp.float32),
@@ -204,6 +213,186 @@ def test_folded_kernel_runs_as_many_grid_steps_as_the_list_has_items():
     assert len(mapping.grid) == 1 and mapping.num_dynamic_grid_bounds == 1
     assert call.invars[0] is jaxpr.outvars[1]  # the bound IS n_items
     assert int(run(*args)[1]) == 1 + 1 + 2 + 1
+
+
+# -- the geometry of an item: the pages its bytes ask for, from a band's first --
+
+# name: (pools a kernel reads, block size, lanes of a page's row, table
+# entries, window, (pages an item takes, the most items a slot has)): the
+# cells' shapes, bfloat16
+_SHAPES = {
+    "trinity-large-ep8.full": (2, 16, 8 * 128, 832, None, (16, 52)),
+    "trinity-large-ep8.window4096": (2, 16, 8 * 128, 832, 4096, (16, 17)),
+    "latent-640-lanes": (1, 64, 640, 544, None, (16, 34)),
+    "gpt2-1p3b": (2, 16, 16 * 128, 64, None, (8, 8)),
+    "olmo-hybrid-7b-pp2": (2, 16, 30 * 128, 2112, None, (8, 264)),
+    "phi4-mini-flash-3p8b.full": (2, 64, 20 * 64, 544, None, (8, 68)),
+    "phi4-mini-flash-3p8b.window512": (2, 64, 20 * 64, 544, 512, (5, 2)),
+}
+
+
+def _contexts(case, edge, bs, max_len):
+    """(contexts, the running slots or None for all) round ``edge``: the
+    window, or an item's keys."""
+    ctxs, running = {
+        "ragged": ([0, 3, bs - 1, bs, edge // 2, edge + 7,
+                    3 * edge + bs // 2, max_len - 1], None),
+        "edges": ([edge - 2, edge - 1, edge, edge + bs - 2, edge + bs - 1,
+                   edge + bs, 2 * edge - 1, 2 * edge], None),
+        "every_slot_full": ([max_len - 1] * 3, None),
+        "idle_slots": ([5 * edge // 2, 0, max_len - 1, edge, 17], [0, 3]),
+    }[case]
+    return [min(c, max_len - 1) for c in ctxs], running
+
+
+@pytest.mark.parametrize("case", ["ragged", "edges", "every_slot_full",
+                                  "idle_slots"])
+@pytest.mark.parametrize("shape", sorted(_SHAPES))
+def test_work_list_items_take_their_bytes_and_tile_the_band(shape, case):
+    """``item_pages`` at the cells' page shapes (16 pages a step where a
+    page weighs 64 or 82 KB, 8 from 131 KB on, a window's band cut evenly:
+    9 pages of 328 KB in 2 items of 5) and ``folded_work_list`` of them: a
+    slot's items are disjoint runs of ``pages`` entries that cover every
+    entry with an attendable key, the first at entry 0 or at the band's
+    first entry, so that a window copies at most ``band + pages - 1``
+    entries; a slot that does not decode keeps ONE item; no item reads past
+    the padded table; the two counters are the copies and the live
+    entries."""
+    from torch_automatic_distributed_neural_network_tpu.ops.paged_attention \
+        import folded_work_list, item_pages
+
+    n_pools, bs, lanes, mb, window, want = _SHAPES[shape]
+    pools = (jax.ShapeDtypeStruct((4097, bs, lanes), jnp.bfloat16),) * n_pools
+    pages, steps = item_pages(pools, mb, window)
+    assert (pages, steps) == want
+    ctxs, running = _contexts(case, window or pages * bs, bs, mb * bs)
+    S = len(ctxs)
+    running = list(range(S)) if running is None else running
+    active = jnp.asarray([s in running for s in range(S)])
+    work = folded_work_list(jnp.asarray(ctxs, jnp.int32), active,
+                            pools=pools, max_blocks=mb, window=window)
+    n = int(work.n_items)
+    assert n <= work.dense == S * steps == work.slot_of.shape[0] - 1
+    slot_of = np.asarray(work.slot_of)[:n].tolist()
+    page0_of = np.asarray(work.page0_of)[:n].tolist()
+    assert slot_of == sorted(slot_of) and set(slot_of) == set(range(S))
+    padded = mb + (pages if window else -mb % pages)
+    copied = live = 0
+    for s, ctx in enumerate(ctxs):
+        mine = [p for t, p in zip(slot_of, page0_of) if t == s]
+        assert (int(work.first[s]), int(work.last[s])) == (
+            slot_of.index(s), slot_of.index(s) + len(mine) - 1)
+        if s not in running:
+            assert mine == [0]
+            continue
+        lo = 0 if window is None else max(ctx - window + 1, 0) // bs
+        hi = ctx // bs
+        # runs of ``pages`` from ``lo``: disjoint, and every entry that
+        # holds an attendable key in exactly one
+        assert mine == list(range(lo, hi + 1, pages))
+        assert mine[-1] + pages <= padded
+        assert len(mine) <= steps
+        assert len(mine) * pages <= hi - lo + 1 + pages - 1
+        copied += len(mine) * pages
+        live += hi - lo + 1
+    assert (int(work.pages_copied), int(work.pages_live)) == (copied, live)
+    if "phi4" in shape and window and case == "edges":
+        # a band of 8 or 9 pages: 10 copies where groups of 8 made 16
+        assert copied == 10 * S and live in range(8 * S, 9 * S + 1)
+
+
+# name: (latent, differential wiring, block size, table entries, window,
+# ITEM_BYTES in pages, (pages an item takes, items a slot), contexts)
+_ITEM_CASES = {
+    "window_items_of_5": (False, False, 8, 24, 64, 8, (5, 2),
+                          [3, 62, 63, 64, 100, 191]),
+    "window_items_of_8": (False, False, 8, 24, 113, 8, (8, 2),
+                          [0, 111, 112, 113, 150, 191]),
+    "window_items_of_16": (False, False, 4, 48, 121, 16, (16, 2),
+                           [2, 119, 120, 121, 160, 191]),
+    "full_items_of_16": (False, False, 4, 48, None, 16, (16, 3),
+                         [0, 63, 64, 127, 128, 191]),
+    "differential_window_items_of_5": (False, True, 8, 24, 64, 8, (5, 2),
+                                       [3, 63, 64, 100, 191]),
+    "differential_full_items_of_8": (False, True, 8, 24, None, 8, (8, 3),
+                                     [3, 63, 64, 100, 191]),
+    "latent_items_of_16": (True, False, 64, 40, None, 16, (16, 3),
+                           [5, 1023, 1024, 2047, 2559]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_ITEM_CASES))
+def test_mxu_kernels_match_the_plain_form_at_every_item_size(case,
+                                                             monkeypatch):
+    """Both MXU kernels in the interpreter against plain ``jax.numpy`` at
+    items of 5, 8 and 16 pages: a window whose band is cut into items that
+    start at its first entry (inside, at and past the window's edge), the
+    same at differential attention's wiring, a full layer, and latent pages
+    at 1,024 keys a step.  ``work`` built by the caller and by the entry
+    give the same rows, and every page no item reads is poisoned."""
+    from torch_automatic_distributed_neural_network_tpu.ops import (
+        paged_attention as paged,
+    )
+    from torch_automatic_distributed_neural_network_tpu.ops.attention import (
+        diff_plain_heads,
+        xla_attention,
+    )
+
+    latent, diff, bs, mb, window, by_bytes, want, ctxs = _ITEM_CASES[case]
+    S, Hq, kvH, hd = len(ctxs), 4, 2, 32
+    rs = np.random.RandomState(len(case))
+    lanes = 128 if latent else kvH * hd
+    pools = [np.zeros((S * mb + 1, bs, lanes), np.float32)
+             for _ in range(1 if latent else 2)]
+    for pool in pools:
+        pool[..., :24 if latent else lanes] = rs.randn(
+            S * mb + 1, bs, 24 if latent else lanes)
+    monkeypatch.setattr(paged, "ITEM_BYTES",
+                        by_bytes * len(pools) * bs * lanes * 4)
+    pages, steps = paged.item_pages(pools, mb, window)
+    assert (pages, steps) == want
+    tables = 1 + rs.permutation(S * mb).reshape(S, mb).astype(np.int32)
+    for s, c in enumerate(ctxs):
+        _poison_unlisted(pools, tables, s, _first_pages(
+            c, True, window, pages, bs, mb), pages)
+        tables[s, c // bs + 1:] = 0
+    for pool in pools:
+        pool[0] = 0.0
+    ctx = jnp.asarray(ctxs, jnp.int32)
+    work = paged.folded_work_list(ctx, pools=pools, max_blocks=mb,
+                                  window=window)
+    pools, tables = [jnp.asarray(pool) for pool in pools], jnp.asarray(tables)
+    clean = [jnp.nan_to_num(pool) for pool in pools]
+    if latent:
+        q = jnp.asarray(rs.randn(S, Hq, 24), jnp.float32)
+        run = lambda work: paged_attention(  # noqa: E731
+            q, pools[0], jnp.zeros((0,), jnp.float32), tables, ctx,
+            scale=0.3, value_dim=20, work=work)
+        want_rows = paged.latent_attention_reference(
+            q[:, None], clean[0][tables].reshape(S, mb * bs, lanes), ctx,
+            scale=0.3, value_dim=20)[:, 0]
+    else:
+        q = jnp.asarray(rs.randn(S, Hq, hd), jnp.float32)
+        run = lambda work: paged_attention(  # noqa: E731
+            q, *pools, tables, ctx, window=window, work=work, diff=diff)
+        if diff:  # 2 H plain heads: a query head, a value head
+            kd, vd = (pool[tables].reshape(S, mb * bs, kvH, hd)
+                      for pool in clean)
+            key = jnp.arange(mb * bs)[None, :]
+            mask = key <= ctx[:, None]
+            if window is not None:
+                mask &= key > ctx[:, None] - window
+            want_rows = xla_attention(
+                *diff_plain_heads(q[:, None], kd, vd), causal=False,
+                mask=mask[:, None, None, :]).reshape(S, Hq, 2 * hd)
+        else:
+            want_rows = paged_attention_reference(q, *clean, tables, ctx,
+                                                  window=window)
+    got = run(work)
+    assert got.shape == want_rows.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want_rows),
+                               atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(run(None)))
 
 
 def test_reference_fp_pool_skips_dequantize_and_matches_int8():
@@ -339,7 +528,7 @@ _LATENT_CASES = {
 
 
 @pytest.mark.parametrize("case", sorted(_LATENT_CASES))
-def test_latent_kernel_matches_the_dense_form(case):
+def test_latent_kernel_matches_the_dense_form(case, small_items):
     """``paged_attention`` over latent pages (one row a token, 20 + 4
     numbers stored in 128 lanes, values its first 20; no second array)
     against plain ``jax.numpy`` on ragged contexts, 6 heads: the grid is
@@ -351,8 +540,8 @@ def test_latent_kernel_matches_the_dense_form(case):
         import (
             folded_work_list,
             is_latent,
+            item_pages,
             latent_attention_reference,
-            latent_pages,
         )
 
     ctxs, running = _LATENT_CASES[case]
@@ -362,13 +551,13 @@ def test_latent_kernel_matches_the_dense_form(case):
     pool = np.zeros((S * _LMB + 1, _LBS, lanes), np.float32)
     pool[..., :row] = rs.randn(S * _LMB + 1, _LBS, row)
     tables = 1 + rs.permutation(S * _LMB).reshape(S, _LMB).astype(np.int32)
-    pages = latent_pages(_LMB, _LBS)
+    pages = item_pages((pool,), _LMB)[0]
     assert pages == 32
     want_items = 0
     for s, ctx in enumerate(ctxs):
-        hi = ctx // (pages * _LBS) if s in running else 0
-        want_items += hi + 1
-        pool[tables[s, [j for j in range(_LMB) if j // pages > hi]]] = np.nan
+        firsts = _first_pages(ctx, s in running, None, pages, _LBS, _LMB)
+        want_items += len(firsts)
+        _poison_unlisted((pool,), tables, s, firsts, pages)
         tables[s, ctx // _LBS + 1:] = 0  # the null block past the newest key
     pool[0] = 0.0
     none = jnp.zeros((0,), jnp.float32)
@@ -376,8 +565,7 @@ def test_latent_kernel_matches_the_dense_form(case):
     q = jnp.asarray(rs.randn(S, Hq, row), jnp.float32)
     ctx = jnp.asarray(ctxs, jnp.int32)
     active = jnp.asarray([s in running for s in range(S)])
-    work = folded_work_list(ctx, active, max_blocks=_LMB, block_size=_LBS,
-                            pages=pages)
+    work = folded_work_list(ctx, active, pools=(pool,), max_blocks=_LMB)
     n = int(work.n_items)
     assert n == want_items <= work.dense == S * 3
     assert work.slot_of.shape[0] == work.dense + 1
@@ -400,7 +588,7 @@ def test_latent_kernel_matches_the_dense_form(case):
                 scale=0.3, value_dim=value)))
 
 
-def test_latent_kernel_is_named_and_reads_a_page_once():
+def test_latent_kernel_is_named_and_reads_a_page_once(small_items):
     """One ``pallas_call`` named ``tadnn_paged_decode_latent`` that takes the
     ONE pool array ONCE (the kernel copies an item's 8 pages of 64 tokens
     itself into a ``[2, 8, 64, F]`` buffer; no value pages beside them), its
@@ -450,7 +638,8 @@ _FETCH_CASES = {
 
 
 @pytest.mark.parametrize("case", sorted(_FETCH_CASES))
-def test_mxu_kernels_fetch_their_pages_without_a_race(case, capfd):
+def test_mxu_kernels_fetch_their_pages_without_a_race(case, capfd,
+                                                      small_items):
     """Both entries under the TPU interpreter that runs a copy only when it
     is waited for, fills unwritten memory with NaN and follows every read and
     write with a vector clock: item w + 1's copies are started before item
@@ -468,8 +657,8 @@ def test_mxu_kernels_fetch_their_pages_without_a_race(case, capfd):
     from torch_automatic_distributed_neural_network_tpu.ops.paged_attention \
         import (
             folded_work_list,
+            item_pages,
             latent_attention_reference,
-            latent_pages,
         )
 
     latent, Hq, kvH, ctxs, running, window = _FETCH_CASES[case]
@@ -477,28 +666,24 @@ def test_mxu_kernels_fetch_their_pages_without_a_race(case, capfd):
     running = list(range(S)) if running is None else running
     rs = np.random.RandomState(len(case))
     if latent:
-        bs, mb, pages = _LBS, _LMB, latent_pages(_LMB, _LBS)
+        bs, mb = _LBS, _LMB
         lanes, row, value = (640, 576, 512) if "640" in case else (128, 24, 20)
         pools = [np.zeros((S * mb + 1, bs, lanes), np.float32)]
         pools[0][..., :row] = rs.randn(S * mb + 1, bs, row)
         q = jnp.asarray(rs.randn(S, Hq, row), jnp.float32)
     else:
-        bs, mb, pages, hd = _BS, _MB, 8, 32
+        bs, mb, hd = _BS, _MB, 32
         pools = [rs.randn(S * mb + 1, bs, kvH * hd).astype(np.float32)
                  for _ in range(2)]
         q = jnp.asarray(rs.randn(S, Hq, hd), jnp.float32)
-    keys = pages * bs
+    pages = item_pages(pools, mb, window)[0]
+    assert pages == (32 if latent else {None: 8, 100: 8, 200: 7}[window])
     tables = 1 + rs.permutation(S * mb).reshape(S, mb).astype(np.int32)
     want_items = 0
     for s, c in enumerate(ctxs):
-        lo, hi = 0, 0
-        if s in running:
-            hi = c // keys
-            lo = 0 if window is None else max(c - window + 1, 0) // keys
-        want_items += hi - lo + 1
-        for pool in pools:
-            pool[tables[s, [j for j in range(mb)
-                            if not lo <= j // pages <= hi]]] = np.nan
+        firsts = _first_pages(c, s in running, window, pages, bs, mb)
+        want_items += len(firsts)
+        _poison_unlisted(pools, tables, s, firsts, pages)
         # the engine's table: the null block past the newest key, and in
         # every entry of a slot that does not run (``programs._step_shared``)
         tables[s, (c // bs + 1) * (s in running):] = 0
@@ -506,8 +691,8 @@ def test_mxu_kernels_fetch_their_pages_without_a_race(case, capfd):
         pool[0] = 0.0
     ctx = jnp.asarray(ctxs, jnp.int32)
     active = jnp.asarray([s in running for s in range(S)])
-    work = folded_work_list(ctx, active, max_blocks=mb, block_size=bs,
-                            window=window, pages=pages)
+    work = folded_work_list(ctx, active, pools=pools, max_blocks=mb,
+                            window=window)
     assert int(work.n_items) == want_items
     if "fills" in case:
         assert want_items == work.dense == work.slot_of.shape[0] - 1
@@ -591,7 +776,7 @@ def test_latent_chunk_kernel_matches_the_plain_form_and_the_module(
         layer_types=("latent_attention",), latent_q_rank=24,
         latent_kv_rank=16, latent_nope_head_dim=8, latent_rope_head_dim=4,
         latent_value_head_dim=8, dtype=dtype, remat=False)
-    monkeypatch.setattr(paged, "LATENT_KEYS", _CKEYS)
+    monkeypatch.setattr(paged, "LATENT_CHUNK_KEYS", _CKEYS)
     monkeypatch.setattr(programs, "KEY_BLOCK", _CKEYS)
     T, lanes = pos0 + n_real, 128
     rs = np.random.RandomState(len(case))

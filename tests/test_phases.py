@@ -389,12 +389,16 @@ def test_first_steps_report_compiles_and_no_prompt_length_after(served):
         1 for s in j.named("serve.step") if s["compiles"])
 
 
-def test_decode_steps_carry_the_attention_grid_they_ran(monkeypatch):
+def test_decode_steps_carry_the_attention_grid_they_ran(monkeypatch,
+                                                        small_items):
     """``attn_grid_items`` / ``attn_grid_dense`` come back with a step's
-    tokens (one call after its dispatch): the live (slot, key group) items
-    the step's paged calls ran, which the host can reckon from the contexts
-    and flags it packed, and the ``slots x groups`` of a dense grid, over
-    the model's two layers.  Pages of 2 keys: 4 groups of 16 in 64."""
+    tokens (one call after its dispatch): the (slot, first page) items the
+    step's paged calls ran, which the host can reckon from the contexts
+    and flags it packed, and the ``slots x items`` of a dense grid, over
+    the model's two layers; beside them ``attn_pages_copied`` (items of
+    running slots x the 8 pages each copies) and ``attn_pages_live`` (the
+    table entries among them that hold a key).  Pages of 2 keys: 4 items
+    of 16 in 64."""
     from torch_automatic_distributed_neural_network_tpu.inference.serve import (
         programs,
     )
@@ -424,6 +428,14 @@ def test_decode_steps_carry_the_attention_grid_they_ran(monkeypatch):
     assert {s["attn_grid_dense"] for s in counted} == {2 * 2 * 4}
     assert all(s["attn_grid_items"] <= s["attn_grid_dense"] for s in counted)
     assert min(reckoned) == 4 < max(reckoned)
+    assert [(s["attn_pages_copied"], s["attn_pages_live"])
+            for s in counted] == [
+        (2 * 8 * sum(ctx // 16 + 1 for ctx, flag in zip(ctx_lens, source)
+                     if flag),
+         2 * sum(ctx // 2 + 1 for ctx, flag in zip(ctx_lens, source) if flag))
+        for ctx_lens, source in packed]
+    assert all(s["attn_pages_live"] <= s["attn_pages_copied"]
+               for s in counted)
     assert not any(k.startswith("moe_") for s in steps for k in s)
 
 

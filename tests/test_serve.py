@@ -1283,9 +1283,10 @@ def test_nothing_compiles_after_the_warm_up_across_admissions():
     assert eng._prefill_fn is None and eng.fused_steps > 0
 
 
-def test_work_list_kernel_serves_the_dense_paths_tokens_without_a_compile():
-    """A mixed-length batch whose contexts grow across key groups (8 pages
-    of 2 keys: 4 groups in ``max_len`` 64): the paged engine's greedy
+def test_work_list_kernel_serves_the_dense_paths_tokens_without_a_compile(
+        small_items):
+    """A mixed-length batch whose contexts grow across items (8 pages
+    of 2 keys: 4 items in ``max_len`` 64): the paged engine's greedy
     tokens are the dense path's, the kernel's grid follows the contexts
     (``attn_grid_items`` on the step events) and no step after the warm-up
     compiles: the list's length is a traced value, not a shape."""
@@ -1342,7 +1343,8 @@ def test_report_prints_the_share_of_steps_dispatched_ahead(tmp_path):
             "compiles": 0, "ahead": int(i > 2),
             "discarded_tokens": 2 if i == 7 else 0,
             # a step's counters come with its tokens, a call late
-            **({"attn_grid_items": 10 + i, "attn_grid_dense": 64}
+            **({"attn_grid_items": 10 + i, "attn_grid_dense": 64,
+                "attn_pages_copied": 8 * (10 + i), "attn_pages_live": 50 + i}
                if i > 2 else {})})
     jp.write_text("".join(json.dumps(r) + "\n" for r in recs))
     report = obs_report.generate(str(jp))
@@ -1351,6 +1353,9 @@ def test_report_prints_the_share_of_steps_dispatched_ahead(tmp_path):
     assert ("paged attention grid: 132 live (slot, key group) items of 512 "
             "in a dense grid over the decode steps (0.258)"
             ) in obs_report.format_report(report)
+    assert (srv["attn_pages_copied"], srv["attn_pages_live"]) == (1056, 452)
+    assert ("paged attention pages: 452 hold a key a slot attends of 1056 "
+            "its items copied (0.428)") in obs_report.format_report(report)
     assert srv["decode_calls"] == 9
     assert srv["steps_ahead_share"] == pytest.approx(8 / 9)
     assert srv["discarded_tokens"] == 2 and srv["step_new_tokens"] == 36
@@ -1364,7 +1369,10 @@ def test_report_prints_the_share_of_steps_dispatched_ahead(tmp_path):
         r.pop("ahead", None)
         r.pop("attn_grid_items", None)
         r.pop("attn_grid_dense", None)
+        r.pop("attn_pages_copied", None)
+        r.pop("attn_pages_live", None)
     jp.write_text("".join(json.dumps(r) + "\n" for r in recs))
     text = obs_report.format_report(obs_report.generate(str(jp)))
     assert "dispatched ahead" not in text
     assert "paged attention grid" not in text
+    assert "paged attention pages" not in text

@@ -1164,7 +1164,8 @@ class ServeEngine:
                     ("moe_pairs", "moe_experts_touched",
                      "moe_max_expert_tokens", "moe_tiles_active",
                      "moe_zero_pairs", "moe_rows",
-                     "attn_grid_items", "attn_grid_dense")[skip:],
+                     "attn_grid_items", "attn_grid_dense",
+                     "attn_pages_copied", "attn_pages_live")[skip:],
                     map(int, counters[skip:])))
                 if experts:
                     self._counters["moe_tiles_laid"] = self._tiles_laid[

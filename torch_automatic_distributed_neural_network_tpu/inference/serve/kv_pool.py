@@ -220,9 +220,12 @@ def ring_table(win_rows: jax.Array, max_blocks: int, lo: jax.Array,
                hi: jax.Array) -> jax.Array:
     """A window layer's table as the kernels take one: ``[S, max_blocks]``,
     logical block ``j`` of slot ``s`` at ring page ``win_rows[s, j % W]``
-    for ``lo[s] <= j <= hi[s]`` and the null block elsewhere (an unchanged
-    index is not copied again, so the dead blocks of a group of pages cost
-    no read; a group with no live block is not in the kernel's work list)."""
+    for ``lo[s] <= j <= hi[s]`` and the null block elsewhere.  A dead entry
+    inside an item of the kernel's work list IS copied (since PR 44 the
+    kernel fetches an item's pages by hand, the null block like any other);
+    what keeps that cheap is that a window layer's items tile the band
+    ``lo .. hi`` from ``lo`` on (``ops.paged_attention.item_pages``), so only
+    the last item's tail is dead; nothing outside the band is in the list."""
     j = jnp.arange(max_blocks)[None, :]
     live = (j >= lo[:, None]) & (j <= hi[:, None])
     return jnp.where(live, win_rows[:, j[0] % win_rows.shape[1]], NULL_BLOCK)

@@ -321,7 +321,7 @@ def _work_of(kind: str | None) -> str:
     return "latent" if kind == "latent_attention" else _pages_of(kind)
 
 
-N_COUNTERS = 8  # what a step's output carries after its tokens
+N_COUNTERS = 10  # what a step's output carries after its tokens
 
 
 def _moe_counters(stats: list) -> jax.Array:
@@ -415,19 +415,20 @@ def rows_walked(program, operands) -> list[int]:
 def _step_shared(cfg, kv, tables, win_tables, ctx_lens, active, adapter_ids,
                  T: int, attention_impl: str):
     """What every layer of a decode step reads: the tables of each kind of
-    page with the null block wherever a slot has no key to read (an
-    unchanged block index is not copied again: past the newest key, and in
-    a ring before the oldest its window still reaches), the slots' rows of
+    page with the null block wherever a slot has no key to read (past the
+    newest key, and in a ring before the oldest its window still reaches:
+    an item of the null block alone is skipped), the slots' rows of
     a linear layer's state (the null row for a slot that does not decode)
-    and the folded kernel's grid, the live (slot, key group) items: one
-    list a kind of table, built here once a step and not in every layer's
-    call.  Returns ``(shared, [grid steps the step's paged calls run, the
-    slots x groups a dense grid would])``."""
+    and the MXU kernels' grid, the (slot, first page) items: one list a
+    kind of table, built here once a step and not in every layer's call,
+    its geometry from the pages' own shape (``item_pages``).  Returns
+    ``(shared, [grid steps the step's paged calls run, the slots x items a
+    dense grid would, the page copies those steps start, the table entries
+    among them that hold a key a slot attends])``."""
     from ...ops.paged_attention import (
-        FOLD_PAGES,
         folded_work_list,
         is_folded,
-        latent_pages,
+        kernel_pools,
     )
 
     S, MB = tables.shape
@@ -443,18 +444,21 @@ def _step_shared(cfg, kv, tables, win_tables, ctx_lens, active, adapter_ids,
         lo = (ctx_lens - cfg.sliding_window + 1) // bs
         shared["tables"]["ring"] = ring_table(win_tables, MB, lo, hi)
     kinds = page_readers(cfg)
-    grid = jnp.zeros((2,), jnp.int32)
+    grid = jnp.zeros((4,), jnp.int32)
     if attention_impl == "paged" and T == 1 and paged and is_folded(pages0):
-        shared["work"] = {  # in the plan's order: the same text every run
-            _work_of(kind): folded_work_list(
-                ctx_lens, active, max_blocks=MB, block_size=bs,
-                window=cfg.layer_window(kind),
-                pages=(latent_pages(MB, bs) if kind == "latent_attention"
-                       else FOLD_PAGES))
-            for kind in dict.fromkeys(kinds)}
+        plan = layer_plan(cfg)
+        for i in paged:  # in the plan's order: the same text every run
+            kind = plan[i][1]
+            if _work_of(kind) not in shared["work"]:
+                shared["work"][_work_of(kind)] = folded_work_list(
+                    ctx_lens, active, max_blocks=MB,
+                    pools=kernel_pools(kv["k"][i], kv["v"][i]),
+                    window=cfg.layer_window(kind))
         works = [shared["work"][_work_of(kind)] for kind in kinds]
         grid = jnp.stack([sum(w.n_items for w in works),
-                          jnp.int32(sum(w.dense for w in works))])
+                          jnp.int32(sum(w.dense for w in works)),
+                          sum(w.pages_copied for w in works),
+                          sum(w.pages_live for w in works)])
     return shared, grid
 
 
@@ -779,8 +783,10 @@ def decode_logits(params, kv, tables, win_tables, ctx_lens, tok, active,
 
     Returns ``(kv, logits [S, T, V], counters [N_COUNTERS])``: the expert
     layers' six (``_moe_counters``), then the grid steps the paged calls
-    of the step ran and the ``slots x groups`` a dense grid would have run,
-    summed over the layers (zeros where no call takes a work list)."""
+    of the step ran, the ``slots x items`` a dense grid would have run, the
+    page copies those steps started and the table entries among them that
+    held an attendable key, summed over the layers (zeros where no call
+    takes a work list)."""
     S, T = tok.shape
     kv = _pool_constraint(kv, mesh, spec)
     shared, grid = _step_shared(cfg, kv, tables, win_tables, ctx_lens,

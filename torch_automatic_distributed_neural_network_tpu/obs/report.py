@@ -491,6 +491,12 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                 e.get("attn_grid_items") for e in ssteps)) or None,
             "attn_grid_dense": sum(_finite(
                 e.get("attn_grid_dense") for e in ssteps)) or None,
+            # the page copies those items started, and the entries among
+            # them that held an attendable key
+            "attn_pages_copied": sum(_finite(
+                e.get("attn_pages_copied") for e in ssteps)) or None,
+            "attn_pages_live": sum(_finite(
+                e.get("attn_pages_live") for e in ssteps)) or None,
             # sharded serving
             "tp": (sengine or {}).get("tp"),
         }
@@ -1193,6 +1199,12 @@ def format_report(report: dict) -> str:
                 f"(slot, key group) items of {sv['attn_grid_dense']} in a "
                 f"dense grid over the decode steps "
                 f"({sv['attn_grid_items'] / sv['attn_grid_dense']:.3f})")
+        if sv.get("attn_pages_copied"):
+            lines.append(
+                f"  paged attention pages: {sv['attn_pages_live']} hold a "
+                f"key a slot attends of {sv['attn_pages_copied']} its items "
+                f"copied "
+                f"({sv['attn_pages_live'] / sv['attn_pages_copied']:.3f})")
         if sv.get("step_phase_mean_s"):
             lines.append(
                 "  step phases (mean ms, host): " + " ".join(

@@ -464,6 +464,12 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # run, summed over the layers; 0 and 0 where no call takes a
             # work list (dense attention, int8 or mesh-sharded pools)
             "attn_grid_items": "int", "attn_grid_dense": "int",
+            # the page copies those grid steps started (an item of the list
+            # is fetched whole: items x the pages an item takes, over the
+            # slots that decode and the layers) and the table entries among
+            # them that held a key a slot attends (the rest are the null
+            # block: behind the newest key, or outside a window's band)
+            "attn_pages_copied": "int", "attn_pages_live": "int",
             # 1 where this call's decode rows went through the layers in
             # its prefill chunk, one program for both (a colocated,
             # non-speculative engine without tenants, a chunk to run and a

@@ -357,10 +357,12 @@ def _paged_attention_local(
 # kernel costs is its grid: about 46 ns a (slot, page) visited, relevant or
 # not (my chip run, PR 27: 1.23 ms a layer over 16 x 832 pages whatever the
 # contexts).  So the grid is a WORK LIST (``folded_work_list``): one step a
-# (slot, group of ``FOLD_PAGES`` pages) that holds a key the slot may attend,
-# in slot order and ascending group order inside a slot, its length a traced
-# value.  A slot with nothing to attend keeps one item, its group 0, so that
-# every output row is written.
+# (slot, first page) ITEM, a run of table entries that holds a key the slot
+# may attend, in slot order and ascending inside a slot, its length a traced
+# value.  A slot with nothing to attend keeps one item, at entry 0, so that
+# every output row is written.  How many pages an item takes and where a
+# slot's items start is ``item_pages``'s to say (PR 47), from the pages' own
+# shape and the layer's window: below.
 #
 # An item's pages are FETCHED BY THE KERNEL (PR 44): each pool is one
 # operand left in HBM, and at item w the body starts item w + 1's copies
@@ -388,82 +390,148 @@ def _paged_attention_local(
 # within 0.5% of each other.  What is left by hand is a step's own latency,
 # about 0.68 us whatever it fetches (4 | 8 | 16 pages a step: 0.823 | 0.964
 # | 1.489 us on trinity's shape, 0.794 | 1.151 | 1.830 on the latent one):
-# twice the keys a step would be 23% and 20% less a key there and nothing
-# on the two shapes whose bytes bind (``PERF.md`` section 7.4(a)).  One
-# thing the pipeline did in silence has to be said by hand: it did not copy
-# a block index that repeats, and the table holds the null block wherever a
-# slot has no key to read, so an idle slot's item cost no bytes.  By hand
-# every page is a copy (gpt2-1p3b's steady cell, one or two of 8 slots
-# running, read its token gaps 1-3% longer), so an item whose entries are
-# ALL the null block is skipped, fetch and arithmetic: a layer's call with
-# 1 of 8 slots running 52.1 (pipeline) | 52.5 | 50.2 us, and 0.7% dearer
-# where no slot idles.
+# twice the keys a step are 23% and 20% less a key there and nothing on the
+# two shapes whose bytes bind.  So (PR 47) AN ITEM TAKES THE PAGES ITS BYTES
+# ASK FOR (``item_pages``: about ``ITEM_BYTES`` over the pools, never under
+# 8 folded pages or 512 latent keys): 16 a step on trinity's pages of 64 KB
+# and on latent pages of 82 KB, 8 from gpt2-1p3b's 131 KB on.  In the
+# serving programs, 30 s into trinity's window (16 slots full; my chip runs,
+# PR 47): a sliding layer's call 334-336 -> 239.5-240.4 us (17 items of 16
+# over the band where 33 groups of 8 ran), the full layer's 473.1 -> 355.7,
+# the decode program 5.510 -> 5.033 ms.
+#
+# One thing the pipeline did in silence has to be said by hand: it did not
+# copy a block index that repeats, and the table holds the null block
+# wherever a slot has no key to read.  By hand every page is a copy, the
+# null block like any other.  So an item whose entries are ALL the null
+# block (an idle slot's) is skipped, fetch and arithmetic (gpt2-1p3b's
+# steady cell, one or two of 8 slots running, read its token gaps 1-3%
+# longer without: a layer's call with 1 of 8 slots running 52.1 (pipeline)
+# | 52.5 | 50.2 us, and 0.7% dearer where no slot idles).  And (PR 47) A
+# WINDOW LAYER'S ITEMS TILE ITS BAND: groups counted from table entry 0
+# made a window of 512 keys on pages of 64 tokens (a band of 8-9 entries)
+# two groups of 8, 16 copies of 328 KB for 9, and the kernel's time is its
+# bytes there; now the band's ``lo .. hi`` is cut into as few items as hold
+# it at the pages above, each as small as still does, from ``lo`` on: 2
+# items of 5.  Only the last item's tail is dead.  us a layer's call on the
+# chip, the kernel alone at that shape (64 slots, 40 heads on 20 of 64 at
+# the differential wiring, contexts 600-30k; my chip runs, PR 47), by
+# (pages an item, items a slot) and the copies it starts for 573 live pages:
+#
+#   groups from entry 0 (8, 2)  438.9 (1,000) | from the band's first page:
+#   (5, 2) 282.9 (640)   (3, 3) 266.6 (576)   (4, 3) 332.9 (756)
+#   (6, 2) 338.3 (768)   (9, 1) 255.5 (576)   (10, 1) 283.3 (640)
+#
+# 0.44 us a copy of 328 KB whatever the items (742 GB/s: the time IS the
+# bytes; 320 keys a step, 2.5 tiles of lanes, cost nothing), and a step's
+# own latency shows only between forms that copy alike ((9, 1) under (3,
+# 3)).  In phi4's decode program a window layer's call reads 439.2-440.7
+# -> 280.9-281.0 us, the program 26.141 -> 24.968 ms.  What is left: a
+# running slot's single null pages behind its newest key (a branch and a
+# wait a page: not taken), and an item that outgrows its bytes by a page to
+# hold a band whole, ``PERF.md`` section 7.4(a).
 
-FOLD_PAGES = 8  # pages a grid step takes (128 keys at 16 a page)
+FOLD_PAGES = 8  # the least pages an item of a full layer takes, folded
+LATENT_KEYS = 512  # the least keys an item takes on latent pages
+ITEM_BYTES = 2 ** 20  # what an item's pages weigh over the pools, about
 
 
 class WorkList(NamedTuple):
-    """The folded kernel's grid: item ``w < n_items`` is group
-    ``group_of[w]`` of slot ``slot_of[w]``; ``first``/``last`` [S] are a
-    slot's first and last group (where its sums start and are written)."""
+    """The MXU kernels' grid: item ``w < n_items`` is the ``pages`` table
+    entries of slot ``slot_of[w]`` from entry ``page0_of[w]`` on (``pages``:
+    ``item_pages`` of the same pools and window); ``first``/``last`` [S] are
+    a slot's first and last ITEM (where its sums start and are written)."""
     first: jax.Array
     last: jax.Array
-    # [S * steps + 1], one more than a dense grid has steps: the pipeline
+    # [S * steps + 1], one more than a dense grid has steps: the kernel
     # reads item w + 1's indices while it runs item w, the last one too
     slot_of: jax.Array
-    group_of: jax.Array
+    page0_of: jax.Array
     n_items: jax.Array   # [] int32
+    # [] int32, the mechanism's two counters over the slots that run: the
+    # page copies the items start (items x pages: an item is fetched whole)
+    # and the table entries among them that hold a key a slot attends
+    pages_copied: jax.Array
+    pages_live: jax.Array
 
     @property
     def dense(self) -> int:
-        """The steps of a dense ``slots x groups`` grid: the list's bound."""
+        """The steps of a dense ``slots x items`` grid: the list's bound."""
         return self.slot_of.shape[0] - 1
 
 
-def _fold(max_blocks: int, block_size: int, window: int | None,
-          pages: int = FOLD_PAGES):
-    """(pages a group, groups a table row, groups a slot can have live)."""
-    pages = min(pages, max_blocks)
-    groups = -(-max_blocks // pages)
+def kernel_pools(k_pool, v_pool) -> tuple:
+    """The arrays an MXU kernel reads of a layer's pair: key pages and value
+    pages, or the ONE pool of latent pages."""
+    return (k_pool,) if is_latent(k_pool, v_pool) else (k_pool, v_pool)
+
+
+def item_pages(pools, max_blocks: int,
+               window: int | None = None) -> tuple[int, int]:
+    """(the pages an item of the work list takes, the most items a slot has)
+    for one layer's ``pools`` (``kernel_pools``: arrays or shapes ``[NB, bs,
+    F]``) under tables of ``max_blocks`` entries: THE geometry, for the list
+    and for both kernels, from what the shapes say and nothing else.
+
+    A grid step costs about 0.68 us of its own whatever it fetches, so an
+    item takes the pages its BYTES ask for: as many of the least item (8
+    folded pages, 512 latent keys) as come nearest ``ITEM_BYTES`` over the
+    pools.  A full layer's items are that many entries from entry 0 on.  A
+    ``window`` layer's items tile its BAND, entries ``(ctx - window + 1) //
+    bs .. ctx // bs`` and at most ``(window - 1) // bs + 2`` of them: as
+    few items as hold the longest band at those pages, each as small as
+    still does (a band of 9 pages under 8 a step: 2 items of 5)."""
+    _, bs, F = pools[0].shape
+    page = len(pools) * bs * F * jnp.dtype(pools[0].dtype).itemsize
+    least = FOLD_PAGES if len(pools) == 2 else max(1, LATENT_KEYS // bs)
+    pages = min(least * max(1, int(ITEM_BYTES / (least * page) + 0.5)),
+                max_blocks)
     if window is None:
-        return pages, groups, groups
-    # the band (ctx - window, ctx] spans at most this many groups
-    return pages, groups, min(groups, (window - 1) // (pages * block_size) + 2)
+        return pages, -(-max_blocks // pages)
+    band = min((window - 1) // bs + 2, max_blocks)
+    items = -(-band // pages)
+    return -(-band // items), items
 
 
 def folded_work_list(ctx_lens: jax.Array, active: jax.Array | None = None, *,
-                     max_blocks: int, block_size: int,
-                     window: int | None = None,
-                     pages: int = FOLD_PAGES) -> WorkList:
-    """The live (slot, group) items of one decode step for the layers of one
-    ``window``: groups ``0 .. ctx // keys`` of a slot, from the band's first
-    group with a window; group 0 alone for a slot that is not ``active``.
-    A group is ``pages`` pages (the keys a grid step takes: the kernel's
-    own number).  A few vector operations on the device: built once a step
-    a kind of layer, whatever the number of layers."""
-    pages, groups, steps = _fold(max_blocks, block_size, window, pages)
-    keys = pages * block_size
+                     pools, max_blocks: int,
+                     window: int | None = None) -> WorkList:
+    """The items of one decode step for the layers of one ``window`` whose
+    pages are ``pools``' (``kernel_pools``; ``item_pages`` sizes an item): a
+    slot's table entries ``lo .. ctx // bs`` cut into items of ``pages``
+    from ``lo`` on, which is 0 on a full layer and the band's first entry
+    with a window; one item, from entry 0, for a slot that is not
+    ``active`` (its table holds the null block alone: skipped).  In slot
+    order, ascending inside a slot.  A few vector operations on the device:
+    built once a step a kind of layer, whatever the number of layers."""
+    bs = pools[0].shape[1]
+    pages, steps = item_pages(pools, max_blocks, window)
     ctx = jnp.maximum(ctx_lens.astype(jnp.int32), 0)
-    last = jnp.minimum(ctx // keys, groups - 1)
-    first = (jnp.zeros_like(last) if window is None
-             else jnp.minimum(jnp.maximum(ctx - window + 1, 0) // keys, last))
-    if active is not None:
-        first, last = jnp.where(active, first, 0), jnp.where(active, last, 0)
-    ends = jnp.cumsum(last - first + 1)  # a slot's items end before ends[s]
+    hi = jnp.minimum(ctx // bs, max_blocks - 1)
+    lo = (jnp.zeros_like(hi) if window is None
+          else jnp.minimum(jnp.maximum(ctx - window + 1, 0) // bs, hi))
+    runs = jnp.ones_like(hi, bool) if active is None else active
+    lo, hi = jnp.where(runs, lo, 0), jnp.where(runs, hi, 0)
+    count = (hi - lo) // pages + 1       # a slot's items
+    ends = jnp.cumsum(count)             # they end before ends[s]
+    first = ends - count
     w = jnp.arange(ctx.shape[0] * steps + 1, dtype=jnp.int32)
-    # past n_items the list repeats the last slot's last group: never run
+    # past n_items the list repeats the last slot's last item: never run
     slot_of = jnp.minimum(jnp.sum(w[:, None] >= ends[None, :], axis=1),
                           ctx.shape[0] - 1).astype(jnp.int32)
-    group_of = jnp.minimum(last[slot_of] - (ends[slot_of] - 1 - w),
-                           last[slot_of])
-    return WorkList(first, last, slot_of, group_of, ends[-1])
+    nth = jnp.clip(w - first[slot_of], 0, count[slot_of] - 1)
+    return WorkList(
+        first, ends - 1, slot_of, lo[slot_of] + nth * pages, ends[-1],
+        pages * jnp.sum(jnp.where(runs, count, 0)),
+        jnp.sum(jnp.where(runs, hi - lo + 1, 0)))
 
 
 def _folded_kernel(tables_ref, ctx_ref, first_ref, last_ref, slot_ref,
-                   group_ref, q_ref, *refs, pages: int, bs: int,
+                   page0_ref, q_ref, *refs, pages: int, bs: int,
                    window: int | None, scale: float,
                    value_dim: int | None = None):
-    """One item of the work list.  ``refs``: the pools as they lie in HBM
+    """One item of the work list: ``pages`` table entries of its slot from
+    its first page on.  ``refs``: the pools as they lie in HBM
     (key pages and value pages; ``value_dim``: ONE pool of latent pages,
     whose row is the key and whose first ``value_dim`` numbers are the
     value), the output block, the float32 sums, then a ``[2, pages, bs, F]``
@@ -478,11 +546,11 @@ def _folded_kernel(tables_ref, ctx_ref, first_ref, last_ref, slot_ref,
     o_ref, acc_ref, m_ref, l_ref = refs[n_pools:n_pools + 4]
     bufs, sem = refs[n_pools + 4:-1], refs[-1]
     w, n_items = pl.program_id(0), pl.num_programs(0)
-    s, g = slot_ref[w], group_ref[w]
+    s = slot_ref[w]
 
     def page_ids(item):
-        si, first_page = slot_ref[item], group_ref[item] * pages
-        return [tables_ref[si, first_page + i] for i in range(pages)]
+        si, page0 = slot_ref[item], page0_ref[item]
+        return [tables_ref[si, page0 + i] for i in range(pages)]
 
     def holds_a_page(ids):
         # the table has the null block (0) wherever a slot has no key to
@@ -507,7 +575,7 @@ def _folded_kernel(tables_ref, ctx_ref, first_ref, last_ref, slot_ref,
     # (the list's arrays hold one entry more than the list: PR 30's fault)
     pl.when(w + 1 < n_items)(lambda: fetch(w + 1, (w + 1) % 2))
 
-    @pl.when(g == first_ref[s])
+    @pl.when(w == first_ref[s])
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_BIG)
@@ -516,7 +584,7 @@ def _folded_kernel(tables_ref, ctx_ref, first_ref, last_ref, slot_ref,
     @pl.when(holds_a_page(page_ids(w)))
     def _attend():
         ctx = ctx_ref[s]
-        start = g * (pages * bs)
+        start = page0_ref[w] * bs
         b = w % 2
         for p in range(n_pools):  # ONE wait a buffer: its pages' bytes, summed
             pltpu.make_async_copy(bufs[p].at[b], bufs[p].at[b],
@@ -552,25 +620,29 @@ def _folded_kernel(tables_ref, ctx_ref, first_ref, last_ref, slot_ref,
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(g == last_ref[s])
+    @pl.when(w == last_ref[s])
     def _finish():
         o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
                     ).astype(o_ref.dtype)
 
 
 def _fetching_call(q, pools, tables, ctx_lens, work: WorkList, *, pages: int,
-                   out_width: int, interpret, **kernel):
+                   out_width: int, interpret, window: int | None, **kernel):
     """``_folded_kernel`` over ``work``: ``q`` [S, Hq, F] and the output
     [S, Hq, out_width] are blocks that follow an item's slot; each of
     ``pools`` [NB, bs, F] is ONE operand left where it lies; ``tables`` [S,
-    MB] is padded to whole groups of ``pages``."""
+    MB] is padded with the null block as far as an item reads: to whole
+    items on a full layer (nothing where ``pages`` divides MB: the operand
+    keeps the shape ``benchmark/metrics/paged_attn_roofline.py`` tells the
+    kernel by), by one item's ``pages`` under a window, whose items start
+    wherever a band does."""
     S, Hq, F = q.shape
-    bs = pools[0].shape[1]
-    tables = jnp.pad(tables.astype(jnp.int32),
-                     ((0, 0), (0, -tables.shape[1] % pages)))
+    bs, MB = pools[0].shape[1], tables.shape[1]
+    tables = jnp.pad(tables.astype(jnp.int32), ((0, 0), (
+        0, -MB % pages if window is None else pages)))
 
     def rows(width):
-        return pl.BlockSpec((1, Hq, width), lambda w, t, c, f, l, so, go: (
+        return pl.BlockSpec((1, Hq, width), lambda w, t, c, f, l, so, po: (
             so[w], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -587,7 +659,8 @@ def _fetching_call(q, pools, tables, ctx_lens, work: WorkList, *, pages: int,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_folded_kernel, pages=pages, bs=bs, **kernel),
+        functools.partial(_folded_kernel, pages=pages, bs=bs, window=window,
+                          **kernel),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Hq, out_width), q.dtype),
         # item w + 1's pages land while item w runs: the items in order
@@ -637,10 +710,11 @@ def paged_attention_folded(
                          f"{kvH} kv heads")
     G = Hq // kvH
     MB = tables.shape[1]
-    pages = _fold(MB, bs, window)[0]
+    pools = (k_pool, v_pool)
+    pages = item_pages(pools, MB, window)[0]
     ctx_lens = ctx_lens.astype(jnp.int32)
     if work is None:
-        work = folded_work_list(ctx_lens, max_blocks=MB, block_size=bs,
+        work = folded_work_list(ctx_lens, pools=pools, max_blocks=MB,
                                 window=window)
     # query head (h, g) in the lanes of KV head h, zeros elsewhere; its
     # output in the lanes of the value heads it reads (one, or a pair)
@@ -654,7 +728,7 @@ def paged_attention_folded(
         key_of[:, None] // 2 == np.arange(kvH // 2)[None, :], q.dtype)
     qf = jnp.einsum("shd,hk->shkd", q, own).reshape(S, Hq, F)
     out = _fetching_call(
-        qf, (k_pool, v_pool), tables, ctx_lens, work, pages=pages,
+        qf, pools, tables, ctx_lens, work, pages=pages,
         out_width=F, interpret=interpret, window=window,
         scale=1.0 / float(np.sqrt(hd)))
     # float32 here too (the default rounds this sum over the 0/1 ``own``)
@@ -674,19 +748,15 @@ def paged_attention_folded(
 # bytes, 60 FLOP/B at 32 heads, still under the v5e's 240.  What it costs
 # is again its grid: a step costs about 0.7 us of its own whatever it
 # fetches (the table above), and 128 latent keys are 164 KB, 0.20 us of
-# HBM time.  So a step takes ``LATENT_KEYS`` keys (655 KB in rows of 640
-# lanes, 0.80 us), in as few page copies as the pool's block size allows:
-# 1.15 us an item by hand at 24 slots x 32 heads x 5.5k keys (1.20 through
-# the pipeline; 1.19 | 1.25 at 64 heads), of it the matmuls' 0.19 that the
-# copies do not cover (my chip runs, PR 44).
-
-
-LATENT_KEYS = 512  # keys a grid step takes
-
-
-def latent_pages(max_blocks: int, block_size: int) -> int:
-    """Pages a grid step of the latent kernel takes."""
-    return max(1, min(LATENT_KEYS // block_size, max_blocks))
+# HBM time.  So a step takes ``LATENT_KEYS`` keys AT LEAST (655 KB in rows
+# of 640 lanes, 0.80 us), in as few page copies as the pool's block size
+# allows: 1.15 us an item by hand at 24 slots x 32 heads x 5.5k keys (1.20
+# through the pipeline; 1.19 | 1.25 at 64 heads), of it the matmuls' 0.19
+# that the copies do not cover (my chip runs, PR 44); and since PR 47 the
+# pages its bytes ask for (``item_pages``): 16 pages of 64 rows, 1,024
+# keys and 1.3 MB a step, 1.83 us an item and 15% less a key at 5.5k keys
+# (310.7 -> 263.6 us a layer's call; my chip runs, PR 44).  The chunk
+# kernel below keeps a key block of its own (``LATENT_CHUNK_KEYS``).
 
 
 def paged_attention_latent(
@@ -705,19 +775,18 @@ def paged_attention_latent(
     in zeros where it is wider: ``kv_pool.stored_row``), ``tables`` [S, MB],
     keys ``0..ctx`` attendable, scores ``q . row * scale``, values a row's
     first ``value_dim`` numbers.  ``work`` is ``folded_work_list`` of the
-    same contexts with ``pages=latent_pages(MB, bs)``.  Returns [S, Hq,
+    same contexts and ``pools=(pool,)``.  Returns [S, Hq,
     value_dim] in ``q.dtype``: the probabilities over the cached latents,
     which ``LatentAttention.lift`` takes to the heads' outputs."""
     if interpret is None:
         interpret = _default_interpret()
-    _, bs, F = pool.shape
+    F = pool.shape[2]
     q = jnp.pad(q, ((0, 0), (0, 0), (0, F - q.shape[2])))
     MB = tables.shape[1]
-    pages = latent_pages(MB, bs)
+    pages = item_pages((pool,), MB)[0]
     ctx_lens = ctx_lens.astype(jnp.int32)
     if work is None:
-        work = folded_work_list(ctx_lens, max_blocks=MB, block_size=bs,
-                                pages=pages)
+        work = folded_work_list(ctx_lens, pools=(pool,), max_blocks=MB)
     return _fetching_call(
         q, (pool,), tables, ctx_lens, work, pages=pages, out_width=value_dim,
         interpret=interpret, window=None, scale=float(scale),
@@ -727,7 +796,7 @@ def paged_attention_latent(
 # -- the chunk kernel: a prompt chunk's queries over the slot's latent pages ------
 #
 # A chunk's C queries attend the EXPANDED form (at 512 queries x 32 heads the
-# absorbed form is twice the work), a block of ``LATENT_KEYS`` keys at a time.
+# absorbed form is twice the work), ``LATENT_CHUNK_KEYS`` keys at a time.
 # In ``jax.numpy`` (``programs._over_key_blocks``) a key block is six fusions:
 # the expansion, two score products, and three passes of vector work over
 # the block's ``[32, 512, 512]`` float32 scores (mask and row maximum; ``exp``
@@ -750,6 +819,8 @@ def paged_attention_latent(
 # operations in the same precision as the plain form, which stays the oracle
 # and the path of every shape the kernel does not tile.
 
+LATENT_CHUNK_KEYS = 512  # keys a key block of the chunk kernel holds (its
+# own number: the decode list's items follow their bytes, ``item_pages``)
 LATENT_CHUNK_HEADS = 8  # heads a grid step of the chunk kernel takes (16
 # were slower by 1.7x: the unrolled program outgrows the instruction memory)
 _LATENT_CHUNK_VMEM = 64 * 2**20  # of a v5e's 128 MiB; the default 16 hold no step
@@ -764,10 +835,15 @@ def latent_chunk_tiles(chunk: int, block_size: int, heads: int, rank: int,
     multiplies float32 too, which the interpreter's tests use; compiled, its
     HIGHEST products unroll to 15 MB of program: the plain form's)."""
     return (not _default_interpret() and chunk % _LANES == 0
-            and LATENT_KEYS % block_size == 0
+            and LATENT_CHUNK_KEYS % block_size == 0
             and heads % LATENT_CHUNK_HEADS == 0
             and rank % _LANES == 0 and nope % _LANES == 0
             and jnp.dtype(dtype) == jnp.bfloat16)
+
+
+def latent_chunk_pages(max_blocks: int, block_size: int) -> int:
+    """Pages a key block of the chunk kernel takes."""
+    return max(1, min(LATENT_CHUNK_KEYS // block_size, max_blocks))
 
 
 def latent_chunk_key_blocks(pos0, chunk: int, max_blocks: int,
@@ -775,7 +851,7 @@ def latent_chunk_key_blocks(pos0, chunk: int, max_blocks: int,
     """The key blocks a chunk of ``chunk`` rows at ``pos0`` attends, the
     first to the one it wrote: the kernel's grid steps a group of heads
     (``pos0`` an int for the engine's counter, traced for the grid)."""
-    keys = latent_pages(max_blocks, block_size) * block_size
+    keys = latent_chunk_pages(max_blocks, block_size) * block_size
     return (pos0 + chunk - 1) // keys + 1
 
 
@@ -875,7 +951,7 @@ def latent_chunk_attention(q_nope, q_rope, pool, table_row, pos0, w_uk, w_uv,
     rank, dv = w_uk.shape[0], w_uv.shape[2]
     heads = min(LATENT_CHUNK_HEADS, H)
     MB = table_row.shape[0]
-    pages = latent_pages(MB, bs)
+    pages = latent_chunk_pages(MB, bs)
     # whole key blocks up to the last a chunk may reach (the null block past
     # the row's end: those keys lie after every query)
     n_kb = -(-(MB * bs + C) // (pages * bs))
