@@ -20,7 +20,9 @@ from ``--seed``):
    which the 1.3B model does not reach: the folded paged kernel at 30 query
    heads on 30 KV heads of 128 with every slot full, and the gated delta
    rule's chunk and step kernels (30 heads, keys of 96, values of 192)
-   against the token-by-token recurrence, compiled and not interpreted;
+   against the token-by-token recurrence, compiled and not interpreted,
+   and the chunk kernels against themselves as they were before two
+   sub-chunks' systems were solved as one (the same bits: 0.0 expected);
    and the latent decode kernel at JoyAI-LLM-Flash's widths (32 heads over
    rows of 512 + 64 numbers, 24 slots of 34,816: empty, partly filled,
    every slot full) against plain ``jax.numpy``, with a call's time at each,
@@ -364,6 +366,76 @@ def serve_phase(sz: Sizes, *, seed: int, on_chip: bool) -> None:
             gc.collect()
 
 
+def delta_rule_float64(q, k, v, g, beta, state):
+    """``ops.gated_delta.gated_delta_recurrent`` on the host in float64: the
+    oracle of the chunk kernels' checks.  The same lines in float32 ON THE
+    CHIP are not one: a token's state is the last one's times ``exp(g)``,
+    512 roundings of the chip's exponential compounded, 1.1e-4 to 2.5e-4 of
+    the largest entry from this, where the kernels (ONE exponential of
+    summed log-decays) are 3e-6 to 4e-6 (``PERF.md`` section 6, PR 48)."""
+    import numpy as np
+
+    q, k, v, g, beta, S = (np.asarray(x, np.float64)
+                           for x in (q, k, v, g, beta, state))
+    o = np.empty(v.shape)
+    for t in range(q.shape[0]):
+        a = np.exp(g[t])  # a head's [H], or a key channel's [H, d_k]
+        S = a.reshape(*a.shape, *(1,) * (3 - a.ndim)) * S
+        u = beta[t][:, None] * (v[t] - np.einsum("hkv,hk->hv", S, k[t]))
+        S = S + k[t][:, :, None] * u[:, None, :]
+        o[t] = np.einsum("hkv,hk->hv", S, q[t])
+    return o, S
+
+
+def parents_unit_lower_inverse(A, dot, block=None):
+    """``ops.gated_delta._unit_lower_inverse`` as it was before PR 48: ``X``
+    starts as the identity and EVERY round is two products."""
+    import jax
+    import jax.numpy as jnp
+
+    n = A.shape[-1]
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    X = (r == c).astype(jnp.float32)
+    for bit in range(math.ceil(math.log2(block or n))):
+        quarter = ((r >> (bit + 1)) == (c >> (bit + 1))) & (
+            (r & (1 << bit)) != 0) & ((c & (1 << bit)) == 0)
+        X = X - dot(dot(X, jnp.where(quarter, A, 0.0)), X)
+    return X
+
+
+def same_as_parent(rec: dict, name: str, entry, args, interpret: bool) -> None:
+    """A chunk kernel's output and state against the kernel as it was before
+    PR 48 solved two sub-chunks' systems as one: a group of ONE sub-chunk
+    (every solve alone, every product behind it 64 rows deep; the group
+    only ever decided what is scheduled side by side) and the parent's lines
+    for the solve.  The change rests on the two being the same bits: the
+    differences are recorded, 0.0 expected, and past 1e-6 of the largest
+    entry the phase fails."""
+    import jax
+    import jax.numpy as jnp
+
+    from torch_automatic_distributed_neural_network_tpu.ops import (
+        gated_delta as gd,
+    )
+
+    run = lambda: jax.jit(lambda *a: entry(  # noqa: E731 — traced anew
+        *a, interpret=interpret))(*args)
+    new = run()
+    keep = gd.CHUNK_GROUP, gd._unit_lower_inverse
+    gd.CHUNK_GROUP, gd._unit_lower_inverse = 1, parents_unit_lower_inverse
+    try:
+        old = run()
+    finally:
+        gd.CHUNK_GROUP, gd._unit_lower_inverse = keep
+    for what, a, b in zip(("out", "state"), new, old):
+        diff = float(jnp.max(jnp.abs(a - b)))
+        rec[f"{name}_{what}_vs_parent_max_abs_diff"] = diff
+        if not diff <= 1e-6 * float(jnp.max(jnp.abs(b))):
+            raise RuntimeError(f"{name} {what}: {diff:.3e} from the kernel "
+                               "before PR 48, which it should equal")
+
+
 def hybrid_kernels_phase(*, seed: int, on_chip: bool) -> dict:
     """The kernels a hybrid model adds, at Olmo-Hybrid-7B's widths on the
     chip (a tenth of them in a rehearsal), float32 in and against float32
@@ -419,7 +491,7 @@ def hybrid_kernels_phase(*, seed: int, on_chip: bool) -> dict:
     g = -A * jnp.exp(jax.random.uniform(
         next(keys), (C, H), minval=math.log(1e-3), maxval=math.log(0.1)))
     state = jax.random.normal(next(keys), (H, dk, dv), jnp.float32)
-    o_ref, s_ref = jax.jit(gd.gated_delta_recurrent)(q, k, v, g, beta, state)
+    o_ref, s_ref = delta_rule_float64(q, k, v, g, beta, state)
     o, s1 = jax.jit(lambda *a: gd.gated_delta_chunk_pallas(
         *a, interpret=interpret))(q, k, v, g, beta, state)
     close("gdn_chunk_out", o, o_ref)
@@ -428,6 +500,10 @@ def hybrid_kernels_phase(*, seed: int, on_chip: bool) -> dict:
     o, s1 = jax.jit(lambda *a: gd.gated_delta_chunk_pallas(
         *a, interpret=interpret))(lo(q), lo(k), lo(v), g, beta, state)
     close("gdn_chunk_bf16_out", o, o_ref, rtol=0.05)
+    same_as_parent(rec, "gdn_chunk", gd.gated_delta_chunk_pallas,
+                   (q, k, v, g, beta, state), interpret)
+    same_as_parent(rec, "gdn_chunk_bf16", gd.gated_delta_chunk_pallas,
+                   (lo(q), lo(k), lo(v), g, beta, state), interpret)
     # 3. the step kernel: S slots over rows of a pool, in place; two slots
     # share the null row, one row is nobody's
     rows = jnp.asarray([(r + 2) % (S + 1) if r < S - 2 else 0
@@ -481,7 +557,7 @@ def kda_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:
         next(keys), (C, H, dk), minval=math.log(1e-3), maxval=math.log(0.1)))
     g = g.at[:, :, 0].set(-60.0).at[:, :, 1].set(0.0)
     state = jax.random.normal(next(keys), (H, dk, dv), jnp.float32)
-    o_ref, s_ref = jax.jit(gd.gated_delta_recurrent)(q, k, v, g, beta, state)
+    o_ref, s_ref = delta_rule_float64(q, k, v, g, beta, state)
     chunk = jax.jit(lambda *a: gd.kda_chunk_pallas(*a, interpret=interpret))
     o, s1 = chunk(q, k, v, g, beta, state)
     close("kda_chunk_out", o, o_ref)
@@ -489,6 +565,10 @@ def kda_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:
     lo = lambda x: x.astype(jnp.bfloat16)  # noqa: E731 — serving's operands
     o, s1 = chunk(lo(q), lo(k), lo(v), g, beta, state)
     close("kda_chunk_bf16_out", o, o_ref, rtol=0.05)
+    same_as_parent(rec, "kda_chunk", gd.kda_chunk_pallas,
+                   (q, k, v, g, beta, state), interpret)
+    same_as_parent(rec, "kda_chunk_bf16", gd.kda_chunk_pallas,
+                   (lo(q), lo(k), lo(v), g, beta, state), interpret)
     rows = jnp.asarray([(r + 2) % (S + 1) if r < S - 2 else 0
                         for r in range(S)], jnp.int32)
     pool0 = jax.random.normal(next(keys), (S + 2, H, dk, dv), jnp.float32)

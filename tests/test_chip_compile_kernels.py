@@ -408,15 +408,20 @@ def test_grouped_matmul_gathers_its_rows_for_v5e(v5e, tokens, top_k, d, f):
 
 
 @pytest.mark.parametrize("rule", ["gdn", "kda"])
-@pytest.mark.parametrize("form,dtype", [
-    ("chunk", jnp.bfloat16), ("chunk", jnp.float32), ("step", jnp.bfloat16)])
-def test_gated_delta_kernels_compile_for_v5e(v5e, form, dtype, rule):
+@pytest.mark.parametrize("form,dtype,T", [
+    ("chunk", jnp.bfloat16, 512), ("chunk", jnp.float32, 512),
+    ("chunk", jnp.bfloat16, 192), ("chunk", jnp.float32, 192),
+    ("step", jnp.bfloat16, None)])
+def test_gated_delta_kernels_compile_for_v5e(v5e, form, dtype, T, rule):
     """``gdn``: 30 heads, keys of 96 and values of 192 (neither a multiple
-    of the lane width): the chunk kernel over a prefill chunk of 512 in
-    serving's bfloat16 and in ``chip_smoke.py``'s float32, the step kernel
-    over 8 slots of a pool of 9 rows, which it reads and writes in place.
-    ``kda``: the kernels of a decay a channel at Kimi-Linear's widths, 32
-    heads of 128 and 128, 96 slots of a pool of 97 rows (203 MB)."""
+    of the lane width): the chunk kernel over a prefill chunk of 512 (eight
+    sub-chunks a head, solved as four pairs: two ``[64, 64]`` float32
+    systems on the diagonal of a ``[128, 128]`` one, the rows behind them
+    stacked) and over 192 rows (a pair and a single) in serving's bfloat16
+    and in ``chip_smoke.py``'s float32, the step kernel over 8 slots of a
+    pool of 9 rows, which it reads and writes in place.  ``kda``: the
+    kernels of a decay a channel at Kimi-Linear's widths, 32 heads of 128
+    and 128, 96 slots of a pool of 97 rows (203 MB)."""
     from torch_automatic_distributed_neural_network_tpu.ops import gated_delta as gd
 
     one = SingleDeviceSharding(v5e[0])
@@ -427,7 +432,6 @@ def test_gated_delta_kernels_compile_for_v5e(v5e, form, dtype, rule):
                    if rule == "gdn" else
                    (gd.kda_chunk_pallas, gd.kda_step_pallas))
     if form == "chunk":
-        T = 512
         text = _compile(
             chunk, sds((T, H, dk), dtype),
             sds((T, H, dk), dtype), sds((T, H, dv), dtype),

@@ -6,11 +6,14 @@ channel (``"channel"``: ``tadnn_kda_chunk``, ``tadnn_kda_step``).  Float32
 throughout, so the tolerance is rounding alone: 1e-5 of values of order
 one."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import chip_smoke
 from torch_automatic_distributed_neural_network_tpu.ops import gated_delta as gd
 
 H, DK, DV = 3, 16, 24
@@ -62,10 +65,12 @@ def rows_of(keep, x):
 @pytest.mark.parametrize("decay", DECAYS)
 @pytest.mark.parametrize("form", sorted(CHUNK_FORMS))
 @pytest.mark.parametrize("neg_eigval", [True, False])
-@pytest.mark.parametrize("T", [1, 5, 64, 100, 130])
+@pytest.mark.parametrize("T", [1, 5, 64, 100, 130, 192, 512])
 def test_chunk_form_is_the_recurrence(form, neg_eigval, T, decay):
     """Lengths that are no whole sub-chunk, one shorter than a sub-chunk,
-    and several sub-chunks; beta up to 2 and up to 1."""
+    and several sub-chunks (two: a pair of systems solved as one; three: a
+    pair and a single; eight: a serving cell's chunk, four pairs); beta up
+    to 2 and up to 1."""
     args = inputs(T, seed=T, neg_eigval=neg_eigval, decay=decay)
     o_ref, s_ref = gd.gated_delta_recurrent(*args)
     o, s = CHUNK_FORMS[form](*args)
@@ -91,6 +96,60 @@ def test_neighbouring_keys_that_are_alike(form, decay):
     np.testing.assert_allclose(o, o_ref, rtol=1e-4, atol=1e-4 * scale)
     np.testing.assert_allclose(s, s_ref, rtol=1e-4,
                                atol=1e-4 * float(jnp.abs(s_ref).max()))
+
+
+def _systems(case: str, n: int):
+    """Two strictly lower triangular ``[n, n]`` systems as a sub-chunk makes
+    them, ``A[t, j] = beta_t k_t . k_j`` times a decay."""
+    q, k, v, g, beta, _ = inputs(2 * n, seed=31, heads=1)
+    if case == "alike_keys":  # test_neighbouring_keys_that_are_alike's
+        base = jax.random.normal(jax.random.key(1), (1, 1, DK))
+        k = gd.l2norm(base + 0.05 * k)
+        beta, g = 1.9 + 0.1 * beta / 2.0, g / 100.0
+    elif case == "random":  # no keys behind it: entries of order one
+        A = jax.random.normal(jax.random.key(2), (2, n, n))
+        return tuple(jnp.tril(A, -1))
+    k, beta, gam = (x[:, 0].reshape(2, n, -1) for x in (k, beta, g))
+    gam = jnp.cumsum(gam[..., 0], -1)
+    decay = jnp.exp(jnp.tril(gam[..., :, None] - gam[..., None, :]))
+    return tuple(jnp.tril(decay * beta * jnp.einsum("btc,bjc->btj", k, k), -1))
+
+
+@pytest.mark.parametrize("n", [1, 8, 40, 64])
+@pytest.mark.parametrize("case", ["keys", "alike_keys", "random"])
+def test_solve_of_two_systems_as_one_is_each_alone(case, n):
+    """What the chunk kernels' solve does since PR 48, against what it did:
+    ``_unit_lower_inverse`` without its first round's two products is the
+    parent's lines (kept in ``chip_smoke.py``, which holds the kernels on
+    the chip against them), and two systems on the diagonal of one ``[2 n, 2 n]``
+    matrix (``n`` a power of two), solved in ``log2 n`` rounds, are each
+    alone: exactly on the CPU, where a product that adds exact zeros
+    changes no bit, and to 1e-6 of the largest entry anywhere; with keys
+    that all point nearly the same way and beta near 2, where the inverse's
+    entries grow."""
+    dot = functools.partial(jnp.matmul, precision=gd.HI)
+    systems = _systems(case, n)
+    alone = [gd._unit_lower_inverse(A, dot) for A in systems]
+    exact = jax.default_backend() == "cpu"
+
+    def same(got, want):
+        np.testing.assert_allclose(
+            got, want, rtol=0, atol=1e-6 * float(jnp.abs(want).max()))
+        if exact:
+            np.testing.assert_array_equal(got, want)
+
+    for A, X in zip(systems, alone):
+        same(X, chip_smoke.parents_unit_lower_inverse(A, dot))
+        np.testing.assert_allclose(  # and it IS the inverse
+            (jnp.eye(n) + A) @ X, jnp.eye(n),
+            atol=2e-5 * float(jnp.abs(X).max()))
+    if n & (n - 1) or n == 1:
+        return  # a sub-chunk shorter than 64 rows is its chunk's only one
+    both = gd._solve(systems)  # the kernels' own lines
+    same(both[:n, :n], alone[0])
+    same(both[n:, n:], alone[1])
+    np.testing.assert_array_equal(both[:n, n:], 0.0)
+    np.testing.assert_array_equal(both[n:, :n], 0.0)
 
 
 def test_decay_is_near_one_in_these_tests():

@@ -19,9 +19,13 @@ arithmetic live here:
   tokens' writes are solved together (``(I + A) U = beta (V - G K S_0)``,
   ``A`` strictly lower triangular: the WY form of a product of
   Householder-like factors) and the state is carried from one sub-chunk to
-  the next.  :func:`gated_delta_chunk` runs the Pallas kernel
-  ``tadnn_gdn_chunk`` (grid: heads x groups of sub-chunks) on a TPU and
-  the plain ``jax.numpy`` form
+  the next.  The solve is ``_unit_lower_inverse``, by halves, float32
+  products at highest precision in every form; the kernels solve two
+  sub-chunks' systems as one block-diagonal ``[128, 128]`` one, whose
+  products fill the MXU's array and add only exact zeros (the same bits,
+  5 products a sub-chunk for 12).  :func:`gated_delta_chunk` runs the
+  Pallas kernel ``tadnn_gdn_chunk`` (grid: heads x groups of sub-chunks)
+  on a TPU and the plain ``jax.numpy`` form
   (:func:`gated_delta_chunk_xla`) elsewhere; state in, state out;
 - the STEP form for decode, one token a slot against a pool of states
   ``[rows, H, d_k, d_v]`` read and written in place through a vector of
@@ -207,7 +211,7 @@ def _chunk_operands(q, k, v, g, beta):
         gc=jnp.exp(gam[..., -1])), T
 
 
-def _unit_lower_inverse(A, dot):
+def _unit_lower_inverse(A, dot, block=None):
     """``(I + A)^-1`` for strictly lower triangular ``A`` [.., n, n], by
     halves: with ``X`` the inverse of the diagonal blocks of size ``s``
     (zero elsewhere; the identity at ``s = 1``) and ``R`` the part of ``A``
@@ -215,19 +219,46 @@ def _unit_lower_inverse(A, dot):
     X R X`` is the inverse of the diagonal blocks of size ``2 s``.  Every
     factor is the inverse of a piece of the true system, whose entries the
     recurrence bounds: no power of ``A`` is ever formed (``A^32`` cancels
-    catastrophically in float32 once neighbouring keys are alike).
-    ``ceil(log2 n)`` rounds of two products; shifts and masks only, so that
-    the kernel runs the same lines."""
+    catastrophically in float32 once neighbouring keys are alike).  The
+    first round is no product: at ``s = 1`` ``X`` is the identity, so ``X -
+    X R X`` is ``I - R`` (to the bit: a product with the identity returns
+    its other factor).  Then ``ceil(log2 n) - 1`` rounds of two products;
+    shifts and masks only, so that the kernel runs the same lines.  Where
+    ``A`` is zero outside diagonal blocks of ``block`` rows (a power of two:
+    several systems side by side, ``_solve``), the rounds stop at
+    ``block``: what would join two blocks is zero, and every product adds
+    exact zeros to the terms a block alone would sum."""
     n = A.shape[-1]
     r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    X = (r == c).astype(F32)
-    for bit in range(max(0, math.ceil(math.log2(n)))):
+
+    def quarter(bit):
         s = 1 << bit
-        quarter = ((r >> (bit + 1)) == (c >> (bit + 1))) & (
-            (r & s) != 0) & ((c & s) == 0)
-        X = X - dot(dot(X, jnp.where(quarter, A, 0.0)), X)
+        return jnp.where(((r >> (bit + 1)) == (c >> (bit + 1))) & (
+            (r & s) != 0) & ((c & s) == 0), A, 0.0)
+
+    X = (r == c).astype(F32) - quarter(0)
+    for bit in range(1, math.ceil(math.log2(block or n))):
+        X = X - dot(dot(X, quarter(bit)), X)
     return X
+
+
+def _solve(systems):
+    """The chunk kernels' solve: ``(I + A)^-1`` of one sub-chunk's ``A``
+    [sub, sub], or of two as ONE system, on the diagonal of a ``[2 sub, 2
+    sub]`` matrix of zeros: its inverse by halves holds each one's (the
+    same bits), from products that fill the MXU's array where a ``[64, 64]``
+    one uses a quarter of it, and its rows times two sub-chunks' stacked
+    rows are the two products behind the solve.  Float32 products at
+    highest precision."""
+    A = systems[0]
+    if len(systems) == 2:
+        zero = jnp.zeros_like(A)
+        A = jnp.concatenate([jnp.concatenate([A, zero], 1),
+                             jnp.concatenate([zero, systems[1]], 1)], 0)
+    return _unit_lower_inverse(A, functools.partial(
+        jnp.dot, precision=HI, preferred_element_type=F32),
+        block=systems[0].shape[-1])
 
 
 def gated_delta_chunk_xla(q, k, v, g, beta, state):
@@ -258,7 +289,8 @@ def _chunk_kernel(q_ref, qg_ref, kb_ref, kbg_ref, kT_ref, kdT_ref, bv_ref,
     without the state (its key-key matrix, its solve, the products with the
     solved factor) is written out for every sub-chunk of the group before
     the state passes through them, so that those chains, which do not
-    depend on each other, can be scheduled side by side."""
+    depend on each other, can be scheduled side by side; the solves two
+    sub-chunks at a time (``_solve``), a group's odd last one alone."""
     n = pl.program_id(1)
 
     @pl.when(n == 0)
@@ -271,15 +303,22 @@ def _chunk_kernel(q_ref, qg_ref, kb_ref, kbg_ref, kT_ref, kdT_ref, bv_ref,
     sub = d_ref.shape[-1]
     r = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
-    solved = []
+    systems = []
     for i in range(d_ref.shape[1]):  # static: the group's sub-chunks
         kT, decay = kT_ref[0, i], d_ref[0, i]
-        A = jnp.where(r > c, decay * dot(kb_ref[0, i], kT), 0.0)
-        Tm = _unit_lower_inverse(A, functools.partial(
-            jnp.dot, precision=HI, preferred_element_type=F32)).astype(op)
-        solved.append((
-            dot(Tm, bv_ref[0, i]), dot(Tm, kbg_ref[0, i]).astype(op),
+        systems.append((
+            jnp.where(r > c, decay * dot(kb_ref[0, i], kT), 0.0),
             jnp.where(r >= c, decay * dot(q_ref[0, i], kT), 0.0).astype(op)))
+    solved = []
+    for i in range(0, len(systems), 2):  # two at a time; an odd last alone
+        As, Pqk = zip(*systems[i:i + 2])
+        Tm = _solve(As).astype(op)  # block-diagonal against stacked rows
+        Wv, Wk = (dot(Tm, jnp.concatenate(
+            [ref[0, i + j] for j in range(len(As))], 0))
+            for ref in (bv_ref, kbg_ref))
+        Wk = Wk.astype(op)
+        solved += [(Wv[j * sub:(j + 1) * sub], Wk[j * sub:(j + 1) * sub], P)
+                   for j, P in enumerate(Pqk)]
     S = s_scr[:]
     for i, (Wv, Wk, Pqk) in enumerate(solved):
         Sop = S.astype(op)
@@ -444,8 +483,9 @@ def _kda_chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, o_ref, s_ref,
     ``A`` or ``P`` is a difference of two large numbers and none is
     positive.  (``last - gam``, the decay from a row to the sub-chunk's
     end, IS such a difference, as ``_kda_operands`` has it: at most a
-    rounding above 0.)  Then the sub-chunks' solves, side by side, then the
-    state through them, each of its rows decayed by its own factor.
+    rounding above 0.)  Then the sub-chunks' solves, side by side and two
+    systems as one (``_solve``), then the state through them, each of its
+    rows decayed by its own factor.
 
     ``A[t, j] = sum_c kb_t[c] k_j[c] exp(gam_t[c] - gam_j[c])`` (and ``P``
     with ``q``) BY HALVES, the masks of ``_unit_lower_inverse``: at level
@@ -496,7 +536,7 @@ def _kda_chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, o_ref, s_ref,
     beta_h = jnp.sum(jnp.where(heads == h, b_ref[:], 0.0), 1, keepdims=True)
     groups = [slice(i * sub, (i + 1) * sub)
               for i in range(q_ref.shape[0] // sub)]
-    solved = []
+    systems = []
     for rows in groups:  # static: the group's sub-chunks
         q, k = q_ref[rows].astype(F32), k_ref[rows].astype(F32)
         beta = beta_h[rows]
@@ -510,15 +550,22 @@ def _kda_chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, o_ref, s_ref,
             X = over(jnp.concatenate([kb * e, q * e], 0), k * e, 1, HI)
             A = A + jnp.where(quarters[bit], X[:sub], 0.0)
             P = P + jnp.where(quarters[bit], X[sub:], 0.0)
-        Tm = _unit_lower_inverse(A, functools.partial(
-            jnp.dot, precision=HI, preferred_element_type=F32)).astype(op)
         gam = E[:sub]
         G, last = jnp.exp(gam), gam[sub - 1:]
         gc = jnp.sum(jnp.where(eye, jnp.exp(last), 0.0), 1, keepdims=True)
-        solved.append((
-            dot(Tm, (beta * v_ref[rows].astype(F32)).astype(op)),
-            dot(Tm, (G * kb).astype(op)).astype(op), P.astype(op),
-            (G * q).astype(op), (jnp.exp(last - gam) * k).astype(op), gc))
+        systems.append((
+            A, (beta * v_ref[rows].astype(F32)).astype(op),
+            (G * kb).astype(op),
+            (P.astype(op), (G * q).astype(op),
+             (jnp.exp(last - gam) * k).astype(op), gc)))
+    solved = []
+    for i in range(0, len(systems), 2):  # two at a time; an odd last alone
+        As, bv, kbg, rest = zip(*systems[i:i + 2])
+        Tm = _solve(As).astype(op)  # block-diagonal against stacked rows
+        Wv = dot(Tm, jnp.concatenate(bv, 0))
+        Wk = dot(Tm, jnp.concatenate(kbg, 0)).astype(op)
+        solved += [(Wv[j * sub:(j + 1) * sub], Wk[j * sub:(j + 1) * sub], *x)
+                   for j, x in enumerate(rest)]
     S = s_scr[:]
     for rows, (Wv, Wk, Pqk, qg, kd, gc) in zip(groups, solved):
         Sop = S.astype(op)
