@@ -1070,6 +1070,8 @@ def test_the_train_generators_calls_hold_on_a_tiny_model(bench):
 KDA_METRICS = ("kda_chunk_ms", "kda_step_ms", "kda_chunk_roofline",
                "kda_step_roofline")
 KDA_CELL = "kimi-linear-48b-ep8.serve-backlog-reasoning"
+# the second shape the KDA kernels are judged at (section 11)
+GATED_CELL = "solar-open2-250b-ep8.serve-backlog-reasoning-s128"
 # what the cell's readers index on the events of a model of both kinds
 MIXED_FIELDS = {
     "linear_mixer": "obs/report.py (the state line: whose the decay is)",
@@ -1126,7 +1128,7 @@ def test_the_new_fields_are_in_the_schema(field):
 @pytest.mark.parametrize("name", KDA_METRICS)
 def test_a_kda_metric_has_its_file_and_its_cell(name):
     entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == [KDA_CELL]
+    assert entry["workloads"] == [KDA_CELL, GATED_CELL]
     assert entry["moves"] == "serve_tokens_per_s"
     assert entry["layer"] == "linear attention"
     assert entry["source"] == "device_trace"
@@ -1161,14 +1163,18 @@ def test_a_kda_reader_finds_nothing_where_there_is_nothing(
     capsys.readouterr()
 
 
-def test_the_kda_readers_read_a_hand_made_trace(bench, mixed, capsys):
-    """Two runs of the chunk's program and one decode step; in each the
+@pytest.mark.parametrize("model", ["mixed", "gated"])
+def test_the_kda_readers_read_a_hand_made_trace(bench, model, request,
+                                                capsys):
+    """On each of the two models with KDA layers (three each at the
+    rehearsal's sizes), whatever else their pools hold: two runs of the chunk's program and one decode step; in each the
     three linear layers' step kernel takes 40 us a layer, with a staged
     copy of a layer's pool open for 60 us round the first (the union is
     counted: 100 us in that run), and the chunk kernel 200 us a layer.  The
     shares are the least time of ``counts_kda`` over those times."""
     from lib import counts_kda
 
+    mixed = request.getfixturevalue(model)
     rec0, keys = mixed["record"], mixed["record"]["model_keys"]
     n, H, dk, dv = 3, keys["linear_value_heads"], \
         keys["linear_key_head_dim"], keys["linear_value_head_dim"]
@@ -1335,9 +1341,9 @@ def test_a_hybrid_decoder_metric_has_its_file_and_its_cell(name):
     assert entry in BENCHMARK["per_layer"][39:46]  # appended, nothing moved
     serve = next(m for m in BENCHMARK["end_to_end"]
                  if m["name"] == "serve_tokens_per_s")
-    assert serve["workloads"][-1] == FLASH_CELL
-    assert BENCHMARK["workloads"][-1]["name"] == FLASH_CELL
-    assert BENCHMARK["workloads"][-1]["chips"] == 1
+    assert FLASH_CELL in serve["workloads"]
+    assert next(w for w in BENCHMARK["workloads"]
+                if w["name"] == FLASH_CELL)["chips"] == 1
     # the engine-wide metrics whose readers find something there; neither
     # count of the paged kernel's bytes holds for a shared cache
     listed = {m["name"] for m in BENCHMARK["per_layer"]
@@ -1492,3 +1498,181 @@ def test_the_hybrid_decoders_counts_are_the_arithmetic(bench):
         == 8 * 2800 + 8 * (100 + 512)
     assert counts_diff_attn.decode_bytes(1, 20, 64, itemsize=2) == 5120
     assert counts_diff_attn.decode_flops(1, 40, 64) == 6 * 40 * 64
+
+
+# -- 11. gated attention FIRST, 64-head KDA with beta to 2, no dense layer (PR 49) --
+
+# what tells such a model apart on its ``serve.engine`` event, and who reads it
+GATED_FIELDS = {
+    "linear_write_max": "obs/report.py (the state line: beta up to)",
+    "attn_gate": "obs/report.py (the attention line)",
+    "dense_layers": "obs/report.py (the experts line: in EVERY layer)",
+    "linear_mixer": "obs/report.py (the state line: whose the decay is)",
+    "state_bytes_linear": "metrics/state_pool_gib.py:12,15,19",
+    "conv_bytes_linear": "metrics/state_pool_gib.py:16,19",
+    "kv_bytes_full": "metrics/kv_pool_gib.py:10,13,16",
+    "layer_kinds": "metrics/kv_pool_gib.py:15",
+}
+
+
+@pytest.fixture(scope="module")
+def gated(bench):
+    """A model of a gated ``full_attention`` layer and three KDA layers
+    whose beta reaches 2, an expert FFN in every one: K/V pages AND state
+    rows."""
+    return _serve(bench, _cell_of("solar-open2-250b-ep8"))
+
+
+@pytest.mark.parametrize("field", sorted(GATED_FIELDS))
+def test_serve_engine_of_a_gated_hybrid_carries_its_counters(gated, field):
+    ev = gated["record"]["serve_engine"]
+    assert ev is not None and ev.get(field) is not None, (
+        f"serve.engine has no {field!r}; read by " + GATED_FIELDS[field])
+
+
+def test_serve_engine_tells_the_gated_hybrid_apart(gated, mixed, hybrid,
+                                                   flash, dense):
+    """The write strength's upper end, the gate on the softmax layers and
+    the count of dense layers, in fields of their own: ``linear_mixer``
+    stays the two-element list it was, the same on both KDA models."""
+    ev, eng = gated["record"]["serve_engine"], gated["eng"]
+    kinds = list(gated["record"]["model_keys"]["layer_types"])
+    assert kinds[0] == "full_attention" and kinds.count(
+        "linear_attention") == 3
+    assert (ev["linear_write_max"], ev["attn_gate"], ev["dense_layers"]) \
+        == (2, True, 0)
+    assert ev["linear_mixer"] == ["gated_delta", "channel"] \
+        == mixed["record"]["serve_engine"]["linear_mixer"]
+    assert ev["attention_form"] == "softmax" and ev["kv_bytes_latent"] == 0
+    other = mixed["record"]["serve_engine"]
+    assert (other["linear_write_max"], other["attn_gate"],
+            other["dense_layers"]) == (1, False, 1)
+    # the scalar rule's model doubles beta too; a model without a linear
+    # layer has no write strength; one scanned layer kind is all dense
+    assert hybrid["record"]["serve_engine"]["linear_write_max"] == 2
+    assert flash["record"]["serve_engine"]["linear_write_max"] is None
+    assert dense["record"]["serve_engine"]["dense_layers"] \
+        == dense["record"]["model_keys"]["n_layers"]
+    # K/V pages of ONE layer and state rows of three in one pool
+    assert (eng.pool.n_full, eng.pool.state.count(True)) == (1, 3)
+    assert ev["kv_bytes_full"] == eng.pool.bytes_per_block \
+        * eng.pool.num_blocks > 0
+    rows = [s["state_rows"] for s in _steps(gated) if "state_rows" in s]
+    assert rows and all(r % 3 == 0 and r > 0 for r in rows)
+    # every plan entry an expert FFN: a decode step's pairs over four layers
+    assert gated["eng"].cfg.n_expert_layers == len(kinds)
+
+
+@pytest.mark.parametrize("field", ["linear_write_max", "attn_gate",
+                                   "dense_layers"])
+def test_the_gated_hybrids_fields_are_in_the_schema(field):
+    from torch_automatic_distributed_neural_network_tpu.obs import schema
+
+    with open(schema.__file__) as f:
+        assert f'"{field}"' in f.read()
+
+
+def test_the_report_tells_the_gated_hybrid_apart(gated, mixed, tmp_path):
+    from torch_automatic_distributed_neural_network_tpu.obs import (
+        report as obs_report,
+    )
+
+    def text_of(run, name):
+        path = tmp_path / name
+        path.write_text("".join(json.dumps(r) + "\n"
+                                for r in run["journal"].records))
+        return obs_report.format_report(obs_report.generate(str(path)))
+
+    text = text_of(gated, "gated.jsonl")
+    assert "gated_delta: a decay a channel, beta up to 2" in text
+    assert "attention: a sigmoid gate on its output" in text
+    assert "experts 4 held of 16 in EVERY layer (no dense FFN)" in text
+    text = text_of(mixed, "mixed.jsonl")
+    assert "gated_delta: a decay a channel, beta up to 1" in text
+    assert "sigmoid gate" not in text and "EVERY layer" not in text
+
+
+def test_the_gated_hybrids_cell_is_appended_and_listed():
+    """One configuration and one cell, the last of their lists; the cell on
+    every reader that finds a number there on the chip, each list's last."""
+    assert BENCHMARK["workloads"][-1] == {
+        **BENCHMARK["workloads"][-1], "name": GATED_CELL,
+        "config": "solar-open2-250b-ep8",
+        "traffic": "serve-backlog-reasoning-s128", "chips": 1}
+    assert BENCHMARK["configs"][-1]["name"] == "solar-open2-250b-ep8"
+    assert BENCHMARK["configs"][-1]["reduced"] == [
+        "num_hidden_layers", "gqa_layers", "n_routed_experts", "vocab_size"]
+    serve = next(m for m in BENCHMARK["end_to_end"]
+                 if m["name"] == "serve_tokens_per_s")
+    assert serve["workloads"][-1] == GATED_CELL
+    listed = {m["name"] for m in BENCHMARK["per_layer"]
+              if GATED_CELL in m.get("workloads", ())}
+    assert listed == set(KDA_METRICS) | {
+        "slot_occupancy", "decode_step_ms.backlog", "device_idle_share.serve",
+        "prefill_chunk_device_ms.backlog", "serve_host_ms.backlog",
+        "serve_stall_ms.backlog", "chunk_call_ms.backlog",
+        "decode_call_ms.backlog", "kv_pool_gib", "state_pool_gib",
+        "moe_grouped_mm_chunk_ms", "paged_attn_roofline.by_kind"}
+    for m in BENCHMARK["per_layer"]:
+        if m["name"] in listed:
+            assert m["workloads"][-1] == GATED_CELL, m["name"]
+    # the traffic is the other reasoning mix's, letter for letter, at 128
+    # slots and twice the list
+    mine, theirs = (MIXES[t] for t in ("serve-backlog-reasoning-s128",
+                                       "serve-backlog-reasoning"))
+    assert mine["lengths"] == theirs["lengths"]
+    assert mine["traffic_seed"] == theirs["traffic_seed"] == 1442695040
+    assert {**theirs["engine"], "n_slots": 128, "num_blocks": 8601} \
+        == mine["engine"]
+    assert mine["arrivals"] == {"kind": "backlog", "requests_per_second": 16}
+
+
+@pytest.mark.parametrize("name", KDA_METRICS + (
+    "paged_attn_roofline.by_kind", "moe_grouped_mm_chunk_ms"))
+def test_a_device_reader_finds_nothing_on_the_gated_hybrid_off_the_chip(
+        bench, gated, name, capsys):
+    """No device trace on the CPU, and a trace of no device: ``None``, and
+    no raise (what the parent's program gives the driver's traced runs)."""
+    reader = _load(os.path.join(BENCH, "metrics", name + ".py"),
+                   "bench_metric")
+    assert reader.read(gated["record"]) is None
+    assert reader.read({**gated["record"], "trace": {"n_devices": 0}}) is None
+    capsys.readouterr()
+
+
+def test_the_events_readers_give_numbers_on_the_gated_hybrid(bench, gated):
+    rec, pool = gated["record"], gated["eng"].pool
+    read = lambda name: _load(os.path.join(  # noqa: E731
+        BENCH, "metrics", name + ".py"), "bench_metric").read(rec)
+    assert read("kv_pool_gib") == pytest.approx(pool.bytes_full / 2**30)
+    assert read("state_pool_gib") == pytest.approx(
+        sum(pool.bytes_state) / 2**30)
+    assert 0 < read("slot_occupancy") <= 100
+
+
+def test_the_kda_counts_at_the_second_shape_are_the_arithmetic(bench):
+    """The count functions take the shape from the configuration: 3 layers
+    of 64 heads of 128 x 128; a decode row moves 8.5 MB of state a layer
+    (twice the other KDA model's) and is bound by it; a decode token at
+    context c reads c keys and values of 8 heads of 128 in the ONE
+    attention layer, 4,096 B a key."""
+    from lib import counts_gdn, counts_kda, counts_moe
+
+    keys = CONFIGS["solar-open2-250b-ep8"]["model"]
+    assert counts_gdn.linear_layers(keys) == (3, 64, 128, 128)
+    per_token = 64 * (4 * 128 * 2 + 128 * 4 + 4)
+    state = 2 * 64 * 128 * 128 * 4
+    assert counts_kda.recurrence_bytes(1, 1, 64, 128, 128, itemsize=2) \
+        == per_token + state == 98_560 + 8_388_608
+    assert counts_kda.recurrence_flops(1, 64, 128, 128) == 7 * 64 * 128 * 128
+    assert counts_kda.recurrence_flops(1, 64, 128, 128) / (
+        per_token + state) < 1
+    kinds = list(keys["layer_types"])
+    flops, moved = counts_moe.paged_attention_by_kind(
+        [1000], n_full=kinds.count("full_attention"),
+        n_window=kinds.count("sliding_attention"), window=None,
+        heads=keys["n_heads"], kv_heads=keys["n_kv_heads"],
+        head_dim=keys["head_size"], itemsize=2)
+    assert (flops, moved) == (4 * 1000 * 64 * 128, 1000 * 4096)
+    assert counts_moe.grouped_mm_bytes(40, 4096, 1280, itemsize=2) \
+        == 40 * 3 * 4096 * 1280 * 2

@@ -29,14 +29,15 @@ _SERVING = {"gpt2-1p3b": (8, 1024, 16, 128, None),
             "joyai-llm-flash-ep8": (24, 34816, 64, 512, 4097),
             "longcat-flash-omni-ep32": (24, 34816, 64, 512, 4097),
             "kimi-linear-48b-ep8": (96, 36864, 64, 512, 6145),
-            "phi4-mini-flash-3p8b": (64, 34816, 64, 512, 6145)}
+            "phi4-mini-flash-3p8b": (64, 34816, 64, 512, 6145),
+            "solar-open2-250b-ep8": (128, 36864, 64, 512, 8601)}
 
 
 # the chunk alone is a program of the speculative and the tenant engines: a
 # configuration that refuses both (``lora_spec`` with ``layer_types``,
 # ``speculative`` with linear layers) has no engine that runs it
 _NO_CHUNK_ALONE = ("olmo-hybrid-7b-pp2", "kimi-linear-48b-ep8",
-                   "phi4-mini-flash-3p8b")
+                   "phi4-mini-flash-3p8b", "solar-open2-250b-ep8")
 
 
 def cases(configs) -> dict:
@@ -177,6 +178,8 @@ def serving_program_updates_the_pool_in_place(
               "prefill_chunk": {"tadnn.attend_step"}}.get(program, set())
     if not cfg.n_expert_layers:
         absent.add("tadnn.ffn_expert")
+    elif cfg.n_dense_layers == 0:  # an expert FFN in EVERY layer
+        absent.add("tadnn.ffn")
     assert scoped == set(programs.SCOPES) - absent
     latent = "latent_attention" in (cfg.layer_types or ())
     mine, other = (("tadnn_paged_decode_latent", "tadnn_paged_decode_folded")
@@ -201,8 +204,12 @@ def serving_program_updates_the_pool_in_place(
     # layers: 0.31 GiB in the chunk that carries the rows, 0.51 while
     # ``kda_products`` ran before the kernel; a copy of ONE state pool would
     # be 0.19 more)
-    roomy = {"olmo-hybrid-7b-pp2": 0.25,
-             "kimi-linear-48b-ep8": 0.35}.get(config, 0.2)
+    # (solar: 128 + 512 rows at d 4,096; 0.36 GiB in the step and in the
+    # chunk alike, most of it 64 MB projections that the compiler stages
+    # through on-chip memory, ``S(1)``, ahead of their products; a copy of
+    # ONE state pool would be 0.50 more and of the K/V pages 1.05)
+    roomy = {"olmo-hybrid-7b-pp2": 0.25, "kimi-linear-48b-ep8": 0.35,
+             "solar-open2-250b-ep8": 0.45}.get(config, 0.2)
     assert mem.temp_size_in_bytes < roomy * 2**30, mem.temp_size_in_bytes
     page_arrays = {("f32" if x.dtype == jnp.float32 else "bf16")
                    + "[%s]" % ",".join(map(str, x.shape))
@@ -212,7 +219,9 @@ def serving_program_updates_the_pool_in_place(
     # anew behind their scatter: 0.3 ms a call over 12 layers, PERF.md
     # section 7; its 203 MB state pool it does not copy: Step 0 of ISSUE 41)
     # (a state-space layer's 2 MB of tails at 65 rows likewise: 9 layers)
-    staged = {"bf16[97,3,12288]", "bf16[65,3,5120]"} & page_arrays
+    # (64-head KDA layers' 19 MB of tails at 129 rows likewise: 3 layers)
+    staged = {"bf16[97,3,12288]", "bf16[65,3,5120]",
+              "bf16[129,3,24576]"} & page_arrays
     assert not [l[:100] for l in text.splitlines()
                 if " copy(" in l and any(a in l for a in page_arrays - staged)]
     if cfg.n_expert_layers:
@@ -240,13 +249,13 @@ def serving_program_updates_the_pool_in_place(
             assert len(re.findall(
                 r"^\s*%tadnn_moe_grouped_mm_" + kernel + r"[.\d]* = ", text,
                 re.M)) == cfg.n_expert_layers
+    count = lambda name: len(re.findall(  # noqa: E731: a kernel's calls
+        r"^\s*%" + name + r"[.\d]* = ", text, re.M))
     if config == "phi4-mini-flash-3p8b":
         # 9 state-space layers: the step kernel wherever rows decode, the
         # chunk kernel wherever a chunk runs; 16 attention layers: ONE
         # folded decode call each (the differential pair costs no second
         # read of a page), on 9 sets of pages
-        count = lambda name: len(re.findall(  # noqa: E731
-            r"^\s*%" + name + r"[.\d]* = ", text, re.M))
         assert count("tadnn_ssm_step") == 9
         assert count("tadnn_ssm_chunk") == 9 * (program != "decode_step")
         assert count("tadnn_paged_decode_folded") == 16
@@ -315,6 +324,26 @@ def serving_program_updates_the_pool_in_place(
         assert round(sum(made["pool"].bytes_state) / 1e9, 2) == 2.53
         assert f"f32[{slots + 1},32,128,128]" in page_arrays
         assert mem.argument_size_in_bytes < 12.6 * 2**30
+    elif config == "solar-open2-250b-ep8":
+        # 3 KDA layers of 64 heads and ONE attention layer, the first: the
+        # KDA kernels as above, one folded decode call, no latent kernel,
+        # and a pair of grouped matmuls in EVERY layer (no dense FFN)
+        assert count("tadnn_kda_step") == 3
+        assert count("tadnn_kda_chunk") == 3 * (program != "decode_step")
+        assert count("tadnn_paged_decode_folded") == 1
+        assert "tadnn_gdn" not in text and "tadnn_latent_chunk" not in text
+        assert cfg.n_expert_layers == cfg.n_layers == 4
+        assert not re.search(r"f32\[[\d,]*16,16,128\]", text)
+        # the pool: 2.25 GB of K/V pages in the one attention layer (4,096
+        # B a token), 1.68 GB of states and tails (13.0 MB a slot)
+        pool = made["pool"]
+        assert (pool.n_full, pool.state.count(True)) == (1, 3)
+        assert pool.bytes_per_block == 64 * 4096
+        assert round(pool.bytes_full / 1e9, 2) == 2.25
+        assert round(sum(pool.bytes_state) / 1e9, 2) == 1.68
+        assert round(sum(pool.bytes_state) / (slots + 1) / 1e6, 1) == 13.0
+        assert f"f32[{slots + 1},64,128,128]" in page_arrays
+        assert mem.argument_size_in_bytes < 10.3 * 2**30
     elif latent:
         # the latent layers' kernel calls (none in the chunk alone: 20, or
         # 8 sublayers), the grouped matmuls of the expert layers (19, or 4

@@ -408,7 +408,8 @@ def test_the_configurations_in_the_benchmark_keep_their_trees_and_plans():
             {name: sorted(tree[name]) for name, *_ in plan})
     old = {"trinity-large-ep8": 5, "olmo-hybrid-7b-pp2": 16,
            "joyai-llm-flash-ep8": 20, "kimi-linear-48b-ep8": 16,
-           "phi4-mini-flash-3p8b": 32}  # PR 46: no branch either
+           "phi4-mini-flash-3p8b": 32,  # PR 46: no branch either
+           "solar-open2-250b-ep8": 4}  # PR 49: nor here
     assert set(seen) == set(old) | {"longcat-flash-omni-ep32"}
     plan, trees = seen["kimi-linear-48b-ep8"]  # PR 41: no branch either
     assert plan == [("latent_attention" if i % 4 == 3 else "linear_attention",
@@ -419,6 +420,9 @@ def test_the_configurations_in_the_benchmark_keep_their_trees_and_plans():
         plan, trees = seen[name]
         assert len(plan) == n and all(b is None for _, _, b in plan)
         assert all("moe" not in t for t in trees.values())
+    plan, _ = seen["solar-open2-250b-ep8"]  # EVERY entry an expert FFN
+    assert plan == [("linear_attention" if i else "full_attention", True,
+                     None) for i in range(4)]
     plan, trees = seen["joyai-llm-flash-ep8"]
     assert plan == [("latent_attention", i >= 1, None) for i in range(20)]
     assert trees["layers_1"] == ["attn", "attn_norm", "mlp", "mlp_norm"]

@@ -228,6 +228,8 @@ _SHAPES = {
     "olmo-hybrid-7b-pp2": (2, 16, 30 * 128, 2112, None, (8, 264)),
     "phi4-mini-flash-3p8b.full": (2, 64, 20 * 64, 544, None, (8, 68)),
     "phi4-mini-flash-3p8b.window512": (2, 64, 20 * 64, 544, 512, (5, 2)),
+    # a page pair of 262 KB: the least item, 72 of them under 576 entries
+    "solar-open2-250b-ep8": (2, 64, 8 * 128, 576, None, (8, 72)),
 }
 
 

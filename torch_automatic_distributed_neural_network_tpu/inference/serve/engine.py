@@ -587,11 +587,23 @@ class ServeEngine:
             # is: a head's or a channel's] (None without such a layer)
             linear_mixer=(["gated_delta", self.cfg.linear_decay]
                           if "linear_attention" in kinds else None),
+            # the upper end of that rule's write strength beta: 2 where the
+            # state's transition may have an eigenvalue down to -1
+            # (``linear_neg_eigval``), else 1 (None without such a layer)
+            linear_write_max=((2 if self.cfg.linear_neg_eigval else 1)
+                              if "linear_attention" in kinds else None),
             # the form the attention layers run: "differential" (adjacent
             # head pairs, two softmaxes subtracted, over values twice a
             # key's width; its decode reads a folded page once, the MXU
             # kernel at another wiring) or "softmax"
             attention_form=("differential" if diff else "softmax"),
+            # whether a sigmoid gate read from the layer's input multiplies
+            # the attention layers' output before its projection
+            attn_gate=self.cfg.attn_gate,
+            # the layers whose FFN is dense: 0 where every layer has the
+            # expert FFN in its place
+            dense_layers=sum(not sparse for _, _, sparse, _
+                             in layer_plan(self.cfg)),
             # a decoder-hybrid-decoder's layout: the first layer of the
             # run of layers that keep no cache (a chunk's rows but one
             # stop before it), the layers that hold paged keys and values

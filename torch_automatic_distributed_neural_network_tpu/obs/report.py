@@ -451,9 +451,9 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                 "layer_kinds", "experts_held", "experts_published",
                 "zero_experts", "shortcut_experts", "kv_bytes_full",
                 "kv_bytes_window", "state_bytes_linear", "conv_bytes_linear",
-                "linear_mixer", "kv_bytes_latent", "latent_row",
-                "attention_form", "cross_start", "paged_sets",
-                "shared_readers")},
+                "linear_mixer", "linear_write_max", "kv_bytes_latent",
+                "latent_row", "attention_form", "attn_gate", "dense_layers",
+                "cross_start", "paged_sets", "shared_readers")},
             # rows that ran the cross-decoder over rows that ran the layers
             # before it (``read.cross_rows`` / ``read.self_rows``)
             "cross_rows_share": _ratio(
@@ -1149,6 +1149,8 @@ def format_report(report: dict) -> str:
                         if kind in kinds)
                     + (", {0}: a decay a {1}".format(*sv["linear_mixer"])
                        if sv.get("linear_mixer") else "")
+                    + (f", beta up to {sv['linear_write_max']}"
+                       if sv.get("linear_write_max") else "")
                     + (f", {sv['mean_state_rows']:.1f} state rows a call"
                        if sv.get("mean_state_rows") is not None else ""))
             if sv.get("cross_start") is not None:
@@ -1165,10 +1167,14 @@ def format_report(report: dict) -> str:
             if sv.get("attention_form") == "differential":
                 eparts.append("attention: differential (pairs of heads, two "
                               "softmaxes subtracted, values twice as wide)")
+            if sv.get("attn_gate"):
+                eparts.append("attention: a sigmoid gate on its output")
             if sv.get("experts_published"):
                 eparts.append(
                     f"experts {sv['experts_held']} held of "
                     f"{sv['experts_published']}"
+                    + (" in EVERY layer (no dense FFN)"
+                       if sv.get("dense_layers") == 0 else "")
                     + (f" + {sv['zero_experts']} zero-compute"
                        if sv.get("zero_experts") else "")
                     + (" (a shortcut branch over two sublayers)"

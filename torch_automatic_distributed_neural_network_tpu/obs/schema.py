@@ -347,6 +347,9 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # which rule those layers run: [the rule, "head" or "channel":
             # whose the decay is] (None without a linear layer)
             "linear_mixer": "list?",
+            # the upper end of that rule's write strength beta: 1, or 2
+            # where beta = 2 sigmoid(.) (None without a linear layer)
+            "linear_write_max": "int?",
             # what of kv_bytes_full the latent_attention layers' pages hold
             # (one row a token, key and value at once), and that row:
             # [the latent's rank, the rotated key part, numbers stored]
@@ -361,6 +364,10 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # and those that read another layer's (state_bytes_linear and
             # conv_bytes_linear count a state_space layer's rows too)
             "attention_form": "str", "cross_start": "int?",
+            # whether a sigmoid gate multiplies the attention layers'
+            # output, and the layers whose FFN is dense (0: an expert FFN
+            # in every layer)
+            "attn_gate": "bool", "dense_layers": "int",
             "paged_sets": "int?", "shared_readers": "int?",
             # the row tiles the expert layers of a call lay out, whatever
             # lands in them: [a call with a chunk, a decode-only call],
