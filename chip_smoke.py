@@ -585,6 +585,8 @@ def kda_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:
     for r in (0, S + 1):  # the null row and a row no slot has
         if not bool(jnp.array_equal(p1[r], pool0[r])):
             raise RuntimeError(f"kda step: row {r} was written")
+    if bool(jnp.any(o[~live])):  # the kernel walks the live slots alone
+        raise RuntimeError("kda step: an idle slot's output is not zero")
 
 
 def scan_kernel_checks(rec: dict, close, key, on_chip: bool) -> None:

@@ -40,6 +40,38 @@ _NO_CHUNK_ALONE = ("olmo-hybrid-7b-pp2", "kimi-linear-48b-ep8",
                    "phi4-mini-flash-3p8b", "solar-open2-250b-ep8")
 
 
+def kda_step_takes_its_operands_as_they_lie(text: str, slots: int,
+                                            heads: int, layers: int) -> None:
+    """``tadnn_kda_step`` in a compiled serving program (PR 51): its keys,
+    queries, decays and values go in as the projections leave them,
+    ``f32[slots, heads, 128]``, so the program holds no array relaid for it
+    (before: a layer's three ``f32[S, G, 8, 128]{2,3,1,0}`` copies,
+    physically ``[S, G, 128, 8]`` and fifteen sixteenths padding, and a
+    stacked ``f32[S, G, 24, 128]``), no operand of the call has a minor axis
+    of 8, and the state pool is still the call's aliased operand in HBM."""
+    import re
+
+    G = heads // 8
+    gone = [rf"f32\[{slots},{G},8,128\]\{{2,3,1,0", rf"f32\[{slots},{G},128,8\]",
+            rf"f32\[{slots},{G},24,128\]"]
+    assert not [l.strip()[:160] for l in text.splitlines()
+                if any(re.search(a, l) for a in gone)]
+    calls = [l for l in text.splitlines()
+             if re.match(r"\s*%tadnn_kda_step[.\d]* = ", l)]
+    assert len(calls) == layers
+    pool = f"f32[{slots + 1},{heads},128,128]"
+    for call in calls:
+        given = re.search(r"operand_layout_constraints=\{(.*?)\}\}", call)
+        shapes = re.findall(r"\w+\[([\d,]*)\]", given.group(1))
+        assert shapes and not [x for x in shapes if x.endswith(",8")], shapes
+        assert given.group(1).count(f"f32[{slots},{heads},128]") == 4
+        assert given.group(1).endswith(pool + "{3,2,1,0")  # the last
+        # the pool in, the pool out: aliased, in HBM (no ``S(1)``)
+        assert re.search(re.escape(pool) + r"\{3,2,1,0:T\(8,128\)\}\) custom-call",
+                         call), call[:300]
+        assert "output_to_operand_aliasing" in call
+
+
 def cases(configs) -> dict:
     """``pytest.mark.parametrize``'s arguments: the programs an engine of
     each of ``configs`` can run."""
@@ -324,6 +356,8 @@ def serving_program_updates_the_pool_in_place(
         assert round(sum(made["pool"].bytes_state) / 1e9, 2) == 2.53
         assert f"f32[{slots + 1},32,128,128]" in page_arrays
         assert mem.argument_size_in_bytes < 12.6 * 2**30
+        if program != "prefill_chunk":
+            kda_step_takes_its_operands_as_they_lie(text, slots, 32, 12)
     elif config == "solar-open2-250b-ep8":
         # 3 KDA layers of 64 heads and ONE attention layer, the first: the
         # KDA kernels as above, one folded decode call, no latent kernel,
@@ -344,6 +378,7 @@ def serving_program_updates_the_pool_in_place(
         assert round(sum(pool.bytes_state) / (slots + 1) / 1e6, 1) == 13.0
         assert f"f32[{slots + 1},64,128,128]" in page_arrays
         assert mem.argument_size_in_bytes < 10.3 * 2**30
+        kda_step_takes_its_operands_as_they_lie(text, slots, 64, 3)
     elif latent:
         # the latent layers' kernel calls (none in the chunk alone: 20, or
         # 8 sublayers), the grouped matmuls of the expert layers (19, or 4

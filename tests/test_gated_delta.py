@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 import chip_smoke
 from torch_automatic_distributed_neural_network_tpu.ops import gated_delta as gd
@@ -252,6 +254,116 @@ def test_step_form_is_the_recurrence(form, heads, decay):
         np.testing.assert_allclose(pool[rows[s]], s_ref, **tol)
     for r in (0, 2, 5):
         np.testing.assert_array_equal(pool[r], pool0[r])
+
+
+def _parent_kda_step_kernel(rows_ref, kT_ref, qT_ref, aT_ref, row_ref, s_ref,
+                            o_ref, out_ref, *, heads: int):
+    del rows_ref
+    for i in range(heads):
+        kc, qc, ac = (ref[0, 0][:, i:i + 1]
+                      for ref in (kT_ref, qT_ref, aT_ref))
+        v, b, kq = (row_ref[0, 0, c * heads + i:c * heads + i + 1]
+                    for c in range(3))
+        S = ac * s_ref[0, i]
+        u = b * (v - jnp.sum(S * kc, axis=0, keepdims=True))
+        out_ref[0, i] = S + kc * u
+        o_ref[0, 0, i:i + 1] = jnp.sum(S * qc, axis=0, keepdims=True) + kq * u
+
+
+def parent_kda_step(q, k, v, g, beta, pool, rows):
+    """``tadnn_kda_step`` as it was before it took a work list (PR 49's
+    tree, to the letter, in the interpreter): grid (ALL slots, groups of
+    heads), a dead slot on the null row; keys, queries and decays relaid as
+    columns [S, G, d_k, hb], value, beta and k.q broadcast and stacked
+    [S, G, 3 hb, d_v].  The oracle of the kernel's bits."""
+    S, H, dk = k.shape
+    dv = v.shape[-1]
+    hb = gd._head_group(H)
+    G = H // hb
+    q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
+    cols = lambda x: jnp.swapaxes(x.reshape(S, G, hb, dk), -1, -2)
+    wide = lambda x: jnp.broadcast_to(x[..., None], (S, H, dv))
+    packed = jnp.stack([v, wide(beta), wide(jnp.sum(k * q, -1))], axis=2)
+    packed = jnp.swapaxes(packed.reshape(S, G, hb, 3, dv), 2, 3).reshape(
+        S, G, 3 * hb, dv)
+    col = pl.BlockSpec((1, 1, dk, hb), lambda s, j, r: (s, j, 0, 0))
+    row3 = pl.BlockSpec((1, 1, 3 * hb, dv), lambda s, j, r: (s, j, 0, 0))
+    st = pl.BlockSpec((1, hb, dk, dv), lambda s, j, r: (r[s], j, 0, 0))
+    o, pool = pl.pallas_call(
+        functools.partial(_parent_kda_step_kernel, heads=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S, G),
+            in_specs=[col, col, col, row3, st],
+            out_specs=[pl.BlockSpec((1, 1, hb, dv),
+                                    lambda s, j, r: (s, j, 0, 0)), st]),
+        out_shape=[jax.ShapeDtypeStruct((S, G, hb, dv), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, jnp.float32)],
+        input_output_aliases={5: 1},
+        interpret=True,
+    )(rows.astype(jnp.int32), cols(k), cols(q), cols(jnp.exp(g)), packed,
+      pool)
+    return o.reshape(S, H, dv), pool
+
+
+LIVE = {  # which of 12 slots decode
+    "all": range(12),
+    "five_scattered": (1, 4, 5, 8, 11),
+    "one": (7,),
+    "none": (),
+    "behind_dead": (9, 10),  # the list's first item is slot 9
+    "strong": (0, 3, 6),  # every beta in (1, 2)
+}
+
+
+@pytest.mark.parametrize("case", sorted(LIVE))
+@pytest.mark.parametrize("heads", [32, 64])
+def test_kda_step_walks_the_live_slots_alone(heads, case):
+    """``tadnn_kda_step`` over 12 slots of which ``LIVE[case]`` decode, at
+    the two served head counts (groups of 8: 4 and 8 a slot): the live
+    slots' rows and outputs are the plain form's within rounding AND the
+    parent's kernel's bit for bit (the same float32 operations in the same
+    order: only where the operands lie changed), every other row of the pool
+    is untouched bit for bit, the null row among them, whether one slot
+    decodes, none or all, and ``o`` of a slot that does not decode is zero.
+    The call is jitted: the grid's first axis is a traced number."""
+    S = 12
+    live = jnp.zeros((S,), bool).at[jnp.asarray(LIVE[case], jnp.int32)].set(
+        True)
+    rows = jnp.where(live, 1 + jnp.arange(S), 0).astype(jnp.int32)
+    harder = strong if case == "strong" else (lambda args: args)
+    q, k, v, g, beta, _ = harder(inputs(S, seed=31, heads=heads,
+                                        decay="channel"))
+    g, beta = rows_of(live, g), rows_of(live, beta)
+    if case == "strong":
+        assert bool(jnp.all(beta[live] > 1.0))
+    pool0 = jax.random.normal(jax.random.key(8), (S + 1, heads, DK, DV))
+    step = jax.jit(functools.partial(gd.kda_step_pallas, interpret=True))
+    o, pool = step(q, k, v, g, beta, pool0, rows)
+    o_par, pool_par = jax.jit(parent_kda_step)(q, k, v, g, beta, pool0, rows)
+    o_ref, pool_ref = gd.kda_step_xla(q, k, v, g, beta, pool0, rows)
+    on = np.asarray(live)
+    np.testing.assert_array_equal(np.asarray(o)[on], np.asarray(o_par)[on])
+    np.testing.assert_array_equal(pool, pool_par)
+    np.testing.assert_allclose(np.asarray(o)[on], np.asarray(o_ref)[on],
+                               **TOL)
+    np.testing.assert_allclose(pool, pool_ref, **TOL)
+    assert not np.asarray(o)[~on].any()
+    dead_rows = np.setdiff1d(np.arange(S + 1), np.asarray(rows)[on])
+    np.testing.assert_array_equal(np.asarray(pool)[dead_rows],
+                                  np.asarray(pool0)[dead_rows])
+    # the list a decode step hands the kernel is the one it derives
+    o_w, pool_w = step(q, k, v, g, beta, pool0, rows,
+                       work=gd.live_slots(live))
+    np.testing.assert_array_equal(o_w, o)
+    np.testing.assert_array_equal(pool_w, pool)
+
+
+def test_live_slots_lists_the_live_slots_first_in_slot_order():
+    work = gd.live_slots(jnp.asarray([0, 1, 0, 0, 1, 1, 0], bool))
+    assert work.order.tolist() == [1, 4, 5, 0, 2, 3, 6]
+    assert int(work.n_live) == 3 and work.order.dtype == jnp.int32
+    none = gd.live_slots(jnp.zeros((4,), bool))
+    assert none.order.tolist() == [0, 1, 2, 3] and int(none.n_live) == 0
 
 
 @pytest.mark.parametrize("decay", DECAYS)

@@ -56,6 +56,12 @@ def test_engine_serves_the_references_first_choice(option, tmp_path):
     # over the six linear layers
     counted = [s["state_rows"] for s in steps if "state_rows" in s]
     assert counted and all(n % 6 == 0 and 0 < n <= 18 for n in counted)
+    # and the rows those kernels walked, on the same calls: here, off the
+    # chip, the plain form's gather and scatter of every slot's row
+    assert [s["state_rows_walked"] for s in steps if "state_rows" in s] \
+        == [3 * 6] * len(counted)
+    assert not [s for s in steps
+                if "state_rows_walked" in s and "state_rows" not in s]
     if option != "reserve":
         return
     ev = journal.named("serve.engine")[-1]
@@ -81,7 +87,25 @@ def test_engine_serves_the_references_first_choice(option, tmp_path):
     assert "(2 latent layers)" in text
     assert "of recurrent state" in text and "(6 linear layers)" in text
     assert "gated_delta: a decay a channel" in text
-    assert "state rows a call" in text
+    share = sum(counted) / (18 * len(counted))
+    assert (f"state rows a call ({share:.0%} of the rows the step kernels "
+            f"walked)") in text
+
+
+@pytest.mark.parametrize("live,on_chip,walked", [
+    (5, True, 5), (0, True, 1), (12, True, 12), (5, False, 12),
+    (0, False, 12)])
+def test_the_kernel_walks_the_live_slots_and_the_plain_form_all(
+        monkeypatch, live, on_chip, walked):
+    """``state_rows_walked``'s count a layer of a decay a channel: on the
+    chip the kernel's grid, the live slots (one item where none decodes);
+    off it every slot's row."""
+    from torch_automatic_distributed_neural_network_tpu.ops import (
+        gated_delta as gd,
+    )
+
+    monkeypatch.setattr(gd, "_on_tpu", lambda: on_chip)
+    assert gd.step_rows_walked(live, 12) == walked
 
 
 def test_a_preempted_request_restarts_and_serves_the_same_tokens():

@@ -128,6 +128,7 @@ import numpy as np
 
 from ...models.transformer_core import TransformerConfig, layer_plan
 from ...obs import journal as _journal
+from ...ops.gated_delta import step_rows_walked
 from ...ops.paged_attention import latent_chunk_key_blocks, tensor_degree
 from ...parallel.expert import expert_tiles
 from ...training.lora import LoraSpec
@@ -260,6 +261,9 @@ class ServeEngine:
         kinds = self.cfg.layer_types or ()
         # the layers that keep a recurrent state, a row a slot
         self._n_linear = sum(kind in STATE_KINDS for kind in kinds)
+        # those of them whose decay is a channel's (Kimi Delta Attention)
+        self._n_kda = (kinds.count("linear_attention")
+                       if self.cfg.linear_decay == "channel" else 0)
         diff = self.cfg.diff_attention
         # what is not served, each with its reason (the message names the
         # option and the kind of layer)
@@ -1188,6 +1192,12 @@ class ServeEngine:
                     # (linear_attention, state_space): known
                     # here, so not one more number in every model's output
                     self._counters["state_rows"] = len(rows) * self._n_linear
+                    # and the rows those kernels walked for them: the
+                    # kernel of a decay a channel takes the step's list of
+                    # live slots, the others walk every slot
+                    self._counters["state_rows_walked"] = (
+                        self._n_kda * step_rows_walked(len(rows), self.n_slots)
+                        + (self._n_linear - self._n_kda) * self.n_slots)
         with self._phase("emit"):
             self._emit(tokens, first, rows, firsts, drafts)
 

@@ -124,7 +124,7 @@ from ...models.transformer_core import (
     make_norm,
     rope,
 )
-from ...ops.gated_delta import gated_delta_chunk, gated_delta_step
+from ...ops.gated_delta import gated_delta_chunk, gated_delta_step, live_slots
 from ...ops.ssm import ssm_chunk, ssm_step
 from ...training.lora import LoraSpec, merge_lora
 from ..decode import (
@@ -418,7 +418,9 @@ def _step_shared(cfg, kv, tables, win_tables, ctx_lens, active, adapter_ids,
     page with the null block wherever a slot has no key to read (past the
     newest key, and in a ring before the oldest its window still reaches:
     an item of the null block alone is skipped), the slots' rows of
-    a linear layer's state (the null row for a slot that does not decode)
+    a linear layer's state (the null row for a slot that does not decode),
+    where its decay is a channel's the list of the slots that decode, which
+    is all that step kernel walks (``live_slots``),
     and the MXU kernels' grid, the (slot, first page) items: one list a
     kind of table, built here once a step and not in every layer's call,
     its geometry from the pages' own shape (``item_pages``).  Returns
@@ -443,6 +445,8 @@ def _step_shared(cfg, kv, tables, win_tables, ctx_lens, active, adapter_ids,
     if win_tables.shape[1]:
         lo = (ctx_lens - cfg.sliding_window + 1) // bs
         shared["tables"]["ring"] = ring_table(win_tables, MB, lo, hi)
+    if cfg.linear_decay == "channel":  # (the configuration has such layers)
+        shared["live"] = live_slots(active)
     kinds = page_readers(cfg)
     grid = jnp.zeros((4,), jnp.int32)
     if attention_impl == "paged" and T == 1 and paged and is_folded(pages0):
@@ -558,7 +562,7 @@ def _step_state(shared, state, tails, convolve, pre, g, beta):
     tails = tails.at[rows].set(full[:, 1:].astype(tails.dtype))
     o, state = gated_delta_step(
         q[:, 0], k[:, 0], v[:, 0], _where_rows(live, g[:, 0]),
-        _where_rows(live, beta[:, 0]), state, rows)
+        _where_rows(live, beta[:, 0]), state, rows, work=shared.get("live"))
     return o[:, None], state, tails
 
 

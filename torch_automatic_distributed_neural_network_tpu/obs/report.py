@@ -463,6 +463,11 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
                     for e in ssteps)),
             # the state rows a call's step kernels read and wrote
             "mean_state_rows": _mean(e.get("state_rows") for e in ssteps),
+            # of the rows those kernels walked, the decoding slots' share
+            "state_rows_live_share": _ratio(
+                sum(e.get("state_rows", 0) for e in ssteps
+                    if "state_rows_walked" in e),
+                sum(e.get("state_rows_walked", 0) for e in ssteps)),
             # the decode steps' expert counters (engines with experts)
             "mean_moe_pairs": _mean(e.get("moe_pairs") for e in ssteps),
             "mean_moe_experts_touched": _mean(
@@ -1152,7 +1157,11 @@ def format_report(report: dict) -> str:
                     + (f", beta up to {sv['linear_write_max']}"
                        if sv.get("linear_write_max") else "")
                     + (f", {sv['mean_state_rows']:.1f} state rows a call"
-                       if sv.get("mean_state_rows") is not None else ""))
+                       if sv.get("mean_state_rows") is not None else "")
+                    + (f" ({sv['state_rows_live_share']:.0%} of the rows "
+                       f"the step kernels walked)"
+                       if sv.get("state_rows_live_share") is not None
+                       else ""))
             if sv.get("cross_start") is not None:
                 eparts.append(
                     f"cross-decoder from layer {sv['cross_start']}: "

@@ -465,6 +465,12 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # state_space: decode rows x those layers); with the step's
             # tokens, on a model with such layers alone
             "state_rows": "int",
+            # the rows of the state pools those kernels walked for them,
+            # summed over the same layers: a kernel that takes the step's
+            # list of live slots (a decay a channel, on the chip) walks the
+            # decoding slots' alone, every other form one a slot whoever
+            # decodes; beside ``state_rows``, on the same calls
+            "state_rows_walked": "int",
             # the grid steps the decode step's paged attention calls ran
             # (the live (slot, key group) items of the folded kernel's
             # work list) and the slots x groups a dense grid would have

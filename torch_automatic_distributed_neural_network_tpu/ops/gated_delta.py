@@ -46,6 +46,9 @@ exp(Gamma_t[c] - Gamma_j[c])`` (and ``P`` with ``q``) has to be formed with
 no exponential of a positive number, and the chip gets kernels of its own,
 ``tadnn_kda_chunk`` and ``tadnn_kda_step`` (:func:`kda_chunk_pallas`,
 :func:`kda_step_pallas`); the scalar rule's kernels stay as they are.
+The step kernel of a decay a channel walks the slots that decode and no
+other (a work list, :func:`live_slots`, is its grid) and takes its
+operands as the projections leave them.
 
 - On the chip ONE kernel a layer a chunk does all of it
   (``_kda_chunk_kernel``): it reads ``q, k, v, g, beta`` as the mixer's
@@ -85,6 +88,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -629,6 +633,23 @@ def kda_chunk_pallas(q, k, v, g, beta, state, *, interpret: bool = False):
 # -- the step form ---------------------------------------------------------------
 
 
+class LiveSlots(NamedTuple):
+    """A decode step's work list for a step kernel that walks the slots
+    that decode and no other: ``order`` [S] the live slots first, in slot
+    order, then the rest; ``n_live`` how many; ``live`` [S] which."""
+
+    order: jax.Array
+    n_live: jax.Array
+    live: jax.Array
+
+
+def live_slots(live: jax.Array) -> LiveSlots:
+    """The work list of a step in which the slots ``live`` [S] decode."""
+    live = live.astype(bool)
+    return LiveSlots(jnp.argsort(~live, stable=True).astype(jnp.int32),
+                     jnp.sum(live, dtype=jnp.int32), live)
+
+
 def gated_delta_step_xla(q, k, v, g, beta, pool, rows):
     """One token a slot in plain ``jax.numpy``: ``q, k`` [S, H, d_k], ``v``
     [S, H, d_v], ``g, beta`` [S, H]; slot ``s`` reads and writes row
@@ -705,19 +726,33 @@ def gated_delta_step_pallas(q, k, v, g, beta, pool, rows, *,
     return o.reshape(S, H, dv), pool
 
 
-def gated_delta_step(q, k, v, g, beta, pool, rows):
+def gated_delta_step(q, k, v, g, beta, pool, rows, *,
+                     work: LiveSlots | None = None):
     """One decode token a slot against the pool of states, in place:
     ``(o [S, H, d_v] float32, pool)``; the decay a head's (``g`` [S, H]) or
     a channel's (``g`` [S, H, d_k]).  Row 0 is the null row of the
     slots that do not decode: with ``beta = 0`` and ``g = 0`` they leave it
-    as it was.  (On a v5e the compiler stages a pool of the scalar rule's
-    size through on-chip memory round the call, in copies of its own: the
-    kernel's time in a trace does not hold its HBM traffic.)"""
+    as it was.  ``work`` is the step's list of the slots that decode
+    (``live_slots``, built once a step): the kernel of a decay a channel
+    walks those alone, the other forms every slot and take no list.  (On a
+    v5e the compiler stages a pool of the scalar rule's size through on-chip
+    memory round the call, in copies of its own: the kernel's time in a
+    trace does not hold its HBM traffic.)"""
+    if g.ndim == 3 and _on_tpu():
+        return kda_step_pallas(q, k, v, g, beta, pool, rows, work=work)
     if g.ndim == 3:
-        form = kda_step_pallas if _on_tpu() else kda_step_xla
+        form = kda_step_xla
     else:
         form = gated_delta_step_pallas if _on_tpu() else gated_delta_step_xla
     return form(q, k, v, g, beta, pool, rows)
+
+
+def step_rows_walked(live: int, slots: int) -> int:
+    """The rows of its pool a call of :func:`gated_delta_step` with a decay
+    a channel walks when ``live`` of ``slots`` slots decode: the kernel the
+    live ones (one item where there is none), the plain form every slot's
+    (its gather and scatter).  For the engine's ``state_rows_walked``."""
+    return max(live, 1) if _on_tpu() else slots
 
 
 # -- the step form, a decay a channel ---------------------------------------------
@@ -735,58 +770,72 @@ def kda_step_xla(q, k, v, g, beta, pool, rows):
     return o, pool.at[rows].set(S)
 
 
-def _kda_step_kernel(rows_ref, kT_ref, qT_ref, aT_ref, row_ref, s_ref, o_ref,
-                     out_ref, *, heads: int):
-    """A group of ``heads`` heads of one slot, on the VPU, as
-    ``_step_kernel``: a head's key, query AND decay as columns [d_k, 1], its
-    value, beta and k.q as rows [1, d_v] (rows ``c * heads + i`` of the
-    packed operand)."""
+def _kda_step_kernel(order_ref, rows_ref, beta_ref, kq_ref, k_ref, q_ref, a_ref,
+                     v_ref, s_ref, o_ref, out_ref, *, heads: int, H: int):
+    """A group of ``heads`` heads of one live slot, on the VPU, as
+    ``_step_kernel``: the group's keys, queries and decays arrive as they
+    lie, a tile [heads, d_k] each, and are turned here, the three stacked
+    and transposed as one, so that a head's is a column [d_k, 1]; its value
+    a row [1, d_v]; its beta and k.q two numbers of scalar memory."""
     del rows_ref
+    at = order_ref[pl.program_id(0)] * H + pl.program_id(1) * heads
+    cols = jnp.concatenate([k_ref[0], q_ref[0], a_ref[0]]).T  # [d_k, 3 heads]
     for i in range(heads):  # static
-        kc, qc, ac = (ref[0, 0][:, i:i + 1]
-                      for ref in (kT_ref, qT_ref, aT_ref))
-        v, b, kq = (row_ref[0, 0, c * heads + i:c * heads + i + 1]
-                    for c in range(3))
+        kc, qc, ac = (cols[:, c * heads + i:c * heads + i + 1]
+                      for c in range(3))
         S = ac * s_ref[0, i]  # every row by its channel's decay
-        u = b * (v - jnp.sum(S * kc, axis=0, keepdims=True))
+        u = beta_ref[at + i] * (
+            v_ref[0, i:i + 1] - jnp.sum(S * kc, axis=0, keepdims=True))
         out_ref[0, i] = S + kc * u
-        o_ref[0, 0, i:i + 1] = jnp.sum(S * qc, axis=0, keepdims=True) + kq * u
+        o_ref[0, i:i + 1] = (jnp.sum(S * qc, axis=0, keepdims=True)
+                             + kq_ref[at + i] * u)
 
 
 def kda_step_pallas(q, k, v, g, beta, pool, rows, *,
-                    interpret: bool = False):
+                    work: LiveSlots | None = None, interpret: bool = False):
     """The step form for a decay a channel as the kernel ``tadnn_kda_step``:
-    grid (slots, groups of heads); a slot's rows of ``pool`` are read and
-    written where they lie (the pool is aliased to the output, the row ids
-    are a scalar prefetch)."""
+    grid (LIVE slots, groups of heads), item ``t`` the slot ``work.order[t]``
+    (``work`` None: the slots off the null row).  The grid's first axis is
+    the traced ``n_live``: a slot that does not decode costs no grid step
+    and its row of ``pool``, the null row, is neither read nor written; a
+    call with no live slot runs item 0 all the same (a grid of no steps is
+    not asked of the chip), a dead slot whose ``beta = 0`` and ``g = 0``
+    leave the null row as it was.  A live slot's rows of ``pool`` are read
+    and written where they lie (the pool is aliased to the output, the
+    order and the row ids are scalar prefetches).  ``q``, ``k``, ``exp(g)``
+    and ``v`` go in as the projections leave them, blocks [1, heads, d] of
+    [S, H, d] (8 heads at the served widths, one float32 tile; a group
+    that is neither whole tiles nor all of H is a block for the interpreter
+    alone), ``beta`` and ``k.q`` as [S H] numbers of scalar memory, and
+    ``o`` comes out [S, H, d_v]: nothing is relaid or broadcast before the
+    call.  The
+    kernel leaves ``o`` of a slot that does not decode unwritten; the
+    select behind it makes those rows zero."""
     S, H, dk = k.shape
     dv = v.shape[-1]
     hb = _head_group(H)
-    G = H // hb
     q, k, v, g, beta = (x.astype(F32) for x in (q, k, v, g, beta))
-
-    def cols(x):  # [S, H, dk] -> [S, G, dk, hb]
-        return jnp.swapaxes(x.reshape(S, G, hb, dk), -1, -2)
-
-    wide = lambda x: jnp.broadcast_to(x[..., None], (S, H, dv))
-    packed = jnp.stack([v, wide(beta), wide(jnp.sum(k * q, -1))], axis=2)
-    packed = jnp.swapaxes(packed.reshape(S, G, hb, 3, dv), 2, 3).reshape(
-        S, G, 3 * hb, dv)
-    col = pl.BlockSpec((1, 1, dk, hb), lambda s, j, r: (s, j, 0, 0))
-    row3 = pl.BlockSpec((1, 1, 3 * hb, dv), lambda s, j, r: (s, j, 0, 0))
-    st = pl.BlockSpec((1, hb, dk, dv), lambda s, j, r: (r[s], j, 0, 0))
+    if work is None:
+        work = live_slots(rows > 0)
+    item = lambda t, j, order, *_: (order[t], j, 0)  # noqa: E731
+    st = pl.BlockSpec((1, hb, dk, dv),
+                      lambda t, j, order, rows, *_: (rows[order[t]], j, 0, 0))
+    key, val = pl.BlockSpec((1, hb, dk), item), pl.BlockSpec((1, hb, dv), item)
     o, pool = pl.pallas_call(
-        functools.partial(_kda_step_kernel, heads=hb),
+        functools.partial(_kda_step_kernel, heads=hb, H=H),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(S, G),
-            in_specs=[col, col, col, row3, st],
-            out_specs=[pl.BlockSpec((1, 1, hb, dv),
-                                    lambda s, j, r: (s, j, 0, 0)), st]),
-        out_shape=[jax.ShapeDtypeStruct((S, G, hb, dv), F32),
+            num_scalar_prefetch=4,
+            # the live slots alone, a traced number (one where there is
+            # none); the pipeline reads the indices of the step after the
+            # last, so the order is one longer than the grid
+            grid=(jnp.clip(work.n_live, 1, S), H // hb),
+            in_specs=[key, key, key, val, st], out_specs=[val, st]),
+        out_shape=[jax.ShapeDtypeStruct((S, H, dv), F32),
                    jax.ShapeDtypeStruct(pool.shape, F32)],
-        input_output_aliases={5: 1},  # the pool, after the row ids
+        input_output_aliases={8: 1},  # the pool, after the prefetches
         interpret=interpret,
         name="tadnn_kda_step",
-    )(rows.astype(jnp.int32), cols(k), cols(q), cols(jnp.exp(g)), packed,
+    )(jnp.pad(work.order, (0, 1), mode="edge"), rows.astype(jnp.int32),
+      beta.reshape(-1), jnp.sum(k * q, -1).reshape(-1), k, q, jnp.exp(g), v,
       pool)
-    return o.reshape(S, H, dv), pool
+    return jnp.where(work.live[:, None, None], o, 0.0), pool
