@@ -329,6 +329,12 @@ ENGINE_FIELDS = {
     "layer_kinds": "metrics/kv_pool_gib.py:15",
     "experts_held": "lib/serving_large.py:39-40,300 (the record's serve_engine)",
     "experts_published": "lib/serving_large.py:39-40,300",
+    # start-up's own account (PR 52): the constructor's seconds and their
+    # parts, and the table of the programs' first calls
+    "build_s": "metrics/engine_build_s.py:12,17",
+    "build_phases": "metrics/engine_build_s.py:15 (printed beside it)",
+    "programs": "metrics/program_load_s.py:14,18 "
+                "metrics/program_compile_s.py:11,14",
 }
 # the same, on a model with linear layers only
 ENGINE_FIELDS_LINEAR = {
@@ -1352,7 +1358,8 @@ def test_a_hybrid_decoder_metric_has_its_file_and_its_cell(name):
         "slot_occupancy", "decode_step_ms.backlog", "device_idle_share.serve",
         "prefill_chunk_device_ms.backlog", "serve_host_ms.backlog",
         "kv_pool_gib", "state_pool_gib", "chunk_call_ms.backlog",
-        "decode_call_ms.backlog", "serve_stall_ms.backlog"}
+        "decode_call_ms.backlog", "serve_stall_ms.backlog",
+        *STARTUP_METRICS}
 
 
 def _flash_reader(name):
@@ -1612,7 +1619,8 @@ def test_the_gated_hybrids_cell_is_appended_and_listed():
         "prefill_chunk_device_ms.backlog", "serve_host_ms.backlog",
         "serve_stall_ms.backlog", "chunk_call_ms.backlog",
         "decode_call_ms.backlog", "kv_pool_gib", "state_pool_gib",
-        "moe_grouped_mm_chunk_ms", "paged_attn_roofline.by_kind"}
+        "moe_grouped_mm_chunk_ms", "paged_attn_roofline.by_kind",
+        *STARTUP_METRICS}
     for m in BENCHMARK["per_layer"]:
         if m["name"] in listed:
             assert m["workloads"][-1] == GATED_CELL, m["name"]
@@ -1676,3 +1684,53 @@ def test_the_kda_counts_at_the_second_shape_are_the_arithmetic(bench):
     assert (flops, moved) == (4 * 1000 * 64 * 128, 1000 * 4096)
     assert counts_moe.grouped_mm_bytes(40, 4096, 1280, itemsize=2) \
         == 40 * 3 * 4096 * 1280 * 2
+
+
+# -- 9. start-up accounts for itself (PR 52) -----------------------------------
+
+STARTUP_METRICS = ("engine_build_s", "program_load_s", "program_compile_s")
+STARTUP_CELLS = [w["name"] for w in BENCHMARK["workloads"]
+                 if MIXES[w["traffic"]]["kind"] in (
+                     "serve-large", "serve-long", "serve-long-routed")]
+
+
+@pytest.mark.parametrize("name", STARTUP_METRICS)
+def test_a_startup_metric_has_its_file_and_its_cells(name):
+    """In the cells whose record holds ``serve_engine`` (the kinds that
+    read ``lib/serving_large.py``'s), and moving ``setup_s``."""
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == name)
+    assert name + ".py" in METRIC_FILES
+    assert entry == {
+        "name": name, "unit": "s", "better": "lower",
+        "source": "program_counter", "layer": "start-up", "moves": "setup_s",
+        "workloads": STARTUP_CELLS}
+    assert entry in BENCHMARK["per_layer"][46:49]  # appended, nothing moved
+    assert len(STARTUP_CELLS) == 7
+
+
+@pytest.mark.parametrize("name", STARTUP_METRICS)
+def test_a_startup_reader_reads_the_engines_own_account(bench, experts,
+                                                        hybrid, name, capsys):
+    """Over a rehearsal record: the constructor's seconds, the sum of the
+    programs' loads (no more than their first calls took), and what of the
+    backend's time was no read of the compile cache.  A tree without the
+    fields (the parent's event) reads ``None``."""
+    read = _span_reader(name)
+    for run in (experts, hybrid):
+        ev = run["record"]["serve_engine"]
+        value = read(run["record"])
+        assert math.isfinite(value) and value >= 0
+        if name == "engine_build_s":
+            assert value == ev["build_s"] > 0
+        else:
+            assert set(ev["programs"]) == set(run["eng"].programs)
+            assert value <= sum(p["call_s"] for p in ev["programs"].values())
+        if name == "program_load_s":
+            assert value >= _span_reader("program_compile_s")(
+                run["record"]) and value > 0
+        old = {k: v for k, v in ev.items()
+               if k not in ("build_s", "build_phases", "build_loads",
+                            "programs")}
+        assert read({**run["record"], "serve_engine": old}) is None
+    assert read({"serve_engine": None}) is None
+    capsys.readouterr()

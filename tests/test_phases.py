@@ -10,6 +10,7 @@ import glob
 import json
 import os
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -92,12 +93,127 @@ def test_compile_counter_is_one_per_process_and_counts_new_programs():
     c = compile_counter()
     assert compile_counter() is c
     f = jax.jit(lambda x: x * 3 + 1)
-    n, secs = c.n, c.seconds
+    n, secs = c.n, c.backend_s
     f(jnp.ones((5,)))
-    assert c.n > n and c.seconds > secs
+    assert c.n > n and c.backend_s > secs
     n = c.n
     f(jnp.ones((5,)))  # the same shape again: nothing to build
     assert c.n == n
+
+
+def test_compile_counter_hears_a_load_by_part_and_nothing_on_a_second_call(
+        tmp_path):
+    """A fresh ``jax.jit`` call moves the trace, the lowering and the
+    backend (a persistent-cache miss reads nothing); the same call again
+    moves none of the four; the same program from another function object
+    is traced and lowered anew and then READ from the persistent cache, a
+    read that lies inside the backend's time.  ``load_s`` is the three
+    without the read, and no more than the call took."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    c = compile_counter()
+    prev = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    cc.reset_cache()
+
+    x = jnp.ones((7, 7))  # (made here: an eager op is a program too)
+
+    def timed(fn):
+        before, t0 = c.loads(), time.monotonic()
+        fn(x)
+        return c.loads(before), time.monotonic() - t0
+
+    try:
+        f = jax.jit(lambda x: jnp.tanh(x @ x) * 52.0)
+        miss, miss_s = timed(f)
+        again, _ = timed(f)
+        hit, hit_s = timed(jax.jit(lambda x: jnp.tanh(x @ x) * 52.0))
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    assert miss["n"] == 1 == hit["n"] and again["n"] == 0
+    assert all(miss[k] > 0 for k in ("trace_s", "lower_s", "backend_s"))
+    assert miss["cache_read_s"] == 0
+    assert not any(again.values())
+    assert all(hit[k] > 0 for k in ("trace_s", "lower_s", "cache_read_s"))
+    assert hit["cache_read_s"] <= hit["backend_s"]
+    for load, call_s in ((miss, miss_s), (hit, hit_s)):
+        assert load["load_s"] == pytest.approx(
+            load["trace_s"] + load["lower_s"] + load["backend_s"])
+        assert load["load_s"] <= call_s
+
+
+def test_a_nested_jit_loads_in_no_more_than_its_call():
+    """A jitted function that calls jitted functions is traced in traces
+    nested in its own, and JAX announces each as it begins (the counter's
+    depth rests on that): the parts count the outermost alone, so their sum
+    is no more than the call took.  A JAX that announces nothing would sum
+    every nested trace beside the one that holds it and fail here."""
+    c = compile_counter()
+    heard = []
+
+    def on(event, dur_s, **_kw):
+        if event.endswith("jaxpr_trace_duration"):
+            heard.append(dur_s)
+
+    @jax.jit
+    def inner(x):
+        for _ in range(40):
+            x = jnp.tanh(x @ x) + jnp.linalg.norm(x)
+        return x
+
+    @jax.jit
+    def outer(x):
+        for _ in range(3):
+            x = inner(x) * 0.5 + jax.nn.softmax(x)
+        return x
+
+    x = jnp.ones((9, 9))
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        before, t0 = c.loads(), time.monotonic()
+        outer(x)
+        load, call_s = c.loads(before), time.monotonic() - t0
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    assert load["n"] == 1 and 0 < load["load_s"] <= call_s
+    # the traces nested: the outermost holds the others' seconds, and the
+    # counter took it alone
+    assert len(heard) > 3 and load["trace_s"] == pytest.approx(max(heard))
+    assert sum(heard) > load["trace_s"]
+
+
+def test_two_threads_loads_are_both_counted():
+    """The depth is a thread's own: an interval of one thread open round
+    another thread's does not make that one an inner interval."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from torch_automatic_distributed_neural_network_tpu.obs.journal import (
+        CompileCounter,
+    )
+
+    c = CompileCounter()
+    trace, backend = (k for k, v in c.PARTS.items()
+                      if v in ("trace_s", "backend_s"))
+    with ThreadPoolExecutor(1) as a, ThreadPoolExecutor(1) as b:
+        a.submit(c._on_scalar, trace, 0.0).result()
+        b.submit(c._on_scalar, trace, 0.0).result()
+        b.submit(c._on_scalar, trace, 0.0).result()  # nested, in b
+        b.submit(c._on, trace, 0.25).result()
+        b.submit(c._on, trace, 1.0).result()
+        a.submit(c._on, trace, 2.0).result()
+        a.submit(c._on_scalar, backend, 0.0).result()
+        a.submit(c._on, backend, 4.0).result()
+    assert c.loads() == {"n": 1, "trace_s": 3.0, "lower_s": 0.0,
+                         "backend_s": 4.0, "cache_read_s": 0.0,
+                         "load_s": 7.0}
 
 
 def test_the_second_annotation_api_is_gone():
@@ -384,9 +500,11 @@ def test_first_steps_report_compiles_and_no_prompt_length_after(served):
     assert j.named("serve.step")[0]["compiles"] > 0
     events = [r for r in j.named("compile") if r.get("fn") == "serve"]
     assert events and all(r["dur_s"] > 0 for r in events)
-    # one compile event for each step that compiled, no more
-    assert len(events) == sum(
-        1 for s in j.named("serve.step") if s["compiles"])
+    # one compile event for each program a step loaded, none for the step
+    assert [r["program"] for r in events] == list(_eng.programs)
+    assert all(s["compiles"] for s in j.named("serve.step")
+               if s["step"] in {p["at_step"]
+                                for p in _eng.programs.values()})
 
 
 def test_decode_steps_carry_the_attention_grid_they_ran(monkeypatch,
@@ -548,7 +666,7 @@ def test_a_programs_parts_are_scoped_by_name(kind_engines, kind, program):
 
 
 def test_report_renders_the_step_phases(served, tmp_path):
-    _eng, j, _ = served
+    eng, j, _ = served
     path = tmp_path / "journal.jsonl"
     with Journal(str(path), host0_only=False) as out:
         for r in j.records[1:]:
@@ -562,6 +680,11 @@ def test_report_renders_the_step_phases(served, tmp_path):
     assert "step phases (mean ms, host):" in text
     assert "decode_wait" in text
     assert "XLA built programs in this process during" in text
+    # start-up, from the newest serve.engine: the build and a line a program
+    assert "start-up: engine built in" in text and "(weights " in text
+    for name, p in eng.programs.items():
+        assert re.search(rf"{name} (COMPILED|loaded) at step {p['at_step']} "
+                         rf"in \d+\.\d\d s \(trace ", text)
     assert rep["compile"]["count"] >= srv["steps_that_compiled"]
 
 

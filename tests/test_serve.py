@@ -1147,7 +1147,7 @@ def test_decode_is_dispatched_before_the_step_before_is_read(
     reads = [e for e in trace.log if e[0] == "read"]
     assert trace.n_dispatched >= 5 and len(reads) >= trace.n_dispatched
     steps = [s for s in j.named("serve.step") if s["decode_s"]]
-    (ev,) = j.named("serve.engine")
+    ev = j.named("serve.engine")[-1]
     if speculative:
         # every read is of the newest output: nothing is in flight behind it
         assert all(step == newest for _, step, newest in reads)

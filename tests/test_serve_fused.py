@@ -29,6 +29,7 @@ from torch_automatic_distributed_neural_network_tpu.models.transformer_core impo
     DecoderLM,
     TransformerConfig,
 )
+from torch_automatic_distributed_neural_network_tpu.obs import schema
 from torch_automatic_distributed_neural_network_tpu.obs.journal import Journal
 from torch_automatic_distributed_neural_network_tpu.training.lora import (
     LoraSpec,
@@ -172,6 +173,97 @@ def test_a_fused_step_serves_the_two_calls_tokens(served, family):
         assert len([s for s in steps if "moe_pairs" in s]) == len(alone)
 
 
+@pytest.mark.parametrize("family", ["gpt2", "linear_attention"])
+def test_each_programs_first_call_is_on_the_engines_description(models,
+                                                                family):
+    """Start-up accounts for itself.  The engine's description says what
+    building it cost; after a step in which a program had its first call
+    it is said AGAIN with the table of programs so far, each by the name a
+    trace shows: the counter's parts over that one call, their sum no more
+    than the call took.  A ``compile`` event a program, on the step it
+    loaded in; a second run loads nothing and says nothing again."""
+    journal = Journal(None, validate=True, host0_only=False)
+    eng, _, _ = _serve(models, family, journal=journal)
+    first, *again = journal.named("serve.engine")
+    last = again[-1]
+    assert first["build_s"] >= sum(first["build_phases"].values()) > 0
+    assert {"weights", "pool", "describe"} <= set(first["build_phases"])
+    assert first["build_loads"]["load_s"] <= first["build_s"]
+    # the same description, the table beside it
+    assert "programs" not in first
+    assert set(last) == set(first) | {"programs"}
+    assert all(last[k] == v for k, v in first.items()
+               if k not in ("t", "wall"))
+    # the chunk that carries a step's rows, the sampler, the step: what
+    # this engine dispatched, and no other
+    assert list(last["programs"]) == list(eng.programs)
+    assert set(eng.programs) == {"serve_prefill_chunk", "serve_first_token",
+                                 "serve_decode_step"}
+    for p in last["programs"].values():
+        assert p["n"] >= 1 and 0 < p["load_s"] <= p["call_s"]
+        assert p["load_s"] == pytest.approx(
+            p["trace_s"] + p["lower_s"] + p["backend_s"])
+        assert 0 <= p["cache_read_s"] <= p["backend_s"]
+        assert "reloaded_at" not in p
+    steps = journal.named("serve.step")
+    # the description again after each step that loaded, and after no other
+    at = sorted({p["at_step"] for p in eng.programs.values()})
+    assert [len(e["programs"]) for e in again] == [
+        sum(p["at_step"] <= step for p in eng.programs.values())
+        for step in at]
+    # ``compiles`` is still what the PROCESS built or loaded in the step
+    assert all(s["compiles"] >= sum(p["at_step"] == s["step"]
+                                    for p in eng.programs.values())
+               for s in steps)
+    events = [e for e in journal.named("compile") if e["fn"] == "serve"]
+    assert [(e["program"], e["dur_s"]) for e in events] == [
+        (name, p["load_s"]) for name, p in eng.programs.items()]
+    # (the parts are in ``programs``, once)
+    assert all(set(e) - set(schema.BASE_FIELDS) == {"fn", "program"}
+               for e in events)
+    n_records = len(journal.records)
+    eng.submit([3, 1, 4, 1, 5, 9, 2, 6, 5, 3], max_new_tokens=5)
+    eng.run()
+    assert len(eng.programs) == 3
+    assert {r["name"] for r in journal.records[n_records:]} == {
+        "serve.step", "serve.request_done"}
+    assert not any(s["compiles"]
+                   for s in journal.named("serve.step")[len(steps):])
+
+
+def test_a_program_loaded_again_is_named_where_it_happens(models):
+    """Once every program has had its first call, a window can compile only
+    by loading one of them AGAIN (here: JAX's caches dropped): the call in
+    which the counter moves is a ``compile`` event under the program's name
+    with that load's seconds, the program's row takes the seconds in and
+    dates the load, and the description is said again."""
+    journal = Journal(None, validate=True, host0_only=False)
+    eng, _, _ = _serve(models, "gpt2", journal=journal)
+    before = {k: dict(v) for k, v in eng.programs.items()}
+    n_steps = len(journal.named("serve.step"))
+    n_events = len(journal.named("compile"))
+    n_said = len(journal.named("serve.engine"))
+    jax.clear_caches()
+    eng.submit([3, 1, 4, 1, 5, 9, 2, 6, 5, 3], max_new_tokens=5)
+    eng.run()
+    assert list(eng.programs) == list(before)
+    events = journal.named("compile")[n_events:]
+    assert {e["program"] for e in events} == set(before)
+    steps = {s["step"]: s for s in journal.named("serve.step")[n_steps:]}
+    for name, p in eng.programs.items():
+        was = before[name]
+        mine = [e for e in events if e["program"] == name]
+        assert p["n"] == was["n"] + len(mine) > was["n"]
+        assert p["load_s"] == pytest.approx(
+            was["load_s"] + sum(e["dur_s"] for e in mine))
+        assert p["load_s"] <= p["call_s"] and p["at_step"] == was["at_step"]
+        assert steps[p["reloaded_at"]]["compiles"] >= 1
+    said = journal.named("serve.engine")[n_said:]
+    assert said and said[-1]["programs"] == eng.programs
+    # an event keeps the row as it stood, not the dict the engine adds to
+    assert journal.named("serve.engine")[n_said - 1]["programs"] == before
+
+
 @pytest.mark.parametrize("fused", [True, False], ids=["fused", "two_calls"])
 def test_live_expert_tiles_are_counted_on_every_call(served, fused, tmp_path):
     """``moe_tiles_active`` rides with every step's tokens, a fused step's
@@ -188,7 +280,7 @@ def test_live_expert_tiles_are_counted_on_every_call(served, fused, tmp_path):
 
     eng, _, _, journal = served("sliding_full_experts", fused)
     steps = journal.named("serve.step")
-    engine, = journal.named("serve.engine")
+    engine = journal.named("serve.engine")[-1]
     path = tmp_path / "journal.jsonl"
     path.write_text("".join(json.dumps(r) + "\n" for r in journal.records))
     cfg = eng.cfg
@@ -283,7 +375,7 @@ def test_engines_that_cannot_fuse_run_the_two_calls(engine):
     assert eng.fused_steps == 0 == sum(s["fused"] for s in steps)
     assert not any(s["fused_decode_rows"] for s in steps)
     assert [r.out_tokens for r in reqs] == [_greedy(p, 7) for p in prompts]
-    (ev,) = journal.named("serve.engine")
+    ev = journal.named("serve.engine")[-1]
     assert ev["speculative"] == (2 if engine == "speculative" else 0)
 
 

@@ -111,6 +111,17 @@ monitor`` (obs/slo_monitor) folds the same stream into rolling SLO
 windows while the engine is still running.  Timeline stamps route
 through the scheduler's injectable clock so a discrete-event replay
 produces the same fields on virtual time.
+
+Start-up accounts for itself: ``serve.engine``, emitted at the end of
+construction, says what building the engine cost (``build_s``, the parts
+in ``build_phases``, what XLA loaded meanwhile in ``build_loads``), and a
+program's FIRST call is recorded where it happens (``_dispatched``): the
+process's compile counter's parts over that one call (trace, lowering,
+a read of the compile cache or a build), and so is a later call in which
+it was loaded AGAIN.  After a step in which a program loaded the event is
+emitted again with the table so far (``programs``), so the newest
+``serve.engine`` is the engine's description; each load is one ``compile``
+event that names the program.
 """
 
 from __future__ import annotations
@@ -128,6 +139,7 @@ import numpy as np
 
 from ...models.transformer_core import TransformerConfig, layer_plan
 from ...obs import journal as _journal
+from ...obs.schema import BASE_FIELDS
 from ...ops.gated_delta import step_rows_walked
 from ...ops.paged_attention import latent_chunk_key_blocks, tensor_degree
 from ...parallel.expert import expert_tiles
@@ -230,9 +242,18 @@ class ServeEngine:
             raise ValueError(
                 f"unknown attention_impl {attention_impl!r} "
                 f"(expected 'paged' or 'dense')")
+        # what this construction costs a restart: host seconds of all of it
+        # and of its parts (``serve.engine``'s ``build_s``, ``build_phases``)
+        # and what XLA loaded meanwhile (``build_loads``: the casts' and the
+        # pool's small programs, an abstract trace of each serving program)
+        t_build = time.monotonic()
+        self._compiles = _journal.compile_counter()
+        loads_before = self._compiles.loads()
+        build: dict[str, float] = {}
         self.cfg: TransformerConfig = model.cfg
         # the tree the base programs take (see the class docstring)
-        self.params = per_layer_params(variables["params"], self.cfg)
+        with _journal.phase(build, "weights", "serve.build.weights"):
+            self.params = per_layer_params(variables["params"], self.cfg)
         # the tenant prefill adds a low-rank delta to the weight as it
         # was given and rounds the SUM: it keeps that tree, and an
         # engine without tenants keeps no reference to it
@@ -335,11 +356,12 @@ class ServeEngine:
         if num_blocks is None:
             # worst case every slot full-length, plus the null block
             num_blocks = n_slots * self.max_blocks + 1
-        self.pool = PagedKVPool(
-            self.cfg, num_blocks=num_blocks, block_size=block_size,
-            dtype=cache_dtype, quantize=quant_kv, mesh=mesh,
-            n_slots=n_slots, max_blocks=self.max_blocks,
-            prefill_chunk=self.prefill_chunk)
+        with _journal.phase(build, "pool", "serve.build.pool"):
+            self.pool = PagedKVPool(
+                self.cfg, num_blocks=num_blocks, block_size=block_size,
+                dtype=cache_dtype, quantize=quant_kv, mesh=mesh,
+                n_slots=n_slots, max_blocks=self.max_blocks,
+                prefill_chunk=self.prefill_chunk)
         self._win_rows = list(self.pool.win_tables)  # a slot's ring
         self.lora_spec = lora_spec
         self.adapter_pool: AdapterPool | None = None
@@ -356,7 +378,6 @@ class ServeEngine:
         # cache-off run's chunk partition of the recomputed suffix is
         # reproduced exactly (bit-identical tokens either way).
         self.journal = journal or _journal.get_default()  # never None
-        self._compiles = _journal.compile_counter()
         self._gc = _journal.gc_counter()
         self._phases: dict[str, float] = {}  # this step's, by PHASES name
         self._prefix_cache = None
@@ -481,15 +502,18 @@ class ServeEngine:
                 self._prefill_lora_fn = jax.jit(serve_prefill_chunk_lora,
                                                 donate_argnums=(2,))
         if self.cfg.cross_start < self.cfg.n_layers:
-            for name, fn, operands in (
-                    ("step", self._step_fn, self._abstract_decode_args),
-                    ("chunk", self._prefill_fn, self._abstract_prefill_args),
-                    ("chunk_and_step", self._fused_fn,
-                     self._abstract_fused_args)):
-                if fn is not None:
-                    took = programs.rows_walked(fn, operands())
-                    self._program_rows[name] = (
-                        took[0], took[self.cfg.cross_start])
+            with _journal.phase(build, "programs_traced",
+                                "serve.build.programs_traced"):
+                for name, fn, operands in (
+                        ("step", self._step_fn, self._abstract_decode_args),
+                        ("chunk", self._prefill_fn,
+                         self._abstract_prefill_args),
+                        ("chunk_and_step", self._fused_fn,
+                         self._abstract_fused_args)):
+                    if fn is not None:
+                        took = programs.rows_walked(fn, operands())
+                        self._program_rows[name] = (
+                            took[0], took[self.cfg.cross_start])
             self._sent = self._nothing_sent()
         # the row tiles the expert layers of a call lay out, whatever lands
         # in them (``moe_tiles_active`` of a step counts those): [a call
@@ -529,19 +553,33 @@ class ServeEngine:
 
         _cache = _export_cache_mod.resolve(export_cache)
         if _cache is not None:
-            self._export_compiled(
-                _cache, num_blocks=num_blocks, block_size=block_size,
-                quant_kv=bool(quant_kv), cache_dtype=cache_dtype,
-                n_adapters=n_adapters,
-                quant_adapters=bool(quant_adapters))
-        given = jax.tree.leaves(variables["params"])
-        held = jax.tree.leaves(self.params)
-
-        def held_bytes(dtype) -> int:
-            return sum(x.nbytes for x in held if x.dtype == dtype)
-
-        self.journal.event(
+            with _journal.phase(build, "export", "serve.build.export"):
+                self._export_compiled(
+                    _cache, num_blocks=num_blocks, block_size=block_size,
+                    quant_kv=bool(quant_kv), cache_dtype=cache_dtype,
+                    n_adapters=n_adapters,
+                    quant_adapters=bool(quant_adapters))
+        # the programs' loads, by the name a trace shows without its
+        # ``jit_`` (``_dispatched``): the newest ``serve.engine`` carries the
+        # table; this step's, [(name, seconds)], a ``compile`` event each;
+        # and the readings before the call last dispatched
+        self.programs: dict[str, dict] = {}
+        self._loads: list[tuple[str, float]] = []
+        self._mark: tuple[dict, float] = (self._compiles.loads(), 0.0)
+        with _journal.phase(build, "describe", "serve.build.describe"):
+            given = jax.tree.leaves(variables["params"])
+            held = jax.tree.leaves(self.params)
+            weights_cast = sum(a.dtype != b.dtype for a, b in zip(
+                given, jax.tree.leaves(jax.eval_shape(
+                    lambda: compute_dtype_params(
+                        variables["params"], self.cfg)))))
+            bytes_compute, bytes_fp32 = (
+                sum(x.nbytes for x in held if x.dtype == dtype)
+                for dtype in (jnp.dtype(self.cfg.dtype), jnp.float32))
+        described = self.journal.event(
             "serve.engine", attention_impl=attention_impl,
+            build_s=time.monotonic() - t_build, build_phases=build,
+            build_loads=self._compiles.loads(loads_before),
             prefill_chunk=self.prefill_chunk,
             n_slots=n_slots, max_len=max_len, block_size=block_size,
             quant_kv=bool(quant_kv),
@@ -554,12 +592,9 @@ class ServeEngine:
             tp=tensor_degree(mesh),
             # of the tree the base programs take: leaves rounded to the
             # compute dtype at construction, and its bytes by dtype
-            weights_cast=sum(a.dtype != b.dtype for a, b in zip(
-                given, jax.tree.leaves(jax.eval_shape(
-                    lambda: compute_dtype_params(
-                        variables["params"], self.cfg))))),
-            weight_bytes_compute=held_bytes(jnp.dtype(self.cfg.dtype)),
-            weight_bytes_fp32=held_bytes(jnp.float32),
+            weights_cast=weights_cast,
+            weight_bytes_compute=bytes_compute,
+            weight_bytes_fp32=bytes_fp32,
             # the layers by kind and the bytes their caches hold: pages for
             # max_len a slot, the sliding layers' rings, and the linear
             # layers' recurrent states and convolution tails (a row a slot)
@@ -618,6 +653,11 @@ class ServeEngine:
             paged_sets=(self.pool.n_full + self.pool.ring.count(True)
                         if kinds else None),
             shared_readers=(list(kinds).count("shared_attention") or None))
+        # what the event said, less the journal's own stamps: said again,
+        # with ``programs``, after a step in which a program loaded (None:
+        # a journal that writes nothing)
+        self._described = described and {
+            k: v for k, v in described.items() if k not in BASE_FIELDS}
         # the counters of the decode step last read (serve.step carries them)
         self._counters: dict[str, int] = {}
 
@@ -784,6 +824,56 @@ class ServeEngine:
         return _journal.phase(self._phases, key, "serve." + key,
                               step=self._step_count + 1, **ids)
 
+    def _dispatching(self) -> None:
+        """Before a program is called: a reading of the process's compile
+        counter and of the clock, for ``_dispatched``.  The pair stands
+        BESIDE the call and not round it, and the methods a program is
+        called from gain no local for it: a first call traces and lowers
+        some hundred Python frames deep, and what lies under it decides
+        where those frames cross the interpreter's 16 KiB stack chunks
+        (CPython 3.12 maps and unmaps a chunk at EVERY call across such a
+        boundary: one frame more under ``solar``'s chunk program tripled a
+        kernel's lowering, 0.55 to 1.6 s; PERF.md, PR 52)."""
+        self._mark = self._compiles.loads(), time.monotonic()
+
+    def _dispatched(self, name: str) -> None:
+        """After the call of the program a trace shows as ``jit_<name>``.
+        A program's FIRST call is where it is traced, lowered and fetched
+        from the compile cache or built: ``programs[name]`` keeps what the
+        two readings say of it, the counter's parts and their sum
+        ``load_s``, the call's host seconds ``call_s`` and the step it fell
+        in.  A later call in which the counter moved (new shapes, a cache
+        dropped: the program was loaded AGAIN, inside somebody's window)
+        is added to those sums and dated ``reloaded_at``."""
+        call_s = time.monotonic() - self._mark[1]
+        seen = self.programs.get(name)
+        if seen is None or self._compiles.n != self._mark[0]["n"]:
+            load = self._compiles.loads(self._mark[0])
+            load["call_s"] = call_s
+            self._loads.append((name, load["load_s"]))
+            if seen is None:
+                self.programs[name] = {**load,
+                                       "at_step": self._step_count + 1}
+            else:
+                seen.update({k: seen[k] + v for k, v in load.items()},
+                            reloaded_at=self._step_count + 1)
+
+    def _say_loads(self, loaded: list) -> None:
+        """One ``compile`` event for each program loaded in this step (an
+        engine's first steps, or a program loaded again): each stalled
+        every stream for so long, and ``programs`` has the parts."""
+        for name, load_s in loaded:
+            self.journal.event("compile", fn="serve", program=name,
+                               dur_s=load_s)
+
+    def _describe_again(self) -> None:
+        """The engine's description again, with what it has loaded so far:
+        the newest ``serve.engine`` is the one readers take."""
+        if self._described:
+            self.journal.event(
+                "serve.engine", **self._described,
+                programs={k: dict(v) for k, v in self.programs.items()})
+
     def _bind_adapter(self, slot: int, req: Request) -> bool:
         """Pin the request's adapter at the transition into decode
         (pins back live decode reads ONLY — prefilling slots reference
@@ -875,6 +965,7 @@ class ServeEngine:
         st = self._prefill[req.rid]
         with self._phase("prefill_dispatch", rid=req.rid, pos=st.pos):
             packed, n_real = self._chunk_operands(slot, req)
+            self._dispatching()
             if st.lora is not None:
                 self.pool.kv, logits = self._prefill_lora_fn(
                     self._merge_base, st.lora, self.pool.kv, packed,
@@ -890,6 +981,8 @@ class ServeEngine:
             else:
                 self.pool.kv, logits = self._prefill_fn(
                     self.params, self.pool.kv, packed, self._win_rows[slot])
+            self._dispatched("serve_prefill_chunk" if st.lora is None
+                             else "serve_prefill_chunk_lora")
         self._sent_program(
             chunk=(n_real, st.pos),
             program="chunk" if self._fused_fn is None else "chunk_and_step")
@@ -909,9 +1002,11 @@ class ServeEngine:
         req.prefill_chunks += 1
         if st.pos >= req.n_prompt and self._bind_adapter(slot, req):
             with self._phase("prefill_dispatch", rid=req.rid, pos=st.pos):
+                self._dispatching()
                 self._out = self._first_fn(
                     self._out, logits,
                     np.asarray([slot, req.rid], np.int32), self._rng)
+                self._dispatched("serve_first_token")
             if self._sent_late is not None:
                 # the unread output now waits for this chunk, and so for
                 # every program that went out before it
@@ -1121,6 +1216,7 @@ class ServeEngine:
                 of_chunk, n_real = self._chunk_operands(*rider)
                 packed = programs.pack_chunk_and_step(of_chunk, packed)
         with self._phase("decode_dispatch"):
+            self._dispatching()
             if rider is None:
                 self.pool.kv, self._out = self._step_fn(
                     self.params, self.pool.kv, packed, self._out,
@@ -1129,6 +1225,8 @@ class ServeEngine:
                 self.pool.kv, self._out, logits = self._fused_fn(
                     self.params, self.pool.kv, packed, self._out,
                     self._win_rows[rider[0]], self.pool.win_tables, step_rng)
+            self._dispatched("serve_decode_step" if rider is None
+                             else "serve_prefill_chunk")
         for _, req, _ in rows:
             req.n_inflight += 1
         self._sent_program(  # (before the rows are unread ones)
@@ -1325,7 +1423,8 @@ class ServeEngine:
         discarded_before = self.discarded_tokens
         fused_before = self.fused_steps, self.fused_decode_rows
         key_blocks_before = self.chunk_key_blocks
-        compiles, compile_s = self._compiles.n, self._compiles.seconds
+        compiles = self._compiles.n
+        self._loads = loaded = []  # (name, seconds) of this step's loads
         gc_s, gc_full = self._gc.total_s, self._gc.passes[self._gc.FULL]
         self._phases = phases = {}
         self._counters = {}
@@ -1379,13 +1478,10 @@ class ServeEngine:
         self._step_count += 1
         self._occupancy_sum += sched.n_active / self.n_slots
         compiles = self._compiles.n - compiles
+        if loaded:
+            self._say_loads(loaded)
         if compiles:
-            # a program built in this step (an engine's first calls)
-            # stalled every stream for this long
-            self.journal.event(
-                "compile", fn="serve",
-                dur_s=self._compiles.seconds - compile_s)
-            # what building it left on the heap (jaxprs, executables, their
+            # what a load leaves on the heap (jaxprs, executables, their
             # caches: a quarter of a million objects) lives as long as the
             # engine.  A full pass of Python's collector walks all of it,
             # 100-130 ms inside whichever later step's allocations set the
@@ -1428,6 +1524,8 @@ class ServeEngine:
                 self.chunk_key_blocks - key_blocks_before}
                if self._kernel_layers and n_chunks else {}),
             **adapter_stats, **self._counters)
+        if loaded:
+            self._describe_again()
         if self._debug_invariants:
             sched.check_invariants()
 

@@ -40,6 +40,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import threading
 import time
 import warnings
 from typing import Any, IO, Iterator
@@ -75,23 +76,94 @@ class phase:
 
 
 class CompileCounter:
-    """XLA backend compiles of this process and their seconds, counted by
-    one ``jax.monitoring`` listener (it fires only when XLA compiles or
-    loads a program: nothing on a hot path).  ``compile_counter()``.
-    The count is the process's: a reader that diffs it over an interval
-    (``serve.step``'s ``compiles``) also sees what another engine or
-    thread compiled in that interval."""
+    """What loading programs cost this process, by part, summed by one
+    pair of ``jax.monitoring`` listeners (they fire only while JAX traces,
+    lowers, compiles or reads its cache: nothing on a hot path).
+    ``compile_counter()``.
 
-    EVENT = "/jax/core/compile/backend_compile_duration"
+    ``n`` counts the programs XLA built or read from the persistent cache
+    (``backend_compile_duration`` events); beside it the four sums of
+    ``PARTS`` (``backend_s`` is those programs' time).  What fires when, for a
+    ``jax.jit`` call (JAX 0.9.0; printed on the CPU and on a v5e, PR 52,
+    the same on both):
+
+    - first call, persistent-cache MISS: a ``jaxpr_trace_duration`` for the
+      program and one for every jitted function it calls (``jnp.tanh``
+      ...: 121 events for a function of 30 lines), NESTED: the program's
+      own contains them; one ``jaxpr_to_mlir_module_duration`` (a lowering
+      rule may trace again inside it: the Pallas interpreter's does); one
+      ``backend_compile_duration`` (1.09 s on the chip).  No
+      ``cache_retrieval_time_sec``: the failed lookup is inside the
+      backend's time, unnamed.
+    - first call, persistent-cache HIT (another process): the same traces
+      and lowering, then ``cache_retrieval_time_sec`` and AFTER it a
+      ``backend_compile_duration`` that CONTAINS it (0.0603 s round
+      0.0596 s on the chip, 0.0081 round 0.0072 on the CPU): the backend's
+      time less the cache's is what XLA compiled.
+    - second call: nothing.
+
+    JAX announces a trace, a lowering and a build as each BEGINS (a
+    ``record_scalar`` under the event's name, from
+    ``dispatch.LogElapsedTimeContextManager.__enter__``), so the counter
+    keeps a depth, a thread its own, and adds an interval's seconds only
+    where no other of that thread is open round it: ``trace_s`` is the
+    outermost traces', a trace inside a lowering is the lowering's, and
+    ``loads()``'s ``load_s`` = ``trace_s + lower_s + backend_s`` counts no
+    second twice (it is no more than the call took;
+    ``test_phases.py::test_a_nested_jit_loads_in_no_more_than_its_call`` holds
+    a JAX that stops announcing to that).  ``backend_s`` contains
+    ``cache_read_s``.
+
+    The sums are the process's: a reader that diffs them over an interval
+    (``serve.step``'s ``compiles``) also sees what another engine or
+    thread loaded in that interval; diffed round ONE call on one thread
+    (``ServeEngine``'s first call of a program) they are that program's."""
+
+    PARTS = {"/jax/core/compile/jaxpr_trace_duration": "trace_s",
+             "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+             "/jax/core/compile/backend_compile_duration": "backend_s",
+             "/jax/compilation_cache/cache_retrieval_time_sec":
+                 "cache_read_s"}
 
     def __init__(self):
         self.n = 0
-        self.seconds = 0.0
+        self.trace_s = self.lower_s = self.backend_s = 0.0
+        self.cache_read_s = 0.0
+        # .open: this thread's traces, lowerings and builds begun and not
+        # ended (threads load programs side by side)
+        self._depth = threading.local()
+
+    def _on_scalar(self, event: str, _value, **_kw) -> None:
+        if event in self.PARTS:
+            self._depth.open = getattr(self._depth, "open", 0) + 1
 
     def _on(self, event: str, dur_s: float, **_kw) -> None:
-        if event == self.EVENT:
-            self.n += 1
-            self.seconds += dur_s
+        part = self.PARTS.get(event)
+        if part is None:
+            return
+        depth = getattr(self._depth, "open", 0)
+        if part == "cache_read_s":
+            # (JAX announces no read: it lies inside its backend's interval)
+            outermost = depth == 1
+        else:
+            self.n += part == "backend_s"
+            # (never below 0: a counter registered inside a trace hears
+            # that trace end and not begin)
+            self._depth.open = depth = max(0, depth - 1)
+            outermost = not depth
+        if outermost:  # else an outer interval's seconds hold these
+            setattr(self, part, getattr(self, part) + dur_s)
+
+    def loads(self, since: dict | None = None) -> dict:
+        """``n``, the four sums and ``load_s`` (trace + lowering + backend:
+        the cache's read lies inside the backend's) as they stand, or
+        their growth since an earlier reading ``since``."""
+        now = {"n": self.n, "trace_s": self.trace_s, "lower_s": self.lower_s,
+               "backend_s": self.backend_s, "cache_read_s": self.cache_read_s,
+               "load_s": self.trace_s + self.lower_s + self.backend_s}
+        if since is None:
+            return now
+        return {k: v - since[k] for k, v in now.items()}
 
 
 _compile_counter: CompileCounter | None = None
@@ -104,6 +176,7 @@ def compile_counter() -> CompileCounter:
         import jax
 
         _compile_counter = CompileCounter()
+        jax.monitoring.register_scalar_listener(_compile_counter._on_scalar)
         jax.monitoring.register_event_duration_secs_listener(
             _compile_counter._on)
     return _compile_counter

@@ -446,8 +446,11 @@ def generate(target: str, metrics_path: str | None = None) -> dict:
             "weight_bytes_compute": (sengine or {}).get(
                 "weight_bytes_compute"),
             "weight_bytes_fp32": (sengine or {}).get("weight_bytes_fp32"),
-            # layer kinds, the experts held and the pool by kind of page
+            # layer kinds, the experts held and the pool by kind of page;
+            # start-up: what the constructor cost and each program's first
+            # call (the newest ``serve.engine`` holds the table)
             **{k: (sengine or {}).get(k) for k in (
+                "build_s", "build_phases", "build_loads", "programs",
                 "layer_kinds", "experts_held", "experts_published",
                 "zero_experts", "shortcut_experts", "kv_bytes_full",
                 "kv_bytes_window", "state_bytes_linear", "conv_bytes_linear",
@@ -1125,6 +1128,26 @@ def format_report(report: dict) -> str:
                 f" GiB float32 ({sv['weights_cast']} leaves rounded once)")
         if bparts:
             lines.append("  " + "  ".join(bparts))
+        if sv.get("build_s") is not None:
+            lines.append(
+                f"  start-up: engine built in {sv['build_s']:.2f} s ("
+                + ", ".join(f"{k.replace('_', ' ')} {v:.2f}" for k, v in
+                            (sv.get("build_phases") or {}).items())
+                + f"; {(sv.get('build_loads') or {}).get('load_s', 0.0):.2f}"
+                " s of it loading its small programs)")
+            for name, p in (sv.get("programs") or {}).items():
+                # no read of the compile cache: XLA built it
+                built = p["backend_s"] > 0 and not p["cache_read_s"]
+                lines.append(
+                    f"    {name} {'COMPILED' if built else 'loaded'} at step "
+                    f"{p['at_step']} in {p['load_s']:.2f} s (trace "
+                    f"{p['trace_s']:.2f}, lowering {p['lower_s']:.2f}, "
+                    + (f"compile {p['backend_s']:.2f}" if built else
+                       f"cache read {p['cache_read_s']:.2f}")
+                    + f"; first call {p['call_s']:.2f} s)"
+                    + (f"; LOADED AGAIN since, last at step "
+                       f"{p['reloaded_at']}: the seconds are those of all "
+                       f"{p['n']} loads" if "reloaded_at" in p else ""))
         if sv.get("kv_bytes_full") is not None:
             kinds = sv.get("layer_kinds") or ()
             eparts = [

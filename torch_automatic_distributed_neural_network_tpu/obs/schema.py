@@ -135,10 +135,16 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             "block_k": "int", "tiles_visited": "int",
             "tiles_square": "int", "tiles_masked": "int"}),
     _s("compile", "first XLA compile of a jitted fn (event from the "
-       "jit cache; span from AOT paths); fn=serve: every program XLA "
-       "built or loaded in the process during one engine step",
+       "jit cache; span from AOT paths); fn=serve: ONE of an engine's "
+       "programs loaded in a call (its first, or a later one that loaded "
+       "it again)",
        req={"fn": "str"},
-       opt={"dur_s": "float", "signature": "str"}, kind="both"),
+       # program: the engine's program, by the name a trace shows without
+       # its jit_; dur_s is then that load's seconds (trace, lowering, and
+       # a read of the compile cache or a build: serve.engine's
+       # ``programs`` has them by part)
+       opt={"dur_s": "float", "signature": "str", "program": "str"},
+       kind="both"),
     _s("recompile", "signature change re-traced an already-compiled fn",
        req={"fn": "str"}, opt={"dur_s": "float", "signature": "str"}),
     _s("run_start", "Trainer.run began",
@@ -320,8 +326,11 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             "findings": "int"}),
 
     # -- serving engine -----------------------------------------------------
-    _s("serve.engine", "engine construction: the serving configuration",
-       version=2,
+    _s("serve.engine", "the engine's description: the serving "
+       "configuration and what building it cost, at construction; again, "
+       "with the programs loaded so far, after each step in which a "
+       "program loaded (the newest is the one to read)",
+       version=3,
        req={"n_slots": "int", "max_len": "int", "block_size": "int",
             "quant_kv": "bool", "attention_impl": "str",
             "prefill_chunk": "int", "speculative": "int",
@@ -386,7 +395,26 @@ REGISTRY: dict[str, EventSchema] = {s.name: s for s in (
             # decode steps the engine dispatches with the step before
             # unread: 1, or 0 where the next step's operands need the
             # tokens' values (speculative drafts)
-            "dispatch_ahead": "int"}),
+            "dispatch_ahead": "int",
+            # what the constructor cost a restart: host seconds of all of
+            # it, of its parts ({"weights": the cast and the per-layer
+            # take-apart, "pool", "programs_traced": an abstract trace of
+            # each program where the model ends in layers that keep no
+            # cache, "export": only with an export cache, "describe": what
+            # feeds this event}; nothing fenced) and what the process's
+            # compile counter heard meanwhile (CompileCounter.loads: the
+            # casts' and the pool's small programs are loads too)
+            "build_s": "float", "build_phases": "dict",
+            "build_loads": "dict",
+            # the programs that have had their first call, by the name a
+            # trace shows without its jit_: {"n", "trace_s", "lower_s",
+            # "backend_s", "cache_read_s", "load_s": the counter's growth
+            # over that ONE call (load_s = trace + lowering + backend; the
+            # backend's time contains the cache's read), "call_s": the
+            # call's host seconds, "at_step"}; a later call that loaded the
+            # program again is added to those sums, and "reloaded_at" is
+            # its step.  Absent on the event of construction
+            "programs": "dict"}),
     _s("serve.step", "one serving iteration (engine or gateway "
        "SimReplica)", version=2,
        req={"n_active": "int", "n_queued": "int", "new_tokens": "int",
